@@ -29,7 +29,9 @@ import sys
 def main() -> int:
     from pipeedge_tpu import benchkit
     from pipeedge_tpu.benchkit import schema
+    from pipeedge_tpu.utils import enable_compile_cache
 
+    enable_compile_cache()
     p = argparse.ArgumentParser(
         description=__doc__.splitlines()[0], add_help=False)
     p.add_argument("-h", "--help", action="store_true")

@@ -1,6 +1,6 @@
 """JAX dispatch-path rules: jit-in-loop, donated reuse, host syncs.
 
-The steady-state laws behind BENCH_r05's 8.15 ms ubatch cadence: tracing
+The steady-state laws behind a steady microbatch cadence: tracing
 is for setup (a `jax.jit` inside a per-microbatch loop recompiles or at
 best re-hashes every iteration, PL301); a donated buffer belongs to XLA
 the moment the jitted call runs (touching it after is undefined, PL302);

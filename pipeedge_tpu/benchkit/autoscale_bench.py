@@ -123,8 +123,9 @@ def _spawn_fleet(args, mode: str):
            "--autoscale-dwell-down", "1.0",
            "--autoscale-queue-high", "2.0",
            "--autoscale-queue-low", "0.5"]
+    # the server inherits this process's platform, so the record's env
+    # stamp (taken here after teardown) names the device the work ran on
     env = dict(os.environ, PYTHONPATH=REPO)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
                             stderr=subprocess.STDOUT, text=True, env=env)
     return proc, f"http://127.0.0.1:{port}"

@@ -82,7 +82,6 @@ def run_int8_compute(args) -> dict:
     from ..models.layers import (QuantizeCompute, set_fast_numerics,
                                  set_quantize_compute)
     from ..ops import int8_matmul
-    from ..utils import require_live_backend
     from .headline import _image_inputs, top1_agreement
 
     # Pin exact numerics AND quantize-compute OFF before any trace: an
@@ -98,7 +97,6 @@ def run_int8_compute(args) -> dict:
     batch = 8
     n_ubatch = args.ubatches
     cfg, metric, xs = _image_inputs(name, parser_error, n_ubatch, batch)
-    require_live_backend(f"int8_{metric}", unit="images/sec")
 
     fn, params, _ = registry.module_shard_factory(
         name, None, 1, registry.get_model_layers(name), dtype=jnp.bfloat16)

@@ -67,19 +67,17 @@ def config_fingerprint(config: dict) -> str:
 
 
 def environment_stamp() -> dict:
-    """Which machine/backend produced this record. Imports jax lazily so
-    schema validation (tests, bench_report) never initializes a backend."""
-    stamp = {"python": sys.version.split()[0]}
-    try:
-        import jax
-        devs = jax.devices()
-        stamp.update(platform=jax.default_backend(),
-                     device_kind=devs[0].device_kind if devs else None,
-                     device_count=len(devs),
-                     jax=jax.__version__)
-    except Exception as exc:  # noqa: BLE001 — a record without a backend
-        stamp.update(platform=None, error=repr(exc))   # is still a record
-    return stamp
+    """Which machine/backend produced this record: no device, no record.
+    Imports jax lazily so schema validation (tests, bench_report) never
+    initializes a backend; a recipe that runs its work in a child calls
+    this only after the child has exited (one process per chip)."""
+    import jax
+    devs = jax.devices()
+    return {"python": sys.version.split()[0],
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
+            "jax": jax.__version__}
 
 
 def make_record(scenario: str, config: dict, blocks: dict,

@@ -140,8 +140,9 @@ def _setup(args) -> dict:
         # scales with the fleet)
         cmd += ["--role", "router", "--replicas", str(replicas),
                 "--router-poll-interval", "0.3"]
+    # the server inherits this process's platform, so the record's env
+    # stamp (taken here after teardown) names the device the work ran on
     env = dict(os.environ, PYTHONPATH=REPO)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
                             stderr=subprocess.STDOUT, text=True, env=env)
     state = {"proc": proc, "port": port,

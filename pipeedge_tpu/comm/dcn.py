@@ -258,9 +258,8 @@ def _local_handoff_enabled() -> bool:
 
 def _put_on_device(tensors: List, device) -> List:
     """Move the device arrays in a colocated hand-off onto the consumer's
-    device (`utils/jax_compat.py` has no shim to add here: `device_put`
-    between colocated devices is the ICI/DMA transfer — it never routes
-    through the host; within one mesh the SPMD pipeline's
+    device (`device_put` between colocated devices is the ICI/DMA
+    transfer — it never routes through the host; within one mesh the SPMD pipeline's
     `collective_permute` edges in parallel/spmd.py cover the same hop).
     Host ndarrays pass through untouched — the consumer's first jit
     places them. No-jax builds (socket-only users) degrade to a no-op."""

@@ -5,9 +5,9 @@ that (a) is a multiple of 8 (the TPU sublane width, guide: tiling
 constraints), (b) divides the padded extent so the grid needs no ragged
 masking, and (c) does not exceed a preferred size chosen for VMEM. The
 attention kernels (`attention.py`), the int8 decode-attention kernels
-(`decode_attention.py`), and the fused quant epilogue kernels
-(`fused_quant.py`) all use this one resolver — one definition of "legal
-block" instead of three drifting copies.
+(`decode_attention.py`) and the int8 matmul (`int8_matmul.py`) all use
+this one resolver — one definition of "legal block" instead of three
+drifting copies.
 """
 from __future__ import annotations
 

@@ -34,10 +34,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._blocks import pick_block
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 _NEG_INF = -1e30
 
 
@@ -249,7 +245,7 @@ def int8_decode_attention(q, k_q, k_scale, k_shift, v_q, v_scale, v_shift,
                 pltpu.VMEM((b, h, d), jnp.float32),       # running acc
             ],
         )
-        compiler_params = _CompilerParams(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",))
     else:
         kernel = functools.partial(_kernel, kv_block=kv_block, scale=scale)

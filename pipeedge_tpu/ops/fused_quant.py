@@ -27,9 +27,8 @@ The arranging transpose runs in XLA outside the kernel where layout changes
 are free.
 
 Mode selection (`PIPEEDGE_FUSED_QUANT`):
-- `auto` (default): fused kernels on TPU backends after a one-time
-  lowering+bit-identity probe (falls back to the XLA ops with a warning if
-  Mosaic rejects the kernel); XLA ops elsewhere.
+- `auto` (default): fused kernels on TPU backends (a lowering error is an
+  error, not a reason to run something else); XLA ops elsewhere.
 - `interpret`: fused kernels in Pallas interpret mode — the CPU CI path
   that keeps the kernels' math honest without TPU hardware.
 - `1`/`0`: force the fused path / force the XLA ops.
@@ -42,19 +41,15 @@ dispatch seam `parallel/pipeline.py` (stage epilogue), `parallel/spmd.py`
 from __future__ import annotations
 
 import functools
-import logging
 import os
-from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import quant as quant_ops
-from ._blocks import pick_block
-
-logger = logging.getLogger(__name__)
 
 ENV_FUSED_QUANT = "PIPEEDGE_FUSED_QUANT"
 
@@ -74,7 +69,8 @@ def _encode_kernel(x_ref, data_ref, scale_ref, shift_ref, *, bit: int,
     Mirrors `quant_ops._quantize_item` ('original' mode) exactly: the
     reductions run over the n_valid real elements (the tail lanes beyond
     them are padding), quantized padding packs as 0 (the reference pads
-    AFTER quantization with zero ints)."""
+    AFTER quantization with zero ints). Words are built in int32 (Mosaic
+    has no f32 -> uint32 cast); the caller reinterprets the bits."""
     per_word, words = x_ref.shape[1], x_ref.shape[2]
     x = x_ref[0]                                    # [per_word, words] f32
     j = jax.lax.broadcasted_iota(jnp.int32, (per_word, words), 0)
@@ -85,30 +81,34 @@ def _encode_kernel(x_ref, data_ref, scale_ref, shift_ref, *, bit: int,
     safe_scale = jnp.where(scale > 0, scale, jnp.float32(1))
     x01 = (x - shift) / safe_scale
     levels = float((1 << bit) - 1)
-    q = jnp.round(x01 * levels).astype(jnp.uint32)
-    q = jnp.where(valid, q, jnp.uint32(0))
+    q = jnp.round(x01 * levels).astype(jnp.int32)
+    q = jnp.where(valid, q, 0)
     # disjoint offsets: OR-accumulate the (static) sublane axis into words
     acc = q[0:1, :]
     for jj in range(1, per_word):
-        acc = acc | (q[jj:jj + 1, :] << np.uint32(jj * bit))
-    data_ref[:, :] = acc
-    scale_ref[0, 0] = scale
-    shift_ref[0, 0] = shift
+        acc = acc | (q[jj:jj + 1, :] << (jj * bit))
+    data_ref[0] = acc
+    item = pl.program_id(0)
+    scale_ref[item] = scale
+    shift_ref[item] = shift
 
 
-def _decode_kernel(data_ref, scale_ref, shift_ref, o_ref, *, bit: int):
+def _decode_kernel(scale_ref, shift_ref, data_ref, o_ref, *, bit: int):
     """One (item, lane-block) cell: packed words -> [per_word, words] f32.
 
     Mirrors `quant_ops._dequantize_item`: unpack by shift+mask, then
-    q / levels * scale + shift in the same op order."""
+    q / levels * scale + shift in the same op order. Words arrive as
+    int32 bit patterns (no uint32 -> f32 cast in Mosaic), so the shift is
+    the logical one."""
     per_word = 32 // bit
-    words = data_ref[:, :]                          # [1, w_blk] uint32
-    mask = np.uint32((1 << bit) - 1)
-    rows = [((words >> np.uint32(jj * bit)) & mask).astype(jnp.float32)
-            for jj in range(per_word)]
+    words = data_ref[0]                             # [1, w_blk] int32
+    mask = (1 << bit) - 1
+    rows = [(jax.lax.shift_right_logical(words, jnp.int32(jj * bit))
+             & mask).astype(jnp.float32) for jj in range(per_word)]
     q = jnp.concatenate(rows, axis=0)               # [per_word, w_blk]
     levels = float((1 << bit) - 1)
-    o_ref[0] = q / levels * scale_ref[0, 0] + shift_ref[0, 0]
+    item = pl.program_id(0)
+    o_ref[0] = q / levels * scale_ref[item] + shift_ref[item]
 
 
 @functools.partial(jax.jit, static_argnames=("bit", "interpret"))
@@ -129,24 +129,28 @@ def fused_encode_outerdim(x: jax.Array, bit: int,
     # value (j, w) at sublane j, lane w — word index on the wide lane axis
     arranged = flat.reshape(b, words, per_word).transpose(0, 2, 1)
     kernel = functools.partial(_encode_kernel, bit=bit, n_valid=n)
+    # per-item outputs: the words ride a [b, 1, words] array so each block's
+    # last two dimensions equal the array's (the TPU block-shape rule);
+    # scale/shift are scalars, so they live in SMEM, whole, indexed by item
     data, scale, shift = pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((b, words), jnp.uint32),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, words), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.float32),
+            jax.ShapeDtypeStruct((b,), jnp.float32),
         ],
         grid=(b,),
         in_specs=[pl.BlockSpec((1, per_word, words), lambda i: (i, 0, 0))],
         out_specs=[
-            pl.BlockSpec((1, words), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, words), lambda i: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         interpret=interpret,
     )(arranged)
-    return quant_ops.QuantizedTensor(data=data, scale=scale[:, 0],
-                                     shift=shift[:, 0], shape=shape, bit=bit)
+    data = jax.lax.bitcast_convert_type(data.reshape(b, words), jnp.uint32)
+    return quant_ops.QuantizedTensor(data=data, scale=scale, shift=shift,
+                                     shape=shape, bit=bit)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -161,20 +165,23 @@ def fused_decode_outerdim(enc: quant_ops.QuantizedTensor,
     n = int(np.prod(shape[1:]))
     per_word = 32 // bit
     words = enc.data.shape[1]
-    w_blk = pick_block(words, DECODE_LANE_BLOCK)
+    # elementwise per lane, so a ragged last block is harmless: its
+    # out-of-range lanes are never written back
+    w_blk = min(DECODE_LANE_BLOCK, words)
     kernel = functools.partial(_decode_kernel, bit=bit)
+    data = jax.lax.bitcast_convert_type(enc.data, jnp.int32)
     full = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, per_word, words), jnp.float32),
-        grid=(b, words // w_blk),
+        grid=(b, pl.cdiv(words, w_blk)),
         in_specs=[
-            pl.BlockSpec((1, w_blk), lambda i, k: (i, k)),
-            pl.BlockSpec((1, 1), lambda i, k: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, k: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, w_blk), lambda i, k: (i, 0, k)),
         ],
         out_specs=pl.BlockSpec((1, per_word, w_blk), lambda i, k: (i, 0, k)),
         interpret=interpret,
-    )(enc.data, enc.scale.reshape(b, 1), enc.shift.reshape(b, 1))
+    )(enc.scale, enc.shift, data.reshape(b, 1, words))
     flat = full.transpose(0, 2, 1).reshape(b, words * per_word)
     return flat[:, :n].reshape(shape)
 
@@ -183,37 +190,6 @@ def fused_decode_outerdim(enc: quant_ops.QuantizedTensor,
 
 def _mode() -> str:
     return os.getenv(ENV_FUSED_QUANT, "auto").strip().lower()
-
-
-# one-time native-lowering probe result per bitwidth (auto mode on TPU):
-# Mosaic rejecting the kernel must degrade to the XLA ops, not kill the run
-_PROBE_OK: Dict[int, bool] = {}
-
-
-def _probe_native(bit: int) -> bool:
-    ok = _PROBE_OK.get(bit)
-    if ok is None:
-        try:
-            x = (jnp.arange(2 * 37, dtype=jnp.float32).reshape(2, 37)
-                 * 0.731 - 11.0)
-            enc = fused_encode_outerdim(x, bit, interpret=False)
-            ref = quant_ops.tensor_encode_outerdim(x, bit)
-            dec = fused_decode_outerdim(enc, interpret=False)
-            ok = (bool(jnp.all(enc.data == ref.data))
-                  and bool(jnp.all(enc.scale == ref.scale))
-                  and bool(jnp.all(
-                      dec == quant_ops.tensor_decode_outerdim(ref))))
-            if not ok:
-                logger.warning("fused quant probe (bit=%d): native kernel "
-                               "output differs from the XLA ops; falling "
-                               "back to the XLA encode/decode", bit)
-        except Exception as exc:  # noqa: BLE001 - Mosaic lowering errors
-            logger.warning("fused quant probe (bit=%d) failed to lower "
-                           "natively (%s); falling back to the XLA "
-                           "encode/decode", bit, exc)
-            ok = False
-        _PROBE_OK[bit] = ok
-    return ok
 
 
 def fused_available(bit: int) -> bool:
@@ -226,8 +202,8 @@ def fused_available(bit: int) -> bool:
         return False
     if mode in ("1", "on", "interpret"):
         return True
-    # auto: native kernels on TPU only, behind the one-time probe
-    return jax.default_backend() == "tpu" and _probe_native(bit)
+    # auto: the native kernels on a TPU backend, the XLA ops elsewhere
+    return jax.default_backend() == "tpu"
 
 
 def _interpret() -> bool:

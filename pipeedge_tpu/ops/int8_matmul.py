@@ -17,9 +17,9 @@ lands in the accumulator, which is what makes the scales per-BLOCK rather
 than per-row: s_x[m, kb] * s_w[n] * (x_q[m, kb*bk:...] @ w_q[...]).
 
 Mode selection (`PIPEEDGE_INT8_MATMUL`, mirroring ops/fused_quant.py):
-- `auto` (default): native Pallas kernel on TPU behind a one-time
-  lowering+parity probe; the block-scaled XLA reference path elsewhere
-  (same math, so CPU CI and the recipe run the identical quantization).
+- `auto` (default): native Pallas kernel on TPU (a lowering error is an
+  error); the block-scaled XLA reference path elsewhere (same math, so
+  CPU CI and the recipe run the identical quantization).
 - `interpret`: Pallas kernel in interpret mode — the CPU CI path that
   keeps the kernel's math honest without TPU hardware.
 - `1`/`0`: force the kernel / force the XLA reference.
@@ -42,7 +42,6 @@ the same jit.
 from __future__ import annotations
 
 import functools
-import logging
 import os
 
 import jax
@@ -53,8 +52,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import quant as quant_ops
 from ._blocks import pick_block
-
-logger = logging.getLogger(__name__)
 
 ENV_INT8_MATMUL = "PIPEEDGE_INT8_MATMUL"
 
@@ -106,21 +103,32 @@ def quantize_act_blocks(x: jax.Array, block_k: int):
 def _matmul_kernel(x_ref, xs_ref, w_ref, ws_ref, o_ref, acc_ref):
     """One (m, n) tile, accumulated over the innermost k grid dimension.
 
-    x_ref  [bm, bk] int8      xs_ref [bm, 1]  f32 (this k-block's scales)
+    x_ref  [bm, bk] int8      xs_ref [bm, K/bk] f32 (the rows' scales)
     w_ref  [bk, bn] int8      ws_ref [1, bn]  f32 (per-channel scales)
     o_ref  [bm, bn] f32       acc_ref [bm, bn] f32 VMEM scratch
     """
-    @pl.when(pl.program_id(2) == 0)
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # integer operands have one precision; naming it keeps a process-wide
+    # `jax_default_matmul_precision` (Mosaic refuses fp32 passes over int8)
+    # out of the kernel
     prod = jax.lax.dot_general(
         x_ref[...], w_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.int32)
-    acc_ref[...] += prod.astype(jnp.float32) * xs_ref[...]
+    # the scale block spans every k-block (a [bm, 1] block breaks the TPU
+    # block-shape rule), so this step's column is picked by a lane mask
+    xs = xs_ref[...]
+    col = jax.lax.broadcasted_iota(jnp.int32, xs.shape, 1) == kk
+    xs_k = jnp.sum(jnp.where(col, xs, 0.0), axis=1, keepdims=True)
+    acc_ref[...] += prod.astype(jnp.float32) * xs_k
 
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    @pl.when(kk == pl.num_programs(2) - 1)
     def _epilogue():
         o_ref[...] = acc_ref[...] * ws_ref[...]
 
@@ -143,7 +151,7 @@ def matmul_pallas(x_q: jax.Array, x_scale: jax.Array, w_q: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, block_k), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bm, 1), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bm, k // block_k), lambda i, j, kk: (i, 0)),
             pl.BlockSpec((block_k, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
@@ -174,39 +182,11 @@ def matmul_xla(x_q: jax.Array, x_scale: jax.Array, w_q: jax.Array,
 
 
 # --------------------------------------------------------------------------
-# dispatch (the fused_quant mode/probe idiom)
+# dispatch (the fused_quant mode idiom)
 # --------------------------------------------------------------------------
 
 def _mode() -> str:
     return os.getenv(ENV_INT8_MATMUL, "auto").strip().lower()
-
-
-_PROBE_OK = None
-
-
-def _probe_native() -> bool:
-    """One-time native lowering + parity probe: Mosaic rejecting the kernel
-    (or producing different math) degrades to the XLA reference."""
-    global _PROBE_OK
-    if _PROBE_OK is None:
-        try:
-            rng = np.random.default_rng(0)
-            x = jnp.asarray(rng.normal(size=(8, 256)), jnp.float32)
-            w = jnp.asarray(rng.normal(size=(256, 128)), jnp.float32)
-            x_q, x_s = quantize_act_blocks(x, 128)
-            w_q, w_s = quantize_weight(w)
-            got = matmul_pallas(x_q, x_s, w_q, w_s, 128, interpret=False)
-            ref = matmul_xla(x_q, x_s, w_q, w_s, 128)
-            ok = bool(jnp.allclose(got, ref, rtol=1e-5, atol=1e-4))
-            if not ok:
-                logger.warning("int8 matmul probe: native kernel differs "
-                               "from the XLA reference; falling back")
-            _PROBE_OK = ok
-        except Exception as exc:  # noqa: BLE001 - Mosaic lowering errors
-            logger.warning("int8 matmul probe failed to lower natively "
-                           "(%s); falling back to the XLA reference", exc)
-            _PROBE_OK = False
-    return _PROBE_OK
 
 
 def kernel_available() -> bool:
@@ -217,7 +197,7 @@ def kernel_available() -> bool:
         return False
     if mode in ("1", "on", "interpret"):
         return True
-    return jax.default_backend() == "tpu" and _probe_native()
+    return jax.default_backend() == "tpu"
 
 
 def matmul_q(x_q: jax.Array, x_scale: jax.Array, w_q: jax.Array,

@@ -38,12 +38,13 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            # the scheduler's on-demand cmake build also produces the codec;
-            # key the staleness check on OUR artifact, not the sched binary
-            from ..sched.scheduler import build_native
-            build_native(artifact=_LIB_PATH)
+        # the scheduler's on-demand cmake build also produces the codec;
+        # key the freshness check on OUR artifact, not the sched binary
+        from ..sched.scheduler import build_native
         try:
+            if build_native(artifact=_LIB_PATH) is None:
+                # never a library left over from other sources
+                raise OSError("no fresh build of native/quantpack.cpp")
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError as exc:
             logger.warning("native quant codec unavailable: %s", exc)

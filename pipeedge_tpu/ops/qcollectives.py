@@ -18,11 +18,9 @@ AllReduce in XLA" (arxiv 2506.17615, PAPERS.md):
   local shard stays exact.
 
 Both are shard_map-body functions over a named mesh axis, built purely on
-`jax.lax.ppermute` — the one collective primitive available across every
-jax this tree supports (utils/jax_compat.py bridges the shard_map entry
-point itself; no psum_scatter/all_gather-with-custom-reduction exists on
-0.4.x shard_map, so the ring IS the portable implementation, exactly the
-fallback EQuARX describes for pre-collective-quantization XLA).
+`jax.lax.ppermute`: no psum_scatter/all_gather takes a custom reduction,
+so the ring IS the implementation, exactly the fallback EQuARX describes
+for pre-collective-quantization XLA.
 
 Block scaling reuses the repo's own codec: a chunk reshaped to
 [n_blocks, block] IS an outer-dim batch, so the block-scaled encode is
@@ -51,7 +49,6 @@ import jax.numpy as jnp
 
 from .. import telemetry
 from ..telemetry.metrics import REGISTRY
-from ..utils import jax_compat
 from . import clamp as clamp_ops
 from . import fused_quant
 from . import quant as quant_ops
@@ -189,7 +186,7 @@ def qpsum(x: jax.Array, axis_name: str, bit: int, *,
     _check_bit(bit)
     if bit == 0:
         return jax.lax.psum(x, axis_name)
-    n = jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     orig_shape, orig_dtype = x.shape, x.dtype
@@ -252,7 +249,7 @@ def qall_gather(x: jax.Array, axis_name: str, bit: int, *, axis: int = 0,
     _check_bit(bit)
     if bit == 0:
         return jax.lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
-    n = jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x if tiled else jnp.expand_dims(x, axis)
     orig_dtype = x.dtype

@@ -171,7 +171,7 @@ def _ep_delta_from_routing(params: Dict, tokens: jax.Array, gate, keep,
     """This device's expert rows of the global routing tables -> local
     deltas (shared core) -> psum combine across `axis`. Used by the
     standalone ep FFN and the expert-parallel decode step."""
-    n = jax_compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     e_local = n_experts // n
     first = idx * e_local

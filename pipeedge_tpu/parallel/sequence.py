@@ -127,7 +127,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, axis_name: str,
     every window are skipped — never computed, never rotated in.
     """
     _check_window(causal, window)
-    n = jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, sq, h, d = q.shape
     scale = 1.0 / (d ** 0.5)
@@ -183,7 +183,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     key block intersects someone's window — the window is mask-only.
     """
     _check_window(causal, window)
-    n = jax_compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     b, s_local, h, d = q.shape
     assert h % n == 0, "ulysses requires head count divisible by axis size"
     scale = 1.0 / (d ** 0.5)

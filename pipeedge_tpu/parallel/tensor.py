@@ -91,7 +91,7 @@ def _tp_block_local(p: Dict, x: jax.Array, cfg: TransformerConfig,
     dense Megatron MLP entirely — how the tp x ep MoE decode plugs the
     ep-sharded routed FFN under the tp-sharded attention
     (decode.make_tp_ep_stage_fns)."""
-    n = jax_compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     heads_local = cfg.num_attention_heads // n
     b, s, d = x.shape
     hd = cfg.head_dim
@@ -195,7 +195,7 @@ def _tp_bert_block_local(p: Dict, x: jax.Array, cfg: TransformerConfig,
                          axis: str) -> jax.Array:
     """Per-device BERT block body (post-LN residuals, bert.py sublayer
     semantics 0-3): attention on raw x, LayerNorm AFTER each residual."""
-    n = jax_compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     heads_local = cfg.num_attention_heads // n
     b, s, _ = x.shape
     hd = cfg.head_dim
@@ -250,7 +250,7 @@ def _tp_llama_block_local(p: Dict, x: jax.Array, cfg: TransformerConfig,
     from ..models.layers import rms_norm, rope_rotate
     from ..models.llama import _gqa_attend
 
-    n = jax_compat.axis_size(axis)
+    n = jax.lax.axis_size(axis)
     heads_local = cfg.num_attention_heads // n
     kv_local = cfg.kv_heads // n
     b, s, _ = x.shape

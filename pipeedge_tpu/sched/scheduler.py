@@ -4,6 +4,7 @@ Parity with /root/reference/src/pipeedge/sched/scheduler.py:24-73: builds the
 CLI arguments, searches `app_paths` then the in-repo build dir then PATH, and
 parses the YAML schedule from stdout into [{host: [layer_l, layer_r]}, ...].
 """
+import hashlib
 import logging
 import os
 import subprocess
@@ -23,13 +24,39 @@ _REPO_BUILD_PATHS = [
 
 _BUILD_FAILED = False
 
+# digest of the sources the build tree was last built from: a build tree
+# is git-ignored and outlives checkouts and copies, so its artifacts are
+# trusted only while this stamp matches the sources beside it
+_SOURCES_STAMP = os.path.join(_NATIVE_DIR, 'build', '.sources.sha256')
+
+
+def _sources_digest() -> str:
+    digest = hashlib.sha256()
+    for name in sorted(os.listdir(_NATIVE_DIR)):
+        path = os.path.join(_NATIVE_DIR, name)
+        if os.path.isfile(path):
+            digest.update(name.encode())
+            with open(path, 'rb') as src:
+                digest.update(src.read())
+    return digest.hexdigest()
+
+
+def _is_fresh(artifact: str) -> bool:
+    """Whether `artifact` exists and was built from the current sources."""
+    try:
+        with open(_SOURCES_STAMP, encoding='utf8') as stamp:
+            built_from = stamp.read().strip()
+    except FileNotFoundError:
+        return False
+    return os.path.exists(artifact) and built_from == _sources_digest()
+
 
 def build_native(force: bool = False,
                  artifact: Optional[str] = None) -> Optional[str]:
-    """Build the in-repo native tree if `artifact` is absent; returns its
-    path. `artifact` defaults to the `sched-pipeline` binary; other targets
-    (e.g. libquantpack.so) pass their own path so a build tree that predates
-    them still gets rebuilt.
+    """Build the in-repo native tree unless `artifact` is there and fresh;
+    returns its path. `artifact` defaults to the `sched-pipeline` binary;
+    other targets (e.g. libquantpack.so) pass their own path so a build
+    tree that predates them still gets rebuilt.
 
     The reference ships its binary inside the wheel via py-build-cmake
     (pyproject.toml:36-52); for a source checkout we compile on first use so
@@ -39,7 +66,7 @@ def build_native(force: bool = False,
     """
     global _BUILD_FAILED
     binary = artifact or _REPO_BUILD_PATHS[0]
-    if os.path.exists(binary) and not force:
+    if _is_fresh(binary) and not force:
         return binary
     if _BUILD_FAILED and not force:
         return None
@@ -55,12 +82,15 @@ def build_native(force: bool = False,
     try:
         if lock_f is not None:
             fcntl.flock(lock_f, fcntl.LOCK_EX)
-            if os.path.exists(binary) and not force:
+            if _is_fresh(binary) and not force:
                 return binary
+        digest = _sources_digest()
         subprocess.run(['cmake', '-B', build_dir, '-G', 'Ninja', _NATIVE_DIR],
                        capture_output=True, check=True)
         subprocess.run(['ninja', '-C', build_dir], capture_output=True,
                        check=True)
+        with open(_SOURCES_STAMP, 'w', encoding='utf8') as stamp:
+            stamp.write(digest)
     except FileNotFoundError as exc:
         logger.warning("native toolchain unavailable (%s); cannot build "
                        "sched-pipeline", exc)
@@ -109,7 +139,9 @@ def sched_pipeline(model_name: str, buffers_in: int, buffers_out: int,
     if dev_file:
         args += ['-D', dev_file]
 
-    candidates = list(app_paths) + _REPO_BUILD_PATHS + ['sched-pipeline']
+    candidates = (list(app_paths)
+                  + [p for p in _REPO_BUILD_PATHS if _is_fresh(p)]
+                  + ['sched-pipeline'])
     proc = None
     last_missing = None
 

@@ -1,36 +1,15 @@
-"""Version-bridging shims for the jax APIs this tree uses.
+"""The one `shard_map` entry point of this tree.
 
-The parallel modules are written against the promoted `jax.shard_map` /
-`jax.lax.axis_size` API (jax >= 0.6); older jaxlibs (0.4.x) ship the same
-machinery as `jax.experimental.shard_map.shard_map` with the replication
-check under its old `check_rep` name and the static in-body axis size
-behind `jax.core.axis_frame`. One import site per concept keeps every
-caller version-agnostic — kernels and meshes are identical either way.
+Every shard_map body here manages its own collectives, so the
+replication/VMA check is off everywhere; calling through this wrapper
+keeps that decision in one place.
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        """`jax.shard_map` with the replication/VMA check disabled (every
-        body in this tree manages its own collectives)."""
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        """experimental shard_map; check_rep is check_vma's old name."""
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
-
-if hasattr(jax.lax, "axis_size"):
-    def axis_size(name) -> int:
-        """Static size of a shard_map/pmap mesh axis, from inside the body."""
-        return jax.lax.axis_size(name)
-else:  # jax 0.4.x: axis_frame resolves the name to its static size
-    def axis_size(name) -> int:
-        """Static size of a shard_map/pmap mesh axis, from inside the body."""
-        return jax.core.axis_frame(name)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """`jax.shard_map` with the replication/VMA check disabled."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -104,7 +104,7 @@ def main():
 
 
 if __name__ == "__main__":
-    from pipeedge_tpu.utils import apply_env_platform
-    apply_env_platform()  # honor an explicit JAX_PLATFORMS (e.g. cpu in CI)
+    from pipeedge_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     logging.basicConfig(level=logging.INFO)
     main()

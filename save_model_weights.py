@@ -105,8 +105,8 @@ def save_weights(model_name: str, model_file: str, random_init: bool = False) ->
 
 
 if __name__ == "__main__":
-    from pipeedge_tpu.utils import apply_env_platform
-    apply_env_platform()  # tests run this CLI with JAX_PLATFORMS=cpu
+    from pipeedge_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description="Save model weights files")
     parser.add_argument("-m", "--model-name", action='append',
                         choices=registry.get_model_names(),
@@ -120,6 +120,7 @@ if __name__ == "__main__":
     os.makedirs(args.output_dir, exist_ok=True)
     model_names = registry.get_model_names() if args.model_name is None \
         else args.model_name
+    failed = []
     for name in model_names:
         model_file = os.path.join(
             args.output_dir, registry.get_model_default_weights_file(name))
@@ -129,6 +130,9 @@ if __name__ == "__main__":
         logger.info('%s: saving weights file: %s', name, model_file)
         try:
             save_weights(name, model_file, random_init=args.random)
-        except Exception as exc:
+        except Exception as exc:   # go on to the other models, then fail
             logger.error('%s: failed (%s); pass --random for offline weights',
                          name, exc)
+            failed.append(name)
+    if failed:
+        sys.exit(f"no weights file for: {', '.join(failed)}")

@@ -1,0 +1,160 @@
+"""Ask the chip's compiler, without the chip: the Pallas kernels at real
+widths, and the whole ViT-Large forward.
+
+The TPU compiler is installed beside the CPU backend and compiles for a chip
+that is described, not attached. Interpret mode (the other kernel tests)
+checks a kernel's arithmetic; only this checks that Mosaic accepts its block
+shapes, casts and memory at the widths the main path runs. A compile that
+passes here is not a chip run and says nothing about results or times.
+
+The chip is described inside a module-scoped fixture and nowhere else, so
+that every xdist worker collects the same tests and only the worker that
+runs this file loads the TPU's library. Keep every such test in this file.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from pipeedge_tpu.models import ShardConfig, registry
+from pipeedge_tpu.ops import (attention, decode_attention, fused_quant,
+                              int8_matmul, quant)
+
+EDGE = (8, 197, 1024)       # the ViT-L stage edge at microbatch 8
+GPT2_HEADS, GPT2_HEAD_DIM = 12, 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip can be written to the persistent cache
+    # but never read back; keep it off around these tests. And compile what
+    # the chip runs: conftest's "highest" matmul precision is for CPU parity
+    was_enabled = jax.config.jax_enable_compilation_cache
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            described = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2")
+        except Exception as exc:   # noqa: BLE001 — whatever says "no compiler"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+        yield described
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        jax.config.update("jax_default_matmul_precision", precision)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def on_chip(topo):
+    """Shape -> the same shape placed on the first described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def place(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return place
+
+
+def _encode(bit):
+    def build(on_chip):
+        return (lambda x: fused_quant.fused_encode_outerdim(x, bit),
+                [on_chip(EDGE, jnp.float32)])
+    return build
+
+
+def _decode(bit):
+    def build(on_chip):
+        words = quant.packed_words(int(np.prod(EDGE[1:])), bit)
+
+        def fn(data, scale, shift):
+            return fused_quant.fused_decode_outerdim(quant.QuantizedTensor(
+                data=data, scale=scale, shift=shift, shape=EDGE, bit=bit))
+        return fn, [on_chip((EDGE[0], words), jnp.uint32),
+                    on_chip(EDGE[:1], jnp.float32),
+                    on_chip(EDGE[:1], jnp.float32)]
+    return build
+
+
+def _matmul(m, k, n):
+    def build(on_chip):
+        return (lambda *ops: int8_matmul.matmul_pallas(*ops, 128),
+                [on_chip((m, k), jnp.int8), on_chip((m, k // 128),
+                                                    jnp.float32),
+                 on_chip((k, n), jnp.int8), on_chip((n,), jnp.float32)])
+    return build
+
+
+def _attention(bh, seq, dim, causal):
+    def build(on_chip):
+        qkv = on_chip((bh, seq, dim), jnp.bfloat16)
+        return (lambda q, k, v: attention.fused_attention_bhsd(
+            q, k, v, causal=causal), [qkv, qkv, qkv])
+    return build
+
+
+def _decode_attention(variant, batch, width):
+    def build(on_chip):
+        h, d = GPT2_HEADS, GPT2_HEAD_DIM
+        row = on_chip((batch, 1, h, d), jnp.bfloat16)
+        cache = on_chip((batch, width, h, d), jnp.int8)
+        meta = on_chip((batch, width, h), jnp.float32)
+
+        def fn(q, kq, ks, kz, vq, vs, vz, kn, vn, pos):
+            return decode_attention.int8_decode_attention(
+                q, kq, ks, kz, vq, vs, vz, kn, vn, pos, variant=variant)
+        return fn, [row, cache, meta, meta, cache, meta, meta, row, row,
+                    on_chip((), jnp.int32)]
+    return build
+
+
+KERNELS = {
+    "fused_encode_8": _encode(8),
+    "fused_encode_4": _encode(4),
+    "fused_decode_8": _decode(8),
+    "fused_decode_4": _decode(4),
+    "int8_matmul_1576x1024x4096": _matmul(1576, 1024, 4096),
+    "int8_matmul_1576x4096x1024": _matmul(1576, 4096, 1024),
+    "attention_16x1024x64": _attention(16, 1024, 64, causal=False),
+    "attention_causal_32x4096x128": _attention(32, 4096, 128, causal=True),
+    "int8_decode_attention_v1": _decode_attention(1, batch=16, width=1024),
+    "int8_decode_attention_v2": _decode_attention(2, batch=16, width=256),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(name, on_chip):
+    fn, shapes = KERNELS[name](on_chip)
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_vit_large_forward_compiles_for_v5e(on_chip):
+    name = "google/vit-large-patch16-224"
+    entry = registry.get_model_entry(name)
+    cfg = entry.config
+    total = registry.get_model_layers(name)
+    # parameter shapes without drawing 300M random numbers: a one-block
+    # model's, with the stacked blocks' leading axis widened to the depth
+    one_block = jax.eval_shape(lambda: entry.family.init_params(
+        dataclasses.replace(cfg, num_hidden_layers=1),
+        ShardConfig(1, 4, is_first=True, is_last=True), dtype=jnp.bfloat16))
+    params = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), one_block)
+    params["blocks"] = jax.tree_util.tree_map(
+        lambda leaf: on_chip((cfg.num_hidden_layers,) + leaf.shape[1:],
+                             leaf.dtype), one_block["blocks"])
+    forward = registry.make_shard_fn(
+        entry.family.FAMILY, cfg,
+        ShardConfig(1, total, is_first=True, is_last=True))
+    images = on_chip((8, cfg.num_channels, cfg.image_size, cfg.image_size),
+                     jnp.bfloat16)
+    compiled = jax.jit(forward).lower(params, images).compile()
+    logits, = jax.tree_util.tree_leaves(compiled.out_info)
+    assert logits.shape == (8, cfg.num_labels)

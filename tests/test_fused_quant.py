@@ -20,6 +20,7 @@ from pipeedge_tpu.ops._blocks import pick_block
 # for both the int8 (4/word) and int4 (8/word) packings
 SHAPES = [
     (2, 37),        # int8: 1-value tail; int4: 5-value tail
+    (2, 197, 128),  # int8: 6304 words, a ragged second decode lane block
     (3, 128),       # exact words both widths
     (1, 5),         # sub-word single item
     (4, 7, 9),      # multi-dim inner shape, 63 values: 3-tail / 7-tail

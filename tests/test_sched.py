@@ -30,11 +30,12 @@ def _ensure_binary():
     """Build on demand at fixture time (not import time, so unrelated pytest
     collection never triggers a native compile)."""
     global BIN
-    if not os.path.exists(BIN):
-        built = shutil.which('sched-pipeline') or build_native()
-        if built is None:
-            pytest.skip("sched-pipeline auto-build failed")
-        BIN = built
+    # build_native returns at once when the tree is fresh, and rebuilds a
+    # tree left over from other sources
+    built = build_native() or shutil.which('sched-pipeline')
+    if built is None:
+        pytest.skip("sched-pipeline auto-build failed")
+    BIN = built
 
 BATCH = 8
 DTYPE = 'torch.float32'
@@ -362,3 +363,19 @@ def test_scaling_projection_from_committed_profiles():
     for k in ("overlapped_comm", "serialized_ici_1600gbps",
               "serialized_dcn_100gbps"):
         assert fa[k]["speedup_vs_single"] >= 4.0, fa
+
+
+def test_build_tree_is_trusted_only_for_its_sources(monkeypatch, tmp_path):
+    """native/build is git-ignored and outlives checkouts and copies: its
+    binaries count as built only while the digest stamped beside them is
+    that of the sources (a stale or unstamped tree is rebuilt, not run)."""
+    from pipeedge_tpu.sched import scheduler
+    assert scheduler._is_fresh(BIN)
+    stamp = tmp_path / ".sources.sha256"
+    monkeypatch.setattr(scheduler, "_SOURCES_STAMP", str(stamp))
+    assert not scheduler._is_fresh(BIN)             # no stamp
+    stamp.write_text("0" * 64)
+    assert not scheduler._is_fresh(BIN)             # other sources
+    stamp.write_text(scheduler._sources_digest())
+    assert scheduler._is_fresh(BIN)
+    assert not scheduler._is_fresh(str(tmp_path / "no-such-binary"))

@@ -30,7 +30,7 @@ rebuild) so they are recorded, not asserted:
    v2 socket, zero-copy socket (pooled recv), colocated hand-off — and
    reports, ONE JSON line per tier: individually-dispatched p50/p99
    end-to-end microbatch latency, the streamed steady-state ubatch time,
-   and their ratio (the BENCH_r05 "10× gap" number; ROADMAP item 5's
+   and their ratio (the "10× gap" number of the former chip's record; the
    target is ratio ≤ 2 on the colocated path).
 
 CPU-safe (JAX_PLATFORMS=cpu) — nothing here needs a TPU. Prints ONE JSON
@@ -191,7 +191,7 @@ def bench_overlap():
 LAT_WORLD = 4                   # data rank + 1 relay stage + 2 idle spares
 LAT_N_UBATCH = 24               # per-tier stream length
 LAT_WORK_MS = 8.0               # modeled stage compute (ViT-L ubatch-ish,
-#                                 BENCH_r05 steady ubatch = 8.15 ms)
+#                                 8.15 ms on the former chip)
 
 # tier name -> env staging applied BEFORE the fleet's contexts exist
 # (both knobs are read at context construction)
@@ -292,7 +292,7 @@ def bench_latency_tier(tier: str, env: dict) -> dict:
             _percentile(lats_sorted, 99) * 1e3, 2),
         "steady_state_ubatch_ms": round(steady_s * 1e3, 2),
         # the ROADMAP item 5 headline: end-to-end p50 over steady cadence
-        # (1.0 = transport adds nothing; BENCH_r05 measured ~10)
+        # (1.0 = transport adds nothing; the former chip measured ~10)
         "p50_over_steady": round(p50 / steady_s, 3) if steady_s else None,
         "throughput_frames_sec": round(1.0 / steady_s, 1) if steady_s
         else None,

@@ -50,16 +50,14 @@ def main():
                         "the small-pad end of the S=197 padding bucket)")
     args = p.parse_args()
     try:
-        # fail BEFORE any chip compile: a malformed list after five warm
-        # builds would waste the whole leased session
+        # fail BEFORE any chip compile, not after five warm builds
         extra_seqs = [int(s) for s in args.extra_seqs.split(",") if s]
     except ValueError:
         p.error(f"--extra-seqs must be comma-separated integers, got "
                 f"{args.extra_seqs!r}")
 
-    from pipeedge_tpu.utils import apply_env_platform, require_live_backend
-    apply_env_platform()
-    require_live_backend("mfu_bucket_base_tflops", unit="TFLOP/s")
+    from pipeedge_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     import numpy as np

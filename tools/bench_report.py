@@ -47,7 +47,7 @@ mismatch under --strict-config).
 
 Examples:
     # two rounds of the multi-scenario artifact
-    python tools/bench_report.py BENCH_r06.json --baseline BENCH_r05.json
+    python tools/bench_report.py BENCH_r07.json --baseline BENCH_r06.json
 
     # CI bench-smoke gate against the committed baseline, generous
     # throughput band (shared runners), tight attainment band

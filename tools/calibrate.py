@@ -17,9 +17,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from pipeedge_tpu.utils import apply_env_platform  # noqa: E402
-
-apply_env_platform()
 
 
 def main() -> int:
@@ -45,7 +42,9 @@ def main() -> int:
     import numpy as np
 
     from pipeedge_tpu.models import registry
-    from pipeedge_tpu.utils import calibrate
+    from pipeedge_tpu.utils import calibrate, enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = registry.get_model_config(args.model)
     layer_end = args.layer_end or registry.get_model_layers(args.model)

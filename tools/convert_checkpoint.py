@@ -46,6 +46,6 @@ def main():
 
 
 if __name__ == "__main__":
-    from pipeedge_tpu.utils import apply_env_platform
-    apply_env_platform()
+    from pipeedge_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     main()

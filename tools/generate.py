@@ -210,8 +210,8 @@ def run_spmd_wave(args, cfg, partition, stage_params, max_len, dtype):
 
 
 def main():
-    from pipeedge_tpu.utils import apply_env_platform
-    apply_env_platform()
+    from pipeedge_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     import jax.numpy as jnp
     from pipeedge_tpu.models import registry
     from pipeedge_tpu.parallel import decode

@@ -2,8 +2,8 @@
 # Generate the committed TPU chip profiles (profiles/README.md recipe) in one
 # serialized chip session: per-layer profiles for ViT-B and ViT-L, the
 # scheduler YAML conversions, and a bench.py run. Run from the repo root on a
-# machine with the real chip. The chip is single-tenant — never run two chip
-# processes at once, and never SIGKILL a running one (stale-lease wedge).
+# machine with the real chip. A chip belongs to one process at a time, so the
+# steps run one after another.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 mkdir -p profiles/tpu

@@ -58,8 +58,8 @@ def main():
         p.error(f"rank must be in [1, {args.world - 1}] (rank 0 is the "
                 "decode side)")
 
-    from pipeedge_tpu.utils import apply_env_platform
-    apply_env_platform()
+    from pipeedge_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     import jax.numpy as jnp
 
     from pipeedge_tpu.comm import chaos, dcn

@@ -36,6 +36,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 PROFILE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "profiles", "tpu")
 ICI_MBPS = 1_600_000          # v5e ICI class (public spec sheet, per chip)
+# img/s of the fused one-chip program, by (model, batch): deleted record of
+# the former chip. Goes with this tool once a four-chip cell is measured.
+FUSED_ANCHOR_IMG_PER_SEC = {("google/vit-large-patch16-224", 8): 946.845}
 
 
 def project(model_name: str, n_devices: int, batch: int,
@@ -102,57 +105,47 @@ def project(model_name: str, n_devices: int, batch: int,
     # ABSOLUTE projection, anchored to the fused-program measurement:
     # the per-sublayer profile times carry per-call dispatch granularity
     # (each sublayer its own program), so their sum (-> `single` above)
-    # is far below the fused single-chip bench (BENCH_r04: one scanned
-    # program). A stage executes ITS sublayers as one fused program too,
-    # so the absolute stage time is better estimated as the measured
+    # is far below the fused single-chip bench (one scanned program). A
+    # stage executes ITS sublayers as one fused program too, so the
+    # absolute stage time is better estimated as the measured
     # fused microbatch time x the stage's PROFILE-TIME SHARE (the
     # profiles' relative balance is the measured quantity the scheduler
     # optimizes), plus the explicit edge cost.
     fused = None
-    bench_path = os.path.join(os.path.dirname(PROFILE_DIR), "..",
-                              "BENCH_r04.json")
-    if os.path.exists(bench_path):
-        with open(bench_path) as f:
-            rec = json.load(f)
-        if "tail" in rec:       # driver record: the bench line is the
-            for line in rec["tail"].splitlines():   # JSON in its tail
-                if line.startswith("{\"metric\""):
-                    rec = json.loads(line)
-                    break
-        if rec.get("metric") == "vit_large_images_per_sec_b8":
-            fused_img = rec["value"]
-            ubatch_ms = batch / fused_img * 1e3
-            shares = [s["compute_ms"] / total_ms for s in stages]
-            worst_share = max(shares)
+    fused_img = FUSED_ANCHOR_IMG_PER_SEC.get((model_name, batch))
+    if fused_img is not None:
+        ubatch_ms = batch / fused_img * 1e3
+        shares = [s["compute_ms"] / total_ms for s in stages]
+        worst_share = max(shares)
 
-            def fused_tp(comm_mbps):
-                worst = 0.0
-                for i, s in enumerate(stages):
-                    t = ubatch_ms * (s["compute_ms"] / total_ms)
-                    if comm_mbps is not None and i > 0:
-                        t += stages[i - 1]["edge_out_mb"] * 8 \
-                            / comm_mbps * 1e3
-                    worst = max(worst, t)
-                return round(batch / (worst / 1e3), 1), round(worst, 3)
+        def fused_tp(comm_mbps):
+            worst = 0.0
+            for i, s in enumerate(stages):
+                t = ubatch_ms * (s["compute_ms"] / total_ms)
+                if comm_mbps is not None and i > 0:
+                    t += stages[i - 1]["edge_out_mb"] * 8 \
+                        / comm_mbps * 1e3
+                worst = max(worst, t)
+            return round(batch / (worst / 1e3), 1), round(worst, 3)
 
-            fused = {
-                "anchor_img_per_sec": fused_img,
-                "anchor_ubatch_ms": round(ubatch_ms, 3),
-                "worst_stage_share": round(worst_share, 4),
-                "overlapped_comm": dict(zip(
-                    ("img_per_sec", "bottleneck_stage_ms"),
-                    fused_tp(None))),
-                "serialized_ici_1600gbps": dict(zip(
-                    ("img_per_sec", "bottleneck_stage_ms"),
-                    fused_tp(ICI_MBPS))),
-                "serialized_dcn_100gbps": dict(zip(
-                    ("img_per_sec", "bottleneck_stage_ms"),
-                    fused_tp(dcn_mbps))),
-            }
-            for k in ("overlapped_comm", "serialized_ici_1600gbps",
-                      "serialized_dcn_100gbps"):
-                fused[k]["speedup_vs_single"] = round(
-                    fused[k]["img_per_sec"] / fused_img, 2)
+        fused = {
+            "anchor_img_per_sec": fused_img,
+            "anchor_ubatch_ms": round(ubatch_ms, 3),
+            "worst_stage_share": round(worst_share, 4),
+            "overlapped_comm": dict(zip(
+                ("img_per_sec", "bottleneck_stage_ms"),
+                fused_tp(None))),
+            "serialized_ici_1600gbps": dict(zip(
+                ("img_per_sec", "bottleneck_stage_ms"),
+                fused_tp(ICI_MBPS))),
+            "serialized_dcn_100gbps": dict(zip(
+                ("img_per_sec", "bottleneck_stage_ms"),
+                fused_tp(dcn_mbps))),
+        }
+        for k in ("overlapped_comm", "serialized_ici_1600gbps",
+                  "serialized_dcn_100gbps"):
+            fused[k]["speedup_vs_single"] = round(
+                fused[k]["img_per_sec"] / fused_img, 2)
     return {
         "fused_anchor_projection": fused,
         "model": model_name, "n_devices": n_devices, "batch": batch,
@@ -199,7 +192,7 @@ granularity, so their sum under-states a fused stage program):
 
 | scenario | img/s | vs 1 chip (fused) | bottleneck stage |
 |---|---|---|---|
-| single chip, fused program (BENCH_r04 anchor) | {fa['anchor_img_per_sec']} | 1.0x | {fa['anchor_ubatch_ms']} ms |
+| single chip, fused program (anchor: deleted record of the former chip) | {fa['anchor_img_per_sec']} | 1.0x | {fa['anchor_ubatch_ms']} ms |
 | {r['n_devices']}-stage, comm overlapped (SPMD ppermute) | {fa['overlapped_comm']['img_per_sec']} | {fa['overlapped_comm']['speedup_vs_single']}x | {fa['overlapped_comm']['bottleneck_stage_ms']} ms |
 | {r['n_devices']}-stage, comm serialized @ ICI 1600 Gbps | {fa['serialized_ici_1600gbps']['img_per_sec']} | {fa['serialized_ici_1600gbps']['speedup_vs_single']}x | {fa['serialized_ici_1600gbps']['bottleneck_stage_ms']} ms |
 | {r['n_devices']}-stage, comm serialized @ DCN 100 Gbps | {fa['serialized_dcn_100gbps']['img_per_sec']} | {fa['serialized_dcn_100gbps']['speedup_vs_single']}x | {fa['serialized_dcn_100gbps']['bottleneck_stage_ms']} ms |

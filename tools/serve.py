@@ -2689,8 +2689,9 @@ def main():
         # routes, health-checks, drains, and migrates
         return _run_router(args)
 
-    from pipeedge_tpu.utils import apply_env_platform
-    apply_env_platform()
+    from pipeedge_tpu.utils import enable_compile_cache, report_devices
+    enable_compile_cache()
+    report_devices()
     import jax.numpy as jnp
 
     from pipeedge_tpu.parallel.decode import build_decode_pipeline

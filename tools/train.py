@@ -63,11 +63,13 @@ def main():
     if args.mixed_precision and args.dtype != "float32":
         p.error("--mixed-precision keeps f32 master weights; use -t "
                 "float32 (the bf16 cast is per-step, inside the program)")
-    if args.platform:
-        os.environ["JAX_PLATFORMS"] = args.platform
-    from pipeedge_tpu.utils import apply_env_platform
-    apply_env_platform()
+    from pipeedge_tpu.utils import (enable_compile_cache,
+                                    report_device_memory, report_devices)
+    enable_compile_cache()
     import jax
+    if args.platform:
+        jax.config.update("jax_platforms", args.platform)
+    report_devices()
     import jax.numpy as jnp
     import numpy as np
     import optax
@@ -155,6 +157,7 @@ def main():
                 and (i + 1) % args.ckpt_every == 0:
             train.save_train_state(args.ckpt_dir, params, opt_state, i + 1)
     wall = time.monotonic() - tik
+    report_device_memory()
     done = max(args.steps - start, 0)
     if args.ckpt_dir and done:
         # never write a checkpoint whose step count moves BACKWARD (a
