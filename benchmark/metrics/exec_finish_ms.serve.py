@@ -1,0 +1,30 @@
+"""Host time the executor spends after one stage-step's dispatch: seconds
+gained by the finish phases (`exec/pick`, `exec/emit`, `exec/eos`,
+`exec/reenter`, `exec/retire`) between the scrapes before and after the
+window, over the `stage/exec0` spans gained. With `exec_dispatch_ms.serve` it
+is the host's time for one token of one request.
+
+Read only from a run on a chip (`peaks` in `observed`): in the CPU rehearsal
+the same spans time XLA's CPU client, which is no number of this cell."""
+from benchmark import prom
+
+PHASES = {"pick", "emit", "eos", "reenter", "retire"}
+
+
+def gained(observed, family, cat, names):
+    """Gain of one digest family between the two scrapes, summed over the
+    spans of category `cat` named in `names`."""
+    def total(text):
+        return sum(value for labels, value in prom.samples(text, family)
+                   if labels.get("cat") == cat and labels.get("name") in names)
+    return total(observed["metrics_after"]) - total(observed["metrics_before"])
+
+
+def read(observed):
+    if "metrics_after" not in observed or "peaks" not in observed:
+        return None
+    steps = gained(observed, "pipeedge_span_count_total", "stage", {"exec0"})
+    if steps <= 0:
+        return None
+    return gained(observed, "pipeedge_span_seconds_total", "exec",
+                  PHASES) / steps * 1e3

@@ -281,11 +281,11 @@ class PagedKvBackend:
         ks = req.kvstate
         batch = req.ids.shape[0]
         span = data.shape[1] if kind in ("prefill", "span", "chunk") else 1
-        writes = [(b, j) for b in range(batch)
-                  for j in self._touched_pages(kind, req, span)
-                  if j >= ks["shared"]]
         with telemetry.span("stage", f"exec{i}", stage=i,
                             rid=str(req.rid)):
+            writes = [(b, j) for b in range(batch)
+                      for j in self._touched_pages(kind, req, span)
+                      if j >= ks["shared"]]
             if st["device"] is not None:
                 data = jax.device_put(data, st["device"])
             with self._arena_lock:
@@ -311,7 +311,9 @@ class PagedKvBackend:
                 and (kind in ("prefill", "span")
                      or (kind == "chunk" and req.chunk_final)) \
                 and tokens_publishable(req):
-            self._publish(req)
+            with telemetry.span("exec", "publish", stage=i,
+                                rid=str(req.rid)):
+                self._publish(req)
         return out
 
     def _publish(self, req) -> None:

@@ -300,6 +300,54 @@ def _finalize_tokens(req: _Request) -> np.ndarray:
     return np.concatenate([np.asarray(req.ids), toks], axis=1)
 
 
+# -- the finish phases, shared by both executors --------------------------
+# Each is one `exec` span (docs/OBSERVABILITY.md): with `stage`/`exec{i}`
+# and the workers' `exec`/`wait{i}` they keep an executor thread inside a
+# named span for all of its time, so an idle gap of the device is named
+# by the phase of the executor it fell in.
+
+def _pick_token(req: _Request, out, executor: str):
+    """`exec/pick`: split the request's rng, pick the next token from the
+    last position's logits (prefill [B,S], span [B,S_s], step [B,1]) and
+    append it — the split-per-pick rng discipline of generate()."""
+    with telemetry.span("exec", "pick", rid=str(req.rid)):
+        logits = out[:, -1]
+        req.rng, sub = jax.random.split(req.rng)
+        token = req.pick(logits.astype(jnp.float32), sub)
+        req.tokens.append(token)
+        M_STEPS.inc(executor=executor)
+    return token
+
+
+def _emit_token(req: _Request, token, on_step) -> None:
+    """`exec/emit`: the executor's `on_step` and the request's `on_token`
+    call-backs (tools/serve.py: admission re-grants, the hand-over of the
+    device token to the streaming HTTP thread)."""
+    with telemetry.span("exec", "emit", rid=str(req.rid)):
+        if on_step is not None:
+            on_step()
+        if req.on_token is not None:
+            req.on_token(len(req.tokens) - 1, token)
+
+
+def _all_rows_eos(req: _Request, token) -> bool:
+    """`exec/eos`: read the just-picked token back (blocks on the device)
+    and report whether every row of the request has now emitted eos."""
+    with telemetry.span("exec", "eos", rid=str(req.rid)):
+        hit = np.asarray(token) == req.eos_token
+        req.rows_done = hit if req.rows_done is None \
+            else req.rows_done | hit
+        return bool(req.rows_done.all())
+
+
+def _step_input(req: _Request, token):
+    """`exec/reenter`: the next decode step's input, the picked token as
+    [B, 1] ids (one more device dispatch) — `exec/retire`'s counterpart
+    for a request that goes on."""
+    with telemetry.span("exec", "reenter", rid=str(req.rid)):
+        return token[:, None]
+
+
 class ContinuousBatcher:
     """Wave-scheduled multi-request decoding over a `DecodePipeline`.
 
@@ -447,6 +495,15 @@ class ContinuousBatcher:
         self.pending.append(req)
 
     def _admit(self) -> None:
+        """Join pending requests while slots (and pages) last: `exec/admit`,
+        which on this executor runs on the worker's own thread (the stage
+        workers admit on the submitter's)."""
+        if not self.pending or self.active >= self.max_active:
+            return
+        with telemetry.span("exec", "admit"):
+            self._admit_pending()
+
+    def _admit_pending(self) -> None:
         while self.pending and self.active < self.max_active:
             req = self.pending[0]
             if _expired(req):
@@ -505,17 +562,9 @@ class ContinuousBatcher:
             M_CHUNKS.inc(executor="wave")
             reentries.append((req, data, "chunk"))
             return
-        del kind  # the last position's logits, for every wave kind:
-        logits = out[:, -1]  # prefill [B,S], span [B,S_s], step [B,1]
-        req.rng, sub = jax.random.split(req.rng)
-        token = req.pick(logits.astype(jnp.float32), sub)
-        req.tokens.append(token)
+        token = _pick_token(req, out, "wave")
         self.stats["tokens"] += int(token.shape[0])
-        M_STEPS.inc(executor="wave")
-        if self.on_step is not None:
-            self.on_step()
-        if req.on_token is not None:
-            req.on_token(len(req.tokens) - 1, token)
+        _emit_token(req, token, self.on_step)
         done = len(req.tokens) >= req.new_tokens
         if not done and (_expired(req) or (req.cancel is not None
                                            and req.cancel.is_set())):
@@ -527,17 +576,18 @@ class ContinuousBatcher:
         if done:
             self._complete(req)
         else:
-            reentries.append((req, token[:, None], "step"))
+            reentries.append((req, _step_input(req, token), "step"))
 
     def _complete(self, req: _Request) -> None:
-        self.results[req.rid] = _finalize_tokens(req)
-        req.caches = None            # free this request's cache slots
-        req.chunk_rest = None
-        if self.kv is not None:
-            self.kv.release(req)     # ... or its page references
-        self.active -= 1
-        self._live_rids.discard(req.rid)
-        _sched_mark("retire", req.rid)
+        with telemetry.span("exec", "retire", rid=str(req.rid)):
+            self.results[req.rid] = _finalize_tokens(req)
+            req.caches = None            # free this request's cache slots
+            req.chunk_rest = None
+            if self.kv is not None:
+                self.kv.release(req)     # ... or its page references
+            self.active -= 1
+            self._live_rids.discard(req.rid)
+            _sched_mark("retire", req.rid)
         if self.step_join:
             # the slot freed at THIS step boundary joins a pending
             # request into stage 0 immediately: the reversed drain has
@@ -553,14 +603,11 @@ class ContinuousBatcher:
         token = req.tokens[-1]
         done = len(req.tokens) >= req.new_tokens
         if not done:
-            hit = np.asarray(token) == req.eos_token
-            req.rows_done = hit if req.rows_done is None \
-                else req.rows_done | hit
-            done = bool(req.rows_done.all())
+            done = _all_rows_eos(req, token)
         if done:
             self._complete(req)
         else:
-            self._stage_q[0].append((req, token[:, None], "step"))
+            self._stage_q[0].append((req, _step_input(req, token), "step"))
 
     def _pop_stage0(self):
         """Token-budget-per-step policy at stage 0: the budget accrues
@@ -894,7 +941,8 @@ class StageWorkerExecutor:
 
     def _stage_loop(self, i: int) -> None:
         while True:
-            item = self._q[i].get()
+            with telemetry.span("exec", f"wait{i}", stage=i):
+                item = self._q[i].get()
             if item is self._DONE:
                 return
             req, data, kind = item
@@ -925,18 +973,7 @@ class StageWorkerExecutor:
         if kind == "chunk" and not req.chunk_final:
             if _expired(req) or (req.cancel is not None
                                  and req.cancel.is_set()):
-                arr = _finalize_tokens(req)   # the bare prompt
-                req.caches = None
-                req.chunk_rest = None
-                if self.kv is not None:
-                    self.kv.release(req)
-                _sched_mark("retire", req.rid)
-                with self._lock:
-                    self.results[req.rid] = arr
-                    self._live.discard(req.rid)
-                    self.active -= 1
-                    self._lock.notify_all()
-                self._slots.release()
+                self._retire(req)             # with the bare prompt
                 return
             data = _next_chunk(req, self.chunk_tokens)
             with self._lock:
@@ -944,32 +981,32 @@ class StageWorkerExecutor:
             M_CHUNKS.inc(executor="workers")
             self._q[0].put((req, data, "chunk"))
             return
-        logits = out[:, -1]
-        req.rng, sub = jax.random.split(req.rng)
-        token = req.pick(logits.astype(jnp.float32), sub)
-        req.tokens.append(token)
+        token = _pick_token(req, out, "workers")
         with self._lock:
             self.stats["tokens"] += int(token.shape[0])
-        M_STEPS.inc(executor="workers")
-        if self.on_step is not None:
-            self.on_step()
-        if req.on_token is not None:
-            req.on_token(len(req.tokens) - 1, token)
+        _emit_token(req, token, self.on_step)
         done = len(req.tokens) >= req.new_tokens
         if not done and _expired(req):
             done = True             # deadline passed: cancel mid-flight
         if not done and req.cancel is not None and req.cancel.is_set():
             done = True             # caller gone: free the slot early
         if not done and req.eos_token is not None:
-            hit = np.asarray(token) == req.eos_token
-            req.rows_done = hit if req.rows_done is None \
-                else req.rows_done | hit
-            done = bool(req.rows_done.all())
+            done = _all_rows_eos(req, token)
         if done:
+            self._retire(req)
+        else:
+            self._q[0].put((req, _step_input(req, token), "step"))
+
+    def _retire(self, req: _Request) -> None:
+        """`exec/retire`: finalise the request's tokens, free its cache
+        slots (or page references), publish the result, wake its waiter
+        and hand the admission slot back."""
+        with telemetry.span("exec", "retire", rid=str(req.rid)):
             arr = _finalize_tokens(req)
-            req.caches = None        # free this request's cache slots
+            req.caches = None
+            req.chunk_rest = None
             if self.kv is not None:
-                self.kv.release(req)  # ... or its page references
+                self.kv.release(req)
             _sched_mark("retire", req.rid)
             with self._lock:
                 self.results[req.rid] = arr
@@ -977,8 +1014,6 @@ class StageWorkerExecutor:
                 self.active -= 1
                 self._lock.notify_all()
             self._slots.release()
-        else:
-            self._q[0].put((req, token[:, None], "step"))
 
     def _die(self, exc: BaseException) -> None:
         with self._lock:

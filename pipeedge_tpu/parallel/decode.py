@@ -452,12 +452,21 @@ def make_stage_fns(family, cfg: TransformerConfig, shard_config: ShardConfig,
     (`_resolve_int8_optin`; None re-resolves from env/config).
     """
     run = _make_stage_run(family, cfg, shard_config, int8_optin=int8_optin)
-    prefill_fn = jax.jit(partial(run, pos=0, prefill=True))
+
+    # plain functions, not partials: jit names the compiled module, and
+    # with it every row of a profiler trace, by `__name__`
+    def prefill(params, data, cache):
+        return run(params, data, cache, pos=0, prefill=True)
+
+    def decode_step(params, data, cache, pos, read_len=None):
+        return run(params, data, cache, pos, prefill=False,
+                   read_len=read_len)
+
+    prefill_fn = jax.jit(prefill)
     # read_len is STATIC: each attend-window bucket compiles its own
     # decode-step program (a handful of power-of-2 variants, the same
     # compile-per-discrete-value pattern as the quantized edge bitwidths)
-    decode_fn = jax.jit(partial(run, prefill=False),
-                        static_argnames=("read_len",))
+    decode_fn = jax.jit(decode_step, static_argnames=("read_len",))
     return prefill_fn, decode_fn
 
 
@@ -606,15 +615,18 @@ def make_tp_stage_fns(family, cfg: TransformerConfig,
     c_specs = tp_cache_specs(init_cache(cfg, 1, 1, 1,
                                         cache_bits=cache_bits), axis)
 
+    def tp_prefill(params, data, cache):
+        return run(params, data, cache, pos=0, prefill=True)
+
     prefill_fn = jax.jit(jax_compat.shard_map(
-        partial(run, pos=0, prefill=True), mesh=mesh,
+        tp_prefill, mesh=mesh,
         in_specs=(p_specs, P(), c_specs), out_specs=(P(), c_specs)))
 
     # the bucketed attend window is bound into the shard_map closure per
     # static read_len value — jit re-traces per bucket, same
     # compile-per-discrete-value pattern as the plain path
     @partial(jax.jit, static_argnames=("read_len",))
-    def decode_fn(params, data, cache, pos, read_len=None):
+    def tp_decode_step(params, data, cache, pos, read_len=None):
         return jax_compat.shard_map(
             partial(run, prefill=False, read_len=read_len), mesh=mesh,
             in_specs=(p_specs, P(), c_specs, P()),
@@ -623,7 +635,7 @@ def make_tp_stage_fns(family, cfg: TransformerConfig,
 
     # p_specs is returned so callers place params with the SAME specs the
     # program compiled against (drift would silently reshard every call)
-    return prefill_fn, decode_fn, p_specs
+    return prefill_fn, tp_decode_step, p_specs
 
 
 def validate_partition(partition: Sequence[Tuple[int, int]],
@@ -757,11 +769,17 @@ def make_ep_stage_fns(family, cfg: TransformerConfig,
     c_specs = {k: P() for k in init_cache(cfg, 1, 1, 1,
                                           cache_bits=cache_bits)}
 
+    def ep_prefill(params, data, cache):
+        return run(params, data, cache, pos=0, prefill=True)
+
+    def ep_decode_step(params, data, cache, pos):
+        return run(params, data, cache, pos, prefill=False)
+
     prefill_fn = jax.jit(jax_compat.shard_map(
-        partial(run, pos=0, prefill=True), mesh=mesh,
+        ep_prefill, mesh=mesh,
         in_specs=(p_specs, P(), c_specs), out_specs=(P(), c_specs)))
     decode_fn = jax.jit(jax_compat.shard_map(
-        partial(run, prefill=False), mesh=mesh,
+        ep_decode_step, mesh=mesh,
         in_specs=(p_specs, P(), c_specs, P()), out_specs=(P(), c_specs)))
     return prefill_fn, decode_fn, p_specs
 
@@ -836,11 +854,17 @@ def make_tp_ep_stage_fns(family, cfg: TransformerConfig,
     # same head-axis convention _fresh_caches places with (tp_cache_specs)
     c_specs = tp_cache_specs(init_cache(cfg, 1, 1, 1), tp_axis)
 
+    def tp_ep_prefill(params, data, cache):
+        return run(params, data, cache, pos=0, prefill=True)
+
+    def tp_ep_decode_step(params, data, cache, pos):
+        return run(params, data, cache, pos, prefill=False)
+
     prefill_fn = jax.jit(jax_compat.shard_map(
-        partial(run, pos=0, prefill=True), mesh=mesh,
+        tp_ep_prefill, mesh=mesh,
         in_specs=(p_specs, P(), c_specs), out_specs=(P(), c_specs)))
     decode_fn = jax.jit(jax_compat.shard_map(
-        partial(run, prefill=False), mesh=mesh,
+        tp_ep_decode_step, mesh=mesh,
         in_specs=(p_specs, P(), c_specs, P()), out_specs=(P(), c_specs)))
     return prefill_fn, decode_fn, p_specs
 
@@ -942,8 +966,12 @@ def make_sp_prefill_fn(family, cfg: TransformerConfig,
                           finalize_fn=sp_finalize, embed_fn=sp_embed)
     edge_in = P() if shard_config.is_first else P(None, axis)
     edge_out = P() if shard_config.is_last else P(None, axis)
+
+    def sp_prefill(params, data, cache):
+        return run(params, data, cache, pos=0, prefill=True)
+
     return jax.jit(jax_compat.shard_map(
-        partial(run, pos=0, prefill=True), mesh=mesh,
+        sp_prefill, mesh=mesh,
         in_specs=(P(), edge_in, P()), out_specs=(edge_out, P())))
 
 

@@ -40,7 +40,6 @@ from .. import telemetry
 from ..ops import clamp as clamp_ops
 from ..ops import fused_quant
 from ..ops import quant as quant_ops
-from ..utils import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -131,12 +130,12 @@ class PipelineStage:
             decode = _tunnel_decode_payload if self.tunnel \
                 else _decode_payload
 
-            def step(params, payload):
+            def host_stage_step(params, payload):
                 data = decode(payload)
                 out = shard_fn(params, data)
                 return _encode_payload(out, bit, do_clamp)
 
-            fn = jax.jit(step, donate_argnums=(
+            fn = jax.jit(host_stage_step, donate_argnums=(
                 (1,) if self.donate_payload else ()))
             self._compiled[bit] = fn
         return fn
@@ -191,13 +190,11 @@ class HostPipeline:
         last = len(self.stages) - 1
         rid = trace.rid if trace is not None else None
         for i, stage in enumerate(self.stages):
-            # named profiler region: stage dispatch shows up on the trace
-            # timeline (see utils/tracing.py; no-op cost when not tracing).
-            # The telemetry span measures HOST dispatch time (device work
-            # is async); the retire span is where device time surfaces.
-            with tracing.annotate(stage.name or f"stage{i}"), \
-                    telemetry.span("stage", stage.name or f"stage{i}",
-                                   stage=i, mb=mb, rid=rid):
+            # the span measures HOST dispatch time (device work is async;
+            # the retire span is where device time surfaces) and, under a
+            # live profiler session, names it on the trace's timeline
+            with telemetry.span("stage", stage.name or f"stage{i}",
+                                stage=i, mb=mb, rid=rid):
                 data = stage(data)
             if edge_bytes is not None and i < last:
                 edge_bytes.append(payload_wire_bytes(data))

@@ -12,10 +12,14 @@ stalled the fleet. This subsystem is the missing correlation layer:
   request id of the span's `TraceContext` (request-scoped tracing), None
   when untraced.
 - module-level `configure()` / `span()` / `record()`: the instrumentation
-  surface. Recording is OFF by default; when off, `span()` returns a shared
-  no-op context manager, so the hot-path cost of a disabled probe is one
-  global read and one attribute call (see `tools/trace_report.py`'s
-  `span_overhead_pct` self-measurement for the enabled cost).
+  surface. `span()` is the one probe and has two sinks: the ring, when a
+  recorder is configured, and the JAX profiler, while a profiler session
+  is live (whoever started it), where the span becomes a host
+  `TraceAnnotation` named `<cat>/<name>` on the device trace's clock.
+  With neither sink it returns a shared no-op context manager, so the
+  hot-path cost of a disabled probe is one global read and one
+  `is_enabled()` call (see `tools/trace_report.py`'s `span_overhead_pct`
+  self-measurement for the enabled cost). `record()` is ring-only.
 - `spans_to_wire` / `spans_from_wire`: span buffers as a single uint8
   ndarray (UTF-8 JSON), the only payload type the DCN command channel
   carries — how a peer's buffer travels in a `_MSG_SPANS` reply
@@ -31,12 +35,15 @@ pipeline per-stage dispatch/retire), `compute` (the jitted shard step),
 lifecycle), `runtime` (schedule rounds), `failover` (detection→recovery),
 `rejoin` (JOIN admission → heal-to-full-capacity), `health` (gray-failure
 lifecycle transitions, pipeedge_tpu/health/), `serve` (HTTP request
-lifecycle).
+lifecycle; the streaming handler's `readback` and `write`), `exec` (the
+decode executors' worker phases: `wait{i}`, `admit`, `pick`, `emit`,
+`eos`, `reenter`, `retire`, `publish`).
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -57,9 +64,12 @@ DEFAULT_SPAN_CAPACITY = 32768
 _FIELDS = ("cat", "name", "rank", "stage", "mb", "t0", "t1", "rid")
 
 # categories folded into the cumulative digest (sched/rebalance.py's
-# sensor): bounded name sets only — feed/results names embed microbatch
-# ids and would grow the digest without bound
-DIGEST_CATEGORIES = frozenset(("stage", "compute", "wire", "quant"))
+# sensor reads stage/compute/wire; GET /metrics renders all of it as
+# pipeedge_span_{seconds,count}_total): bounded name sets only —
+# feed/results names embed microbatch ids and would grow the digest
+# without bound
+DIGEST_CATEGORIES = frozenset(("stage", "compute", "wire", "quant",
+                               "exec", "serve"))
 
 # a digest maps (cat, name, stage) -> (count, total_ns), CUMULATIVE since
 # the recorder was configured — consumers difference two digests to get a
@@ -117,8 +127,9 @@ class SpanRecorder:
     def span(self, cat: str, name: str, stage: Optional[int] = None,
              mb: Optional[int] = None,
              rid: Optional[str] = None) -> "_Span":
-        """Context manager recording [enter, exit] as one span."""
-        return _Span(self, cat, name, stage, mb, rid)
+        """Context manager recording [enter, exit] as one span (ring
+        only: the module-level `span()` is the probe with both sinks)."""
+        return _Span(self, None, cat, name, stage, mb, rid)
 
     def __len__(self) -> int:
         with self._lock:
@@ -147,12 +158,16 @@ class SpanRecorder:
 
 
 class _Span:
-    """Live span: stamps monotonic_ns on enter/exit, records on exit."""
+    """Live span: stamps monotonic_ns on enter/exit and records into the
+    ring on exit (when `rec` is set); when `ann` is set — a profiler
+    session is live — the span is also that `TraceAnnotation`'s life."""
 
-    __slots__ = ("_rec", "_cat", "_name", "_stage", "_mb", "_rid", "_t0")
+    __slots__ = ("_rec", "_ann", "_cat", "_name", "_stage", "_mb", "_rid",
+                 "_t0")
 
-    def __init__(self, rec, cat, name, stage, mb, rid=None):
+    def __init__(self, rec, ann, cat, name, stage, mb, rid=None):
         self._rec = rec
+        self._ann = ann
         self._cat = cat
         self._name = name
         self._stage = stage
@@ -160,13 +175,18 @@ class _Span:
         self._rid = rid
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc):
-        self._rec.record(self._cat, self._name, self._t0,
-                         time.monotonic_ns(), self._stage, self._mb,
-                         rid=self._rid)
+        t1 = time.monotonic_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._rec is not None:
+            self._rec.record(self._cat, self._name, self._t0, t1,
+                             self._stage, self._mb, rid=self._rid)
         return False
 
 
@@ -184,6 +204,26 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 _recorder: Optional[SpanRecorder] = None
+# jax.profiler.TraceAnnotation, resolved once and only after something else
+# imported JAX (this package is imported by code that must not); False
+# where this JAX has none
+_annotation = None
+
+
+def _live_annotation():
+    """`TraceAnnotation` while a profiler session is live, else None."""
+    global _annotation  # pylint: disable=global-statement
+    cls = _annotation
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None         # no JAX in the process, so no session
+        try:
+            from jax.profiler import TraceAnnotation as cls
+            cls.is_enabled()
+        except (ImportError, AttributeError):
+            cls = False
+        _annotation = cls
+    return cls if cls and cls.is_enabled() else None
 
 
 def configure(rank: int = 0, capacity: Optional[int] = None) -> SpanRecorder:
@@ -210,14 +250,21 @@ def enabled() -> bool:
 
 def span(cat: str, name: str, stage: Optional[int] = None,
          mb: Optional[int] = None, rid: Optional[str] = None):
-    """Instrumentation probe: a recording span when configured, the shared
-    no-op otherwise. Safe on any thread. `rid` tags the span with a
-    request id; None picks up the calling thread's current trace context
-    (set_trace / trace_scope) at record time."""
+    """THE instrumentation probe. Records into the ring when a recorder
+    is configured; while a JAX profiler session is live (started by anyone:
+    `utils/tracing.trace`, POST /debug/profile, a benchmark's side door) it
+    also is a host `TraceAnnotation` named `<cat>/<name>`, so the span
+    lies on the device trace's clock. With neither sink: the shared no-op.
+    Safe on any thread. `rid` tags the ring's span with a request id; None
+    picks up the calling thread's current trace context (set_trace /
+    trace_scope) at record time."""
     rec = _recorder
-    if rec is None:
-        return _NULL_SPAN
-    return _Span(rec, cat, name, stage, mb, rid)
+    ann = _live_annotation()
+    if ann is None:
+        if rec is None:
+            return _NULL_SPAN
+        return _Span(rec, None, cat, name, stage, mb, rid)
+    return _Span(rec, ann(f"{cat}/{name}"), cat, name, stage, mb, rid)
 
 
 def record(cat: str, name: str, t0: int, t1: int,

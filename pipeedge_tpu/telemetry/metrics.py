@@ -410,3 +410,61 @@ def render_monitoring_snapshot(snapshot: dict,
                     f'{name}{{key="{key}",scope="{scope}"}} '
                     f"{_fmt(float(value))}")
     return lines
+
+
+def render_span_digest(digest: dict) -> List[str]:
+    """The span recorder's cumulative digest (`SpanRecorder.digest()`:
+    (cat, name, stage) -> (count, total_ns), never dropped) as two counter
+    families — one instrumentation site per phase serves the ring, the
+    profiler and /metrics alike. `rate(seconds_total)` of a phase is the
+    share of a second its thread spends in it; seconds over count is its
+    mean. Seconds are written to the nanosecond."""
+    rows = sorted(digest.items(),
+                  key=lambda kv: (kv[0][0], kv[0][1],
+                                  -1 if kv[0][2] is None else kv[0][2]))
+    lines = []
+    for family, what, column in (
+            ("pipeedge_span_seconds_total", "seconds spent in", 1),
+            ("pipeedge_span_count_total", "completed", 0)):
+        lines.append(f"# HELP {family} {what} telemetry spans of the "
+                     "digest categories, by category, name and stage")
+        lines.append(f"# TYPE {family} counter")
+        for (cat, name, stage), cell in rows:
+            labels = _label_str((("cat", cat), ("name", name),
+                                 ("stage", "" if stage is None else stage)))
+            value = f"{cell[1] / 1e9:.9f}" if column else str(cell[0])
+            lines.append(f"{family}{labels} {value}")
+    return lines
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def count_jax_compiles(registry: Registry = REGISTRY) -> Tuple[Counter,
+                                                               Counter]:
+    """Count every program JAX hands to the backend compiler, through a
+    `jax.monitoring` duration listener: `pipeedge_jax_compiles_total` and
+    `pipeedge_jax_compile_seconds_total` in `registry`. The event fires
+    once for each new (function, shapes, static values), also where the
+    persistent cache answers (the seconds are then the read); a repeat of
+    a warm shape does not fire it. The listener lives as long as the
+    process: call once per registry (tools/serve.py does, at start-up).
+    Returns the two counters."""
+    import jax.monitoring
+    compiles = registry.counter(
+        "pipeedge_jax_compiles_total",
+        "programs handed to the backend compiler (persistent-cache hits "
+        "included): one per new jitted function, shape or static value")
+    seconds = registry.counter(
+        "pipeedge_jax_compile_seconds_total",
+        "seconds in the backend compiler or its persistent cache")
+    compiles.declare()
+    seconds.declare()
+
+    def on_duration(event, duration, **_):
+        if event == _COMPILE_EVENT:
+            compiles.inc()
+            seconds.inc(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return compiles, seconds

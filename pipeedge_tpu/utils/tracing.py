@@ -7,12 +7,14 @@ so the runtime exposes it first-class:
 
 - `trace(out_dir)`: context manager capturing a profiler session; view with
   TensorBoard's profile plugin or Perfetto (xplane → trace.json.gz is
-  emitted automatically).
-- `annotate(name)`: named host-side region that shows up on the trace
-  timeline (wraps `jax.profiler.TraceAnnotation`), used by the pipeline
-  drivers to label per-microbatch/per-stage work.
+  emitted automatically). Taken with the options the benchmark's traces
+  use (`profile_options`): Python-call tracing off, host tracer level 2.
 
-Both degrade to no-ops if the profiler backend is unavailable (e.g. a
+Host-side regions on the trace's timeline come from `telemetry.span()`,
+the one probe: while a session is live every span is also a
+`TraceAnnotation` named `<cat>/<name>`.
+
+`trace` degrades to a no-op if the profiler backend is unavailable (e.g. a
 second concurrent session), mirroring the monitoring subsystem's graceful
 energy-meter fallback (reference monitoring.py:104-121).
 """
@@ -26,6 +28,26 @@ from typing import Iterator, Optional
 logger = logging.getLogger(__name__)
 
 
+def profile_options():
+    """The profiler options of every trace this repo takes: Python-call
+    tracing off (with it on, a few seconds of serving are too much to read
+    back; the program's host time is named by `telemetry.span`'s
+    annotations instead), XLA's host events and TraceAnnotations kept."""
+    import jax
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    return options
+
+
+def start_trace(out_dir: str) -> None:
+    """Start a profiler session into `out_dir` with `profile_options()`.
+    Raises what JAX raises: RuntimeError while another session is live."""
+    import jax
+    os.makedirs(out_dir, exist_ok=True)
+    jax.profiler.start_trace(out_dir, profiler_options=profile_options())
+
+
 @contextlib.contextmanager
 def trace(out_dir: Optional[str]) -> Iterator[None]:
     """Capture a JAX profiler trace into `out_dir` (no-op when None)."""
@@ -34,8 +56,7 @@ def trace(out_dir: Optional[str]) -> Iterator[None]:
         return
     import jax
     try:
-        os.makedirs(out_dir, exist_ok=True)
-        jax.profiler.start_trace(out_dir)
+        start_trace(out_dir)
     except Exception as exc:  # bad path / profiler busy: degrade gracefully
         logger.warning("trace capture unavailable (%s); continuing without",
                        exc)
@@ -50,46 +71,3 @@ def trace(out_dir: Optional[str]) -> Iterator[None]:
                         out_dir, out_dir)
         except Exception as exc:
             logger.warning("trace stop failed: %s", exc)
-
-
-class _SafeAnnotation:
-    """TraceAnnotation wrapper that degrades to a no-op if the profiler
-    backend rejects entry (e.g. a second concurrent session) — the same
-    graceful fallback `trace()` applies, honoring the module contract."""
-
-    __slots__ = ("_inner", "_entered")
-
-    def __init__(self, inner):
-        self._inner = inner
-        self._entered = False
-
-    def __enter__(self):
-        try:
-            self._inner.__enter__()
-            self._entered = True
-        except Exception as exc:  # profiler busy/unavailable: no-op region
-            logger.warning("annotate unavailable (%s); continuing without",
-                           exc)
-        return self
-
-    def __exit__(self, *exc):
-        if not self._entered:
-            return False
-        self._entered = False
-        try:
-            return self._inner.__exit__(*exc)
-        except Exception as err:
-            logger.warning("annotate exit failed: %s", err)
-            return False
-
-
-def annotate(name: str):
-    """Named region on the profiler timeline (host + linked device ops);
-    degrades to a no-op context manager when the profiler backend is
-    unavailable, like `trace()`."""
-    try:
-        import jax
-        return _SafeAnnotation(jax.profiler.TraceAnnotation(name))
-    except Exception as exc:  # import/constructor failure: degrade
-        logger.warning("annotate unavailable (%s); continuing without", exc)
-        return contextlib.nullcontext()

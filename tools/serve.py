@@ -50,7 +50,16 @@ Endpoints (all JSON unless noted):
                               gauges when a monitoring session is open,
                               and whatever the runtime's DCN hooks fed
                               into the shared registry (wire bytes,
-                              negotiated edge bitwidths, heartbeats)
+                              negotiated edge bitwidths, heartbeats),
+                              the span recorder's cumulative digest
+                              (pipeedge_span_{seconds,count}_total) and
+                              the compile counters
+- POST /debug/profile?seconds=N
+                           -> {"path": DIR, "seconds": n} — with
+                              --profile-dir DIR only: one JAX profiler
+                              session of N seconds (capped) into DIR, the
+                              serving loop's spans on the device trace's
+                              clock; 409 while a session is live
 - POST /degraded {"degraded": bool, "dead_rank"?: n, "retry_after"?: s,
                   "healing"?: bool, "healed"?: bool, "rank"?: n}
                            -> {"degraded": bool} — the failover
@@ -140,6 +149,7 @@ import queue as queue_mod
 import sys
 import threading
 import time
+import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
@@ -443,10 +453,15 @@ class _Service:
 
     def _loop(self):
         while True:
-            with self.cond:
+            # `exec/wait0`, the wave worker's only blocking wait: first
+            # for the condition's lock, which every submitting and every
+            # waiting handler thread shares with it, then for work
+            with telemetry.span("exec", "wait0", stage=0):
+                self.cond.acquire()
                 while not self._stop and not (
                         self.batcher.pending or self.batcher.active):
                     self.cond.wait()
+            try:
                 if self._stop:
                     return
                 try:
@@ -459,6 +474,8 @@ class _Service:
                     raise
                 if self.batcher.results:
                     self.cond.notify_all()
+            finally:
+                self.cond.release()
 
     @property
     def dead(self) -> Optional[BaseException]:
@@ -1311,7 +1328,25 @@ class _Service:
             self.prefill_supervisor.stop()
 
 
-def make_handler(service, model_name):
+PROFILE_MAX_SECONDS = 60.0
+
+
+def _profile(out_dir, seconds):
+    """POST /debug/profile: one JAX profiler session of `seconds` into
+    `out_dir`, taken with the options of every trace this repo takes
+    (utils/tracing.profile_options). While it runs, every telemetry span
+    of the process is a host annotation on the trace. JAX allows one
+    session a process: a second start raises RuntimeError."""
+    import jax
+    from pipeedge_tpu.utils import tracing
+    tracing.start_trace(out_dir)
+    try:
+        time.sleep(seconds)
+    finally:
+        jax.profiler.stop_trace()
+
+
+def make_handler(service, model_name, profile_dir=None):
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"      # chunked transfer needs 1.1
 
@@ -1411,12 +1446,14 @@ def make_handler(service, model_name):
                 # the blocking device readback happens HERE, in the
                 # handler thread — the executor worker only enqueued the
                 # device array and moved on
-                tok = np.asarray(token).tolist()
+                with telemetry.span("serve", "readback", rid=rid):
+                    tok = np.asarray(token).tolist()
                 if first_ms is None:
                     first_ms = round((time.monotonic() - t0) * 1e3, 3)
                 if not cancel.is_set():
                     try:
-                        self._chunk({"step": step, "tokens": tok})
+                        with telemetry.span("serve", "write", rid=rid):
+                            self._chunk({"step": step, "tokens": tok})
                     except OSError:
                         # client went away: cancel the generation but keep
                         # draining the queue until the worker's terminal
@@ -1446,6 +1483,9 @@ def make_handler(service, model_name):
                 import monitoring
                 extra = prom.render_monitoring_snapshot(
                     monitoring.snapshot())
+                rec = telemetry.recorder()
+                if rec is not None:
+                    extra += prom.render_span_digest(rec.digest())
                 body = prom.REGISTRY.render(extra=extra).encode()
                 self.send_response(200)
                 self.send_header("Content-Type",
@@ -1500,6 +1540,26 @@ def make_handler(service, model_name):
                     self._send(200, {"path": path,
                                      "written_total":
                                      service.flight.written_total()})
+                elif self.path.split("?", 1)[0] == "/debug/profile":
+                    if profile_dir is None:
+                        self._send(404, {"error": "profiling is off: "
+                                         "start with --profile-dir DIR"})
+                        return
+                    query = urllib.parse.parse_qs(
+                        urllib.parse.urlsplit(self.path).query)
+                    seconds = float(query.get("seconds", ["3"])[0])
+                    if not seconds > 0:
+                        raise ValueError("seconds must be > 0")
+                    seconds = min(seconds, PROFILE_MAX_SECONDS)
+                    try:
+                        _profile(profile_dir, seconds)
+                    except RuntimeError as exc:
+                        # JAX's own one-session rule: another POST, or
+                        # whoever else started a session in this process
+                        self._send(409, {"error": str(exc)})
+                        return
+                    self._send(200, {"path": profile_dir,
+                                     "seconds": seconds})
                 elif self.path == "/degraded":
                     # the failover orchestrator's switch (see module doc):
                     # degraded -> healing -> healed lifecycle
@@ -2598,6 +2658,12 @@ def main():
                    help="record request/stage spans and write a Perfetto-"
                         "loadable trace JSON to OUT on shutdown "
                         "(tools/trace_report.py analyzes it)")
+    p.add_argument("--profile-dir", default=None, metavar="DIR",
+                   help="enable POST /debug/profile?seconds=N: one JAX "
+                        "profiler session at a time (409 while one runs, "
+                        f"N capped at {PROFILE_MAX_SECONDS:g}) written "
+                        "to DIR, the serving loop's spans named on the "
+                        "device trace's clock (docs/OBSERVABILITY.md)")
     p.add_argument("--postmortem-dir", default=None, metavar="DIR",
                    help="directory for flight-recorder postmortem bundles "
                         "(default: env PIPEEDGE_POSTMORTEM_DIR or "
@@ -2779,6 +2845,8 @@ def main():
     # the ring for trace_report --fleet federation without pre-arming.
     # --trace-spans keeps controlling only the shutdown trace dump.
     telemetry.configure(rank=0)
+    # pipeedge_jax_compiles_total: "nothing compiles under load" as a number
+    prom.count_jax_compiles()
     from pipeedge_tpu.analysis import lockdep
     if args.trace_spans or lockdep.enabled():
         # SIGTERM must unwind through the finally below (the default
@@ -2825,7 +2893,8 @@ def main():
         # deaths/readmissions) land in the flight recorder's event ring
         prefill_fleet.flight_note = service.flight.note
     server = ThreadingHTTPServer((args.host, args.port),
-                                 make_handler(service, args.model_name))
+                                 make_handler(service, args.model_name,
+                                              profile_dir=args.profile_dir))
     print(f"serving {args.model_name} ({len(pipe.stages)} stages, "
           f"{args.executor} executor) on {args.host}:{args.port}",
           flush=True)
