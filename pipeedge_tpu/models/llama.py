@@ -71,25 +71,23 @@ def _window_keep(keep: jax.Array, q_pos, cfg: TransformerConfig):
     return keep & (k_pos > q_pos - cfg.sliding_window)
 
 
-def _gqa_attend(q, k, v, cfg: TransformerConfig, keep=None,
-                q_pos=None) -> jax.Array:
+def _gqa_attend(q, k, v, cfg: TransformerConfig, keep=None) -> jax.Array:
     """softmax(QK^T)V with GQA head repetition; `keep` optionally masks
-    key positions ([S_q, S_k], decode path — pass `q_pos` [S_q, 1] so the
-    sliding window can anchor to absolute positions), else causal (+
-    window). Delegates the masked-softmax body to the decode subsystem's
-    `_attend` — ONE copy of the attention numerics for both consumers."""
-    from ..parallel.decode import _attend
+    key positions (the decode path: k, v and keep are then the parts
+    `_cache_update_and_read` returns, sliding window included), else
+    causal (+ window). Delegates the masked-softmax body to the decode
+    subsystem's `_attend` — ONE copy of the attention numerics for both
+    consumers."""
+    from ..parallel.decode import _attend, _parts
 
     h = q.shape[2]
-    k = _repeat_kv(k, h // k.shape[2])
-    v = _repeat_kv(v, h // v.shape[2])
+    k = tuple(_repeat_kv(part, h // part.shape[2]) for part in _parts(k))
+    v = tuple(_repeat_kv(part, h // part.shape[2]) for part in _parts(v))
     if keep is None:                 # full forward: causal over [S, S]
-        s_q, s_k = q.shape[1], k.shape[1]
+        s_q, s_k = q.shape[1], k[0].shape[1]
         q_pos = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 0)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 1)
-        keep = k_pos <= q_pos
-    if q_pos is not None:
-        keep = _window_keep(keep, q_pos, cfg)
+        keep = _window_keep(k_pos <= q_pos, q_pos, cfg)
     return _attend(q, k, v, keep, cfg)
 
 
@@ -138,15 +136,6 @@ def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig) -> jax.Array:
     return dense(p["head"], rms_norm(p["ln"], hidden, cfg.layer_norm_eps))
 
 
-def _abs_q_pos(pos, s: int, prefill: bool):
-    """Absolute query positions [S_q, 1] for the cached attention's
-    sliding-window anchor: query row i sits at pos + i — prefill binds
-    pos=0 (the prompt rows), a decode step has s=1 at the traced `pos`,
-    and a span (speculative verify) step covers [pos, pos+s)."""
-    del prefill  # pos + offset covers every mode (prefill binds pos=0)
-    return jnp.asarray(pos) + jnp.arange(s)[:, None]
-
-
 def decode_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
     """Single decode-step token embed [B, 1, D]: wte row only (RoPE puts
     the position into the attention rotation, not the embedding)."""
@@ -186,9 +175,9 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
     pos_ids = jnp.asarray(pos) + jnp.arange(s)
     q, k_new, v_new = _qkv_rope(p, normed, cfg, pos_ids)
     k, v, keep, bcache = _cache_update_and_read(
-        bcache, k_new, v_new, pos, prefill, s, q.dtype, read_len=read_len)
-    ctx = _gqa_attend(q, k, v, cfg, keep=keep,
-                      q_pos=_abs_q_pos(pos, s, prefill))
+        bcache, k_new, v_new, pos, prefill, s, q.dtype, read_len=read_len,
+        window=cfg.sliding_window)
+    ctx = _gqa_attend(q, k, v, cfg, keep=keep)
     return _block_tail(p, x, ctx, cfg), bcache
 
 
@@ -203,20 +192,17 @@ def tp_cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
     from ..parallel.decode import _cache_update_and_read
     from ..parallel.tensor import _tp_llama_block_local
 
-    new_cache = {}
-
     def cache_attend(q, k_new, v_new):
-        k, v, keep, bc = _cache_update_and_read(
+        nonlocal bcache
+        k, v, keep, bcache = _cache_update_and_read(
             bcache, k_new, v_new, pos, prefill, x.shape[1], q.dtype,
-            read_len=read_len)
-        new_cache.update(bc)
-        return _gqa_attend(q, k, v, cfg, keep=keep,
-                           q_pos=_abs_q_pos(pos, x.shape[1], prefill))
+            read_len=read_len, window=cfg.sliding_window)
+        return _gqa_attend(q, k, v, cfg, keep=keep)
 
     pos_ids = jnp.asarray(pos) + jnp.arange(x.shape[1])
     y = _tp_llama_block_local(p, x, cfg, axis, qkv_to_ctx=cache_attend,
                               pos_ids=pos_ids)
-    return y, new_cache
+    return y, bcache
 
 
 def tp_finalize(pf: Dict, hidden, cfg: TransformerConfig, axis: str):

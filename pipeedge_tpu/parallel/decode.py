@@ -23,8 +23,8 @@ token-for-token (tests/test_decode.py).
 """
 from __future__ import annotations
 
-from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from functools import partial, reduce
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +38,72 @@ Cache = Dict[str, jax.Array]   # {'k': [L, B, T, H, Dh], 'v': [L, B, T, H, Dh]}
 # int8 variant adds per-(block, batch, position, head) scale/shift rows —
 # the head axis shards over 'tp' with the K/V buffers:
 #   {'k': int8, 'v': int8, 'k_scale'/'k_shift'/'v_scale'/'v_shift': [L, B, T, H]}
+#
+# A stage's cache is updated IN PLACE. Every jitted stage program (prefill
+# and decode step of the plain, tp, ep, tp x ep and sp makers) DONATES its
+# cache argument: the buffers handed in are dead after the call, and the
+# returned cache lives in the same memory. A caller rebinds
+# (`out, cache = step(params, data, cache, pos)`) and never reads the old
+# reference again; whoever must keep a cache across a call (a prefix
+# handle, a beam reshuffle) hands the program a copy (`_repeat_batch`,
+# `_gather_batch`). Inside a program a block step is handed a `LayerCache`
+# (the whole stack and its layer's index), and only
+# `_cache_update_and_read` and `_cache_write_quantized` open it: they read
+# the attended window of that layer and record the new rows, which
+# `_run_blocks` writes into the stack once the scan is done. A step moves
+# the new rows and the window, nothing else (docs/DECODE.md).
+
+
+class LayerCache(NamedTuple):
+    """What a block step holds of its stage's cache: the stacked buffers
+    (leaves `[L, B, T, ...]`, as they were before this step), the index of
+    the one layer it may read, and, once a cache function has run, the
+    `rows` (`[B, S, ...]` a leaf) this step writes at `[pos, pos + S)`."""
+    stack: Cache
+    layer: jax.Array
+    rows: Optional[Cache] = None
+
+
+def _read_window(buf: jax.Array, layer, width: int) -> jax.Array:
+    """Positions [0, width) of one layer of stacked `buf` -> [B, width, ...]."""
+    start = (layer,) + (0,) * (buf.ndim - 1)
+    return jax.lax.dynamic_slice(
+        buf, start, (1, buf.shape[1], width) + buf.shape[3:])[0]
+
+
+# positions in one tile of a stored leaf: the TPU keeps a `[L, B, T, H, Dh]`
+# leaf with T minor-most (no padding of a 64-wide head that way), tiled by 128
+_POS_TILE = 128
+
+
+def _write_rows(cache: Cache, rows: Cache, pos) -> Cache:
+    """Every layer's new `rows` (leaves `[L, B, S, ...]`) into the stacked
+    cache at positions [pos, pos + S): one in-place update a leaf.
+
+    A decode step's single row is one lane of each tile it touches, and
+    the bare row update runs at a fifth of the rate those tiles could be
+    rewritten at (PERF.md, PR 25). So where the position axis is whole
+    tiles, the step updates the whole tile of positions that holds `pos`:
+    sliced at an offset the compiler can see is aligned, the row selected
+    in, written back where it came from."""
+    def write(buf, new):
+        new = new.astype(buf.dtype)
+        tail = (0,) * (buf.ndim - 3)
+        if new.shape[2] != 1 or buf.shape[2] % _POS_TILE:
+            return jax.lax.dynamic_update_slice(buf, new, (0, 0, pos) + tail)
+        # by bits, not `//`, and with no wrap of a negative index: the
+        # compiler's known-bits analysis must see a whole number of tiles
+        base = pos & -_POS_TILE
+        tile = jax.lax.dynamic_slice(
+            buf, (0, 0, base) + tail,
+            buf.shape[:2] + (_POS_TILE,) + buf.shape[3:],
+            allow_negative_indices=False)
+        here = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 2) == pos - base
+        return jax.lax.dynamic_update_slice(
+            buf, jnp.where(here, new, tile), (0, 0, base) + tail,
+            allow_negative_indices=False)
+
+    return {name: write(buf, rows[name]) for name, buf in cache.items()}
 
 
 def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
@@ -96,44 +162,62 @@ def _qkv(p: Dict, normed: jax.Array, cfg: TransformerConfig):
             dense(p["v"], normed).reshape(b, s, h, hd))
 
 
-def _attend(q: jax.Array, k: jax.Array, v: jax.Array, keep: jax.Array,
-            cfg: TransformerConfig) -> jax.Array:
+def _parts(x) -> tuple:
+    """`_attend` takes its keys in one part or several: a bare array is one."""
+    return tuple(x) if isinstance(x, (tuple, list)) else (x,)
+
+
+def _attend(q: jax.Array, k, v, keep, cfg: TransformerConfig) -> jax.Array:
     """Masked attention of q [B,S,H,Dh] over k/v [B,T,H,Dh]; `keep`
-    [S, T] marks key positions each query may attend to."""
+    [S, T] marks key positions each query may attend to. k, v and keep
+    may each be a tuple of such parts (a cached step's window and its
+    fresh rows, `_cache_update_and_read`): one softmax runs over all
+    their keys, and no part is copied to sit beside another."""
     b, s, h, hd = q.shape
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(hd))
-    scores = jnp.where(keep[None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
-                     preferred_element_type=jnp.float32).astype(q.dtype)
-    return ctx.reshape(b, s, h * hd)
+    scores = []
+    for k_part, keep_part in zip(_parts(k), _parts(keep)):
+        part = jnp.einsum("bqhd,bkhd->bhqk", q, k_part,
+                          preferred_element_type=jnp.float32)
+        part = part / jnp.sqrt(jnp.float32(hd))
+        scores.append(jnp.where(keep_part[None, None], part, -1e30))
+    if len(scores) == 1:
+        probs = [jax.nn.softmax(scores[0], axis=-1)]
+    else:       # softmax over the parts' concatenation, not concatenated
+        top = reduce(jnp.maximum, [jnp.max(part, axis=-1, keepdims=True)
+                                   for part in scores])
+        probs = [jnp.exp(part - top) for part in scores]
+        total = sum(jnp.sum(part, axis=-1, keepdims=True) for part in probs)
+        probs = [part / total for part in probs]
+    ctx = sum(jnp.einsum("bhqk,bkhd->bqhd", p_part.astype(q.dtype), v_part,
+                         preferred_element_type=jnp.float32)
+              for p_part, v_part in zip(probs, _parts(v)))
+    return ctx.astype(q.dtype).reshape(b, s, h * hd)
 
 
-def _attend_width(bcache: Cache, read_len: Optional[int]) -> int:
+def _attend_width(bcache: LayerCache, read_len: Optional[int]) -> int:
     """Static attend-window width: the full cache, truncated to the
     bucketed `read_len` when one is bound — THE window formula, shared
     by the XLA read path and the Pallas kernel route so they can never
     attend different windows."""
-    t_max = bcache["k"].shape[1]
+    t_max = bcache.stack["k"].shape[2]
     return t_max if read_len is None else min(read_len, t_max)
 
 
-def _cache_write_quantized(bcache: Cache, k_new: jax.Array,
-                           v_new: jax.Array, start) -> Cache:
-    """Quantize the new K/V rows and write them (plus their per-(position,
-    head) scale/shift rows) at `start` — the single int8 write path,
-    shared by the XLA read path and the fused Pallas decode kernel."""
-    bcache = dict(bcache)
+def _cache_write_quantized(bcache: LayerCache, k_new: jax.Array,
+                           v_new: jax.Array, width: int) \
+        -> Tuple[LayerCache, Cache]:
+    """Quantize the new K/V rows (with their per-(position, head)
+    scale/shift rows) for writing, and read this layer's int8 window
+    [0, width) as a dict of `[B, width, ...]` leaves — the single int8
+    write path, shared by the XLA read path and the fused Pallas decode
+    kernel. The window is the cache as it was: both readers take the new
+    rows from the caller's hands, unquantized."""
+    rows = {}
     for t, new in (("k", k_new), ("v", v_new)):
-        qv, scale, shift = _quantize_rows(new)
-        bcache[t] = jax.lax.dynamic_update_slice(bcache[t], qv, start)
-        bcache[f"{t}_scale"] = jax.lax.dynamic_update_slice(
-            bcache[f"{t}_scale"], scale, start[:3])
-        bcache[f"{t}_shift"] = jax.lax.dynamic_update_slice(
-            bcache[f"{t}_shift"], shift, start[:3])
-    return bcache
+        rows[t], rows[f"{t}_scale"], rows[f"{t}_shift"] = _quantize_rows(new)
+    window = {name: _read_window(buf, bcache.layer, width)
+              for name, buf in bcache.stack.items()}
+    return bcache._replace(rows=rows), window
 
 
 # per-tensor int8 window bytes the kernel may stage in VMEM: the window
@@ -236,56 +320,62 @@ def _use_int8_decode_kernel(bcache: Cache, s: int, cfg: TransformerConfig,
     return (not int8_decode_attention_supported(), variant)
 
 
-def _cache_update_and_read(bcache: Cache, k_new: jax.Array, v_new: jax.Array,
-                           pos, prefill: bool, s: int, dtype,
-                           read_len: Optional[int] = None) \
-        -> Tuple[jax.Array, jax.Array, jax.Array, Cache]:
-    """Write the new K/V rows at [pos, pos+S) and return (k, v, keep, cache)
-    for attention over the cache window.
+def _cache_update_and_read(bcache: LayerCache, k_new: jax.Array,
+                           v_new: jax.Array, pos, prefill: bool, s: int,
+                           dtype, read_len: Optional[int] = None,
+                           window: int = 0):
+    """Record the new K/V rows for [pos, pos+S) of this layer and return
+    (k, v, keep, cache) for `_attend`: k, v and keep are tuples of two
+    parts, the cached window [0, width) as it was (one `dynamic_slice` a
+    leaf, kept only below `pos`) and the step's own rows (causal among
+    themselves). Nothing of a whole layer's shape is materialised, and the
+    window is not copied to have the rows put into it. A prefill has only
+    the second part: it attends its own rows and reads no cache.
 
-    `read_len` (STATIC) truncates the attend window to cache positions
+    `read_len` (STATIC) truncates the window to cache positions
     [0, read_len): the caller guarantees pos < read_len, and positions
     beyond it were fully masked anyway (their softmax columns are exact
     zeros), so truncation is numerically identical while the attend
     matmul and (for int8 caches) the dequantize shrink from max_len to
     read_len — the bucketed decode-step optimization
-    (DecodePipeline::attend_bucket)."""
+    (DecodePipeline::attend_bucket). `window` (STATIC, 0 = off) is a
+    sliding attention window: a query at q attends (q - window, q]."""
     width = _attend_width(bcache, read_len)
-    quantized = "k_scale" in bcache
-    start = (0, 0, 0, 0) if prefill else (0, pos, 0, 0)
+    quantized = "k_scale" in bcache.stack
     if quantized:
-        bcache = _cache_write_quantized(bcache, k_new, v_new, start)
-        # dequantize only the attended window
-        k = _dequantize_rows(bcache["k"][:, :width],
-                             bcache["k_scale"][:, :width],
-                             bcache["k_shift"][:, :width], dtype)
-        v = _dequantize_rows(bcache["v"][:, :width],
-                             bcache["v_scale"][:, :width],
-                             bcache["v_shift"][:, :width], dtype)
+        bcache, win = _cache_write_quantized(bcache, k_new, v_new, width)
         # the freshly computed rows are in hand — attend over them exactly;
         # quantization error applies only to genuinely cached positions
-        k = jax.lax.dynamic_update_slice(k, k_new.astype(dtype), start)
-        v = jax.lax.dynamic_update_slice(v, v_new.astype(dtype), start)
+        k_new, v_new = k_new.astype(dtype), v_new.astype(dtype)
     else:
-        bcache = dict(bcache)   # don't mutate the caller's dict
-        for t, new in (("k", k_new), ("v", v_new)):
-            bcache[t] = jax.lax.dynamic_update_slice(
-                bcache[t], new.astype(bcache[t].dtype), start)
-        k = bcache["k"][:, :width].astype(dtype)
-        v = bcache["v"][:, :width].astype(dtype)
+        stack = bcache.stack
+        # through the cache's dtype, as if read back from it
+        k_new = k_new.astype(stack["k"].dtype).astype(dtype)
+        v_new = v_new.astype(stack["v"].dtype).astype(dtype)
+        bcache = bcache._replace(rows={"k": k_new, "v": v_new})
+    # query i sits at absolute position pos + i (a prefill has pos 0, the
+    # classic decode step s == 1, a SPAN step, the speculative verify,
+    # s > 1) and attends every cached row below pos and rows [0, i] of
+    # its own step
+    q_off = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
+    k_off = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
+    keep_new = k_off <= q_off
+    if window:
+        keep_new &= k_off > q_off - window
     if prefill:
-        q_pos = jax.lax.broadcasted_iota(jnp.int32, (s, width), 0)
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, (s, width), 1)
-        keep = k_pos <= q_pos          # causal within the prompt
+        return (k_new,), (v_new,), (keep_new,), bcache
+    if quantized:   # dequantize only the attended window
+        k = _dequantize_rows(win["k"], win["k_scale"], win["k_shift"], dtype)
+        v = _dequantize_rows(win["v"], win["v_scale"], win["v_shift"], dtype)
     else:
-        # s == 1: the classic decode step (attend [0, pos]); s > 1: a
-        # SPAN step (speculative-decoding verify) — query i sits at
-        # absolute position pos + i and attends [0, pos + i], causal
-        # within the span exactly like prefill but offset by pos
+        k = _read_window(stack["k"], bcache.layer, width).astype(dtype)
+        v = _read_window(stack["v"], bcache.layer, width).astype(dtype)
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, (s, width), 1)
+    keep = k_pos < pos
+    if window:
         q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, (s, width), 0)
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, (s, width), 1)
-        keep = k_pos <= q_pos
-    return k, v, keep, bcache
+        keep &= k_pos > q_pos - window
+    return (k, k_new), (v, v_new), (keep, keep_new), bcache
 
 
 def _block_tail(p: Dict, x: jax.Array, ctx: jax.Array,
@@ -311,11 +401,11 @@ def _block_tail(p: Dict, x: jax.Array, ctx: jax.Array,
     return dense(p["mlp_down"], gelu_new(dense(p["mlp_up"], normed))) + x
 
 
-def _attention_core(p: Dict, x: jax.Array, bcache: Cache, pos,
+def _attention_core(p: Dict, x: jax.Array, bcache: LayerCache, pos,
                     cfg: TransformerConfig, prefill: bool,
                     read_len: Optional[int] = None,
                     int8_optin: int = 0) \
-        -> Tuple[jax.Array, Cache]:
+        -> Tuple[jax.Array, LayerCache]:
     """ln + qkv + cache update + masked attend: the cached attention half
     shared by the plain and expert-parallel decode steps. `int8_optin` is
     the construction-time PIPEEDGE_INT8_DECODE_ATTEND resolution (bound
@@ -324,19 +414,17 @@ def _attention_core(p: Dict, x: jax.Array, bcache: Cache, pos,
     see _use_int8_decode_kernel)."""
     normed = layer_norm(p["ln_before"], x, cfg.layer_norm_eps)
     q, k_new, v_new = _qkv(p, normed, cfg)
-    w = _attend_width(bcache, read_len) if "k" in bcache else 0
+    w = _attend_width(bcache, read_len)
     route = (None if prefill
-             else _use_int8_decode_kernel(bcache, x.shape[1], cfg, w,
+             else _use_int8_decode_kernel(bcache.stack, x.shape[1], cfg, w,
                                           int8_optin, batch=x.shape[0]))
     if route is not None:
         from ..ops.decode_attention import int8_decode_attention
         interpret, variant = route
-        bcache = _cache_write_quantized(bcache, k_new, v_new,
-                                        (0, pos, 0, 0))
+        bcache, win = _cache_write_quantized(bcache, k_new, v_new, w)
         ctx = int8_decode_attention(
-            q, bcache["k"][:, :w], bcache["k_scale"][:, :w],
-            bcache["k_shift"][:, :w], bcache["v"][:, :w],
-            bcache["v_scale"][:, :w], bcache["v_shift"][:, :w],
+            q, win["k"], win["k_scale"], win["k_shift"], win["v"],
+            win["v_scale"], win["v_shift"],
             k_new, v_new, pos, interpret=interpret, variant=variant)
         return ctx, bcache
     k, v, keep, bcache = _cache_update_and_read(
@@ -345,25 +433,26 @@ def _attention_core(p: Dict, x: jax.Array, bcache: Cache, pos,
     return _attend(q, k, v, keep, cfg), bcache
 
 
-def _block_step(p: Dict, x: jax.Array, bcache: Cache, pos,
+def _block_step(p: Dict, x: jax.Array, bcache: LayerCache, pos,
                 cfg: TransformerConfig, prefill: bool,
                 read_len: Optional[int] = None,
-                int8_optin: int = 0) -> Tuple[jax.Array, Cache]:
+                int8_optin: int = 0) -> Tuple[jax.Array, LayerCache]:
     """One GPT-2 block over current token(s) with cache read/update.
 
     Prefill: x is the full prompt [B, S, D] written at positions [0, S);
     decode: x is one token [B, 1, D] written at position `pos`. `bcache`
-    is this block's cache slice {k, v[, *_scale, *_shift]}. `read_len`:
+    is the stage's stacked cache and this block's index in it. `read_len`:
     static attend-window truncation (see _cache_update_and_read)."""
     ctx, bcache = _attention_core(p, x, bcache, pos, cfg, prefill,
                                   read_len=read_len, int8_optin=int8_optin)
     return _block_tail(p, x, ctx, cfg), bcache
 
 
-def _block_step_tp(p: Dict, x: jax.Array, bcache: Cache, pos,
+def _block_step_tp(p: Dict, x: jax.Array, bcache: LayerCache, pos,
                    cfg: TransformerConfig, prefill: bool,
                    axis: str, act=gelu_new, ffn_delta=None,
-                   read_len: Optional[int] = None) -> Tuple[jax.Array, Cache]:
+                   read_len: Optional[int] = None) \
+        -> Tuple[jax.Array, LayerCache]:
     """Megatron tensor-parallel block step under `shard_map`: the shared
     projection/psum/MLP body from parallel/tensor.py with the attention
     core swapped for a cache-attend over the head-sharded KV cache.
@@ -372,18 +461,16 @@ def _block_step_tp(p: Dict, x: jax.Array, bcache: Cache, pos,
     unsharded, so truncation is per-shard local)."""
     from .tensor import _tp_block_local
 
-    new_cache = {}
-
     def cache_attend(q, k_new, v_new):
-        k, v, keep, bc = _cache_update_and_read(
+        nonlocal bcache
+        k, v, keep, bcache = _cache_update_and_read(
             bcache, k_new, v_new, pos, prefill, x.shape[1], q.dtype,
             read_len=read_len)
-        new_cache.update(bc)
         return _attend(q, k, v, keep, cfg)      # [b, s, h_local * hd]
 
     y = _tp_block_local(p, x, cfg, axis, act=act,
                         qkv_to_ctx=cache_attend, ffn_delta=ffn_delta)
-    return y, new_cache
+    return y, bcache
 
 
 def single_token_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
@@ -431,12 +518,27 @@ def attend_bucket(pos_next: int, max_len: int, floor: int = 64) -> int:
 
 def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
                 prefill: bool, block_fn=_block_step) -> Tuple[jax.Array, Cache]:
-    def body(carry, xs):
-        bp, bc = xs
-        y, bc = block_fn(bp, carry, bc, pos, cfg, prefill)
-        return y, bc
+    """Scan the stage's blocks over x. The scan only READS the stacked
+    cache (each block its layer's window) and stacks the blocks' new rows;
+    one update a leaf then writes them where the donated buffer's layout
+    is the program's own. The stack must not be the scan's carry: the TPU
+    compiler lays a carried buffer out to suit the rows written into it and
+    copies the whole cache into and out of that layout around the loop
+    (PERF.md, PR 25)."""
+    def body(y, xs):
+        bp, layer = xs
+        y, bc = block_fn(bp, y, LayerCache(cache, layer), pos, cfg, prefill)
+        return y, bc.rows
 
-    return jax.lax.scan(body, x, (blocks, cache))
+    n_blocks = next(iter(cache.values())).shape[0]
+    x, rows = jax.lax.scan(body, x, (blocks, jnp.arange(n_blocks)))
+    return x, _write_rows(cache, rows, 0 if prefill else pos)
+
+
+# every stage program takes (params, data, cache[, pos]) and donates the
+# cache: XLA aliases it to the returned cache, so the rows are written in
+# place and a step holds one copy of the cache, not two
+_DONATE_CACHE = (2,)
 
 
 def make_stage_fns(family, cfg: TransformerConfig, shard_config: ShardConfig,
@@ -445,6 +547,8 @@ def make_stage_fns(family, cfg: TransformerConfig, shard_config: ShardConfig,
 
     prefill_fn(params, data, cache)        -> (out, cache)   data: ids|hidden
     decode_fn(params, data, cache, pos)    -> (out, cache)   data: ids|hidden
+
+    Both DONATE `cache`: the caller rebinds and drops the old reference.
 
     First stage embeds token ids (decode positions offset by `pos`); last
     stage applies the final LN + LM head and returns per-token logits.
@@ -462,11 +566,12 @@ def make_stage_fns(family, cfg: TransformerConfig, shard_config: ShardConfig,
         return run(params, data, cache, pos, prefill=False,
                    read_len=read_len)
 
-    prefill_fn = jax.jit(prefill)
+    prefill_fn = jax.jit(prefill, donate_argnums=_DONATE_CACHE)
     # read_len is STATIC: each attend-window bucket compiles its own
     # decode-step program (a handful of power-of-2 variants, the same
     # compile-per-discrete-value pattern as the quantized edge bitwidths)
-    decode_fn = jax.jit(decode_step, static_argnames=("read_len",))
+    decode_fn = jax.jit(decode_step, static_argnames=("read_len",),
+                        donate_argnums=_DONATE_CACHE)
     return prefill_fn, decode_fn
 
 
@@ -620,12 +725,14 @@ def make_tp_stage_fns(family, cfg: TransformerConfig,
 
     prefill_fn = jax.jit(jax_compat.shard_map(
         tp_prefill, mesh=mesh,
-        in_specs=(p_specs, P(), c_specs), out_specs=(P(), c_specs)))
+        in_specs=(p_specs, P(), c_specs), out_specs=(P(), c_specs)),
+        donate_argnums=_DONATE_CACHE)
 
     # the bucketed attend window is bound into the shard_map closure per
     # static read_len value — jit re-traces per bucket, same
     # compile-per-discrete-value pattern as the plain path
-    @partial(jax.jit, static_argnames=("read_len",))
+    @partial(jax.jit, static_argnames=("read_len",),
+             donate_argnums=_DONATE_CACHE)
     def tp_decode_step(params, data, cache, pos, read_len=None):
         return jax_compat.shard_map(
             partial(run, prefill=False, read_len=read_len), mesh=mesh,
@@ -777,10 +884,12 @@ def make_ep_stage_fns(family, cfg: TransformerConfig,
 
     prefill_fn = jax.jit(jax_compat.shard_map(
         ep_prefill, mesh=mesh,
-        in_specs=(p_specs, P(), c_specs), out_specs=(P(), c_specs)))
+        in_specs=(p_specs, P(), c_specs), out_specs=(P(), c_specs)),
+        donate_argnums=_DONATE_CACHE)
     decode_fn = jax.jit(jax_compat.shard_map(
         ep_decode_step, mesh=mesh,
-        in_specs=(p_specs, P(), c_specs, P()), out_specs=(P(), c_specs)))
+        in_specs=(p_specs, P(), c_specs, P()), out_specs=(P(), c_specs)),
+        donate_argnums=_DONATE_CACHE)
     return prefill_fn, decode_fn, p_specs
 
 
@@ -862,10 +971,12 @@ def make_tp_ep_stage_fns(family, cfg: TransformerConfig,
 
     prefill_fn = jax.jit(jax_compat.shard_map(
         tp_ep_prefill, mesh=mesh,
-        in_specs=(p_specs, P(), c_specs), out_specs=(P(), c_specs)))
+        in_specs=(p_specs, P(), c_specs), out_specs=(P(), c_specs)),
+        donate_argnums=_DONATE_CACHE)
     decode_fn = jax.jit(jax_compat.shard_map(
         tp_ep_decode_step, mesh=mesh,
-        in_specs=(p_specs, P(), c_specs, P()), out_specs=(P(), c_specs)))
+        in_specs=(p_specs, P(), c_specs, P()), out_specs=(P(), c_specs)),
+        donate_argnums=_DONATE_CACHE)
     return prefill_fn, decode_fn, p_specs
 
 
@@ -922,12 +1033,11 @@ def make_sp_prefill_fn(family, cfg: TransformerConfig,
     def cache_gather(bcache, k_new, v_new):
         """All-gather this chunk's K/V rows into the (replicated) stage
         cache — shared by the default and family sp block steps."""
-        bcache = dict(bcache)
-        for t, new in (("k", k_new), ("v", v_new)):
-            full = jax.lax.all_gather(new, axis, axis=1, tiled=True)
-            bcache[t] = jax.lax.dynamic_update_slice(
-                bcache[t], full.astype(bcache[t].dtype), (0, 0, 0, 0))
-        return bcache
+        k_full, v_full = (jax.lax.all_gather(new, axis, axis=1, tiled=True)
+                          for new in (k_new, v_new))
+        # rows with no read: the window it also returns is dead code
+        return _cache_update_and_read(bcache, k_full, v_full, 0, True,
+                                      k_full.shape[1], k_full.dtype)[3]
 
     if fam_sp_block is not None:
         def block_prefill(p, x, bcache, pos, cfg_, prefill):
@@ -972,7 +1082,8 @@ def make_sp_prefill_fn(family, cfg: TransformerConfig,
 
     return jax.jit(jax_compat.shard_map(
         sp_prefill, mesh=mesh,
-        in_specs=(P(), edge_in, P()), out_specs=(edge_out, P())))
+        in_specs=(P(), edge_in, P()), out_specs=(edge_out, P())),
+        donate_argnums=_DONATE_CACHE)
 
 
 def build_decode_pipeline(model_name: str,

@@ -143,8 +143,16 @@ class SpmdDecodePipeline:
             j, bp, bc = xs
 
             def live(args):
+                # the block steps read a stacked cache by layer index and
+                # hand back the rows to write (decode.LayerCache): give
+                # them this layer as a stack of one
                 c, cache_j = args
-                return block_fn(bp, c, cache_j, pos, cfg, prefill)
+                one = jax.tree_util.tree_map(lambda a: a[None], cache_j)
+                y, bc = block_fn(bp, c, dec.LayerCache(one, 0), pos, cfg,
+                                 prefill)
+                rows = jax.tree_util.tree_map(lambda a: a[None], bc.rows)
+                one = dec._write_rows(one, rows, 0 if prefill else pos)
+                return y, jax.tree_util.tree_map(lambda a: a[0], one)
 
             out, bc_new = jax.lax.cond(
                 j < n_valid, live, lambda args: args, (carry, bc))

@@ -89,6 +89,24 @@ def test_single_request_loses_nothing_vs_solo(tiny_pipe):
     assert batcher.stats["ticks"] == N * K
 
 
+def test_paged_requests_outlive_donated_steps(tiny_pipe):
+    """`--kv-pages`: every stage step gathers a request's pages into a
+    cache view, hands it to a program that donates it, and scatters the
+    returned view back (kv/backend.py). Two interleaved requests stay
+    token-identical to their solo runs."""
+    from pipeedge_tpu.kv import PagedKvBackend
+    from pipeedge_tpu.telemetry import metrics as prom
+    kv = PagedKvBackend(tiny_pipe, 24, 4, registry=prom.Registry())
+    batcher = ContinuousBatcher(tiny_pipe, kv=kv)
+    prompts = _prompts(2, lens=(7, 5))
+    for i, ids in enumerate(prompts):
+        batcher.submit(i, ids, new_tokens=6)
+    results = batcher.run()
+    for i, ids in enumerate(prompts):
+        np.testing.assert_array_equal(
+            results[i], np.asarray(tiny_pipe.generate(ids, new_tokens=6)))
+
+
 def test_ready_queue_admission_and_heterogeneous_requests(tiny_pipe):
     """More requests than active slots, mixed prompt lengths and token
     budgets: completions free cache slots for pending requests; every

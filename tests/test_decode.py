@@ -35,6 +35,33 @@ def _stage_params(cfg, partition, weights):
         for l, r in partition]
 
 
+def _beam_oracle(cfg, weights, prompt, beams, steps):
+    """Step-by-step numpy beam search for one prompt [S]: a full (no-cache)
+    forward per hypothesis, exactly `generate_beam`'s semantics."""
+    total = 4 * cfg.num_hidden_layers
+    sc = ShardConfig(1, total, is_first=True, is_last=True)
+    params = gpt2_mod.load_params(cfg, sc, weights)
+    from pipeedge_tpu.models.shard import make_shard_fn
+    fn = make_shard_fn(gpt2_mod.FAMILY, cfg, sc)
+
+    def logprobs(seqs):   # [N, S] -> [N, V] next-token log-probs
+        logits = np.asarray(fn(params, jnp.asarray(seqs, jnp.int32)))
+        x = logits[:, -1].astype(np.float64)
+        x = x - x.max(axis=-1, keepdims=True)
+        return x - np.log(np.exp(x).sum(axis=-1, keepdims=True))
+
+    lp = logprobs(prompt[None])[0]
+    hyps = [(lp[t], [int(t)]) for t in np.argsort(-lp)[:beams]]
+    for _ in range(steps - 1):
+        seqs = np.stack([np.concatenate([prompt, h[1]]) for h in hyps])
+        lps = logprobs(seqs)
+        cand = [(h[0] + lps[i][t], h[1] + [int(t)])
+                for i, h in enumerate(hyps) for t in range(cfg.vocab_size)]
+        cand.sort(key=lambda c: -c[0])
+        hyps = cand[:beams]
+    return np.asarray(hyps[0][1])
+
+
 @pytest.mark.parametrize("partition", [
     [(1, 12)],
     [(1, 4), (5, 12)],
@@ -158,31 +185,9 @@ def test_beam_search_matches_oracle(gpt2_setup):
     beams, steps = 3, 4
     got = np.asarray(pipe.generate_beam(ids, steps, beams=beams))
 
-    # oracle: full forward per hypothesis, exact same beam semantics
-    total = 4 * cfg.num_hidden_layers
-    sc = ShardConfig(1, total, is_first=True, is_last=True)
-    params = gpt2_mod.load_params(cfg, sc, weights)
-    from pipeedge_tpu.models.shard import make_shard_fn
-    fn = make_shard_fn(gpt2_mod.FAMILY, cfg, sc)
-
-    def logprobs(seqs):   # [N, S] -> [N, V] next-token log-probs
-        logits = np.asarray(fn(params, jnp.asarray(seqs, jnp.int32)))
-        x = logits[:, -1].astype(np.float64)
-        x = x - x.max(axis=-1, keepdims=True)
-        return x - np.log(np.exp(x).sum(axis=-1, keepdims=True))
-
     for b in range(ids.shape[0]):
-        lp = logprobs(ids[b:b + 1])[0]
-        order = np.argsort(-lp)[:beams]
-        hyps = [(lp[t], [int(t)]) for t in order]
-        for _ in range(steps - 1):
-            seqs = np.stack([np.concatenate([ids[b], h[1]]) for h in hyps])
-            lps = logprobs(seqs)
-            cand = [(h[0] + lps[i][t], h[1] + [int(t)])
-                    for i, h in enumerate(hyps) for t in range(cfg.vocab_size)]
-            cand.sort(key=lambda c: -c[0])
-            hyps = cand[:beams]
-        np.testing.assert_array_equal(got[b, 6:], np.asarray(hyps[0][1]))
+        np.testing.assert_array_equal(
+            got[b, 6:], _beam_oracle(cfg, weights, ids[b], beams, steps))
 
 
 @pytest.mark.slow
@@ -495,3 +500,116 @@ def test_bucketed_attend_crosses_buckets(gpt2_setup):
     np.testing.assert_array_equal(
         np.asarray(tp_int8_bucketed.generate(ids, new)),
         np.asarray(int8_full.generate(ids, new)))
+
+
+def _long_pipe(max_len, seed, **kw):
+    """A one-stage tiny GPT-2 with 512 positions and seeded random weights
+    (the HF fixture has 64): room for a cache several attend buckets long."""
+    cfg = TransformerConfig(model_type="gpt2", **TINY, layer_norm_eps=1e-5,
+                            vocab_size=100, max_position_embeddings=512)
+    sc = ShardConfig(1, 12, is_first=True, is_last=True)
+    return decode.DecodePipeline(
+        gpt2_mod.FAMILY, cfg, [(1, 12)],
+        [gpt2_mod.init_params(cfg, sc, seed=seed)], max_len=max_len, **kw)
+
+
+@pytest.mark.parametrize("tp", [False, True], ids=["plain", "tp"])
+@pytest.mark.parametrize("cache_bits", [0, 8], ids=["fp", "int8"])
+def test_decode_step_updates_cache_in_place(cache_bits, tp):
+    """The compiled decode step of a stage holds ONE copy of its cache and
+    moves none of it but the rows and the window: every cache leaf is
+    aliased input to output, the donated input is dead after the call,
+    and with `max_len` eight times the attend bucket the program's
+    temporaries stay under a single layer's cache."""
+    import re
+
+    import jax
+    from jax.sharding import Mesh
+    pipe = _long_pipe(
+        512, seed=3, cache_bits=cache_bits,
+        mesh=Mesh(np.array(jax.devices()[:2]), ("tp",)) if tp else None)
+    st = pipe.stages[0]
+    batch, pos = 2, 40
+    [cache] = pipe._fresh_caches(batch)
+    tok = jnp.zeros((batch, 1), jnp.int32)
+    assert pipe._read_len(pos) == 64
+    compiled = st["decode"].lower(st["params"], tok, cache, pos,
+                                  read_len=64).compile()
+
+    header = compiled.as_text().split("\n", 1)[0]
+    aliased = re.findall(r"\{\d+\}: \(\d+, \{\}, (?:may|must)-alias\)",
+                         header)
+    assert len(aliased) == len(cache), header
+    on_device = sum(leaf.addressable_shards[0].data.nbytes
+                    for leaf in cache.values())
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == on_device
+    assert mem.temp_size_in_bytes < on_device // st["n_blocks"], mem
+
+    _, new = pipe._decode_step(st, tok, cache, pos)
+    assert all(leaf.is_deleted() for leaf in cache.values())
+    assert not any(leaf.is_deleted() for leaf in new.values())
+
+
+def test_prefix_handle_outlives_donated_steps(gpt2_setup):
+    """A prefix handle keeps its caches across calls that donate theirs:
+    at batch 1, where tiling the handle's rows repeats them once and must
+    still copy, two generate calls off one handle both give the tokens of
+    the whole prompt prefilled at once."""
+    cfg, weights, _ = gpt2_setup
+    partition = [(1, 4), (5, 12)]
+    pipe = decode.DecodePipeline(
+        gpt2_mod.FAMILY, cfg, partition,
+        _stage_params(cfg, partition, weights), max_len=32)
+    ids = np.asarray(
+        np.random.default_rng(61).integers(0, 100, size=(1, 9)), np.int64)
+    want = np.asarray(pipe.generate(ids, 6))[:, 5:]
+    handle = pipe.precompute_prefix(ids[0, :5])
+    for _ in range(2):
+        got = np.asarray(pipe.generate(ids[:, 5:], 6, prefix=handle))
+        np.testing.assert_array_equal(got, want)
+    assert not any(leaf.is_deleted() for c in handle["caches"]
+                   for leaf in c.values())
+
+
+def test_beam_reshuffle_outlives_donated_steps(gpt2_setup):
+    """Beam search tiles the prefill's caches and regathers them by parent
+    beam between steps that donate them; the tokens are the oracle's."""
+    cfg, weights, _ = gpt2_setup
+    pipe = decode.DecodePipeline(
+        gpt2_mod.FAMILY, cfg, [(1, 12)],
+        _stage_params(cfg, [(1, 12)], weights), max_len=32)
+    ids = np.asarray(
+        np.random.default_rng(52).integers(0, 100, size=(1, 5)), np.int64)
+    got = np.asarray(pipe.generate_beam(ids, 4, beams=2))
+    np.testing.assert_array_equal(
+        got[0, 5:], _beam_oracle(cfg, weights, ids[0], 2, 4))
+
+
+@pytest.mark.parametrize("cache_bits", [0, 8], ids=["fp", "int8"])
+def test_tile_write_matches_row_write(cache_bits):
+    """Where the cache's position axis is whole tiles of 128 a decode step
+    rewrites the tile that holds its row (`_write_rows`), elsewhere the row
+    alone: the same cache rows and logits either way (to the rounding of
+    two window widths), across a tile's edge, and nothing written beyond
+    the row."""
+    rng = np.random.default_rng(71)
+    ids = jnp.asarray(rng.integers(0, 100, size=(2, 120)), jnp.int32)
+    steps = rng.integers(0, 100, size=(16, 2, 1))
+
+    def run(max_len):
+        pipe = _long_pipe(max_len, seed=5, cache_bits=cache_bits)
+        _, [cache] = pipe._prefill(ids)
+        for i, tok in enumerate(steps):
+            out, cache = pipe._decode_step(
+                pipe.stages[0], jnp.asarray(tok, jnp.int32), cache, 120 + i)
+        return np.asarray(out), {k: np.asarray(v) for k, v in cache.items()}
+
+    (out_tile, tiled), (out_row, rowed) = run(256), run(200)
+    np.testing.assert_allclose(out_tile, out_row, atol=1e-5)
+    for name, leaf in tiled.items():
+        np.testing.assert_allclose(
+            leaf[:, :, :200].astype(np.float32),
+            rowed[name].astype(np.float32), rtol=1e-4,
+            atol=1 if leaf.dtype == np.int8 else 1e-5)
+        assert leaf[:, :, :136].any() and not leaf[:, :, 136:].any(), name

@@ -66,6 +66,21 @@ def test_spec_greedy_exact_gpt2(gpt2_pipes, gamma, batch):
     assert 0.0 <= spec.last_acceptance_rate <= 1.0
 
 
+@pytest.mark.parametrize("sync", ["host", "device"])
+def test_spec_rejected_round_outlives_donated_steps(gpt2_pipes, sync):
+    """Both pipelines donate their caches to every span and step: after a
+    round that rejects, the target's cache holds rows past the accepted
+    ones and the draft's is rewound by position only. Tokens stay the
+    target's own, in both sync modes."""
+    target, draft = gpt2_pipes
+    ids = _ids(2, 8, seed=3)
+    want = np.asarray(target.generate(ids, 14))
+    spec = SpeculativeDecoder(target, draft, gamma=4, sync=sync)
+    got = np.asarray(spec.generate(ids, 14))
+    np.testing.assert_array_equal(got, want)
+    assert spec.last_acceptance_rate < 1.0      # some round rejected
+
+
 def test_spec_self_draft_accepts_everything(gpt2_pipes):
     """Draft == target: every proposal matches, acceptance 1.0, each
     round commits gamma+1 tokens."""
