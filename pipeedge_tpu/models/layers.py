@@ -20,7 +20,8 @@ class TransformerConfig:
     """Static model hyperparameters (stands in for HF `AutoConfig`, which the
     reference fetches over the network — model_cfg.py:57-66; here configs are
     local constants so the framework runs with zero egress)."""
-    model_type: str              # 'vit' | 'bert' | 'deit' | 'gpt2'
+    model_type: str              # the family: 'vit' | 'bert' | 'deit' |
+    #                              'gpt2' | 'llama' | 'keye'
     hidden_size: int
     num_hidden_layers: int       # transformer blocks (sublayers = 4x this)
     num_attention_heads: int
@@ -45,10 +46,33 @@ class TransformerConfig:
     # sliding-window attention (Mistral-style): each position attends to
     # the last `sliding_window` positions (incl. itself); 0 = full causal
     sliding_window: int = 0
+    # width of one attention head where the model states it apart from
+    # hidden_size // heads (0 = that quotient): `head_dim` below
+    attn_head_dim: int = 0
+    # top-k routed experts without drops (keye family; `n_experts` counts
+    # them): the experts' own width, experts a token, and whether the kept
+    # gates are renormalised to sum to one
+    moe_intermediate_size: int = 0
+    num_experts_per_tok: int = 0
+    norm_topk_prob: bool = False
+    # per-head RMSNorm of q and k before the rotation
+    qk_norm: bool = False
+    # M-RoPE: how many of a head's rotary frequencies turn with the
+    # temporal, height and width position; () = one position row
+    mrope_section: tuple = ()
+    # learned sparse attention: an indexer of `index_heads` heads of
+    # `index_head_dim` scores every live position and the query attends
+    # the `index_topk` best (0 = attend all). `index_q_chunk` is the span
+    # a prompt is prefilled in.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_q_chunk: int = 0
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+        return self.attn_head_dim \
+            or self.hidden_size // self.num_attention_heads
 
     @property
     def kv_heads(self) -> int:
@@ -196,6 +220,40 @@ def dense(p, x: jax.Array, tag: Optional[str] = None) -> jax.Array:
     return (y + p["b"]).astype(x.dtype)
 
 
+def exact_dot(x: jax.Array, w: jax.Array, w_contract: int = 0) -> jax.Array:
+    """x [..., K] times w over w's axis `w_contract` -> float32 [..., N].
+
+    Float32 activations over narrower weights (bfloat16 as stored): x is
+    split into three bfloat16 parts that add up to it to 24 bits, the parts
+    are stacked as rows of ONE product with w as it lies (read once, never
+    widened), and the three results are added in float32. Every product of
+    two bfloat16 values is exact in float32, so this is the accuracy of
+    `Precision.HIGHEST` at three passes instead of six. Both float32: one
+    product at HIGHEST. Otherwise (both narrow): the plain product,
+    accumulated in float32."""
+    def dot(a, precision=None):
+        return jax.lax.dot_general(
+            a, w, (((a.ndim - 1,), (w_contract,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+
+    if x.dtype != jnp.float32:
+        return dot(x.astype(w.dtype))
+    if w.dtype == jnp.float32:
+        return dot(x, jax.lax.Precision.HIGHEST)
+    # `reduce_precision` and not a cast there and back: inside a compiled
+    # program the chip's compiler may keep the excess precision of such a
+    # pair, the remainder is then zero and the product a single bfloat16
+    # pass (1.7e-3 off where this is 1e-7; my chip runs, PR 27)
+    parts, rest = [], x
+    for _ in range(3):
+        part = jax.lax.reduce_precision(rest, exponent_bits=8,
+                                        mantissa_bits=7)
+        parts.append(part.astype(w.dtype))
+        rest = rest - part
+    whole = dot(jnp.stack(parts))             # [3, ..., N]
+    return whole[0] + whole[1] + whole[2]
+
+
 def _use_fused_attention(seq_len: int) -> bool:
     """Pallas fused attention: on TPU for long sequences, where streaming the
     [S, S] scores through VMEM beats XLA (measured ~5x at S=8192); for short
@@ -217,14 +275,21 @@ def rms_norm(p, x: jax.Array, eps: float) -> jax.Array:
     return (normed * p["scale"]).astype(x.dtype)
 
 
+def rope_frequencies(head_dim: int, theta: float):
+    """The rotation's head_dim/2 frequencies, float32, computed on the host
+    (HF's formula): a constant of the program. The chip's own `pow` is a
+    few 1e-6 off, which at position 16,000 is hundredths of a radian."""
+    import numpy as np
+    return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                            / head_dim))
+
+
 def rope_rotate(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     """Rotary position embedding on [B, S, H, Dh] at positions `pos` [S]
     (HF llama convention: half-split rotate, angles in float32, one
     frequency per pair duplicated across the two halves)."""
-    hd = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32)
-                                / hd))
-    angles = pos.astype(jnp.float32)[:, None] * inv_freq[None]   # [S, hd/2]
+    angles = pos.astype(jnp.float32)[:, None] \
+        * rope_frequencies(x.shape[-1], theta)[None]             # [S, hd/2]
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)        # [S, hd]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

@@ -24,6 +24,7 @@ from .shard import make_shard_fn, unstack_blocks
 from . import bert as bert_mod
 from . import deit as deit_mod
 from . import gpt2 as gpt2_mod
+from . import keye as keye_mod
 from . import llama as llama_mod
 from . import vit as vit_mod
 
@@ -80,6 +81,22 @@ def _llama(name, layers, weights, hidden, blocks, heads, kv_heads, inter,
         sliding_window=window))
 
 
+def _keye(name, weights, hidden, blocks, heads, kv_heads, head_dim, vocab,
+          max_pos, experts, expert_width, per_tok, index, mrope,
+          theta=1e7):
+    index_heads, index_head_dim, topk, q_chunk = index
+    return ModelEntry(name, 4 * blocks, weights, keye_mod, TransformerConfig(
+        model_type="keye", hidden_size=hidden, num_hidden_layers=blocks,
+        num_attention_heads=heads, num_kv_heads=kv_heads,
+        attn_head_dim=head_dim, intermediate_size=0, layer_norm_eps=1e-6,
+        vocab_size=vocab, max_position_embeddings=max_pos, rope_theta=theta,
+        n_experts=experts, moe_intermediate_size=expert_width,
+        num_experts_per_tok=per_tok, norm_topk_prob=True, qk_norm=True,
+        mrope_section=tuple(mrope), index_heads=index_heads,
+        index_head_dim=index_head_dim, index_topk=topk,
+        index_q_chunk=q_chunk))
+
+
 _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
     _vit("google/vit-base-patch16-224", 48, "ViT-B_16-224.npz", 768, 12, 12, 3072, 1000),
     _vit("google/vit-large-patch16-224", 96, "ViT-L_16-224.npz", 1024, 24, 16, 4096, 1000),
@@ -109,6 +126,12 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
     # HF state-dict layout; the window is a mask, not a weight change)
     _llama("mistralai/Mistral-7B-v0.1", 128, "Mistral-7B.npz", 4096, 32,
            32, 8, 14336, vocab=32000, max_pos=32768, window=4096),
+    # Keye-VL-2.0's language model: Qwen3-MoE blocks (128 experts, 8 a
+    # token, no drops) whose attention reads a learned top-2048 selection
+    _keye("Kwai-Keye/Keye-VL-2.0-30B-A3B", "Keye-VL-2.0-30B-A3B.npz", 2048,
+          48, 32, 4, 128, vocab=151936, max_pos=262144, experts=128,
+          expert_width=768, per_tok=8, index=(16, 64, 2048, 512),
+          mrope=(16, 24, 24)),
     # tiny synthetic models for fast tests / CI (not in the reference's list)
     _vit("pipeedge/test-tiny-vit", 8, "test-tiny-vit.npz", 32, 2, 4, 64, 5,
          patch=4, img=16),
@@ -123,6 +146,9 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
     # pure per-token top-1 gate, which is causal and batch-size-invariant,
     # so cached decode and split pipelines match the full forward exactly
     # (capacity-bounded models trade that exactness for bounded compute)
+    _keye("pipeedge/test-tiny-keye", "test-tiny-keye.npz", 32, 2, 4, 2, 16,
+          vocab=100, max_pos=64, experts=8, expert_width=16, per_tok=2,
+          index=(2, 8, 4, 8), mrope=(2, 3, 3)),
     _gpt2("pipeedge/test-tiny-moe", 8, "test-tiny-moe.npz", 32, 2, 4, 64,
           vocab=100, max_pos=64, n_experts=4, capacity_factor=4.0),
 ]}
@@ -134,22 +160,51 @@ def get_model_names() -> List[str]:
 
 
 def get_model_entry(model_name: str) -> ModelEntry:
-    return _MODELS[model_name]
+    """The entry of `model_name`. `<name>@<blocks>` is the same model cut to
+    its first `blocks` blocks, with its embedding, final norm and head: the
+    depth cut a benchmark or a stage-sized deployment runs, as a rule and
+    not as entries of their own."""
+    name, at, depth = model_name.partition("@")
+    entry = _MODELS[name]
+    if not at:
+        return entry
+    blocks = int(depth)
+    if not 1 <= blocks <= entry.config.num_hidden_layers:
+        raise ValueError(f"{model_name}: {name} has "
+                         f"{entry.config.num_hidden_layers} blocks")
+    stem, ext = os.path.splitext(entry.weights_file)
+    return dataclasses.replace(
+        entry, name=model_name, layers=4 * blocks,
+        weights_file=f"{stem}@{blocks}{ext}",
+        config=dataclasses.replace(entry.config, num_hidden_layers=blocks))
+
+
+def decoder_model(model_name: str) -> str:
+    """argparse `type=` of the decoding CLIs: a registered causal decoder,
+    whole or as `<name>@<blocks>`."""
+    try:
+        known = get_model_entry(model_name).config.model_type in (
+            "gpt2", "llama", "keye")
+    except (KeyError, ValueError):
+        known = False
+    if not known:
+        raise ValueError(f"{model_name!r} is no registered decoder")
+    return model_name
 
 
 def get_model_layers(model_name: str) -> int:
     """Total sublayer count (model_cfg.py:53-55)."""
-    return _MODELS[model_name].layers
+    return get_model_entry(model_name).layers
 
 
 def get_model_config(model_name: str) -> TransformerConfig:
     """Static config (model_cfg.py:57-66, without the network fetch)."""
-    return _MODELS[model_name].config
+    return get_model_entry(model_name).config
 
 
 def get_model_default_weights_file(model_name: str) -> str:
     """Default weights filename (model_cfg.py:68-70)."""
-    return _MODELS[model_name].weights_file
+    return get_model_entry(model_name).weights_file
 
 
 def make_shard_config(model_name: str, layer_start: int, layer_end: int) -> ShardConfig:
@@ -187,7 +242,7 @@ def module_shard_factory(model_name: str, model_file: Optional[str],
     `should_unroll_blocks`); pass False where the stacked layout is
     required, e.g. params feeding the SPMD driver's stage stacking.
     """
-    entry = _MODELS[model_name]
+    entry = get_model_entry(model_name)
     if model_file is None:
         model_file = entry.weights_file
     shard_config = make_shard_config(model_name, layer_start, layer_end)

@@ -56,6 +56,18 @@ class FamilySpec:
     # sequence-parallel prefill block for position-dependent families:
     # (p, x, bcache, cfg, axis, core, cache_gather) -> (x, bcache)
     sp_prefill_block_step: Any = None
+    # a family whose cache is not the plain `k`, `v` pair names its leaves:
+    # (cfg) -> {name: what follows [L, B, T]} (`init_cache`)
+    cache_leaves: Any = None
+    # (cfg) -> positions a prompt is prefilled at a time, through the
+    # decode-shaped stage program; None = one whole-prompt prefill program
+    prefill_span: Any = None
+    # block leaves the decode scan does not slice a layer at a time but
+    # hands the block step whole, as `(stack, layer)`
+    whole_leaves: tuple = ()
+    # what the block steps count into the cache's `stats` leaf, in order:
+    # each is read back once a batch into `pipeedge_<name>_total{phase}`
+    stats_names: tuple = ()
     # sublayers that LEAD with a dense and accept an 8-bit wire
     # `QuantizedTensor` as the payload's first tensor (the int8
     # stage-seam tunnel, parallel/pipeline.py + ops/int8_matmul.py)
@@ -137,12 +149,15 @@ def unstack_blocks(params: Dict) -> Dict:
 def build_shard_params(shard_config: ShardConfig,
                        get_embed: Callable[[], Dict],
                        get_block: Callable[[int, tuple], Dict],
-                       get_final: Callable[[], Dict]) -> Dict:
+                       get_final: Callable[[], Dict],
+                       stack: Callable = None) -> Dict:
     """Assemble a shard's parameter pytree from per-component getters.
 
     `get_block(block_id, sublayers)` returns only the parameters the listed
     sublayers need — a shard never materializes weights outside its layer
     range, mirroring the reference's lazy npz slicing (vit.py:93-118).
+    `stack` replaces `stack_blocks` (a family whose getters return host
+    arrays stacks them there).
     """
     plan = plan_shard(shard_config)
     params: Dict = {}
@@ -151,7 +166,7 @@ def build_shard_params(shard_config: ShardConfig,
     if plan.head is not None:
         params["head"] = get_block(plan.head.block_id, tuple(plan.head.sublayers()))
     if plan.full_ids:
-        params["blocks"] = stack_blocks(
+        params["blocks"] = (stack or stack_blocks)(
             [get_block(b, (0, 1, 2, 3)) for b in plan.full_ids])
     if plan.tail is not None:
         params["tail"] = get_block(plan.tail.block_id, tuple(plan.tail.sublayers()))

@@ -29,6 +29,8 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
+from ..telemetry import metrics as prom
 from ..utils import jax_compat
 
 from ..models import ShardConfig, plan_shard
@@ -54,6 +56,25 @@ Cache = Dict[str, jax.Array]   # {'k': [L, B, T, H, Dh], 'v': [L, B, T, H, Dh]}
 # the new rows and the window, nothing else (docs/DECODE.md).
 
 
+# A family may name further leaves (`FamilySpec.cache_leaves`: an indexer's
+# keys beside `k` and `v`), shaped `[L, B, T, ...]` like these and written
+# and read by the same two functions. One leaf is not rows: `stats`, `[L, n,
+# 2]` int32, the counts a family's block steps add up on the device (a block
+# step's `rows["stats"]` is `[n]` int32, what this call counted). A count is
+# kept as (units of 2**20, remainder) so that it passes 2**31; the host reads
+# the leaf once a batch (`read_stats`).
+STATS = "stats"
+_STATS_UNIT = 20
+
+
+class LayerSlice(NamedTuple):
+    """Stacked block leaves `[L, ...]` a family asked to be handed whole
+    (`FamilySpec.whole_leaves`) and the layer the block step may read: it
+    slices what it needs, and no whole layer is copied out of the stack."""
+    stack: Dict
+    layer: jax.Array
+
+
 class LayerCache(NamedTuple):
     """What a block step holds of its stage's cache: the stacked buffers
     (leaves `[L, B, T, ...]`, as they were before this step), the index of
@@ -64,11 +85,17 @@ class LayerCache(NamedTuple):
     rows: Optional[Cache] = None
 
 
-def _read_window(buf: jax.Array, layer, width: int) -> jax.Array:
-    """Positions [0, width) of one layer of stacked `buf` -> [B, width, ...]."""
+def _read_window(buf: jax.Array, layer, width: int,
+                 lanes: Optional[slice] = None) -> jax.Array:
+    """Positions [0, width) of one layer of stacked `buf` -> [B, width, ...];
+    `lanes` keeps that slice of the last axis (one head of a leaf that folds
+    its heads into it)."""
     start = (layer,) + (0,) * (buf.ndim - 1)
-    return jax.lax.dynamic_slice(
-        buf, start, (1, buf.shape[1], width) + buf.shape[3:])[0]
+    sizes = (1, buf.shape[1], width) + buf.shape[3:]
+    if lanes is not None:
+        start = start[:-1] + (lanes.start,)
+        sizes = sizes[:-1] + (lanes.stop - lanes.start,)
+    return jax.lax.dynamic_slice(buf, start, sizes)[0]
 
 
 # positions in one tile of a stored leaf: the TPU keeps a `[L, B, T, H, Dh]`
@@ -103,13 +130,31 @@ def _write_rows(cache: Cache, rows: Cache, pos) -> Cache:
             buf, jnp.where(here, new, tile), (0, 0, base) + tail,
             allow_negative_indices=False)
 
-    return {name: write(buf, rows[name]) for name, buf in cache.items()}
+    def add(buf, new):      # `stats`: [L, n, 2] += [L, n]
+        low = buf[..., 1] + new
+        return jnp.stack([buf[..., 0] + (low >> _STATS_UNIT),
+                          low & ((1 << _STATS_UNIT) - 1)], axis=-1)
+
+    return {name: (add if name == STATS else write)(buf, rows[name])
+            for name, buf in cache.items()}
+
+
+def read_stats(cache: Cache):
+    """The `stats` leaf's counts as whole numbers on the host, summed over
+    the layers: an int64 [n]."""
+    import numpy as np
+    pairs = np.asarray(cache[STATS]).astype(np.int64)
+    return (pairs[..., 0] * (1 << _STATS_UNIT) + pairs[..., 1]).sum(axis=0)
 
 
 def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
                max_len: int, dtype=jnp.float32,
-               cache_bits: int = 0) -> Cache:
+               cache_bits: int = 0, leaves=None) -> Cache:
     """Zeroed stacked KV cache for `n_blocks` blocks.
+
+    `leaves` ({name: ShapeDtypeStruct of what follows [L, B, T]}, a
+    family's `cache_leaves(cfg)`) replaces the plain `k`, `v` pair; its
+    `stats` entry sizes the counters' leaf.
 
     `cache_bits=8` stores K/V as int8 with per-(position, head) affine
     scales (QuantPipe's activation-compression idea applied to the decode
@@ -123,6 +168,15 @@ def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
     The head axis is `cfg.kv_heads` — equal to the query head count for
     every family except GQA decoders (llama), whose cache is kv_heads/
     num_attention_heads times smaller (the point of GQA)."""
+    if leaves is not None:
+        if cache_bits:
+            raise NotImplementedError(
+                "the int8 cache route covers the plain k, v cache only")
+        return {name: jnp.zeros((n_blocks,) + tail.shape + (2,), tail.dtype)
+                if name == STATS else
+                jnp.zeros((n_blocks, batch, max_len) + tail.shape,
+                          tail.dtype)
+                for name, tail in leaves.items()}
     shape = (n_blocks, batch, max_len, cfg.kv_heads, cfg.head_dim)
     if cache_bits == 0:
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
@@ -517,16 +571,26 @@ def attend_bucket(pos_next: int, max_len: int, floor: int = 64) -> int:
 
 
 def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
-                prefill: bool, block_fn=_block_step) -> Tuple[jax.Array, Cache]:
+                prefill: bool, block_fn=_block_step,
+                whole: tuple = ()) -> Tuple[jax.Array, Cache]:
     """Scan the stage's blocks over x. The scan only READS the stacked
     cache (each block its layer's window) and stacks the blocks' new rows;
     one update a leaf then writes them where the donated buffer's layout
     is the program's own. The stack must not be the scan's carry: the TPU
     compiler lays a carried buffer out to suit the rows written into it and
     copies the whole cache into and out of that layout around the loop
-    (PERF.md, PR 25)."""
+    (PERF.md, PR 25). Block leaves named in `whole` are not scanned over
+    either: the block step gets each as a `LayerSlice`."""
+    held = {name: blocks[name] for name in whole}
+    if held:
+        blocks = {name: leaf for name, leaf in blocks.items()
+                  if name not in held}
+
     def body(y, xs):
         bp, layer = xs
+        if held:
+            bp = dict(bp, **{name: LayerSlice(leaf, layer)
+                             for name, leaf in held.items()})
         y, bc = block_fn(bp, y, LayerCache(cache, layer), pos, cfg, prefill)
         return y, bc.rows
 
@@ -562,15 +626,19 @@ def make_stage_fns(family, cfg: TransformerConfig, shard_config: ShardConfig,
     def prefill(params, data, cache):
         return run(params, data, cache, pos=0, prefill=True)
 
-    def decode_step(params, data, cache, pos, read_len=None):
+    def decode_step(params, data, cache, pos, read_len=None,
+                    last_only=False):
         return run(params, data, cache, pos, prefill=False,
-                   read_len=read_len)
+                   read_len=read_len, last_only=last_only)
 
     prefill_fn = jax.jit(prefill, donate_argnums=_DONATE_CACHE)
     # read_len is STATIC: each attend-window bucket compiles its own
     # decode-step program (a handful of power-of-2 variants, the same
     # compile-per-discrete-value pattern as the quantized edge bitwidths)
-    decode_fn = jax.jit(decode_step, static_argnames=("read_len",),
+    # so is last_only: a span of a prompt prefilled in spans gives only its
+    # last row to the head
+    decode_fn = jax.jit(decode_step,
+                        static_argnames=("read_len", "last_only"),
                         donate_argnums=_DONATE_CACHE)
     return prefill_fn, decode_fn
 
@@ -594,7 +662,10 @@ def _make_stage_run(family, cfg: TransformerConfig,
             block_fn = partial(_block_step,
                                int8_optin=_resolve_int8_optin(int8_optin))
 
-    def run(params, data, cache, pos, prefill, read_len=None):
+    whole = tuple(getattr(family, "whole_leaves", ()))
+
+    def run(params, data, cache, pos, prefill, read_len=None,
+            last_only=False):
         if shard_config.is_first:
             if embed_fn is not None:
                 data = embed_fn(params["embeddings"], data)
@@ -614,8 +685,10 @@ def _make_stage_run(family, cfg: TransformerConfig,
         bf = block_fn if read_len is None \
             else partial(block_fn, read_len=read_len)
         data, cache = _run_blocks(stage_blocks(params), data, cache, pos,
-                                  cfg, prefill, block_fn=bf)
+                                  cfg, prefill, block_fn=bf, whole=whole)
         if shard_config.is_last:
+            if last_only:
+                data = data[:, -1:]
             data = (finalize_fn or family.finalize)(params["final"], data,
                                                     cfg)
         return data, cache
@@ -794,13 +867,14 @@ def validate_capacity(cfg: TransformerConfig, max_len: int,
 def _repeat_batch(tree, k: int):
     """Tile the batch axis (axis 1 of [L, B, ...] cache leaves) k times:
     beam b of batch i occupies row i*k + b."""
-    return jax.tree_util.tree_map(
-        lambda x: jnp.repeat(x, k, axis=1), tree)
+    return {name: x if name == STATS else jnp.repeat(x, k, axis=1)
+            for name, x in tree.items()}
 
 
 def _gather_batch(tree, rows: jax.Array):
     """Reorder the batch axis of cache leaves by `rows` [B*k]."""
-    return jax.tree_util.tree_map(lambda x: jnp.take(x, rows, axis=1), tree)
+    return {name: x if name == STATS else jnp.take(x, rows, axis=1)
+            for name, x in tree.items()}
 
 
 @partial(jax.jit, static_argnames=("temperature", "top_k"))
@@ -1156,6 +1230,29 @@ class DecodePipeline:
             raise ValueError("tp_ep_mesh (tp x ep MoE decode) replaces the "
                              "single-axis meshes; it does not compose with "
                              "mesh/ep_mesh/sp_mesh, int8 cache, or devices")
+        # what the family cannot do yet is refused here, by name
+        if getattr(family, "cached_block_step", None) is not None:
+            for asked, hook, what in (
+                    (mesh, "tp_cached_block_step", "a tp mesh"),
+                    (sp_mesh, "sp_prefill_block_step", "an sp_mesh"),
+                    (ep_mesh, "ep_cached_block_step", "an ep_mesh"),
+                    (tp_ep_mesh, "ep_cached_block_step", "a tp_ep_mesh")):
+                if asked is not None and getattr(family, hook, None) is None:
+                    raise NotImplementedError(
+                        f"the {family.name} family has no {hook}: it does "
+                        f"not decode under {what} yet")
+        self.cache_leaves = None
+        if getattr(family, "cache_leaves", None) is not None:
+            if cache_bits:
+                raise NotImplementedError(
+                    f"the {family.name} family names its own cache leaves; "
+                    "the int8 cache route covers the plain k, v cache only")
+            self.cache_leaves = family.cache_leaves(cfg)
+        # positions a prompt is prefilled at a time (None: one program for
+        # the whole prompt)
+        span = getattr(family, "prefill_span", None)
+        self.prefill_span = span(cfg) if span is not None else None
+        self.family = family
         self.cfg = cfg
         self.max_len = max_len
         self.mesh, self.tp_axis = mesh, tp_axis
@@ -1233,14 +1330,19 @@ class DecodePipeline:
         stage programs aren't bucketed)."""
         if not self._bucketed:
             return None
-        return attend_bucket(pos + span, self.max_len, self.attend_floor)
+        # a span's window is at least eight spans wide: a prompt prefilled
+        # in spans of 512 compiles three programs up to 16k, not six
+        return attend_bucket(pos + span, self.max_len,
+                             max(self.attend_floor, 8 * span if span > 1
+                                 else 0))
 
     def _fresh_caches(self, batch: int) -> List[Cache]:
         caches = []
         cache_mesh = self.mesh if self.mesh is not None else self.tp_ep_mesh
         for st in self.stages:
             c = init_cache(self.cfg, st["n_blocks"], batch, self.max_len,
-                           self.dtype, cache_bits=self.cache_bits)
+                           self.dtype, cache_bits=self.cache_bits,
+                           leaves=self.cache_leaves)
             if cache_mesh is not None:
                 from jax.sharding import NamedSharding
                 # head axis over tp; replicated over ep when present
@@ -1252,15 +1354,21 @@ class DecodePipeline:
             caches.append(c)
         return caches
 
-    def _decode_step(self, st, data, cache, pos: int, span: int = 1):
+    def _decode_step(self, st, data, cache, pos: int, span: int = 1,
+                     last_only: bool = False):
         """Dispatch one stage's decode program at host-known `pos`,
         binding the static attend bucket when this pipeline is bucketed
         (the batcher dispatches through here too). `span` > 1 runs the
         same program shape over a K-token span [pos, pos+K) — the
-        speculative-decoding verify step."""
+        speculative-decoding verify step, and one span of a prompt
+        prefilled in spans, whose head sees its last row only
+        (`last_only`; the plain stage programs take it)."""
         rl = self._read_len(pos, span)
         if rl is None:
             return st["decode"](st["params"], data, cache, pos)
+        if last_only:
+            return st["decode"](st["params"], data, cache, pos, read_len=rl,
+                                last_only=True)
         return st["decode"](st["params"], data, cache, pos, read_len=rl)
 
     def _prefill(self, ids, prefill_ubatch: Optional[int] = None):
@@ -1277,11 +1385,19 @@ class DecodePipeline:
 
         def run_stages(data):
             caches = self._fresh_caches(data.shape[0])
-            for i, st in enumerate(self.stages):
-                if st["device"] is not None:
-                    data = jax.device_put(data, st["device"])
-                data, caches[i] = st["prefill"](st["params"], data,
-                                                caches[i])
+            if self.prefill_span:       # span by span over the cache
+                for start in range(0, data.shape[1], self.prefill_span):
+                    with telemetry.span("generate", "prefill"):
+                        out, caches = self.extend(
+                            data[:, start:start + self.prefill_span],
+                            caches, start, last_only=True)
+                return out, caches
+            with telemetry.span("generate", "prefill"):
+                for i, st in enumerate(self.stages):
+                    if st["device"] is not None:
+                        data = jax.device_put(data, st["device"])
+                    data, caches[i] = st["prefill"](st["params"], data,
+                                                    caches[i])
             return data, caches
 
         if prefill_ubatch is None or prefill_ubatch >= batch:
@@ -1303,7 +1419,7 @@ class DecodePipeline:
             for i in range(len(self.stages))]
         return jnp.concatenate(outs, axis=0), merged
 
-    def extend(self, tokens, caches, pos: int):
+    def extend(self, tokens, caches, pos: int, last_only: bool = False):
         """Run a K-token span [B, K] through every stage at cache offset
         `pos`: K/V rows [pos, pos+K) are written and span row i attends
         cache positions [0, pos+i] (causal within the span, full history
@@ -1325,8 +1441,9 @@ class DecodePipeline:
         for i, st in enumerate(self.stages):
             if st["device"] is not None:
                 data = jax.device_put(data, st["device"])
-            data, caches[i] = self._decode_step(st, data, caches[i], pos,
-                                                span=k)
+            data, caches[i] = self._decode_step(
+                st, data, caches[i], pos, span=k,
+                last_only=last_only and i == len(self.stages) - 1)
         return data, caches
 
     def precompute_prefix(self, prefix_ids) -> Dict:
@@ -1426,23 +1543,45 @@ class DecodePipeline:
             data, caches = self.extend(ids, caches, prefix["len"])
         else:
             data, caches = self._prefill(ids, prefill_ubatch)
+        # the counts as the prompt left them: copies, since the caches are
+        # donated to the steps; read back once, after the last step
+        after_prompt = [c[STATS] + 0 for c in caches if STATS in c]
         rng, sub = jax.random.split(rng)
-        tokens = [pick(data[:, -1].astype(jnp.float32), sub)]
+        with telemetry.span("generate", "pick"):
+            tokens = [pick(data[:, -1].astype(jnp.float32), sub)]
         if step_callback is not None:
             step_callback(0, tokens[-1])
         for step in range(1, new_tokens):
             pos = prompt_len + step - 1
             data = tokens[-1][:, None]
-            for i, st in enumerate(self.stages):
-                if st["device"] is not None:
-                    data = jax.device_put(data, st["device"])
-                data, caches[i] = self._decode_step(st, data, caches[i],
-                                                    pos)
+            with telemetry.span("generate", "step"):
+                for i, st in enumerate(self.stages):
+                    if st["device"] is not None:
+                        data = jax.device_put(data, st["device"])
+                    data, caches[i] = self._decode_step(st, data, caches[i],
+                                                        pos)
             rng, sub = jax.random.split(rng)
-            tokens.append(pick(data[:, 0].astype(jnp.float32), sub))
+            with telemetry.span("generate", "pick"):
+                tokens.append(pick(data[:, 0].astype(jnp.float32), sub))
             if step_callback is not None:
                 step_callback(step, tokens[-1])
+        if after_prompt:
+            self._count(after_prompt, caches)
         return jnp.concatenate([ids, jnp.stack(tokens, axis=1)], axis=1)
+
+    def _count(self, after_prompt, caches) -> None:
+        """Add a batch's device counts to the registry's counters, by
+        phase: what the prompt counted, and what the steps added."""
+        names = self.family.stats_names
+        prompt = sum(read_stats({STATS: s}) for s in after_prompt)
+        total = sum(read_stats(c) for c in caches if STATS in c)
+        for phase, counts in (("prefill", prompt),
+                              ("decode", total - prompt)):
+            for name, count in zip(names, counts):
+                prom.REGISTRY.counter(
+                    f"pipeedge_{name}_total",
+                    "counted on the device by the stage programs, read "
+                    "back once a batch").inc(float(count), phase=phase)
 
     def generate_beam(self, ids, new_tokens: int, beams: int):
         """Beam-search decode: keep the `beams` highest log-probability
@@ -1473,7 +1612,7 @@ class DecodePipeline:
         caches = [_repeat_batch(c, beams) for c in caches]
 
         logp = jax.nn.log_softmax(
-            data[:, prompt_len - 1].astype(jnp.float32), axis=-1)  # [B, V]
+            data[:, -1].astype(jnp.float32), axis=-1)     # [B, V]
         scores, first = jax.lax.top_k(logp, beams)        # [B, beams]
         history = first[..., None]                        # [B, beams, 1]
 

@@ -41,7 +41,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..utils import jax_compat
-from ..models.layers import TransformerConfig, gelu
+from ..models.layers import TransformerConfig, exact_dot, gelu
 
 
 def init_moe_params(cfg: TransformerConfig, n_experts: int,
@@ -239,3 +239,132 @@ def shard_moe_params(params: Dict, mesh: Mesh, axis: str = "ep") -> Dict:
         "experts": jax.tree_util.tree_map(
             lambda v: put(v, P(axis)), params["experts"]),
     }
+
+
+# -- top-k routing without drops (the keye family's expert layer) --------
+#
+# A token goes to its `num_experts_per_tok` best experts, every assignment
+# is computed, and the layer is told which experts it holds: it routes over
+# all of them and computes the part of the result that its own give. On one
+# chip it holds all and nothing is exchanged; under an 'ep' axis each device
+# passes its slab and one psum adds the parts (`_ep_delta_from_routing`'s
+# shape for top-1).
+
+# rows of one tile of the grouped product: the assignments are sorted by
+# expert, each expert's group is covered by whole tiles, and one loop step
+# multiplies one tile by its expert's three matrices. 256 rows of a 2,048 x
+# 768 expert are as many FLOPs as its weights are bytes on a v5e.
+EXPERT_TILE = 256
+
+# what `topk_ffn_delta` counts, in this order (a float32 vector; each is far
+# below 2**24 a call)
+MOE_STATS = ("assignments", "rows_computed", "experts_touched")
+
+
+def topk_route(router_w: jax.Array, tokens: jax.Array,
+               cfg: TransformerConfig):
+    """(experts [T, k], gates [T, k]) of `tokens` [T, D]: softmax over all
+    experts and the top-k in float32, gates renormalised over the kept
+    where the config says so. Ties go to the lower expert (`lax.top_k`)."""
+    logits = jnp.dot(tokens.astype(jnp.float32),
+                     router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return experts, gates
+
+
+def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
+                   held=None, layer=None):
+    """Routed SwiGLU FFN delta of `normed` [B, S, D], and its counts.
+
+    `params`: `router` {w [D, E]} and `experts` {gate, up [.., F, D], down
+    [.., D, F]} (`nn.Linear` layout, no bias), the expert axis holding the
+    `held` experts only. `held` = (first, count): the experts this caller
+    computes, `first` possibly traced (an 'ep' device's slab); None = all.
+    Assignments to other experts add nothing here. `layer`, when given,
+    indexes a leading layer axis of the expert leaves: the stacked blocks
+    are then sliced one tile's matrices at a time and a whole layer's
+    experts are never copied out of the stack.
+
+    Returns (delta [B, S, D], stats float32 [len(MOE_STATS)])."""
+    b, s, d = normed.shape
+    tokens = normed.reshape(-1, d)
+    t, k = tokens.shape[0], cfg.num_experts_per_tok
+    first, count = (0, cfg.n_experts) if held is None else held
+    experts, gates = topk_route(params["router"]["w"], tokens, cfg)
+    local = experts.reshape(-1) - first                     # [A]
+    mine = (local >= 0) & (local < count)
+    local = jnp.where(mine, local, count)                   # others sort last
+    gates = jnp.where(mine, gates.reshape(-1), 0.0)
+    n_assign = t * k
+
+    tile = min(EXPERT_TILE, -(-t // 8) * 8)
+    # an expert is given a token at most once, so a group has at most
+    # ceil(t / tile) tiles; all groups together have at most one tile a
+    # group more than the assignments fill, and no more than assignments
+    n_tiles = min(count * -(-t // tile), -(-n_assign // tile) + count,
+                  n_assign)
+    # the assignments sorted by expert (stable: by token within an expert;
+    # other chips' last), by sorts and searches: a scatter of this many
+    # single values is the slow way on the chip
+    order = jnp.argsort(local, stable=True)
+    bounds = jnp.searchsorted(local[order], jnp.arange(count + 1))
+    group_first, sizes = bounds[:-1], bounds[1:] - bounds[:-1]
+    tiles_of = -(-sizes // tile)
+    tile_ends = jnp.cumsum(tiles_of)
+    used = tile_ends[-1]
+    # tile i: its expert, and the sorted row it starts at. A group's last
+    # tile runs past the group's end into the rows of later groups; their
+    # own tiles come later in the loop and write those rows again, so no
+    # group is padded and no row is moved to make room
+    tile_id = jnp.arange(n_tiles)
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_ends, tile_id, side="right"), count - 1)
+    tile_start = group_first[tile_expert] + tile * (
+        tile_id - (tile_ends - tiles_of)[tile_expert])
+    rows = jnp.concatenate([tokens[order // k],
+                            jnp.zeros((tile, d), tokens.dtype)])
+
+    ex = params["experts"]
+
+    def matrix(name, e):
+        w = ex[name]
+        if layer is None:
+            return jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
+        return jax.lax.dynamic_slice(
+            w, (layer, e, 0, 0), (1, 1) + w.shape[2:])[0, 0]
+
+    def one_tile(i, out):
+        x = jax.lax.dynamic_slice_in_dim(rows, tile_start[i], tile)
+        e = tile_expert[i]
+
+        def product(a, w):      # a [tile, in] with w [out, in]
+            return exact_dot(a, w, w_contract=1)
+
+        gate = jax.nn.silu(product(x, matrix("gate", e)))
+        hidden = (gate * product(x, matrix("up", e))).astype(x.dtype)
+        y = product(hidden, matrix("down", e)).astype(out.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(out, y, tile_start[i], 0)
+
+    out = jax.lax.fori_loop(0, used, one_tile, jnp.zeros_like(rows))
+    # back to the order the assignments were made in: token by token
+    picked = out[jnp.argsort(order)]
+    delta = jnp.sum(picked.reshape(t, k, d).astype(jnp.float32)
+                    * gates.reshape(t, k, 1), axis=1)
+    stats = jnp.stack([jnp.sum(mine), used * tile,
+                       jnp.sum(sizes > 0)]).astype(jnp.float32)
+    return delta.reshape(b, s, d).astype(normed.dtype), stats
+
+
+def ep_topk_ffn_delta(params: Dict, normed: jax.Array,
+                      cfg: TransformerConfig, axis: str):
+    """`topk_ffn_delta` under `shard_map` with the expert axis of
+    `params["experts"]` sharded over `axis`: each device computes its
+    slab's part and one psum adds them."""
+    count = cfg.n_experts // jax.lax.axis_size(axis)
+    first = jax.lax.axis_index(axis) * count
+    delta, stats = topk_ffn_delta(params, normed, cfg, held=(first, count))
+    return jax.lax.psum(delta, axis), jax.lax.psum(stats, axis)

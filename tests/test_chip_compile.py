@@ -158,3 +158,29 @@ def test_vit_large_forward_compiles_for_v5e(on_chip):
     compiled = jax.jit(forward).lower(params, images).compile()
     logits, = jax.tree_util.tree_leaves(compiled.out_info)
     assert logits.shape == (8, cfg.num_labels)
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (512, True)])
+def test_keye_stage_program_compiles_for_v5e(span, last_only, on_chip):
+    """The benchmark's keye cell at its real size: six layers at the
+    published widths, eight rows, the 16,384 bucket; a decode step and one
+    span of the prefill. The resident 10.5 GB and the program's temporaries
+    have to fit one chip's 16 GB."""
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry("Kwai-Keye/Keye-VL-2.0-30B-A3B@6")
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    params = jax.eval_shape(lambda: entry.family.init_params(
+        cfg, stage, dtype=jnp.bfloat16))
+    cache = jax.eval_shape(lambda: decode.init_cache(
+        cfg, cfg.num_hidden_layers, 8, 16384, jnp.bfloat16,
+        leaves=entry.family.cache_leaves(cfg)))
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((8, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=16384,
+                          last_only=last_only).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes > 1.7e9       # the cache, in place
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9

@@ -220,9 +220,9 @@ def main():
         description="Pipelined KV-cache greedy generation",
         formatter_class=argparse.ArgumentDefaultsHelpFormatter)
     parser.add_argument("-m", "--model-name", default="gpt2",
-                        choices=[n for n in registry.get_model_names()
-                                 if registry.get_model_config(n).model_type
-                                 in ("gpt2", "llama")])
+                        type=registry.decoder_model,
+                        help="a registered decoder, or <name>@<blocks>: "
+                             "its first blocks with its head")
     parser.add_argument("-M", "--model-file", default=None)
     parser.add_argument("-pt", "--partition", default=None,
                         help="comma-separated layer ranges, e.g. 1,24,25,48 "

@@ -241,6 +241,11 @@ class _Service:
         # stage-time with prefills.
         self.kv_backend = None
         if kv_pages:
+            if pipe.cache_leaves is not None:
+                raise NotImplementedError(
+                    f"--kv-pages: the {pipe.family.name} family names its "
+                    "own cache leaves, and a page of the pool holds the "
+                    "plain k, v pair")
             from pipeedge_tpu.kv import PagedKvBackend
             self.kv_backend = PagedKvBackend(pipe, kv_pages,
                                              kv_page_size)
