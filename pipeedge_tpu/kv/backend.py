@@ -241,9 +241,8 @@ class PagedKvBackend:
         # the prefill fleet ships LOGITS, not a token: the pick stays on
         # the decode side with the request's own rng discipline, so
         # disaggregated tokens are identical to colocated ones
-        logits = jnp.asarray(handle["logits"])
-        req.rng, sub = jax.random.split(req.rng)
-        token = req.pick(logits.astype(jnp.float32), sub)
+        token, req.step_ids, req.rng = req.pick(
+            jnp.asarray(handle["logits"])[:, None], req.rng)
         req.tokens.append(token)
         if req.on_token is not None:
             req.on_token(0, token)
@@ -252,7 +251,7 @@ class PagedKvBackend:
             hit = np.asarray(token) == req.eos_token
             req.rows_done = hit
             done = bool(hit.all())
-        result = ("done", None) if done else ("step", token[:, None])
+        result = ("done", None) if done else ("step", req.step_ids)
         ks["install_result"] = result
         return result
 

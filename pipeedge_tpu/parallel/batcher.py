@@ -50,7 +50,7 @@ import numpy as np
 
 from .. import telemetry
 from ..telemetry import metrics as prom
-from .decode import (DecodePipeline, _repeat_batch, make_token_picker,
+from .decode import (DecodePipeline, _repeat_batch, make_next_picker,
                      validate_capacity)
 
 # iteration-level scheduling counters (docs/OBSERVABILITY.md): one family
@@ -85,7 +85,7 @@ class _Request:
     ids: jnp.ndarray                 # [B, S] prompt (prompt included in
     new_tokens: int                  # the result; the SUFFIX when a
     pick: object                     # prefix handle seeds the caches)
-    rng: jax.Array
+    rng: jax.Array                   # the key `pick` splits next
     prompt_len: int                  # prefix + suffix
     prefix: Optional[Dict] = None    # precompute_prefix handle
     eos_token: Optional[int] = None  # stop early once every row emitted it
@@ -125,6 +125,9 @@ class _Request:
     chunk_final: bool = False
     chunks_done: int = 0
     tokens: List = field(default_factory=list)
+    # the last picked token as [B, 1] ids, made by the pick's own program:
+    # the next decode step's input
+    step_ids: Optional[jax.Array] = None
 
     @property
     def pos(self) -> int:
@@ -166,7 +169,7 @@ def _build_request(pipe: DecodePipeline, rid, ids, new_tokens: int,
     validate_capacity(pipe.cfg, pipe.max_len, prompt_len, new_tokens)
     return _Request(
         rid=rid, ids=ids, new_tokens=new_tokens,
-        pick=make_token_picker(temperature, top_k),
+        pick=make_next_picker(temperature, top_k),
         rng=jax.random.PRNGKey(seed), prompt_len=prompt_len,
         prefix=prefix, eos_token=eos_token,
         pad_token=eos_token if pad_token is None else pad_token,
@@ -307,13 +310,12 @@ def _finalize_tokens(req: _Request) -> np.ndarray:
 # by the phase of the executor it fell in.
 
 def _pick_token(req: _Request, out, executor: str):
-    """`exec/pick`: split the request's rng, pick the next token from the
-    last position's logits (prefill [B,S], span [B,S_s], step [B,1]) and
-    append it — the split-per-pick rng discipline of generate()."""
+    """`exec/pick`: one program (decode.make_next_picker) splits the
+    request's rng, picks the next token from the last position's logits
+    (prefill [B,S], span [B,S_s], step [B,1]) and shapes it as the next
+    step's input — the split-per-pick rng discipline of generate()."""
     with telemetry.span("exec", "pick", rid=str(req.rid)):
-        logits = out[:, -1]
-        req.rng, sub = jax.random.split(req.rng)
-        token = req.pick(logits.astype(jnp.float32), sub)
+        token, req.step_ids, req.rng = req.pick(out, req.rng)
         req.tokens.append(token)
         M_STEPS.inc(executor=executor)
     return token
@@ -338,14 +340,6 @@ def _all_rows_eos(req: _Request, token) -> bool:
         req.rows_done = hit if req.rows_done is None \
             else req.rows_done | hit
         return bool(req.rows_done.all())
-
-
-def _step_input(req: _Request, token):
-    """`exec/reenter`: the next decode step's input, the picked token as
-    [B, 1] ids (one more device dispatch) — `exec/retire`'s counterpart
-    for a request that goes on."""
-    with telemetry.span("exec", "reenter", rid=str(req.rid)):
-        return token[:, None]
 
 
 class ContinuousBatcher:
@@ -576,7 +570,7 @@ class ContinuousBatcher:
         if done:
             self._complete(req)
         else:
-            reentries.append((req, _step_input(req, token), "step"))
+            reentries.append((req, req.step_ids, "step"))
 
     def _complete(self, req: _Request) -> None:
         with telemetry.span("exec", "retire", rid=str(req.rid)):
@@ -607,7 +601,7 @@ class ContinuousBatcher:
         if done:
             self._complete(req)
         else:
-            self._stage_q[0].append((req, _step_input(req, token), "step"))
+            self._stage_q[0].append((req, req.step_ids, "step"))
 
     def _pop_stage0(self):
         """Token-budget-per-step policy at stage 0: the budget accrues
@@ -995,7 +989,7 @@ class StageWorkerExecutor:
         if done:
             self._retire(req)
         else:
-            self._q[0].put((req, _step_input(req, token), "step"))
+            self._q[0].put((req, req.step_ids, "step"))
 
     def _retire(self, req: _Request) -> None:
         """`exec/retire`: finalise the request's tokens, free its cache

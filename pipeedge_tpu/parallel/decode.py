@@ -905,6 +905,37 @@ def make_token_picker(temperature: float = 0.0, top_k: int = 0):
                    top_k=int(top_k))
 
 
+@partial(jax.jit, static_argnames=("temperature", "top_k"))
+def pick_next(out, rng, temperature: float, top_k: int):
+    rng, sub = jax.random.split(rng)
+    token = _pick_token(out[:, -1].astype(jnp.float32), sub,
+                        temperature=temperature, top_k=top_k)
+    return token, token[:, None], rng
+
+
+def _pick_last(out, rng, temperature: float, top_k: int):
+    if out.shape[1] > 1:
+        # a prompt pass or a span: its last position is cut out here, one
+        # eager dispatch a request, so that `pick_next` compiles for the
+        # step's shape alone (half a second a prompt length on the chip)
+        out = jax.lax.slice_in_dim(out, out.shape[1] - 1, None, axis=1)
+    return pick_next(out, rng, temperature=temperature, top_k=top_k)
+
+
+def make_next_picker(temperature: float = 0.0, top_k: int = 0):
+    """`pick(out [B, S, V], rng) -> (tokens [B], ids [B, 1], rng)`: one
+    program between two stage programs. From the last stage's output
+    (prompt pass, span or step) and the stream's key it returns the picked
+    token (`make_token_picker`'s rule on the last position's float32
+    logits, under one split of the key), the same token shaped as the next
+    step's input, and the key for the next pick. `generate` and both
+    decode executors pick with it, so their streams stay token-identical,
+    and a token costs the host one dispatch here: the slice, the split,
+    the cast and the reshape, made eagerly, cost one each."""
+    return partial(_pick_last, temperature=float(temperature),
+                   top_k=int(top_k))
+
+
 def make_ep_stage_fns(family, cfg: TransformerConfig,
                       shard_config: ShardConfig, mesh, params: Dict,
                       axis: str = "ep", cache_bits: int = 0):
@@ -1524,7 +1555,7 @@ class DecodePipeline:
             raise ValueError(f"prompt length {prompt_len} not divisible by "
                              f"the sp prefill degree {self.sp_degree}")
         rng = jax.random.PRNGKey(seed)
-        pick = make_token_picker(temperature, top_k)
+        pick = make_next_picker(temperature, top_k)
 
         if prefix is not None:
             self.check_prefix(prefix)
@@ -1546,25 +1577,20 @@ class DecodePipeline:
         # the counts as the prompt left them: copies, since the caches are
         # donated to the steps; read back once, after the last step
         after_prompt = [c[STATS] + 0 for c in caches if STATS in c]
-        rng, sub = jax.random.split(rng)
-        with telemetry.span("generate", "pick"):
-            tokens = [pick(data[:, -1].astype(jnp.float32), sub)]
-        if step_callback is not None:
-            step_callback(0, tokens[-1])
-        for step in range(1, new_tokens):
-            pos = prompt_len + step - 1
-            data = tokens[-1][:, None]
-            with telemetry.span("generate", "step"):
-                for i, st in enumerate(self.stages):
-                    if st["device"] is not None:
-                        data = jax.device_put(data, st["device"])
-                    data, caches[i] = self._decode_step(st, data, caches[i],
-                                                        pos)
-            rng, sub = jax.random.split(rng)
+        tokens = []
+        for step in range(new_tokens):
+            if step:
+                with telemetry.span("generate", "step"):
+                    for i, st in enumerate(self.stages):
+                        if st["device"] is not None:
+                            data = jax.device_put(data, st["device"])
+                        data, caches[i] = self._decode_step(
+                            st, data, caches[i], prompt_len + step - 1)
             with telemetry.span("generate", "pick"):
-                tokens.append(pick(data[:, 0].astype(jnp.float32), sub))
+                token, data, rng = pick(data, rng)
+            tokens.append(token)
             if step_callback is not None:
-                step_callback(step, tokens[-1])
+                step_callback(step, token)
         if after_prompt:
             self._count(after_prompt, caches)
         return jnp.concatenate([ids, jnp.stack(tokens, axis=1)], axis=1)

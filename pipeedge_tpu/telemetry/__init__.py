@@ -37,7 +37,7 @@ lifecycle), `runtime` (schedule rounds), `failover` (detection→recovery),
 lifecycle transitions, pipeedge_tpu/health/), `serve` (HTTP request
 lifecycle; the streaming handler's `readback` and `write`), `exec` (the
 decode executors' worker phases: `wait{i}`, `admit`, `pick`, `emit`,
-`eos`, `reenter`, `retire`, `publish`).
+`eos`, `retire`, `publish`).
 """
 from __future__ import annotations
 

@@ -22,6 +22,7 @@ from pipeedge_tpu.kv import (KvPagePool, PagedKvBackend,  # noqa: E402
 from pipeedge_tpu.kv import ship as ship_mod  # noqa: E402
 from pipeedge_tpu.parallel.batcher import (ContinuousBatcher,  # noqa: E402
                                            StageWorkerExecutor)
+from pipeedge_tpu.parallel.decode import make_next_picker  # noqa: E402
 from pipeedge_tpu.telemetry import metrics as prom  # noqa: E402
 
 MODEL = "pipeedge/test-tiny-gpt2"
@@ -552,8 +553,7 @@ def test_mid_ship_death_leaks_zero_pages_after_sweep(pipe):
         rows_done = None
         eos_token = None
         on_token = None
-        pick = staticmethod(
-            lambda logits, sub: jnp.argmax(logits, axis=-1))
+        pick = staticmethod(make_next_picker())
 
     req = _Req()
     req.ids = np.asarray(ids)

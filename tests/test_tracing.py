@@ -24,14 +24,18 @@ def _profile_files(root):
     return [os.path.join(dp, f) for dp, _, fs in os.walk(root) for f in fs]
 
 
+def _newest_xplane(trace_dir):
+    return sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                            recursive=True), key=os.path.getmtime)[-1]
+
+
 def _host_events(trace_dir):
     """{event name} over every line of every host plane of the newest
     `.xplane.pb` under `trace_dir`."""
     from jax.profiler import ProfileData
-    path = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                            recursive=True), key=os.path.getmtime)[-1]
     return {event.name
-            for plane in ProfileData.from_file(path).planes
+            for plane in ProfileData.from_file(
+                _newest_xplane(trace_dir)).planes
             if not plane.name.startswith("/device:")
             for line in plane.lines for event in line.events}
 

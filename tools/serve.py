@@ -1336,6 +1336,14 @@ class _Service:
 PROFILE_MAX_SECONDS = 60.0
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    """The kernel's accept queue at 128 where socketserver asks for 5:
+    under a burst of arrivals a full queue resets connections before
+    admission can answer them (503 and Retry-After are the server's to
+    say, docs/SERVING.md)."""
+    request_queue_size = 128
+
+
 def _profile(out_dir, seconds):
     """POST /debug/profile: one JAX profiler session of `seconds` into
     `out_dir`, taken with the options of every trace this repo takes
@@ -2418,7 +2426,7 @@ def _run_router(args):
               f"min={args.autoscale_min} max={args.autoscale_max} "
               f"confirm={args.autoscale_confirm} "
               f"cooldown={args.autoscale_cooldown:g}", flush=True)
-    server = ThreadingHTTPServer(
+    server = _HTTPServer(
         (args.host, args.port),
         make_router_handler(router, args.model_name,
                             collector=collector,
@@ -2897,9 +2905,9 @@ def main():
         # ship-plane faults (lease timeouts, zombie drops, worker
         # deaths/readmissions) land in the flight recorder's event ring
         prefill_fleet.flight_note = service.flight.note
-    server = ThreadingHTTPServer((args.host, args.port),
-                                 make_handler(service, args.model_name,
-                                              profile_dir=args.profile_dir))
+    server = _HTTPServer((args.host, args.port),
+                         make_handler(service, args.model_name,
+                                      profile_dir=args.profile_dir))
     print(f"serving {args.model_name} ({len(pipe.stages)} stages, "
           f"{args.executor} executor) on {args.host}:{args.port}",
           flush=True)
