@@ -53,8 +53,6 @@ def _serve_args(p) -> None:
     p.add_argument("--max-len", type=int, default=48)
     p.add_argument("--dtype", default="float32",
                    choices=["float32", "bfloat16"])
-    p.add_argument("--executor", default="wave",
-                   choices=["wave", "stage"])
     p.add_argument("--max-active", type=int, default=1,
                    help="execution slots (1 pins capacity so the "
                         "overload factor is deterministic)")
@@ -115,7 +113,7 @@ def _setup(args) -> dict:
     cmd = [sys.executable, os.path.join(REPO, "tools", "serve.py"),
            "-m", args.model, "-pt", args.partition,
            "--max-len", str(args.max_len), "-t", args.dtype,
-           "--executor", args.executor, "--port", str(port),
+           "--port", str(port),
            "--queue-capacity", str(args.queue_capacity),
            "--trace-spans", args.trace_out,
            # brownout watermarks scaled for a 1-slot loopback server:

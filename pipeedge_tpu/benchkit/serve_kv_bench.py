@@ -46,8 +46,6 @@ def _args(p) -> None:
     p.add_argument("--max-len", type=int, default=64)
     p.add_argument("--dtype", default="float32",
                    choices=["float32", "bfloat16"])
-    p.add_argument("--executor", default="wave",
-                   choices=["wave", "stage"])
     p.add_argument("--kv-pages", type=int, default=96,
                    help="page-pool size (tools/serve.py --kv-pages)")
     p.add_argument("--kv-page-size", type=int, default=8)

@@ -9,7 +9,7 @@ over the existing tiered transport:
 
 - `pool`:    `KvPagePool` — per-stage page arenas, refcounts, eviction
 - `prefix`:  `PrefixTrie` — whole-page prompt matching + cold eviction
-- `backend`: `PagedKvBackend` — the executors' gather/scatter cache
+- `backend`: `PagedKvBackend` — the executor's gather/scatter cache
              provider (token-identical to the dense path for fp caches)
 - `ship`:    KV rows as wire-v2 frames (int8 option, CRC, socket path)
 - `disagg`:  `PrefillFleet` — prompt passes on a dedicated IN-PROCESS

@@ -1,8 +1,8 @@
-"""Paged-KV execution backend for the serving executors.
+"""Paged-KV execution backend for the serving executor.
 
-`ContinuousBatcher` / `StageWorkerExecutor` (parallel/batcher.py) drive
-per-request stage-steps; this backend replaces their dense per-request
-cache slots with page-table indirection over the shared pool:
+`ContinuousBatcher` (parallel/batcher.py) drives per-request
+stage-steps; this backend replaces its dense per-request cache slots
+with page-table indirection over the shared pool:
 
 - **admit**: charge `ceil((prompt + new_tokens) / page_size)` pages per
   batch row (power-of-two bucketed to bound compiled cache shapes),
@@ -22,7 +22,7 @@ cache slots with page-table indirection over the shared pool:
 Numerics: the gathered view is `[n_blocks, B, pages * page_size, ...]`
 instead of the dense `[.., max_len, ..]` — positions past the window
 were fully masked in the dense path (exact softmax zeros), so the paged
-path is TOKEN-IDENTICAL to the dense executors and to solo
+path is TOKEN-IDENTICAL to the dense executor and to solo
 `DecodePipeline.generate` runs for fp caches (tests/test_kv_plane.py
 pins this); int8 caches carry the same quantization caveat as
 `precompute_prefix` reuse.
@@ -55,7 +55,7 @@ def _next_pow2(n: int) -> int:
 
 
 class PagedKvBackend:
-    """The executors' cache provider: page tables instead of dense slots.
+    """The executor's cache provider: page tables instead of dense slots.
 
     `share_prefixes` arms the trie (single-row requests only — lockstep
     multi-row prompts have per-row token content); `bucket_pages` rounds
@@ -136,7 +136,7 @@ class PagedKvBackend:
 
     # -- admission --------------------------------------------------------
 
-    def admit(self, req, block: bool = False) -> Tuple[str, object]:
+    def admit(self, req) -> Tuple[str, object]:
         """Seed the request's page tables; returns `(kind, data)` for
         its first stage-0 dispatch: ("prefill", ids) for a fresh prompt,
         ("span", suffix_ids) when the trie matched a prefix, ("step",
@@ -161,8 +161,7 @@ class PagedKvBackend:
         private: List[List[int]] = []
         try:
             for _ in range(batch):
-                private.append(self.pool.alloc(per_row - shared,
-                                               block=block))
+                private.append(self.pool.alloc(per_row - shared))
         except BaseException:
             for row in private:
                 self.pool.release(row)

@@ -11,7 +11,7 @@ item 2 names). The split:
   `prefill()` returns a ship handle: per-stage KV rows + final logits
   (kv/ship.py). Concurrency is bounded (each in-flight prefill holds
   dense prompt-sized buffers until shipped).
-- The DECODE executors admit the handle through
+- The DECODE executor admits the handle through
   `PagedKvBackend.admit` (`shipped=`): pages are charged, the rows land
   by gather/scatter, the first token is picked decode-side from the
   shipped logits with the request's own rng — so disaggregated token

@@ -1,6 +1,6 @@
 """Paged KV pool: fixed-size token pages behind per-request page tables.
 
-The serving executors historically gave every request PRIVATE dense
+The serving executor historically gave every request PRIVATE dense
 per-stage cache slots sized for `max_len` tokens (`DecodePipeline.
 _fresh_caches`), so concurrency was bounded by SLOTS — a 6-token
 interactive request held the same KV memory as a 1024-token one, and a
@@ -21,7 +21,7 @@ module is the memory half of ROADMAP item 2's paged KV plane:
   (kv/prefix.py) can retain a finished prompt's pages for cross-request
   reuse — a later request with the same prompt prefix references the
   SAME arena pages instead of re-prefilling them.
-- **Static shapes preserved**: the executors materialize a request's
+- **Static shapes preserved**: the executor materializes a request's
   cache view by a gather over the page axis and write back touched
   pages with a scatter (kv/backend.py); the compiled stage programs are
   exactly `DecodePipeline`'s, shaped `[n_blocks, B, pages * page_size,
@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..telemetry import metrics as prom
-from ..utils.threads import make_condition
+from ..utils.threads import make_rlock
 
 
 class PoolExhausted(RuntimeError):
@@ -75,7 +75,7 @@ class KvPagePool:
     pipelines (tp/sp/ep meshes) are refused: their caches are
     device-sharded pytrees whose page gather/scatter would silently
     gather across shards (the paged plane covers the host-driven
-    serving pipeline, like the executors it backs).
+    serving pipeline, like the executor it backs).
 
     Thread model: page accounting (free list, refcounts) lives under one
     condition ("kv.pool"); `release` notifies so a blocking `alloc` can
@@ -115,7 +115,7 @@ class KvPagePool:
                     arr = jax.device_put(arr, st["device"])
                 leaves[name] = arr
             self._arena.append(leaves)
-        self._cond = make_condition("kv.pool")
+        self._lock = make_rlock("kv.pool")
         self._free: List[int] = list(range(self.n_pages - 1, -1, -1))
         self._refs: Dict[int, int] = {}
         # owner ledger (leak audit, docs/FAULT_TOLERANCE.md): the page
@@ -124,7 +124,6 @@ class KvPagePool:
         # submitter that died mid-ship can never strand its pages
         self._owners: Dict[str, List[int]] = {}
         self._evict_hook: Optional[Callable[[int], int]] = None
-        self._closed = False
         reg = prom.REGISTRY if registry is None else registry
         self.m_pages = reg.gauge(
             "pipeedge_kv_pages",
@@ -155,7 +154,7 @@ class KvPagePool:
 
     @property
     def free_pages(self) -> int:
-        with self._cond:
+        with self._lock:
             return len(self._free)
 
     def set_evict_hook(self, hook: Optional[Callable[[int], int]]) -> None:
@@ -164,40 +163,27 @@ class KvPagePool:
         self._evict_hook = hook
 
     def refcount(self, pid: int) -> int:
-        with self._cond:
+        with self._lock:
             return self._refs.get(pid, 0)
 
     def refcounts(self) -> Dict[int, int]:
         """One locked snapshot of every page's refcount — the trie's
         cold-page walks take this ONCE instead of a pool-lock round
         trip per node (kv/prefix.py)."""
-        with self._cond:
+        with self._lock:
             return dict(self._refs)
 
-    def close(self) -> None:
-        """Fail every current and future BLOCKING allocation: the
-        executor's death/stop path must wake submitters parked on page
-        availability, exactly like its semaphore over-release wakes
-        slot-blocked ones (parallel/batcher.py's wake-on-death
-        contract). Releases still work — in-flight completions drain."""
-        with self._cond:
-            self._closed = True
-            self._cond.notify_all()
-
-    def alloc(self, n: int, block: bool = False,
-              timeout: Optional[float] = None) -> List[int]:
+    def alloc(self, n: int) -> List[int]:
         """Take `n` fresh pages (refcount 1 each). On a dry free list the
-        evict hook runs first; `block=True` then waits for releases (the
-        stage-worker submit path's backpressure) up to `timeout`."""
+        evict hook runs first; a pool still short then raises
+        `PoolExhausted` (the executor asks `can_admit` first and keeps
+        the request pending — parallel/batcher.py)."""
         if n <= 0:
             return []
         if n > self.n_pages:
             raise PoolExhausted(n, self.free_pages, self.n_pages)
         while True:
-            with self._cond:
-                if self._closed:
-                    raise RuntimeError(
-                        "KV page pool closed (executor shut down)")
+            with self._lock:
                 if len(self._free) >= n:
                     pids = [self._free.pop() for _ in range(n)]
                     for p in pids:
@@ -208,20 +194,14 @@ class KvPagePool:
             hook = self._evict_hook
             if hook is not None and hook(short) > 0:
                 continue            # eviction freed something: retry
-            with self._cond:
-                if self._closed:
-                    raise RuntimeError(
-                        "KV page pool closed (executor shut down)")
+            with self._lock:
                 if len(self._free) >= n:
                     continue        # a release raced us: retry the take
-                if not block:
-                    raise PoolExhausted(n, len(self._free), self.n_pages)
-                if not self._cond.wait(timeout):
-                    raise PoolExhausted(n, len(self._free), self.n_pages)
+                raise PoolExhausted(n, len(self._free), self.n_pages)
 
     def share(self, pids: Sequence[int]) -> None:
         """Add one reference to each page (prefix reuse / trie retention)."""
-        with self._cond:
+        with self._lock:
             for p in pids:
                 if self._refs.get(p, 0) <= 0:
                     raise ValueError(f"share of unallocated page {p}")
@@ -229,9 +209,9 @@ class KvPagePool:
 
     def release(self, pids: Sequence[int], evicted: bool = False) -> None:
         """Drop one reference per page; refcount 0 returns the page to
-        the free list and wakes blocked allocators."""
+        the free list."""
         freed = 0
-        with self._cond:
+        with self._lock:
             for p in pids:
                 r = self._refs.get(p, 0)
                 if r <= 0:
@@ -244,7 +224,6 @@ class KvPagePool:
                     self._refs[p] = r - 1
             if freed:
                 self.m_pages.set(len(self._free), state="free")
-                self._cond.notify_all()
         if evicted and freed:
             self.m_evicted.inc(freed)
 
@@ -255,7 +234,7 @@ class KvPagePool:
         each page in `pids` — the set `release`/`sweep_leaked` will
         drop. Exactly ONE of the two ever drops it: `disown` is the
         atomic claim."""
-        with self._cond:
+        with self._lock:
             self._owners[str(owner)] = list(pids)
 
     def disown(self, owner) -> Optional[List[int]]:
@@ -263,15 +242,15 @@ class KvPagePool:
         claimed (the request's own release path and the orphan sweep
         race benignly: whoever pops the ledger entry does the release,
         the other sees None and does nothing)."""
-        with self._cond:
+        with self._lock:
             return self._owners.pop(str(owner), None)
 
     def sweep_leaked(self, live_owners) -> int:
         """Reconcile the owner ledger against executor liveness: drop
         the page references of every owner no longer live (a submitter
         or shipper that died between page charge and release). Safe
-        against completion races — executors list a request as live
-        BEFORE charging pages and release pages BEFORE delisting it, so
+        against completion races — the executor lists a request as live
+        BEFORE charging pages and releases pages BEFORE delisting it, so
         a ledger entry whose owner is not live is genuinely orphaned —
         but ONLY if the ledger is observed FIRST and liveness SECOND:
         pass `live_owners` as a CALLABLE for live systems (invoked
@@ -281,7 +260,7 @@ class KvPagePool:
         concurrent admissions. Returns pages reference-dropped
         (pipeedge_kv_pages_leaked_total counts them; /healthz surfaces
         the running total)."""
-        with self._cond:
+        with self._lock:
             owners = list(self._owners)
         if callable(live_owners):
             live_owners = live_owners()
@@ -300,7 +279,7 @@ class KvPagePool:
         return leaked
 
     def stats(self) -> dict:
-        with self._cond:
+        with self._lock:
             free = len(self._free)
             shared = sum(1 for r in self._refs.values() if r > 1)
             owners = len(self._owners)

@@ -34,11 +34,19 @@ TPU-first constraints drive the design:
   token-budget-per-step policy — a long prompt streams in at a bounded
   rate instead of monopolizing the pipeline (docs/SERVING.md).
 
+- **One executor, two ways to drive it**: a caller that owns the loop
+  submits and calls `run()` (or `tick()`) itself — tools/generate.py, the
+  strict-wave tests. A server calls `start()`: the executor's own worker
+  thread then ticks while there is work, handler threads `submit` and
+  `wait` on the executor's condition, a worker that dies or a `stop()`
+  fails every waiter instead of hanging it (tools/serve.py).
+
 The reference has no analogue (its runtime is single-shot batch inference;
 the decode subsystem itself is already beyond-reference — docs/DECODE.md).
 """
 from __future__ import annotations
 
+import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -50,12 +58,13 @@ import numpy as np
 
 from .. import telemetry
 from ..telemetry import metrics as prom
+from ..utils.threads import make_condition
 from .decode import (DecodePipeline, _repeat_batch, make_next_picker,
                      validate_capacity)
 
 # iteration-level scheduling counters (docs/OBSERVABILITY.md): one family
-# per event, labelled by executor so /metrics tells the wave batcher's
-# steps from the stage workers' without a second registry
+# per event. The `executor` label has one value; it stays because scrapes
+# of a running fleet spell it
 M_STEPS = prom.REGISTRY.counter(
     "pipeedge_decode_steps_total",
     "decode-step boundaries crossed (one per picked token wave), "
@@ -64,16 +73,13 @@ M_CHUNKS = prom.REGISTRY.counter(
     "pipeedge_prefill_chunks_total",
     "prompt chunks dispatched by the chunked-prefill scheduler, "
     "by executor")
-for _ex in ("wave", "workers"):
-    M_STEPS.declare(executor=_ex)
-    M_CHUNKS.declare(executor=_ex)
-del _ex
+M_STEPS.declare(executor="wave")
+M_CHUNKS.declare(executor="wave")
 
 
 def _sched_mark(name: str, rid) -> None:
     """Instant `sched` span (join/retire/chunk): scheduler decisions are
-    point events whose endpoints may straddle threads, so both executors
-    record them pre-timed instead of opening a with-span."""
+    point events, recorded pre-timed instead of opening a with-span."""
     if telemetry.enabled():
         now = time.monotonic_ns()
         telemetry.record("sched", name, now, now, rid=str(rid))
@@ -145,9 +151,9 @@ def _build_request(pipe: DecodePipeline, rid, ids, new_tokens: int,
                    deadline: Optional[float] = None,
                    shipped: Optional[Dict] = None) -> _Request:
     """Validate one request's arguments against `pipe` and build its
-    `_Request` — the shared admission contract of the wave batcher and
-    the stage-worker executor (identical errors, identical rng/pick
-    discipline, so token streams match across executors)."""
+    `_Request` — the admission contract `submit` and tools/serve.py's
+    `prevalidate` share (identical errors before and after the response
+    headers commit; the rng/pick discipline of `generate`)."""
     ids = jnp.asarray(ids, jnp.int32)
     if ids.ndim != 2 or ids.shape[1] == 0:
         raise ValueError("prompt must be [B, S] with S >= 1, got "
@@ -181,8 +187,7 @@ def _build_request(pipe: DecodePipeline, rid, ids, new_tokens: int,
 def _seed_caches(pipe: DecodePipeline, req: _Request) -> str:
     """Create the request's per-stage cache slots and return its prompt
     pass kind: a prefix-seeded request's suffix runs as one SPAN at the
-    prefix offset (prompt caching); otherwise a fresh prefill. Shared by
-    the wave batcher's admission and the stage workers' submit."""
+    prefix offset (prompt caching); otherwise a fresh prefill."""
     if req.prefix is not None:
         req.caches = [_repeat_batch(c, req.ids.shape[0])
                       for c in req.prefix["caches"]]
@@ -232,9 +237,9 @@ def _maybe_chunk(req: _Request, kind: str, data,
 def _run_stage(pipe: DecodePipeline, i: int, req: _Request, data,
                kind: str):
     """One stage-step dispatch for request `req` at stage `i` — THE
-    per-stage semantics (device placement, prefill vs span vs step),
-    shared by ContinuousBatcher.tick and StageWorkerExecutor's workers
-    so the two executors can never drift apart. Each step records a
+    per-stage semantics (device placement, prefill vs span vs step) of
+    dense cache slots (kv/backend.py's `run_stage` is the paged twin).
+    Each step records a
     request-tagged `stage`/`exec{i}` span (rid = the request id), so
     trace_report --request attributes a slow request's per-stage compute
     without a fleet trace — free when span recording is off. The mb tag
@@ -269,8 +274,8 @@ def _run_stage(pipe: DecodePipeline, i: int, req: _Request, data,
 
 
 def _expired(req: _Request, now: Optional[float] = None) -> bool:
-    """THE deadline check, shared by both executors at their decode-step
-    boundaries (and at admission): past-deadline requests fire the
+    """THE deadline check, at every decode-step and chunk boundary and
+    at admission: past-deadline requests fire the
     existing `cancel` flag — one cancellation mechanism, two triggers
     (client disconnect, deadline) — and record `expired` so the serving
     layer can tell a 504 from an ordinary early completion."""
@@ -303,13 +308,13 @@ def _finalize_tokens(req: _Request) -> np.ndarray:
     return np.concatenate([np.asarray(req.ids), toks], axis=1)
 
 
-# -- the finish phases, shared by both executors --------------------------
+# -- the finish phases ----------------------------------------------------
 # Each is one `exec` span (docs/OBSERVABILITY.md): with `stage`/`exec{i}`
-# and the workers' `exec`/`wait{i}` they keep an executor thread inside a
+# and the worker's `exec`/`wait0` they keep the executor's thread inside a
 # named span for all of its time, so an idle gap of the device is named
 # by the phase of the executor it fell in.
 
-def _pick_token(req: _Request, out, executor: str):
+def _pick_token(req: _Request, out):
     """`exec/pick`: one program (decode.make_next_picker) splits the
     request's rng, picks the next token from the last position's logits
     (prefill [B,S], span [B,S_s], step [B,1]) and shapes it as the next
@@ -317,7 +322,7 @@ def _pick_token(req: _Request, out, executor: str):
     with telemetry.span("exec", "pick", rid=str(req.rid)):
         token, req.step_ids, req.rng = req.pick(out, req.rng)
         req.tokens.append(token)
-        M_STEPS.inc(executor=executor)
+        M_STEPS.inc(executor="wave")
     return token
 
 
@@ -356,6 +361,21 @@ class ContinuousBatcher:
     differs. `stats` afterwards reports ticks/stage_steps/tokens — in
     steady state with >= n_stages active requests every stage works every
     tick, i.e. ~1 token per tick vs a solo stream's 1 per n_stages.
+
+    Served, the executor drives itself: `start()` runs the ticks on its
+    own worker thread, and each caller thread (one HTTP handler a request
+    in tools/serve.py) hands a request over and blocks for its result:
+
+    >>> batcher = ContinuousBatcher(pipe, max_active=48).start()
+    >>> batcher.submit("a", ids, new_tokens=8)   # returns immediately
+    >>> out = batcher.wait("a")                  # [B, S+8]
+    >>> batcher.stop()
+
+    `max_active` bounds the requests that hold cache slots; the rest wait
+    in `pending`. A worker that raises marks the executor dead, and
+    `stop()` with requests in flight does the same: every current and
+    later `submit` and `wait` raises instead of hanging (the /healthz
+    contract of tools/serve.py).
     """
 
     def __init__(self, pipe: DecodePipeline, max_active: Optional[int] = None,
@@ -413,6 +433,17 @@ class ContinuousBatcher:
         self.results: Dict = {}
         self.stats = {"ticks": 0, "stage_steps": 0, "tokens": 0,
                       "prefill_chunks": 0}
+        # the served life cycle (start/wait/stop): ONE condition guards
+        # the queues and `results` between the worker and the caller
+        # threads. The worker holds its lock for the whole of a tick.
+        # tools/serve.py takes the same lock for its prefix registry and
+        # its admission checks: there is no second lock to order against.
+        # Re-entrant, so a caller may hold it across a look-up of its own
+        # and `submit`.
+        self.cond = make_condition("batcher.results")
+        self._worker: Optional[threading.Thread] = None
+        self._stop = False
+        self._dead: Optional[BaseException] = None
 
     def set_chunk_tokens(self, n: int) -> None:
         """Retarget the chunk size (GIL-atomic int write) — the brownout
@@ -432,7 +463,7 @@ class ContinuousBatcher:
         lockstep (B=1 for a single sequence); each distinct (B, S) shape
         compiles its own prefill program, shared across requests.
 
-        `shipped` (paged-KV executors only) is a prefill fleet's ship
+        `shipped` (with a paged-KV backend only) is a prefill fleet's ship
         handle (kv/disagg.py): the prompt pass already ran remotely, so
         admission installs the KV rows into this request's pages and
         decoding starts at the first decode step.
@@ -471,27 +502,30 @@ class ContinuousBatcher:
         completes the request with the tokens decoded so far
         (`docs/SERVING.md` — expired work must not keep consuming the
         pipeline)."""
-        if rid in self.results or rid in self._live_rids:
-            raise ValueError(f"duplicate request id {rid!r}")
-        if shipped is not None and self.kv is None:
-            raise ValueError("shipped KV needs a paged-KV backend "
-                             "(ContinuousBatcher(kv=...))")
-        req = _build_request(self.pipe, rid, ids, new_tokens, temperature,
-                             top_k, seed, eos_token, pad_token, prefix,
-                             on_token=on_token, cancel=cancel,
-                             deadline=deadline, shipped=shipped)
-        if self.kv is not None:
-            # a reservation bigger than the whole pool would wedge the
-            # pending queue forever (can_admit never true): reject it
-            # up front like the dense path's capacity check
-            self.kv.check_admittable(req)
-        self._live_rids.add(rid)
-        self.pending.append(req)
+        with self.cond:
+            self._check_dead()
+            if rid in self.results or rid in self._live_rids:
+                raise ValueError(f"duplicate request id {rid!r}")
+            if shipped is not None and self.kv is None:
+                raise ValueError("shipped KV needs a paged-KV backend "
+                                 "(ContinuousBatcher(kv=...))")
+            req = _build_request(self.pipe, rid, ids, new_tokens,
+                                 temperature, top_k, seed, eos_token,
+                                 pad_token, prefix, on_token=on_token,
+                                 cancel=cancel, deadline=deadline,
+                                 shipped=shipped)
+            if self.kv is not None:
+                # a reservation bigger than the whole pool would wedge the
+                # pending queue forever (can_admit never true): reject it
+                # up front like the dense path's capacity check
+                self.kv.check_admittable(req)
+            self._live_rids.add(rid)
+            self.pending.append(req)
+            self.cond.notify_all()       # the worker may be waiting for work
 
     def _admit(self) -> None:
         """Join pending requests while slots (and pages) last: `exec/admit`,
-        which on this executor runs on the worker's own thread (the stage
-        workers admit on the submitter's)."""
+        on the thread that ticks."""
         if not self.pending or self.active >= self.max_active:
             return
         with telemetry.span("exec", "admit"):
@@ -556,7 +590,7 @@ class ContinuousBatcher:
             M_CHUNKS.inc(executor="wave")
             reentries.append((req, data, "chunk"))
             return
-        token = _pick_token(req, out, "wave")
+        token = _pick_token(req, out)
         self.stats["tokens"] += int(token.shape[0])
         _emit_token(req, token, self.on_step)
         done = len(req.tokens) >= req.new_tokens
@@ -678,345 +712,102 @@ class ContinuousBatcher:
             pass
         return self.results
 
+    # -- the served life cycle: the executor's own worker thread ----------
 
-class StageWorkerExecutor:
-    """Stage-pinned multi-worker executor: one thread per pipeline stage.
+    def start(self) -> "ContinuousBatcher":
+        """Start the worker thread that ticks while there is work. Only a
+        caller that wants `submit`/`wait` from other threads asks for it;
+        `run()` and `tick()` drive the same executor without one."""
+        if self._worker is None:
+            self._worker = threading.Thread(target=self._loop, daemon=True,
+                                            name="decode-executor")
+            self._worker.start()
+        return self
 
-    Where `ContinuousBatcher.tick` serializes the HOST side of every
-    stage's dispatch through one Python loop (the device work is async,
-    but tracing/argument handling/dispatch are not), this executor pins a
-    worker thread to each stage: worker `i` blocks on stage `i`'s input
-    queue, dispatches exactly its own stage's compiled programs, and
-    hands the wave to stage `i+1`'s queue. Host-side dispatch of
-    different stages genuinely overlaps, and the last stage's token
-    picks (plus eos readbacks) never stall the other stages' dispatch.
-
-    The per-request computation is exactly the wave batcher's — the same
-    `_build_request` admission contract, the same stage programs, the
-    same pick/rng discipline — so token streams are identical to solo
-    `DecodePipeline.generate` runs and to `ContinuousBatcher` results
-    (tests/test_batcher.py). Request lifecycle:
-
-    >>> ex = StageWorkerExecutor(pipe)
-    >>> ex.submit("a", ids, new_tokens=8)       # returns immediately
-    >>> out = ex.wait("a")                      # [B, S+8]
-    >>> ex.stop()
-
-    `max_active` bounds concurrently admitted requests (KV-cache memory)
-    with a semaphore: `submit` blocks while the pipeline is full —
-    callers ARE the queue (one HTTP handler thread per request in
-    tools/serve.py), so admission backpressure lands on them directly.
-    A worker that raises marks the executor dead; every current and
-    future waiter raises instead of hanging (the serve.py healthz
-    contract)."""
-
-    _DONE = object()
-
-    def __init__(self, pipe: DecodePipeline,
-                 max_active: Optional[int] = None, kv=None,
-                 chunk_tokens: int = 0, step_join: bool = False,
-                 on_step=None):
-        import queue as queue_mod
-        import threading
-
-        from ..utils.threads import make_condition
-
-        if pipe.sp_degree != 1:
-            raise ValueError("stage workers drive per-request decode "
-                             "waves; sp prefill is a whole-pipeline pass")
-        self.pipe = pipe
-        self.n_stages = len(pipe.stages)
-        # paged-KV backend: page-table caches + token-bounded admission
-        # (submit blocks on PAGE availability, not just the slot count)
-        self.kv = kv
-        if max_active is None:
-            max_active = (self.n_stages + 1 if kv is None
-                          else max(self.n_stages + 1, kv.pool.n_pages))
-        self.max_active = max_active
-        if self.max_active < 1:
-            raise ValueError(f"max_active must be >= 1, got {self.max_active}")
-        # chunked prefill: the stage queues are FIFO, so bounding every
-        # item's token cost at `chunk_tokens` IS the latency policy here
-        # — a decode step queued behind a chunk waits one chunk-time,
-        # not one whole-prompt-time (no explicit budget needed: workers
-        # interleave whatever order the queues hold)
-        if chunk_tokens < 0:
-            raise ValueError(f"chunk_tokens must be >= 0, got {chunk_tokens}")
-        self.chunk_tokens = int(chunk_tokens)
-        # stage workers join/retire at step boundaries BY CONSTRUCTION
-        # (submit feeds stage 0 whenever a slot frees, mid-wave);
-        # `step_join` is accepted for signature parity with the wave
-        # batcher so tools/serve.py configures both identically
-        self.step_join = bool(step_join)
-        # on_step(): fired after each decode-step pick (last stage's
-        # worker thread) — tools/serve.py chains admission re-grants
-        self.on_step = on_step
-        self._q = [queue_mod.Queue() for _ in range(self.n_stages)]
-        # plain (not Bounded) semaphore: _die() over-releases on purpose
-        # so submitters blocked on admission wake up and see the failure
-        self._slots = threading.Semaphore(self.max_active)
-        self._lock = make_condition("batcher.results")
-        self.results: Dict = {}
-        self._live = set()
-        self._dead: Optional[BaseException] = None
-        self.active = 0
-        self.stats = {"stage_steps": [0] * self.n_stages,
-                      "busy": [False] * self.n_stages, "tokens": 0,
-                      "prefill_chunks": 0}
-        self._workers = [
-            threading.Thread(target=self._stage_loop, args=(i,),
-                             daemon=True, name=f"stage-worker-{i}")
-            for i in range(self.n_stages)]
-        for w in self._workers:
-            w.start()
-
-    # -- client side ------------------------------------------------------
-
-    def submit(self, rid, ids, new_tokens: int, temperature: float = 0.0,
-               top_k: int = 0, seed: int = 0,
-               eos_token: Optional[int] = None,
-               pad_token: Optional[int] = None,
-               prefix: Optional[Dict] = None,
-               on_token=None, cancel=None,
-               deadline: Optional[float] = None,
-               shipped: Optional[Dict] = None) -> None:
-        """Admit one request (same argument contract as
-        `ContinuousBatcher.submit`, including prefix-handle validation,
-        the `on_token` streaming hook, the `cancel` flag, the `deadline`
-        and — on a paged-KV executor — a prefill fleet's `shipped`
-        handle). BLOCKS while `max_active` requests are in flight —
-        admission backpressure is the caller's thread, not an internal
-        queue; a paged executor additionally blocks on PAGE
-        availability."""
-        if shipped is not None and self.kv is None:
-            raise ValueError("shipped KV needs a paged-KV backend "
-                             "(StageWorkerExecutor(kv=...))")
-        req = _build_request(self.pipe, rid, ids, new_tokens, temperature,
-                             top_k, seed, eos_token, pad_token, prefix,
-                             on_token=on_token, cancel=cancel,
-                             deadline=deadline, shipped=shipped)
-        if self.kv is not None:
-            # reject a bigger-than-the-pool reservation BEFORE taking a
-            # slot (alloc would raise PoolExhausted anyway; this makes
-            # it the same up-front ValueError the wave batcher gives)
-            self.kv.check_admittable(req)
-        with self._lock:
-            self._check_dead()
-            if rid in self.results or rid in self._live:
-                raise ValueError(f"duplicate request id {rid!r}")
-            self._live.add(rid)
-        self._slots.acquire()
-        try:
-            with self._lock:
-                if self._dead is not None:   # woken by _die's over-release
-                    self._check_dead()
-                self.active += 1
-            if _expired(req):
-                # the admission wait outlived the deadline: complete with
-                # the bare prompt without ever touching the pipeline
-                with self._lock:
-                    self.results[rid] = _finalize_tokens(req)
-                    self._live.discard(rid)
-                    self.active -= 1
-                    self._lock.notify_all()
-                self._slots.release()
-                return
+    def _loop(self) -> None:
+        while True:
+            # `exec/wait0`, the worker's only blocking wait: first for the
+            # condition's lock, which every submitting and every waiting
+            # caller thread shares with it, then for work
+            with telemetry.span("exec", "wait0", stage=0):
+                self.cond.acquire()
+                while not self._stop and not (self.pending or self.active):
+                    self.cond.wait()
             try:
-                if self.kv is not None:
-                    # page admission blocks like the slot semaphore does:
-                    # completions release pages, so waiting here is the
-                    # same caller-thread backpressure contract
-                    kind, data = self.kv.admit(req, block=True)
-                    if req.tokens and kind != "done":
-                        # a shipped install's first token was picked in
-                        # admit — count it like the wave batcher does
-                        with self._lock:
-                            self.stats["tokens"] += int(req.ids.shape[0])
-                else:
-                    kind, data = _seed_caches(self.pipe, req), req.ids
-                if kind == "done":
-                    # a shipped install whose first token already
-                    # completed the request: never touches the pipeline
-                    arr = _finalize_tokens(req)
-                    self.kv.release(req)
-                    with self._lock:
-                        self.stats["tokens"] += int(req.ids.shape[0])
-                        self.results[rid] = arr
-                        self._live.discard(rid)
-                        self.active -= 1
-                        self._lock.notify_all()
-                    self._slots.release()
+                if self._stop:
                     return
-                kind, data = _maybe_chunk(req, kind, data,
-                                          self.chunk_tokens)
-                if kind == "chunk":
-                    with self._lock:
-                        self.stats["prefill_chunks"] += 1
-                    M_CHUNKS.inc(executor="workers")
-                _sched_mark("join", rid)
-                self._q[0].put((req, data, kind))
-            except BaseException:
-                # roll the admission back (e.g. cache allocation OOM /
-                # page-pool exhaustion): leaking the slot would
-                # eventually wedge every submit while healthz reports ok
-                with self._lock:
-                    self.active -= 1
-                raise
-        except BaseException:
-            with self._lock:
-                self._live.discard(rid)
-            self._slots.release()
-            raise
+                try:
+                    self.tick()
+                except BaseException as exc:   # noqa: BLE001 — a wedged
+                    # worker would hang every waiter forever; record the
+                    # failure so they raise instead
+                    self._die(exc)
+                    raise
+                if self.results:
+                    self.cond.notify_all()
+            finally:
+                self.cond.release()
 
-    def wait(self, rid, timeout: Optional[float] = None) -> np.ndarray:
-        """Block until request `rid` completes; returns its [B, S + T]
-        ids (the same array `ContinuousBatcher.run` would record)."""
-        with self._lock:
-            while rid not in self.results:
-                self._check_dead()
-                if not self._lock.wait(timeout):
-                    raise TimeoutError(f"request {rid!r} not done after "
-                                       f"{timeout}s")
-            return self.results.pop(rid)
+    @property
+    def dead(self) -> Optional[BaseException]:
+        """What killed the worker, or the `stop()` that ended it; None
+        while the executor serves."""
+        return self._dead
 
-    def snapshot(self) -> Dict:
-        """Point-in-time per-worker stats for health reporting: stage
-        steps and busy flag per worker, queue depths, tokens, active."""
-        with self._lock:
-            return {"stage_steps": list(self.stats["stage_steps"]),
-                    "busy": list(self.stats["busy"]),
-                    "queued": [q.qsize() for q in self._q],
-                    "tokens": self.stats["tokens"],
-                    "prefill_chunks": self.stats["prefill_chunks"],
-                    "active": self.active}
-
-    def set_chunk_tokens(self, n: int) -> None:
-        """Retarget the chunk size (GIL-atomic int write) — the brownout
-        ladder's chunk-clamp rung calls this from the governor thread;
-        in-flight requests see it at their next chunk boundary."""
-        self.chunk_tokens = max(0, int(n))
-
-    def stop(self) -> None:
-        """Shut the workers down. Queued work ahead of the sentinels is
-        processed, but a multi-step request cannot finish once worker 0
-        exits (its re-entering waves have no one to run them) — after
-        the join, every still-live request's waiter is FAILED rather
-        than left hanging. Drain with `wait` before stopping if results
-        matter."""
-        if self.kv is not None:
-            # wake submitters parked on PAGE availability too (the
-            # semaphore over-release below only reaches slot waiters);
-            # in-flight completions still release their pages
-            self.kv.pool.close()
-        for q in self._q:
-            q.put(self._DONE)
-        for w in self._workers:
-            w.join()
-        with self._lock:
-            if self._live and self._dead is None:
-                self._dead = RuntimeError(
-                    f"executor stopped with {len(self._live)} request(s) "
-                    "in flight")
-            self._lock.notify_all()
-            dead = self._dead is not None
-        if dead:
-            # mirror _die(): in-flight requests will never release their
-            # admission slots now, so over-release the semaphore to wake
-            # submitters blocked in acquire — they re-check _dead and
-            # raise instead of hanging forever (ADVICE.md r5)
-            for _ in range(self.max_active):
-                self._slots.release()
+    def _die(self, exc: BaseException) -> None:
+        """Record the first cause of death and wake every waiter."""
+        with self.cond:
+            if self._dead is None:
+                self._dead = exc
+            self.cond.notify_all()
 
     def _check_dead(self) -> None:
         if self._dead is not None:
-            raise RuntimeError(f"stage worker died: {self._dead!r}")
+            raise RuntimeError(f"serving worker died: {self._dead!r}")
 
-    # -- worker side ------------------------------------------------------
+    def wait(self, rid, timeout: Optional[float] = None) -> np.ndarray:
+        """Block until request `rid` completes and take its [B, S + T] ids
+        (the array `run()` would record). Raises RuntimeError once the
+        worker has died or `stop()` cut the request short, TimeoutError
+        after `timeout` seconds."""
+        end = None if timeout is None else time.monotonic() + timeout
+        with self.cond:
+            while rid not in self.results:
+                self._check_dead()
+                left = None if end is None else end - time.monotonic()
+                if left is not None and left <= 0:
+                    raise TimeoutError(f"request {rid!r} not done after "
+                                       f"{timeout}s")
+                self.cond.wait(left)
+            return self.results.pop(rid)
 
-    def _stage_loop(self, i: int) -> None:
-        while True:
-            with telemetry.span("exec", f"wait{i}", stage=i):
-                item = self._q[i].get()
-            if item is self._DONE:
-                return
-            req, data, kind = item
-            self.stats["busy"][i] = True
+    def live_rids(self) -> Optional[set]:
+        """The ids of every request pending or admitted and not yet
+        complete: the liveness set of tools/serve.py's orphan sweep. Read
+        without the lock (a sweep must not wait out a tick); None when
+        the copy raced a mutation three times, and the next sweep retries."""
+        for _ in range(3):
             try:
-                out = (self.kv.run_stage(i, req, data, kind)
-                       if self.kv is not None
-                       else _run_stage(self.pipe, i, req, data, kind))
-                self.stats["stage_steps"][i] += 1
-                if i + 1 < self.n_stages:
-                    self._q[i + 1].put((req, out, kind))
-                else:
-                    self._finish(req, out, kind)
-            except BaseException as exc:   # noqa: BLE001 — a dead worker
-                self._die(exc)             # must fail waiters, not hang them
-                raise
-            finally:
-                self.stats["busy"][i] = False
+                return set(self._live_rids)
+            except RuntimeError:     # set mutated during copy
+                continue
+        return None
 
-    def _finish(self, req: _Request, out, kind: str) -> None:
-        """Last stage done (runs in the last stage's worker): pick the
-        next token, stream it, then complete or re-enter stage 0. The
-        eos readback blocks only THIS worker; earlier stages keep
-        dispatching other requests. An INTERMEDIATE prompt chunk picks
-        nothing: its boundary retires an expired/cancelled request (the
-        mid-prompt shed frees pages before a single token decodes) or
-        queues the next chunk."""
-        if kind == "chunk" and not req.chunk_final:
-            if _expired(req) or (req.cancel is not None
-                                 and req.cancel.is_set()):
-                self._retire(req)             # with the bare prompt
-                return
-            data = _next_chunk(req, self.chunk_tokens)
-            with self._lock:
-                self.stats["prefill_chunks"] += 1
-            M_CHUNKS.inc(executor="workers")
-            self._q[0].put((req, data, "chunk"))
-            return
-        token = _pick_token(req, out, "workers")
-        with self._lock:
-            self.stats["tokens"] += int(token.shape[0])
-        _emit_token(req, token, self.on_step)
-        done = len(req.tokens) >= req.new_tokens
-        if not done and _expired(req):
-            done = True             # deadline passed: cancel mid-flight
-        if not done and req.cancel is not None and req.cancel.is_set():
-            done = True             # caller gone: free the slot early
-        if not done and req.eos_token is not None:
-            done = _all_rows_eos(req, token)
-        if done:
-            self._retire(req)
-        else:
-            self._q[0].put((req, req.step_ids, "step"))
+    def snapshot(self) -> Dict:
+        """The stats /healthz shows. Lock-free and best-effort (GIL-atomic
+        reads; a momentary inconsistency is fine for health)."""
+        return dict(self.stats, active=self.active,
+                    pending=len(self.pending))
 
-    def _retire(self, req: _Request) -> None:
-        """`exec/retire`: finalise the request's tokens, free its cache
-        slots (or page references), publish the result, wake its waiter
-        and hand the admission slot back."""
-        with telemetry.span("exec", "retire", rid=str(req.rid)):
-            arr = _finalize_tokens(req)
-            req.caches = None
-            req.chunk_rest = None
-            if self.kv is not None:
-                self.kv.release(req)
-            _sched_mark("retire", req.rid)
-            with self._lock:
-                self.results[req.rid] = arr
-                self._live.discard(req.rid)
-                self.active -= 1
-                self._lock.notify_all()
-            self._slots.release()
-
-    def _die(self, exc: BaseException) -> None:
-        with self._lock:
-            if self._dead is None:
-                self._dead = exc
-            self._lock.notify_all()
-        # wake submitters blocked on admission so they observe the death
-        # — both the slot semaphore and (paged) the page-pool wait
-        if self.kv is not None:
-            self.kv.pool.close()
-        for _ in range(self.max_active):
-            self._slots.release()
+    def stop(self) -> None:
+        """Stop the worker at the next tick boundary. A request still
+        pending or in flight can never finish then, so its waiter is
+        FAILED rather than left hanging, and so is every later `submit`.
+        Drain with `wait` before stopping if results matter."""
+        # set before the lock is asked for: a worker with work re-takes
+        # its lock at once, and reads this at its next tick boundary
+        self._stop = True
+        self._die(RuntimeError(f"executor stopped with "
+                               f"{len(self._live_rids)} request(s) in flight"))
+        if self._worker is not None:
+            self._worker.join()

@@ -39,6 +39,11 @@ generate() discipline), R == n_stages request slots, equal prompt
 lengths/budgets per slot (the static-shape steady state; the host-driven
 batcher handles ragged arrivals). Token-identical to per-request
 `DecodePipeline.generate` (tests/test_spmd_decode.py).
+
+Not a serving executor: tools/serve.py runs `ContinuousBatcher` and
+nothing else; this engine is reached from tools/generate.py `--spmd-wave`
+and `__graft_entry__.py` only, and stays or goes with the four-chip
+decoder cell (ROADMAP R1, D2).
 """
 from __future__ import annotations
 

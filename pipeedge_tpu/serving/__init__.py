@@ -17,7 +17,7 @@ tools/serve.py the three mechanisms that bound the damage:
   disable-speculative -> clamp new_tokens -> evict cold KV pages ->
   shed best-effort -> shed batch, and steps back down with hysteresis
   (`BrownoutLadder`).
-- deadline propagation itself lives in the executors
+- deadline propagation itself lives in the executor
   (`parallel/batcher.py`): each request's absolute deadline rides into
   the decode loop, and expiry fires the existing `cancel` flag at the
   next decode-step boundary so dead work stops consuming TPU time.

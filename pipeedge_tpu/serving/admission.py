@@ -295,7 +295,7 @@ class AdmissionShed(RuntimeError):
 
 class DeadlineExceeded(RuntimeError):
     """The request's deadline expired while it was EXECUTING: the
-    executors cancelled it at a decode-step boundary (HTTP 504). Distinct
+    executor cancelled it at a decode-step boundary (HTTP 504). Distinct
     from an in-queue expiry, which sheds with 503 + Retry-After (the work
     never started)."""
 
@@ -571,7 +571,7 @@ class AdmissionController:
 
     def notify_step(self, now: Optional[float] = None) -> None:
         """Re-run the grant pass at a decode-step boundary (the
-        executors' `on_step` hook, tools/serve.py). Slots and tokens
+        executor's `on_step` hook, tools/serve.py). Slots and tokens
         free when `release` runs, but a token-budget head-of-line wait
         can also unblock when the STEP-granular picture changes (an
         expired waiter sheds, a clamp lands); stepping the grant pass

@@ -36,7 +36,7 @@ lifecycle), `runtime` (schedule rounds), `failover` (detection→recovery),
 `rejoin` (JOIN admission → heal-to-full-capacity), `health` (gray-failure
 lifecycle transitions, pipeedge_tpu/health/), `serve` (HTTP request
 lifecycle; the streaming handler's `readback` and `write`), `exec` (the
-decode executors' worker phases: `wait{i}`, `admit`, `pick`, `emit`,
+decode executor's worker phases: `wait0`, `admit`, `pick`, `emit`,
 `eos`, `retire`, `publish`).
 """
 from __future__ import annotations

@@ -478,7 +478,7 @@ def test_report_serving_section_from_spans():
 
 
 # ---------------------------------------------------------------------------
-# deadline propagation into the executors (the cancel-flag contract)
+# deadline propagation into the executor (the cancel-flag contract)
 # ---------------------------------------------------------------------------
 
 def _tiny_pipe(max_len=64):
@@ -493,42 +493,45 @@ def _tiny_pipe(max_len=64):
         max_len=max_len)
 
 
-@pytest.mark.parametrize("executor", ["wave", "stage"])
-def test_pre_expired_deadline_never_touches_pipeline(executor):
+def _result(batcher, drive, rid):
+    """`rid`'s result from an executor driven the offline way (`run()`)
+    or the served way (its own worker thread, `wait`)."""
+    if drive == "thread":
+        batcher.start()
+    try:
+        return (batcher.wait(rid, timeout=120) if drive == "thread"
+                else batcher.run()[rid])
+    finally:
+        batcher.stop()
+
+
+@pytest.mark.parametrize("drive", ["run", "thread"])
+def test_pre_expired_deadline_never_touches_pipeline(drive):
     """A request whose deadline already passed completes with the bare
     prompt — no cache seeding, no decode steps spent on dead work."""
     import jax.numpy as jnp
 
-    from pipeedge_tpu.parallel.batcher import (ContinuousBatcher,
-                                               StageWorkerExecutor)
+    from pipeedge_tpu.parallel.batcher import ContinuousBatcher
 
     pipe = _tiny_pipe()
     ids = jnp.zeros((1, 4), jnp.int32)
     dead = time.monotonic() - 1.0
-    if executor == "stage":
-        ex = StageWorkerExecutor(pipe, max_active=1)
-        try:
-            ex.submit("r", ids, 8, deadline=dead)
-            out = ex.wait("r", timeout=120)
-        finally:
-            ex.stop()
-    else:
-        b = ContinuousBatcher(pipe, max_active=1)
-        b.submit("r", ids, 8, deadline=dead)
-        out = b.run()["r"]
+    b = ContinuousBatcher(pipe, max_active=1)
+    b.submit("r", ids, 8, deadline=dead)
+    out = _result(b, drive, "r")
     assert out.shape == (1, 4)               # prompt only, zero tokens
+    assert b.stats["stage_steps"] == 0
 
 
-@pytest.mark.parametrize("executor", ["wave", "stage"])
-def test_deadline_expiry_cancels_mid_flight(executor):
+@pytest.mark.parametrize("drive", ["run", "thread"])
+def test_deadline_expiry_cancels_mid_flight(drive):
     """The executor checks the deadline at every decode-step boundary and
     fires the existing `cancel` flag on expiry: the request completes
     with the tokens decoded so far, far short of the cap — expired work
     stops consuming the pipeline (docs/SERVING.md)."""
     import jax.numpy as jnp
 
-    from pipeedge_tpu.parallel.batcher import (ContinuousBatcher,
-                                               StageWorkerExecutor)
+    from pipeedge_tpu.parallel.batcher import ContinuousBatcher
 
     pipe = _tiny_pipe()
     cap = 40
@@ -541,19 +544,10 @@ def test_deadline_expiry_cancels_mid_flight(executor):
         time.sleep(0.05)
 
     deadline = time.monotonic() + 0.3
-    if executor == "stage":
-        ex = StageWorkerExecutor(pipe, max_active=1)
-        try:
-            ex.submit("r", ids, cap, on_token=on_token, cancel=cancel,
-                      deadline=deadline)
-            out = ex.wait("r", timeout=120)
-        finally:
-            ex.stop()
-    else:
-        b = ContinuousBatcher(pipe, max_active=1)
-        b.submit("r", ids, cap, on_token=on_token, cancel=cancel,
-                 deadline=deadline)
-        out = b.run()["r"]
+    b = ContinuousBatcher(pipe, max_active=1)
+    b.submit("r", ids, cap, on_token=on_token, cancel=cancel,
+             deadline=deadline)
+    out = _result(b, drive, "r")
     decoded = out.shape[1] - 4
     assert 1 <= decoded < cap, f"decoded {decoded} of {cap}"
     # expiry cancels through the ONE shared mechanism: the cancel flag

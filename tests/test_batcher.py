@@ -307,19 +307,17 @@ def test_prefix_handle_validated_at_submit(tiny_pipe):
         results[2], np.asarray(tiny_pipe.generate(suffix, 4, prefix=handle)))
 
 
-def test_stage_worker_executor_matches_solo(tiny_pipe):
-    """StageWorkerExecutor (one thread pinned per stage) is token-
+def test_thread_driven_executor_matches_solo(tiny_pipe):
+    """The executor on its own worker thread (the served way) is token-
     identical to solo generate() for mixed plain/sampled/prefix/eos
     requests submitted concurrently, streams per-step tokens via
-    on_token, and reports per-worker stats."""
+    on_token, and reports its stats."""
     import threading
-
-    from pipeedge_tpu.parallel.batcher import StageWorkerExecutor
 
     rng = np.random.default_rng(41)
     prefix = rng.integers(0, 100, size=(1, 6))
     handle = tiny_pipe.precompute_prefix(prefix)
-    ex = StageWorkerExecutor(tiny_pipe)
+    ex = ContinuousBatcher(tiny_pipe).start()
     try:
         plain = rng.integers(0, 100, size=(2, 7))
         sampled = rng.integers(0, 100, size=(1, 5))
@@ -368,9 +366,10 @@ def test_stage_worker_executor_matches_solo(tiny_pipe):
         np.testing.assert_array_equal(got, outs["plain"][:, 7:])
 
         snap = ex.snapshot()
-        assert len(snap["stage_steps"]) == len(PARTITION)
-        assert all(s > 0 for s in snap["stage_steps"])
-        assert snap["active"] == 0 and snap["tokens"] >= 22
+        # 6 + 5 + 5 steps and the eos request's, each through every stage
+        assert snap["stage_steps"] > 16 * len(PARTITION)
+        assert snap["active"] == 0 and snap["pending"] == 0
+        assert snap["tokens"] >= 22
 
         with pytest.raises(ValueError, match="duplicate"):
             ex.submit("plain2", plain, 2)  # rid free, fine

@@ -1,5 +1,5 @@
 """Continuous batching + chunked prefill (ISSUE 16): iteration-level
-scheduling inside the decode executors.
+scheduling inside the decode executor.
 
 The invariants that let the serving plane interleave prompt ingress
 with decode steps without touching numerics:
@@ -26,8 +26,7 @@ import numpy as np
 import pytest
 
 from pipeedge_tpu.kv import KvPagePool, PagedKvBackend  # noqa: E402
-from pipeedge_tpu.parallel.batcher import (ContinuousBatcher,  # noqa: E402
-                                           StageWorkerExecutor)
+from pipeedge_tpu.parallel.batcher import ContinuousBatcher  # noqa: E402
 from pipeedge_tpu.parallel.speculative import SpeculativeDecoder  # noqa: E402
 from pipeedge_tpu.telemetry import metrics as prom  # noqa: E402
 
@@ -100,11 +99,11 @@ def test_chunked_wave_token_identical_to_dense(pipe):
     assert kv.pool.free_pages + cached == kv.pool.n_pages
 
 
-def test_chunked_stage_executor_token_identical(pipe):
-    """The worker-thread executor chunks at submit and re-enqueues at
-    _finish: same parity contract, same chunk accounting."""
+def test_chunked_thread_driven_token_identical(pipe):
+    """The executor on its own worker thread, fed by concurrent client
+    threads: same parity contract, same chunk accounting."""
     kv = _backend(pipe)
-    ex = StageWorkerExecutor(pipe, kv=kv, chunk_tokens=4)
+    ex = ContinuousBatcher(pipe, kv=kv, chunk_tokens=4).start()
     try:
         prompts = _prompts(2, lens=(17, 11), seed0=13)
         outs = {}
@@ -280,13 +279,13 @@ def test_cancel_mid_chunk_retires_and_frees_pages(pipe):
         + kv.trie.stats()["pages_cached"] == kv.pool.n_pages
 
 
-def test_deadline_expiry_mid_chunk_stage_executor(pipe):
-    """Same retire point on the worker-thread executor, driven by the
-    deadline flavor of cancellation: expired mid-prompt -> bare prompt
-    back, no leaked pages, slot freed for the next request."""
+def test_deadline_expiry_mid_chunk_thread_driven(pipe):
+    """Same retire point with the executor on its own worker thread,
+    driven by the deadline flavor of cancellation: expired mid-prompt ->
+    bare prompt back, no leaked pages, slot freed for the next request."""
     import time
     kv = _backend(pipe)
-    ex = StageWorkerExecutor(pipe, kv=kv, chunk_tokens=4)
+    ex = ContinuousBatcher(pipe, kv=kv, chunk_tokens=4).start()
     try:
         ids = _prompts(1, lens=(20,), seed0=53)[0]
         ex.submit("d", ids, 6, deadline=time.monotonic() + 0.001)

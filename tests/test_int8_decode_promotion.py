@@ -1,9 +1,9 @@
 """Int8-KV decode-attention promotion (ISSUE 19): the kernel opt-in is a
 constructor knob on DecodePipeline (`int8_decode_attend=`), resolved once
 at build — env `PIPEEDGE_INT8_DECODE_ATTEND` and the QuantizeCompute
-config are fallbacks — and BOTH production executors (ContinuousBatcher,
-StageWorkerExecutor) stay token-identical to the XLA dequant route while
-the KV pages hold int8 in the KvPagePool."""
+config are fallbacks — and the production executor (ContinuousBatcher),
+driven by `run()` and by its own worker thread, stays token-identical to
+the XLA dequant route while the KV pages hold int8 in the KvPagePool."""
 import threading
 
 import numpy as np
@@ -15,8 +15,7 @@ import jax.numpy as jnp  # noqa: E402
 from pipeedge_tpu.kv import PagedKvBackend  # noqa: E402
 from pipeedge_tpu.models import layers, registry  # noqa: E402
 from pipeedge_tpu.parallel import decode  # noqa: E402
-from pipeedge_tpu.parallel.batcher import (ContinuousBatcher,  # noqa: E402
-                                           StageWorkerExecutor)
+from pipeedge_tpu.parallel.batcher import ContinuousBatcher  # noqa: E402
 from pipeedge_tpu.telemetry import metrics as prom  # noqa: E402
 
 MODEL = "pipeedge/test-tiny-gpt2"
@@ -78,7 +77,7 @@ def test_constructor_arg_binds_optin(pipes):
     assert pipe_xla.int8_decode_optin == 0
 
 
-# -- both executors, token parity, int8 pages ---------------------------
+# -- the executor driven both ways, token parity, int8 pages ------------
 
 def _assert_pool_pages_int8(kv):
     for stage_leaves in kv.pool._arena:
@@ -105,10 +104,10 @@ def test_wave_batcher_token_identical_with_kernel(pipes):
     assert kv.pool.free_pages + cached == kv.pool.n_pages
 
 
-def test_stage_executor_token_identical_with_kernel(pipes):
+def test_thread_driven_token_identical_with_kernel(pipes):
     pipe_kernel, pipe_xla = pipes
     kv = _backend(pipe_kernel)
-    ex = StageWorkerExecutor(pipe_kernel, kv=kv)
+    ex = ContinuousBatcher(pipe_kernel, kv=kv).start()
     try:
         rng = np.random.default_rng(31)
         ids = rng.integers(0, 100, size=(1, 7))

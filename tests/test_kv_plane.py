@@ -2,7 +2,7 @@
 trie, eviction under pressure, token-budget admission, KV shipping, and
 the loopback disaggregated prefill/decode acceptance.
 
-Tier-1 by design (ISSUE 14): the paged executors must stay
+Tier-1 by design (ISSUE 14): the paged executor must stay
 TOKEN-IDENTICAL to the dense-cache path on a pinned seed, and the
 disaggregated split must produce the same tokens as the colocated path
 — these are the gates that let the serving plane swap its memory model
@@ -20,10 +20,10 @@ from pipeedge_tpu.kv import (KvPagePool, PagedKvBackend,  # noqa: E402
                              PoolExhausted, PrefillFleet, PrefixTrie,
                              pages_for)
 from pipeedge_tpu.kv import ship as ship_mod  # noqa: E402
-from pipeedge_tpu.parallel.batcher import (ContinuousBatcher,  # noqa: E402
-                                           StageWorkerExecutor)
+from pipeedge_tpu.parallel.batcher import ContinuousBatcher  # noqa: E402
 from pipeedge_tpu.parallel.decode import make_next_picker  # noqa: E402
 from pipeedge_tpu.telemetry import metrics as prom  # noqa: E402
+from test_serve import _await_live  # noqa: E402
 
 MODEL = "pipeedge/test-tiny-gpt2"
 PARTITION = [(1, 4), (5, 8)]
@@ -258,42 +258,44 @@ def test_token_budget_head_keeps_queue_position():
     assert order == ["big", "small"], order
 
 
-def test_paged_submit_rejects_bigger_than_pool(pipe):
-    """A reservation exceeding the WHOLE pool is rejected at submit on
-    both executors (waiting could never admit it; the wave batcher's
+@pytest.mark.parametrize("drive", ["run", "thread"])
+def test_paged_submit_rejects_bigger_than_pool(pipe, drive):
+    """A reservation exceeding the WHOLE pool is rejected at submit,
+    however the executor is driven (waiting could never admit it; the
     pending queue would otherwise wedge behind it forever) — and so is
     a hand-passed prefix handle (rejected at submit, not as a deferred
     crash of the wave loop)."""
     ids = np.zeros((1, 6), np.int64)    # 6+8 tokens -> 4 pages > 2
     b = ContinuousBatcher(pipe, kv=_backend(pipe, n_pages=2, page_size=4))
-    with pytest.raises(ValueError, match="KV page"):
-        b.submit("big", ids, new_tokens=8)
-    assert not b.pending and b.tick() is False
-    handle = pipe.precompute_prefix(np.asarray([[1, 2, 3, 4]]))
-    with pytest.raises(ValueError, match="prefix trie"):
-        b.submit("pfx", ids, new_tokens=2, prefix=handle)
-    ex = StageWorkerExecutor(pipe,
-                             kv=_backend(pipe, n_pages=2, page_size=4))
+    if drive == "thread":
+        b.start()
     try:
         with pytest.raises(ValueError, match="KV page"):
-            ex.submit("big", ids, 8)
+            b.submit("big", ids, new_tokens=8)
+        handle = pipe.precompute_prefix(np.asarray([[1, 2, 3, 4]]))
         with pytest.raises(ValueError, match="prefix trie"):
-            ex.submit("pfx", ids, 2, prefix=handle)
-        assert ex.active == 0
+            b.submit("pfx", ids, new_tokens=2, prefix=handle)
+        assert not b.pending and b.active == 0 and not b.live_rids()
+        if drive == "run":
+            assert b.tick() is False
     finally:
-        ex.stop()
+        b.stop()
 
 
 def test_paged_stop_wakes_page_blocked_submitter(pipe):
-    """The wake-on-death/stop contract extends to PAGE waits: a
-    submitter parked on pool availability (slots free, pages not) must
-    raise on stop(), not hang — the paged twin of
-    test_stage_executor_stop_wakes_blocked_submitter."""
+    """The wake-on-stop contract extends to PAGE waits: a request kept
+    pending by pool availability (slots free, pages not) must have its
+    waiter raise on stop(), not hang — the paged twin of
+    test_executor_stop_wakes_pending_submitter."""
     kv = _backend(pipe, n_pages=16, page_size=4)
-    ex = StageWorkerExecutor(pipe, kv=kv, max_active=8)
+    ex = ContinuousBatcher(pipe, kv=kv, max_active=8)
     errs = {}
     first_token = threading.Event()
     ids = np.zeros((1, 4), np.int64)
+
+    def slow_token(step, tok):
+        first_token.set()
+        time.sleep(0.05)
 
     def client(rid, tokens, **kw):
         try:
@@ -302,27 +304,29 @@ def test_paged_stop_wakes_page_blocked_submitter(pipe):
         except RuntimeError as exc:
             errs[rid] = str(exc)
 
-    # "a" reserves the WHOLE pool (4+44 tokens -> 12 pages -> bucket 16)
-    # with a generation long enough that it cannot complete between the
-    # first streamed token and stop()
+    # "a" reserves 12 of the 16 pages (4+44 tokens = max_len) with a
+    # generation that cannot complete between the first streamed
+    # token and stop(): its on_token holds every step 50 ms. Both clients
+    # submit, in order, before the worker starts
     t_a = threading.Thread(target=client, args=("a", 44), daemon=True,
-                           kwargs={"on_token":
-                                   lambda s, t: first_token.set()})
+                           kwargs={"on_token": slow_token})
     t_a.start()
-    assert first_token.wait(timeout=120)
-    # "b" passes the slot semaphore and parks in the PAGE wait
-    t_b = threading.Thread(target=client, args=("b", 4), daemon=True)
+    _await_live(ex, "a")
+    t_b = threading.Thread(target=client, args=("b", 20), daemon=True)
     t_b.start()
-    deadline = time.monotonic() + 120
-    while time.monotonic() < deadline and "b" not in ex._live:
-        time.sleep(0.01)
-    assert "b" in ex._live
+    _await_live(ex, "b")
+    ex.start()
+    assert first_token.wait(timeout=120)
+    # "b" (4+20 tokens -> 6 pages -> bucket 8) has a slot to take
+    # (max_active=8) and 4 pages too few: it stays pending
+    snap = ex.snapshot()
+    assert (snap["active"], snap["pending"]) == (1, 1)
     ex.stop()
     t_a.join(timeout=120)
     t_b.join(timeout=120)
     assert not t_a.is_alive() and not t_b.is_alive(), \
         "stop() left a page-blocked submitter hanging"
-    assert "b" in errs
+    assert "in flight" in errs.get("a", "") and "b" in errs
 
 
 def test_paged_batcher_active_exceeds_dense_slot_equivalent(pipe):
@@ -386,11 +390,12 @@ def test_paged_wave_batcher_token_identical_to_dense(pipe):
     assert kv.pool.free_pages + cached == kv.pool.n_pages
 
 
-def test_paged_stage_executor_token_identical_and_prefix_shared(pipe):
-    """StageWorkerExecutor over pages: concurrent submitters, token
-    parity, and the second same-prompt request hits the trie."""
+def test_paged_thread_driven_token_identical_and_prefix_shared(pipe):
+    """The executor on its own worker thread over pages: concurrent
+    submitters, token parity, and the second same-prompt request hits
+    the trie."""
     kv = _backend(pipe)
-    ex = StageWorkerExecutor(pipe, kv=kv)
+    ex = ContinuousBatcher(pipe, kv=kv).start()
     try:
         rng = np.random.default_rng(17)
         ids = rng.integers(0, 100, size=(1, 9))
@@ -621,7 +626,7 @@ def test_shipped_install_publishes_prefix(pipe):
                          registry=prom.Registry())
     rng = np.random.default_rng(47)
     ids = rng.integers(0, 100, size=(1, 8))
-    ex = StageWorkerExecutor(pipe, kv=kv)
+    ex = ContinuousBatcher(pipe, kv=kv).start()
     try:
         ex.submit("shipped", ids, 4, shipped=fleet.prefill(ids))
         out = ex.wait("shipped", timeout=300)

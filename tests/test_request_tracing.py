@@ -396,7 +396,7 @@ def test_streaming_shed_counts_class_outcome_matrix():
         registry.get_model_entry("pipeedge/test-tiny-gpt2").family.FAMILY,
         registry.get_model_config("pipeedge/test-tiny-gpt2"),
         [(1, total)], [params], max_len=32)
-    svc = serve_mod._Service(pipe, executor="wave")
+    svc = serve_mod._Service(pipe)
     try:
         def always_shed(request_class, deadline_s=None, rid=None,
                         tokens=0):

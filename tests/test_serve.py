@@ -207,23 +207,17 @@ def test_speculative_unavailable_without_draft(server):
         assert exc.code == 400
 
 
-@pytest.fixture(scope="module")
-def stage_server():
-    yield from _spawn_server(("--executor", "stage"))
-
-
-def test_stage_executor_matches_solo_and_reports_workers(stage_server,
-                                                         solo_pipe):
-    """--executor stage: one worker thread per pipeline stage produces
-    the same tokens as solo runs; /healthz reports per-worker stats."""
-    port = stage_server
+def test_served_executor_matches_solo_and_reports_stats(server, solo_pipe):
+    """The executor's own worker thread, behind HTTP, produces the same
+    tokens as solo runs; /healthz reports the one worker's stats."""
+    port = server
     rng = np.random.default_rng(17)
     ids = rng.integers(0, 100, size=(2, 8)).tolist()
     got = _post(port, "/generate", {"ids": ids, "new_tokens": 6})["ids"]
     want = np.asarray(solo_pipe.generate(np.asarray(ids), 6))
     np.testing.assert_array_equal(np.asarray(got), want)
 
-    # prefix reuse flows through the stage executor too
+    # prefix reuse flows through the served executor too
     prefix = rng.integers(0, 100, size=(6,)).tolist()
     reg = _post(port, "/prefix", {"ids": prefix})
     suffix = rng.integers(0, 100, size=(1, 4)).tolist()
@@ -237,12 +231,12 @@ def test_stage_executor_matches_solo_and_reports_workers(stage_server,
     with urllib.request.urlopen(
             f"http://127.0.0.1:{port}/healthz", timeout=30) as resp:
         health = json.loads(resp.read())
-    assert health["executor"] == "stage"
+    assert health["executor"] == "wave"
     stats = health["stats"]
-    assert len(stats["stage_steps"]) == 2        # one counter per worker
-    assert all(s > 0 for s in stats["stage_steps"])
-    assert len(stats["busy"]) == 2 and len(stats["queued"]) == 2
-    assert stats["active"] == 0
+    # two stages: every token of both requests took two stage-steps
+    assert stats["stage_steps"] >= 2 * (6 + 5)
+    assert stats["ticks"] > 0 and stats["tokens"] >= 2 * 6 + 5
+    assert stats["active"] == 0 and stats["pending"] == 0
 
 
 def _stream_lines(port, obj, timeout=120):
@@ -270,12 +264,12 @@ def _stream_lines(port, obj, timeout=120):
         conn.close()
 
 
-@pytest.mark.parametrize("fixture_name", ["server", "stage_server"])
+@pytest.mark.parametrize("fixture_name", ["server", "tight_server"])
 def test_streaming_generate(fixture_name, request, solo_pipe):
     """"stream": true returns one x-ndjson line per decode step followed
     by a final line whose ids equal the non-streaming response; the
-    final line records server-side first-token latency. Works on both
-    executors."""
+    final line records server-side first-token latency. Works with
+    slots to spare and on the single-slot server."""
     port = request.getfixturevalue(fixture_name)
     rng = np.random.default_rng(21)
     ids = rng.integers(0, 100, size=(2, 8)).tolist()
@@ -312,11 +306,12 @@ def test_streaming_eos_final_line_is_masked(server, solo_pipe):
     assert len(lines) - 1 == lines[-1]["steps"]
 
 
-@pytest.mark.parametrize("fixture_name", ["server", "stage_server"])
+@pytest.mark.parametrize("fixture_name", ["server", "tight_server"])
 def test_concurrent_clients(fixture_name, request, solo_pipe):
     """Several clients hammering /generate concurrently (mixed plain,
     sampled, prefix, streaming) each get exactly their solo-run tokens —
-    the executor isolation contract under real HTTP concurrency."""
+    the executor isolation contract under real HTTP concurrency, whether
+    they share the pipeline or queue for its single slot."""
     import threading
     port = request.getfixturevalue(fixture_name)
     rng = np.random.default_rng(29)
@@ -431,29 +426,38 @@ def _tiny_pipe(partition=None, max_len=64):
         registry.get_model_config(MODEL), partition, params, max_len=max_len)
 
 
-def test_stage_executor_stop_wakes_blocked_submitter():
-    """stop() must over-release the admission semaphore like _die() does:
-    a submitter blocked in _slots.acquire() (pipeline full) wakes and
-    raises instead of hanging forever (ADVICE.md r5).
+def _await_live(ex, rid):
+    """Until `rid` is in the executor's live set (its submit has landed)."""
+    deadline = time.monotonic() + 120
+    while rid not in (ex.live_rids() or ()):
+        assert time.monotonic() < deadline, f"{rid!r} never submitted"
+        time.sleep(0.01)
+
+
+def test_executor_stop_wakes_pending_submitter():
+    """stop() fails the waiter of a request that never got a slot, not
+    only those in flight: "b" sits in `pending` behind "a" (max_active=1)
+    and its wait raises instead of hanging forever (ADVICE.md r5).
 
     Deterministic by construction (this flaked under full-suite load
-    when it was sleep-paced): "a" is known admitted once its FIRST token
-    streams back (on_token fires from the last stage's worker), and "b"
-    is known registered once it appears in the executor's live set —
-    which happens BEFORE its semaphore wait, so stop()'s over-release
-    reaches it whether it is already parked in acquire() or still on the
-    way there (both paths re-check _dead and raise). "a" cannot complete
-    early: its 44-token budget would need the whole pipeline to drain
-    between two adjacent host steps here."""
+    when it was sleep-paced): both clients submit, in order, before the
+    worker starts, "a" is known admitted once its FIRST token streams
+    back (on_token fires from the worker), and it cannot complete early:
+    its on_token holds every step 50 ms, so its 55 tokens outlast by far
+    the thread switch between its first token and stop()."""
     import threading
 
     import jax.numpy as jnp
 
-    from pipeedge_tpu.parallel.batcher import StageWorkerExecutor
+    from pipeedge_tpu.parallel.batcher import ContinuousBatcher
 
-    ex = StageWorkerExecutor(_tiny_pipe(), max_active=1)
+    ex = ContinuousBatcher(_tiny_pipe(), max_active=1)
     errs = {}
     first_token = threading.Event()
+
+    def slow_token(step, tok):
+        first_token.set()
+        time.sleep(0.05)
 
     def client(rid, tokens, **kw):
         try:
@@ -462,33 +466,108 @@ def test_stage_executor_stop_wakes_blocked_submitter():
         except RuntimeError as exc:
             errs[rid] = str(exc)
 
-    # "a" holds the only admission slot with a long generation
-    t_a = threading.Thread(target=client, args=("a", 44), daemon=True,
-                           kwargs={"on_token":
-                                   lambda s, t: first_token.set()})
+    # "a" will hold the only admission slot with a long generation
+    t_a = threading.Thread(target=client, args=("a", 55), daemon=True,
+                           kwargs={"on_token": slow_token})
     t_a.start()
-    assert first_token.wait(timeout=120), "'a' never started decoding"
-    # "b" heads for _slots.acquire (admission backpressure): it is in
-    # the live set before it can block, so this wait is bounded by
-    # thread scheduling only, not by any pipeline progress
+    _await_live(ex, "a")
     t_b = threading.Thread(target=client, args=("b", 2), daemon=True)
     t_b.start()
-    deadline = time.monotonic() + 120
-    while time.monotonic() < deadline and "b" not in ex._live:
-        time.sleep(0.01)
-    assert "b" in ex._live, "'b' never reached admission"
+    _await_live(ex, "b")
+    ex.start()
+    assert first_token.wait(timeout=120), "'a' never started decoding"
+    assert ex.snapshot()["pending"] == 1      # "b": no slot, it waits
     ex.stop()
     t_a.join(timeout=120)
     t_b.join(timeout=120)
     assert not t_a.is_alive() and not t_b.is_alive(), \
         "stop() left a submitter/waiter hanging"
     assert "in flight" in errs.get("a", "")
-    # "b" raises either from the admission wake or from wait()
-    assert "b" in errs
+    assert "in flight" in errs.get("b", "")
+    # ... and so is every later submit
+    with pytest.raises(RuntimeError, match="in flight"):
+        ex.submit("c", jnp.zeros((1, 4), jnp.int32), 2)
 
 
-@pytest.mark.parametrize("executor", ["wave", "stage"])
-def test_cancel_flag_completes_request_early(executor):
+def test_executor_worker_death_fails_current_and_later_waits():
+    """A worker whose tick() raises marks the executor dead: the waiter of
+    the request in flight raises with that error instead of hanging, and
+    so does every later submit and wait (what /healthz answers 503 on)."""
+    import threading
+
+    import jax.numpy as jnp
+
+    from pipeedge_tpu.parallel.batcher import ContinuousBatcher
+
+    ex = ContinuousBatcher(_tiny_pipe(), max_active=1)
+
+    def broken_token(step, tok):
+        raise OSError("the device went away")
+
+    ids = jnp.zeros((1, 4), jnp.int32)
+    ex.submit("r", ids, 8, on_token=broken_token)
+    quiet, threading.excepthook = threading.excepthook, lambda args: None
+    try:                          # the worker re-raises as it dies: expected
+        ex.start()
+        with pytest.raises(RuntimeError, match="the device went away"):
+            ex.wait("r", timeout=120)
+        ex._worker.join(timeout=120)
+    finally:
+        threading.excepthook = quiet
+    assert isinstance(ex.dead, OSError) and not ex._worker.is_alive()
+    with pytest.raises(RuntimeError, match="the device went away"):
+        ex.wait("never-submitted", timeout=120)
+    with pytest.raises(RuntimeError, match="the device went away"):
+        ex.submit("later", ids, 2)
+    ex.stop()                     # a dead executor still stops cleanly
+
+
+def test_eight_client_threads_match_run():
+    """Eight threads submitting and waiting at once on the thread-driven
+    executor get, each, the tokens `run()` gives the same requests."""
+    import threading
+
+    from pipeedge_tpu.parallel.batcher import ContinuousBatcher
+
+    pipe = _tiny_pipe(partition=[(1, 4), (5, 8)])
+    rng = np.random.default_rng(83)
+    jobs = [(f"r{i}", rng.integers(0, 100, size=(1 + i % 2, 3 + i)),
+             4 + i % 3, {} if i % 2 else {"temperature": 0.9, "seed": i})
+            for i in range(8)]
+    offline = ContinuousBatcher(pipe, max_active=3)
+    for rid, ids, n, kw in jobs:
+        offline.submit(rid, ids, n, **kw)
+    want = offline.run()
+
+    ex = ContinuousBatcher(pipe, max_active=3).start()
+    got, errs = {}, []
+
+    def client(rid, ids, n, kw):
+        try:
+            ex.submit(rid, ids, n, **kw)
+            got[rid] = ex.wait(rid, timeout=300)
+        except BaseException as exc:   # noqa: BLE001 — reported below
+            errs.append((rid, exc))
+
+    threads = [threading.Thread(target=client, args=job, daemon=True)
+               for job in jobs]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+            assert not t.is_alive()
+    finally:
+        ex.stop()
+    assert not errs, errs
+    assert set(got) == set(want)
+    for rid in want:
+        np.testing.assert_array_equal(got[rid], want[rid])
+    assert ex.dead is not None and not ex.live_rids()   # stopped, drained
+
+
+@pytest.mark.parametrize("drive", ["run", "thread"])
+def test_cancel_flag_completes_request_early(drive):
     """A set `cancel` flag finishes the request at its next pick with the
     tokens decoded so far, freeing executor capacity for live requests
     (the serve.py streaming-disconnect contract)."""
@@ -496,8 +575,7 @@ def test_cancel_flag_completes_request_early(executor):
 
     import jax.numpy as jnp
 
-    from pipeedge_tpu.parallel.batcher import (ContinuousBatcher,
-                                               StageWorkerExecutor)
+    from pipeedge_tpu.parallel.batcher import ContinuousBatcher
 
     pipe = _tiny_pipe()
     cancel = threading.Event()
@@ -510,17 +588,15 @@ def test_cancel_flag_completes_request_early(executor):
             cancel.set()
 
     ids = jnp.zeros((1, 4), jnp.int32)
-    if executor == "stage":
-        ex = StageWorkerExecutor(pipe, max_active=1)
-        try:
-            ex.submit("r", ids, 40, on_token=on_token, cancel=cancel)
-            out = ex.wait("r", timeout=120)
-        finally:
-            ex.stop()
-    else:
-        batcher = ContinuousBatcher(pipe, max_active=1)
-        batcher.submit("r", ids, 40, on_token=on_token, cancel=cancel)
-        out = batcher.run()["r"]
+    batcher = ContinuousBatcher(pipe, max_active=1)
+    if drive == "thread":
+        batcher.start()
+    batcher.submit("r", ids, 40, on_token=on_token, cancel=cancel)
+    try:
+        out = (batcher.wait("r", timeout=120) if drive == "thread"
+               else batcher.run()["r"])
+    finally:
+        batcher.stop()
     # prompt (4) + the tokens decoded before the cancel took effect —
     # far short of the 40-token cap
     assert out.shape[1] == 4 + stop_after
@@ -529,9 +605,9 @@ def test_cancel_flag_completes_request_early(executor):
 
 @pytest.fixture(scope="module")
 def tight_server():
-    """Stage executor with a SINGLE admission slot: a dead request that
-    failed to free its slot would block every later request."""
-    yield from _spawn_server(("--executor", "stage", "--max-active", "1"))
+    """A server with a SINGLE admission slot: a dead request that failed
+    to free its slot would block every later request."""
+    yield from _spawn_server(("--max-active", "1"))
 
 
 def test_streaming_disconnect_cancels_generation(tight_server):
@@ -634,16 +710,16 @@ def test_streaming_disconnect_storm_does_not_exhaust_slots(tight_server):
     assert len(out["ids"][0]) == 5
 
 
-def test_stage_executor_stop_fails_live_waiters():
-    """StageWorkerExecutor.stop() with requests in flight fails their
-    waiters instead of hanging them (code-review finding)."""
+def test_executor_stop_fails_live_waiters():
+    """stop() with requests in flight fails their waiters instead of
+    hanging them (code-review finding)."""
     import threading
 
     import jax.numpy as jnp
 
     from pipeedge_tpu.models import registry
     from pipeedge_tpu.parallel import decode
-    from pipeedge_tpu.parallel.batcher import StageWorkerExecutor
+    from pipeedge_tpu.parallel.batcher import ContinuousBatcher
 
     total = registry.get_model_layers(MODEL)
     _, params, _ = registry.module_shard_factory(MODEL, None, 1, total,
@@ -652,11 +728,17 @@ def test_stage_executor_stop_fails_live_waiters():
         registry.get_model_entry(MODEL).family.FAMILY,
         registry.get_model_config(MODEL), [(1, total)], [params],
         max_len=64)
-    ex = StageWorkerExecutor(pipe)
+    ex = ContinuousBatcher(pipe).start()
     errs = {}
+    first_token = threading.Event()
+
+    def slow_token(step, tok):
+        first_token.set()
+        time.sleep(0.05)
 
     def client():
-        ex.submit("r", jnp.zeros((1, 4), jnp.int32), 40)
+        ex.submit("r", jnp.zeros((1, 4), jnp.int32), 55,
+                  on_token=slow_token)
         try:
             ex.wait("r", timeout=120)
         except RuntimeError as exc:
@@ -664,7 +746,8 @@ def test_stage_executor_stop_fails_live_waiters():
 
     t = threading.Thread(target=client)
     t.start()
-    time.sleep(0.5)          # let the request enter the pipeline
+    # in the pipeline for certain, and seconds from its end
+    assert first_token.wait(timeout=120)
     ex.stop()
     t.join(timeout=120)
     assert not t.is_alive()
@@ -761,7 +844,7 @@ def test_degraded_in_flight_request_replayed(solo_pipe):
 
     from tools import serve as serve_mod
 
-    svc = serve_mod._Service(solo_pipe, executor="wave")
+    svc = serve_mod._Service(solo_pipe)
     try:
         calls = []
         orig = svc._generate_once

@@ -1,5 +1,5 @@
 """The serving loop under the one probe (docs/OBSERVABILITY.md): the decode
-executors' phases as `exec` spans, the HTTP thread's two waits, the span
+executor's phases as `exec` spans, the HTTP thread's two waits, the span
 digest and the compile counters on /metrics, stable names for the jitted
 stage programs, and the profiler hook."""
 import json
@@ -18,8 +18,7 @@ import pytest
 from benchmark import prom as bench_prom
 from pipeedge_tpu import telemetry
 from pipeedge_tpu.parallel import decode
-from pipeedge_tpu.parallel.batcher import (ContinuousBatcher,
-                                           StageWorkerExecutor)
+from pipeedge_tpu.parallel.batcher import ContinuousBatcher
 from pipeedge_tpu.telemetry import metrics as prom
 from pipeedge_tpu.utils import tracing
 from test_serve import _spawn_server
@@ -40,26 +39,28 @@ def _prompts():
             for _ in range(REQUESTS)]
 
 
-def _run_workers(pipe, tag):
-    executor = StageWorkerExecutor(pipe, max_active=REQUESTS)
+def _run_thread(pipe, tag):
+    """The served way: the executor's own worker thread ticks."""
+    batcher = ContinuousBatcher(pipe, max_active=REQUESTS).start()
     try:
         for i, ids in enumerate(_prompts()):
-            executor.submit(f"{tag}{i}", ids, new_tokens=NEW_TOKENS)
+            batcher.submit(f"{tag}{i}", ids, new_tokens=NEW_TOKENS)
         for i in range(REQUESTS):
-            executor.wait(f"{tag}{i}", timeout=120)
+            batcher.wait(f"{tag}{i}", timeout=120)
     finally:
-        executor.stop()
+        batcher.stop()
 
 
-def _run_wave(pipe, tag):
+def _run_offline(pipe, tag):
+    """The offline way: the caller's thread ticks (`run()`)."""
     batcher = ContinuousBatcher(pipe, max_active=REQUESTS)
     for i, ids in enumerate(_prompts()):
         batcher.submit(f"{tag}{i}", ids, new_tokens=NEW_TOKENS)
     batcher.run()
 
 
-@pytest.mark.parametrize("run", [_run_workers, _run_wave],
-                         ids=["workers", "wave"])
+@pytest.mark.parametrize("run", [_run_thread, _run_offline],
+                         ids=["thread", "run"])
 def test_executor_worker_is_always_inside_a_named_span(pipe, run):
     """Per token one `stage/exec0`, `exec/pick` and `exec/emit`, one
     `exec/retire` per request, all request-tagged; the spans of the one
@@ -106,10 +107,10 @@ def _run_generate(pipe, tag):
 
 
 @pytest.mark.parametrize("run, step, pick", [
-    (_run_workers, "stage/exec0", "exec/pick"),
-    (_run_wave, "stage/exec0", "exec/pick"),
+    (_run_thread, "stage/exec0", "exec/pick"),
+    (_run_offline, "stage/exec0", "exec/pick"),
     (_run_generate, "generate/prefill", "generate/pick")],
-    ids=["workers", "wave", "generate"])
+    ids=["thread", "run", "generate"])
 def test_a_token_is_a_stage_program_and_a_pick_and_nothing_eager(
         pipe, tmp_path, run, step, pick):
     """What the profiler sees the decoding thread dispatch, from its first
@@ -143,11 +144,11 @@ def test_a_token_is_a_stage_program_and_a_pick_and_nothing_eager(
 
 
 def test_worker_waits_in_a_span_of_its_own(pipe):
-    """A stage worker with nothing to do sits in `exec/wait{i}`: its idle
-    time is named, and is a digest row like every phase."""
+    """The executor's worker with nothing to do sits in `exec/wait0`: its
+    idle time is named, and is a digest row like every phase."""
     rec = telemetry.configure()
     try:
-        executor = StageWorkerExecutor(pipe, max_active=1)
+        executor = ContinuousBatcher(pipe, max_active=1).start()
         time.sleep(0.05)
         executor.stop()
     finally:

@@ -1,10 +1,11 @@
-"""HTTP serving front end over the pipelined decode executors.
+"""HTTP serving front end over the pipelined decode executor.
 
 Beyond-reference serving surface (the reference runtime is single-shot
-batch inference; SURVEY.md §2.4): a stdlib-only JSON/HTTP server that
-drives a `ContinuousBatcher` (wave executor) or a `StageWorkerExecutor`
-(one worker thread pinned per pipeline stage) continuously — requests
-admit as they arrive, share the pipeline, and prompt prefixes
+batch inference; SURVEY.md §2.4): a stdlib-only JSON/HTTP server over one
+`ContinuousBatcher` (parallel/batcher.py), whose own worker thread ticks
+the waves — strict wave semantics, JAX async dispatch keeps every stage
+busy from a single host thread. Handler threads submit to it and wait on
+it: requests admit as they arrive, share the pipeline, and prompt prefixes
 registered once via /prefix are reused by any number of /generate
 requests (prompt caching).
 
@@ -16,7 +17,7 @@ surge shed excess load with 503 + a Retry-After computed from the
 observed service rate instead of degrading every request. Requests may
 carry `"class"` ("interactive" | "batch" | "best_effort", default
 interactive) and `"deadline_ms"` (budget from receipt); the deadline
-propagates into the executors, which cancel expired work at the next
+propagates into the executor, which cancels expired work at the next
 decode-step boundary (HTTP 504, `pipeedge_deadline_exceeded_total`).
 
 Endpoints (all JSON unless noted):
@@ -35,9 +36,7 @@ Endpoints (all JSON unless noted):
                                pending, prefixes,
                                degraded_entered_total,
                                failover_replays_total,
-                               rejoined_ranks_total, last_dead_rank, ...;
-                               stage mode adds per-worker
-                               stage_steps/busy/queued}};
+                               rejoined_ranks_total, last_dead_rank, ...}};
                                the degraded object carries a "phase"
                                ("degraded" | "healing");
                                HTTP 503 once a serving worker has died
@@ -108,17 +107,8 @@ authoritative (eos-masked) result, identical to the non-streaming
 response. First-token latency is measured server-side from request
 receipt to the first step's readback.
 
-Executors (`--executor`):
-- `wave` (default): one worker thread ticks the batcher
-  (`ContinuousBatcher`) — strict wave semantics, JAX async dispatch
-  keeps every stage busy from a single host thread.
-- `stage`: one worker thread PER pipeline stage
-  (`StageWorkerExecutor`) — host-side dispatch of different stages
-  overlaps, and the last stage's token picks / eos readbacks never
-  stall earlier stages' dispatch. healthz reports per-worker stats.
-
-Paged KV plane (`--kv-pages N`, docs/SERVING.md): the executors swap
-their dense per-request cache slots for page tables over one shared
+Paged KV plane (`--kv-pages N`, docs/SERVING.md): the executor swaps
+its dense per-request cache slots for page tables over one shared
 pool (pipeedge_tpu/kv/) — admission charges a KV TOKEN budget
 (prompt + max-new-tokens pages) instead of max_active slots, prompt
 prefixes are shared across requests automatically through a token-hash
@@ -138,9 +128,9 @@ JAX dispatch is thread-safe, so the batcher keeps serving while a
 speculative generation runs (round-4 advice).
 
 Tokens are identical to solo `DecodePipeline.generate` runs with the
-same settings — the executors' shared contract (tests/test_serve.py).
+same settings — the executor's contract (tests/test_serve.py).
 
-Usage: python tools/serve.py -m gpt2 [--port 8321] [--executor stage] ...
+Usage: python tools/serve.py -m gpt2 [--port 8321] ...
 """
 import argparse
 import json
@@ -165,7 +155,7 @@ from pipeedge_tpu.serving import (AdmissionController,  # noqa: E402
 from pipeedge_tpu.telemetry import collector as fleet_obs  # noqa: E402
 from pipeedge_tpu.telemetry import flight  # noqa: E402
 from pipeedge_tpu.telemetry import metrics as prom  # noqa: E402
-from pipeedge_tpu.utils.threads import make_condition, make_lock  # noqa: E402
+from pipeedge_tpu.utils.threads import make_lock  # noqa: E402
 
 # request outcomes the per-class counter tracks (the request-class x
 # outcome matrix — pre-declared at service construction, pipelint PL501)
@@ -214,7 +204,7 @@ class _Service:
     and wait for (or stream) their results."""
 
     def __init__(self, pipe, max_active=None, max_prefixes=8, spec=None,
-                 executor="wave", edge_itemsize=2,
+                 edge_itemsize=2,
                  admission_enabled=True, queue_capacity=64,
                  class_rates=None, class_deadlines_s=None,
                  brownout_enabled=True, brownout_marks=None,
@@ -227,13 +217,11 @@ class _Service:
                  slo_burn_slow=300.0, slo_burn_threshold=10.0):
         from collections import OrderedDict, deque
 
-        from pipeedge_tpu.parallel.batcher import (ContinuousBatcher,
-                                                   StageWorkerExecutor)
+        from pipeedge_tpu.parallel.batcher import ContinuousBatcher
         self.pipe = pipe
         self.spec = spec
-        self.executor = executor
         # -- paged KV plane (docs/SERVING.md, pipeedge_tpu/kv) ----------
-        # kv_pages > 0 swaps the executors' dense per-request cache
+        # kv_pages > 0 swaps the executor's dense per-request cache
         # slots for page tables over one shared pool (+ the prefix
         # trie); admission then runs on a KV TOKEN budget. The optional
         # prefill fleet (--disaggregate) runs prompt passes on its OWN
@@ -279,7 +267,6 @@ class _Service:
                 "while disaggregation was configured, by reason")
             for reason in ("unavailable", "brownout"):
                 self.m_prefill_colocated.declare(reason=reason)
-        self.cond = make_condition("serve.results")
         # -- /metrics + healthz counters (one source of truth) ----------
         # the registry instruments below ARE the state: healthz's stats
         # read them back (stats()), so both surfaces always agree — even
@@ -355,11 +342,9 @@ class _Service:
         self.max_prefixes = max_prefixes
         self._next_rid = 0
         self._next_pid = 0
-        self._stop = False
-        self._dead: Optional[BaseException] = None
         # failover window (enter_degraded/exit_degraded): while set, new
         # work is refused with 503 + Retry-After and healthz reports the
-        # dead rank; unlike `_dead` it is expected to clear
+        # dead rank; unlike a dead executor it is expected to clear
         self.degraded_info: Optional[dict] = None
         # graceful drain (POST /drain, routed fleets): new admits are
         # refused 503 + Retry-After while in-flight requests complete;
@@ -379,36 +364,22 @@ class _Service:
         # admission queue at every decode-step boundary so joiners ride
         # the next tick instead of the next completion. `_on_step` is a
         # bound closure because the admission controller is constructed
-        # AFTER the executors (it needs their concurrency bound).
+        # AFTER the executor (it needs its concurrency bound).
         self.chunked_prefill = int(chunked_prefill)
         self.step_join = bool(step_join)
-        if executor == "stage":
-            self.exec = StageWorkerExecutor(pipe, max_active=max_active,
-                                            kv=self.kv_backend,
-                                            chunk_tokens=self.chunked_prefill,
-                                            step_join=self.step_join,
-                                            on_step=self._on_step)
-            self.batcher = None
-            self.worker = None
-        elif executor == "wave":
-            self.exec = None
-            self.batcher = ContinuousBatcher(pipe, max_active=max_active,
-                                             kv=self.kv_backend,
-                                             chunk_tokens=self.chunked_prefill,
-                                             prefill_budget=prefill_budget,
-                                             step_join=self.step_join,
-                                             on_step=self._on_step)
-            self.worker = threading.Thread(target=self._loop, daemon=True)
-            self.worker.start()
-        else:
-            raise ValueError(f"unknown executor {executor!r} "
-                             "(expected 'wave' or 'stage')")
+        self.executor = ContinuousBatcher(
+            pipe, max_active=max_active, kv=self.kv_backend,
+            chunk_tokens=self.chunked_prefill,
+            prefill_budget=prefill_budget, step_join=self.step_join,
+            on_step=self._on_step).start()
+        # ONE lock: the prefix registry, the degraded window and the rid
+        # counter share the condition the executor's worker ticks under
+        self.cond = self.executor.cond
         # -- overload-protection plane (docs/SERVING.md) ----------------
         # admission concurrency mirrors the executor's own bound, so the
         # EDF queue is the ONLY place requests wait and the executor
         # admits a granted request immediately
-        concurrency = (self.exec.max_active if self.exec is not None
-                       else self.batcher.max_active)
+        concurrency = self.executor.max_active
         self.m_deadline = prom.REGISTRY.counter(
             "pipeedge_deadline_exceeded_total",
             "requests whose deadline expired mid-flight (cancelled at a "
@@ -450,43 +421,15 @@ class _Service:
         admission queue at every step boundary, so a joiner whose slot
         or token charge just freed is granted mid-request instead of
         waiting out the whole completion. Cheap no-op when the queue is
-        empty; tolerant of construction order (the executors exist
+        empty; tolerant of construction order (the executor exists
         before the admission controller does)."""
         adm = getattr(self, "admission", None)
         if adm is not None:
             adm.notify_step()
 
-    def _loop(self):
-        while True:
-            # `exec/wait0`, the wave worker's only blocking wait: first
-            # for the condition's lock, which every submitting and every
-            # waiting handler thread shares with it, then for work
-            with telemetry.span("exec", "wait0", stage=0):
-                self.cond.acquire()
-                while not self._stop and not (
-                        self.batcher.pending or self.batcher.active):
-                    self.cond.wait()
-            try:
-                if self._stop:
-                    return
-                try:
-                    self.batcher.tick()
-                except BaseException as exc:   # noqa: BLE001 — a wedged
-                    # worker would hang every waiter forever; record the
-                    # failure so they raise instead
-                    self._dead = exc
-                    self.cond.notify_all()
-                    raise
-                if self.batcher.results:
-                    self.cond.notify_all()
-            finally:
-                self.cond.release()
-
     @property
     def dead(self) -> Optional[BaseException]:
-        if self._dead is not None:
-            return self._dead
-        return self.exec._dead if self.exec is not None else None
+        return self.executor.dead
 
     def add_prefix(self, ids):
         with self.cond:
@@ -567,17 +510,8 @@ class _Service:
         """Snapshot of every live executor request id — the orphan
         sweep's liveness set. None = the snapshot raced a mutation
         (skip this sweep; the next tick retries)."""
-        src = (self.exec._live if self.exec is not None
-               else self.batcher._live_rids)
-        for _ in range(3):
-            try:
-                live = set(src)
-                break
-            except RuntimeError:     # set mutated during copy
-                continue
-        else:
-            return None
-        if self.spec is not None:
+        live = self.executor.live_rids()
+        if live is not None and self.spec is not None:
             # paged speculative rounds reserve pages from the decode
             # plane's pool under their own owner ids — union them in so
             # a mid-generate speculative request survives the sweep
@@ -645,10 +579,8 @@ class _Service:
                     # more step boundaries per second (identity when the
                     # lever is unarmed — clamp_chunk_tokens == 0)
                     want = self.brownout.clamp_chunk(self.chunked_prefill)
-                    ex = self.exec if self.exec is not None \
-                        else self.batcher
-                    if ex.chunk_tokens != want:
-                        ex.set_chunk_tokens(want)
+                    if self.executor.chunk_tokens != want:
+                        self.executor.set_chunk_tokens(want)
                         self.flight.note("chunk_clamp", chunk_tokens=want)
             if self.kv_backend is not None and ticks % sweep_every == 0:
                 # liveness passed as a CALLABLE: the sweep snapshots
@@ -900,15 +832,12 @@ class _Service:
             # size, the EFFECTIVE one (brownout may have clamped it),
             # and how many chunk waves have run — the serve_kv bench's
             # chunked-arm evidence (docs/SERVING.md)
-            ex = self.exec if self.exec is not None else self.batcher
             s["scheduler"] = {
                 "chunked_prefill": self.chunked_prefill,
-                "chunk_tokens": ex.chunk_tokens,
+                "chunk_tokens": self.executor.chunk_tokens,
                 "step_join": self.step_join,
                 "prefill_chunks": int(
-                    self.exec.snapshot()["prefill_chunks"]
-                    if self.exec is not None
-                    else self.batcher.stats["prefill_chunks"]),
+                    self.executor.snapshot()["prefill_chunks"]),
             }
         if self.kv_backend is not None:
             s["kv"] = self.kv_backend.snapshot()
@@ -1235,7 +1164,7 @@ class _Service:
     def _generate_once(self, ids, new_tokens, on_token, kw, rid=None):
         # the trace rid doubles as the EXECUTOR request id: the mapping
         # between an HTTP request and its executor lifecycle is identity,
-        # and the executors' per-stage spans tag it for free (_run_stage)
+        # and the executor's per-stage spans tag it for free (_run_stage)
         if rid is None:
             rid = self.mint_rid()
         if self.prefill_fleet is not None and kw.get("shipped") is None:
@@ -1276,35 +1205,17 @@ class _Service:
                     self.flight.note("prefill_colocated", rid=rid,
                                      reason="unavailable",
                                      error=str(exc))
-        if self.exec is not None:
-            with self.cond:
-                self._check_dead()
-                self._resolve_prefix(kw)
-            self.exec.submit(rid, ids, new_tokens, on_token=on_token, **kw)
-            return self.exec.wait(rid)
-        with self.cond:
-            self._check_dead()
+        with self.cond:        # re-entrant: one hold, look-up to hand-over
             self._resolve_prefix(kw)
-            self.batcher.submit(rid, ids, new_tokens, on_token=on_token,
-                                **kw)
-            self.cond.notify_all()
-            while rid not in self.batcher.results:
-                self._check_dead()
-                self.cond.wait()
-            return self.batcher.results.pop(rid)
+            self.executor.submit(rid, ids, new_tokens, on_token=on_token,
+                                 **kw)
+        return self.executor.wait(rid)
 
     def stats(self):
         """Lock-free best-effort snapshot for /healthz (GIL-atomic reads;
         momentary inconsistency is fine for health)."""
-        if self.exec is not None:
-            s = self.exec.snapshot()
-            s["pending"] = 0          # admission blocks in submit threads
-            s["prefixes"] = len(self.prefixes)
-        else:
-            s = dict(self.batcher.stats,
-                     active=self.batcher.active,
-                     pending=len(self.batcher.pending),
-                     prefixes=len(self.prefixes))
+        s = self.executor.snapshot()
+        s["prefixes"] = len(self.prefixes)
         # degraded/failover history: read back from the SAME registry
         # instruments /metrics renders, so the two surfaces cannot diverge
         s["degraded_entered_total"] = int(self.m_degraded.value())
@@ -1319,11 +1230,7 @@ class _Service:
         self._gov_stop.set()
         if self.admission is not None:
             self.admission.close()   # shed every queued waiter (shutdown)
-        with self.cond:
-            self._stop = True
-            self.cond.notify_all()
-        if self.exec is not None:
-            self.exec.stop()
+        self.executor.stop()
         # tear the ship plane down LAST: in-flight prefills were already
         # failed fast by the executor stop above
         close = getattr(self.prefill_fleet, "close", None)
@@ -1528,7 +1435,7 @@ def make_handler(service, model_name, profile_dir=None):
                            {"ok": not dead, "model": model_name,
                             "stages": len(service.pipe.stages),
                             "speculative": service.spec is not None,
-                            "executor": service.executor,
+                            "executor": "wave",
                             "degraded": degraded,
                             "draining": service.draining,
                             "serving": service.serving_stats(),
@@ -2263,7 +2170,6 @@ def _run_router(args):
             "--max-len", str(args.max_len), "-t", args.dtype,
             "--kv-bits", str(args.kv_bits),
             "--attend-floor", str(args.attend_floor),
-            "--executor", args.executor,
             "--max-prefixes", str(args.max_prefixes),
             "--queue-capacity", str(args.queue_capacity),
             "--kv-pages", str(args.kv_pages),
@@ -2462,10 +2368,6 @@ def main():
                         "width-policy v2. Default: PIPEEDGE_INT8_DECODE_"
                         "ATTEND, else on (auto) when the int8 compute "
                         "path is enabled (docs/QUANTIZATION.md)")
-    p.add_argument("--executor", default="wave", choices=["wave", "stage"],
-                   help="wave: one thread ticks the batcher; stage: one "
-                        "worker thread pinned per pipeline stage "
-                        "(healthz reports per-worker stats)")
     p.add_argument("--draft-model", default=None,
                    help="enable speculative generation: requests with "
                         '"speculative": true run greedy draft/verify '
@@ -2588,7 +2490,7 @@ def main():
                         "prefill (the historical mode)")
     p.add_argument("--prefill-budget", default=None, type=int,
                    metavar="TOKENS",
-                   help="prompt tokens the wave executor may start per "
+                   help="prompt tokens the executor may start per "
                         "decode step when chunking (default: the chunk "
                         "size — one chunk per step)")
     p.add_argument("--step-join", action="store_true",
@@ -2869,7 +2771,6 @@ def main():
         signal.signal(signal.SIGTERM, lambda *a: sys.exit(0))
     service = _Service(pipe, max_active=args.max_active,
                        max_prefixes=args.max_prefixes, spec=spec,
-                       executor=args.executor,
                        edge_itemsize=2 if args.dtype == "bfloat16" else 4,
                        admission_enabled=not args.no_admission,
                        queue_capacity=args.queue_capacity,
@@ -2908,9 +2809,8 @@ def main():
     server = _HTTPServer((args.host, args.port),
                          make_handler(service, args.model_name,
                                       profile_dir=args.profile_dir))
-    print(f"serving {args.model_name} ({len(pipe.stages)} stages, "
-          f"{args.executor} executor) on {args.host}:{args.port}",
-          flush=True)
+    print(f"serving {args.model_name} ({len(pipe.stages)} stages) on "
+          f"{args.host}:{args.port}", flush=True)
     try:
         server.serve_forever()
     finally:
