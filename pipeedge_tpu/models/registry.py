@@ -217,8 +217,10 @@ def make_shard_config(model_name: str, layer_start: int, layer_end: int) -> Shar
 def should_unroll_blocks(n_blocks: int) -> bool:
     """Execution-layout policy: unroll full blocks when the depth is within
     PIPEEDGE_UNROLL_BLOCKS (default 48, covering every registered model —
-    unrolled runs ~6% faster and compiles faster on TPU; see
-    shard.shard_apply). 0 disables unrolling (always scan)."""
+    on the v5e six unrolled ViT-Large blocks take 2.083 ms where a scan over
+    their stack takes 2.360; see shard.shard_apply). 0 disables unrolling
+    (always scan) in the host driver's stage programs; the SPMD driver
+    always unrolls (parallel/spmd.py::run_blocks)."""
     limit = int(os.getenv("PIPEEDGE_UNROLL_BLOCKS", "48"))
     return 0 < n_blocks <= limit
 

@@ -9,7 +9,9 @@ under `shard_map` over a `jax.sharding.Mesh`:
   rank, comm/p2p), 'dp' optionally shards the microbatch dimension (data
   parallelism within a stage — absent in the reference, SURVEY.md §2.4).
 - Each device holds only its own stage's transformer blocks (parameters are
-  stage-sharded; stages with fewer blocks are zero-padded and masked).
+  stage-sharded; stages with fewer blocks are zero-padded and masked). A tick
+  runs them unrolled over per-block arrays sliced out of the stage's stack
+  once a call; only the padded slots sit under a `lax.cond`.
 - One `lax.scan` over T = n_microbatches + n_stages - 1 "ticks" runs the
   fill/steady/drain schedule; the inter-stage edge is `lax.ppermute` over ICI
   — the collective-permute equivalent of the reference's gloo send/recv
@@ -42,8 +44,18 @@ from ..models.layers import TransformerConfig
 from ..models.shard import FamilySpec, stack_blocks
 from ..ops import fused_quant
 from ..ops import quant as quant_ops
+from ..telemetry import metrics as prom
 
 logger = logging.getLogger(__name__)
+
+# /metrics plane: whether a deployment's partition engages the unrolled
+# block body. Set at build from the partition alone: `unconditional` slots
+# run with no `lax.cond` in the tick, `masked` ones are some stage's padding
+_M_STAGE_BLOCKS = prom.REGISTRY.gauge(
+    "pipeedge_spmd_stage_blocks",
+    "block slots a tick of the newest SPMD pipeline runs, by kind: "
+    "unconditional (every stage holds the block) / masked (padding on the "
+    "shallower stages, under lax.cond)")
 
 BlockRange = Tuple[int, int]
 
@@ -135,7 +147,8 @@ class SpmdPipeline:
     cfg: TransformerConfig
     mesh: Mesh
     n_stages: int
-    max_blocks: int
+    max_blocks: int         # the deepest stage's block count (slots a tick)
+    min_blocks: int         # the shallowest stage's: slots with no padding
     params: Dict            # {'embed', 'final', 'blocks', 'n_blocks'}
     stage_bits: Tuple[int, ...] = (0,)
     sp_kind: str = "ring"   # sp attention core: 'ring' | 'ulysses'
@@ -175,6 +188,7 @@ class SpmdPipeline:
     def _build(self, inputs: jax.Array):
         family, cfg = self.family, self.cfg
         n_stages, max_b = self.n_stages, self.max_blocks
+        min_b = self.min_blocks
         mesh = self.mesh
         n_ubatch = inputs.shape[0]
         n_ticks = n_ubatch + n_stages - 1
@@ -257,13 +271,17 @@ class SpmdPipeline:
             block_apply = jax.checkpoint(block_apply)
 
         def run_blocks(blocks, n_valid, x):
-            def step(carry, xs):
-                bp, j = xs
-                out = jax.lax.cond(j < n_valid, lambda c: block_apply(bp, c),
-                                   lambda c: c, carry)
-                return out, None
-
-            x, _ = jax.lax.scan(step, x, (blocks, jnp.arange(max_b)))
+            # unrolled over per-block pytrees: a block reads its own arrays,
+            # where a scan over the stack slices 25 MB out of it every
+            # iteration (ViT-L; models/shard.py::shard_apply has the
+            # measured pair). Slots every stage fills run unconditionally;
+            # only some stage's padding needs the device-side test
+            for j, bp in enumerate(blocks):
+                if j < min_b:
+                    x = block_apply(bp, x)
+                else:
+                    x = jax.lax.cond(j < n_valid, partial(block_apply, bp),
+                                     lambda c: c, x)
             return x
 
         fwd_perm = [(i, i + 1) for i in range(n_stages - 1)]
@@ -361,8 +379,13 @@ class SpmdPipeline:
 
         def spmd_body(params, stacked_inputs):
             # local views: blocks [1, max_b, ...] (stage-sharded), inputs
-            # [M, B/dp, ...] (dp-sharded), embed/final replicated
-            blocks = jax.tree_util.tree_map(lambda x: x[0], params["blocks"])
+            # [M, B/dp, ...] (dp-sharded), embed/final replicated. The
+            # stage's stack is taken apart HERE, once a call: outside the
+            # tick scan the per-block arrays are its loop invariants. The
+            # same static slice inside `tick` would be made every tick
+            blocks = [jax.tree_util.tree_map(lambda x, j=j: x[0, j],
+                                             params["blocks"])
+                      for j in range(max_b)]
             n_valid = params["n_blocks"][0]
             stage = jax.lax.axis_index("stage")
             is_first = stage == 0
@@ -495,7 +518,8 @@ def build_spmd_pipeline(family: FamilySpec, cfg: TransformerConfig,
     `stage_params[i]` is the pytree built by a family loader for stage i's
     `ShardConfig` (block-aligned). Stage 0 must carry 'embeddings', the last
     stage 'final'; per-stage 'blocks' stacks are zero-padded to the deepest
-    stage and masked at run time.
+    stage, and the slots past the shallowest stage's count are masked at run
+    time (`min_blocks`; an even partition has none).
 
     `quant_bit`: an int applied to every inter-stage edge, or a per-stage
     sequence where entry i quantizes the edge leaving stage i (reference
@@ -529,7 +553,7 @@ def build_spmd_pipeline(family: FamilySpec, cfg: TransformerConfig,
                 "unroll=False) or family loaders directly")
         blocks_list.append(p["blocks"])
         n_blocks.append(jax.tree_util.tree_leaves(p["blocks"])[0].shape[0])
-    max_b = max(n_blocks)
+    max_b, min_b = max(n_blocks), min(n_blocks)
     nonzero = [b for b in stage_bits[:-1] if b > 0]
     if nonzero and any(b == 0 for b in stage_bits[:-1]):
         logger.warning(
@@ -572,8 +596,10 @@ def build_spmd_pipeline(family: FamilySpec, cfg: TransformerConfig,
         "n_blocks": jax.device_put(params["n_blocks"],
                                    NamedSharding(mesh, P("stage"))),
     }
+    _M_STAGE_BLOCKS.set(min_b, kind="unconditional")
+    _M_STAGE_BLOCKS.set(max_b - min_b, kind="masked")
     return SpmdPipeline(family=family, cfg=cfg, mesh=mesh, n_stages=n_stages,
-                        max_blocks=max_b, params=params,
+                        max_blocks=max_b, min_blocks=min_b, params=params,
                         stage_bits=stage_bits, sp_kind=sp_kind,
                         remat=remat)
 

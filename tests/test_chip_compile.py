@@ -135,16 +135,23 @@ def test_kernel_compiles_for_v5e(name, on_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_vit_large_forward_compiles_for_v5e(on_chip):
-    name = "google/vit-large-patch16-224"
-    entry = registry.get_model_entry(name)
-    cfg = entry.config
-    total = registry.get_model_layers(name)
-    # parameter shapes without drawing 300M random numbers: a one-block
-    # model's, with the stacked blocks' leading axis widened to the depth
+VIT_LARGE = "google/vit-large-patch16-224"
+
+
+def _vit_large_one_block():
+    """ViT-L's parameter shapes without drawing 300M random numbers: a
+    one-block model's, whose stacked blocks' leading axis the caller widens
+    to the depth it compiles."""
+    entry = registry.get_model_entry(VIT_LARGE)
     one_block = jax.eval_shape(lambda: entry.family.init_params(
-        dataclasses.replace(cfg, num_hidden_layers=1),
+        dataclasses.replace(entry.config, num_hidden_layers=1),
         ShardConfig(1, 4, is_first=True, is_last=True), dtype=jnp.bfloat16))
+    return entry, entry.config, one_block
+
+
+def test_vit_large_forward_compiles_for_v5e(on_chip):
+    entry, cfg, one_block = _vit_large_one_block()
+    total = registry.get_model_layers(VIT_LARGE)
     params = jax.tree_util.tree_map(
         lambda leaf: on_chip(leaf.shape, leaf.dtype), one_block)
     params["blocks"] = jax.tree_util.tree_map(
@@ -184,3 +191,58 @@ def test_keye_stage_program_compiles_for_v5e(span, last_only, on_chip):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes > 1.7e9       # the cache, in place
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
+
+
+@pytest.mark.parametrize("n_ubatch", [1024, 4])
+def test_spmd_vit_large_cell_compiles_for_v5e(n_ubatch, topo):
+    """`vit-l.spmd-4stage` at its real widths: four stages of six ViT-L
+    blocks on the four described chips, microbatches of 8 in bfloat16; a
+    round's 1,027 ticks, which have to fit a chip beside the other staged
+    round (2.5 GB), and a short program of 7. A chip holds its stage's
+    weights as the stack it is given and once more as the per-block arrays
+    the tick scan reads, never a third time among the temporaries; only the
+    short program can tell, a round's two buffers of embedded images (6.6
+    GB) being forty times the weights."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from pipeedge_tpu.parallel import spmd
+    entry, cfg, one_block = _vit_large_one_block()
+    n_stages, per_stage, ubatch = 4, 6, 8
+    mesh = spmd.make_pipeline_mesh(n_stages, devices=topo.devices)
+
+    def placed(tree, spec, lead=()):
+        return jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(
+                lead + leaf.shape, leaf.dtype,
+                sharding=NamedSharding(mesh, spec)), tree)
+
+    a_block = jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape[1:], leaf.dtype),
+        one_block["blocks"])
+    params = {
+        "embed": placed(one_block["embeddings"], P()),
+        "final": placed(one_block["final"], P()),
+        "blocks": placed(a_block, P("stage"), (n_stages, per_stage)),
+        "n_blocks": jax.ShapeDtypeStruct(
+            (n_stages,), jnp.int32, sharding=NamedSharding(mesh, P("stage"))),
+    }
+    pipe = spmd.SpmdPipeline(
+        family=entry.family.FAMILY, cfg=cfg, mesh=mesh, n_stages=n_stages,
+        max_blocks=per_stage, min_blocks=per_stage, params=params,
+        stage_bits=(0,) * n_stages)
+    shape = (n_ubatch, ubatch, cfg.num_channels, cfg.image_size,
+             cfg.image_size)
+    # `_build` reads shapes only: a broadcast view stands in for 2.5 GB
+    program = pipe._build(np.broadcast_to(np.zeros((), jnp.bfloat16), shape))
+    compiled = program.lower(params, jax.ShapeDtypeStruct(
+        shape, jnp.bfloat16, sharding=NamedSharding(mesh, P()))).compile()
+    logits, = jax.tree_util.tree_leaves(compiled.out_info)
+    assert logits.shape == (n_ubatch, ubatch, cfg.num_labels)
+    assert "collective-permute" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    stage_bytes = per_stage * sum(
+        int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+        for leaf in jax.tree_util.tree_leaves(a_block))
+    embedded = n_ubatch * ubatch * 197 * cfg.hidden_size * 2
+    assert memory.temp_size_in_bytes < 2 * embedded + 1.5 * stage_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 16e9 - 2.5e9
