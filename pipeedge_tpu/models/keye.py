@@ -278,15 +278,17 @@ def sparse_attention(q, iq, iw, q_pos, parts, cfg: TransformerConfig):
 
 
 def _experts(p: Dict, normed, cfg: TransformerConfig):
-    """The routed FFN's delta and counts: `p["experts"]` is the block's
-    own leaves, or `(stack, layer)` where the decode scan keeps the
-    stacked blocks' experts whole (parallel/decode.py `_run_blocks`)."""
+    """The routed FFN's delta (with the shared expert's, where the block
+    has one) and counts: `p["experts"]` is the block's own leaves, or
+    `(stack, layer)` where the decode scan keeps the stacked blocks'
+    experts whole (parallel/decode.py `_run_blocks`)."""
     from ..parallel.expert import topk_ffn_delta
     experts, layer = p["experts"], None
     if isinstance(experts, tuple):
         experts, layer = experts
-    return topk_ffn_delta({"router": p["router"], "experts": experts},
-                          normed, cfg, layer=layer)
+    return topk_ffn_delta(
+        dict({name: p[name] for name in ("router", "shared") if name in p},
+             experts=experts), normed, cfg, layer=layer)
 
 
 def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:

@@ -1,0 +1,487 @@
+"""Kimi-K2 (`model_type` kimi_k2): DeepSeek-V3's block. Latent attention
+(MLA) over a cache of one shared row a position, a leading dense layer, then
+expert layers routed by a sigmoid beside a shared expert.
+
+The block, `x` [B, S, D], RMSNorm everywhere, no bias anywhere:
+  h = x + MLA(rms(x));  x' = h + FFN(rms(h))
+FFN is a SwiGLU of `intermediate_size` in the first `first_k_dense` blocks
+and the expert layer after them (parallel/expert.py `topk_ffn_delta`:
+sigmoid scores, the choice made on score + bias, gates normalised and
+scaled, the chip's `held_experts` of `n_experts`, one shared expert). The
+two kinds of block have different leaves, so a stage holds them as runs
+(`block_kind`, models/shard.py `BlockRuns`), each its own scan over the one
+cache stack.
+
+**MLA.** `c_q = rms(W_qa u)`; `q = W_qb c_q` in heads of (nope | rope).
+`[c_kv | k_pe] = W_kva u`; `c_kv = rms(c_kv)`; `k_pe` is one rotary key for
+all heads. `[k_nope | v] = W_kvb c_kv` by head. Score of head j = (q_nope_j
+. k_nope_j + q_pe_j . k_pe) * s, `s = (nope + rope)**-0.5 * m**2`, `m = 0.1
+* mscale_all_dim * ln(factor) + 1` (YaRN). The rotation keeps the
+checkpoint's layout: pairs interleaved, de-interleaved before the halves
+are rotated, for q_pe and k_pe alike.
+
+**Cache.** Two leaves a position a layer, shared by all heads: `c_kv`
+[L, B, T, kv_lora_rank] after its norm and `k_pe` [L, B, T, rope] after its
+rotation (576 values where the heads' keys and values would be 20,480), and
+`stats`. Compiled for a described v5e at the cell's size (64 rows, 4,096
+positions, 5 layers, float32) the chip keeps `c_kv` at its own size (2.68
+GB; 512 is four lane tiles) and `k_pe` too (0.34 GB: with 64 in the minor
+axis it tiles the positions by 128 beside it and pads nothing), which one
+576-wide leaf would not (4.5 tiles, padded to 5).
+
+**Two attention paths, one result.** The rows of this call (a prompt's
+span) attend each other in the expanded form: each latent row is expanded
+to its heads' k_nope and v once, in the call that writes it. Rows read from
+the cache (every decode step; a span's view of earlier spans) are attended
+in the absorbed form: with `W_kvb` split by head into `W_uk_j`, `W_uv_j`,
+`q'_j = W_uk_j^T q_nope_j`, score = (q'_j . c_kv + q_pe_j . k_pe) * s, `o_j
+= W_uv_j (sum p c_kv)`: 64 heads over one 576-wide row, and no cached row is
+ever expanded again. One softmax runs over both. A decode step's own row is
+attended in the absorbed form as well. Queries run in chunks so that no
+chunk's scores pass `_SCORE_BYTES`.
+
+**Precision.** Weights as stored (bfloat16). Activations and the cache are
+float32: products with weights through `exact_dot` / `exact_einsum`,
+products of two activations at `Precision.HIGH` (three bfloat16 passes; the
+attention makes no discrete choice, so 16 bits suffice where keye's
+selection needed 24). The router's top-8 of 384 is a discrete choice that a
+bfloat16 computation makes differently from the float32 reference, and the
+benchmark's comparison then fails (PERF.md, PR 31).
+
+**Prefill** runs in spans of `cfg.prefill_chunk` positions through the
+decode-shaped stage program, as keye's does.
+
+Refused by name: the forward path (`sublayer`: runs of blocks are not in
+`shard_apply`), tp, sp and ep meshes, the int8 cache, `--kv-pages`.
+
+Weight format: DeepSeek-V3's HF state dict (`model.layers.N.self_attn.
+{q_a_proj, q_a_layernorm, q_b_proj, kv_a_proj_with_mqa, kv_a_layernorm,
+kv_b_proj, o_proj}`, `mlp.{gate,up,down}_proj` in a dense layer, `mlp.gate.
+{weight, e_score_correction_bias}`, `mlp.experts.E.*`, `mlp.shared_experts.
+*` in an expert layer). Every matrix stays `[out, in]` as stored; of a
+layer's experts the held ones are read, by their published index.
+"""
+from __future__ import annotations
+
+import math
+from typing import Dict, Mapping
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from . import ShardConfig
+from .keye import _experts
+from .layers import (TransformerConfig, exact_dot, exact_einsum, rms_norm,
+                     rope_frequencies)
+from .shard import FamilySpec, build_shard_params
+
+# what a block step counts into the cache's `stats` leaf, in this order
+STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
+         "moe_layer_calls", "mla_rows_written", "mla_rows_expanded",
+         "mla_rows_read")
+
+# activations and cache (module docstring, Precision)
+ACTIVATIONS = jnp.float32
+
+# bytes of float32 attention scores one chunk of queries may hold, and of
+# the three-pass result of one chunk of rows of a wide product
+_SCORE_BYTES = 1 << 29
+_PRODUCT_BYTES = 1 << 29
+
+
+def prefill_span(cfg: TransformerConfig) -> int:
+    return cfg.prefill_chunk
+
+
+def block_kind(cfg: TransformerConfig, block_id: int) -> str:
+    return "dense" if block_id < cfg.first_k_dense else "experts"
+
+
+def cache_leaves(cfg: TransformerConfig) -> Dict:
+    """What follows `[L, B, T]` in each leaf of the cache."""
+    return {"c_kv": jax.ShapeDtypeStruct((cfg.kv_lora_rank,), ACTIVATIONS),
+            "k_pe": jax.ShapeDtypeStruct((cfg.qk_rope_head_dim,),
+                                         ACTIVATIONS),
+            "stats": jax.ShapeDtypeStruct((len(STATS),), jnp.int32)}
+
+
+def yarn_frequencies(cfg: TransformerConfig) -> np.ndarray:
+    """The rotation's `qk_rope_head_dim / 2` frequencies under YaRN: those
+    that turn more than `beta_fast` times in the original context stay,
+    those that turn fewer than `beta_slow` times are divided by the factor,
+    a linear ramp between."""
+    dim = cfg.qk_rope_head_dim
+    freqs = rope_frequencies(dim, cfg.rope_theta)
+    if not cfg.rope_yarn:
+        return freqs
+    factor, original, fast, slow = cfg.rope_yarn[:4]
+
+    def correction(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(correction(fast)), 0)
+    high = min(math.ceil(correction(slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / ((high if high != low else high + 0.001) - low), 0, 1)
+    return (freqs / factor * ramp + freqs * (1 - ramp)).astype(np.float32)
+
+
+def attention_scale(cfg: TransformerConfig) -> float:
+    """s of the module docstring. The cosine and sine carry mscale /
+    mscale_all_dim, which is 1 for this model and is not applied."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_yarn:
+        factor, all_dim = cfg.rope_yarn[0], cfg.rope_yarn[5]
+        if factor > 1:
+            scale *= (0.1 * all_dim * math.log(factor) + 1.0) ** 2
+    return scale
+
+
+def rotate(x: jax.Array, pos: jax.Array, cfg: TransformerConfig):
+    """x [B, S, ..., R] turned at positions `pos` [S]: pairs (0,1), (2,3),
+    ... de-interleaved into halves, then the half-split rotation."""
+    angles = pos.astype(jnp.float32)[:, None] * yarn_frequencies(cfg)[None]
+    cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)      # [S, R]
+    sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
+    if x.ndim == 4:
+        cos, sin = cos[:, None], sin[:, None]
+    xf = x.astype(jnp.float32)
+    xf = jnp.concatenate([xf[..., 0::2], xf[..., 1::2]], axis=-1)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    return (xf * cos + jnp.concatenate([-x2, x1], axis=-1) * sin).astype(
+        x.dtype)
+
+
+def _lin(w: jax.Array, x: jax.Array) -> jax.Array:
+    """x over w stored [out, in] (`exact_dot`)."""
+    return exact_dot(x, w, w_contract=1).astype(x.dtype)
+
+
+def _in_row_chunks(fn, x: jax.Array, widest: int) -> jax.Array:
+    """`fn` over the rows of x [B, S, D] -> [B, S, N], in chunks of rows
+    whose three-pass product of width `widest` stays under
+    `_PRODUCT_BYTES`."""
+    b, s, d = x.shape
+    rows, n = b * s, 1
+    while rows % (2 * n) == 0 and rows // n * widest * 12 > _PRODUCT_BYTES:
+        n *= 2
+    if n == 1:
+        return fn(x)
+    out = jax.lax.map(fn, x.reshape(n, 1, rows // n, d))
+    return out.reshape(b, s, -1)
+
+
+def _queries(p: Dict, normed, pos, cfg: TransformerConfig):
+    """(q_nope [B,S,H,Dn], q_pe [B,S,H,Dr] rotated) of `normed`."""
+    b, s, _ = normed.shape
+    heads = cfg.num_attention_heads
+    eps = cfg.layer_norm_eps
+    q = _in_row_chunks(
+        lambda rows: _lin(p["q_b"]["w"], rms_norm(
+            p["q_a_norm"], _lin(p["q_a"]["w"], rows), eps)),
+        normed, heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+    q = q.reshape(b, s, heads, -1)
+    q_nope, q_pe = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+    return q_nope, rotate(q_pe, pos, cfg)
+
+
+def _latent(p: Dict, normed, pos, cfg: TransformerConfig):
+    """(c_kv [B,S,C] normed, k_pe [B,S,Dr] rotated) of `normed`: the cache's
+    row."""
+    c_kv, k_pe = jnp.split(_lin(p["kv_a"]["w"], normed),
+                           [cfg.kv_lora_rank], axis=-1)
+    return (rms_norm(p["kv_a_norm"], c_kv, cfg.layer_norm_eps),
+            rotate(k_pe, pos, cfg))
+
+
+def expand(p: Dict, c_kv: jax.Array):
+    """(k_nope [B,K,H,Dn], v [B,K,H,Dv]) of latent rows c_kv [B,K,C]."""
+    return (exact_einsum("bkc,hdc->bkhd", c_kv, p["w_uk"]).astype(
+        c_kv.dtype), exact_einsum("bkc,hdc->bkhd", c_kv, p["w_uv"]).astype(
+            c_kv.dtype))
+
+
+def _precision(x: jax.Array):
+    """Of a product of two activations (module docstring, Precision)."""
+    return jax.lax.Precision.HIGH if x.dtype == jnp.float32 else None
+
+
+def _attend_chunk(p: Dict, q_nope, q_pe, latent, own, scale: float):
+    """Context [B, Q, H, Dv] of one chunk of queries. `latent`: parts
+    attended in the absorbed form, each (c_kv [B,K,C], k_pe [B,K,Dr], keep
+    [Q,K]); `own`: None or the expanded part (k_nope [B,K,H,Dn], v
+    [B,K,H,Dv], k_pe [B,K,Dr], keep [Q,K]). One softmax over all keys."""
+    dtype, precision = q_nope.dtype, _precision(q_nope)
+
+    def dots(spec, a, b):
+        return jnp.einsum(spec, a, b.astype(dtype), precision=precision,
+                          preferred_element_type=jnp.float32)
+
+    scores = []
+    if latent:
+        q_lat = exact_einsum("bqhd,hdc->bqhc", q_nope, p["w_uk"]).astype(
+            dtype)
+    for c_kv, k_pe, keep in latent:
+        part = dots("bqhc,bkc->bhqk", q_lat, c_kv) \
+            + dots("bqhr,bkr->bhqk", q_pe, k_pe)
+        scores.append(jnp.where(keep[None, None], part * scale, -1e30))
+    if own is not None:
+        k_nope, v, k_pe, keep = own
+        part = dots("bqhd,bkhd->bhqk", q_nope, k_nope) \
+            + dots("bqhr,bkr->bhqk", q_pe, k_pe)
+        scores.append(jnp.where(keep[None, None], part * scale, -1e30))
+    top = jnp.max(jnp.concatenate(
+        [jnp.max(sc, axis=-1, keepdims=True) for sc in scores], -1),
+        axis=-1, keepdims=True)
+    probs = [jnp.exp(sc - top) for sc in scores]
+    total = sum(jnp.sum(pr, axis=-1, keepdims=True) for pr in probs)
+    probs = [(pr / total).astype(dtype) for pr in probs]
+    out = 0.0
+    if latent:
+        mixed = sum(dots("bhqk,bkc->bqhc", pr, part[0])
+                    for pr, part in zip(probs, latent))
+        out = exact_einsum("bqhc,hdc->bqhd", mixed.astype(dtype), p["w_uv"])
+    if own is not None:
+        out = out + dots("bhqk,bkhd->bqhd", probs[-1], own[1])
+    return out.astype(dtype)
+
+
+def latent_attention(p: Dict, q_nope, q_pe, latent, own,
+                     cfg: TransformerConfig) -> jax.Array:
+    """-> [B, Q, H * Dv]; arguments as `_attend_chunk`'s, all queries."""
+    b, n_q, heads, _ = q_nope.shape
+    n_keys = sum(part[0].shape[1] for part in latent) \
+        + (own[0].shape[1] if own is not None else 0)
+    chunk = n_q
+    while chunk > 1 and chunk % 2 == 0 \
+            and b * heads * chunk * n_keys * 4 > _SCORE_BYTES:
+        chunk //= 2
+    scale = attention_scale(cfg)
+    if chunk == n_q:
+        return _attend_chunk(p, q_nope, q_pe, latent, own, scale).reshape(
+            b, n_q, -1)
+    n = n_q // chunk
+
+    def chunks(x):      # [B, Q, ...] -> [n, B, chunk, ...]
+        return jnp.moveaxis(x.reshape((b, n, chunk) + x.shape[2:]), 1, 0)
+
+    def one(args):
+        q_n, q_r, keeps = args
+        parts = [part[:2] + (keep,) for part, keep in zip(latent, keeps)]
+        mine = None if own is None else own[:3] + (keeps[-1],)
+        return _attend_chunk(p, q_n, q_r, parts, mine, scale)
+
+    keeps = tuple(part[-1].reshape(n, chunk, -1)
+                  for part in list(latent) + ([own] if own is not None
+                                              else []))
+    ctx = jax.lax.map(one, (chunks(q_nope), chunks(q_pe), keeps))
+    return jnp.moveaxis(ctx, 0, 1).reshape(b, n_q, -1)
+
+
+def _dense_ffn(p: Dict, normed: jax.Array) -> jax.Array:
+    def swiglu(rows):
+        hidden = jax.nn.silu(_lin(p["gate"], rows)) * _lin(p["up"], rows)
+        return _lin(p["down"], hidden)
+    return _in_row_chunks(swiglu, normed, p["gate"].shape[0])
+
+
+def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
+    """Token embedding [B, K] -> [B, K, D]: positions live in the rotation."""
+    return jnp.take(pe["wte"], tok, axis=0).astype(ACTIVATIONS)
+
+
+def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    return span_embed(p, input_ids, 0)
+
+
+def decode_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
+    return span_embed(pe, tok.reshape(-1, 1), pos)
+
+
+def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
+             attention_fn=None):
+    raise NotImplementedError(
+        "the kimi family runs through the cached decode path only: its "
+        "blocks come in runs of two kinds, which the forward path "
+        "(models/shard.py shard_apply) does not scan yet")
+
+
+def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """Final RMSNorm + LM head -> [B, S, vocab] logits."""
+    return _lin(p["head"]["w"], rms_norm(p["ln"], hidden,
+                                         cfg.layer_norm_eps))
+
+
+def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
+                      prefill: bool, read_len=None):
+    """Cached block (parallel/decode.py's `_block_step` contract): the rows
+    of `x` sit at [pos, pos + S), attend the cached window below `pos` in
+    the absorbed form and themselves in the expanded form (a single row:
+    absorbed too), and are recorded for `_write_rows` as latent rows with
+    the step's counts. A prefill (`pos` 0) reads no cache."""
+    from ..parallel.decode import _attend_width, _read_window
+
+    b, s, _ = x.shape
+    normed = rms_norm(p["ln_before"], x, cfg.layer_norm_eps)
+    q_pos = jnp.asarray(pos) + jnp.arange(s)
+    q_nope, q_pe = _queries(p, normed, q_pos, cfg)
+    c_kv, k_pe = _latent(p, normed, q_pos, cfg)
+    stack = bcache.stack
+    # through the cache's dtype, as if read back from it
+    c_kv = c_kv.astype(stack["c_kv"].dtype).astype(x.dtype)
+    k_pe = k_pe.astype(stack["k_pe"].dtype).astype(x.dtype)
+    causal = jnp.tril(jnp.ones((s, s), bool))
+    latent, own, read = [], None, jnp.int32(0)
+    if s > 1:
+        own = expand(p, c_kv) + (k_pe, causal)
+    else:
+        latent.append((c_kv, k_pe, causal))
+    if not prefill:
+        width = _attend_width(bcache, read_len)
+        live = jnp.broadcast_to(jnp.arange(width) < pos, (s, width))
+        latent.insert(0, (_read_window(stack["c_kv"], bcache.layer, width),
+                          _read_window(stack["k_pe"], bcache.layer, width),
+                          live))
+        read = (b * jnp.asarray(pos)).astype(jnp.int32)
+    ctx = latent_attention(p, q_nope, q_pe, latent, own, cfg)
+    h = _lin(p["attn_out"]["w"], ctx) + x
+    normed = rms_norm(p["ln_after"], h, cfg.layer_norm_eps)
+    if "router" in p:
+        delta, moe = _experts(p, normed, cfg)
+        moe = jnp.concatenate([moe.astype(jnp.int32), jnp.ones(1, jnp.int32)])
+    else:
+        delta, moe = _dense_ffn(p["mlp"], normed), jnp.zeros(4, jnp.int32)
+    stats = jnp.concatenate([moe, jnp.stack(
+        [jnp.int32(b * s), jnp.int32(b * s if s > 1 else 0), read])])
+    return h + delta, bcache._replace(
+        rows={"c_kv": c_kv, "k_pe": k_pe, "stats": stats})
+
+
+FAMILY = FamilySpec(name="kimi", embed=embed, sublayer=sublayer,
+                    finalize=finalize, cached_block_step=cached_block_step,
+                    decode_embed=decode_embed, span_embed=span_embed,
+                    position_dependent_attention=True,
+                    cache_leaves=cache_leaves, prefill_span=prefill_span,
+                    whole_leaves=("experts",), stats_names=STATS,
+                    block_kind=block_kind)
+
+
+def _stack(leaves):
+    """One `[n, ...]` array of like leaves, on the host where they are host
+    arrays (the device never holds a layer twice)."""
+    return (np if isinstance(leaves[0], np.ndarray) else jnp).stack(leaves)
+
+
+def _on_device(params, dtype):
+    """Host leaves onto the device in `dtype` (the router's correction bias
+    stays float32, as published), one at a time and each waited for
+    (models/keye.py `_on_device`)."""
+    flat, tree = jax.tree_util.tree_flatten_with_path(params)
+    out = []
+    for path, leaf in flat:
+        keep = getattr(path[-1], "key", None) == "bias"
+        out.append(jax.block_until_ready(jnp.asarray(leaf).astype(
+            jnp.float32 if keep else dtype)))
+    return jax.tree_util.tree_unflatten(tree, out)
+
+
+def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
+              dtype) -> Dict:
+    """Shard params from `get(key, shape)`, a tensor of the published
+    scheme: every leaf stays a host array until its run is stacked. (Traced
+    values pass through as well: `jax.eval_shape` over this with a `get` of
+    `jnp.zeros` gives the model's shapes without its 3.5 G values.)"""
+    d, heads = cfg.hidden_size, cfg.num_attention_heads
+    nope, rope, v_dim = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+        cfg.v_head_dim
+    rank, f = cfg.kv_lora_rank, cfg.moe_intermediate_size
+    first, count = cfg.held_experts or (0, cfg.n_experts)
+
+    def scale(key, n):
+        return {"scale": get(key, (n,))}
+
+    def mlp(root, width):
+        return {"gate": get(root + "gate_proj.weight", (width, d)),
+                "up": get(root + "up_proj.weight", (width, d)),
+                "down": get(root + "down_proj.weight", (d, width))}
+
+    def get_embed() -> Dict:
+        return {"wte": get("model.embed_tokens.weight",
+                           (cfg.vocab_size, d))}
+
+    def get_block(block_id: int, subs: tuple) -> Dict:
+        if subs != (0, 1, 2, 3):
+            raise NotImplementedError(
+                "the kimi family takes whole blocks: a partition that cuts "
+                "one is for the forward path, which it does not run")
+        root = f"model.layers.{block_id}."
+        att = root + "self_attn."
+        kv_b = get(att + "kv_b_proj.weight", (heads * (nope + v_dim), rank))
+        kv_b = kv_b.reshape(heads, nope + v_dim, rank)
+        p = {"ln_before": scale(root + "input_layernorm.weight", d),
+             "q_a": {"w": get(att + "q_a_proj.weight",
+                              (cfg.q_lora_rank, d))},
+             "q_a_norm": scale(att + "q_a_layernorm.weight",
+                               cfg.q_lora_rank),
+             "q_b": {"w": get(att + "q_b_proj.weight",
+                              (heads * (nope + rope), cfg.q_lora_rank))},
+             "kv_a": {"w": get(att + "kv_a_proj_with_mqa.weight",
+                               (rank + rope, d))},
+             "kv_a_norm": scale(att + "kv_a_layernorm.weight", rank),
+             "w_uk": kv_b[:, :nope], "w_uv": kv_b[:, nope:],
+             "attn_out": {"w": get(att + "o_proj.weight",
+                                   (d, heads * v_dim))},
+             "ln_after": scale(root + "post_attention_layernorm.weight", d)}
+        if block_kind(cfg, block_id) == "dense":
+            p["mlp"] = mlp(root + "mlp.", cfg.intermediate_size)
+            return p
+        p["router"] = {
+            "w": get(root + "mlp.gate.weight", (cfg.n_experts, d)).T,
+            "bias": get(root + "mlp.gate.e_score_correction_bias",
+                        (cfg.n_experts,))}
+        held = [mlp(f"{root}mlp.experts.{e}.", f)
+                for e in range(first, first + count)]
+        p["experts"] = {name: _stack([one[name] for one in held])
+                        for name in ("gate", "up", "down")}
+        p["shared"] = mlp(root + "mlp.shared_experts.",
+                          f * cfg.n_shared_experts)
+        return p
+
+    def get_final() -> Dict:
+        return {"ln": scale("model.norm.weight", d),
+                "head": {"w": get("lm_head.weight", (cfg.vocab_size, d))}}
+
+    return _on_device(build_shard_params(
+        shard_config, get_embed, get_block, get_final,
+        stack=lambda blocks: jax.tree_util.tree_map(
+            lambda *leaves: _stack(leaves), *blocks),
+        kind=lambda block_id: block_kind(cfg, block_id)), dtype)
+
+
+def load_params(cfg: TransformerConfig, shard_config: ShardConfig,
+                weights: Mapping, dtype=jnp.float32) -> Dict:
+    """Shard params from a DeepSeek-V3-style state-dict npz (module
+    docstring). A sliced vocabulary is the table's first rows."""
+    def get(key, shape):
+        value = np.asarray(weights[key])
+        if key in ("model.embed_tokens.weight", "lm_head.weight"):
+            value = value[:shape[0]]
+        if value.shape != shape:
+            raise ValueError(f"{key}: {value.shape} in the file, {shape} "
+                             "in the model")
+        return value
+    return _assemble(cfg, shard_config, get, dtype)
+
+
+def init_params(cfg: TransformerConfig, shard_config: ShardConfig,
+                seed: int = 0, dtype=jnp.float32) -> Dict:
+    """Random shard params with the same pytree structure as `load_params`."""
+    rng = np.random.default_rng(seed)
+
+    def get(key, shape):
+        if key.endswith("norm.weight"):
+            return np.ones(shape, np.float32)
+        return rng.normal(0, 0.02, size=shape).astype(np.float32)
+    return _assemble(cfg, shard_config, get, dtype)

@@ -21,7 +21,7 @@ class TransformerConfig:
     reference fetches over the network — model_cfg.py:57-66; here configs are
     local constants so the framework runs with zero egress)."""
     model_type: str              # the family: 'vit' | 'bert' | 'deit' |
-    #                              'gpt2' | 'llama' | 'keye'
+    #                              'gpt2' | 'llama' | 'keye' | 'kimi'
     hidden_size: int
     num_hidden_layers: int       # transformer blocks (sublayers = 4x this)
     num_attention_heads: int
@@ -68,6 +68,34 @@ class TransformerConfig:
     index_head_dim: int = 0
     index_topk: int = 0
     index_q_chunk: int = 0
+    # the expert layer's router: 'softmax', or 'sigmoid' (each expert's
+    # score on its own, the choice made on score + a learned bias, the
+    # kept scores normalised and scaled by `routed_scaling_factor`);
+    # `n_shared_experts` experts of the routed width beside the routed ones
+    # that every token goes through
+    router: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    n_shared_experts: int = 0
+    # the chip's share of each expert layer, (first, count) of `n_experts`
+    # (`<name>@e<first>+<count>`, models/registry.py); () = all of them
+    held_experts: tuple = ()
+    # the first `first_k_dense` blocks have a dense FFN of
+    # `intermediate_size`, the rest the expert layer (kimi family)
+    first_k_dense: int = 0
+    # latent attention (MLA, kimi family): the query's and the key/value's
+    # low-rank widths, and a head's width without and with rotation, and of
+    # its value
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN: (factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, mscale, mscale_all_dim); () = plain rotation
+    rope_yarn: tuple = ()
+    # positions a prompt is prefilled at a time where the family prefills
+    # in spans and no other field says (keye's is `index_q_chunk`)
+    prefill_chunk: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -240,17 +268,44 @@ def exact_dot(x: jax.Array, w: jax.Array, w_contract: int = 0) -> jax.Array:
         return dot(x.astype(w.dtype))
     if w.dtype == jnp.float32:
         return dot(x, jax.lax.Precision.HIGHEST)
-    # `reduce_precision` and not a cast there and back: inside a compiled
-    # program the chip's compiler may keep the excess precision of such a
-    # pair, the remainder is then zero and the product a single bfloat16
-    # pass (1.7e-3 off where this is 1e-7; my chip runs, PR 27)
+    whole = dot(_three_parts(x, w.dtype))     # [3, ..., N]
+    return whole[0] + whole[1] + whole[2]
+
+
+def _three_parts(x: jax.Array, dtype) -> jax.Array:
+    """Float32 x as three `dtype` (bfloat16) parts, stacked, that add up to
+    it to 24 bits. `reduce_precision` and not a cast there and back: inside
+    a compiled program the chip's compiler may keep the excess precision of
+    such a pair, the remainder is then zero and the product a single
+    bfloat16 pass (1.7e-3 off where this is 1e-7; my chip runs, PR 27)."""
     parts, rest = [], x
     for _ in range(3):
         part = jax.lax.reduce_precision(rest, exponent_bits=8,
                                         mantissa_bits=7)
-        parts.append(part.astype(w.dtype))
+        parts.append(part.astype(dtype))
         rest = rest - part
-    whole = dot(jnp.stack(parts))             # [3, ..., N]
+    return jnp.stack(parts)
+
+
+def exact_einsum(spec: str, x: jax.Array, w: jax.Array) -> jax.Array:
+    """`jnp.einsum(spec, x, w)` in float32 with `exact_dot`'s rule for
+    activations x over weights w, for products `exact_dot` cannot spell (a
+    head axis that both carry). `spec` names no axis `z`.
+
+    The three parts stay float32 arrays here (of values a bfloat16 holds)
+    and w is widened beside them: the one pass the chip makes of a float32
+    product at `DEFAULT` rounds both to bfloat16, which changes neither, and
+    the CPU has no bfloat16 product with a batch axis at all."""
+    one_pass = jax.lax.Precision.DEFAULT
+    if x.dtype != jnp.float32:
+        return jnp.einsum(spec, x.astype(jnp.float32),
+                          w.astype(jnp.float32), precision=one_pass)
+    if w.dtype == jnp.float32:
+        return jnp.einsum(spec, x, w, precision=jax.lax.Precision.HIGHEST)
+    lhs, rest = spec.split(",")
+    whole = jnp.einsum(f"z{lhs},{rest.replace('->', '->z')}",
+                       _three_parts(x, w.dtype).astype(jnp.float32),
+                       w.astype(jnp.float32), precision=one_pass)
     return whole[0] + whole[1] + whole[2]
 
 

@@ -25,6 +25,7 @@ from . import bert as bert_mod
 from . import deit as deit_mod
 from . import gpt2 as gpt2_mod
 from . import keye as keye_mod
+from . import kimi as kimi_mod
 from . import llama as llama_mod
 from . import vit as vit_mod
 
@@ -97,6 +98,22 @@ def _keye(name, weights, hidden, blocks, heads, kv_heads, head_dim, vocab,
         index_q_chunk=q_chunk))
 
 
+def _kimi(name, weights, hidden, blocks, heads, mla, dense_width, vocab,
+          max_pos, experts, expert_width, per_tok, span):
+    q_rank, kv_rank, nope, rope, v_dim = mla
+    return ModelEntry(name, 4 * blocks, weights, kimi_mod, TransformerConfig(
+        model_type="kimi", hidden_size=hidden, num_hidden_layers=blocks,
+        num_attention_heads=heads, intermediate_size=dense_width,
+        layer_norm_eps=1e-6, vocab_size=vocab,
+        max_position_embeddings=max_pos, rope_theta=50000.0,
+        rope_yarn=(32.0, 4096, 1.0, 1.0, 1.0, 1.0), n_experts=experts,
+        moe_intermediate_size=expert_width, num_experts_per_tok=per_tok,
+        norm_topk_prob=True, router="sigmoid", routed_scaling_factor=2.827,
+        n_shared_experts=1, first_k_dense=1, q_lora_rank=q_rank,
+        kv_lora_rank=kv_rank, qk_nope_head_dim=nope, qk_rope_head_dim=rope,
+        v_head_dim=v_dim, prefill_chunk=span))
+
+
 _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
     _vit("google/vit-base-patch16-224", 48, "ViT-B_16-224.npz", 768, 12, 12, 3072, 1000),
     _vit("google/vit-large-patch16-224", 96, "ViT-L_16-224.npz", 1024, 24, 16, 4096, 1000),
@@ -132,6 +149,13 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
           48, 32, 4, 128, vocab=151936, max_pos=262144, experts=128,
           expert_width=768, per_tok=8, index=(16, 64, 2048, 512),
           mrope=(16, 24, 24)),
+    # Kimi-K2: DeepSeek-V3's block (latent attention, a leading dense layer,
+    # 384 experts routed 8 a token by a sigmoid beside a shared one). One
+    # chip holds a share of it: `...@5,e0+12,v20480` (get_model_entry)
+    _kimi("moonshotai/Kimi-K2-Instruct", "Kimi-K2-Instruct.npz", 7168, 61,
+          64, (1536, 512, 128, 64, 128), 18432, vocab=163840,
+          max_pos=131072, experts=384, expert_width=2048, per_tok=8,
+          span=128),
     # tiny synthetic models for fast tests / CI (not in the reference's list)
     _vit("pipeedge/test-tiny-vit", 8, "test-tiny-vit.npz", 32, 2, 4, 64, 5,
          patch=4, img=16),
@@ -149,6 +173,9 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
     _keye("pipeedge/test-tiny-keye", "test-tiny-keye.npz", 32, 2, 4, 2, 16,
           vocab=100, max_pos=64, experts=8, expert_width=16, per_tok=2,
           index=(2, 8, 4, 8), mrope=(2, 3, 3)),
+    _kimi("pipeedge/test-tiny-kimi", "test-tiny-kimi.npz", 32, 3, 4,
+          (24, 16, 8, 8, 8), 64, vocab=100, max_pos=64, experts=8,
+          expert_width=16, per_tok=2, span=8),
     _gpt2("pipeedge/test-tiny-moe", 8, "test-tiny-moe.npz", 32, 2, 4, 64,
           vocab=100, max_pos=64, n_experts=4, capacity_factor=4.0),
 ]}
@@ -160,31 +187,54 @@ def get_model_names() -> List[str]:
 
 
 def get_model_entry(model_name: str) -> ModelEntry:
-    """The entry of `model_name`. `<name>@<blocks>` is the same model cut to
-    its first `blocks` blocks, with its embedding, final norm and head: the
-    depth cut a benchmark or a stage-sized deployment runs, as a rule and
-    not as entries of their own."""
-    name, at, depth = model_name.partition("@")
+    """The entry of `model_name`. `<name>@<cut>` is the same model cut to
+    what one chip or one stage holds of it, with its embedding, final norm
+    and head, as a rule and not as entries of their own: `<cut>` is comma
+    separated, `<blocks>` (its first blocks: the depth cut a benchmark or a
+    stage-sized deployment runs), `e<first>+<count>` (of each expert
+    layer's experts the `count` from `first`: the share of one of the chips
+    a deployment divides a layer over; the router keeps its width) and
+    `v<rows>` (the first rows of the vocabulary, in embedding and head)."""
+    name, at, cut = model_name.partition("@")
     entry = _MODELS[name]
     if not at:
         return entry
-    blocks = int(depth)
-    if not 1 <= blocks <= entry.config.num_hidden_layers:
-        raise ValueError(f"{model_name}: {name} has "
-                         f"{entry.config.num_hidden_layers} blocks")
+    cfg, layers = entry.config, entry.layers
+    for part in cut.split(","):
+        try:
+            if part[:1] == "e":
+                held = tuple(int(n) for n in part[1:].split("+"))
+                first, count = held
+                if not (cfg.num_experts_per_tok and first >= 0 and count >= 1
+                        and first + count <= cfg.n_experts):
+                    raise ValueError
+                cfg = dataclasses.replace(cfg, held_experts=held)
+            elif part[:1] == "v":
+                if not 1 <= int(part[1:]) <= cfg.vocab_size:
+                    raise ValueError
+                cfg = dataclasses.replace(cfg, vocab_size=int(part[1:]))
+            else:
+                if not 1 <= int(part) <= cfg.num_hidden_layers:
+                    raise ValueError
+                cfg = dataclasses.replace(cfg, num_hidden_layers=int(part))
+                layers = 4 * int(part)
+        except ValueError:
+            raise ValueError(
+                f"{model_name}: {name} has {entry.config.num_hidden_layers} "
+                f"blocks, {entry.config.n_experts} routed experts and a "
+                f"vocabulary of {entry.config.vocab_size}; no cut "
+                f"{part!r}") from None
     stem, ext = os.path.splitext(entry.weights_file)
-    return dataclasses.replace(
-        entry, name=model_name, layers=4 * blocks,
-        weights_file=f"{stem}@{blocks}{ext}",
-        config=dataclasses.replace(entry.config, num_hidden_layers=blocks))
+    return dataclasses.replace(entry, name=model_name, layers=layers,
+                               weights_file=f"{stem}@{cut}{ext}", config=cfg)
 
 
 def decoder_model(model_name: str) -> str:
     """argparse `type=` of the decoding CLIs: a registered causal decoder,
-    whole or as `<name>@<blocks>`."""
+    whole or as `<name>@<cut>`."""
     try:
         known = get_model_entry(model_name).config.model_type in (
-            "gpt2", "llama", "keye")
+            "gpt2", "llama", "keye", "kimi")
     except (KeyError, ValueError):
         known = False
     if not known:

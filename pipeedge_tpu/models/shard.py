@@ -20,7 +20,8 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Callable, Dict
+from itertools import groupby
+from typing import Any, Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +30,15 @@ from . import BlockSlice, ShardConfig, plan_shard
 from .layers import TransformerConfig
 
 ShardData = Any  # jax.Array | tuple[jax.Array, jax.Array]
+
+
+class BlockRuns(NamedTuple):
+    """A shard's full blocks where they are not all of one kind (a leading
+    dense layer before the expert layers): one stacked pytree `[n, ...]` a
+    run of like blocks, in the model's order. The decode scan takes a run
+    at a time (parallel/decode.py `_run_blocks`); a shard of one kind keeps
+    the bare stacked pytree."""
+    runs: tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +78,10 @@ class FamilySpec:
     # what the block steps count into the cache's `stats` leaf, in order:
     # each is read back once a batch into `pipeedge_<name>_total{phase}`
     stats_names: tuple = ()
+    # (cfg, block_id) -> the kind of that block, where a model's blocks
+    # differ in their leaves: consecutive blocks of one kind are one run
+    # (`build_shard_params`' `kind`, `BlockRuns`); None = all alike
+    block_kind: Any = None
     # sublayers that LEAD with a dense and accept an 8-bit wire
     # `QuantizedTensor` as the payload's first tensor (the int8
     # stage-seam tunnel, parallel/pipeline.py + ops/int8_matmul.py)
@@ -112,6 +126,11 @@ def shard_apply(family: FamilySpec, cfg: TransformerConfig,
     if plan.full_ids:
         full = BlockSlice(0, 0, 3)
         blocks = params["blocks"]
+        if isinstance(blocks, BlockRuns):
+            raise NotImplementedError(
+                f"the {family.name} family's blocks come in runs of "
+                "different kinds, which the forward path does not scan yet; "
+                "it runs through the cached decode path (parallel/decode.py)")
         if isinstance(blocks, (tuple, list)):
             for block_params in blocks:
                 data = _apply_slice(family, block_params, data, full, cfg)
@@ -144,7 +163,7 @@ def unstack_blocks(params: Dict) -> Dict:
     docstring for the measured TPU win). No-op for shards without full
     blocks or already-unstacked params."""
     blocks = params.get("blocks")
-    if blocks is None or isinstance(blocks, (tuple, list)):
+    if blocks is None or isinstance(blocks, (tuple, list)):     # or runs
         return params
     n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
     out = dict(params)
@@ -157,14 +176,18 @@ def build_shard_params(shard_config: ShardConfig,
                        get_embed: Callable[[], Dict],
                        get_block: Callable[[int, tuple], Dict],
                        get_final: Callable[[], Dict],
-                       stack: Callable = None) -> Dict:
+                       stack: Callable = None,
+                       kind: Callable = None) -> Dict:
     """Assemble a shard's parameter pytree from per-component getters.
 
     `get_block(block_id, sublayers)` returns only the parameters the listed
     sublayers need — a shard never materializes weights outside its layer
     range, mirroring the reference's lazy npz slicing (vit.py:93-118).
     `stack` replaces `stack_blocks` (a family whose getters return host
-    arrays stacks them there).
+    arrays stacks them there). `kind(block_id)` tells blocks of different
+    leaves apart: each run of consecutive like blocks is stacked on its
+    own, and where there is more than one the shard's blocks are a
+    `BlockRuns`.
     """
     plan = plan_shard(shard_config)
     params: Dict = {}
@@ -173,8 +196,12 @@ def build_shard_params(shard_config: ShardConfig,
     if plan.head is not None:
         params["head"] = get_block(plan.head.block_id, tuple(plan.head.sublayers()))
     if plan.full_ids:
-        params["blocks"] = (stack or stack_blocks)(
-            [get_block(b, (0, 1, 2, 3)) for b in plan.full_ids])
+        runs = [(stack or stack_blocks)(
+            [get_block(b, (0, 1, 2, 3)) for b in run])
+            for _, run in groupby(plan.full_ids,
+                                  key=kind or (lambda b: None))]
+        params["blocks"] = runs[0] if len(runs) == 1 \
+            else BlockRuns(tuple(runs))
     if plan.tail is not None:
         params["tail"] = get_block(plan.tail.block_id, tuple(plan.tail.sublayers()))
     if shard_config.is_last:

@@ -1,4 +1,5 @@
-"""Autoregressive KV-cache decoding through the pipeline (GPT-2 family).
+"""Autoregressive KV-cache decoding through the pipeline (the GPT-2 family
+by default; llama, keye and kimi through `FamilySpec.cached_block_step`).
 
 NEW capability beyond the reference (whose model list is encoder-only and
 whose runtime is single-shot batch inference). TPU-first design:
@@ -34,6 +35,7 @@ from ..telemetry import metrics as prom
 from ..utils import jax_compat
 
 from ..models import ShardConfig, plan_shard
+from ..models.shard import BlockRuns
 from ..models.layers import (TransformerConfig, dense, gelu_new, layer_norm)
 
 Cache = Dict[str, jax.Array]   # {'k': [L, B, T, H, Dh], 'v': [L, B, T, H, Dh]}
@@ -253,7 +255,8 @@ def _attend_width(bcache: LayerCache, read_len: Optional[int]) -> int:
     bucketed `read_len` when one is bound — THE window formula, shared
     by the XLA read path and the Pallas kernel route so they can never
     attend different windows."""
-    t_max = bcache.stack["k"].shape[2]
+    t_max = next(buf.shape[2] for name, buf in bcache.stack.items()
+                 if name != STATS)
     return t_max if read_len is None else min(read_len, t_max)
 
 
@@ -545,11 +548,14 @@ def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
 
 
 def stage_blocks(params: Dict) -> jax.Array:
-    """The stacked blocks pytree of a decode stage (block-aligned shard)."""
+    """The stacked blocks pytree of a decode stage (block-aligned shard),
+    or its `BlockRuns` where the blocks are of more than one kind."""
     blocks = params.get("blocks")
     if blocks is None:
         raise ValueError("decode stages must contain full blocks "
                          "(block-aligned partition)")
+    if isinstance(blocks, BlockRuns):
+        return blocks
     if isinstance(blocks, (tuple, list)):  # unrolled layout -> restack
         blocks = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *blocks)
     return blocks
@@ -573,29 +579,40 @@ def attend_bucket(pos_next: int, max_len: int, floor: int = 64) -> int:
 def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
                 prefill: bool, block_fn=_block_step,
                 whole: tuple = ()) -> Tuple[jax.Array, Cache]:
-    """Scan the stage's blocks over x. The scan only READS the stacked
-    cache (each block its layer's window) and stacks the blocks' new rows;
-    one update a leaf then writes them where the donated buffer's layout
-    is the program's own. The stack must not be the scan's carry: the TPU
-    compiler lays a carried buffer out to suit the rows written into it and
-    copies the whole cache into and out of that layout around the loop
-    (PERF.md, PR 25). Block leaves named in `whole` are not scanned over
-    either: the block step gets each as a `LayerSlice`."""
-    held = {name: blocks[name] for name in whole}
-    if held:
-        blocks = {name: leaf for name, leaf in blocks.items()
-                  if name not in held}
-
-    def body(y, xs):
-        bp, layer = xs
+    """Scan the stage's blocks over x: one scan a run of like blocks (a
+    bare stacked pytree is one run; `BlockRuns`, a dense layer before
+    expert layers, several), all over the one cache stack, a run's blocks
+    at the layers that follow the run before. The scan only READS the
+    stacked cache (each block its layer's window) and stacks the blocks'
+    new rows; one update a leaf then writes them where the donated buffer's
+    layout is the program's own. The stack must not be the scan's carry:
+    the TPU compiler lays a carried buffer out to suit the rows written
+    into it and copies the whole cache into and out of that layout around
+    the loop (PERF.md, PR 25). Block leaves named in `whole` are not
+    scanned over either: the block step gets each as a `LayerSlice`."""
+    runs = blocks.runs if isinstance(blocks, BlockRuns) else (blocks,)
+    rows, first = [], 0
+    for run in runs:
+        held = {name: run[name] for name in whole if name in run}
         if held:
-            bp = dict(bp, **{name: LayerSlice(leaf, layer)
-                             for name, leaf in held.items()})
-        y, bc = block_fn(bp, y, LayerCache(cache, layer), pos, cfg, prefill)
-        return y, bc.rows
+            run = {name: leaf for name, leaf in run.items()
+                   if name not in held}
 
-    n_blocks = next(iter(cache.values())).shape[0]
-    x, rows = jax.lax.scan(body, x, (blocks, jnp.arange(n_blocks)))
+        def body(y, xs, held=held, first=first):
+            bp, layer = xs
+            if held:
+                bp = dict(bp, **{name: LayerSlice(leaf, layer)
+                                 for name, leaf in held.items()})
+            at = first + layer if first else layer      # the cache's layer
+            y, bc = block_fn(bp, y, LayerCache(cache, at), pos, cfg, prefill)
+            return y, bc.rows
+
+        n_blocks = jax.tree_util.tree_leaves(run)[0].shape[0]
+        x, new = jax.lax.scan(body, x, (run, jnp.arange(n_blocks)))
+        rows.append(new)
+        first += n_blocks
+    rows = rows[0] if len(rows) == 1 else jax.tree_util.tree_map(
+        lambda *parts: jnp.concatenate(parts), *rows)
     return x, _write_rows(cache, rows, 0 if prefill else pos)
 
 

@@ -241,14 +241,15 @@ def shard_moe_params(params: Dict, mesh: Mesh, axis: str = "ep") -> Dict:
     }
 
 
-# -- top-k routing without drops (the keye family's expert layer) --------
+# -- top-k routing without drops (the keye and kimi families' expert layer)
 #
 # A token goes to its `num_experts_per_tok` best experts, every assignment
 # is computed, and the layer is told which experts it holds: it routes over
 # all of them and computes the part of the result that its own give. On one
-# chip it holds all and nothing is exchanged; under an 'ep' axis each device
-# passes its slab and one psum adds the parts (`_ep_delta_from_routing`'s
-# shape for top-1).
+# chip that holds all nothing is exchanged; a chip that holds a share (one
+# of the chips a deployment divides each layer over) leaves out what the
+# absent experts would add; under an 'ep' axis each device passes its slab
+# and one psum adds the parts (`_ep_delta_from_routing`'s shape for top-1).
 
 # rows of one tile of the grouped product: the assignments are sorted by
 # expert, each expert's group is covered by whole tiles, and one loop step
@@ -256,35 +257,69 @@ def shard_moe_params(params: Dict, mesh: Mesh, axis: str = "ep") -> Dict:
 # 768 expert are as many FLOPs as its weights are bytes on a v5e.
 EXPERT_TILE = 256
 
+# tiles whose results are kept at a time, as a multiple of what the held
+# experts are given when the router spreads its choices evenly
+ROUND_SLACK = 4
+
 # what `topk_ffn_delta` counts, in this order (a float32 vector; each is far
 # below 2**24 a call)
 MOE_STATS = ("assignments", "rows_computed", "experts_touched")
 
 
-def topk_route(router_w: jax.Array, tokens: jax.Array,
-               cfg: TransformerConfig):
-    """(experts [T, k], gates [T, k]) of `tokens` [T, D]: softmax over all
-    experts and the top-k in float32, gates renormalised over the kept
-    where the config says so. Ties go to the lower expert (`lax.top_k`)."""
+def topk_route(router: Dict, tokens: jax.Array, cfg: TransformerConfig):
+    """(experts [T, k], gates [T, k]) of `tokens` [T, D], in float32 over
+    all the experts the router `{w [D, E][, bias [E]]}` knows. Ties go to
+    the lower expert (`lax.top_k`).
+
+    `cfg.router` "softmax": the top-k of the softmax, gates renormalised
+    over the kept where the config says so. "sigmoid": each expert's score
+    is its own sigmoid; the k are chosen on score + `bias` (a correction
+    that steers the choice and is no part of the gate), and the gates are
+    the chosen scores, renormalised where the config says so, times
+    `cfg.routed_scaling_factor`."""
     logits = jnp.dot(tokens.astype(jnp.float32),
-                     router_w.astype(jnp.float32),
+                     router["w"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, experts = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    k = cfg.num_experts_per_tok
+    if cfg.router == "softmax":
+        gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    elif cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(
+            scores + router["bias"].astype(jnp.float32), k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+    else:
+        raise ValueError(f"no router {cfg.router!r}")
     if cfg.norm_topk_prob:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
-    return experts, gates
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
+                         + (1e-20 if cfg.router == "sigmoid" else 0.0))
+    return experts, gates * cfg.routed_scaling_factor
+
+
+def _swiglu(x: jax.Array, gate_w, up_w, down_w) -> jax.Array:
+    """down(silu(gate x) * up x) of x [rows, D] over `nn.Linear` matrices
+    [out, in] as stored -> float32 [rows, D]."""
+    def product(a, w):
+        return exact_dot(a, w, w_contract=1)
+
+    hidden = (jax.nn.silu(product(x, gate_w))
+              * product(x, up_w)).astype(x.dtype)
+    return product(hidden, down_w)
 
 
 def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
                    held=None, layer=None):
     """Routed SwiGLU FFN delta of `normed` [B, S, D], and its counts.
 
-    `params`: `router` {w [D, E]} and `experts` {gate, up [.., F, D], down
-    [.., D, F]} (`nn.Linear` layout, no bias), the expert axis holding the
-    `held` experts only. `held` = (first, count): the experts this caller
-    computes, `first` possibly traced (an 'ep' device's slab); None = all.
-    Assignments to other experts add nothing here. `layer`, when given,
+    `params`: `router` {w [D, E][, bias]}, `experts` {gate, up [.., F, D],
+    down [.., D, F]} (`nn.Linear` layout, no bias), the expert axis holding
+    the `held` experts only, and where the model has one `shared` {gate, up
+    [Fs, D], down [D, Fs]}, the expert every token goes through, which is
+    added here (every chip of a deployment computes it alike: when shares
+    are added up it counts once). `held` = (first, count): the experts this
+    caller computes, `first` possibly traced (an 'ep' device's slab); None
+    = `cfg.held_experts`, or all. Assignments to other experts cost a sort
+    key and nothing more, and add nothing here. `layer`, when given,
     indexes a leading layer axis of the expert leaves: the stacked blocks
     are then sliced one tile's matrices at a time and a whole layer's
     experts are never copied out of the stack.
@@ -293,12 +328,11 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     b, s, d = normed.shape
     tokens = normed.reshape(-1, d)
     t, k = tokens.shape[0], cfg.num_experts_per_tok
-    first, count = (0, cfg.n_experts) if held is None else held
-    experts, gates = topk_route(params["router"]["w"], tokens, cfg)
+    first, count = held or cfg.held_experts or (0, cfg.n_experts)
+    experts, gates = topk_route(params["router"], tokens, cfg)
     local = experts.reshape(-1) - first                     # [A]
     mine = (local >= 0) & (local < count)
     local = jnp.where(mine, local, count)                   # others sort last
-    gates = jnp.where(mine, gates.reshape(-1), 0.0)
     n_assign = t * k
 
     tile = min(EXPERT_TILE, -(-t // 8) * 8)
@@ -316,17 +350,27 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     tiles_of = -(-sizes // tile)
     tile_ends = jnp.cumsum(tiles_of)
     used = tile_ends[-1]
-    # tile i: its expert, and the sorted row it starts at. A group's last
-    # tile runs past the group's end into the rows of later groups; their
-    # own tiles come later in the loop and write those rows again, so no
-    # group is padded and no row is moved to make room
+    # tile i: its expert, and the sorted assignment it starts at. A group's
+    # last tile runs past the group's end into the rows of later groups;
+    # their own tiles come later in the loop and write those rows again, so
+    # no group is padded and no row is moved to make room
     tile_id = jnp.arange(n_tiles)
     tile_expert = jnp.minimum(
         jnp.searchsorted(tile_ends, tile_id, side="right"), count - 1)
     tile_start = group_first[tile_expert] + tile * (
         tile_id - (tile_ends - tiles_of)[tile_expert])
-    rows = jnp.concatenate([tokens[order // k],
-                            jnp.zeros((tile, d), tokens.dtype)])
+    # the tiles' results are kept for one round of tiles at a time
+    # (`ROUND_SLACK`; every tile where all experts are held). One round is
+    # all there is unless the routing is that skewed, and nothing of the
+    # size assignments x hidden is built for a share
+    round_tiles = min(n_tiles, -(-ROUND_SLACK * n_assign * count
+                                 // (cfg.n_experts * tile)) + count)
+    kept_rows = min(round_tiles * tile, n_assign + tile)
+    # each token's assignments: where they sorted to, and their gates
+    sorted_at = jnp.argsort(order).reshape(t, k)
+    gates = jnp.where(mine, gates.reshape(-1), 0.0).reshape(t, k)
+    # a group's last tile may run past the last assignment
+    order = jnp.concatenate([order, jnp.full((tile,), n_assign, order.dtype)])
 
     ex = params["experts"]
 
@@ -337,23 +381,41 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
         return jax.lax.dynamic_slice(
             w, (layer, e, 0, 0), (1, 1) + w.shape[2:])[0, 0]
 
-    def one_tile(i, out):
-        x = jax.lax.dynamic_slice_in_dim(rows, tile_start[i], tile)
-        e = tile_expert[i]
+    def one_round(r, delta):
+        first_tile = r * round_tiles
+        last_tile = jnp.minimum(first_tile + round_tiles, used)
+        base = tile_start[first_tile]
+        end = jnp.where(last_tile < used,
+                        tile_start[jnp.minimum(last_tile, n_tiles - 1)],
+                        bounds[count])
 
-        def product(a, w):      # a [tile, in] with w [out, in]
-            return exact_dot(a, w, w_contract=1)
+        def one_tile(i, out):
+            # the tile's rows, gathered here from the tokens
+            at = jax.lax.dynamic_slice_in_dim(order, tile_start[i], tile)
+            x = jnp.take(tokens, jnp.minimum(at // k, t - 1), axis=0)
+            e = tile_expert[i]
+            y = _swiglu(x, *(matrix(name, e)
+                             for name in ("gate", "up", "down")))
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, y, tile_start[i] - base, 0)
 
-        gate = jax.nn.silu(product(x, matrix("gate", e)))
-        hidden = (gate * product(x, matrix("up", e))).astype(x.dtype)
-        y = product(hidden, matrix("down", e)).astype(out.dtype)
-        return jax.lax.dynamic_update_slice_in_dim(out, y, tile_start[i], 0)
+        out = jax.lax.fori_loop(first_tile, last_tile, one_tile,
+                                jnp.zeros((kept_rows, d), jnp.float32))
+        # back to the tokens: each of a token's k assignments in turn
+        for slot in range(k):
+            at = sorted_at[:, slot]
+            here = (at >= base) & (at < end)
+            rows = jnp.take(out, jnp.clip(at - base, 0, kept_rows - 1),
+                            axis=0)
+            delta = delta + jnp.where(here, gates[:, slot],
+                                      0.0)[:, None] * rows
+        return delta
 
-    out = jax.lax.fori_loop(0, used, one_tile, jnp.zeros_like(rows))
-    # back to the order the assignments were made in: token by token
-    picked = out[jnp.argsort(order)]
-    delta = jnp.sum(picked.reshape(t, k, d).astype(jnp.float32)
-                    * gates.reshape(t, k, 1), axis=1)
+    delta = jax.lax.fori_loop(0, -(-used // round_tiles), one_round,
+                              jnp.zeros((t, d), jnp.float32))
+    if "shared" in params:
+        delta = delta + _swiglu(tokens, *(params["shared"][name]
+                                          for name in ("gate", "up", "down")))
     stats = jnp.stack([jnp.sum(mine), used * tile,
                        jnp.sum(sizes > 0)]).astype(jnp.float32)
     return delta.reshape(b, s, d).astype(normed.dtype), stats

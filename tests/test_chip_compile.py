@@ -193,6 +193,47 @@ def test_keye_stage_program_compiles_for_v5e(span, last_only, on_chip):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
 
 
+KIMI_CELL = "moonshotai/Kimi-K2-Instruct@5,e0+12,v20480"
+
+
+@pytest.mark.parametrize("rows", [32, 64])
+@pytest.mark.parametrize("span, last_only", [(1, False), (128, True)])
+def test_kimi_stage_program_compiles_for_v5e(span, last_only, rows, on_chip):
+    """`kimi-k2.agent-batch` at its real size: the dense layer and four
+    expert layers at the published widths with 12 of 384 experts held, the
+    4,096 bucket; a decode step and one span of the prefill, at the cell's
+    32 rows and at the 64 that ISSUE 31 sized it for (a batch of which takes
+    46 s on the chip). The resident bytes (6.99 GB of weights, 3.02 GB of
+    latent cache at 64 rows) and the program's temporaries have to fit one
+    chip's 16 GB."""
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry(KIMI_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    # the shapes alone: `init_params` would draw 3.5 G values on the host
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: decode.init_cache(
+        cfg, cfg.num_hidden_layers, rows, 4096,
+        leaves=entry.family.cache_leaves(cfg)))
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=4096,
+                          last_only=last_only).compile()
+    memory = compiled.memory_analysis()
+    print(f"kimi {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * 4096 * 11520
+    assert memory.alias_size_in_bytes > cache_bytes     # updated in place
+    # no leaf of the cache is padded: 576 values a row are what it takes
+    assert memory.argument_size_in_bytes < 7.0e9 + 1.01 * cache_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+
+
 @pytest.mark.parametrize("n_ubatch", [1024, 4])
 def test_spmd_vit_large_cell_compiles_for_v5e(n_ubatch, topo):
     """`vit-l.spmd-4stage` at its real widths: four stages of six ViT-L
