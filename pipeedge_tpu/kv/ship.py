@@ -2,7 +2,7 @@
 
 The disaggregation split (kv/disagg.py) runs prompt passes on a PREFILL
 fleet and decode waves on a DECODE fleet; what travels between them is
-each request's per-stage KV rows `[n_blocks, B, prompt_len, H, Dh]`
+each request's per-stage KV rows `[n_blocks, B, prompt_len, H*Dh]`
 plus the last stage's final-position logits `[B, V]` (the pick stays on
 the decode side, with the request's own rng — disaggregated tokens are
 identical to colocated ones).

@@ -18,7 +18,7 @@ KV-cache decoding: the family supplies its own cached block step
 (`cached_block_step`) and single-token embed (`decode_embed`) through the
 FamilySpec hooks, so `DecodePipeline` / the continuous batcher / the SPMD
 wave decoder drive LLaMA unchanged. The cache stores POST-RoPE K at the
-GQA head count ([blocks, B, T, kv_heads, Dh] — `cfg.kv_heads` sizes it),
+GQA head count ([blocks, B, T, kv_heads * Dh] — `cfg.kv_heads` sizes it),
 and each step rotates only the new token's q/k at its position.
 
 Weight format: HF `LlamaForCausalLM` state dict (`model.`-prefixed
@@ -55,11 +55,6 @@ def _split_heads(y: jax.Array, n_heads: int) -> jax.Array:
     return y.reshape(b, s, n_heads, -1)
 
 
-def _repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
-    """[B, S, kv_heads, Dh] -> [B, S, kv_heads * n_rep, Dh] (GQA groups)."""
-    return x if n_rep == 1 else jnp.repeat(x, n_rep, axis=2)
-
-
 def _window_keep(keep: jax.Array, q_pos, cfg: TransformerConfig):
     """Intersect a keep mask [S_q, S_k] with the sliding window: position
     q attends to k in (q - window, q] (Mistral semantics — the last
@@ -72,19 +67,17 @@ def _window_keep(keep: jax.Array, q_pos, cfg: TransformerConfig):
 
 
 def _gqa_attend(q, k, v, cfg: TransformerConfig, keep=None) -> jax.Array:
-    """softmax(QK^T)V with GQA head repetition; `keep` optionally masks
-    key positions (the decode path: k, v and keep are then the parts
+    """softmax(QK^T)V over grouped kv heads; `keep` optionally masks key
+    positions (the decode path: k, v and keep are then the parts
     `_cache_update_and_read` returns, sliding window included), else
-    causal (+ window). Delegates the masked-softmax body to the decode
-    subsystem's `_attend` — ONE copy of the attention numerics for both
-    consumers."""
-    from ..parallel.decode import _attend, _parts
+    causal (+ window). The decode subsystem's `_attend` is the masked
+    softmax and the grouping both (a query head reads its group's kv head;
+    nothing is repeated up to the query heads) — ONE copy of the attention
+    numerics for both consumers."""
+    from ..parallel.decode import _attend
 
-    h = q.shape[2]
-    k = tuple(_repeat_kv(part, h // part.shape[2]) for part in _parts(k))
-    v = tuple(_repeat_kv(part, h // part.shape[2]) for part in _parts(v))
     if keep is None:                 # full forward: causal over [S, S]
-        s_q, s_k = q.shape[1], k[0].shape[1]
+        s_q, s_k = q.shape[1], k.shape[1]
         q_pos = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 0)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 1)
         keep = _window_keep(k_pos <= q_pos, q_pos, cfg)

@@ -5,7 +5,7 @@ NEW capability beyond the reference (whose model list is encoder-only and
 whose runtime is single-shot batch inference). TPU-first design:
 
 - **Static shapes everywhere**: the KV cache is a fixed [n_blocks, B,
-  max_len, H, Dh] buffer per stage; the current length rides as a traced
+  max_len, H*Dh] buffer per stage; the current length rides as a traced
   scalar `pos`, future positions are masked. One compiled prefill program +
   one compiled decode-step program per stage serve the whole generation —
   no per-step recompilation (the reference's dynamic-shape wire protocol
@@ -16,8 +16,8 @@ whose runtime is single-shot batch inference). TPU-first design:
   pipeline (quantizable, device-placeable). Autoregression serializes
   decode steps, so parallelism comes from the batch dimension; stages
   still split the model across devices for memory capacity.
-- Attention over the cache streams as one [B, H, 1, T_max] masked matmul —
-  MXU-shaped, no gather.
+- Attention over the cache streams the window as it is stored, one masked
+  matmul a product — MXU-shaped, no gather, no copy of the window.
 
 Greedy decoding matches HF `GPT2LMHeadModel.generate(do_sample=False)`
 token-for-token (tests/test_decode.py).
@@ -38,7 +38,15 @@ from ..models import ShardConfig, plan_shard
 from ..models.shard import BlockRuns
 from ..models.layers import (TransformerConfig, dense, gelu_new, layer_norm)
 
-Cache = Dict[str, jax.Array]   # {'k': [L, B, T, H, Dh], 'v': [L, B, T, H, Dh]}
+Cache = Dict[str, jax.Array]   # {'k': [L, B, T, H*Dh], 'v': [L, B, T, H*Dh]}
+# The heads are folded into the last axis (H is `cfg.kv_heads`, head g in
+# lanes [g*Dh, (g+1)*Dh)). The TPU compiler chooses a leaf's layout from its
+# shape: `[L, B, T, H, Dh]` with Dh = 64 it stores positions minor-most in
+# tiles of 128 (a 64-wide minor axis would be padded to 128 lanes), and a
+# decode step's one position is then one lane of every tile it touches. The
+# folded leaf's minor axis is whole lanes, so it is kept as declared, a
+# position is a row, and `_write_rows` is one row update a leaf
+# (docs/DECODE.md). The attention reads the window in this form (`_attend`).
 # int8 variant adds per-(block, batch, position, head) scale/shift rows —
 # the head axis shards over 'tp' with the K/V buffers:
 #   {'k': int8, 'v': int8, 'k_scale'/'k_shift'/'v_scale'/'v_shift': [L, B, T, H]}
@@ -100,37 +108,13 @@ def _read_window(buf: jax.Array, layer, width: int,
     return jax.lax.dynamic_slice(buf, start, sizes)[0]
 
 
-# positions in one tile of a stored leaf: the TPU keeps a `[L, B, T, H, Dh]`
-# leaf with T minor-most (no padding of a 64-wide head that way), tiled by 128
-_POS_TILE = 128
-
-
 def _write_rows(cache: Cache, rows: Cache, pos) -> Cache:
     """Every layer's new `rows` (leaves `[L, B, S, ...]`) into the stacked
-    cache at positions [pos, pos + S): one in-place update a leaf.
-
-    A decode step's single row is one lane of each tile it touches, and
-    the bare row update runs at a fifth of the rate those tiles could be
-    rewritten at (PERF.md, PR 25). So where the position axis is whole
-    tiles, the step updates the whole tile of positions that holds `pos`:
-    sliced at an offset the compiler can see is aligned, the row selected
-    in, written back where it came from."""
+    cache at positions [pos, pos + S): one in-place update a leaf, for a
+    decode step, a span and a prefill alike."""
     def write(buf, new):
-        new = new.astype(buf.dtype)
-        tail = (0,) * (buf.ndim - 3)
-        if new.shape[2] != 1 or buf.shape[2] % _POS_TILE:
-            return jax.lax.dynamic_update_slice(buf, new, (0, 0, pos) + tail)
-        # by bits, not `//`, and with no wrap of a negative index: the
-        # compiler's known-bits analysis must see a whole number of tiles
-        base = pos & -_POS_TILE
-        tile = jax.lax.dynamic_slice(
-            buf, (0, 0, base) + tail,
-            buf.shape[:2] + (_POS_TILE,) + buf.shape[3:],
-            allow_negative_indices=False)
-        here = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 2) == pos - base
         return jax.lax.dynamic_update_slice(
-            buf, jnp.where(here, new, tile), (0, 0, base) + tail,
-            allow_negative_indices=False)
+            buf, new.astype(buf.dtype), (0, 0, pos) + (0,) * (buf.ndim - 3))
 
     def add(buf, new):      # `stats`: [L, n, 2] += [L, n]
         low = buf[..., 1] + new
@@ -167,9 +151,10 @@ def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
     compose with tensor-parallel decode, and the finer granularity also
     tightens the quantization error.
 
-    The head axis is `cfg.kv_heads` — equal to the query head count for
+    The heads are `cfg.kv_heads` — equal to the query head count for
     every family except GQA decoders (llama), whose cache is kv_heads/
-    num_attention_heads times smaller (the point of GQA)."""
+    num_attention_heads times smaller (the point of GQA) — folded with
+    the head width into the last axis (the `Cache` comment above)."""
     if leaves is not None:
         if cache_bits:
             raise NotImplementedError(
@@ -179,12 +164,12 @@ def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
                 jnp.zeros((n_blocks, batch, max_len) + tail.shape,
                           tail.dtype)
                 for name, tail in leaves.items()}
-    shape = (n_blocks, batch, max_len, cfg.kv_heads, cfg.head_dim)
+    shape = (n_blocks, batch, max_len, cfg.kv_heads * cfg.head_dim)
     if cache_bits == 0:
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if cache_bits != 8:
         raise ValueError(f"cache_bits must be 0 (off) or 8, got {cache_bits}")
-    rows = shape[:4]                       # [..., T, H] per-head scales
+    rows = shape[:3] + (cfg.kv_heads,)     # [..., T, H] per-head scales
     cache = {"k": jnp.zeros(shape, jnp.int8),
              "v": jnp.zeros(shape, jnp.int8)}
     for t in ("k", "v"):
@@ -223,19 +208,79 @@ def _parts(x) -> tuple:
     return tuple(x) if isinstance(x, (tuple, list)) else (x,)
 
 
-def _attend(q: jax.Array, k, v, keep, cfg: TransformerConfig) -> jax.Array:
-    """Masked attention of q [B,S,H,Dh] over k/v [B,T,H,Dh]; `keep`
-    [S, T] marks key positions each query may attend to. k, v and keep
-    may each be a tuple of such parts (a cached step's window and its
-    fresh rows, `_cache_update_and_read`): one softmax runs over all
-    their keys, and no part is copied to sit beside another."""
+# columns of one MXU pass: a product with fewer is padded to these. On the
+# chip (gpt2-medium, 32 rows, 512 window; PERF.md, PR 32) a span's step over
+# the stored window took 10.3 ms at 128 query columns and 19.1 at 256, with
+# the window copied heads apart 14.6 and 17.9
+_MXU_COLUMNS = 128
+
+
+def _fold(x: jax.Array) -> jax.Array:
+    """[B, S, H, Dh] -> [B, S, H*Dh]: the heads into the last axis, as a
+    cache leaf stores them."""
+    return x.reshape(x.shape[:2] + (-1,))
+
+
+def _scores(q: jax.Array, k_part: jax.Array) -> jax.Array:
+    """q [B,S,H,Dh] against one part's keys -> [B,H,S,T] float32."""
     b, s, h, hd = q.shape
-    scores = []
-    for k_part, keep_part in zip(_parts(k), _parts(keep)):
-        part = jnp.einsum("bqhd,bkhd->bhqk", q, k_part,
+    stored = k_part.ndim == 3
+    g = k_part.shape[2] // hd if stored else k_part.shape[2]
+    q = q.reshape(b, s, g, h // g, hd)
+    if stored:      # the queries in blocks, column (h, s)
+        blocks = jnp.einsum("bqgrd,cg->bcdgrq", q, jnp.eye(g, dtype=q.dtype))
+        part = jnp.einsum("bkc,bcn->bnk", k_part,
+                          blocks.reshape(b, g * hd, h * s),
                           preferred_element_type=jnp.float32)
-        part = part / jnp.sqrt(jnp.float32(hd))
-        scores.append(jnp.where(keep_part[None, None], part, -1e30))
+    else:
+        part = jnp.einsum("bqgrd,bkgd->bgrqk", q, k_part,
+                          preferred_element_type=jnp.float32)
+    return part.reshape(b, h, s, -1)
+
+
+def _context(probs: jax.Array, v_part: jax.Array, hd: int) -> jax.Array:
+    """probs [B,H,S,T] over one part's values -> [B,S,H,Dh] float32."""
+    b, h, s, _ = probs.shape
+    if v_part.ndim == 4:
+        g = v_part.shape[2]
+        ctx = jnp.einsum("bgrqk,bkgd->bqgrd",
+                         probs.reshape(b, g, h // g, s, -1), v_part,
+                         preferred_element_type=jnp.float32)
+    else:       # as stored: every head over every lane, its kv head's kept
+        g = v_part.shape[2] // hd
+        every = jnp.einsum("bnk,bkc->bnc", probs.reshape(b, h * s, -1),
+                           v_part, preferred_element_type=jnp.float32)
+        own = jnp.eye(g, dtype=bool)[:, None, None, :, None]
+        ctx = jnp.sum(jnp.where(
+            own, every.reshape(b, g, h // g, s, g, hd), 0), axis=4)
+        ctx = jnp.transpose(ctx, (0, 3, 1, 2, 4))
+    return ctx.reshape(b, s, h, hd)
+
+
+def _attend(q: jax.Array, k, v, keep, cfg: TransformerConfig) -> jax.Array:
+    """Masked attention of q [B,S,H,Dh] over k/v; `keep` [S, T] marks key
+    positions each query may attend to. k, v and keep may each be a tuple
+    of parts (a cached step's window and its fresh rows,
+    `_cache_update_and_read`): one softmax runs over all their keys, and no
+    part is copied to sit beside another.
+
+    A part is [B,T,G,Dh], its heads apart, or [B,T,G*Dh], a window in its
+    stored form. G divides H (GQA): query head h reads kv head h // (H/G),
+    and no part is repeated up to the query heads. A stored window is not
+    reshaped (the TPU would copy it whole into a layout with the heads
+    apart): its products run over the folded axis against the queries laid
+    out in blocks, column (h, s) holding query (s, h) in its kv head's Dh
+    lanes and zeros in the others. The zeros add nothing to a sum, and cost
+    nothing while the columns fit one MXU pass; a span with more columns
+    pays the copy instead and takes the window with its heads apart."""
+    b, s, h, hd = q.shape
+    k, v = _parts(k), _parts(v)
+    if s * h > _MXU_COLUMNS:
+        k, v = ([x.reshape(x.shape[:2] + (-1, hd)) for x in parts]
+                for parts in (k, v))
+    scores = [jnp.where(keep_part[None, None],
+                        _scores(q, k_part) / jnp.sqrt(jnp.float32(hd)), -1e30)
+              for k_part, keep_part in zip(k, _parts(keep))]
     if len(scores) == 1:
         probs = [jax.nn.softmax(scores[0], axis=-1)]
     else:       # softmax over the parts' concatenation, not concatenated
@@ -244,9 +289,8 @@ def _attend(q: jax.Array, k, v, keep, cfg: TransformerConfig) -> jax.Array:
         probs = [jnp.exp(part - top) for part in scores]
         total = sum(jnp.sum(part, axis=-1, keepdims=True) for part in probs)
         probs = [part / total for part in probs]
-    ctx = sum(jnp.einsum("bhqk,bkhd->bqhd", p_part.astype(q.dtype), v_part,
-                         preferred_element_type=jnp.float32)
-              for p_part, v_part in zip(probs, _parts(v)))
+    ctx = sum(_context(p_part.astype(q.dtype), v_part, hd)
+              for p_part, v_part in zip(probs, v))
     return ctx.astype(q.dtype).reshape(b, s, h * hd)
 
 
@@ -272,8 +316,11 @@ def _cache_write_quantized(bcache: LayerCache, k_new: jax.Array,
     rows = {}
     for t, new in (("k", k_new), ("v", v_new)):
         rows[t], rows[f"{t}_scale"], rows[f"{t}_shift"] = _quantize_rows(new)
+        rows[t] = _fold(rows[t])
     window = {name: _read_window(buf, bcache.layer, width)
               for name, buf in bcache.stack.items()}
+    for t in ("k", "v"):    # a scale a head: both readers take the heads apart
+        window[t] = window[t].reshape(window[f"{t}_scale"].shape + (-1,))
     return bcache._replace(rows=rows), window
 
 
@@ -384,9 +431,10 @@ def _cache_update_and_read(bcache: LayerCache, k_new: jax.Array,
     """Record the new K/V rows for [pos, pos+S) of this layer and return
     (k, v, keep, cache) for `_attend`: k, v and keep are tuples of two
     parts, the cached window [0, width) as it was (one `dynamic_slice` a
-    leaf, kept only below `pos`) and the step's own rows (causal among
-    themselves). Nothing of a whole layer's shape is materialised, and the
-    window is not copied to have the rows put into it. A prefill has only
+    leaf, in its stored form `[B, width, H*Dh]`, kept only below `pos`) and
+    the step's own rows (`[B, S, H, Dh]`, causal among themselves). Nothing
+    of a whole layer's shape is materialised, and the window is not copied
+    to have the rows put into it. A prefill has only
     the second part: it attends its own rows and reads no cache.
 
     `read_len` (STATIC) truncates the window to cache positions
@@ -409,7 +457,7 @@ def _cache_update_and_read(bcache: LayerCache, k_new: jax.Array,
         # through the cache's dtype, as if read back from it
         k_new = k_new.astype(stack["k"].dtype).astype(dtype)
         v_new = v_new.astype(stack["v"].dtype).astype(dtype)
-        bcache = bcache._replace(rows={"k": k_new, "v": v_new})
+        bcache = bcache._replace(rows={"k": _fold(k_new), "v": _fold(v_new)})
     # query i sits at absolute position pos + i (a prefill has pos 0, the
     # classic decode step s == 1, a SPAN step, the speculative verify,
     # s > 1) and attends every cached row below pos and rows [0, i] of

@@ -22,7 +22,7 @@ Design notes (mirrors parallel/spmd.py's forward pipeline):
   index, so only stage 0 pays the embed and only the last stage pays the
   LM-head matmul per tick.
 - Per-stage KV caches hold every request's rows for that stage's blocks:
-  leaf [stage, max_b, R, B, T, H, Dh], sharded over 'stage'. A tick
+  leaf [stage, max_b, R, B, T, H*Dh], sharded over 'stage'. A tick
   dynamic-slices its request's cache, runs the shared cached block step
   (parallel/decode.py `_block_step` — one attention/cache semantics for
   host and SPMD decode), and writes back gated on tick validity so
@@ -167,7 +167,7 @@ class SpmdDecodePipeline:
         return jax.lax.scan(step, x, (idx, blocks, bcache))
 
     def _cache_slice(self, caches, req):
-        """caches leaf [max_b, R, B, T, H, Dh] -> request slice [max_b, B,..]."""
+        """caches leaf [max_b, R, B, T, H*Dh] -> request slice [max_b, B,..]."""
         return jax.tree_util.tree_map(
             lambda c: jax.lax.dynamic_index_in_dim(c, req, axis=1,
                                                    keepdims=False), caches)
@@ -190,7 +190,7 @@ class SpmdDecodePipeline:
         if (r_slots, batch) not in self._cache_init:
             from jax.sharding import NamedSharding
             shape = (self.n_stages, self.max_b, r_slots, batch,
-                     self.max_len, self.cfg.kv_heads, self.cfg.head_dim)
+                     self.max_len, self.cfg.kv_heads * self.cfg.head_dim)
             self._cache_init[(r_slots, batch)] = jax.jit(
                 partial(jnp.zeros, shape, self.dtype),
                 out_shardings=NamedSharding(self.mesh, P("stage")))
@@ -205,7 +205,7 @@ class SpmdDecodePipeline:
         key = ("pfx-tile", r_slots, batch)
         if key not in self._cache_init:
             shape = (self.n_stages, self.max_b, r_slots, batch,
-                     self.max_len, self.cfg.kv_heads, self.cfg.head_dim)
+                     self.max_len, self.cfg.kv_heads * self.cfg.head_dim)
             self._cache_init[key] = jax.jit(
                 partial(jnp.broadcast_to, shape=shape),
                 out_shardings=NamedSharding(self.mesh, P("stage")))

@@ -238,7 +238,7 @@ def _tp_llama_block_local(p: Dict, x: jax.Array, cfg: TransformerConfig,
     Column-sharded q/k/v keep GQA grouping local: shard i holds query
     heads [i*h/n, (i+1)*h/n) and kv heads [i*kv/n, (i+1)*kv/n), and query
     head g's kv head g//(h/kv) lands on the same shard, so the local
-    repeat-and-attend needs no collective. Requires heads, kv_heads, and
+    grouped attend needs no collective. Requires heads, kv_heads, and
     intermediate_size divisible by the tp degree (reshapes fail loudly
     otherwise). Two psums per block, like every Megatron body here.
 

@@ -234,6 +234,71 @@ def test_kimi_stage_program_compiles_for_v5e(span, last_only, rows, on_chip):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
 
 
+def test_gpt2_medium_decode_step_keeps_cache_rows_as_rows(on_chip):
+    """`gpt2-m.offline-batch`'s decode step at its real size (32 rows, 1,024
+    positions, the 512 bucket, bfloat16; shapes from the loader): the chip
+    keeps the cache as it is declared, a position a row of whole lanes, so
+    the step writes its rows as rows and reads the window as stored. The
+    three ways that was lost before (PERF.md, PR 25 and PR 32): positions
+    minor-most in tiles of 128; a tile of 128 positions rewritten to store
+    one; the window copied or converted whole on its way to the attention."""
+    import re
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry("gpt2-medium")
+    cfg = entry.config
+    rows, max_len, read_len = 32, 1024, 512
+    d, n_layers = cfg.hidden_size, cfg.num_hidden_layers
+
+    def zeros(*shape):      # a checkpoint's worth of shapes, no bytes
+        return np.broadcast_to(np.zeros((), np.float16), shape)
+
+    state = {"wte.weight": zeros(cfg.vocab_size, d),
+             "wpe.weight": zeros(cfg.max_position_embeddings, d),
+             "ln_f.weight": zeros(d), "ln_f.bias": zeros(d)}
+    for name, shape in (("ln_1", (d,)), ("ln_2", (d,)),
+                        ("attn.c_attn", (d, 3 * d)), ("attn.c_proj", (d, d)),
+                        ("mlp.c_fc", (d, 4 * d)), ("mlp.c_proj", (4 * d, d))):
+        state[f"h.0.{name}.weight"] = zeros(*shape)
+        state[f"h.0.{name}.bias"] = zeros(shape[-1])
+    one_block = jax.eval_shape(lambda: entry.family.load_params(
+        dataclasses.replace(cfg, num_hidden_layers=1),
+        ShardConfig(1, 4, is_first=True, is_last=True), state, jnp.bfloat16))
+    params = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), one_block)
+    params["blocks"] = jax.tree_util.tree_map(
+        lambda leaf: on_chip((n_layers,) + leaf.shape[1:], leaf.dtype),
+        one_block["blocks"])
+    cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: decode.init_cache(
+            cfg, n_layers, rows, max_len, jnp.bfloat16)))
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, 1), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=read_len).compile()
+    text = compiled.as_text()
+
+    width = cfg.kv_heads * cfg.head_dim
+    leaf = rf"bf16\[{n_layers},{rows},{max_len},{width}\]"
+    layouts = re.findall(leaf + r"\{([\d,]+)[:}][^=]* parameter\(\d+\)"
+                         r"[^\n]*op_name=\"cache\[", text)
+    assert len(layouts) == 2, layouts
+    for minor_to_major in layouts:      # (a) positions are axis 2
+        assert minor_to_major.split(",")[0] != "2", minor_to_major
+    # (b) the step handles the cache whole, a layer's window, or the rows
+    assert not re.search(rf"\[(?:{n_layers}|1),{rows},128,", text)
+    assert re.search(rf"bf16\[{n_layers},{rows},1,{width}\]\S* "
+                     r"dynamic-update-slice\(", text)
+    # (c) nothing of a window's size is copied or converted
+    moved = re.findall(r"= \w+\[([\d,]*)\]\S* (?:copy|convert)\(", text)
+    assert moved
+    largest = max(int(np.prod([int(n) for n in dims.split(",") if n]))
+                  for dims in moved)
+    assert largest < rows * read_len * width, largest
+    cache_bytes = 2 * n_layers * rows * max_len * width * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+
+
 @pytest.mark.parametrize("n_ubatch", [1024, 4])
 def test_spmd_vit_large_cell_compiles_for_v5e(n_ubatch, topo):
     """`vit-l.spmd-4stage` at its real widths: four stages of six ViT-L

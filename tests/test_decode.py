@@ -586,30 +586,77 @@ def test_beam_reshuffle_outlives_donated_steps(gpt2_setup):
         got[0, 5:], _beam_oracle(cfg, weights, ids[0], 2, 4))
 
 
-@pytest.mark.parametrize("cache_bits", [0, 8], ids=["fp", "int8"])
-def test_tile_write_matches_row_write(cache_bits):
-    """Where the cache's position axis is whole tiles of 128 a decode step
-    rewrites the tile that holds its row (`_write_rows`), elsewhere the row
-    alone: the same cache rows and logits either way (to the rounding of
-    two window widths), across a tile's edge, and nothing written beyond
-    the row."""
-    rng = np.random.default_rng(71)
-    ids = jnp.asarray(rng.integers(0, 100, size=(2, 120)), jnp.int32)
-    steps = rng.integers(0, 100, size=(16, 2, 1))
+@pytest.mark.parametrize(
+    "heads, kv_heads, head_dim, span, window, dtype", [
+        (4, 4, 64, 1, 0, "float32"),
+        (4, 4, 64, 4, 0, "float32"),
+        (4, 2, 64, 1, 0, "float32"),
+        (4, 2, 64, 4, 5, "float32"),
+        (8, 2, 16, 1, 6, "float32"),
+        (4, 4, 64, 40, 0, "float32"),   # more columns than one MXU pass
+        (8, 4, 64, 20, 3, "float32"),   # the same, grouped kv heads
+        (2, 2, 128, 1, 0, "bfloat16"),
+        (2, 1, 128, 4, 0, "bfloat16"),
+        (4, 4, 64, 1, 6, "bfloat16"),
+        (4, 4, 64, 4, 0, "bfloat16"),
+    ])
+def test_stored_form_attention_matches_plain_einsum(heads, kv_heads,
+                                                    head_dim, span, window,
+                                                    dtype):
+    """A step over a cache in its stored form (`[L, B, T, H*Dh]`, the heads
+    folded, the window read as stored and never reshaped) attends what the
+    plain `[B, T, H, Dh]` einsum written here attends: rows at [pos, pos +
+    span) over the positions below `pos` and, causally, themselves, grouped
+    kv heads repeated up to the query heads, a sliding window where one is
+    set; and the step's rows land at `pos` in the stored form, nothing
+    written beside them."""
+    dtype = jnp.dtype(dtype)
+    rng = np.random.default_rng(83)
+    batch, max_len, read_len, pos, layer = 2, 64, 32, 21, 1
+    cfg = TransformerConfig(model_type="llama", hidden_size=heads * head_dim,
+                            num_hidden_layers=2, num_attention_heads=heads,
+                            num_kv_heads=kv_heads,
+                            intermediate_size=8)
 
-    def run(max_len):
-        pipe = _long_pipe(max_len, seed=5, cache_bits=cache_bits)
-        _, [cache] = pipe._prefill(ids)
-        for i, tok in enumerate(steps):
-            out, cache = pipe._decode_step(
-                pipe.stages[0], jnp.asarray(tok, jnp.int32), cache, 120 + i)
-        return np.asarray(out), {k: np.asarray(v) for k, v in cache.items()}
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape), dtype)
 
-    (out_tile, tiled), (out_row, rowed) = run(256), run(200)
-    np.testing.assert_allclose(out_tile, out_row, atol=1e-5)
-    for name, leaf in tiled.items():
-        np.testing.assert_allclose(
-            leaf[:, :, :200].astype(np.float32),
-            rowed[name].astype(np.float32), rtol=1e-4,
-            atol=1 if leaf.dtype == np.int8 else 1e-5)
-        assert leaf[:, :, :136].any() and not leaf[:, :, 136:].any(), name
+    q = draw(batch, span, heads, head_dim)
+    k_new, v_new = (draw(batch, span, kv_heads, head_dim) for _ in "kv")
+    held = {t: draw(2, batch, max_len, kv_heads, head_dim) for t in "kv"}
+    cache = decode.init_cache(cfg, 2, batch, max_len, dtype)
+    assert cache["k"].shape == (2, batch, max_len, kv_heads * head_dim)
+    cache = {t: held[t].reshape(cache[t].shape) for t in "kv"}
+
+    k, v, keep, bcache = decode._cache_update_and_read(
+        decode.LayerCache(cache, layer), k_new, v_new, pos, False, span,
+        dtype, read_len=read_len, window=window)
+    assert k[0].shape == (batch, read_len, kv_heads * head_dim)
+    got = np.asarray(decode._attend(q, k, v, keep, cfg), np.float32)
+
+    def f32(x):
+        return np.asarray(x, np.float32)
+
+    # the reference: every key in one [B, T, H, Dh] array, heads repeated
+    keys, values = (np.repeat(np.concatenate(
+        [f32(held[t][layer, :, :pos]), f32(new)], axis=1),
+        heads // kv_heads, axis=2) for t, new in (("k", k_new), ("v", v_new)))
+    scores = np.einsum("bqhd,bkhd->bhqk", f32(q), keys) / np.sqrt(head_dim)
+    q_at = pos + np.arange(span)[:, None]
+    k_at = np.arange(pos + span)[None]
+    seen = k_at <= q_at
+    if window:
+        seen &= k_at > q_at - window
+    scores = np.where(seen[None, None], scores, -1e30)
+    probs = np.exp(scores - scores.max(-1, keepdims=True))
+    probs = f32(jnp.asarray(probs / probs.sum(-1, keepdims=True), dtype))
+    want = np.einsum("bhqk,bkhd->bqhd", probs, values).reshape(got.shape)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+    rows = {t: jnp.stack([bcache.rows[t]] * 2) for t in "kv"}
+    written = decode._write_rows(cache, rows, pos)
+    for t, new in (("k", k_new), ("v", v_new)):
+        want = np.array(f32(cache[t]))
+        want[:, :, pos:pos + span] = f32(new).reshape(batch, span, -1)
+        np.testing.assert_array_equal(f32(written[t]), want)

@@ -105,8 +105,8 @@ def test_pool_gather_scatter_roundtrip(pipe):
     view = pool.gather(0, table)
     n_blocks = pipe.stages[0]["n_blocks"]
     cfg = pipe.cfg
-    assert view["k"].shape == (n_blocks, 1, 8, cfg.kv_heads,
-                               cfg.head_dim)
+    assert view["k"].shape == (n_blocks, 1, 8,
+                               cfg.kv_heads * cfg.head_dim)
     # write a recognizable pattern, scatter back, re-gather
     marked = {k: jnp.full_like(v, 7.0) for k, v in view.items()}
     pool.scatter(0, table, marked, [(0, 0), (0, 1)])

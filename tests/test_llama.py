@@ -85,7 +85,7 @@ def test_split_pipeline_matches_whole(llama_setup, partition):
 @pytest.mark.slow
 def test_greedy_decode_matches_hf_generate(llama_setup):
     """Pipelined KV-cache greedy decode == HF generate(do_sample=False):
-    the GQA cache ([*, kv_heads, Dh]) and per-step RoPE rotation are
+    the GQA cache ([*, kv_heads * Dh]) and per-step RoPE rotation are
     exercised across a 2-stage partition."""
     cfg, weights, model = llama_setup
     partition = [(1, 4), (5, 8)]
@@ -93,7 +93,7 @@ def test_greedy_decode_matches_hf_generate(llama_setup):
         llama_mod.FAMILY, cfg, partition,
         _stage_params(cfg, partition, weights), max_len=32)
     cache = decode.init_cache(cfg, 1, 2, 8)
-    assert cache["k"].shape[3] == cfg.kv_heads    # GQA-sized cache
+    assert cache["k"].shape[3] == cfg.kv_heads * cfg.head_dim   # GQA-sized
     ids = np.random.default_rng(7).integers(0, cfg.vocab_size, size=(2, 6))
     got = np.asarray(pipe.generate(ids, new_tokens=8))
     with torch.no_grad():
