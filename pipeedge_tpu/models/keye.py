@@ -287,8 +287,8 @@ def _experts(p: Dict, normed, cfg: TransformerConfig):
     if isinstance(experts, tuple):
         experts, layer = experts
     return topk_ffn_delta(
-        dict({name: p[name] for name in ("router", "shared") if name in p},
-             experts=experts), normed, cfg, layer=layer)
+        dict({name: p[name] for name in ("router", "shared", "shared_gate")
+              if name in p}, experts=experts), normed, cfg, layer=layer)
 
 
 def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:

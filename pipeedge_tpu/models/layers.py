@@ -21,7 +21,8 @@ class TransformerConfig:
     reference fetches over the network — model_cfg.py:57-66; here configs are
     local constants so the framework runs with zero egress)."""
     model_type: str              # the family: 'vit' | 'bert' | 'deit' |
-    #                              'gpt2' | 'llama' | 'keye' | 'kimi'
+    #                              'gpt2' | 'llama' | 'keye' | 'kimi' |
+    #                              'qwen3_next'
     hidden_size: int
     num_hidden_layers: int       # transformer blocks (sublayers = 4x this)
     num_attention_heads: int
@@ -96,6 +97,22 @@ class TransformerConfig:
     # positions a prompt is prefilled at a time where the family prefills
     # in spans and no other field says (keye's is `index_q_chunk`)
     prefill_chunk: int = 0
+    # linear attention beside full attention (qwen3_next family): block i
+    # is full attention where (i + 1) % `full_attention_interval` == 0 and
+    # a Gated DeltaNet layer elsewhere: `linear_key_heads` key heads of
+    # `linear_key_dim` and `linear_value_heads` value heads of
+    # `linear_value_dim` (a state of key_dim x value_dim a value head), a
+    # depthwise causal convolution `linear_conv_kernel` wide, a span run in
+    # chunks of `linear_chunk` positions
+    full_attention_interval: int = 0
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_kernel: int = 0
+    linear_chunk: int = 0
+    # the share of a head's width that the rotation turns (its first lanes)
+    partial_rotary_factor: float = 1.0
 
     @property
     def head_dim(self) -> int:

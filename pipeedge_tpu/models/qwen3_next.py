@@ -1,0 +1,594 @@
+"""Qwen3-Next (`model_type` qwen3_next): blocks in periods of four, three
+Gated DeltaNet layers (linear attention over a state that is a matrix a
+head) and one gated full-attention layer, each before an expert layer of
+many small experts beside a shared one that a learned sigmoid gates.
+
+The block, `x` [B, S, D], no bias anywhere. `rms` is the family's
+ZERO-CENTRED norm, `x * rsqrt(mean(x^2) + eps) * (1 + w)` in float32:
+  h = x + Mixer(rms(x));  x' = h + MoE(rms(h))
+Block i is full attention where `(i + 1) % full_attention_interval == 0`
+(`block_kind`); the two kinds have different leaves, so a stage holds them
+as runs (models/shard.py `BlockRuns`).
+
+**Gated DeltaNet.** `m = [q | k | v]` (16 + 16 key heads and 32 value heads
+of 128: 8,192 channels) goes through a depthwise causal convolution four
+wide and SiLU; `q`, `k` are l2-normalised a head, repeated to the value
+heads, `q` scaled by `Dk**-0.5`. A value head: `beta_t = sigmoid(b_t)`, `g_t
+= -exp(A_log) * softplus(a_t + dt_bias)`, and the state `S` [Dk, Dv]:
+  S <- exp(g_t) S;  d = beta_t (v_t - S^T k_t);  S <- S + k_t d^T;
+  o_t = S^T q_t
+(`delta_step`, what a decode step runs). The output goes through a norm a
+head gated by `silu(z)` and `out_proj`. A span of a prompt runs the same in
+chunks of `C` = `cfg.linear_chunk` (`delta_chunked`): with `gamma` the
+running sum of `g` in a chunk and `Gamma_ts = exp(gamma_t - gamma_s)`,
+  A = (I + strict_lower((beta K) K^T * Gamma))^-1
+  D = A (beta V) - A (beta exp(gamma) K) S_0          (the chunk's d)
+  O = (Q exp(gamma)) S_0 + lower(Q K^T * Gamma) D
+  S_C = exp(gamma_C) S_0 + (K exp(gamma_C - gamma))^T D
+everything that does not need `S_0` for all chunks at once, then one scan
+over the chunks that carries the state. A last chunk that the span does not
+fill is padded with `beta` = 0 and `g` = 0, which leaves the state as it
+was.
+
+**Gated full attention.** A head of `q_proj` is `[query | gate]`; q and k
+are normed a head (zero-centred), the first `partial_rotary_factor` of a
+head's width is rotated (halves layout), GQA, causal softmax in float32; the
+heads' outputs are multiplied by `sigmoid(gate)` before `o_proj`.
+
+**Cache: two geometries** (`cache_leaves`, models/shard.py `CacheLeaf`).
+The full layers own `k`, `v` `[L_full, B, T, G*Dh]`, a row a position,
+written at `pos` and read as a window. The linear layers own `gdn_state`
+`[L_linear, B, Hv, Dk, Dv]` and `gdn_conv` `[L_linear, B, 3, 8192]` (the
+convolution's last three inputs), a row a REQUEST, read and replaced whole
+by every call: a span takes its initial state from there and leaves its
+final state there. No layer holds the other kind's leaves: at 8 rows x
+32,768 positions in float32 that is 1.07 GB of keys and values in the one
+full layer of four and 6.6 MB a row of state in the three others, where
+four layers of keys and values would be 4.3 GB.
+
+**Precision.** Weights as stored (bfloat16); activations, cache and state
+float32: products with weights through `exact_dot`, the delta rule's
+products of two activations at `HIGHEST`, the delta rule's (the state is a
+sum over every position before) and the attention's (`_ATTENTION` says what
+`HIGH` did). The router's top-10 of 512 is a discrete choice that a bfloat16
+computation makes differently from the float32 reference.
+
+**Prefill** runs in spans of `cfg.prefill_chunk` positions (a multiple of
+the chunk) through the decode-shaped stage program, as keye's does.
+
+Refused by name: the forward path (`sublayer`), tp, sp and ep meshes, the
+int8 cache, `--kv-pages` (a page holds positions) and speculative verify (a
+rejected draft would need the state of an earlier position).
+
+Weight format: the published state dict (`model.layers.N.linear_attn.
+{in_proj_qkvz, in_proj_ba, conv1d, out_proj}.weight`, `.linear_attn.{dt_bias,
+A_log, norm.weight}`; `.self_attn.{q,k,v,o}_proj.weight`, `.self_attn.{q,k}_
+norm.weight`; `.mlp.gate.weight`, `.mlp.experts.E.*`, `.mlp.shared_expert.*`,
+`.mlp.shared_expert_gate.weight`). `in_proj_qkvz` and `in_proj_ba` keep
+their rows grouped by key head and `q_proj` by head; the loader sorts the
+rows once into `[q | k | v]`, `z`, `[b | a]` and `query`, `gate`, so the
+program splits nothing.
+"""
+from __future__ import annotations
+
+from typing import Dict, Mapping
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from . import ShardConfig
+from .keye import _by_head, _experts
+from .kimi import _in_row_chunks, _lin, _on_device, _stack
+from .layers import TransformerConfig, rope_rotate
+from .shard import CacheLeaf, FamilySpec, build_shard_params
+
+# what a block step counts into the cache's `stats` leaf, in this order
+STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
+         "moe_layer_calls", "gdn_positions_chunked", "gdn_positions_stepped",
+         "gdn_state_carries")
+
+# activations, cache and state (module docstring, Precision)
+ACTIVATIONS = jnp.float32
+# products of two activations: float32 in full, the delta rule's (the state
+# is a sum over every position before it) and the attention's. At `HIGH`
+# (three bfloat16 passes, about 16 bits) the first chip run's greedy tokens
+# lay up to 0.86% of the logits' range from the reference's (PERF.md, PR 33):
+# q and k are normed, so scores are of order 1 and 1e-4 off, and the router's
+# top-10 of 512 after them is a discrete choice that amplifies it (keye's
+# attention found the same, PR 27)
+_STATE = jax.lax.Precision.HIGHEST
+_ATTENTION = jax.lax.Precision.HIGHEST
+
+# bytes of float32 attention scores one chunk of queries may hold (one KV
+# group's at a time)
+_SCORE_BYTES = 1 << 29
+
+
+def prefill_span(cfg: TransformerConfig) -> int:
+    return cfg.prefill_chunk
+
+
+def block_kind(cfg: TransformerConfig, block_id: int) -> str:
+    return "full" if (block_id + 1) % cfg.full_attention_interval == 0 \
+        else "linear"
+
+
+def conv_channels(cfg: TransformerConfig) -> int:
+    """Channels of `m = [q | k | v]`, what the convolution runs over."""
+    return 2 * cfg.linear_key_heads * cfg.linear_key_dim \
+        + cfg.linear_value_heads * cfg.linear_value_dim
+
+
+def cache_leaves(cfg: TransformerConfig) -> Dict:
+    """The cache's leaves (module docstring, Cache): what follows `[L, B,
+    T]` in the full layers' `k`, `v` and `[L, B]` in the linear layers'
+    state, with the kind of block that owns each."""
+    rows = CacheLeaf((cfg.kv_heads * cfg.head_dim,), ACTIVATIONS, "full")
+    return {"k": rows, "v": rows,
+            "gdn_state": CacheLeaf(
+                (cfg.linear_value_heads, cfg.linear_key_dim,
+                 cfg.linear_value_dim), ACTIVATIONS, "linear", whole=True),
+            "gdn_conv": CacheLeaf(
+                (cfg.linear_conv_kernel - 1, conv_channels(cfg)),
+                ACTIVATIONS, "linear", whole=True),
+            "stats": jax.ShapeDtypeStruct((len(STATS),), jnp.int32)}
+
+
+def rms(w: jax.Array, x: jax.Array, eps: float) -> jax.Array:
+    """The zero-centred norm: the stored weight is what is added to 1."""
+    xf = x.astype(jnp.float32)
+    normed = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                                + eps)
+    return (normed * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+
+
+def partial_rotate(x: jax.Array, pos: jax.Array,
+                   cfg: TransformerConfig) -> jax.Array:
+    """x [B, S, H, Dh] at positions `pos` [S]: the first
+    `partial_rotary_factor` of the head's width turned (`rope_rotate`: halves
+    layout, frequencies for that width), the rest as it is."""
+    turned = int(x.shape[-1] * cfg.partial_rotary_factor)
+    head, rest = jnp.split(x, [turned], axis=-1)
+    return jnp.concatenate([rope_rotate(head, pos, cfg.rope_theta), rest],
+                           axis=-1)
+
+
+# -- the gated delta rule ----------------------------------------------------
+
+def _exp(x: jax.Array) -> jax.Array:
+    """exp(x) for float32 x <= 0 to an ulp: x = n ln 2 + r (ln 2 in two
+    parts), a polynomial in r (Cephes `expf`'s), 2**n from its bits.
+
+    A decay is applied a position after another (a chunk after another), so
+    its error compounds over a head's memory, thousands of positions for the
+    slowest. The chip's own float32 `exp` is a few 1e-7 off WITH A BIAS: the
+    one-token form lay 1.7e-4 from a float64 recurrence after 512 positions
+    where the chunked form, which takes the exp of sums, lay 3e-6, and the
+    plain reference's scan over 32 k positions 1e-3 of the logits' range
+    from the program (my chip runs, PR 33). `1 + expm1(x)` repaired the
+    slowest heads only (the chip's `expm1` is `exp - 1` but for tiny x)."""
+    n = jnp.round(x * 1.44269504088896341)
+    r = (x - n * 0.693359375) - n * -2.12194440e-4
+    poly = 1.9875691500e-4
+    for c in (1.3981999507e-3, 8.3334519073e-3, 4.1665795894e-2,
+              1.6666665459e-1, 5.0000001201e-1):
+        poly = poly * r + c
+    two_n = jax.lax.bitcast_convert_type(
+        (jnp.maximum(n, -126.0).astype(jnp.int32) + 127) << 23, jnp.float32)
+    return jnp.where(x < -87.0, 0.0, (poly * r * r + r + 1.0) * two_n)
+
+
+def _decay(x: jax.Array) -> jax.Array:
+    """exp(x) for x <= 0 where it is applied once and `_exp`'s thirty
+    operations a value would show: the chunked form's matrices."""
+    return 1.0 + jnp.expm1(x)
+
+
+def delta_step(q, k, v, beta, g, state):
+    """One position of the recurrence (module docstring): q, k [B, H, Dk],
+    v [B, H, Dv], beta, g [B, H], state [B, H, Dk, Dv], float32. Products
+    on the vector unit, exact: a step reads the state and writes it, and
+    has nothing for the MXU. -> (o [B, H, Dv], state)."""
+    state = state * _exp(g)[..., None, None]
+    d = beta[..., None] * (v - jnp.sum(state * k[..., None], axis=-2))
+    state = state + k[..., None] * d[..., None, :]
+    return jnp.sum(state * q[..., None], axis=-2), state
+
+
+def _inverse_unit_lower(low: jax.Array) -> jax.Array:
+    """(I + low)^-1 of strictly lower triangular `low` [..., C, C], by
+    forward substitution a row at a time: row i of the inverse's strict
+    part is `-low_i - low_i X` over the rows above, which are final. No
+    series in powers of `low`: keys of a trained model lie close together
+    in a chunk, and the powers' terms then cancel from 1e18 down."""
+    c = low.shape[-1]
+    at = jnp.arange(c)
+
+    def row(i, x):
+        mine = jax.lax.dynamic_slice_in_dim(x, i, 1, axis=-2)
+        mine = mine + jnp.einsum("...ij,...jk->...ik", mine, x,
+                                 precision=_STATE)
+        return jax.lax.dynamic_update_slice_in_dim(x, mine, i, axis=-2)
+
+    return jax.lax.fori_loop(1, c, row, -low) + (at[:, None] == at[None, :])
+
+
+def delta_chunked(q, k, v, beta, g, state, chunk: int):
+    """The recurrence over a span in chunks (module docstring): q, k [B, S,
+    H, Dk], v [B, S, H, Dv], beta, g [B, S, H], state [B, H, Dk, Dv], all
+    float32. -> (o [B, S, H, Dv], the state after the span)."""
+    b, s, h, _ = q.shape
+    n = -(-s // chunk)
+
+    def lay(x):     # [B, S, H, ...] -> [N, B, H, C, ...], zeros past S
+        x = jnp.pad(x, ((0, 0), (0, n * chunk - s)) + ((0, 0),)
+                    * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape((b, n, chunk) + x.shape[2:]),
+                            (1, 3), (0, 2))
+
+    def dots(spec, x, y):
+        return jnp.einsum(spec, x, y, precision=_STATE,
+                          preferred_element_type=jnp.float32)
+
+    q, k, v, beta, g = (lay(x) for x in (q, k, v, beta, g))
+    at = jnp.arange(chunk)
+    lower = at[:, None] >= at[None, :]
+    # the running sum as a product in full float32: the chip runs a
+    # `cumsum` as one bfloat16 pass, which left gamma 1e-3 off and with it
+    # the state a prefill hands to the steps (PERF.md, PR 33)
+    gamma = dots("nbhs,cs->nbhc", g, lower.astype(g.dtype))  # [N, B, H, C]
+    decay = jnp.where(lower, _decay(jnp.where(
+        lower, gamma[..., :, None] - gamma[..., None, :], 0.0)), 0.0)
+    k_beta = k * beta[..., None]
+    inverse = _inverse_unit_lower(
+        jnp.where(at[:, None] > at[None, :],
+                  dots("nbhck,nbhsk->nbhcs", k_beta, k) * decay, 0.0))
+    u = dots("nbhcs,nbhsv->nbhcv", inverse, v * beta[..., None])
+    w = dots("nbhcs,nbhsk->nbhck", inverse,
+             k_beta * _decay(gamma)[..., None])
+    within = dots("nbhck,nbhsk->nbhcs", q, k) * decay
+    q_in = q * _decay(gamma)[..., None]
+    k_out = k * _decay(gamma[..., -1:] - gamma)[..., None]
+    kept = _exp(gamma[..., -1])                             # [N, B, H]
+
+    def one_chunk(carry, xs):
+        u_n, w_n, within_n, q_n, k_n, kept_n = xs
+        d = u_n - dots("bhck,bhkv->bhcv", w_n, carry)
+        o = dots("bhck,bhkv->bhcv", q_n, carry) \
+            + dots("bhcs,bhsv->bhcv", within_n, d)
+        return kept_n[..., None, None] * carry \
+            + dots("bhck,bhcv->bhkv", k_n, d), o
+
+    state, o = jax.lax.scan(one_chunk, state,
+                            (u, w, within, q_in, k_out, kept))
+    o = jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, n * chunk, h, -1)
+    return o[:, :s], state
+
+
+def gated_delta_net(p: Dict, normed, state, tail, cfg: TransformerConfig):
+    """The linear mixer of `normed` [B, S, D] from `state` [B, Hv, Dk, Dv]
+    and the convolution's `tail` [B, K - 1, channels] (its inputs at the
+    positions before). -> (out [B, S, D], state, tail) after the span."""
+    b, s, _ = normed.shape
+    hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
+    dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+    eps = cfg.layer_norm_eps
+    m = _in_row_chunks(lambda rows: _lin(p["in_m"], rows), normed,
+                       p["in_m"].shape[0])
+    z = _lin(p["in_z"], normed).reshape(b, s, hv, dv)
+    ba = _lin(p["in_ba"], normed).astype(jnp.float32)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(p["a_log"].astype(jnp.float32)) * jax.nn.softplus(
+        ba[..., hv:] + p["dt_bias"].astype(jnp.float32))
+    # the convolution: position t sees m at t - K + 1 .. t
+    padded = jnp.concatenate([tail.astype(m.dtype), m], axis=1)
+    kernel = p["conv"].astype(jnp.float32)                  # [K, channels]
+    mixed = sum(kernel[j] * padded[:, j:j + s]
+                for j in range(kernel.shape[0]))
+    tail = padded[:, s:]
+    q, k, v = jnp.split(jax.nn.silu(mixed), [hk * dk, 2 * hk * dk], axis=-1)
+
+    def heads(x, scale):    # l2 norm a key head, then a copy a value head
+        x = x.reshape(b, s, hk, dk)
+        x = x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+        return jnp.repeat(x * scale, hv // hk, axis=2)
+
+    q, k, v = heads(q, dk ** -0.5), heads(k, 1.0), v.reshape(b, s, hv, dv)
+    if s == 1:
+        o, state = delta_step(q[:, 0], k[:, 0], v[:, 0], beta[:, 0],
+                              g[:, 0], state)
+        o = o[:, None]
+    else:
+        o, state = delta_chunked(q, k, v, beta, g, state, cfg.linear_chunk)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) \
+        * p["out_norm"].astype(jnp.float32) * jax.nn.silu(z)
+    return _lin(p["out"], o.reshape(b, s, -1).astype(normed.dtype)), \
+        state, tail
+
+
+# -- the gated full attention ------------------------------------------------
+
+def _attend_chunk(q, parts, first, pos):
+    """Context [B, Q, H*Dh] of the queries q [B, Q, H, Dh] that sit `first`
+    rows into the span at `pos`. `parts`: (k, v: one [B, K, Dh] a KV head;
+    own: the part is the span's rows, causal, else cached rows, live below
+    `pos`). One softmax over all parts, a KV group at a time."""
+    b, n_q, h, hd = q.shape
+    groups = len(parts[0][0])
+    q = q.reshape(b, n_q, groups, h // groups, hd)
+    keeps = []
+    for k, _, own in parts:
+        at = jnp.arange(k[0].shape[1])
+        keeps.append(at[None, :] <= first + jnp.arange(n_q)[:, None] if own
+                     else jnp.broadcast_to(at < pos, (n_q, at.shape[0])))
+    out = []
+    for grp in range(groups):
+        scores = [jnp.where(keep[None, None], jnp.einsum(
+            "bqrd,bkd->brqk", q[:, :, grp], k[grp].astype(q.dtype),
+            preferred_element_type=jnp.float32, precision=_ATTENTION)
+            * hd ** -0.5, -1e30) for (k, _, _), keep in zip(parts, keeps)]
+        top = jnp.max(jnp.concatenate(
+            [jnp.max(sc, axis=-1, keepdims=True) for sc in scores], -1),
+            axis=-1, keepdims=True)
+        # the weights are divided by their sum after they have met the
+        # values: one pass over [.., Q, Dh] and not one over [.., Q, K]
+        probs = [jnp.exp(sc - top) for sc in scores]
+        total = sum(jnp.sum(pr, axis=-1) for pr in probs)       # [B, r, Q]
+        mixed = sum(jnp.einsum(
+            "brqk,bkd->bqrd", pr.astype(q.dtype), v[grp].astype(q.dtype),
+            preferred_element_type=jnp.float32, precision=_ATTENTION)
+            for pr, (_, v, _) in zip(probs, parts))
+        out.append(mixed / jnp.moveaxis(total, 1, 2)[..., None])
+    return jnp.stack(out, axis=2).astype(q.dtype).reshape(b, n_q, h * hd)
+
+
+def attend(q, parts, pos) -> jax.Array:
+    """`_attend_chunk` over all queries [B, Q, H, Dh], in chunks of queries
+    whose scores (one KV group's) stay under `_SCORE_BYTES`."""
+    b, n_q, h, _ = q.shape
+    n_keys = sum(part[0][0].shape[1] for part in parts)
+    chunk = n_q
+    while chunk > 1 and chunk % 2 == 0 and \
+            b * (h // len(parts[0][0])) * chunk * n_keys * 4 > _SCORE_BYTES:
+        chunk //= 2
+    if chunk == n_q:
+        return _attend_chunk(q, parts, 0, pos)
+    n = n_q // chunk
+    ctx = jax.lax.map(
+        lambda xs: _attend_chunk(xs[0], parts, xs[1], pos),
+        (jnp.moveaxis(q.reshape((b, n, chunk) + q.shape[2:]), 1, 0),
+         jnp.arange(n) * chunk))
+    return jnp.moveaxis(ctx, 0, 1).reshape(b, n_q, -1)
+
+
+def gated_attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
+                    prefill: bool, read_len=None):
+    """The full mixer of `normed` [B, S, D] at [pos, pos + S) over the
+    cached window below `pos` and its own rows. -> (out, the rows k, v
+    [B, S, G*Dh] for the cache)."""
+    from ..parallel.decode import _attend_width, _read_window
+
+    b, s, _ = normed.shape
+    eps, groups = cfg.layer_norm_eps, cfg.kv_heads
+    q_pos = jnp.asarray(pos) + jnp.arange(s)
+    q = _in_row_chunks(lambda rows: _lin(p["q"]["w"], rows), normed,
+                       p["q"]["w"].shape[0])
+    q = q.reshape(b, s, cfg.num_attention_heads, -1)
+    gate = _lin(p["gate"]["w"], normed)
+    k = _lin(p["k"]["w"], normed).reshape(b, s, groups, -1)
+    v = _lin(p["v"]["w"], normed)
+    q = partial_rotate(rms(p["q_norm"], q, eps), q_pos, cfg)
+    k = partial_rotate(rms(p["k_norm"], k, eps), q_pos, cfg).reshape(b, s, -1)
+    stack = bcache.stack
+    # through the cache's dtype, as if read back from it
+    k = k.astype(stack["k"].dtype).astype(normed.dtype)
+    v = v.astype(stack["v"].dtype).astype(normed.dtype)
+    parts = [(_by_head(k, groups), _by_head(v, groups), True)]
+    if not prefill:
+        width = _attend_width(bcache, read_len)
+        lanes = [slice(grp * cfg.head_dim, (grp + 1) * cfg.head_dim)
+                 for grp in range(groups)]
+        parts.insert(0, tuple(
+            tuple(_read_window(stack[name], bcache.layer, width, head)
+                  for head in lanes) for name in ("k", "v")) + (False,))
+    ctx = attend(q, parts, pos)
+    return _lin(p["attn_out"]["w"], ctx * jax.nn.sigmoid(gate)), k, v
+
+
+# -- the family's hooks --------------------------------------------------------
+
+def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
+    """Token embedding [B, K] -> [B, K, D]: positions live in the rotation
+    and in the state."""
+    return jnp.take(pe["wte"], tok, axis=0).astype(ACTIVATIONS)
+
+
+def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    return span_embed(p, input_ids, 0)
+
+
+def decode_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
+    return span_embed(pe, tok.reshape(-1, 1), pos)
+
+
+def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
+             attention_fn=None):
+    raise NotImplementedError(
+        "the qwen3_next family runs through the cached decode path only: "
+        "its blocks come in runs of two kinds, which the forward path "
+        "(models/shard.py shard_apply) does not scan yet")
+
+
+def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """Final (zero-centred) norm + LM head -> [B, S, vocab] logits."""
+    return _lin(p["head"]["w"], rms(p["ln"], hidden, cfg.layer_norm_eps))
+
+
+def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
+                      prefill: bool, read_len=None):
+    """Cached block (parallel/decode.py's `_block_step` contract) of either
+    kind. The rows of `x` sit at [pos, pos + S). A full block attends the
+    cached window below `pos` and its own rows and records their keys and
+    values for `_write_rows`; a linear block takes its state and its
+    convolution's tail from the cache (a prefill, at `pos` 0: zeros) and
+    records what they are after the span, which takes their place."""
+    b, s, _ = x.shape
+    eps = cfg.layer_norm_eps
+    normed = rms(p["ln_before"], x, eps)
+    counts = jnp.zeros(3, jnp.int32)
+    if "in_m" in p:
+        stack = bcache.stack
+        state, tail = (jax.lax.dynamic_index_in_dim(
+            stack[name], bcache.layer, 0, keepdims=False)
+            for name in ("gdn_state", "gdn_conv"))
+        if prefill:
+            state, tail = jnp.zeros_like(state), jnp.zeros_like(tail)
+        mixed, state, tail = gated_delta_net(
+            p, normed, state.astype(jnp.float32), tail, cfg)
+        rows = {"gdn_state": state, "gdn_conv": tail}
+        counts = jnp.array([b * s if s > 1 else 0, b if s == 1 else 0,
+                            0 if prefill else 1], jnp.int32)
+    else:
+        mixed, k, v = gated_attention(p, normed, bcache, pos, cfg, prefill,
+                                      read_len)
+        rows = {"k": k, "v": v}
+    h = x + mixed
+    delta, moe = _experts(p, rms(p["ln_after"], h, eps), cfg)
+    rows["stats"] = jnp.concatenate(
+        [moe.astype(jnp.int32), jnp.ones(1, jnp.int32), counts])
+    return h + delta, bcache._replace(rows=rows)
+
+
+FAMILY = FamilySpec(name="qwen3_next", embed=embed, sublayer=sublayer,
+                    finalize=finalize, cached_block_step=cached_block_step,
+                    decode_embed=decode_embed, span_embed=span_embed,
+                    position_dependent_attention=True,
+                    cache_leaves=cache_leaves, prefill_span=prefill_span,
+                    whole_leaves=("experts",), stats_names=STATS,
+                    block_kind=block_kind)
+
+
+# -- loading -------------------------------------------------------------------
+
+def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
+              dtype) -> Dict:
+    """Shard params from `get(key, shape)`, a tensor of the published
+    scheme (models/kimi.py `_assemble`: host leaves until a run is stacked;
+    traced values pass through, for `jax.eval_shape`)."""
+    d, heads, groups, hd = cfg.hidden_size, cfg.num_attention_heads, \
+        cfg.kv_heads, cfg.head_dim
+    hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
+    dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+    per = hv // hk          # value heads a key head
+    f = cfg.moe_intermediate_size
+    first, count = cfg.held_experts or (0, cfg.n_experts)
+
+    def mlp(root, width):
+        return {"gate": get(root + "gate_proj.weight", (width, d)),
+                "up": get(root + "up_proj.weight", (width, d)),
+                "down": get(root + "down_proj.weight", (d, width))}
+
+    def get_embed() -> Dict:
+        return {"wte": get("model.embed_tokens.weight",
+                           (cfg.vocab_size, d))}
+
+    def linear_mixer(root: str) -> Dict:
+        # rows grouped by key head: [q Dk | k Dk | v per*Dv | z per*Dv]
+        qkvz = get(root + "in_proj_qkvz.weight",
+                   (2 * hk * dk + 2 * hv * dv, d)).reshape(hk, -1, d)
+        q, k, v, z = (part.reshape(-1, d) for part in (
+            qkvz[:, :dk], qkvz[:, dk:2 * dk],
+            qkvz[:, 2 * dk:2 * dk + per * dv], qkvz[:, 2 * dk + per * dv:]))
+        # and [b per | a per]
+        ba = get(root + "in_proj_ba.weight", (2 * hv, d)).reshape(hk, -1, d)
+        cat = np.concatenate if isinstance(q, np.ndarray) else jnp.concatenate
+        return {"in_m": cat([q, k, v]), "in_z": z,
+                "in_ba": cat([ba[:, :per].reshape(-1, d),
+                              ba[:, per:].reshape(-1, d)]),
+                "conv": get(root + "conv1d.weight", (
+                    2 * hk * dk + hv * dv, 1, cfg.linear_conv_kernel)
+                    )[:, 0].T,
+                "a_log": get(root + "A_log", (hv,)),
+                "dt_bias": get(root + "dt_bias", (hv,)),
+                "out_norm": get(root + "norm.weight", (dv,)),
+                "out": get(root + "out_proj.weight", (d, hv * dv))}
+
+    def full_mixer(root: str) -> Dict:
+        # a head of q_proj: [query Dh | gate Dh]
+        q = get(root + "q_proj.weight", (2 * heads * hd, d)).reshape(
+            heads, 2, hd, d)
+        return {"q": {"w": q[:, 0].reshape(-1, d)},
+                "gate": {"w": q[:, 1].reshape(-1, d)},
+                "k": {"w": get(root + "k_proj.weight", (groups * hd, d))},
+                "v": {"w": get(root + "v_proj.weight", (groups * hd, d))},
+                "q_norm": get(root + "q_norm.weight", (hd,)),
+                "k_norm": get(root + "k_norm.weight", (hd,)),
+                "attn_out": {"w": get(root + "o_proj.weight",
+                                      (d, heads * hd))}}
+
+    def get_block(block_id: int, subs: tuple) -> Dict:
+        if subs != (0, 1, 2, 3):
+            raise NotImplementedError(
+                "the qwen3_next family takes whole blocks: a partition that "
+                "cuts one is for the forward path, which it does not run")
+        root = f"model.layers.{block_id}."
+        p = full_mixer(root + "self_attn.") \
+            if block_kind(cfg, block_id) == "full" \
+            else linear_mixer(root + "linear_attn.")
+        p["ln_before"] = get(root + "input_layernorm.weight", (d,))
+        p["ln_after"] = get(root + "post_attention_layernorm.weight", (d,))
+        p["router"] = {"w": get(root + "mlp.gate.weight",
+                                (cfg.n_experts, d)).T}
+        held = [mlp(f"{root}mlp.experts.{e}.", f)
+                for e in range(first, first + count)]
+        p["experts"] = {name: _stack([one[name] for one in held])
+                        for name in ("gate", "up", "down")}
+        p["shared"] = mlp(root + "mlp.shared_expert.",
+                          f * cfg.n_shared_experts)
+        p["shared_gate"] = get(root + "mlp.shared_expert_gate.weight",
+                               (1, d))
+        return p
+
+    def get_final() -> Dict:
+        return {"ln": get("model.norm.weight", (d,)),
+                "head": {"w": get("lm_head.weight", (cfg.vocab_size, d))}}
+
+    return _on_device(build_shard_params(
+        shard_config, get_embed, get_block, get_final,
+        stack=lambda blocks: jax.tree_util.tree_map(
+            lambda *leaves: _stack(leaves), *blocks),
+        kind=lambda block_id: block_kind(cfg, block_id)), dtype)
+
+
+def load_params(cfg: TransformerConfig, shard_config: ShardConfig,
+                weights: Mapping, dtype=jnp.float32) -> Dict:
+    """Shard params from a published-style state-dict npz (module
+    docstring). A sliced vocabulary is the table's first rows."""
+    def get(key, shape):
+        value = np.asarray(weights[key])
+        if key in ("model.embed_tokens.weight", "lm_head.weight"):
+            value = value[:shape[0]]
+        if value.shape != shape:
+            raise ValueError(f"{key}: {value.shape} in the file, {shape} "
+                             "in the model")
+        return value
+    return _assemble(cfg, shard_config, get, dtype)
+
+
+def init_params(cfg: TransformerConfig, shard_config: ShardConfig,
+                seed: int = 0, dtype=jnp.float32) -> Dict:
+    """Random shard params with the same pytree structure as `load_params`:
+    zero-centred norm weights about 0, the gated norm's about 1, and the
+    decays spread over the heads from a half to nearly one a position."""
+    rng = np.random.default_rng(seed)
+
+    def get(key, shape):
+        if key.endswith("linear_attn.norm.weight"):
+            return np.ones(shape, np.float32)
+        if key.endswith("A_log"):   # exp(g) = 2**-exp(A_log) at a = 0
+            return np.linspace(-6.5, 0.0, shape[0], dtype=np.float32)
+        if key.endswith("dt_bias"):
+            return np.zeros(shape, np.float32)
+        return rng.normal(0, 0.02, size=shape).astype(np.float32)
+    return _assemble(cfg, shard_config, get, dtype)

@@ -27,6 +27,7 @@ from . import gpt2 as gpt2_mod
 from . import keye as keye_mod
 from . import kimi as kimi_mod
 from . import llama as llama_mod
+from . import qwen3_next as qwen3_next_mod
 from . import vit as vit_mod
 
 logger = logging.getLogger(__name__)
@@ -114,6 +115,24 @@ def _kimi(name, weights, hidden, blocks, heads, mla, dense_width, vocab,
         v_head_dim=v_dim, prefill_chunk=span))
 
 
+def _qwen3_next(name, weights, hidden, blocks, heads, kv_heads, head_dim,
+                linear, vocab, max_pos, experts, expert_width, per_tok, span):
+    key_heads, value_heads, key_dim, value_dim, chunk = linear
+    return ModelEntry(name, 4 * blocks, weights, qwen3_next_mod,
+                      TransformerConfig(
+        model_type="qwen3_next", hidden_size=hidden,
+        num_hidden_layers=blocks, num_attention_heads=heads,
+        num_kv_heads=kv_heads, attn_head_dim=head_dim, intermediate_size=0,
+        layer_norm_eps=1e-6, vocab_size=vocab,
+        max_position_embeddings=max_pos, rope_theta=1e7,
+        partial_rotary_factor=0.25, n_experts=experts,
+        moe_intermediate_size=expert_width, num_experts_per_tok=per_tok,
+        norm_topk_prob=True, n_shared_experts=1, full_attention_interval=4,
+        linear_key_heads=key_heads, linear_value_heads=value_heads,
+        linear_key_dim=key_dim, linear_value_dim=value_dim,
+        linear_conv_kernel=4, linear_chunk=chunk, prefill_chunk=span))
+
+
 _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
     _vit("google/vit-base-patch16-224", 48, "ViT-B_16-224.npz", 768, 12, 12, 3072, 1000),
     _vit("google/vit-large-patch16-224", 96, "ViT-L_16-224.npz", 1024, 24, 16, 4096, 1000),
@@ -156,6 +175,14 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
           64, (1536, 512, 128, 64, 128), 18432, vocab=163840,
           max_pos=131072, experts=384, expert_width=2048, per_tok=8,
           span=128),
+    # Qwen3-Next: periods of three Gated DeltaNet layers (a state a head,
+    # no keys and values) and one gated full-attention layer, 512 small
+    # experts routed 10 a token beside a gated shared one. One chip holds a
+    # share of one period: `...@4,e0+256,v75968`
+    _qwen3_next("Qwen/Qwen3-Next-80B-A3B-Instruct",
+                "Qwen3-Next-80B-A3B-Instruct.npz", 2048, 48, 16, 2, 256,
+                (16, 32, 128, 128, 64), vocab=151936, max_pos=262144,
+                experts=512, expert_width=512, per_tok=10, span=1024),
     # tiny synthetic models for fast tests / CI (not in the reference's list)
     _vit("pipeedge/test-tiny-vit", 8, "test-tiny-vit.npz", 32, 2, 4, 64, 5,
          patch=4, img=16),
@@ -176,6 +203,10 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
     _kimi("pipeedge/test-tiny-kimi", "test-tiny-kimi.npz", 32, 3, 4,
           (24, 16, 8, 8, 8), 64, vocab=100, max_pos=64, experts=8,
           expert_width=16, per_tok=2, span=8),
+    # eight blocks: each kind of block has two runs in one stage
+    _qwen3_next("pipeedge/test-tiny-qwen3-next", "test-tiny-qwen3-next.npz",
+                32, 8, 4, 2, 16, (2, 4, 8, 8, 4), vocab=100, max_pos=64,
+                experts=8, expert_width=16, per_tok=2, span=8),
     _gpt2("pipeedge/test-tiny-moe", 8, "test-tiny-moe.npz", 32, 2, 4, 64,
           vocab=100, max_pos=64, n_experts=4, capacity_factor=4.0),
 ]}
@@ -234,7 +265,7 @@ def decoder_model(model_name: str) -> str:
     whole or as `<name>@<cut>`."""
     try:
         known = get_model_entry(model_name).config.model_type in (
-            "gpt2", "llama", "keye", "kimi")
+            "gpt2", "llama", "keye", "kimi", "qwen3_next")
     except (KeyError, ValueError):
         known = False
     if not known:

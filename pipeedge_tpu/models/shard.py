@@ -41,6 +41,31 @@ class BlockRuns(NamedTuple):
     runs: tuple
 
 
+class CacheLeaf(NamedTuple):
+    """One leaf of a stage's cache, where a family says more of it than what
+    follows `[L, B, T]` (a bare `jax.ShapeDtypeStruct` says that much).
+    `kind`: the kind of block (`FamilySpec.block_kind`) that owns the leaf,
+    whose count in the stage is the leaf's `L`; None = every block. `whole`:
+    the leaf is a row a request, `[L, B] + shape`, replaced whole by every
+    call (a recurrent state), and not a row a position, `[L, B, T] + shape`,
+    written at `pos`."""
+    shape: tuple
+    dtype: Any
+    kind: Any = None
+    whole: bool = False
+
+
+def kind_runs(family, cfg: TransformerConfig,
+              shard_config: ShardConfig) -> tuple:
+    """The shard's full blocks as runs of like blocks, in the model's order:
+    `((kind, count), ...)`, one entry where all are alike (`kind` None where
+    the family tells no kinds apart). The runs `build_shard_params` stacks."""
+    kind = getattr(family, "block_kind", None)
+    return tuple((k, len(list(run))) for k, run in groupby(
+        plan_shard(shard_config).full_ids,
+        key=(lambda b: kind(cfg, b)) if kind else (lambda b: None)))
+
+
 @dataclasses.dataclass(frozen=True)
 class FamilySpec:
     """Pure-function hooks defining a model family (vit/bert/deit/gpt2/
@@ -67,7 +92,7 @@ class FamilySpec:
     # (p, x, bcache, cfg, axis, core, cache_gather) -> (x, bcache)
     sp_prefill_block_step: Any = None
     # a family whose cache is not the plain `k`, `v` pair names its leaves:
-    # (cfg) -> {name: what follows [L, B, T]} (`init_cache`)
+    # (cfg) -> {name: what follows [L, B, T], or a `CacheLeaf`} (`init_cache`)
     cache_leaves: Any = None
     # (cfg) -> positions a prompt is prefilled at a time, through the
     # decode-shaped stage program; None = one whole-prompt prefill program

@@ -35,7 +35,7 @@ from ..telemetry import metrics as prom
 from ..utils import jax_compat
 
 from ..models import ShardConfig, plan_shard
-from ..models.shard import BlockRuns
+from ..models.shard import BlockRuns, kind_runs
 from ..models.layers import (TransformerConfig, dense, gelu_new, layer_norm)
 
 Cache = Dict[str, jax.Array]   # {'k': [L, B, T, H*Dh], 'v': [L, B, T, H*Dh]}
@@ -73,8 +73,28 @@ Cache = Dict[str, jax.Array]   # {'k': [L, B, T, H*Dh], 'v': [L, B, T, H*Dh]}
 # step's `rows["stats"]` is `[n]` int32, what this call counted). A count is
 # kept as (units of 2**20, remainder) so that it passes 2**31; the host reads
 # the leaf once a batch (`read_stats`).
+#
+# A leaf may say more (`models/shard.py` `CacheLeaf`). Its `kind` is the kind
+# of block that owns it: the leaf's `L` is then the count of that kind in the
+# stage, a run of blocks is handed the leaves its kind owns beside those no
+# kind does, and its blocks index their own kind's layers (`_run_blocks`).
+# `whole` makes it a row a request, `[L, B, ...]` with no position axis: a
+# recurrent state, which a call reads, and replaces whole where the others
+# are written at `pos` (docs/DECODE.md, "Two geometries").
 STATS = "stats"
 _STATS_UNIT = 20
+
+
+def _owner(leaves) -> Dict:
+    """{leaf: the kind of block that owns it} of the leaves that say."""
+    return {name: leaf.kind for name, leaf in (leaves or {}).items()
+            if getattr(leaf, "kind", None) is not None}
+
+
+def _whole(leaves) -> tuple:
+    """The leaves that are a row a request and replaced whole."""
+    return tuple(name for name, leaf in (leaves or {}).items()
+                 if getattr(leaf, "whole", False))
 
 
 class LayerSlice(NamedTuple):
@@ -108,20 +128,27 @@ def _read_window(buf: jax.Array, layer, width: int,
     return jax.lax.dynamic_slice(buf, start, sizes)[0]
 
 
-def _write_rows(cache: Cache, rows: Cache, pos) -> Cache:
+def _write_rows(cache: Cache, rows: Cache, pos, whole: tuple = ()) -> Cache:
     """Every layer's new `rows` (leaves `[L, B, S, ...]`) into the stacked
     cache at positions [pos, pos + S): one in-place update a leaf, for a
-    decode step, a span and a prefill alike."""
+    decode step, a span and a prefill alike. A leaf named in `whole` has no
+    positions: its rows `[L, B, ...]` take the place of what was there."""
     def write(buf, new):
         return jax.lax.dynamic_update_slice(
             buf, new.astype(buf.dtype), (0, 0, pos) + (0,) * (buf.ndim - 3))
+
+    def replace(buf, new):
+        assert new.shape == buf.shape, (new.shape, buf.shape)
+        return new.astype(buf.dtype)
 
     def add(buf, new):      # `stats`: [L, n, 2] += [L, n]
         low = buf[..., 1] + new
         return jnp.stack([buf[..., 0] + (low >> _STATS_UNIT),
                           low & ((1 << _STATS_UNIT) - 1)], axis=-1)
 
-    return {name: (add if name == STATS else write)(buf, rows[name])
+    return {name: buf if not buf.shape[0] else
+            (add if name == STATS else
+             replace if name in whole else write)(buf, rows[name])
             for name, buf in cache.items()}
 
 
@@ -135,12 +162,15 @@ def read_stats(cache: Cache):
 
 def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
                max_len: int, dtype=jnp.float32,
-               cache_bits: int = 0, leaves=None) -> Cache:
+               cache_bits: int = 0, leaves=None, runs=None) -> Cache:
     """Zeroed stacked KV cache for `n_blocks` blocks.
 
     `leaves` ({name: ShapeDtypeStruct of what follows [L, B, T]}, a
     family's `cache_leaves(cfg)`) replaces the plain `k`, `v` pair; its
-    `stats` entry sizes the counters' leaf.
+    `stats` entry sizes the counters' leaf. Where a leaf is a `CacheLeaf`
+    that names the kind of block that owns it, `runs` (`kind_runs`: the
+    stage's blocks as `(kind, count)`) gives its `L`, the count of that
+    kind among the `n_blocks`; a `whole` leaf has no `T`.
 
     `cache_bits=8` stores K/V as int8 with per-(position, head) affine
     scales (QuantPipe's activation-compression idea applied to the decode
@@ -159,10 +189,23 @@ def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
         if cache_bits:
             raise NotImplementedError(
                 "the int8 cache route covers the plain k, v cache only")
+        owner, whole = _owner(leaves), _whole(leaves)
+        if owner and sum(n for _, n in runs or ()) != n_blocks:
+            raise ValueError(
+                f"leaves {sorted(owner)} belong to kinds of block: "
+                f"init_cache needs the stage's runs of kinds, got {runs} "
+                f"for {n_blocks} blocks")
+
+        def layers(name):
+            if name not in owner:
+                return n_blocks
+            return sum(n for kind, n in runs if kind == owner[name])
+
         return {name: jnp.zeros((n_blocks,) + tail.shape + (2,), tail.dtype)
                 if name == STATS else
-                jnp.zeros((n_blocks, batch, max_len) + tail.shape,
-                          tail.dtype)
+                jnp.zeros((layers(name), batch)
+                          + (() if name in whole else (max_len,))
+                          + tuple(tail.shape), tail.dtype)
                 for name, tail in leaves.items()}
     shape = (n_blocks, batch, max_len, cfg.kv_heads * cfg.head_dim)
     if cache_bits == 0:
@@ -626,11 +669,19 @@ def attend_bucket(pos_next: int, max_len: int, floor: int = 64) -> int:
 
 def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
                 prefill: bool, block_fn=_block_step,
-                whole: tuple = ()) -> Tuple[jax.Array, Cache]:
+                whole: tuple = (), kinds: tuple = (),
+                leaves=None) -> Tuple[jax.Array, Cache]:
     """Scan the stage's blocks over x: one scan a run of like blocks (a
     bare stacked pytree is one run; `BlockRuns`, a dense layer before
     expert layers, several), all over the one cache stack, a run's blocks
-    at the layers that follow the run before. The scan only READS the
+    at the layers that follow the run before. Where leaves of the cache
+    belong to kinds of block (`leaves`, the family's `cache_leaves`, and
+    `kinds`, the kind of each run), a run sees its own kind's leaves and
+    those of no kind, and its blocks are at the layers that follow the
+    earlier runs OF ITS KIND: in a stage of three linear blocks, a full
+    one, three linear and a full, the linear runs are at layers 0-2 and 3-5
+    of their leaves and the full ones at 0 and 1 of theirs. The scan only
+    READS the
     stacked cache (each block its layer's window) and stacks the blocks'
     new rows; one update a leaf then writes them where the donated buffer's
     layout is the program's own. The stack must not be the scan's carry:
@@ -639,29 +690,41 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
     the loop (PERF.md, PR 25). Block leaves named in `whole` are not
     scanned over either: the block step gets each as a `LayerSlice`."""
     runs = blocks.runs if isinstance(blocks, BlockRuns) else (blocks,)
-    rows, first = [], 0
-    for run in runs:
+    owner = _owner(leaves)
+    rows, done, of_kind = [], 0, {}
+    for run, kind in zip(runs, kinds or (None,) * len(runs)):
+        view, first = cache, done
+        if owner:       # this kind's leaves, at this kind's layers
+            view = {name: buf for name, buf in cache.items()
+                    if owner.get(name, kind) == kind}
+            if kind in owner.values():
+                first = of_kind.get(kind, 0)
         held = {name: run[name] for name in whole if name in run}
         if held:
             run = {name: leaf for name, leaf in run.items()
                    if name not in held}
 
-        def body(y, xs, held=held, first=first):
+        def body(y, xs, held=held, first=first, view=view):
             bp, layer = xs
             if held:
                 bp = dict(bp, **{name: LayerSlice(leaf, layer)
                                  for name, leaf in held.items()})
             at = first + layer if first else layer      # the cache's layer
-            y, bc = block_fn(bp, y, LayerCache(cache, at), pos, cfg, prefill)
+            y, bc = block_fn(bp, y, LayerCache(view, at), pos, cfg, prefill)
             return y, bc.rows
 
         n_blocks = jax.tree_util.tree_leaves(run)[0].shape[0]
         x, new = jax.lax.scan(body, x, (run, jnp.arange(n_blocks)))
         rows.append(new)
-        first += n_blocks
-    rows = rows[0] if len(rows) == 1 else jax.tree_util.tree_map(
-        lambda *parts: jnp.concatenate(parts), *rows)
-    return x, _write_rows(cache, rows, 0 if prefill else pos)
+        done += n_blocks
+        of_kind[kind] = of_kind.get(kind, 0) + n_blocks
+    # a leaf's rows, from the runs that wrote it, in the model's order (a
+    # leaf of a kind the stage has no block of has none, and no layers)
+    rows = rows[0] if len(rows) == 1 else {
+        name: jnp.concatenate(parts) for name in cache
+        if (parts := [new[name] for new in rows if name in new])}
+    return x, _write_rows(cache, rows, 0 if prefill else pos,
+                          whole=_whole(leaves))
 
 
 # every stage program takes (params, data, cache[, pos]) and donates the
@@ -728,6 +791,9 @@ def _make_stage_run(family, cfg: TransformerConfig,
                                int8_optin=_resolve_int8_optin(int8_optin))
 
     whole = tuple(getattr(family, "whole_leaves", ()))
+    kinds = tuple(kind for kind, _ in kind_runs(family, cfg, shard_config))
+    leaves = getattr(family, "cache_leaves", None)
+    leaves = leaves(cfg) if leaves is not None else None
 
     def run(params, data, cache, pos, prefill, read_len=None,
             last_only=False):
@@ -750,7 +816,8 @@ def _make_stage_run(family, cfg: TransformerConfig,
         bf = block_fn if read_len is None \
             else partial(block_fn, read_len=read_len)
         data, cache = _run_blocks(stage_blocks(params), data, cache, pos,
-                                  cfg, prefill, block_fn=bf, whole=whole)
+                                  cfg, prefill, block_fn=bf, whole=whole,
+                                  kinds=kinds, leaves=leaves)
         if shard_config.is_last:
             if last_only:
                 data = data[:, -1:]
@@ -1403,6 +1470,7 @@ class DecodePipeline:
             n_blocks = (r - l + 1) // 4
             self.stages.append({"prefill": pre, "decode": dec,
                                 "params": params, "n_blocks": n_blocks,
+                                "runs": kind_runs(family, cfg, sc),
                                 "device": None if devices is None or
                                 mesh is not None else devices[i]})
         self.dtype = dtype
@@ -1438,7 +1506,7 @@ class DecodePipeline:
         for st in self.stages:
             c = init_cache(self.cfg, st["n_blocks"], batch, self.max_len,
                            self.dtype, cache_bits=self.cache_bits,
-                           leaves=self.cache_leaves)
+                           leaves=self.cache_leaves, runs=st.get("runs"))
             if cache_mesh is not None:
                 from jax.sharding import NamedSharding
                 # head axis over tp; replicated over ep when present
@@ -1569,14 +1637,21 @@ class DecodePipeline:
         """Cache-compatibility signature stamped into prefix handles: a
         handle built by one pipeline is only valid on a pipeline whose
         per-stage cache layout (block split, max_len, quantization,
-        dtype, KV geometry) matches — a mismatched handle would otherwise
-        die deep inside jit with an opaque shape error or silently
-        corrupt attend windows (round-4 advice)."""
-        return ("decode-prefix-v1",
-                tuple(st["n_blocks"] for st in self.stages),
+        dtype, KV geometry, and the geometry of every leaf the family names:
+        its shape and type, the kind of block that owns it and whether it is
+        a row a position or a row a request) matches — a mismatched handle
+        would otherwise die deep inside jit with an opaque shape error or
+        silently corrupt attend windows (round-4 advice)."""
+        named = tuple(
+            (name, tuple(leaf.shape), jnp.dtype(leaf.dtype).name,
+             getattr(leaf, "kind", None), getattr(leaf, "whole", False))
+            for name, leaf in sorted((self.cache_leaves or {}).items()))
+        return ("decode-prefix-v2",
+                tuple(st.get("runs") or st["n_blocks"]
+                      for st in self.stages),
                 self.max_len, self.cache_bits,
                 jax.dtypes.canonicalize_dtype(self.dtype).name,
-                self.cfg.kv_heads, self.cfg.head_dim)
+                self.cfg.kv_heads, self.cfg.head_dim, named)
 
     def check_prefix(self, prefix: Dict) -> None:
         """Validate a `precompute_prefix` handle against THIS pipeline's
@@ -1591,8 +1666,8 @@ class DecodePipeline:
             raise ValueError(
                 "prefix handle was built by an incompatible pipeline: "
                 f"handle sig {sig} vs this pipeline {self._prefix_sig()} "
-                "(fields: version, per-stage block counts, max_len, "
-                "cache_bits, dtype, kv_heads, head_dim)")
+                "(fields: version, per-stage runs of blocks, max_len, "
+                "cache_bits, dtype, kv_heads, head_dim, named leaves)")
 
     def generate(self, ids, new_tokens: int, temperature: float = 0.0,
                  top_k: int = 0, seed: int = 0, step_callback=None,

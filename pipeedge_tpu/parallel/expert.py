@@ -316,7 +316,8 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     the `held` experts only, and where the model has one `shared` {gate, up
     [Fs, D], down [D, Fs]}, the expert every token goes through, which is
     added here (every chip of a deployment computes it alike: when shares
-    are added up it counts once). `held` = (first, count): the experts this
+    are added up it counts once), times `sigmoid(shared_gate . token)`
+    where the model has a `shared_gate` [1, D] beside it. `held` = (first, count): the experts this
     caller computes, `first` possibly traced (an 'ep' device's slab); None
     = `cfg.held_experts`, or all. Assignments to other experts cost a sort
     key and nothing more, and add nothing here. `layer`, when given,
@@ -414,8 +415,12 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     delta = jax.lax.fori_loop(0, -(-used // round_tiles), one_round,
                               jnp.zeros((t, d), jnp.float32))
     if "shared" in params:
-        delta = delta + _swiglu(tokens, *(params["shared"][name]
-                                          for name in ("gate", "up", "down")))
+        shared = _swiglu(tokens, *(params["shared"][name]
+                                   for name in ("gate", "up", "down")))
+        if "shared_gate" in params:
+            shared = shared * jax.nn.sigmoid(exact_dot(
+                tokens, params["shared_gate"], w_contract=1))
+        delta = delta + shared
     stats = jnp.stack([jnp.sum(mine), used * tile,
                        jnp.sum(sizes > 0)]).astype(jnp.float32)
     return delta.reshape(b, s, d).astype(normed.dtype), stats

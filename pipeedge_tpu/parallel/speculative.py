@@ -108,6 +108,18 @@ class SpeculativeDecoder:
                 "paged speculative decoding needs BOTH pools: target_kv "
                 "(the decode plane's PagedKvBackend) and draft_pool (a "
                 "KvPagePool over the draft pipeline)")
+        for name, pipe in (("target", target), ("draft", draft)):
+            whole = [leaf for leaf, spec in (pipe.cache_leaves or {}).items()
+                     if getattr(spec, "whole", False)]
+            if whole:
+                # a round writes gamma rows past the committed position and
+                # a rejection steps back over them: rows a position are
+                # simply overwritten, a state a request has moved on
+                raise NotImplementedError(
+                    f"the {pipe.family.name} family ({name}) keeps {whole} "
+                    "a request, not a position: a rejected draft would need "
+                    "the state of an earlier position, and speculative "
+                    "verify has no snapshot to roll back to")
         if target.cfg.vocab_size != draft.cfg.vocab_size:
             raise ValueError(
                 "draft and target must share a vocabulary: "

@@ -234,6 +234,47 @@ def test_kimi_stage_program_compiles_for_v5e(span, last_only, rows, on_chip):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
 
 
+QWEN3_NEXT_CELL = "Qwen/Qwen3-Next-80B-A3B-Instruct@4,e0+256,v75968"
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (1024, True)])
+def test_qwen3_next_stage_program_compiles_for_v5e(span, last_only, on_chip):
+    """`qwen3-next.longdoc-batch` at its real size: one period (three Gated
+    DeltaNet layers, one gated full-attention layer) at the published widths
+    with 256 of 512 experts held, 8 rows, the 32,768 bucket; a decode step
+    and one span of the prefill. The resident bytes (7.36 GB of weights,
+    1.07 GB of keys and values in ONE layer, 53 MB of state in three) and
+    the program's temporaries have to fit one chip's 16 GB."""
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry(QWEN3_NEXT_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    rows, max_len = 8, 32768
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: decode.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg),
+        runs=kind_runs(entry.family.FAMILY, cfg, stage)))
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=max_len,
+                          last_only=last_only).compile()
+    memory = compiled.memory_analysis()
+    print(f"qwen3-next {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * (max_len * 4096 + 6586368)
+    assert memory.alias_size_in_bytes > cache_bytes     # updated in place
+    # keys and values in the one full layer only: four layers' would be 4.3 GB
+    assert memory.argument_size_in_bytes < 7.37e9 + 1.05 * cache_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+
+
 def test_gpt2_medium_decode_step_keeps_cache_rows_as_rows(on_chip):
     """`gpt2-m.offline-batch`'s decode step at its real size (32 rows, 1,024
     positions, the 512 bucket, bfloat16; shapes from the loader): the chip
