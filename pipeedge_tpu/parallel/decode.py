@@ -652,19 +652,70 @@ def stage_blocks(params: Dict) -> jax.Array:
     return blocks
 
 
-def attend_bucket(pos_next: int, max_len: int, floor: int = 64) -> int:
+# widths an octave for a batch job (`DecodePipeline.generate`); every other
+# caller keeps the powers of two (`attend_bucket`)
+JOB_PER_OCTAVE = 4
+
+
+def job_per_octave(leaves, stages) -> int:
+    """Widths an octave worth a program to a batch job on these stages:
+    `JOB_PER_OCTAVE` where the window is what most blocks read, half as
+    many where fewer than half of the blocks keep a row a position (the
+    family's `cache_leaves` say which kinds do). A program more is a compile
+    in a first run and a load in every later one, and a narrower window
+    speeds up only the blocks that attend it: three layers in four of
+    qwen3_next keep a state and no window, its span programs are the
+    largest (1.2 s a load), and at four an octave its warm set-up grew by
+    12% for 4% of tokens/s (PERF.md, PR 34)."""
+    kinds = {getattr(leaf, "kind", None) for name, leaf in
+             (leaves or {}).items()
+             if name != STATS and not getattr(leaf, "whole", False)}
+    runs = [run for st in stages for run in st.get("runs") or ()]
+    if not kinds or None in kinds or not runs:
+        return JOB_PER_OCTAVE
+    windowed = sum(count for kind, count in runs if kind in kinds)
+    return JOB_PER_OCTAVE if 2 * windowed >= sum(
+        count for _, count in runs) else JOB_PER_OCTAVE // 2
+
+M_ATTEND = prom.REGISTRY.counter(
+    "pipeedge_attend_positions_total",
+    "cache positions the dispatched stage programs attend, a row a query: "
+    "kind=read the static window compiled for, kind=live the positions "
+    "below the call's first; phase=prefill a span, phase=decode a step")
+
+
+def attend_bucket(pos_next: int, max_len: int, floor: int = 64,
+                  per_octave: int = 1, grain: Optional[int] = None) -> int:
     """Static attend-window size for a decode step with `pos_next` valid
-    cache rows: the smallest power-of-2 >= pos_next (>= floor), capped at
-    max_len. Powers of two bound the compiled-variant count to
-    log2(max_len/floor) + 1 while the attend matmul and int8 dequant
-    track the LIVE cache length instead of max_len — the longer the
-    max_len headroom, the bigger the decode-step saving."""
+    cache rows: the smallest width >= pos_next on a ladder, capped at
+    max_len. The attend matmul and int8 dequant then track the LIVE cache
+    length instead of max_len (the longer the max_len headroom, the bigger
+    the saving), and every width is one compiled program.
+
+    There are two ladders, because two kinds of caller want opposite things
+    of it. `per_octave=1` is the powers of two from `floor`: log2(max_len /
+    floor) + 1 programs, few enough for a server to warm every one before
+    it takes traffic (a compile inside a live window is a stalled user), at
+    the price of a window up to twice the live length. `per_octave=4` adds
+    1.25, 1.5 and 1.75 times each power of two: a batch job meets every
+    width of its schedule in its first batch and the persistent compile
+    cache makes later runs a load, so it takes four times the programs for
+    a window at most a quarter longer than it needs. `floor` is the least
+    width; `grain` (default `floor`) the least difference between two
+    widths, so the low octaves are not cut finer than is worth a program
+    (from 64 by 64: 64, 128, 192, 256, 320, 384, 448, 512, 640, ...);
+    where `floor` is a whole number of grains, so is every width."""
     if pos_next > max_len:
         raise ValueError(f"pos_next {pos_next} exceeds max_len {max_len}")
-    b = max(1, floor)
-    while b < pos_next:
-        b *= 2
-    return min(b, max_len)
+    base = floor = max(1, floor)
+    while 2 * base < pos_next:
+        base *= 2
+    if pos_next <= base:
+        return min(base, max_len)
+    grain = floor if grain is None else max(1, grain)
+    step = grain * -(-base // (per_octave * grain))     # whole grains
+    width = base + -(-(pos_next - base) // step) * step
+    return min(width, 2 * base, max_len)
 
 
 def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
@@ -761,8 +812,9 @@ def make_stage_fns(family, cfg: TransformerConfig, shard_config: ShardConfig,
 
     prefill_fn = jax.jit(prefill, donate_argnums=_DONATE_CACHE)
     # read_len is STATIC: each attend-window bucket compiles its own
-    # decode-step program (a handful of power-of-2 variants, the same
-    # compile-per-discrete-value pattern as the quantized edge bitwidths)
+    # decode-step program (a handful of variants off `attend_bucket`'s
+    # ladder, the same compile-per-discrete-value pattern as the quantized
+    # edge bitwidths)
     # so is last_only: a span of a prompt prefilled in spans gives only its
     # last row to the head
     decode_fn = jax.jit(decode_step,
@@ -1487,18 +1539,26 @@ class DecodePipeline:
         if attend_floor < 1:
             raise ValueError(f"attend_floor must be >= 1, got {attend_floor}")
         self.attend_floor = attend_floor
+        # the ladder `generate` asks `_read_len` for
+        self.job_per_octave = job_per_octave(self.cache_leaves, self.stages)
 
-    def _read_len(self, pos: int, span: int = 1):
+    def _read_len(self, pos: int, span: int = 1, per_octave: int = 1):
         """Static attend window for a decode/span step whose last query
         row sits at host-known pos + span - 1 (None when this pipeline's
-        stage programs aren't bucketed)."""
+        stage programs aren't bucketed), from `attend_bucket`'s ladder of
+        `per_octave` widths an octave: 1, the powers of two, for every
+        caller that must have met its programs before it serves (the
+        batcher, the speculative decoder, beam search); `job_per_octave`
+        for `generate`, the batch job."""
         if not self._bucketed:
             return None
-        # a span's window is at least eight spans wide: a prompt prefilled
-        # in spans of 512 compiles three programs up to 16k, not six
+        # a span's window is at least eight spans wide (a prompt prefilled
+        # in spans of 512 starts at 4096, not at 512) and its widths are
+        # whole spans apart; a step's are whole floors apart
         return attend_bucket(pos + span, self.max_len,
                              max(self.attend_floor, 8 * span if span > 1
-                                 else 0))
+                                 else 0), per_octave,
+                             grain=max(self.attend_floor, span))
 
     def _fresh_caches(self, batch: int) -> List[Cache]:
         caches = []
@@ -1519,15 +1579,23 @@ class DecodePipeline:
         return caches
 
     def _decode_step(self, st, data, cache, pos: int, span: int = 1,
-                     last_only: bool = False):
+                     last_only: bool = False, per_octave: int = 1):
         """Dispatch one stage's decode program at host-known `pos`,
         binding the static attend bucket when this pipeline is bucketed
         (the batcher dispatches through here too). `span` > 1 runs the
         same program shape over a K-token span [pos, pos+K) — the
         speculative-decoding verify step, and one span of a prompt
         prefilled in spans, whose head sees its last row only
-        (`last_only`; the plain stage programs take it)."""
-        rl = self._read_len(pos, span)
+        (`last_only`; the plain stage programs take it). `per_octave`
+        names the bucket's ladder (`_read_len`). What the call attends is
+        counted as it goes out (`M_ATTEND`): the window compiled for and
+        the positions of it that are live, a row a query."""
+        rl = self._read_len(pos, span, per_octave)
+        queries = data.shape[0] * span
+        phase = "prefill" if span > 1 or last_only else "decode"
+        M_ATTEND.inc(queries * (self.max_len if rl is None else rl),
+                     phase=phase, kind="read")
+        M_ATTEND.inc(queries * pos, phase=phase, kind="live")
         if rl is None:
             return st["decode"](st["params"], data, cache, pos)
         if last_only:
@@ -1535,9 +1603,11 @@ class DecodePipeline:
                                 last_only=True)
         return st["decode"](st["params"], data, cache, pos, read_len=rl)
 
-    def _prefill(self, ids, prefill_ubatch: Optional[int] = None):
+    def _prefill(self, ids, prefill_ubatch: Optional[int] = None,
+                 per_octave: int = 1):
         """Run the prompt through all stages; returns (last-stage output,
-        per-stage caches).
+        per-stage caches). Where the family prefills in spans, each span
+        attends a window off the ladder of `per_octave` (`_read_len`).
 
         `prefill_ubatch` splits the batch into chunks so prefill PIPELINES
         across stages: JAX dispatch is asynchronous, so stage i's program
@@ -1554,7 +1624,8 @@ class DecodePipeline:
                     with telemetry.span("generate", "prefill"):
                         out, caches = self.extend(
                             data[:, start:start + self.prefill_span],
-                            caches, start, last_only=True)
+                            caches, start, last_only=True,
+                            per_octave=per_octave)
                 return out, caches
             with telemetry.span("generate", "prefill"):
                 for i, st in enumerate(self.stages):
@@ -1583,7 +1654,8 @@ class DecodePipeline:
             for i in range(len(self.stages))]
         return jnp.concatenate(outs, axis=0), merged
 
-    def extend(self, tokens, caches, pos: int, last_only: bool = False):
+    def extend(self, tokens, caches, pos: int, last_only: bool = False,
+               per_octave: int = 1):
         """Run a K-token span [B, K] through every stage at cache offset
         `pos`: K/V rows [pos, pos+K) are written and span row i attends
         cache positions [0, pos+i] (causal within the span, full history
@@ -1592,7 +1664,8 @@ class DecodePipeline:
         This is the speculative-decoding VERIFY primitive: one pipelined
         forward scores K proposed tokens instead of K serial decode
         steps. K is static per call site (one compiled program per
-        distinct span length x attend bucket). With an int8 cache the
+        distinct span length x attend bucket; `per_octave` names the
+        bucket's ladder, `_read_len`). With an int8 cache the
         in-span rows are attended unquantized (exactly like the current
         row of a plain decode step), so span scoring of K tokens is not
         bit-identical to K serial int8 steps — fp caches are exact."""
@@ -1607,7 +1680,8 @@ class DecodePipeline:
                 data = jax.device_put(data, st["device"])
             data, caches[i] = self._decode_step(
                 st, data, caches[i], pos, span=k,
-                last_only=last_only and i == len(self.stages) - 1)
+                last_only=last_only and i == len(self.stages) - 1,
+                per_octave=per_octave)
         return data, caches
 
     def precompute_prefix(self, prefix_ids) -> Dict:
@@ -1684,7 +1758,14 @@ class DecodePipeline:
         with a shared prompt prefix; `ids` is then each request's SUFFIX,
         run as one span at the prefix offset instead of a fresh prefill.
         Returns [B, S + new_tokens] token ids (the prefix is not
-        included in the returned array)."""
+        included in the returned array).
+
+        This is the batch job: it meets every attend width of its schedule
+        in its first batch, so its steps, its prompt's spans and its
+        prefix's suffix ask for the fine ladder (`job_per_octave`,
+        `attend_bucket`) and attend a window close to the live length. The
+        tokens are those of any other ladder: the positions a wider window
+        adds are masked to exact zeros."""
         ids = jnp.asarray(ids, jnp.int32)
         batch, suffix_len = ids.shape
         prompt_len = suffix_len + (prefix["len"] if prefix else 0)
@@ -1711,9 +1792,11 @@ class DecodePipeline:
             # beam-search batch-tiling rule), then run the whole suffix
             # as one span at the prefix offset
             caches = [_repeat_batch(c, batch) for c in prefix["caches"]]
-            data, caches = self.extend(ids, caches, prefix["len"])
+            data, caches = self.extend(ids, caches, prefix["len"],
+                                       per_octave=self.job_per_octave)
         else:
-            data, caches = self._prefill(ids, prefill_ubatch)
+            data, caches = self._prefill(ids, prefill_ubatch,
+                                         per_octave=self.job_per_octave)
         # the counts as the prompt left them: copies, since the caches are
         # donated to the steps; read back once, after the last step
         after_prompt = [c[STATS] + 0 for c in caches if STATS in c]
@@ -1725,7 +1808,8 @@ class DecodePipeline:
                         if st["device"] is not None:
                             data = jax.device_put(data, st["device"])
                         data, caches[i] = self._decode_step(
-                            st, data, caches[i], prompt_len + step - 1)
+                            st, data, caches[i], prompt_len + step - 1,
+                            per_octave=self.job_per_octave)
             with telemetry.span("generate", "pick"):
                 token, data, rng = pick(data, rng)
             tokens.append(token)

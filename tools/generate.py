@@ -239,9 +239,11 @@ def main():
                              "HBM traffic; 0 = full precision)")
     parser.add_argument("--attend-floor", default=64, type=int,
                         help="smallest bucketed attend window: decode "
-                             "steps attend over the least power-of-2 "
-                             "window >= the live cache length instead of "
-                             "max_len (one compiled variant per bucket)")
+                             "steps attend over the least window of a "
+                             "ladder >= the live cache length instead of "
+                             "max_len (one compiled variant per bucket; "
+                             "four widths a power of two for a plain "
+                             "generation, the powers of two elsewhere)")
     parser.add_argument("--tp", default=1, type=int,
                         help="Megatron tensor-parallel degree per stage "
                              "(head-sharded KV cache, shard_map)")
