@@ -6,7 +6,8 @@ time, so dashboards and alerts watching the full matrix silently miss the
 series that hasn't fired yet (the PR 7 shed matrix was pre-declared for
 exactly this reason). PL501 requires every labeled counter family to
 `declare()` its matrix somewhere in the linted tree. PL502 keeps span
-probes exception-safe: `telemetry.span()` outside a `with` risks an
+probes exception-safe: `telemetry.span()` (or `telemetry.startup()`, the
+same probe with a counter) outside a `with` risks an
 __enter__ with no __exit__ on the error path (unbalanced spans corrupt
 the bubble math); cross-thread pairs belong to `telemetry.record()`.
 """
@@ -130,7 +131,7 @@ class UnpairedSpan(Rule):
         for node in ast.walk(module.tree):
             if not isinstance(node, ast.Call) \
                     or not isinstance(node.func, ast.Attribute) \
-                    or node.func.attr != "span":
+                    or node.func.attr not in ("span", "startup"):
                 continue
             parent = module.parent(node)
             if isinstance(parent, ast.withitem):

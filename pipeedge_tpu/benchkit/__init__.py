@@ -35,18 +35,16 @@ Recipes (see docs/PERF.md for the catalog + flags):
 Entry point: `python bench.py --recipe NAME [recipe flags]` (the default
 recipe is `exact`, keeping `python bench.py` the headline record).
 
-Lifecycle telemetry: each run emits paired `bench` spans
-(`setup:<recipe>` / `run:<recipe>` / `teardown:<recipe>`, PL502-clean)
-and counts on `pipeedge_bench_runs_total{recipe,status}` — the full
-matrix is pre-declared at registration (PL501), so a dashboard sees
-every recipe's series before its first run.
+Lifecycle telemetry: each run counts on
+`pipeedge_bench_runs_total{recipe,status}` — the full matrix is
+pre-declared at registration (PL501), so a dashboard sees every recipe's
+series before its first run.
 """
 from __future__ import annotations
 
 import argparse
 from typing import Callable, Dict, List, Optional
 
-from .. import telemetry
 from ..telemetry import metrics as prom
 from . import schema
 
@@ -137,8 +135,8 @@ def _ensure_loaded() -> None:
 def run_recipe(name: str, argv: Optional[List[str]] = None,
                notes: Optional[str] = None) -> dict:
     """Parse `argv` with the recipe's parser, run setup -> run ->
-    teardown under paired bench spans, and return the assembled
-    trajectory record (NOT printed — the caller owns stdout)."""
+    teardown, and return the assembled trajectory record (NOT printed —
+    the caller owns stdout)."""
     recipe = get_recipe(name)
     args = recipe.parser().parse_args(argv or [])
     config = {k: v for k, v in sorted(vars(args).items())}
@@ -146,16 +144,13 @@ def run_recipe(name: str, argv: Optional[List[str]] = None,
     state = None
     try:
         if recipe.setup is not None:
-            with telemetry.span("bench", f"setup:{name}"):
-                state = recipe.setup(args)
+            state = recipe.setup(args)
         try:
-            with telemetry.span("bench", f"run:{name}"):
-                blocks = (recipe.run(args) if recipe.setup is None
-                          else recipe.run(args, state))
+            blocks = (recipe.run(args) if recipe.setup is None
+                      else recipe.run(args, state))
         finally:
             if recipe.teardown is not None:
-                with telemetry.span("bench", f"teardown:{name}"):
-                    recipe.teardown(state)
+                recipe.teardown(state)
     except BaseException:
         _M_RUNS.inc(recipe=name, status="error")
         raise

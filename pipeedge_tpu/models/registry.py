@@ -12,12 +12,14 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
+from collections.abc import Mapping
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from . import ShardConfig
 from .layers import TransformerConfig
 from .shard import make_shard_fn, unstack_blocks
@@ -306,6 +308,37 @@ def should_unroll_blocks(n_blocks: int) -> bool:
     return 0 < n_blocks <= limit
 
 
+class _TimedReads(Mapping):
+    """A weights file (`.npz`) whose every array is read under
+    `telemetry.startup("weights_read")` and counted into that phase's
+    bytes: what a family's `load_params` is handed as `weights`."""
+
+    def __init__(self, path: str):
+        with telemetry.startup("weights_read"):
+            self._file = np.load(path)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self._file.close()
+
+    def __getitem__(self, key):
+        with telemetry.startup("weights_read") as phase:
+            value = self._file[key]
+            phase.moved(value.nbytes)
+        return value
+
+    def __contains__(self, key):
+        return key in self._file
+
+    def __iter__(self):
+        return iter(self._file)
+
+    def __len__(self):
+        return len(self._file)
+
+
 def module_shard_factory(model_name: str, model_file: Optional[str],
                          layer_start: int, layer_end: int, stage: int = 0,
                          dtype=jnp.float32,
@@ -335,21 +368,28 @@ def module_shard_factory(model_name: str, model_file: Optional[str],
                                   if jnp.issubdtype(x.dtype, jnp.floating)
                                   else None), params)
     elif model_file and os.path.exists(model_file):
-        with np.load(model_file) as weights:
-            params = entry.family.load_params(entry.config, shard_config, weights,
-                                              dtype=dtype)
+        # the family's loader reads a key and places it, key after key:
+        # each read suspends the placement's phase (telemetry.startup)
+        with telemetry.startup("weights_place"):
+            with _TimedReads(model_file) as weights:
+                params = entry.family.load_params(
+                    entry.config, shard_config, weights, dtype=dtype)
     else:
         logger.warning("weights file %r not found for %s; using random init",
                        model_file, model_name)
-        params = entry.family.init_params(entry.config, shard_config, dtype=dtype)
+        with telemetry.startup("weights_place"):
+            params = entry.family.init_params(entry.config, shard_config,
+                                              dtype=dtype)
     blocks = params.get("blocks")
     if blocks is not None and not isinstance(blocks, (tuple, list)):
         n_blocks = jax.tree_util.tree_leaves(blocks)[0].shape[0]
         do_unroll = unroll if unroll is not None \
             else should_unroll_blocks(n_blocks)
         if do_unroll:
-            params = unstack_blocks(params)
-    fn = make_shard_fn(entry.family.FAMILY, entry.config, shard_config)
+            with telemetry.startup("weights_place"):
+                params = unstack_blocks(params)
+    with telemetry.startup("programs"):
+        fn = make_shard_fn(entry.family.FAMILY, entry.config, shard_config)
     logger.info("======= %s stage %d: layers [%d, %d] =======",
                 model_name, stage, layer_start, layer_end)
     return fn, params, shard_config

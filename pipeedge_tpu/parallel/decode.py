@@ -682,6 +682,9 @@ M_ATTEND = prom.REGISTRY.counter(
     "cache positions the dispatched stage programs attend, a row a query: "
     "kind=read the static window compiled for, kind=live the positions "
     "below the call's first; phase=prefill a span, phase=decode a step")
+for _phase in ("prefill", "decode"):
+    for _kind in ("read", "live"):
+        M_ATTEND.declare(phase=_phase, kind=_kind)
 
 
 def attend_bucket(pos_next: int, max_len: int, floor: int = 64,
@@ -1390,6 +1393,7 @@ def build_decode_pipeline(model_name: str,
     drivers); extra kwargs (mesh=/sp_mesh=/ep_mesh=/tp_ep_mesh=/devices=/
     int8_decode_attend=) pass through."""
     from ..models import registry
+    prom.count_jax_compiles()
     cfg = registry.get_model_config(model_name)
     total = registry.get_model_layers(model_name)
     partition = list(partition) if partition else [(1, total)]
@@ -1483,15 +1487,18 @@ class DecodePipeline:
             sc = ShardConfig(l, r, is_first=l == 1, is_last=r == total)
             params = dict(stage_params[i])
             # restack an unrolled block layout ONCE here, not per traced call
-            params["blocks"] = stage_blocks(params)
+            with telemetry.startup("weights_place"):
+                params["blocks"] = stage_blocks(params)
             if tp_ep_mesh is not None:
                 from jax.sharding import NamedSharding
-                pre, dec, p_specs = make_tp_ep_stage_fns(
-                    family, cfg, sc, tp_ep_mesh, params,
-                    tp_axis=tp_axis, ep_axis=ep_axis)
-                params = jax.tree_util.tree_map(
-                    lambda x, s: jax.device_put(
-                        x, NamedSharding(tp_ep_mesh, s)), params, p_specs)
+                with telemetry.startup("programs"):
+                    pre, dec, p_specs = make_tp_ep_stage_fns(
+                        family, cfg, sc, tp_ep_mesh, params,
+                        tp_axis=tp_axis, ep_axis=ep_axis)
+                with telemetry.startup("weights_place"):
+                    params = jax.tree_util.tree_map(
+                        lambda x, s: jax.device_put(
+                            x, NamedSharding(tp_ep_mesh, s)), params, p_specs)
                 n_blocks = (r - l + 1) // 4
                 self.stages.append({"prefill": pre, "decode": dec,
                                     "params": params, "n_blocks": n_blocks,
@@ -1507,18 +1514,24 @@ class DecodePipeline:
                 kw = ({"cache_bits": cache_bits}
                       if maker in (make_tp_stage_fns, make_ep_stage_fns)
                       else {})
-                pre, dec, p_specs = maker(family, cfg, sc, m, params,
-                                          axis=ax, **kw)
-                params = jax.tree_util.tree_map(
-                    lambda x, s: jax.device_put(x, NamedSharding(m, s)),
-                    params, p_specs)
+                with telemetry.startup("programs"):
+                    pre, dec, p_specs = maker(family, cfg, sc, m, params,
+                                              axis=ax, **kw)
+                with telemetry.startup("weights_place"):
+                    params = jax.tree_util.tree_map(
+                        lambda x, s: jax.device_put(x, NamedSharding(m, s)),
+                        params, p_specs)
             else:
-                pre, dec = make_stage_fns(family, cfg, sc, int8_optin=optin)
-                if sp_mesh is not None:
-                    pre = make_sp_prefill_fn(family, cfg, sc, sp_mesh,
-                                             axis=sp_axis, sp_kind=sp_kind)
+                with telemetry.startup("programs"):
+                    pre, dec = make_stage_fns(family, cfg, sc,
+                                              int8_optin=optin)
+                    if sp_mesh is not None:
+                        pre = make_sp_prefill_fn(family, cfg, sc, sp_mesh,
+                                                 axis=sp_axis,
+                                                 sp_kind=sp_kind)
                 if devices is not None:
-                    params = jax.device_put(params, devices[i])
+                    with telemetry.startup("weights_place"):
+                        params = jax.device_put(params, devices[i])
             n_blocks = (r - l + 1) // 4
             self.stages.append({"prefill": pre, "decode": dec,
                                 "params": params, "n_blocks": n_blocks,

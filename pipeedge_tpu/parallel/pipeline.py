@@ -40,6 +40,7 @@ from .. import telemetry
 from ..ops import clamp as clamp_ops
 from ..ops import fused_quant
 from ..ops import quant as quant_ops
+from ..telemetry import metrics as prom
 
 logger = logging.getLogger(__name__)
 
@@ -120,7 +121,8 @@ class PipelineStage:
     tunnel: bool = False
 
     def __post_init__(self):
-        self.params = jax.device_put(self.params, self.device)
+        with telemetry.startup("weights_place"):
+            self.params = jax.device_put(self.params, self.device)
         self._compiled: Dict[int, Callable] = {}
 
     def _fn_for_bit(self, bit: int) -> Callable:
@@ -135,8 +137,9 @@ class PipelineStage:
                 out = shard_fn(params, data)
                 return _encode_payload(out, bit, do_clamp)
 
-            fn = jax.jit(host_stage_step, donate_argnums=(
-                (1,) if self.donate_payload else ()))
+            with telemetry.startup("programs"):
+                fn = jax.jit(host_stage_step, donate_argnums=(
+                    (1,) if self.donate_payload else ()))
             self._compiled[bit] = fn
         return fn
 
@@ -435,6 +438,7 @@ def build_pipeline(model_name: str, partition: Sequence[Tuple[int, int]],
     from ..models import registry
     from ..models.layers import quantize_compute
 
+    prom.count_jax_compiles()
     if devices is None:
         devices = jax.local_devices()
     if dtype is None:

@@ -35,6 +35,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from ..utils import jax_compat
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -173,7 +174,10 @@ class SpmdPipeline:
                get_tp_quant_bits())
         fn = self._compiled.get(key)
         if fn is None:
-            fn = self._build(inputs)
+            # the program waits for its input's shape: built at the first
+            # call, not in `build_spmd_pipeline`
+            with telemetry.startup("programs"):
+                fn = self._build(inputs)
             self._compiled[key] = fn
         return fn
 
@@ -526,6 +530,7 @@ def build_spmd_pipeline(family: FamilySpec, cfg: TransformerConfig,
     `-q` list semantics, runtime.py:652-656; the final entry is the result
     edge and is forced to 0).
     """
+    prom.count_jax_compiles()
     n_stages = len(partition)
     if isinstance(quant_bit, (list, tuple)):
         if len(quant_bit) != n_stages:
@@ -577,25 +582,23 @@ def build_spmd_pipeline(family: FamilySpec, cfg: TransformerConfig,
         # than silently compute something different from the oracle
         raise NotImplementedError(
             "MoE blocks do not compose with the 'tp'/'sp' mesh axes")
-    params = {
-        "embed": stage_params[0]["embeddings"],
-        "final": stage_params[-1]["final"],
-        "blocks": _pad_stack(blocks_list, max_b),
-        "n_blocks": jnp.asarray(n_blocks, jnp.int32),
-    }
-    # place parameters: blocks stage-sharded (and Megatron tp-sharded when
-    # the mesh has a tp axis), embed/final replicated
-    block_specs = _stacked_block_specs(cfg, params["blocks"], tp)
-    params = {
-        "embed": jax.device_put(params["embed"],
-                                NamedSharding(mesh, P())),
-        "final": jax.device_put(params["final"], NamedSharding(mesh, P())),
-        "blocks": jax.tree_util.tree_map(
-            lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
-            params["blocks"], block_specs),
-        "n_blocks": jax.device_put(params["n_blocks"],
-                                   NamedSharding(mesh, P("stage"))),
-    }
+    # place parameters: blocks stacked over the stage axis and stage-sharded
+    # (and Megatron tp-sharded when the mesh has a tp axis), embed/final
+    # replicated
+    with telemetry.startup("weights_place"):
+        blocks = _pad_stack(blocks_list, max_b)
+        block_specs = _stacked_block_specs(cfg, blocks, tp)
+        params = {
+            "embed": jax.device_put(stage_params[0]["embeddings"],
+                                    NamedSharding(mesh, P())),
+            "final": jax.device_put(stage_params[-1]["final"],
+                                    NamedSharding(mesh, P())),
+            "blocks": jax.tree_util.tree_map(
+                lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
+                blocks, block_specs),
+            "n_blocks": jax.device_put(jnp.asarray(n_blocks, jnp.int32),
+                                       NamedSharding(mesh, P("stage"))),
+        }
     _M_STAGE_BLOCKS.set(min_b, kind="unconditional")
     _M_STAGE_BLOCKS.set(max_b - min_b, kind="masked")
     return SpmdPipeline(family=family, cfg=cfg, mesh=mesh, n_stages=n_stages,

@@ -37,7 +37,9 @@ lifecycle), `runtime` (schedule rounds), `failover` (detection→recovery),
 lifecycle transitions, pipeedge_tpu/health/), `serve` (HTTP request
 lifecycle; the streaming handler's `readback` and `write`), `exec` (the
 decode executor's worker phases: `wait0`, `admit`, `pick`, `emit`,
-`eos`, `retire`, `publish`).
+`eos`, `retire`, `publish`), `startup` (`startup()`: where a process's
+set-up goes, phase by phase; also the always-on
+`pipeedge_startup_seconds_total{phase}`).
 """
 from __future__ import annotations
 
@@ -52,6 +54,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..utils.threads import make_lock
+from . import metrics
 
 ENV_SPAN_CAPACITY = "PIPEEDGE_SPAN_CAPACITY"
 DEFAULT_SPAN_CAPACITY = 32768
@@ -160,12 +163,15 @@ class SpanRecorder:
 class _Span:
     """Live span: stamps monotonic_ns on enter/exit and records into the
     ring on exit (when `rec` is set); when `ann` is set — a profiler
-    session is live — the span is also that `TraceAnnotation`'s life."""
+    session is live — the span is also that `TraceAnnotation`'s life.
+    `seconds`, a counter labelled by phase, gets the same two stamps'
+    difference under the span's name (`startup()`)."""
 
     __slots__ = ("_rec", "_ann", "_cat", "_name", "_stage", "_mb", "_rid",
-                 "_t0")
+                 "_seconds", "_t0")
 
-    def __init__(self, rec, ann, cat, name, stage, mb, rid=None):
+    def __init__(self, rec, ann, cat, name, stage, mb, rid=None,
+                 seconds=None):
         self._rec = rec
         self._ann = ann
         self._cat = cat
@@ -173,6 +179,7 @@ class _Span:
         self._stage = stage
         self._mb = mb
         self._rid = rid
+        self._seconds = seconds
 
     def __enter__(self):
         if self._ann is not None:
@@ -187,6 +194,8 @@ class _Span:
         if self._rec is not None:
             self._rec.record(self._cat, self._name, self._t0, t1,
                              self._stage, self._mb, rid=self._rid)
+        if self._seconds is not None:
+            self._seconds.inc((t1 - self._t0) / 1e9, phase=self._name)
         return False
 
 
@@ -265,6 +274,103 @@ def span(cat: str, name: str, stage: Optional[int] = None,
             return _NULL_SPAN
         return _Span(rec, None, cat, name, stage, mb, rid)
     return _Span(rec, ann(f"{cat}/{name}"), cat, name, stage, mb, rid)
+
+
+# -- start-up phases -----------------------------------------------------
+
+# where a process's set-up goes, in the order a serving process meets
+# them: `backend` (the first `jax.devices()`: the accelerator runtime's
+# start), `weights_read` (file to host arrays), `weights_place` (host arrays
+# to a stage's parameters as its builder keeps them: cast, stacking,
+# device_put), `programs` (constructing the stage programs; nothing
+# compiles here) and `service` (executor, admission, governor, the HTTP
+# socket). The first and the last are tools/serve.py's.
+STARTUP_PHASES = ("backend", "weights_read", "weights_place", "programs",
+                  "service")
+
+_STARTUP_SECONDS = metrics.REGISTRY.counter(
+    "pipeedge_startup_seconds_total",
+    "seconds of set-up by phase (the `startup` spans' own clock readings); "
+    "phases exclude each other, one opened inside another suspends it")
+_STARTUP_BYTES = metrics.REGISTRY.counter(
+    "pipeedge_startup_bytes_total",
+    "bytes a set-up phase moved: weights_read, the host arrays read from "
+    "the weights file")
+for _phase in STARTUP_PHASES:
+    _STARTUP_SECONDS.declare(phase=_phase)
+_STARTUP_BYTES.declare(phase="weights_read")
+_startup_open = threading.local()
+
+
+class _Startup:
+    """One start-up phase on this thread, as a run of `startup/<phase>`
+    spans: one, unless a phase opened inside it suspends it meanwhile."""
+
+    __slots__ = ("_phase", "_outer", "_span")
+
+    def __init__(self, phase: str):
+        if phase not in STARTUP_PHASES:
+            raise ValueError(f"{phase!r} is no declared start-up phase: "
+                             f"{STARTUP_PHASES}")
+        self._phase = phase
+
+    def _resume(self):
+        ann = _live_annotation()
+        if ann is not None:
+            ann = ann(f"startup/{self._phase}")
+        self._span = _Span(_recorder, ann, "startup", self._phase, None,
+                           None, seconds=_STARTUP_SECONDS)
+        self._span.__enter__()
+
+    def _suspend(self, *exc):
+        self._span.__exit__(*exc)
+
+    def __enter__(self):
+        self._outer = getattr(_startup_open, "phase", None)
+        if self._outer is not None:
+            self._outer._suspend(None, None, None)
+        _startup_open.phase = self
+        self._resume()
+        return self
+
+    def __exit__(self, *exc):
+        self._suspend(*exc)
+        _startup_open.phase = self._outer
+        if self._outer is not None:
+            self._outer._resume()
+        return False
+
+    def moved(self, nbytes: int) -> None:
+        """Add to the phase's `pipeedge_startup_bytes_total`."""
+        _STARTUP_BYTES.inc(nbytes, phase=self._phase)
+
+
+def startup(phase: str) -> _Startup:
+    """`span("startup", phase)` that is never the no-op: with a ring it is
+    a span, under a live profiler session a `TraceAnnotation`
+    `startup/<phase>`, and the same two clock readings always add to
+    `pipeedge_startup_seconds_total{phase}`, so that a process can say
+    where its set-up went without having been asked beforehand. `phase` is
+    one of `STARTUP_PHASES`. Phases exclude each other: one opened inside
+    another (the loader's reads inside its placement) suspends the outer
+    one, so their seconds add up to wall time. It fences nothing: a phase
+    ends where the host is free to go on."""
+    return _Startup(phase)
+
+
+def startup_line() -> str:
+    """What `tools/generate.py` and `runtime.py` print once they have
+    built: the weights' seconds and bytes, and the programs built so far
+    (`metrics.count_jax_compiles`' families, the pipeline's own only)."""
+    read_s = _STARTUP_SECONDS.value(phase="weights_read")
+    place_s = _STARTUP_SECONDS.value(phase="weights_place")
+    read = _STARTUP_BYTES.value(phase="weights_read")
+    steps = metrics.program_builds()
+    compiled, cached = steps["compile"][0], steps["cache_read"][0]
+    return (f"startup: weights {read_s + place_s:.2f} s ({read / 1e9:.2f} GB "
+            f"read in {read_s:.2f} s), programs built {compiled + cached} "
+            f"({compiled} compiled, {cached} read) in "
+            f"{sum(s for _, s in steps.values()):.2f} s")
 
 
 def record(cat: str, name: str, t0: int, t1: int,

@@ -17,8 +17,10 @@ without restarting the process in tests).
 """
 from __future__ import annotations
 
+import threading
 import time
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Dict, Iterable, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 from ..utils.threads import make_lock
 
@@ -437,20 +439,99 @@ def render_span_digest(digest: dict) -> List[str]:
     return lines
 
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# the step of a program's build each `jax.monitoring` duration event times.
+# The compile event fires where the persistent cache answers too; a hit
+# announces itself on the same thread just before, by _CACHE_READ_EVENT
+_BUILD_STEPS = {_TRACE_EVENT: "trace",
+                "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+                _COMPILE_EVENT: "compile"}
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+BUILD_STEPS = ("trace", "lower", "compile", "cache_read")
+# the jitted functions the pipeline builders and the decode path make, by
+# the name the device trace prints after `jit_`; every other build (eager
+# operations, a caller's own programs) is `other`
+PROGRAMS = ("prefill", "decode_step", "pick_next", "host_stage_step",
+            "shard_apply", "spmd_body", "tp_prefill", "tp_decode_step",
+            "ep_prefill", "ep_decode_step", "tp_ep_prefill",
+            "tp_ep_decode_step", "sp_prefill")
+OTHER_PROGRAM = "other"
+
+class _BuildCounters(NamedTuple):
+    """What the one listener of this process feeds in one registry (held
+    here too, so that its id stays its own)."""
+    registry: "Registry"
+    compiles: Counter
+    seconds: Counter
+    builds: Counter
+    build_seconds: Counter
+
+
+_COMPILE_SINKS: Dict[int, _BuildCounters] = {}      # by id(registry)
+# per thread: `traces`, how many traces are open (a jitted function called
+# inside another is traced inside its trace), and `hit`, whether the
+# persistent cache answered the compile that is about to report
+_build_state = threading.local()
+
+
+def _on_trace_start(event, _start, **_):
+    if event == _TRACE_EVENT:
+        _build_state.traces = getattr(_build_state, "traces", 0) + 1
+
+
+def _on_build_event(event, duration, fun_name=None, **_):
+    if event == _CACHE_READ_EVENT:
+        _build_state.hit = True
+        return
+    step = _BUILD_STEPS.get(event)
+    if step is None:
+        return
+    if step == "trace":
+        # only the outermost trace counts: its seconds hold those of the
+        # jitted functions it calls, which get no lower or compile either
+        _build_state.traces = open_traces = max(
+            getattr(_build_state, "traces", 1) - 1, 0)
+        if open_traces:
+            return
+    elif step == "compile" and getattr(_build_state, "hit", False):
+        _build_state.hit = False
+        step = "cache_read"
+    program = str(fun_name or "")
+    if program.startswith("jit(") and program.endswith(")"):
+        program = program[4:-1]         # lower and compile: the module name
+    if program not in PROGRAMS:
+        program = OTHER_PROGRAM
+    for sink in _COMPILE_SINKS.values():
+        if event == _COMPILE_EVENT:
+            sink.compiles.inc()
+            sink.seconds.inc(duration)
+        sink.builds.inc(program=program, step=step)
+        sink.build_seconds.inc(duration, program=program, step=step)
 
 
 def count_jax_compiles(registry: Registry = REGISTRY) -> Tuple[Counter,
                                                                Counter]:
-    """Count every program JAX hands to the backend compiler, through a
-    `jax.monitoring` duration listener: `pipeedge_jax_compiles_total` and
-    `pipeedge_jax_compile_seconds_total` in `registry`. The event fires
-    once for each new (function, shapes, static values), also where the
-    persistent cache answers (the seconds are then the read); a repeat of
-    a warm shape does not fire it. The listener lives as long as the
-    process: call once per registry (tools/serve.py does, at start-up).
-    Returns the two counters."""
+    """Count what JAX builds, through ONE `jax.monitoring` duration
+    listener a process, into `registry`. Idempotent: every pipeline
+    builder and tools/serve.py call it, and a build is counted once.
+
+    `pipeedge_jax_compiles_total` / `pipeedge_jax_compile_seconds_total`:
+    every program handed to the backend compiler. The event fires once for
+    each new (function, shapes, static values), also where the persistent
+    cache answers (the seconds are then the read); a repeat of a warm
+    shape does not fire it. Returns these two counters.
+
+    `pipeedge_jax_program_builds_total{program,step}` /
+    `pipeedge_jax_program_build_seconds_total{program,step}`: the same
+    builds and the two steps before them, by program (`PROGRAMS`, else
+    `other`) and step (`BUILD_STEPS`): `trace` (Python to a jaxpr),
+    `lower` (jaxpr to an MLIR module), then `compile` or, where the
+    persistent cache held the program, `cache_read`."""
     import jax.monitoring
+    sink = _COMPILE_SINKS.get(id(registry))
+    if sink is not None:
+        return sink.compiles, sink.seconds
     compiles = registry.counter(
         "pipeedge_jax_compiles_total",
         "programs handed to the backend compiler (persistent-cache hits "
@@ -458,13 +539,41 @@ def count_jax_compiles(registry: Registry = REGISTRY) -> Tuple[Counter,
     seconds = registry.counter(
         "pipeedge_jax_compile_seconds_total",
         "seconds in the backend compiler or its persistent cache")
+    builds = registry.counter(
+        "pipeedge_jax_program_builds_total",
+        "steps of program builds, by program (the jitted function's name, "
+        "`other` outside the pipeline's own) and step (trace, lower, "
+        "compile or cache_read)")
+    build_seconds = registry.counter(
+        "pipeedge_jax_program_build_seconds_total",
+        "seconds in each step of program builds, by program and step")
     compiles.declare()
     seconds.declare()
-
-    def on_duration(event, duration, **_):
-        if event == _COMPILE_EVENT:
-            compiles.inc()
-            seconds.inc(duration)
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    for program in PROGRAMS + (OTHER_PROGRAM,):
+        for step in BUILD_STEPS:
+            builds.declare(program=program, step=step)
+            build_seconds.declare(program=program, step=step)
+    if not _COMPILE_SINKS:
+        # two callbacks, one listener: a trace's start is a scalar event,
+        # everything else a duration
+        jax.monitoring.register_scalar_listener(_on_trace_start)
+        jax.monitoring.register_event_duration_secs_listener(_on_build_event)
+    _COMPILE_SINKS[id(registry)] = _BuildCounters(
+        registry, compiles, seconds, builds, build_seconds)
     return compiles, seconds
+
+
+def program_builds(registry: Registry = REGISTRY) -> Dict[str, Tuple[int,
+                                                                     float]]:
+    """step -> (builds, seconds) of the pipeline's own programs (every
+    `program` but `other`) as `count_jax_compiles` has counted them into
+    `registry`; zeros where it was never installed there."""
+    sink = _COMPILE_SINKS.get(id(registry))
+    steps = {step: [0, 0.0] for step in BUILD_STEPS}
+    if sink is not None:
+        for column, counter in enumerate((sink.builds, sink.build_seconds)):
+            for labels, value in counter.values().items():
+                labels = dict(labels)
+                if labels["program"] != OTHER_PROGRAM:
+                    steps[labels["step"]][column] += value
+    return {step: (int(n), s) for step, (n, s) in steps.items()}

@@ -2636,6 +2636,7 @@ def _report(tik, tok, ubatches):
     throughput = batch_size / latency if latency > 0 else 0
     logger.info("Latency: %f seconds", latency)
     logger.info("Throughput: %f items/sec", throughput)
+    print(telemetry.startup_line())
     print(f"latency_sec={latency:.6f} throughput_items_sec={throughput:.3f}")
 
 

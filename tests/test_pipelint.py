@@ -499,8 +499,19 @@ def run_stage(req, i, trace):
 """
 
 
-def test_pl502_fires(tmp_path):
-    assert "PL502" in rule_ids(run_on(tmp_path, PL502_BAD))
+PL502_STARTUP_BAD = """
+from pipeedge_tpu import telemetry
+
+def load():
+    phase = telemetry.startup("weights_read")    # the same probe, a counter
+    phase.__enter__()
+"""
+
+
+@pytest.mark.parametrize("source", [PL502_BAD, PL502_STARTUP_BAD],
+                         ids=["span", "startup"])
+def test_pl502_fires(tmp_path, source):
+    assert "PL502" in rule_ids(run_on(tmp_path, source))
 
 
 def test_pl502_fires_on_request_tagged_span(tmp_path):

@@ -17,6 +17,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
+from pipeedge_tpu import telemetry  # noqa: E402
+
 
 def prompt_ids(args, cfg):
     """Synthetic prompt token ids [B, prompt_len] (seeded, rank-consistent)."""
@@ -25,6 +27,7 @@ def prompt_ids(args, cfg):
 
 
 def print_summary(args, dt, result, label):
+    print(telemetry.startup_line())
     print(f"generated {args.batch_size}x{args.new_tokens} tokens in "
           f"{dt:.3f}s = {args.batch_size * args.new_tokens / dt:.1f} tok/s "
           f"({label})")
@@ -528,6 +531,7 @@ def main():
         dt = time.monotonic() - tik
         n_tok = args.concurrent * args.batch_size * args.new_tokens
         shared = f", shared prefix {p_len}" if p_len else ""
+        print(telemetry.startup_line())
         print(f"generated {args.concurrent}x{args.batch_size}x"
               f"{args.new_tokens} tokens in {dt:.3f}s = {n_tok / dt:.1f} "
               f"tok/s ({len(partition)} stages, continuous batching"

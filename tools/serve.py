@@ -2672,7 +2672,15 @@ def main():
 
     from pipeedge_tpu.utils import enable_compile_cache, report_devices
     enable_compile_cache()
-    report_devices()
+    # spans are always on in serving processes: GET /debug/spans drains
+    # the ring for trace_report --fleet federation without pre-arming
+    # (the `startup` spans of this function among them).
+    # --trace-spans keeps controlling only the shutdown trace dump.
+    telemetry.configure(rank=0)
+    # pipeedge_jax_compiles_total: "nothing compiles under load" as a number
+    prom.count_jax_compiles()
+    with telemetry.startup("backend"):
+        report_devices()
     import jax.numpy as jnp
 
     from pipeedge_tpu.parallel.decode import build_decode_pipeline
@@ -2756,12 +2764,6 @@ def main():
             ship_bits=args.kv_ship_bits,
             max_concurrent=args.prefill_concurrency)
 
-    # spans are always on in serving processes: GET /debug/spans drains
-    # the ring for trace_report --fleet federation without pre-arming.
-    # --trace-spans keeps controlling only the shutdown trace dump.
-    telemetry.configure(rank=0)
-    # pipeedge_jax_compiles_total: "nothing compiles under load" as a number
-    prom.count_jax_compiles()
     from pipeedge_tpu.analysis import lockdep
     if args.trace_spans or lockdep.enabled():
         # SIGTERM must unwind through the finally below (the default
@@ -2769,46 +2771,47 @@ def main():
         # PIPEEDGE_LOCKDEP atexit report — is written)
         import signal
         signal.signal(signal.SIGTERM, lambda *a: sys.exit(0))
-    service = _Service(pipe, max_active=args.max_active,
-                       max_prefixes=args.max_prefixes, spec=spec,
-                       edge_itemsize=2 if args.dtype == "bfloat16" else 4,
-                       admission_enabled=not args.no_admission,
-                       queue_capacity=args.queue_capacity,
-                       class_rates=_parse_class_map(
-                           args.class_rate, "--class-rate", p),
-                       class_deadlines_s=_parse_class_map(
-                           args.class_deadline, "--class-deadline", p),
-                       brownout_enabled=not args.no_brownout,
-                       brownout_marks=Watermarks(
-                           queue_high=args.brownout_queue_high,
-                           queue_low=args.brownout_queue_low,
-                           p95_high_s=args.brownout_p95_high,
-                           p95_low_s=args.brownout_p95_low,
-                           dwell_up_s=args.brownout_dwell_up,
-                           dwell_down_s=args.brownout_dwell_down),
-                       clamp_new_tokens=args.brownout_clamp_tokens,
-                       governor_interval=args.governor_interval,
-                       postmortem_dir=args.postmortem_dir,
-                       kv_pages=args.kv_pages,
-                       kv_page_size=args.kv_page_size,
-                       prefill_fleet=prefill_fleet,
-                       prefill_supervisor=prefill_supervisor,
-                       chunked_prefill=args.chunked_prefill,
-                       step_join=args.step_join,
-                       prefill_budget=args.prefill_budget,
-                       clamp_chunk_tokens=args.brownout_clamp_chunk,
-                       slo_objective=args.slo_objective,
-                       slo_burn_fast=args.slo_burn_fast,
-                       slo_burn_slow=args.slo_burn_slow,
-                       slo_burn_threshold=args.slo_burn_threshold)
-    if prefill_fleet is not None and hasattr(prefill_fleet,
-                                             "flight_note"):
-        # ship-plane faults (lease timeouts, zombie drops, worker
-        # deaths/readmissions) land in the flight recorder's event ring
-        prefill_fleet.flight_note = service.flight.note
-    server = _HTTPServer((args.host, args.port),
-                         make_handler(service, args.model_name,
-                                      profile_dir=args.profile_dir))
+    with telemetry.startup("service"):
+        service = _Service(pipe, max_active=args.max_active,
+                           max_prefixes=args.max_prefixes, spec=spec,
+                           edge_itemsize=2 if args.dtype == "bfloat16" else 4,
+                           admission_enabled=not args.no_admission,
+                           queue_capacity=args.queue_capacity,
+                           class_rates=_parse_class_map(
+                               args.class_rate, "--class-rate", p),
+                           class_deadlines_s=_parse_class_map(
+                               args.class_deadline, "--class-deadline", p),
+                           brownout_enabled=not args.no_brownout,
+                           brownout_marks=Watermarks(
+                               queue_high=args.brownout_queue_high,
+                               queue_low=args.brownout_queue_low,
+                               p95_high_s=args.brownout_p95_high,
+                               p95_low_s=args.brownout_p95_low,
+                               dwell_up_s=args.brownout_dwell_up,
+                               dwell_down_s=args.brownout_dwell_down),
+                           clamp_new_tokens=args.brownout_clamp_tokens,
+                           governor_interval=args.governor_interval,
+                           postmortem_dir=args.postmortem_dir,
+                           kv_pages=args.kv_pages,
+                           kv_page_size=args.kv_page_size,
+                           prefill_fleet=prefill_fleet,
+                           prefill_supervisor=prefill_supervisor,
+                           chunked_prefill=args.chunked_prefill,
+                           step_join=args.step_join,
+                           prefill_budget=args.prefill_budget,
+                           clamp_chunk_tokens=args.brownout_clamp_chunk,
+                           slo_objective=args.slo_objective,
+                           slo_burn_fast=args.slo_burn_fast,
+                           slo_burn_slow=args.slo_burn_slow,
+                           slo_burn_threshold=args.slo_burn_threshold)
+        if prefill_fleet is not None and hasattr(prefill_fleet,
+                                                 "flight_note"):
+            # ship-plane faults (lease timeouts, zombie drops, worker
+            # deaths/readmissions) land in the flight recorder's event ring
+            prefill_fleet.flight_note = service.flight.note
+        server = _HTTPServer((args.host, args.port),
+                             make_handler(service, args.model_name,
+                                          profile_dir=args.profile_dir))
     print(f"serving {args.model_name} ({len(pipe.stages)} stages) on "
           f"{args.host}:{args.port}", flush=True)
     try:
