@@ -26,9 +26,9 @@ running sum of `g` in a chunk and `Gamma_ts = exp(gamma_t - gamma_s)`,
   O = (Q exp(gamma)) S_0 + lower(Q K^T * Gamma) D
   S_C = exp(gamma_C) S_0 + (K exp(gamma_C - gamma))^T D
 everything that does not need `S_0` for all chunks at once, then one scan
-over the chunks that carries the state. A last chunk that the span does not
-fill is padded with `beta` = 0 and `g` = 0, which leaves the state as it
-was.
+over the chunks that carries the state. `A` is forward substitution by
+blocks (`_inverse_unit_lower`). A last chunk that the span does not fill is
+padded with `beta` = 0 and `g` = 0, which leaves the state as it was.
 
 **Gated full attention.** A head of `q_proj` is `[query | gate]`; q and k
 are normed a head (zero-centred), the first `partial_rotary_factor` of a
@@ -50,8 +50,10 @@ four layers of keys and values would be 4.3 GB.
 float32: products with weights through `exact_dot`, the delta rule's
 products of two activations at `HIGHEST`, the delta rule's (the state is a
 sum over every position before) and the attention's (`_ATTENTION` says what
-`HIGH` did). The router's top-10 of 512 is a discrete choice that a bfloat16
-computation makes differently from the float32 reference.
+`HIGH` did); a step's and the chunk's inverse's are float32 multiplications
+and sums on the vector unit, which is what `HIGHEST` stands in for. The
+router's top-10 of 512 is a discrete choice that a bfloat16 computation
+makes differently from the float32 reference.
 
 **Prefill** runs in spans of `cfg.prefill_chunk` positions (a multiple of
 the chunk) through the decode-shaped stage program, as keye's does.
@@ -78,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ShardConfig
+from ..telemetry import metrics as prom
 from .keye import _by_head, _experts
 from .kimi import _in_row_chunks, _lin, _on_device, _stack
 from .layers import TransformerConfig, rope_rotate
@@ -103,6 +106,18 @@ _ATTENTION = jax.lax.Precision.HIGHEST
 # bytes of float32 attention scores one chunk of queries may hold (one KV
 # group's at a time)
 _SCORE_BYTES = 1 << 29
+
+# the widest diagonal block of a chunk's triangular matrix that is inverted a
+# row at a time (`inverse_block`); wider ones are merged from two
+_INVERSE_BLOCK = 16
+
+# /metrics plane: which form of `_inverse_unit_lower` a built stage's chunks
+# take. Set when a stage's parameters are assembled, from the chunk alone
+_M_INVERSE_BLOCK = prom.REGISTRY.gauge(
+    "pipeedge_gdn_inverse_block",
+    "width of the diagonal blocks the chunked delta rule's triangular "
+    "inverse takes a row at a time before it merges them, by chunk; 0 = the "
+    "chunk is one block, plain rows")
 
 
 def prefill_span(cfg: TransformerConfig) -> int:
@@ -196,22 +211,73 @@ def delta_step(q, k, v, beta, g, state):
     return jnp.sum(state * q[..., None], axis=-2), state
 
 
+def inverse_block(chunk: int) -> int:
+    """The width of the diagonal blocks that `_inverse_unit_lower` inverts
+    a row at a time in a chunk of `chunk`: `chunk` halved while it is even
+    and above `_INVERSE_BLOCK` (64 -> 16, 24 -> 12, 16 and under as they
+    are), so that blocks of that width merge upward in pairs to the whole."""
+    block = chunk
+    while block > _INVERSE_BLOCK and block % 2 == 0:
+        block //= 2
+    return block
+
+
 def _inverse_unit_lower(low: jax.Array) -> jax.Array:
     """(I + low)^-1 of strictly lower triangular `low` [..., C, C], by
-    forward substitution a row at a time: row i of the inverse's strict
-    part is `-low_i - low_i X` over the rows above, which are final. No
-    series in powers of `low`: keys of a trained model lie close together
-    in a chunk, and the powers' terms then cancel from 1e18 down."""
+    forward substitution, in blocks. The diagonal blocks of
+    `inverse_block(C)`, all of them in one array, a row at a time: row i of
+    a block's inverse's strict part is `-low_i - low_i X` over the rows
+    above, which are final. Then neighbours merge upward until one block is
+    the whole (16 -> 32 -> 64):
+      [[A, 0], [L, B]]^-1 = [[A^-1, 0], [-B^-1 L A^-1, B^-1]]
+    with `L` the block of `low` below A and beside B. That is the same
+    substitution with blocks for entries, every term of it computed once.
+    It is NOT a series in powers of `low`: keys of a trained model lie
+    close together in a chunk, and the powers' terms then cancel from 1e18
+    down. A chunk no wider than one block (the tests' 4) is the plain row
+    substitution and merges nothing: one algorithm, its one parameter read
+    off the shape.
+
+    The matrices of a span (4,096 of 64 x 64 in the cell) lie on the minor
+    axis throughout, `[C, C, M]`: a row's step and a merge's products are
+    then float32 multiplications and sums over whole lanes on the vector
+    unit, exact as `delta_step`'s are, with no axis of 16 or 64 padded to a
+    lane tile and no row written as a tile of eight. Over `[..., C, C]` as
+    it comes, every trip of a row loop sweeps the whole array from HBM, its
+    64 lanes padded to 128 (134 MB in the cell; 63 trips were 1.78 s of a
+    prefill's 10.7: PERF.md, PR 36)."""
     c = low.shape[-1]
-    at = jnp.arange(c)
+    block = inverse_block(c)
+    x = jnp.moveaxis(low.reshape((-1, c, c)), 0, -1)            # [C, C, M]
 
-    def row(i, x):
-        mine = jax.lax.dynamic_slice_in_dim(x, i, 1, axis=-2)
-        mine = mine + jnp.einsum("...ij,...jk->...ik", mine, x,
-                                 precision=_STATE)
-        return jax.lax.dynamic_update_slice_in_dim(x, mine, i, axis=-2)
+    def tiles(down):    # `low`'s blocks `down` below the diagonal, every
+        # (1 + down)th: [tiles, row, column, M]
+        grid = x.reshape(c // block, block, c // block, block, -1)
+        return jnp.stack([grid[j + down, :, j]
+                          for j in range(0, c // block, 1 + down)])
 
-    return jax.lax.fori_loop(1, c, row, -low) + (at[:, None] == at[None, :])
+    def row(i, d):
+        mine = jax.lax.dynamic_slice_in_dim(d, i, 1, axis=1)
+        mine = mine + jnp.sum(jnp.swapaxes(mine, 1, 2) * d, axis=1,
+                              keepdims=True)
+        return jax.lax.dynamic_update_slice_in_dim(d, mine, i, axis=1)
+
+    def times(a, b):    # [P, r, k, M] x [P, k, c, M] -> [P, r, c, M]
+        return jnp.sum(a[:, :, :, None] * b[:, None], axis=2)
+
+    inverse = jax.lax.fori_loop(1, block, row, -tiles(0)) \
+        + jnp.eye(block, dtype=low.dtype)[:, :, None]
+    while block < c:
+        # neighbours by a reshape: a slice of every second block compiles
+        # to a gather on the chip
+        pairs = inverse.reshape((-1, 2) + inverse.shape[1:])
+        first, second = pairs[:, 0], pairs[:, 1]
+        corner = -times(times(second, tiles(1)), first)
+        inverse = jnp.concatenate(
+            [jnp.concatenate([first, jnp.zeros_like(first)], axis=2),
+             jnp.concatenate([corner, second], axis=2)], axis=1)
+        block *= 2
+    return jnp.moveaxis(inverse[0], -1, 0).reshape(low.shape)
 
 
 def delta_chunked(q, k, v, beta, g, state, chunk: int):
@@ -478,6 +544,9 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
     traced values pass through, for `jax.eval_shape`)."""
     d, heads, groups, hd = cfg.hidden_size, cfg.num_attention_heads, \
         cfg.kv_heads, cfg.head_dim
+    block = inverse_block(cfg.linear_chunk)
+    _M_INVERSE_BLOCK.set(block if block < cfg.linear_chunk else 0,
+                         chunk=str(cfg.linear_chunk))
     hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
     dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
     per = hv // hk          # value heads a key head

@@ -273,6 +273,10 @@ def test_qwen3_next_stage_program_compiles_for_v5e(span, last_only, on_chip):
     # keys and values in the one full layer only: four layers' would be 4.3 GB
     assert memory.argument_size_in_bytes < 7.37e9 + 1.05 * cache_bytes
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+    # the span's temporaries as they were with the chunk's inverse a row at a
+    # time (2.34 GB): the blocked inverse buys no speed with memory, the
+    # cell peaks at 11.1 of 16 GB beside the benchmark's reference
+    assert memory.temp_size_in_bytes < (2.35e9 if span > 1 else 0.07e9)
 
 
 def test_gpt2_medium_decode_step_keeps_cache_rows_as_rows(on_chip):

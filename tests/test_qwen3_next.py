@@ -181,10 +181,11 @@ def _by_recurrence(q, k, v, beta, g, state):
     return out, state
 
 
-@pytest.mark.parametrize("length", [8, 11])     # whole chunks; a padded one
+# whole chunks; a padded one; the cell's chunk (its inverse by blocks), 2.5
+@pytest.mark.parametrize("chunk, length", [(4, 8), (4, 11), (64, 160)])
 @pytest.mark.parametrize("decay", ["slow", "fast", "mixed"])
 @pytest.mark.parametrize("start", ["zero", "nonzero"])
-def test_the_chunked_form_is_the_recurrence(start, decay, length):
+def test_the_chunked_form_is_the_recurrence(start, decay, chunk, length):
     q, k, v, beta, g = _delta_inputs(length, decay)
     state = np.zeros((2, 3, 8, 6), np.float32)
     if start == "nonzero":
@@ -192,7 +193,7 @@ def test_the_chunked_form_is_the_recurrence(start, decay, length):
             np.float32)
     wanted, wanted_state = _by_recurrence(q, k, v, beta, g, state)
     got, got_state = qwen3_next.delta_chunked(
-        *(jnp.asarray(x) for x in (q, k, v, beta, g, state)), chunk=4)
+        *(jnp.asarray(x) for x in (q, k, v, beta, g, state)), chunk=chunk)
     np.testing.assert_allclose(got, wanted, atol=2e-5)
     np.testing.assert_allclose(got_state, wanted_state, atol=2e-5)
     # and the one-token form, a position at a time
@@ -236,14 +237,81 @@ def test_a_decay_is_exp_to_an_ulp_and_without_a_bias(decade):
     assert float(qwen3_next._exp(jnp.float32(-200.0))) == 0.0
 
 
-def test_the_inverse_is_exact_where_a_series_would_not_be():
-    """Keys that lie close together in a chunk: `I + L` with L near all
-    ones, whose inverse is small and whose powers are binomials."""
-    low = np.tril(np.full((16, 16), 0.98, np.float32), -1)[None]
-    got = qwen3_next._inverse_unit_lower(jnp.asarray(low))
+def _inverse_by_rows(low):
+    """The row substitution `_inverse_unit_lower` was before it took blocks,
+    kept as its reference: a row at a time over `[..., C, C]` as it comes."""
+    c = low.shape[-1]
+
+    def row(i, x):
+        mine = jax.lax.dynamic_slice_in_dim(x, i, 1, axis=-2)
+        mine = mine + jnp.einsum("...ij,...jk->...ik", mine, x,
+                                 precision=jax.lax.Precision.HIGHEST)
+        return jax.lax.dynamic_update_slice_in_dim(x, mine, i, axis=-2)
+
+    return jax.lax.fori_loop(1, c, row, -low) + jnp.eye(c, dtype=low.dtype)
+
+
+def _strict_lower(kind, c, lead=(2, 2, 3)):
+    """`L` [N, B, H, C, C] of a chunk. `close`: keys that lie close together,
+    L near all ones, whose inverse is small and whose powers are binomials;
+    `decays`: what `delta_chunked` builds, `(beta K) K^T * Gamma` of normed
+    keys under the heads' decays; `zeros`: a padded chunk's (beta 0)."""
+    if kind == "zeros":
+        return np.zeros(lead + (c, c), np.float32)
+    if kind == "close":
+        return np.broadcast_to(
+            np.tril(np.full((c, c), 0.98, np.float32), -1), lead + (c, c))
+    rng = np.random.default_rng(c)
+    k = rng.normal(size=lead + (c, 8))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = rng.uniform(0, 1, size=lead + (c, 1))
+    gamma = np.cumsum(np.log(rng.uniform(0.3, 0.999, size=lead + (c,))), -1)
+    decay = np.exp(gamma[..., :, None] - gamma[..., None, :])
+    return np.tril((beta * k) @ np.swapaxes(k, -1, -2) * decay, -1).astype(
+        np.float32)
+
+
+@pytest.mark.parametrize("kind", ["close", "decays", "zeros"])
+@pytest.mark.parametrize("c", [1, 4, 16, 24, 64])   # blocks of 1, 4, 16, 12, 16
+def test_the_inverse_is_exact_where_a_series_would_not_be(c, kind):
+    low = _strict_lower(kind, c)
+    got = jax.jit(qwen3_next._inverse_unit_lower)(jnp.asarray(low))
+    assert got.shape == low.shape and got.dtype == jnp.float32
     np.testing.assert_allclose(
-        got[0], np.linalg.inv(np.eye(16) + low[0].astype(np.float64)),
-        atol=1e-5)
+        got, np.linalg.inv(np.eye(c) + low.astype(np.float64)), atol=1e-5)
+    np.testing.assert_allclose(got, _inverse_by_rows(jnp.asarray(low)),
+                               atol=1e-5)
+    # one matrix and no batch, as the leading axes are folded
+    np.testing.assert_allclose(
+        qwen3_next._inverse_unit_lower(jnp.asarray(low[0, 0, 0])),
+        got[0, 0, 0], atol=1e-6)
+
+
+@pytest.mark.parametrize("chunk, block", [(1, 1), (4, 4), (16, 16), (24, 12),
+                                          (33, 33), (48, 12), (64, 16),
+                                          (128, 16)])
+def test_a_chunks_blocks_merge_in_pairs_to_the_whole(chunk, block):
+    """One parameter read off the chunk: halved while even and above 16."""
+    assert qwen3_next.inverse_block(chunk) == block
+    width = block
+    while width < chunk:
+        width *= 2
+    assert width == chunk
+
+
+@pytest.mark.parametrize("size, block", [("tiny", 0), ("published", 16)])
+def test_a_build_says_which_inverse_its_chunks_take(size, block):
+    """`pipeedge_gdn_inverse_block{chunk}`: 0 where a chunk is one block (the
+    tiny model's 4), the block's width where blocks merge (the cell's 64)."""
+    cfg = registry.get_model_config(TINY if size == "tiny" else CELL)
+    stage = ShardConfig(1, 4, is_first=False, is_last=False)
+    jax.eval_shape(lambda: qwen3_next._assemble(
+        dataclasses.replace(cfg, num_hidden_layers=1), stage,
+        lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    assert qwen3_next._M_INVERSE_BLOCK.value(
+        chunk=str(cfg.linear_chunk)) == block
+    assert f'pipeedge_gdn_inverse_block{{chunk="{cfg.linear_chunk}"}}' \
+        in prom.REGISTRY.render()
 
 
 def _linear_block(seed=0):
