@@ -22,7 +22,7 @@ class TransformerConfig:
     local constants so the framework runs with zero egress)."""
     model_type: str              # the family: 'vit' | 'bert' | 'deit' |
     #                              'gpt2' | 'llama' | 'keye' | 'kimi' |
-    #                              'qwen3_next'
+    #                              'qwen3_next' | 'lfm2'
     hidden_size: int
     num_hidden_layers: int       # transformer blocks (sublayers = 4x this)
     num_attention_heads: int
@@ -76,6 +76,9 @@ class TransformerConfig:
     # that every token goes through
     router: str = "softmax"
     routed_scaling_factor: float = 1.0
+    # what the kept gates' sum is guarded with where they are renormalised
+    # (DeepSeek-V3's code adds 1e-20, LFM2's 1e-6; a softmax's sum needs none)
+    gate_sum_eps: float = 0.0
     n_shared_experts: int = 0
     # the chip's share of each expert layer, (first, count) of `n_experts`
     # (`<name>@e<first>+<count>`, models/registry.py); () = all of them
@@ -113,6 +116,11 @@ class TransformerConfig:
     linear_chunk: int = 0
     # the share of a head's width that the rotation turns (its first lanes)
     partial_rotary_factor: float = 1.0
+    # the mixer of each block where a list and no interval says (lfm2
+    # family: "conv" | "full_attention"), and how many positions its gated
+    # short convolution spans
+    layer_types: tuple = ()
+    conv_kernel: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -368,6 +376,20 @@ def rope_rotate(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     rotated = jnp.concatenate([-x2, x1], axis=-1)
     return (x * cos[None, :, None] + rotated
             * sin[None, :, None]).astype(x.dtype)
+
+
+def causal_conv(kernel: jax.Array, x: jax.Array, tail: jax.Array):
+    """Depthwise causal convolution of x [B, S, C] with `kernel` [K, C],
+    carried across calls: position t sees x at t - K + 1 .. t, the K - 1
+    positions before the span coming from `tail` [B, K - 1, C] (zeros at a
+    prompt's start). -> (mixed [B, S, C] float32, the tail after the span:
+    the last K - 1 inputs, whatever S)."""
+    s = x.shape[1]
+    padded = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    kernel = kernel.astype(jnp.float32)
+    mixed = sum(kernel[j] * padded[:, j:j + s]
+                for j in range(kernel.shape[0]))
+    return mixed, padded[:, s:]
 
 
 def apply_causal_mask(scores: jax.Array) -> jax.Array:

@@ -83,7 +83,7 @@ from . import ShardConfig
 from ..telemetry import metrics as prom
 from .keye import _by_head, _experts
 from .kimi import _in_row_chunks, _lin, _on_device, _stack
-from .layers import TransformerConfig, rope_rotate
+from .layers import TransformerConfig, causal_conv, rope_rotate
 from .shard import CacheLeaf, FamilySpec, build_shard_params
 
 # what a block step counts into the cache's `stats` leaf, in this order
@@ -347,12 +347,7 @@ def gated_delta_net(p: Dict, normed, state, tail, cfg: TransformerConfig):
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(p["a_log"].astype(jnp.float32)) * jax.nn.softplus(
         ba[..., hv:] + p["dt_bias"].astype(jnp.float32))
-    # the convolution: position t sees m at t - K + 1 .. t
-    padded = jnp.concatenate([tail.astype(m.dtype), m], axis=1)
-    kernel = p["conv"].astype(jnp.float32)                  # [K, channels]
-    mixed = sum(kernel[j] * padded[:, j:j + s]
-                for j in range(kernel.shape[0]))
-    tail = padded[:, s:]
+    mixed, tail = causal_conv(p["conv"], m, tail)           # [K, channels]
     q, k, v = jnp.split(jax.nn.silu(mixed), [hk * dk, 2 * hk * dk], axis=-1)
 
     def heads(x, scale):    # l2 norm a key head, then a copy a value head

@@ -28,6 +28,7 @@ from . import deit as deit_mod
 from . import gpt2 as gpt2_mod
 from . import keye as keye_mod
 from . import kimi as kimi_mod
+from . import lfm2 as lfm2_mod
 from . import llama as llama_mod
 from . import qwen3_next as qwen3_next_mod
 from . import vit as vit_mod
@@ -112,7 +113,7 @@ def _kimi(name, weights, hidden, blocks, heads, mla, dense_width, vocab,
         rope_yarn=(32.0, 4096, 1.0, 1.0, 1.0, 1.0), n_experts=experts,
         moe_intermediate_size=expert_width, num_experts_per_tok=per_tok,
         norm_topk_prob=True, router="sigmoid", routed_scaling_factor=2.827,
-        n_shared_experts=1, first_k_dense=1, q_lora_rank=q_rank,
+        gate_sum_eps=1e-20, n_shared_experts=1, first_k_dense=1, q_lora_rank=q_rank,
         kv_lora_rank=kv_rank, qk_nope_head_dim=nope, qk_rope_head_dim=rope,
         v_head_dim=v_dim, prefill_chunk=span))
 
@@ -133,6 +134,26 @@ def _qwen3_next(name, weights, hidden, blocks, heads, kv_heads, head_dim,
         linear_key_heads=key_heads, linear_value_heads=value_heads,
         linear_key_dim=key_dim, linear_value_dim=value_dim,
         linear_conv_kernel=4, linear_chunk=chunk, prefill_chunk=span))
+
+
+def _lfm2(name, weights, hidden, layer_types, heads, kv_heads, dense_width,
+          vocab, max_pos, experts, expert_width, per_tok, span):
+    blocks = len(layer_types)
+    return ModelEntry(name, 4 * blocks, weights, lfm2_mod, TransformerConfig(
+        model_type="lfm2", hidden_size=hidden, num_hidden_layers=blocks,
+        num_attention_heads=heads, num_kv_heads=kv_heads,
+        intermediate_size=dense_width, layer_norm_eps=1e-5, vocab_size=vocab,
+        max_position_embeddings=max_pos, rope_theta=1e6, qk_norm=True,
+        n_experts=experts, moe_intermediate_size=expert_width,
+        num_experts_per_tok=per_tok, norm_topk_prob=True, router="sigmoid",
+        routed_scaling_factor=1.0, gate_sum_eps=1e-6, first_k_dense=2,
+        layer_types=tuple(layer_types), conv_kernel=3, prefill_chunk=span))
+
+
+# LFM2's pattern of mixers, one letter a block: c a gated short convolution,
+# a grouped-query attention (no interval: the last attention comes early)
+def _layer_types(pattern: str) -> tuple:
+    return tuple({"c": "conv", "a": "full_attention"}[m] for m in pattern)
 
 
 _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
@@ -185,6 +206,13 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
                 "Qwen3-Next-80B-A3B-Instruct.npz", 2048, 48, 16, 2, 256,
                 (16, 32, 128, 128, 64), vocab=151936, max_pos=262144,
                 experts=512, expert_width=512, per_tok=10, span=1024),
+    # LFM2-8B-A1B: 18 gated short convolutions (a state of two positions a
+    # request) and 6 GQA layers, two leading dense FFNs, then 32 experts
+    # routed 4 a token by a sigmoid, none shared; the head is the embedding.
+    # One chip holds one of two pipeline stages: `...@12`
+    _lfm2("LiquidAI/LFM2-8B-A1B", "LFM2-8B-A1B.npz", 2048,
+          _layer_types("ccacccacccacccacccaccacc"), 32, 8, 7168, vocab=65536,
+          max_pos=128000, experts=32, expert_width=1792, per_tok=4, span=128),
     # tiny synthetic models for fast tests / CI (not in the reference's list)
     _vit("pipeedge/test-tiny-vit", 8, "test-tiny-vit.npz", 32, 2, 4, 64, 5,
          patch=4, img=16),
@@ -209,6 +237,11 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
     _qwen3_next("pipeedge/test-tiny-qwen3-next", "test-tiny-qwen3-next.npz",
                 32, 8, 4, 2, 16, (2, 4, 8, 8, 4), vocab=100, max_pos=64,
                 experts=8, expert_width=16, per_tok=2, span=8),
+    # eight blocks in the published order: two kinds of convolution block
+    # (the leading dense pair, then routed) around two attention blocks
+    _lfm2("pipeedge/test-tiny-lfm2", "test-tiny-lfm2.npz", 32,
+          _layer_types("ccacccac"), 4, 2, 64, vocab=100, max_pos=64,
+          experts=8, expert_width=16, per_tok=2, span=8),
     _gpt2("pipeedge/test-tiny-moe", 8, "test-tiny-moe.npz", 32, 2, 4, 64,
           vocab=100, max_pos=64, n_experts=4, capacity_factor=4.0),
 ]}
@@ -267,7 +300,7 @@ def decoder_model(model_name: str) -> str:
     whole or as `<name>@<cut>`."""
     try:
         known = get_model_entry(model_name).config.model_type in (
-            "gpt2", "llama", "keye", "kimi", "qwen3_next")
+            "gpt2", "llama", "keye", "kimi", "qwen3_next", "lfm2")
     except (KeyError, ValueError):
         known = False
     if not known:

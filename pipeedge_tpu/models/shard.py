@@ -45,10 +45,13 @@ class CacheLeaf(NamedTuple):
     """One leaf of a stage's cache, where a family says more of it than what
     follows `[L, B, T]` (a bare `jax.ShapeDtypeStruct` says that much).
     `kind`: the kind of block (`FamilySpec.block_kind`) that owns the leaf,
-    whose count in the stage is the leaf's `L`; None = every block. `whole`:
-    the leaf is a row a request, `[L, B] + shape`, replaced whole by every
-    call (a recurrent state), and not a row a position, `[L, B, T] + shape`,
-    written at `pos`."""
+    or a tuple of kinds where blocks of more than one own it (a mixer's leaf
+    in a model whose blocks also differ by their FFN: the runs differ, the
+    leaf is one); their count in the stage is the leaf's `L`, in the model's
+    order across those kinds; None = every block. `whole`: the leaf is a row
+    a request, `[L, B] + shape`, replaced whole by every call (a recurrent
+    state), and not a row a position, `[L, B, T] + shape`, written at
+    `pos`."""
     shape: tuple
     dtype: Any
     kind: Any = None

@@ -75,9 +75,10 @@ Cache = Dict[str, jax.Array]   # {'k': [L, B, T, H*Dh], 'v': [L, B, T, H*Dh]}
 # the leaf once a batch (`read_stats`).
 #
 # A leaf may say more (`models/shard.py` `CacheLeaf`). Its `kind` is the kind
-# of block that owns it: the leaf's `L` is then the count of that kind in the
-# stage, a run of blocks is handed the leaves its kind owns beside those no
-# kind does, and its blocks index their own kind's layers (`_run_blocks`).
+# of block that owns it, or the kinds where more than one do: the leaf's `L`
+# is then the count of those kinds in the stage, a run of blocks is handed the
+# leaves its kind owns beside those no kind does, and its blocks index the
+# layers of the kinds that share its leaves (`_run_blocks`).
 # `whole` makes it a row a request, `[L, B, ...]` with no position axis: a
 # recurrent state, which a call reads, and replaces whole where the others
 # are written at `pos` (docs/DECODE.md, "Two geometries").
@@ -86,15 +87,31 @@ _STATS_UNIT = 20
 
 
 def _owner(leaves) -> Dict:
-    """{leaf: the kind of block that owns it} of the leaves that say."""
-    return {name: leaf.kind for name, leaf in (leaves or {}).items()
-            if getattr(leaf, "kind", None) is not None}
+    """{leaf: the kinds of block that own it} of the leaves that say."""
+    kinds = {name: getattr(leaf, "kind", None)
+             for name, leaf in (leaves or {}).items()}
+    return {name: kind if isinstance(kind, tuple) else (kind,)
+            for name, kind in kinds.items() if kind is not None}
 
 
 def _whole(leaves) -> tuple:
     """The leaves that are a row a request and replaced whole."""
     return tuple(name for name, leaf in (leaves or {}).items()
                  if getattr(leaf, "whole", False))
+
+
+def _shares_layers(owner: Dict, kind) -> tuple:
+    """The kinds of block whose layers a leaf of `kind`'s counts (`owner`:
+    `_owner`'s), `kind` among them; () where `kind` owns no leaf. A block
+    step has one layer index for all it reads, so the leaves a kind owns
+    have to belong to the same kinds."""
+    sharing = {kinds for kinds in owner.values() if kind in kinds}
+    if len(sharing) > 1:
+        raise ValueError(
+            f"the leaves that blocks of kind {kind!r} own belong to "
+            f"different sets of kinds, {sorted(sharing)}: one block step "
+            "has one layer index")
+    return next(iter(sharing), ())
 
 
 class LayerSlice(NamedTuple):
@@ -168,9 +185,9 @@ def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
     `leaves` ({name: ShapeDtypeStruct of what follows [L, B, T]}, a
     family's `cache_leaves(cfg)`) replaces the plain `k`, `v` pair; its
     `stats` entry sizes the counters' leaf. Where a leaf is a `CacheLeaf`
-    that names the kind of block that owns it, `runs` (`kind_runs`: the
-    stage's blocks as `(kind, count)`) gives its `L`, the count of that
-    kind among the `n_blocks`; a `whole` leaf has no `T`.
+    that names the kind (or kinds) of block that owns it, `runs`
+    (`kind_runs`: the stage's blocks as `(kind, count)`) gives its `L`, the
+    count of those kinds among the `n_blocks`; a `whole` leaf has no `T`.
 
     `cache_bits=8` stores K/V as int8 with per-(position, head) affine
     scales (QuantPipe's activation-compression idea applied to the decode
@@ -199,7 +216,7 @@ def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
         def layers(name):
             if name not in owner:
                 return n_blocks
-            return sum(n for kind, n in runs if kind == owner[name])
+            return sum(n for kind, n in runs if kind in owner[name])
 
         return {name: jnp.zeros((n_blocks,) + tail.shape + (2,), tail.dtype)
                 if name == STATS else
@@ -264,7 +281,7 @@ def _fold(x: jax.Array) -> jax.Array:
     return x.reshape(x.shape[:2] + (-1,))
 
 
-def _scores(q: jax.Array, k_part: jax.Array) -> jax.Array:
+def _scores(q: jax.Array, k_part: jax.Array, precision=None) -> jax.Array:
     """q [B,S,H,Dh] against one part's keys -> [B,H,S,T] float32."""
     b, s, h, hd = q.shape
     stored = k_part.ndim == 3
@@ -274,25 +291,30 @@ def _scores(q: jax.Array, k_part: jax.Array) -> jax.Array:
         blocks = jnp.einsum("bqgrd,cg->bcdgrq", q, jnp.eye(g, dtype=q.dtype))
         part = jnp.einsum("bkc,bcn->bnk", k_part,
                           blocks.reshape(b, g * hd, h * s),
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=precision)
     else:
         part = jnp.einsum("bqgrd,bkgd->bgrqk", q, k_part,
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=precision)
     return part.reshape(b, h, s, -1)
 
 
-def _context(probs: jax.Array, v_part: jax.Array, hd: int) -> jax.Array:
+def _context(probs: jax.Array, v_part: jax.Array, hd: int,
+             precision=None) -> jax.Array:
     """probs [B,H,S,T] over one part's values -> [B,S,H,Dh] float32."""
     b, h, s, _ = probs.shape
     if v_part.ndim == 4:
         g = v_part.shape[2]
         ctx = jnp.einsum("bgrqk,bkgd->bqgrd",
                          probs.reshape(b, g, h // g, s, -1), v_part,
-                         preferred_element_type=jnp.float32)
+                         preferred_element_type=jnp.float32,
+                         precision=precision)
     else:       # as stored: every head over every lane, its kv head's kept
         g = v_part.shape[2] // hd
         every = jnp.einsum("bnk,bkc->bnc", probs.reshape(b, h * s, -1),
-                           v_part, preferred_element_type=jnp.float32)
+                           v_part, preferred_element_type=jnp.float32,
+                           precision=precision)
         own = jnp.eye(g, dtype=bool)[:, None, None, :, None]
         ctx = jnp.sum(jnp.where(
             own, every.reshape(b, g, h // g, s, g, hd), 0), axis=4)
@@ -300,7 +322,8 @@ def _context(probs: jax.Array, v_part: jax.Array, hd: int) -> jax.Array:
     return ctx.reshape(b, s, h, hd)
 
 
-def _attend(q: jax.Array, k, v, keep, cfg: TransformerConfig) -> jax.Array:
+def _attend(q: jax.Array, k, v, keep, cfg: TransformerConfig,
+            precision=None) -> jax.Array:
     """Masked attention of q [B,S,H,Dh] over k/v; `keep` [S, T] marks key
     positions each query may attend to. k, v and keep may each be a tuple
     of parts (a cached step's window and its fresh rows,
@@ -315,14 +338,19 @@ def _attend(q: jax.Array, k, v, keep, cfg: TransformerConfig) -> jax.Array:
     out in blocks, column (h, s) holding query (s, h) in its kv head's Dh
     lanes and zeros in the others. The zeros add nothing to a sum, and cost
     nothing while the columns fit one MXU pass; a span with more columns
-    pays the copy instead and takes the window with its heads apart."""
+    pays the copy instead and takes the window with its heads apart.
+
+    `precision` is that of the products of two activations: None for the
+    narrow ones most families run; a family whose activations and cache are
+    float32 says how many bfloat16 passes it needs (one would round both)."""
     b, s, h, hd = q.shape
     k, v = _parts(k), _parts(v)
     if s * h > _MXU_COLUMNS:
         k, v = ([x.reshape(x.shape[:2] + (-1, hd)) for x in parts]
                 for parts in (k, v))
     scores = [jnp.where(keep_part[None, None],
-                        _scores(q, k_part) / jnp.sqrt(jnp.float32(hd)), -1e30)
+                        _scores(q, k_part, precision)
+                        / jnp.sqrt(jnp.float32(hd)), -1e30)
               for k_part, keep_part in zip(k, _parts(keep))]
     if len(scores) == 1:
         probs = [jax.nn.softmax(scores[0], axis=-1)]
@@ -332,7 +360,7 @@ def _attend(q: jax.Array, k, v, keep, cfg: TransformerConfig) -> jax.Array:
         probs = [jnp.exp(part - top) for part in scores]
         total = sum(jnp.sum(part, axis=-1, keepdims=True) for part in probs)
         probs = [part / total for part in probs]
-    ctx = sum(_context(p_part.astype(q.dtype), v_part, hd)
+    ctx = sum(_context(p_part.astype(q.dtype), v_part, hd, precision)
               for p_part, v_part in zip(probs, v))
     return ctx.astype(q.dtype).reshape(b, s, h * hd)
 
@@ -667,12 +695,13 @@ def job_per_octave(leaves, stages) -> int:
     qwen3_next keep a state and no window, its span programs are the
     largest (1.2 s a load), and at four an octave its warm set-up grew by
     12% for 4% of tokens/s (PERF.md, PR 34)."""
-    kinds = {getattr(leaf, "kind", None) for name, leaf in
-             (leaves or {}).items()
-             if name != STATS and not getattr(leaf, "whole", False)}
+    rows = {name: leaf for name, leaf in (leaves or {}).items()
+            if name != STATS and not getattr(leaf, "whole", False)}
+    owner = _owner(rows)
     runs = [run for st in stages for run in st.get("runs") or ()]
-    if not kinds or None in kinds or not runs:
+    if not rows or len(owner) < len(rows) or not runs:
         return JOB_PER_OCTAVE
+    kinds = {kind for kinds in owner.values() for kind in kinds}
     windowed = sum(count for kind, count in runs if kind in kinds)
     return JOB_PER_OCTAVE if 2 * windowed >= sum(
         count for _, count in runs) else JOB_PER_OCTAVE // 2
@@ -732,11 +761,12 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
     belong to kinds of block (`leaves`, the family's `cache_leaves`, and
     `kinds`, the kind of each run), a run sees its own kind's leaves and
     those of no kind, and its blocks are at the layers that follow the
-    earlier runs OF ITS KIND: in a stage of three linear blocks, a full
-    one, three linear and a full, the linear runs are at layers 0-2 and 3-5
-    of their leaves and the full ones at 0 and 1 of theirs. The scan only
-    READS the
-    stacked cache (each block its layer's window) and stacks the blocks'
+    earlier runs OF THE KINDS THAT OWN ITS LEAVES: in a stage of three
+    linear blocks, a full one, three linear and a full, the linear runs are
+    at layers 0-2 and 3-5 of their leaves and the full ones at 0 and 1 of
+    theirs; where a leaf belongs to two kinds (one mixer before two kinds
+    of FFN: two runs, one leaf) the second kind's run follows the first's
+    in it (`_shares_layers`). The scan only READS the stacked cache (each block its layer's window) and stacks the blocks'
     new rows; one update a leaf then writes them where the donated buffer's
     layout is the program's own. The stack must not be the scan's carry:
     the TPU compiler lays a carried buffer out to suit the rows written
@@ -748,11 +778,12 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
     rows, done, of_kind = [], 0, {}
     for run, kind in zip(runs, kinds or (None,) * len(runs)):
         view, first = cache, done
-        if owner:       # this kind's leaves, at this kind's layers
+        if owner:       # this kind's leaves, at their owners' layers
             view = {name: buf for name, buf in cache.items()
-                    if owner.get(name, kind) == kind}
-            if kind in owner.values():
-                first = of_kind.get(kind, 0)
+                    if kind in owner.get(name, (kind,))}
+            sharing = _shares_layers(owner, kind)
+            if sharing:
+                first = sum(of_kind.get(other, 0) for other in sharing)
         held = {name: run[name] for name in whole if name in run}
         if held:
             run = {name: leaf for name, leaf in run.items()

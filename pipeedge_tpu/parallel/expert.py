@@ -275,8 +275,9 @@ def topk_route(router: Dict, tokens: jax.Array, cfg: TransformerConfig):
     over the kept where the config says so. "sigmoid": each expert's score
     is its own sigmoid; the k are chosen on score + `bias` (a correction
     that steers the choice and is no part of the gate), and the gates are
-    the chosen scores, renormalised where the config says so, times
-    `cfg.routed_scaling_factor`."""
+    the chosen scores, renormalised where the config says so (over their
+    sum + `cfg.gate_sum_eps`, the constant the model's own code guards the
+    division with), times `cfg.routed_scaling_factor`."""
     logits = jnp.dot(tokens.astype(jnp.float32),
                      router["w"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
@@ -292,7 +293,7 @@ def topk_route(router: Dict, tokens: jax.Array, cfg: TransformerConfig):
         raise ValueError(f"no router {cfg.router!r}")
     if cfg.norm_topk_prob:
         gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
-                         + (1e-20 if cfg.router == "sigmoid" else 0.0))
+                         + cfg.gate_sum_eps)
     return experts, gates * cfg.routed_scaling_factor
 
 

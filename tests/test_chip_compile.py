@@ -279,6 +279,49 @@ def test_qwen3_next_stage_program_compiles_for_v5e(span, last_only, on_chip):
     assert memory.temp_size_in_bytes < (2.35e9 if span > 1 else 0.07e9)
 
 
+LFM2_CELL = "LiquidAI/LFM2-8B-A1B@12"
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (128, True)])
+def test_lfm2_stage_program_compiles_for_v5e(span, last_only, on_chip):
+    """`lfm2.extract-batch` at its real size: twelve blocks at the published
+    widths in seven runs (two dense convolution blocks, then attention and
+    routed convolution blocks), all 32 experts held, 128 rows, the 1,024
+    bucket; a decode step and one span of the prefill. The resident bytes
+    (8.13 GB of weights with the tied table held as embedding and as head,
+    1.61 GB of keys and values in THREE layers, 19 MB of tails in nine) and
+    the program's temporaries have to fit one chip's 16 GB."""
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry(LFM2_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    rows, max_len = 128, 1024
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: decode.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg),
+        runs=kind_runs(entry.family.FAMILY, cfg, stage)))
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=max_len,
+                          last_only=last_only).compile()
+    memory = compiled.memory_analysis()
+    print(f"lfm2 {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * (max_len * 12288 + 147456)
+    assert memory.alias_size_in_bytes > cache_bytes     # updated in place
+    # keys and values in the three attention layers only, no leaf padded:
+    # twelve layers' would be 6.4 GB
+    assert memory.argument_size_in_bytes < 8.14e9 + 1.05 * cache_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+
+
 def test_gpt2_medium_decode_step_keeps_cache_rows_as_rows(on_chip):
     """`gpt2-m.offline-batch`'s decode step at its real size (32 rows, 1,024
     positions, the 512 bucket, bfloat16; shapes from the loader): the chip
