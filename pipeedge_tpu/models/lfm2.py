@@ -41,6 +41,12 @@ PERF.md, PRs 27, 31, 33).
 decode-shaped stage program: the convolution takes its tail from the cache
 and leaves the span's last `K - 1` inputs there. A step is the span of one.
 
+**The expert layer at load.** All 32 experts are held and a batch job steps
+many rows at once, so a step's layer call gives every expert a group (16
+tokens at 128 rows) where the other sparse families' steps give a few
+experts one token: `parallel/expert.py::expert_tile` sizes the loop's tile
+from that group (32 rows), not from the call's rows.
+
 Refused by name: the forward path (`sublayer`), tp, sp and ep meshes, the
 int8 cache, `--kv-pages` (a page holds positions) and speculative verify (a
 rejected draft would need the tail of an earlier position).

@@ -251,11 +251,49 @@ def shard_moe_params(params: Dict, mesh: Mesh, axis: str = "ep") -> Dict:
 # absent experts would add; under an 'ep' axis each device passes its slab
 # and one psum adds the parts (`_ep_delta_from_routing`'s shape for top-1).
 
-# rows of one tile of the grouped product: the assignments are sorted by
-# expert, each expert's group is covered by whole tiles, and one loop step
-# multiplies one tile by its expert's three matrices. 256 rows of a 2,048 x
-# 768 expert are as many FLOPs as its weights are bytes on a v5e.
+# rows of one tile of the grouped product, at most: the assignments are
+# sorted by expert, each expert's group is covered by whole tiles, and one
+# loop step multiplies one tile by its expert's three matrices. How many
+# rows a call's tiles have is `expert_tile`'s to say; this is its cap (256
+# rows of a 2,048 x 768 expert are as many FLOPs in one bfloat16 pass as its
+# weights are bytes on a v5e).
 EXPERT_TILE = 256
+
+# standard deviations of a group's size that a tile leaves room for above
+# the mean, so that nearly every group is one tile
+GROUP_SPREAD = 3
+
+
+def expert_tile(tokens: int, per_tok: int, n_experts: int) -> int:
+    """Rows of one tile in a call whose `tokens` tokens go to `per_tok` of
+    `n_experts` experts each: the smallest multiple of 8 that holds what an
+    expert is given with room for its spread, at most `EXPERT_TILE`.
+
+    An expert's group is a binomial where the router is even: each token
+    picks it with probability `per_tok / n_experts` (whatever share of the
+    experts the caller holds), so it has `tokens * per_tok / n_experts`
+    assignments on average, `GROUP_SPREAD` deviations more at most, and
+    never more than the tokens.
+
+    The cost model (one tile of the loop timed on a v5e at 8 to 256 rows,
+    float32 rows over four bfloat16 expert shapes; PERF.md, PR 38): a tile
+    costs 14 to 27 us whatever it holds (its rows gathered, its matrices
+    sliced, its result written, the expert's bytes read below the chip's
+    peak rate), then the larger of its expert's bytes and its rows'
+    three-pass products, which meet near 64 rows. So a group's second tile
+    always costs more than the same rows in its first, and rows a tile has
+    beyond its group are products or, under 64 rows, nearly free: the
+    smallest tile that nearly every group fits whole is the cheapest, at
+    every load up to the cap. Groups above the cap take tiles of the cap:
+    the fixed cost of more tiles outweighs the half tile of padding a group
+    that a smaller tile would save (96 rows lost to 256 in every prefill
+    that has such groups, and a 96-row product costs 15 to 58% more a row
+    than a 256-row one)."""
+    share = per_tok / n_experts
+    mean = tokens * share
+    group = min(tokens, mean + GROUP_SPREAD * math.sqrt(mean * (1 - share)))
+    return min(EXPERT_TILE, -(-math.ceil(group) // 8) * 8)
+
 
 # tiles whose results are kept at a time, as a multiple of what the held
 # experts are given when the router spreads its choices evenly
@@ -326,6 +364,14 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     are then sliced one tile's matrices at a time and a whole layer's
     experts are never copied out of the stack.
 
+    The assignments are sorted by expert and each held expert's group is
+    covered by whole tiles of `expert_tile(tokens, k, experts)` rows, one
+    loop step a tile. The tile follows what an expert is given in this call
+    (a step of 128 rows, top-4 of 32: groups of 16, tiles of 32; a span of
+    4,096 tokens, top-8 of 128: groups of 256, tiles of 256); a row's
+    result does not depend on it, only how many rows of padding are
+    multiplied beside that row.
+
     Returns (delta [B, S, D], stats float32 [len(MOE_STATS)])."""
     b, s, d = normed.shape
     tokens = normed.reshape(-1, d)
@@ -337,7 +383,7 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     local = jnp.where(mine, local, count)                   # others sort last
     n_assign = t * k
 
-    tile = min(EXPERT_TILE, -(-t // 8) * 8)
+    tile = expert_tile(t, k, cfg.n_experts)
     # an expert is given a token at most once, so a group has at most
     # ceil(t / tile) tiles; all groups together have at most one tile a
     # group more than the assignments fill, and no more than assignments

@@ -250,8 +250,12 @@ def test_counters_of_one_batch_are_what_its_sizes_predict(tiny):
     assert gained["sparse_scored", "decode"] == rows * layers \
         * sum(range(25, 32))
     assert gained["sparse_kept", "decode"] == rows * layers * steps * topk
-    for phase, tile in (("prefill", 16), ("decode", 8)):
-        # every touched expert's group is one tile at these sizes
+    experts = _config()["num_experts"]
+    for phase, tokens in (("prefill", rows * 8), ("decode", rows)):
+        # a prefill in spans of 8: every touched expert's group is one tile
+        # of the rule's at these sizes
+        tile = expert.expert_tile(tokens, per_tok, experts)
+        assert tile == -(-tokens // 8) * 8
         assert gained["moe_rows_computed", phase] \
             == tile * gained["moe_experts_touched", phase]
         assert gained["moe_experts_touched", phase] \

@@ -311,10 +311,16 @@ def test_skewed_routing_takes_more_rounds_and_drops_nothing(slack,
     params = dict(params, router={
         "w": params["router"]["w"],
         "bias": jnp.asarray([9.0] + [0.0] * 7, jnp.float32)})
-    # 1,200 tokens: five tiles of 256 for the one expert; at a slack of 1
-    # a round keeps three
+    # 1,200 tokens, all of them the one expert's: a group of as many tiles
+    # as the rule's tile for an even router's 300 goes into 1,200; a round
+    # keeps what the slack allows an even router and one tile a held expert
     x = jnp.asarray(np.random.default_rng(7).normal(size=(2, 600, 32)),
                     jnp.float32)
+    tile = expert.expert_tile(1200, cfg.num_experts_per_tok, cfg.n_experts)
+    tiles = -(-1200 // tile)
+    a_round = -(-slack * 1200 * cfg.num_experts_per_tok
+                // (cfg.n_experts * tile)) + 1
+    assert (a_round < tiles) == (slack == 1) and tiles > 1
     wanted, _ = expert.topk_ffn_delta(params, x, cfg)
     total = 0.0
     for first, count in ((0, 1), (1, 7)):
@@ -326,11 +332,100 @@ def test_skewed_routing_takes_more_rounds_and_drops_nothing(slack,
         delta, counts = expert.topk_ffn_delta(
             mine, x, dataclasses.replace(cfg, held_experts=(first, count)))
         if first == 0:
-            assert counts[0] == 1200 and counts[1] == 5 * 256
+            assert counts[0] == 1200 and counts[1] == tiles * tile
         total = total + delta
     np.testing.assert_allclose(total, wanted, atol=1e-4)
     np.testing.assert_allclose(wanted[:, :3], _plain_layer(
         cfg, params, x)[:, :3], atol=1e-4)
+
+
+# (tokens, top-k, experts) -> the tile: the eight layer calls of the four
+# sparse cells (a step and a prefill span each), then the edges
+TILES = {
+    "lfm2-step": (128, 4, 32, 32), "lfm2-span": (16384, 4, 32, 256),
+    "keye-step": (8, 8, 128, 8), "keye-span": (4096, 8, 128, 256),
+    "qwen3-next-step": (8, 10, 512, 8),
+    "qwen3-next-span": (8192, 10, 512, 200),
+    "kimi-step": (32, 8, 384, 8), "kimi-span": (4096, 8, 384, 120),
+    "fewer-than-8-tokens": (3, 2, 8, 8), "one-token": (1, 8, 384, 8),
+    "mean-under-1": (64, 1, 1024, 8), "every-expert": (40, 8, 8, 40),
+    "one-expert": (1200, 1, 1, 256), "tiny-span": (16, 2, 8, 16),
+}
+
+
+@pytest.mark.parametrize("case", list(TILES))
+def test_a_tile_holds_a_group_and_follows_the_load(case):
+    tokens, per_tok, experts, wanted = TILES[case]
+    tile = expert.expert_tile(tokens, per_tok, experts)
+    assert tile == wanted
+    assert tile % 8 == 0 and 8 <= tile <= expert.EXPERT_TILE
+    assert tile <= -(-tokens // 8) * 8
+    # the mean group fits, or the tile is the cap
+    assert tile >= min(tokens * per_tok / experts, expert.EXPERT_TILE)
+    # more tokens, more choices a token or fewer experts: never a smaller
+    # tile
+    for more in (1, 2, 3, 5, 8, 64):
+        grown = tokens * more + more - 1
+        assert expert.expert_tile(grown, per_tok, experts) >= tile
+        assert expert.expert_tile(
+            tokens, min(per_tok + more, experts), experts) >= tile
+        assert expert.expert_tile(
+            tokens, per_tok, max(experts // (more + 1), per_tok)) >= tile
+
+
+def _skewed_layer():
+    """100 tokens over the tiny layer's eight experts, the bias so skewed
+    that expert 0 is given every token, expert 7 most, the others a few:
+    groups of three tiles, two and one."""
+    cfg, params, _ = _expert_layer()
+    params = dict(params, router={
+        "w": params["router"]["w"] * 0.25,
+        "bias": jnp.asarray([9.0] + [0.0] * 6 + [0.2], jnp.float32)})
+    x = jnp.asarray(np.random.default_rng(11).normal(size=(4, 25, 32)),
+                    jnp.float32)
+    chosen, _ = reference.route(
+        x.reshape(-1, 32), params["router"]["w"].T, params["router"]["bias"],
+        cfg.num_experts_per_tok, cfg.routed_scaling_factor)
+    sizes = np.bincount(np.asarray(chosen).reshape(-1), minlength=8)
+    return cfg, params, x, sizes
+
+
+@pytest.mark.parametrize("how", ["whole", "shares", "layer"])
+def test_groups_of_one_two_and_three_tiles_match_the_plain_layer(how):
+    cfg, params, x, sizes = _skewed_layer()
+    tile = expert.expert_tile(100, cfg.num_experts_per_tok, cfg.n_experts)
+    tiles = -(-sizes // tile)
+    assert set(tiles.tolist()) >= {1, 2, 3}
+    # the last group's last tile runs past the last assignment
+    assert sizes[7] % tile and sizes.sum() == 200
+    wanted = _plain_layer(cfg, params, x)
+    if how == "shares":
+        total = 0.0
+        for first, count in ((0, 3), (3, 5)):
+            mine = {"router": params["router"], "experts": {
+                name: leaf[first:first + count]
+                for name, leaf in params["experts"].items()}}
+            if first == 0:
+                mine["shared"] = params["shared"]
+            delta, counts = expert.topk_ffn_delta(
+                mine, x, cfg, held=(first, count))
+            held = slice(first, first + count)
+            assert counts.tolist() == [sizes[held].sum(),
+                                       tiles[held].sum() * tile,
+                                       (sizes[held] > 0).sum()]
+            total = total + delta
+        np.testing.assert_allclose(total, wanted, atol=1e-5)
+        return
+    layer = None
+    if how == "layer":      # the stacked blocks' leaves, this block the 2nd
+        params = dict(params, experts={
+            name: jnp.stack([jnp.full_like(leaf, jnp.nan), leaf])
+            for name, leaf in params["experts"].items()})
+        layer = jnp.int32(1)
+    delta, counts = jax.jit(lambda p, y, at: expert.topk_ffn_delta(
+        p, y, cfg, layer=at))(params, x, layer)
+    np.testing.assert_allclose(delta, wanted, atol=1e-5)
+    assert counts.tolist() == [200, tiles.sum() * tile, (sizes > 0).sum()]
 
 
 def _counters():

@@ -70,7 +70,8 @@ def tiny(tmp_path_factory):
 
 # float32 program against float32 reference: they differ by the order of
 # their sums (a span's convolution against the whole row's, the experts'
-# tiles against an expert at a time; 1.1e-7 of the logits' range measured);
+# tiles, whose rows follow the call's load (`expert.expert_tile`), against an
+# expert at a time; 1.1e-7 of the logits' range measured);
 # 1e-5 leaves room for another BLAS and would fail a bfloat16 product or a
 # bfloat16 tail (2e-3) two hundred times over
 TOLERANCE = 1e-5
@@ -404,7 +405,9 @@ def test_the_router_is_the_biased_top_k_of_the_sigmoids(case):
         np.testing.assert_allclose(weight, gates, rtol=2e-5)
 
 
-def test_the_expert_layer_is_every_chosen_expert_and_no_shared_one():
+def _expert_layer(rows):
+    """(cfg, params, x [rows, 5, D]) of the tiny layer in float32; the
+    router in antithetic pairs as the benchmark's scheme draws it."""
     cfg = registry.get_model_config(TINY)
     rng = np.random.default_rng(2)
     d, f, e = cfg.hidden_size, cfg.moe_intermediate_size, cfg.n_experts
@@ -412,24 +415,59 @@ def test_the_expert_layer_is_every_chosen_expert_and_no_shared_one():
     def mat(*shape):
         return jnp.asarray(rng.normal(0, 0.3, size=shape), jnp.float32)
 
-    params = {"router": {"w": mat(d, e), "bias": mat(e) * 0.1},
+    half = mat(d, e // 2)
+    params = {"router": {"w": jnp.stack([half, -half], 2).reshape(d, e),
+                         "bias": mat(e) * 0.1},
               "experts": {"gate": mat(e, f, d), "up": mat(e, f, d),
                           "down": mat(e, d, f)}}
-    x = mat(2, 5, d)
-    delta, stats = expert.topk_ffn_delta(params, x, cfg)
+    return cfg, params, mat(rows, 5, d)
+
+
+def _plain_experts(params, x):
+    """Every token through each of its chosen experts in turn, as the
+    reference has the layer -> (delta like x, the experts chosen)."""
+    d = x.shape[-1]
     tokens = x.reshape(-1, d)
     experts, gates = reference.route(tokens, params["router"]["w"].T,
                                      params["router"]["bias"], 2, 1.0)
-    wanted = np.zeros((10, d), np.float32)
-    for t in range(10):
+    wanted = np.zeros(tokens.shape, np.float32)
+    for t in range(tokens.shape[0]):
         for one, gate in zip(np.asarray(experts[t]), np.asarray(gates[t])):
             # the reference's SwiGLU takes (w1, w2, w3) = (gate, down, up)
             wanted[t] += gate * np.asarray(reference._swiglu(
                 tokens[t:t + 1], params["experts"]["gate"][one],
                 params["experts"]["down"][one],
                 params["experts"]["up"][one]))[0]
-    np.testing.assert_allclose(delta.reshape(10, d), wanted, atol=1e-5)
+    return wanted.reshape(x.shape), np.asarray(experts)
+
+
+def test_the_expert_layer_is_every_chosen_expert_and_no_shared_one():
+    cfg, params, x = _expert_layer(2)
+    delta, stats = expert.topk_ffn_delta(params, x, cfg)
+    wanted, _ = _plain_experts(params, x)
+    np.testing.assert_allclose(delta, wanted, atol=1e-5)
     assert stats[0] == 10 * 2
+
+
+@pytest.mark.parametrize("activations", ["float32", "bfloat16"])
+def test_at_load_an_expert_takes_one_tile_that_follows_its_group(activations):
+    """The cell's step in small: 65 tokens, top-2 of 8, so 16 an expert
+    where the call's tokens rounded to 8 are 72. The tile holds a group
+    with room and no more, whatever the activations are stored in."""
+    cfg, params, x = _expert_layer(13)
+    tile = expert.expert_tile(65, cfg.num_experts_per_tok, cfg.n_experts)
+    assert tile == 32
+    wanted, chosen = _plain_experts(params, x)
+    sizes = np.bincount(chosen.reshape(-1), minlength=cfg.n_experts)
+    assert 0 < sizes.min() and sizes.max() <= tile
+    delta, stats = expert.topk_ffn_delta(params, x.astype(activations), cfg)
+    assert delta.dtype == activations
+    if activations == "float32":
+        np.testing.assert_allclose(delta, wanted, atol=1e-5)
+        assert stats.tolist() == [130, cfg.n_experts * tile, cfg.n_experts]
+    else:       # a bfloat16 router may choose otherwise; the tile is the same
+        assert stats[0] == 130 and stats[1] % tile == 0
+        assert stats[1] <= (cfg.n_experts + 1) * tile
 
 
 # -- a cache leaf that runs of two kinds own -------------------------------------
