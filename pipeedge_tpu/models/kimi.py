@@ -70,7 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import ShardConfig
+from . import ShardConfig, layers
 from .keye import _experts
 from .layers import (TransformerConfig, exact_dot, exact_einsum, rms_norm,
                      rope_frequencies)
@@ -107,25 +107,12 @@ def cache_leaves(cfg: TransformerConfig) -> Dict:
 
 
 def yarn_frequencies(cfg: TransformerConfig) -> np.ndarray:
-    """The rotation's `qk_rope_head_dim / 2` frequencies under YaRN: those
-    that turn more than `beta_fast` times in the original context stay,
-    those that turn fewer than `beta_slow` times are divided by the factor,
-    a linear ramp between."""
-    dim = cfg.qk_rope_head_dim
-    freqs = rope_frequencies(dim, cfg.rope_theta)
+    """The rotation's `qk_rope_head_dim / 2` frequencies, under YaRN where
+    the model says (`layers.yarn_frequencies`)."""
     if not cfg.rope_yarn:
-        return freqs
-    factor, original, fast, slow = cfg.rope_yarn[:4]
-
-    def correction(turns):
-        return dim * math.log(original / (turns * 2 * math.pi)) \
-            / (2 * math.log(cfg.rope_theta))
-
-    low = max(math.floor(correction(fast)), 0)
-    high = min(math.ceil(correction(slow)), dim - 1)
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
-                   / ((high if high != low else high + 0.001) - low), 0, 1)
-    return (freqs / factor * ramp + freqs * (1 - ramp)).astype(np.float32)
+        return rope_frequencies(cfg.qk_rope_head_dim, cfg.rope_theta)
+    return layers.yarn_frequencies(cfg.qk_rope_head_dim, cfg.rope_theta,
+                                   *cfg.rope_yarn[:4])
 
 
 def attention_scale(cfg: TransformerConfig) -> float:

@@ -1,0 +1,412 @@
+"""Laguna (`model_type` laguna): grouped-query attention layers of two kinds,
+a few that attend every position and most that attend the last
+`sliding_window`, with different numbers of query heads and different
+rotations; a leading dense SwiGLU FFN, then many small experts routed by a
+softmax beside a gated shared one.
+
+The block, `x` [B, S, D], plain RMSNorm, no bias anywhere:
+  h = x + Attn(rms(x; input_layernorm));
+  x' = h + FFN(rms(h; post_attention_layernorm))
+`cfg.layer_types[i]` names block i's attention ("full_attention" |
+"sliding_attention"), `cfg.layer_heads[i]` its query heads, and the first
+`cfg.first_k_dense` blocks have the dense FFN. A block's kind is both,
+`<mixer>_<ffn>` (`block_kind`): the attention decides the shapes of `q`,
+`gate` and `attn_out` and which cache leaves the block owns, the FFN its
+other leaves, so which blocks stack into one run (models/shard.py
+`BlockRuns`).
+
+**Attention**, both kinds, `H` the block's own query heads (read off its
+`q` leaf, and held to the configuration's count for its kind): `q` (D -> H x
+Dh), `k`, `v` (D -> G x Dh); q and k RMS-normed a head; rotated; causal
+softmax at `Dh**-0.5`, `H / G` query heads a KV head; each head's output
+times `sigmoid(g_proj u)`, one gate a head, before `o_proj`.
+- full: every position below and the query's own. The first
+  `partial_rotary_factor` of a head's lanes turn, by YaRN's frequencies
+  (`layers.yarn_frequencies`), cosine and sine times the attention factor.
+- sliding: query `q` attends `q - W < p <= q`. The whole head turns, plain,
+  base `cfg.sliding_rope_theta`.
+
+**Cache: two lengths in one stage** (`cache_leaves`, models/shard.py
+`CacheLeaf`). The full blocks own `k`, `v` `[L_full, B, T, G*Dh]`, a row a
+position up to the stage's `max_len`, read as the ladder's window
+(`attend_bucket`). The sliding blocks own `k_ring`, `v_ring` `[L_sliding, B,
+W, G*Dh]`, a RING of the last `W` positions (parallel/decode.py, "A ring"):
+written at `pos mod W`, read whole at every position, masked by what each
+slot holds. At 32 rows x 8,192 positions in float32 the three window layers
+of the cell's cut keep 0.40 GB where rows a position would be 6.4 GB.
+
+**Precision.** Weights as stored (bfloat16); activations and cache float32:
+products with weights through `exact_dot`, the attention's products of two
+activations at `HIGHEST` (q and k are normed, so scores are of order 1, and
+the router's top-8 of 256 after them is a discrete choice that a narrower
+computation makes differently from the float32 reference: PERF.md, PRs 27,
+31, 33).
+
+**Prefill** runs in spans of `cfg.prefill_chunk` positions through the
+decode-shaped stage program: a span's window layers attend their ring and
+the span's own rows, not the ladder's width. A step is the span of one.
+
+Refused by name: the forward path (`sublayer`), tp, sp and ep meshes, the
+int8 cache, `--kv-pages` (a page holds positions of one length) and
+speculative verify (a rejected draft's rows have overwritten slots of the
+ring).
+
+Weight format: Qwen3-MoE's state dict with a gate (`model.layers.N.
+{input_layernorm,post_attention_layernorm}.weight`, `.self_attn.{q_proj,
+k_proj,v_proj,o_proj,g_proj}.weight`, `.self_attn.{q_norm,k_norm}.weight`,
+`.mlp.{gate_proj,up_proj,down_proj}.weight` in the dense layer, `.mlp.gate.
+weight`, `.mlp.experts.E.*`, `.mlp.shared_expert.*`, `.mlp.
+shared_expert_gate.weight` in an expert layer; `model.embed_tokens`,
+`model.norm`, `lm_head`, untied).
+"""
+from __future__ import annotations
+
+from typing import Dict, Mapping
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from . import ShardConfig
+from .keye import _experts
+from .kimi import _dense_ffn, _in_row_chunks, _lin, _on_device, _stack
+from .layers import (TransformerConfig, rms_norm, rotate_halves,
+                     rope_frequencies, yarn_frequencies)
+from .shard import CacheLeaf, FamilySpec, build_shard_params
+
+# what a block step counts into the cache's `stats` leaf, in this order
+STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
+         "moe_layer_calls", "swa_positions_read", "swa_positions_live",
+         "swa_ring_wraps")
+
+# activations and cache (module docstring, Precision)
+ACTIVATIONS = jnp.float32
+_ATTENTION = jax.lax.Precision.HIGHEST
+
+# bytes of float32 attention scores one chunk of queries may hold (one KV
+# group's at a time)
+_SCORE_BYTES = 1 << 29
+
+_MIXERS = {"full_attention": "full", "sliding_attention": "sliding"}
+_FFNS = ("dense", "routed")
+
+
+def prefill_span(cfg: TransformerConfig) -> int:
+    return cfg.prefill_chunk
+
+
+def block_kind(cfg: TransformerConfig, block_id: int) -> str:
+    """`<mixer>_<ffn>`: full | sliding, dense | routed."""
+    return _MIXERS[cfg.layer_types[block_id]] + "_" \
+        + _FFNS[block_id >= cfg.first_k_dense]
+
+
+def heads_of(cfg: TransformerConfig, mixer: str) -> int:
+    """The query heads of the blocks whose attention is `mixer` ("full" |
+    "sliding"): one count a kind."""
+    counts = {heads for kind, heads in zip(cfg.layer_types, cfg.layer_heads)
+              if _MIXERS[kind] == mixer}
+    if len(counts) != 1:
+        raise ValueError(f"the {mixer} layers have query heads {counts}: "
+                         "one count a kind of layer")
+    return counts.pop()
+
+
+def cache_leaves(cfg: TransformerConfig) -> Dict:
+    """The cache's leaves (module docstring, Cache): what follows `[L, B,
+    T]` in the full blocks' `k`, `v` and `[L, B, W]` in the sliding blocks'
+    rings, each owned by its mixer's blocks of either FFN."""
+    def rows(mixer, length=0):
+        return CacheLeaf((cfg.kv_heads * cfg.head_dim,), ACTIVATIONS,
+                         tuple(f"{mixer}_{ffn}" for ffn in _FFNS),
+                         length=length)
+
+    return {"k": rows("full"), "v": rows("full"),
+            "k_ring": rows("sliding", cfg.sliding_window),
+            "v_ring": rows("sliding", cfg.sliding_window),
+            "stats": jax.ShapeDtypeStruct((len(STATS),), jnp.int32)}
+
+
+def full_frequencies(cfg: TransformerConfig) -> np.ndarray:
+    """The full layers' frequencies: YaRN over the lanes that turn."""
+    turned = int(cfg.head_dim * cfg.partial_rotary_factor)
+    if not cfg.rope_yarn:
+        return rope_frequencies(turned, cfg.rope_theta)
+    return yarn_frequencies(turned, cfg.rope_theta, *cfg.rope_yarn[:4])
+
+
+def rotate(x: jax.Array, pos: jax.Array, cfg: TransformerConfig,
+           sliding: bool) -> jax.Array:
+    """x [B, S, H, Dh] at positions `pos` [S], by its layer's scheme (module
+    docstring): halves layout in both."""
+    if sliding:
+        return rotate_halves(x, pos, rope_frequencies(
+            x.shape[-1], cfg.sliding_rope_theta))
+    freqs = full_frequencies(cfg)
+    head, rest = jnp.split(x, [2 * len(freqs)], axis=-1)
+    scale = cfg.rope_yarn[4] if cfg.rope_yarn else 1.0
+    return jnp.concatenate([rotate_halves(head, pos, freqs, scale), rest],
+                           axis=-1)
+
+
+def _attend_group(q, ks, vs, keeps):
+    """Context [B, Q, r, Dh] of ONE KV group's queries q [B, Q, r, Dh] over
+    key parts: `ks` and `vs` that group's [B, K, Dh] a part, `keeps` a [Q, K]
+    a part. One softmax over all parts."""
+    hd = q.shape[-1]
+    scores = [jnp.where(keep[None, None], jnp.einsum(
+        "bqrd,bkd->brqk", q, k.astype(q.dtype),
+        preferred_element_type=jnp.float32, precision=_ATTENTION)
+        * hd ** -0.5, -1e30) for k, keep in zip(ks, keeps)]
+    top = jnp.max(jnp.concatenate(
+        [jnp.max(sc, axis=-1, keepdims=True) for sc in scores], -1),
+        axis=-1, keepdims=True)
+    # the weights are divided by their sum after they have met the values:
+    # one pass over [.., Q, Dh] and not one over [.., Q, K]
+    probs = [jnp.exp(sc - top) for sc in scores]
+    total = sum(jnp.sum(pr, axis=-1) for pr in probs)           # [B, r, Q]
+    mixed = sum(jnp.einsum(
+        "brqk,bkd->bqrd", pr.astype(q.dtype), v.astype(q.dtype),
+        preferred_element_type=jnp.float32, precision=_ATTENTION)
+        for pr, v in zip(probs, vs))
+    return (mixed / jnp.moveaxis(total, 1, 2)[..., None]).astype(q.dtype)
+
+
+def attend(q, ks, vs, keeps) -> jax.Array:
+    """Grouped-query attention of q [B, Q, H, Dh] over key parts as
+    `decode._cache_update_and_read` hands them with `unread`: a cached
+    window as a `Window`, the call's rows `[B, S, G, Dh]`; `keeps` a [Q, K] a
+    part. A KV group at a time: its lanes of the window are read when the
+    group before is done (each read waits for that group's context, through
+    an `optimization_barrier`), so one group's slices of a long window are
+    live at a time and not all of them, which a span's loop over chunks of
+    queries would otherwise hold from its start (2.1 GB a full layer in the
+    cell); within a group the queries in chunks whose scores stay under
+    `_SCORE_BYTES`. -> [B, Q, H, Dh]."""
+    from ..parallel.decode import Window, _read_window
+
+    b, n_q, h, hd = q.shape
+    groups = ks[-1].shape[2]
+    n_keys = sum(keep.shape[1] for keep in keeps)
+    chunk = n_q
+    while chunk > 1 and chunk % 2 == 0 and \
+            b * (h // groups) * chunk * n_keys * 4 > _SCORE_BYTES:
+        chunk //= 2
+    n = n_q // chunk
+    q = q.reshape(b, n_q, groups, h // groups, hd)
+    out, done = [], 0
+    for grp in range(groups):
+        def mine(part, grp=grp, done=done):
+            if isinstance(part, Window):
+                return _read_window(part.buf, part.layer + done, part.width,
+                                    slice(grp * hd, (grp + 1) * hd)
+                                    ).astype(q.dtype)
+            return part[:, :, grp]
+
+        k_g, v_g = [mine(k) for k in ks], [mine(v) for v in vs]
+        if n == 1:
+            ctx = _attend_group(q[:, :, grp], k_g, v_g, keeps)
+        else:
+            ctx = jax.lax.map(
+                lambda xs, k_g=k_g, v_g=v_g: _attend_group(xs[0], k_g, v_g,
+                                                           xs[1]),
+                (jnp.moveaxis(q[:, :, grp].reshape(b, n, chunk, -1, hd), 1,
+                              0),
+                 tuple(keep.reshape(n, chunk, -1) for keep in keeps)))
+            ctx = jnp.moveaxis(ctx, 0, 1).reshape(b, n_q, -1, hd)
+        # `done` is zero, and known only when this group's context is
+        ctx, done = jax.lax.optimization_barrier((ctx, jnp.int32(0)))
+        out.append(ctx)
+    return jnp.stack(out, axis=2).reshape(b, n_q, h, hd)
+
+
+def attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
+              prefill: bool, read_len=None):
+    """Gated GQA of `normed` [B, S, D] at [pos, pos + S) over what its
+    layer's leaves hold below `pos` and its own rows. -> (out, the cache
+    with the rows recorded, counts int32 [3]: the window layers'
+    `swa_positions_read`, `swa_positions_live`, `swa_ring_wraps`)."""
+    from ..parallel.decode import _cache_update_and_read
+
+    b, s, _ = normed.shape
+    eps, hd, groups = cfg.layer_norm_eps, cfg.head_dim, cfg.kv_heads
+    sliding = "k_ring" in bcache.stack
+    heads = heads_of(cfg, "sliding" if sliding else "full")
+    if p["q"]["w"].shape[0] != heads * hd or p["gate"]["w"].shape[0] != heads:
+        raise ValueError(
+            f"a {'sliding' if sliding else 'full'} block of {heads} query "
+            f"heads of {hd} was built with q_proj {p['q']['w'].shape} and "
+            f"g_proj {p['gate']['w'].shape}")
+    q_pos = jnp.asarray(pos) + jnp.arange(s)
+    q = _in_row_chunks(lambda rows: _lin(p["q"]["w"], rows), normed,
+                       heads * hd).reshape(b, s, heads, hd)
+    k = _lin(p["k"]["w"], normed).reshape(b, s, groups, hd)
+    v = _lin(p["v"]["w"], normed).reshape(b, s, groups, hd)
+    gate = jax.nn.sigmoid(_lin(p["gate"]["w"], normed))        # [B, S, H]
+    q = rotate(rms_norm(p["q_norm"], q, eps), q_pos, cfg, sliding)
+    k = rotate(rms_norm(p["k_norm"], k, eps), q_pos, cfg, sliding)
+    leaves = dict(window=cfg.sliding_window, names=("k_ring", "v_ring"),
+                  ring=True) if sliding else dict(read_len=read_len)
+    ks, vs, keeps, bcache = _cache_update_and_read(
+        bcache, k, v, pos, prefill, s, normed.dtype, unread=True, **leaves)
+    counts = jnp.zeros(3, jnp.int32)
+    if sliding:
+        ring = bcache.stack["k_ring"].shape[2]
+        counts = jnp.stack([
+            jnp.int32(b * sum(keep.size for keep in keeps)),
+            b * sum(jnp.sum(keep, dtype=jnp.int32) for keep in keeps),
+            (jnp.asarray(pos) % ring + s > ring).astype(jnp.int32)])
+    ctx = attend(q, ks, vs, keeps) * gate[..., None]
+    return _lin(p["attn_out"]["w"], ctx.reshape(b, s, heads * hd)), \
+        bcache, counts
+
+
+# -- the family's hooks --------------------------------------------------------
+
+def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
+    """Token embedding [B, K] -> [B, K, D]: positions live in the rotation."""
+    return jnp.take(pe["wte"], tok, axis=0).astype(ACTIVATIONS)
+
+
+def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    return span_embed(p, input_ids, 0)
+
+
+def decode_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
+    return span_embed(pe, tok.reshape(-1, 1), pos)
+
+
+def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
+             attention_fn=None):
+    raise NotImplementedError(
+        "the laguna family runs through the cached decode path only: its "
+        "blocks come in runs of up to four kinds, which the forward path "
+        "(models/shard.py shard_apply) does not scan yet")
+
+
+def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    """Final RMSNorm + the untied head -> logits."""
+    return _lin(p["head"]["w"], rms_norm(p["ln"], hidden,
+                                         cfg.layer_norm_eps))
+
+
+def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
+                      prefill: bool, read_len=None):
+    """Cached block (parallel/decode.py's `_block_step` contract) of any of
+    the kinds. The rows of `x` sit at [pos, pos + S): a full block attends
+    the cached window below `pos`, a sliding block its ring, both their own
+    rows, and their keys and values are recorded for `_write_rows`."""
+    eps = cfg.layer_norm_eps
+    mixed, bcache, counts = attention(
+        p, rms_norm(p["ln_before"], x, eps), bcache, pos, cfg, prefill,
+        read_len)
+    h = x + mixed
+    normed = rms_norm(p["ln_after"], h, eps)
+    if "router" in p:
+        delta, moe = _experts(p, normed, cfg)
+        moe = jnp.concatenate([moe.astype(jnp.int32), jnp.ones(1, jnp.int32)])
+    else:
+        delta, moe = _dense_ffn(p["mlp"], normed), jnp.zeros(4, jnp.int32)
+    return h + delta, bcache._replace(
+        rows=dict(bcache.rows, stats=jnp.concatenate([moe, counts])))
+
+
+FAMILY = FamilySpec(name="laguna", embed=embed, sublayer=sublayer,
+                    finalize=finalize, cached_block_step=cached_block_step,
+                    decode_embed=decode_embed, span_embed=span_embed,
+                    position_dependent_attention=True,
+                    cache_leaves=cache_leaves, prefill_span=prefill_span,
+                    whole_leaves=("experts",), stats_names=STATS,
+                    block_kind=block_kind)
+
+
+# -- loading -------------------------------------------------------------------
+
+def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
+              dtype) -> Dict:
+    """Shard params from `get(key, shape)`, a tensor of the published
+    scheme (models/kimi.py `_assemble`: host leaves until a run is stacked;
+    traced values pass through, for `jax.eval_shape`)."""
+    d, groups, hd = cfg.hidden_size, cfg.kv_heads, cfg.head_dim
+    first, count = cfg.held_experts or (0, cfg.n_experts)
+
+    def scale(key, n):
+        return {"scale": get(key, (n,))}
+
+    def mlp(root, width):
+        return {"gate": get(root + "gate_proj.weight", (width, d)),
+                "up": get(root + "up_proj.weight", (width, d)),
+                "down": get(root + "down_proj.weight", (d, width))}
+
+    def get_embed() -> Dict:
+        return {"wte": get("model.embed_tokens.weight",
+                           (cfg.vocab_size, d))}
+
+    def get_block(block_id: int, subs: tuple) -> Dict:
+        if subs != (0, 1, 2, 3):
+            raise NotImplementedError(
+                "the laguna family takes whole blocks: a partition that "
+                "cuts one is for the forward path, which it does not run")
+        root = f"model.layers.{block_id}."
+        att = root + "self_attn."
+        heads = cfg.layer_heads[block_id]
+        p = {"ln_before": scale(root + "input_layernorm.weight", d),
+             "q": {"w": get(att + "q_proj.weight", (heads * hd, d))},
+             "k": {"w": get(att + "k_proj.weight", (groups * hd, d))},
+             "v": {"w": get(att + "v_proj.weight", (groups * hd, d))},
+             "gate": {"w": get(att + "g_proj.weight", (heads, d))},
+             "q_norm": scale(att + "q_norm.weight", hd),
+             "k_norm": scale(att + "k_norm.weight", hd),
+             "attn_out": {"w": get(att + "o_proj.weight", (d, heads * hd))},
+             "ln_after": scale(root + "post_attention_layernorm.weight", d)}
+        if block_id < cfg.first_k_dense:
+            p["mlp"] = mlp(root + "mlp.", cfg.intermediate_size)
+            return p
+        p["router"] = {"w": get(root + "mlp.gate.weight",
+                                (cfg.n_experts, d)).T}
+        held = [mlp(f"{root}mlp.experts.{e}.", cfg.moe_intermediate_size)
+                for e in range(first, first + count)]
+        p["experts"] = {name: _stack([one[name] for one in held])
+                        for name in ("gate", "up", "down")}
+        p["shared"] = mlp(root + "mlp.shared_expert.",
+                          cfg.moe_intermediate_size * cfg.n_shared_experts)
+        p["shared_gate"] = get(root + "mlp.shared_expert_gate.weight",
+                               (1, d))
+        return p
+
+    def get_final() -> Dict:
+        return {"ln": scale("model.norm.weight", d),
+                "head": {"w": get("lm_head.weight", (cfg.vocab_size, d))}}
+
+    return _on_device(build_shard_params(
+        shard_config, get_embed, get_block, get_final,
+        stack=lambda blocks: jax.tree_util.tree_map(
+            lambda *leaves: _stack(leaves), *blocks),
+        kind=lambda block_id: block_kind(cfg, block_id)), dtype)
+
+
+def load_params(cfg: TransformerConfig, shard_config: ShardConfig,
+                weights: Mapping, dtype=jnp.float32) -> Dict:
+    """Shard params from a published-style state-dict npz (module
+    docstring). A sliced vocabulary is the table's first rows."""
+    def get(key, shape):
+        value = np.asarray(weights[key])
+        if key in ("model.embed_tokens.weight", "lm_head.weight"):
+            value = value[:shape[0]]
+        if value.shape != shape:
+            raise ValueError(f"{key}: {value.shape} in the file, {shape} "
+                             "in the model")
+        return value
+    return _assemble(cfg, shard_config, get, dtype)
+
+
+def init_params(cfg: TransformerConfig, shard_config: ShardConfig,
+                seed: int = 0, dtype=jnp.float32) -> Dict:
+    """Random shard params with the same pytree structure as `load_params`."""
+    rng = np.random.default_rng(seed)
+
+    def get(key, shape):
+        if key.endswith(("norm.weight", "layernorm.weight")):
+            return np.ones(shape, np.float32)
+        return rng.normal(0, 0.02, size=shape).astype(np.float32)
+    return _assemble(cfg, shard_config, get, dtype)

@@ -95,7 +95,9 @@ class TransformerConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     # YaRN: (factor, original_max_position_embeddings, beta_fast,
-    # beta_slow, mscale, mscale_all_dim); () = plain rotation
+    # beta_slow, mscale, mscale_all_dim); () = plain rotation. The laguna
+    # family's fifth entry is the factor its cosine and sine carry
+    # (`attention_factor`), and it has no sixth
     rope_yarn: tuple = ()
     # positions a prompt is prefilled at a time where the family prefills
     # in spans and no other field says (keye's is `index_q_chunk`)
@@ -121,6 +123,13 @@ class TransformerConfig:
     # short convolution spans
     layer_types: tuple = ()
     conv_kernel: int = 0
+    # laguna family ("full_attention" | "sliding_attention" in
+    # `layer_types`): the query heads of each block where the kinds of
+    # layer differ in them, and the rotation's base in the window layers
+    # (plain rotation of the whole head; `rope_theta`, `rope_yarn` and
+    # `partial_rotary_factor` are the full layers')
+    layer_heads: tuple = ()
+    sliding_rope_theta: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -364,18 +373,50 @@ def rope_frequencies(head_dim: int, theta: float):
                             / head_dim))
 
 
-def rope_rotate(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding on [B, S, H, Dh] at positions `pos` [S]
+def yarn_frequencies(dim: int, theta: float, factor: float, original: int,
+                     beta_fast: float, beta_slow: float):
+    """The `dim / 2` frequencies of a rotation over `dim` lanes under YaRN
+    (the `transformers` library's computation, on the host): those that
+    turn more than `beta_fast` times in the `original` context stay, those
+    that turn fewer than `beta_slow` times are divided by `factor`, a
+    linear ramp between."""
+    import math
+
+    import numpy as np
+    freqs = rope_frequencies(dim, theta)
+
+    def correction(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / ((high if high != low else high + 0.001) - low), 0, 1)
+    return (freqs / factor * ramp + freqs * (1 - ramp)).astype(np.float32)
+
+
+def rotate_halves(x: jax.Array, pos: jax.Array, freqs,
+                  scale: float = 1.0) -> jax.Array:
+    """x [B, S, H, Dh] turned at positions `pos` [S] by the Dh/2 `freqs`
     (HF llama convention: half-split rotate, angles in float32, one
-    frequency per pair duplicated across the two halves)."""
-    angles = pos.astype(jnp.float32)[:, None] \
-        * rope_frequencies(x.shape[-1], theta)[None]             # [S, hd/2]
+    frequency per pair duplicated across the two halves); cosine and sine
+    times `scale` where a scheme (YaRN's attention factor) carries one."""
+    angles = pos.astype(jnp.float32)[:, None] * freqs[None]      # [S, hd/2]
     cos = jnp.concatenate([jnp.cos(angles)] * 2, axis=-1)        # [S, hd]
     sin = jnp.concatenate([jnp.sin(angles)] * 2, axis=-1)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     rotated = jnp.concatenate([-x2, x1], axis=-1)
     return (x * cos[None, :, None] + rotated
             * sin[None, :, None]).astype(x.dtype)
+
+
+def rope_rotate(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+    """Rotary position embedding on [B, S, H, Dh] at positions `pos` [S]:
+    `rotate_halves` by the plain frequencies of base `theta`."""
+    return rotate_halves(x, pos, rope_frequencies(x.shape[-1], theta))
 
 
 def causal_conv(kernel: jax.Array, x: jax.Array, tail: jax.Array):

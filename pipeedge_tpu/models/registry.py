@@ -28,6 +28,7 @@ from . import deit as deit_mod
 from . import gpt2 as gpt2_mod
 from . import keye as keye_mod
 from . import kimi as kimi_mod
+from . import laguna as laguna_mod
 from . import lfm2 as lfm2_mod
 from . import llama as llama_mod
 from . import qwen3_next as qwen3_next_mod
@@ -150,10 +151,35 @@ def _lfm2(name, weights, hidden, layer_types, heads, kv_heads, dense_width,
         layer_types=tuple(layer_types), conv_kernel=3, prefill_chunk=span))
 
 
-# LFM2's pattern of mixers, one letter a block: c a gated short convolution,
-# a grouped-query attention (no interval: the last attention comes early)
+def _laguna(name, weights, hidden, pattern, heads, kv_heads, head_dim,
+            window, dense_width, vocab, max_pos, experts, expert_width,
+            per_tok, yarn, sliding_theta, span, theta=500000.0):
+    full_heads, sliding_heads = heads
+    blocks = len(pattern)
+    return ModelEntry(name, 4 * blocks, weights, laguna_mod,
+                      TransformerConfig(
+        model_type="laguna", hidden_size=hidden, num_hidden_layers=blocks,
+        num_attention_heads=max(heads), num_kv_heads=kv_heads,
+        attn_head_dim=head_dim, intermediate_size=dense_width,
+        layer_norm_eps=1e-6, vocab_size=vocab,
+        max_position_embeddings=max_pos, rope_theta=theta,
+        rope_yarn=tuple(yarn), partial_rotary_factor=0.5,
+        sliding_rope_theta=sliding_theta, sliding_window=window,
+        qk_norm=True, n_experts=experts, moe_intermediate_size=expert_width,
+        num_experts_per_tok=per_tok, norm_topk_prob=True,
+        routed_scaling_factor=2.5, n_shared_experts=1, first_k_dense=1,
+        layer_types=_layer_types(pattern),
+        layer_heads=tuple({"f": full_heads, "s": sliding_heads}[m]
+                          for m in pattern),
+        prefill_chunk=span))
+
+
+# a pattern of mixers, one letter a block. LFM2's: c a gated short
+# convolution, a grouped-query attention (no interval: the last attention
+# comes early). Laguna's: f attention over every position, s over a window
 def _layer_types(pattern: str) -> tuple:
-    return tuple({"c": "conv", "a": "full_attention"}[m] for m in pattern)
+    return tuple({"c": "conv", "a": "full_attention", "f": "full_attention",
+                  "s": "sliding_attention"}[m] for m in pattern)
 
 
 _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
@@ -213,6 +239,17 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
     _lfm2("LiquidAI/LFM2-8B-A1B", "LFM2-8B-A1B.npz", 2048,
           _layer_types("ccacccacccacccacccaccacc"), 32, 8, 7168, vocab=65536,
           max_pos=128000, experts=32, expert_width=1792, per_tok=4, span=128),
+    # Laguna-XS.2: periods of one full-attention layer (48 query heads,
+    # YaRN on half of a head's lanes) and three that attend the last 512
+    # positions (64 query heads, plain rotation) and keep a ring of them;
+    # one leading dense FFN, then 256 experts routed 8 a token by a softmax
+    # beside a gated shared one. One chip holds the first of eight pipeline
+    # stages: `...@5`
+    _laguna("poolside/Laguna-XS.2", "Laguna-XS.2.npz", 2048, "fsss" * 10,
+            (48, 64), 8, 128, window=512, dense_width=8192, vocab=100352,
+            max_pos=262144, experts=256, expert_width=512, per_tok=8,
+            yarn=(64.0, 4096, 64.0, 1.0, 1.4158883083359672),
+            sliding_theta=10000.0, span=128),
     # tiny synthetic models for fast tests / CI (not in the reference's list)
     _vit("pipeedge/test-tiny-vit", 8, "test-tiny-vit.npz", 32, 2, 4, 64, 5,
          patch=4, img=16),
@@ -242,6 +279,14 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
     _lfm2("pipeedge/test-tiny-lfm2", "test-tiny-lfm2.npz", 32,
           _layer_types("ccacccac"), 4, 2, 64, vocab=100, max_pos=64,
           experts=8, expert_width=16, per_tok=2, span=8),
+    # six blocks: the dense full block, a period's three window blocks, a
+    # routed full block and a window block after it (two runs share the
+    # rings); the ramp of its YaRN has a frequency halfway
+    _laguna("pipeedge/test-tiny-laguna", "test-tiny-laguna.npz", 32,
+            "fsssfs", (4, 6), 2, 16, window=8, dense_width=64, vocab=100,
+            max_pos=64, experts=8, expert_width=16, per_tok=2,
+            yarn=(4.0, 16, 2.0, 0.25, 1.1386294361119891),
+            sliding_theta=100.0, span=4, theta=10000.0),
     _gpt2("pipeedge/test-tiny-moe", 8, "test-tiny-moe.npz", 32, 2, 4, 64,
           vocab=100, max_pos=64, n_experts=4, capacity_factor=4.0),
 ]}
@@ -300,7 +345,7 @@ def decoder_model(model_name: str) -> str:
     whole or as `<name>@<cut>`."""
     try:
         known = get_model_entry(model_name).config.model_type in (
-            "gpt2", "llama", "keye", "kimi", "qwen3_next", "lfm2")
+            "gpt2", "llama", "keye", "kimi", "qwen3_next", "lfm2", "laguna")
     except (KeyError, ValueError):
         known = False
     if not known:

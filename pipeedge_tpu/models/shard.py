@@ -51,11 +51,16 @@ class CacheLeaf(NamedTuple):
     order across those kinds; None = every block. `whole`: the leaf is a row
     a request, `[L, B] + shape`, replaced whole by every call (a recurrent
     state), and not a row a position, `[L, B, T] + shape`, written at
-    `pos`."""
+    `pos`. `length`: the positions the leaf keeps where that is not the
+    stage's `max_len` (0): a RING of the last `length` positions, `[L, B,
+    min(length, max_len)] + shape`, position `p` at slot `p mod length`
+    (a layer that attends a window of that many positions and needs keep no
+    more; parallel/decode.py, "A ring")."""
     shape: tuple
     dtype: Any
     kind: Any = None
     whole: bool = False
+    length: int = 0
 
 
 def kind_runs(family, cfg: TransformerConfig,

@@ -82,6 +82,16 @@ Cache = Dict[str, jax.Array]   # {'k': [L, B, T, H*Dh], 'v': [L, B, T, H*Dh]}
 # `whole` makes it a row a request, `[L, B, ...]` with no position axis: a
 # recurrent state, which a call reads, and replaces whole where the others
 # are written at `pos` (docs/DECODE.md, "Two geometries").
+#
+# A ring. A leaf with a `length` keeps that many positions whatever the
+# stage's `max_len`: `[L, B, W, ...]`, position `p` at slot `p mod W`
+# (`init_cache`, `W = min(length, max_len)`). A call's rows are written where
+# they fall, around the ring's end where they straddle it (`_write_rows`);
+# the ring is read whole, as stored, never rolled or copied into position
+# order, and a slot is masked by the position it holds: before a call at
+# `pos`, slot `s` holds the largest `p < pos` with `p mod W == s`, nothing
+# while that is negative (`_cache_update_and_read`, `ring=True`). The ladder
+# (`attend_bucket`) does not reach it: it reads `W` at every position.
 STATS = "stats"
 _STATS_UNIT = 20
 
@@ -98,6 +108,12 @@ def _whole(leaves) -> tuple:
     """The leaves that are a row a request and replaced whole."""
     return tuple(name for name, leaf in (leaves or {}).items()
                  if getattr(leaf, "whole", False))
+
+
+def _rings(leaves) -> tuple:
+    """The leaves that keep a ring of positions, not `max_len` of them."""
+    return tuple(name for name, leaf in (leaves or {}).items()
+                 if getattr(leaf, "length", 0))
 
 
 def _shares_layers(owner: Dict, kind) -> tuple:
@@ -132,6 +148,14 @@ class LayerCache(NamedTuple):
     rows: Optional[Cache] = None
 
 
+class Window(NamedTuple):
+    """Positions [0, width) of one layer of a stacked leaf, not read yet
+    (`_cache_update_and_read`, `unread`): `_read_window`'s arguments."""
+    buf: jax.Array
+    layer: jax.Array
+    width: int
+
+
 def _read_window(buf: jax.Array, layer, width: int,
                  lanes: Optional[slice] = None) -> jax.Array:
     """Positions [0, width) of one layer of stacked `buf` -> [B, width, ...];
@@ -145,14 +169,50 @@ def _read_window(buf: jax.Array, layer, width: int,
     return jax.lax.dynamic_slice(buf, start, sizes)[0]
 
 
-def _write_rows(cache: Cache, rows: Cache, pos, whole: tuple = ()) -> Cache:
+def _write_rows(cache: Cache, rows: Cache, pos, whole: tuple = (),
+                rings: tuple = ()) -> Cache:
     """Every layer's new `rows` (leaves `[L, B, S, ...]`) into the stacked
     cache at positions [pos, pos + S): one in-place update a leaf, for a
     decode step, a span and a prefill alike. A leaf named in `whole` has no
-    positions: its rows `[L, B, ...]` take the place of what was there."""
+    positions: its rows `[L, B, ...]` take the place of what was there. A
+    leaf named in `rings` takes row i at slot `(pos + i) mod W`: one update
+    for a step, and for a span two windows of S slots read, merged and
+    written back, the one that ends no later than the ring does and the
+    one at the ring's start, which takes the rows that ran past its end
+    (none, as a rule). Of a span longer than the ring (a whole prompt
+    through the prefill program) the last W rows are written."""
     def write(buf, new):
         return jax.lax.dynamic_update_slice(
             buf, new.astype(buf.dtype), (0, 0, pos) + (0,) * (buf.ndim - 3))
+
+    def around(buf, new):
+        ring, span, first = buf.shape[2], new.shape[2], pos
+        new = new.astype(buf.dtype)
+        if span > ring:
+            new, first, span = new[:, :, span - ring:], pos + span - ring, ring
+        start = first % ring
+        if span == 1:
+            return jax.lax.dynamic_update_slice(
+                buf, new, (0, 0, start) + (0,) * (buf.ndim - 3))
+        at = jnp.arange(span).reshape((span,) + (1,) * (buf.ndim - 3))
+        spare = jnp.zeros_like(new)
+
+        def merge(buf, low, rows, mine):   # slots [low, low + S) <- rows
+            old = jax.lax.dynamic_slice_in_dim(buf, low, span, axis=2)
+            return jax.lax.dynamic_update_slice_in_dim(
+                buf, jnp.where(mine, rows, old), low, axis=2)
+
+        # slot low + j takes row j - shift, where there is one
+        low = jnp.minimum(start, ring - span)
+        shift = start - low
+        buf = merge(buf, low, jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([spare, new], axis=2), span - shift, span,
+            axis=2), at >= shift)
+        # the rows past the ring's end: slot j takes row j + (W - start)
+        inside = jnp.minimum(ring - start, span)
+        return merge(buf, 0, jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([new, spare], axis=2), inside, span, axis=2),
+            at < span - inside)
 
     def replace(buf, new):
         assert new.shape == buf.shape, (new.shape, buf.shape)
@@ -165,7 +225,8 @@ def _write_rows(cache: Cache, rows: Cache, pos, whole: tuple = ()) -> Cache:
 
     return {name: buf if not buf.shape[0] else
             (add if name == STATS else
-             replace if name in whole else write)(buf, rows[name])
+             replace if name in whole else
+             around if name in rings else write)(buf, rows[name])
             for name, buf in cache.items()}
 
 
@@ -187,7 +248,8 @@ def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
     `stats` entry sizes the counters' leaf. Where a leaf is a `CacheLeaf`
     that names the kind (or kinds) of block that owns it, `runs`
     (`kind_runs`: the stage's blocks as `(kind, count)`) gives its `L`, the
-    count of those kinds among the `n_blocks`; a `whole` leaf has no `T`.
+    count of those kinds among the `n_blocks`; a `whole` leaf has no `T`,
+    and one with a `length` keeps `min(length, max_len)` positions, a ring.
 
     `cache_bits=8` stores K/V as int8 with per-(position, head) affine
     scales (QuantPipe's activation-compression idea applied to the decode
@@ -221,7 +283,9 @@ def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
         return {name: jnp.zeros((n_blocks,) + tail.shape + (2,), tail.dtype)
                 if name == STATS else
                 jnp.zeros((layers(name), batch)
-                          + (() if name in whole else (max_len,))
+                          + (() if name in whole else
+                             (min(getattr(tail, "length", 0) or max_len,
+                                  max_len),))
                           + tuple(tail.shape), tail.dtype)
                 for name, tail in leaves.items()}
     shape = (n_blocks, batch, max_len, cfg.kv_heads * cfg.head_dim)
@@ -498,7 +562,8 @@ def _use_int8_decode_kernel(bcache: Cache, s: int, cfg: TransformerConfig,
 def _cache_update_and_read(bcache: LayerCache, k_new: jax.Array,
                            v_new: jax.Array, pos, prefill: bool, s: int,
                            dtype, read_len: Optional[int] = None,
-                           window: int = 0):
+                           window: int = 0, names: tuple = ("k", "v"),
+                           ring: bool = False, unread: bool = False):
     """Record the new K/V rows for [pos, pos+S) of this layer and return
     (k, v, keep, cache) for `_attend`: k, v and keep are tuples of two
     parts, the cached window [0, width) as it was (one `dynamic_slice` a
@@ -515,8 +580,17 @@ def _cache_update_and_read(bcache: LayerCache, k_new: jax.Array,
     matmul and (for int8 caches) the dequantize shrink from max_len to
     read_len — the bucketed decode-step optimization
     (DecodePipeline::attend_bucket). `window` (STATIC, 0 = off) is a
-    sliding attention window: a query at q attends (q - window, q]."""
-    width = _attend_width(bcache, read_len)
+    sliding attention window: a query at q attends (q - window, q].
+    `names` (STATIC) are the two leaves, where a family keeps more than one
+    pair. `ring` (STATIC): they are rings (`CacheLeaf.length`, no shorter
+    than what of `window` fits `max_len`): the first part is the whole ring
+    as stored, whatever `read_len`, each slot kept by the position it holds
+    (the largest below `pos` that falls on it, if that is inside the
+    query's window). `unread` (STATIC): the first part's k and v are handed
+    back as `Window`s, for an attention that reads a head's lanes at a time
+    when it comes to them (`_read_window`): nothing of the window's whole
+    size is then copied out, or live at once."""
+    width = _attend_width(bcache, None if ring else read_len)
     quantized = "k_scale" in bcache.stack
     if quantized:
         bcache, win = _cache_write_quantized(bcache, k_new, v_new, width)
@@ -526,9 +600,10 @@ def _cache_update_and_read(bcache: LayerCache, k_new: jax.Array,
     else:
         stack = bcache.stack
         # through the cache's dtype, as if read back from it
-        k_new = k_new.astype(stack["k"].dtype).astype(dtype)
-        v_new = v_new.astype(stack["v"].dtype).astype(dtype)
-        bcache = bcache._replace(rows={"k": _fold(k_new), "v": _fold(v_new)})
+        k_new = k_new.astype(stack[names[0]].dtype).astype(dtype)
+        v_new = v_new.astype(stack[names[1]].dtype).astype(dtype)
+        bcache = bcache._replace(rows={names[0]: _fold(k_new),
+                                       names[1]: _fold(v_new)})
     # query i sits at absolute position pos + i (a prefill has pos 0, the
     # classic decode step s == 1, a SPAN step, the speculative verify,
     # s > 1) and attends every cached row below pos and rows [0, i] of
@@ -544,10 +619,15 @@ def _cache_update_and_read(bcache: LayerCache, k_new: jax.Array,
         k = _dequantize_rows(win["k"], win["k_scale"], win["k_shift"], dtype)
         v = _dequantize_rows(win["v"], win["v_scale"], win["v_shift"], dtype)
     else:
-        k = _read_window(stack["k"], bcache.layer, width).astype(dtype)
-        v = _read_window(stack["v"], bcache.layer, width).astype(dtype)
+        k, v = (Window(stack[name], bcache.layer, width) if unread else
+                _read_window(stack[name], bcache.layer, width).astype(dtype)
+                for name in names)
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (s, width), 1)
-    keep = k_pos < pos
+    if ring:    # the position each slot holds; negative: nothing yet
+        k_pos = pos - 1 - jnp.mod(pos - 1 - k_pos, width)
+        keep = k_pos >= 0
+    else:
+        keep = k_pos < pos
     if window:
         q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, (s, width), 0)
         keep &= k_pos > q_pos - window
@@ -689,14 +769,17 @@ def job_per_octave(leaves, stages) -> int:
     """Widths an octave worth a program to a batch job on these stages:
     `JOB_PER_OCTAVE` where the window is what most blocks read, half as
     many where fewer than half of the blocks keep a row a position (the
-    family's `cache_leaves` say which kinds do). A program more is a compile
+    family's `cache_leaves` say which kinds do; a block whose leaves are
+    rings reads its ring whatever the ladder says and counts with those
+    that keep a state). A program more is a compile
     in a first run and a load in every later one, and a narrower window
     speeds up only the blocks that attend it: three layers in four of
     qwen3_next keep a state and no window, its span programs are the
     largest (1.2 s a load), and at four an octave its warm set-up grew by
     12% for 4% of tokens/s (PERF.md, PR 34)."""
     rows = {name: leaf for name, leaf in (leaves or {}).items()
-            if name != STATS and not getattr(leaf, "whole", False)}
+            if name != STATS and not getattr(leaf, "whole", False)
+            and not getattr(leaf, "length", 0)}
     owner = _owner(rows)
     runs = [run for st in stages for run in st.get("runs") or ()]
     if not rows or len(owner) < len(rows) or not runs:
@@ -714,6 +797,11 @@ M_ATTEND = prom.REGISTRY.counter(
 for _phase in ("prefill", "decode"):
     for _kind in ("read", "live"):
         M_ATTEND.declare(phase=_phase, kind=_kind)
+M_LEAF_BYTES = prom.REGISTRY.gauge(
+    "pipeedge_cache_leaf_bytes",
+    "bytes of each leaf of the caches a pipeline made last, over its "
+    "stages, where its family names its leaves: a ring's do not grow with "
+    "max_len")
 
 
 def attend_bucket(pos_next: int, max_len: int, floor: int = 64,
@@ -809,7 +897,7 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
         name: jnp.concatenate(parts) for name in cache
         if (parts := [new[name] for new in rows if name in new])}
     return x, _write_rows(cache, rows, 0 if prefill else pos,
-                          whole=_whole(leaves))
+                          whole=_whole(leaves), rings=_rings(leaves))
 
 
 # every stage program takes (params, data, cache[, pos]) and donates the
@@ -1502,6 +1590,13 @@ class DecodePipeline:
         # the whole prompt)
         span = getattr(family, "prefill_span", None)
         self.prefill_span = span(cfg) if span is not None else None
+        for name in _rings(self.cache_leaves):
+            ring = min(self.cache_leaves[name].length, max_len)
+            if self.prefill_span and self.prefill_span > ring:
+                raise ValueError(
+                    f"the {family.name} family prefills in spans of "
+                    f"{self.prefill_span} positions and its leaf {name!r} "
+                    f"keeps a ring of {ring}: a span must fit the ring")
         self.family = family
         self.cfg = cfg
         self.max_len = max_len
@@ -1620,6 +1715,8 @@ class DecodePipeline:
             elif st["device"] is not None:
                 c = jax.device_put(c, st["device"])
             caches.append(c)
+        for name in caches[0] if self.cache_leaves else ():
+            M_LEAF_BYTES.set(sum(c[name].nbytes for c in caches), leaf=name)
         return caches
 
     def _decode_step(self, st, data, cache, pos: int, span: int = 1,
@@ -1757,12 +1854,13 @@ class DecodePipeline:
         per-stage cache layout (block split, max_len, quantization,
         dtype, KV geometry, and the geometry of every leaf the family names:
         its shape and type, the kind of block that owns it and whether it is
-        a row a position or a row a request) matches — a mismatched handle
-        would otherwise die deep inside jit with an opaque shape error or
+        a row a position, a ring of them or a row a request) matches — a
+        mismatched handle would otherwise die deep inside jit with an opaque shape error or
         silently corrupt attend windows (round-4 advice)."""
         named = tuple(
             (name, tuple(leaf.shape), jnp.dtype(leaf.dtype).name,
              getattr(leaf, "kind", None), getattr(leaf, "whole", False))
+            + ((leaf.length,) if getattr(leaf, "length", 0) else ())
             for name, leaf in sorted((self.cache_leaves or {}).items()))
         return ("decode-prefix-v2",
                 tuple(st.get("runs") or st["n_blocks"]

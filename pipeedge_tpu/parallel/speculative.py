@@ -120,6 +120,16 @@ class SpeculativeDecoder:
                     "a request, not a position: a rejected draft would need "
                     "the state of an earlier position, and speculative "
                     "verify has no snapshot to roll back to")
+            rings = [leaf for leaf, spec in (pipe.cache_leaves or {}).items()
+                     if getattr(spec, "length", 0)]
+            if rings:
+                # the gamma rows of a round take the slots of the oldest
+                # positions in the window, which a rejection needs again
+                raise NotImplementedError(
+                    f"the {pipe.family.name} family ({name}) keeps {rings} "
+                    "as rings: a rejected draft's rows have overwritten "
+                    "positions the window still holds, and speculative "
+                    "verify has no snapshot to roll back to")
         if target.cfg.vocab_size != draft.cfg.vocab_size:
             raise ValueError(
                 "draft and target must share a vocabulary: "

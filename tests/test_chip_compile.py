@@ -322,6 +322,51 @@ def test_lfm2_stage_program_compiles_for_v5e(span, last_only, on_chip):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
 
 
+LAGUNA_CELL = "poolside/Laguna-XS.2@5"
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (128, True)])
+def test_laguna_stage_program_compiles_for_v5e(span, last_only, on_chip):
+    """`laguna-xs2.repo-batch` at its real size: five blocks at the
+    published widths in three runs (the dense full block of 48 query heads,
+    three window blocks of 64, a routed full block), all 256 experts held,
+    32 rows, the 8,192 bucket; a decode step and one span of the prefill.
+    The window blocks' leaves are rings of 512 positions: 7.74 GB of
+    weights, 4.29 GB of keys and values in the TWO full layers and 0.40 GB
+    of rings in three (all five kept whole would be 18.5 GB with the
+    weights), and the program's temporaries have to fit one chip's 16 GB."""
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry(LAGUNA_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    rows, max_len = 32, 8192
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: decode.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg),
+        runs=kind_runs(entry.family.FAMILY, cfg, stage)))
+    assert cache["k_ring"].shape == (3, rows, 512, 1024)
+    assert cache["k"].shape == (2, rows, max_len, 1024)
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=max_len,
+                          last_only=last_only).compile()
+    memory = compiled.memory_analysis()
+    print(f"laguna {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * (max_len * 16384 + 3 * 512 * 8192)
+    assert memory.alias_size_in_bytes > cache_bytes     # updated in place
+    # no leaf padded, and the rings are rings
+    assert memory.argument_size_in_bytes < 7.75e9 + 1.02 * cache_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+
+
 def test_gpt2_medium_decode_step_keeps_cache_rows_as_rows(on_chip):
     """`gpt2-m.offline-batch`'s decode step at its real size (32 rows, 1,024
     positions, the 512 bucket, bfloat16; shapes from the loader): the chip
