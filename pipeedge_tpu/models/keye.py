@@ -68,7 +68,7 @@ from .shard import FamilySpec, build_shard_params
 
 # what a block step counts into the cache's `stats` leaf, in this order
 STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
-         "moe_layer_calls", "sparse_scored", "sparse_kept")
+         "moe_grouped_calls", "moe_layer_calls", "sparse_scored", "sparse_kept")
 
 # bytes of float32 attention scores one chunk of queries may hold: the
 # chunk is the largest power of two of query rows that stays under it

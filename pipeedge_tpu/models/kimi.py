@@ -78,7 +78,7 @@ from .shard import FamilySpec, build_shard_params
 
 # what a block step counts into the cache's `stats` leaf, in this order
 STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
-         "moe_layer_calls", "mla_rows_written", "mla_rows_expanded",
+         "moe_grouped_calls", "moe_layer_calls", "mla_rows_written", "mla_rows_expanded",
          "mla_rows_read")
 
 # activations and cache (module docstring, Precision)
@@ -339,7 +339,7 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
         delta, moe = _experts(p, normed, cfg)
         moe = jnp.concatenate([moe.astype(jnp.int32), jnp.ones(1, jnp.int32)])
     else:
-        delta, moe = _dense_ffn(p["mlp"], normed), jnp.zeros(4, jnp.int32)
+        delta, moe = _dense_ffn(p["mlp"], normed), jnp.zeros(5, jnp.int32)
     stats = jnp.concatenate([moe, jnp.stack(
         [jnp.int32(b * s), jnp.int32(b * s if s > 1 else 0), read])])
     return h + delta, bcache._replace(

@@ -76,7 +76,7 @@ from .shard import CacheLeaf, FamilySpec, build_shard_params
 
 # what a block step counts into the cache's `stats` leaf, in this order
 STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
-         "moe_layer_calls", "swa_positions_read", "swa_positions_live",
+         "moe_grouped_calls", "moe_layer_calls", "swa_positions_read", "swa_positions_live",
          "swa_ring_wraps")
 
 # activations and cache (module docstring, Precision)
@@ -306,7 +306,7 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
         delta, moe = _experts(p, normed, cfg)
         moe = jnp.concatenate([moe.astype(jnp.int32), jnp.ones(1, jnp.int32)])
     else:
-        delta, moe = _dense_ffn(p["mlp"], normed), jnp.zeros(4, jnp.int32)
+        delta, moe = _dense_ffn(p["mlp"], normed), jnp.zeros(5, jnp.int32)
     return h + delta, bcache._replace(
         rows=dict(bcache.rows, stats=jnp.concatenate([moe, counts])))
 

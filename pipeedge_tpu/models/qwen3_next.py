@@ -88,7 +88,7 @@ from .shard import CacheLeaf, FamilySpec, build_shard_params
 
 # what a block step counts into the cache's `stats` leaf, in this order
 STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
-         "moe_layer_calls", "gdn_positions_chunked", "gdn_positions_stepped",
+         "moe_grouped_calls", "moe_layer_calls", "gdn_positions_chunked", "gdn_positions_stepped",
          "gdn_state_carries")
 
 # activations, cache and state (module docstring, Precision)

@@ -41,7 +41,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..utils import jax_compat
-from ..models.layers import TransformerConfig, exact_dot, gelu
+from ..models.layers import (TransformerConfig, _three_parts, exact_dot,
+                             gelu)
+from ..ops import grouped_matmul as gm
 
 
 def init_moe_params(cfg: TransformerConfig, n_experts: int,
@@ -256,7 +258,8 @@ def shard_moe_params(params: Dict, mesh: Mesh, axis: str = "ep") -> Dict:
 # loop step multiplies one tile by its expert's three matrices. How many
 # rows a call's tiles have is `expert_tile`'s to say; this is its cap (256
 # rows of a 2,048 x 768 expert are as many FLOPs in one bfloat16 pass as its
-# weights are bytes on a v5e).
+# weights are bytes on a v5e). What `expert_tile` returns also says which
+# calls leave the loop for the grouped kernels (`GROUPED_RIDGE`, below).
 EXPERT_TILE = 256
 
 # standard deviations of a group's size that a tile leaves room for above
@@ -299,9 +302,51 @@ def expert_tile(tokens: int, per_tok: int, n_experts: int) -> int:
 # experts are given when the router spreads its choices evenly
 ROUND_SLACK = 4
 
+# a call whose tile is at most this many rows walks its groups inside one
+# grouped kernel a product (`ops/grouped_matmul.py`); above it the tile loop
+# stays. Under the ridge a tile is bound by its expert's bytes and the loop
+# pays a trip, three slices, a gather and a write for every touched expert,
+# which the kernel does not (PERF.md, PR 41's table: one layer call alone,
+# loop against grouped); above it a tile is bound by its three-pass
+# products, which the loop's fusions run near the chip's peak. The cells'
+# steps have tiles of 8 and 32, their spans 120 to 256
+GROUPED_RIDGE = 64
+
 # what `topk_ffn_delta` counts, in this order (a float32 vector; each is far
-# below 2**24 a call)
-MOE_STATS = ("assignments", "rows_computed", "experts_touched")
+# below 2**24 a call). `rows_computed`: rows the products multiplied, a
+# row's three parts once: a loop tile's rows, or every visit of one of the
+# grouped kernel's row tiles to a group. `grouped_calls`: 1 where the call
+# took the grouped kernel
+MOE_STATS = ("assignments", "rows_computed", "experts_touched",
+             "grouped_calls")
+
+
+def _grouped_mode():
+    """How this backend runs the grouped kernel: "mosaic" on a TPU, None
+    where Mosaic cannot run (the tile loop serves every call); the tests
+    put "interpret" here."""
+    return "mosaic" if jax.default_backend() == "tpu" else None
+
+
+def grouped_layout(tile: int):
+    """(rows of the grouped kernel's row tile, whether every group starts
+    on a row tile's first row) in a call whose groups fit a loop tile of
+    `tile` rows (`expert_tile`).
+
+    The row tile is that tile in whole bfloat16 sublane tiles of 16: a
+    visit multiplies the whole row tile (three parts a row) by one block of
+    the group's matrix, which takes the matrix unit about as long as the
+    block's bytes take the HBM up to about 100 rows, and longer beyond
+    (PERF.md, PR 41: lfm2's step, packed, at row tiles of 32 / 64 / 96 /
+    128: 1.21 / 1.19 / 1.32 / 1.63 ms a call). Groups of a row or two (a
+    loop tile of 8) lie packed as they sorted: a row tile then holds many
+    groups, and starting each on a tile of its own would lay out sixteen
+    rows for one (laguna's call 2.18 ms for 1.56). Groups near the row
+    tile's size (a loop tile of 16 and more) each start on a row tile's
+    first row: packed, half of them straddle two row tiles and are visited
+    twice, and the second visit's products are not hidden behind bytes
+    (lfm2's call 1.19 ms packed, 1.03 a group a tile)."""
+    return -(-tile // 16) * 16, tile >= 16
 
 
 def topk_route(router: Dict, tokens: jax.Array, cfg: TransformerConfig):
@@ -361,18 +406,26 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     = `cfg.held_experts`, or all. Assignments to other experts cost a sort
     key and nothing more, and add nothing here. `layer`, when given,
     indexes a leading layer axis of the expert leaves: the stacked blocks
-    are then sliced one tile's matrices at a time and a whole layer's
+    are then read one expert's matrices at a time and a whole layer's
     experts are never copied out of the stack.
 
-    The assignments are sorted by expert and each held expert's group is
-    covered by whole tiles of `expert_tile(tokens, k, experts)` rows, one
-    loop step a tile. The tile follows what an expert is given in this call
-    (a step of 128 rows, top-4 of 32: groups of 16, tiles of 32; a span of
-    4,096 tokens, top-8 of 128: groups of 256, tiles of 256); a row's
-    result does not depend on it, only how many rows of padding are
-    multiplied beside that row.
+    The assignments are sorted by expert, and the sorted groups are
+    multiplied by their experts in one of two ways, by what the call's
+    shapes say (`expert_tile(tokens, k, experts)`; no option):
 
-    Returns (delta [B, S, D], stats float32 [len(MOE_STATS)])."""
+    - a tile above `GROUPED_RIDGE` rows (a span of 4,096 tokens, top-8 of
+      128: groups of 256, tiles of 256): each held expert's group is covered
+      by whole tiles of that many rows, one loop step a tile
+      (`_tile_loop`), bound by its three-pass products;
+    - a tile at or under it (a step of 128 rows, top-4 of 32: groups of 16;
+      a step of 32 rows, top-8 of 256: single assignments), on a backend
+      that runs Mosaic: the gate and up products and then the down product
+      walk the groups inside one kernel each (`_grouped`), bound by the
+      touched experts' bytes.
+
+    A row's result does not depend on the way, but for the order of the
+    float32 sums. Returns (delta [B, S, D], stats float32
+    [len(MOE_STATS)])."""
     b, s, d = normed.shape
     tokens = normed.reshape(-1, d)
     t, k = tokens.shape[0], cfg.num_experts_per_tok
@@ -381,19 +434,145 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     local = experts.reshape(-1) - first                     # [A]
     mine = (local >= 0) & (local < count)
     local = jnp.where(mine, local, count)                   # others sort last
-    n_assign = t * k
-
+    # the assignments sorted by expert (stable: by token within an expert;
+    # other chips' last), by sorts and searches: a scatter of this many
+    # single values is the slow way on the chip
+    order = jnp.argsort(local, stable=True)
     tile = expert_tile(t, k, cfg.n_experts)
+    mode = _grouped_mode() if tile <= GROUPED_RIDGE else None
+    # where each held expert's group starts: the assignments before it. A
+    # search a bound over a span's tens of thousands of sorted assignments;
+    # a step's few hundred are counted in one pass (`gm.pick`'s reason)
+    held = jnp.arange(count + 1)
+    bounds = jnp.sum(local[None, :] < held[:, None], axis=1) if mode \
+        else jnp.searchsorted(local[order], held)
+    # each token's assignments: where they sorted to, and their gates
+    sorted_at = jnp.argsort(order).reshape(t, k)
+    gates = jnp.where(mine, gates.reshape(-1), 0.0).reshape(t, k)
+    ways = (tokens, params["experts"], layer, order, bounds, sorted_at,
+            gates)
+    if mode:
+        delta, rows = _grouped(*ways, local.reshape(t, k),
+                               *grouped_layout(tile), mode == "interpret")
+    else:
+        delta, rows = _tile_loop(*ways, tile, cfg.n_experts)
+    if "shared" in params:
+        shared = _swiglu(tokens, *(params["shared"][name]
+                                   for name in ("gate", "up", "down")))
+        if "shared_gate" in params:
+            shared = shared * jax.nn.sigmoid(exact_dot(
+                tokens, params["shared_gate"], w_contract=1))
+        delta = delta + shared
+    sizes = bounds[1:] - bounds[:-1]
+    stats = jnp.stack([jnp.sum(mine), rows, jnp.sum(sizes > 0),
+                       1 if mode else 0]).astype(jnp.float32)
+    return delta.reshape(b, s, d).astype(normed.dtype), stats
+
+
+def _back_to_tokens(delta, out, sorted_at, gates, base, end):
+    """`delta` [T, D] plus, for each token, its gates times its assignments'
+    rows of `out`, which holds the sorted assignments `[base, end)` from its
+    row 0. Rows are selected, never multiplied by a zero gate: a row no
+    expert here wrote may hold anything."""
+    for slot in range(sorted_at.shape[1]):
+        at = sorted_at[:, slot]
+        here = (at >= base) & (at < end)
+        rows = jnp.take(out, jnp.clip(at - base, 0, out.shape[0] - 1),
+                        axis=0)
+        delta = delta + jnp.where(here[:, None],
+                                  gates[:, slot][:, None] * rows, 0.0)
+    return delta
+
+
+def _grouped(tokens, ex, layer, order, bounds, sorted_at, gates, local,
+             row_tile: int, aligned: bool, interpret: bool):
+    """The sorted groups times their experts in two grouped kernels
+    (`ops/grouped_matmul.py`): `silu(gate x) * up x`, then `down`; the
+    arithmetic is `_swiglu`'s over `exact_dot`. -> (delta [T, D] float32,
+    rows the kernels multiplied).
+
+    The stack is handed over as it lies, its leading axes flattened (a free
+    reshape), and group g is its matrix `layer * experts + g`: a layer's
+    experts are never taken out of it. `local` [T, k]: each assignment's
+    group, `count` for other chips'. `aligned`: every group starts on a row
+    tile's first row, so a group that fits a tile is one visit; else the
+    groups lie packed as they sorted (`grouped_layout`)."""
+    (t, d), k = tokens.shape, sorted_at.shape[1]
+    count = bounds.shape[0] - 1
+    stacks = {name: w.reshape((-1,) + w.shape[-2:]) for name, w in ex.items()}
+    first_group = 0 if layer is None else layer * ex["gate"].shape[-3]
+    n_assign = t * k
+    sizes = bounds[1:] - bounds[:-1]
+    sorted_tokens = order // k
+    if aligned:
+        padded = -(-sizes // row_tile) * row_tile
+        starts = jnp.cumsum(padded) - padded
+        n_tiles = -(-n_assign // row_tile) + min(count, n_assign)
+        # a group's rows lie `shift` rows after where they sorted to; row r
+        # of the layout holds the sorted assignment `r - shift` of the last
+        # group that starts at or before it (a row between two groups takes
+        # some assignment's token: nobody owns it). Short tables are read
+        # by comparison, never by a gather of single values (`gm.pick`)
+        shift = starts - bounds[:-1]
+        row = jnp.arange(n_tiles * row_tile)
+        at = jnp.clip(row - gm.pick(shift, gm.count_up_to(starts, row) - 1),
+                      0, n_assign - 1)
+        token_of_row = gm.pick(sorted_tokens, at)
+        laid_at = sorted_at + gm.pick(shift, jnp.minimum(local, count - 1))
+    else:
+        starts = bounds[:-1]
+        n_tiles = -(-n_assign // row_tile)
+        token_of_row = jnp.concatenate([sorted_tokens, jnp.zeros(
+            (n_tiles * row_tile - n_assign,), order.dtype)])
+        laid_at = sorted_at
+    n_rows = n_tiles * row_tile
+    items = gm.group_items(starts, starts + sizes, first_group, row_tile,
+                           gm.max_items(n_rows, count, row_tile))
+    rows = jnp.take(tokens, token_of_row, axis=0)
+
+    # `exact_dot`'s three cases, the parts of a row tile next to each other
+    w_dtype = ex["gate"].dtype
+    precision = None
+    if tokens.dtype == w_dtype == jnp.float32:
+        precision = jax.lax.Precision.HIGHEST
+
+    def by_tiles(x):
+        if x.dtype != jnp.float32:
+            parts = x.astype(w_dtype)[None]
+        elif w_dtype == jnp.float32:
+            parts = x[None]
+        else:
+            parts = _three_parts(x, w_dtype)
+        n = parts.shape[0]
+        return n, parts.reshape(n, n_tiles, row_tile, -1).swapaxes(
+            0, 1).reshape(n * n_rows, -1)
+
+    call = partial(gm.grouped_matmul, items=items, row_tile=row_tile,
+                   precision=precision, interpret=interpret)
+    parts, x = by_tiles(rows)
+    hidden = call(x, (stacks["gate"], stacks["up"]), parts=parts)
+    parts, x = by_tiles(hidden.astype(tokens.dtype))
+    out = call(x, (stacks["down"],), parts=parts)
+    # other chips' assignments lie nowhere in the layout
+    delta = _back_to_tokens(jnp.zeros((t, d), jnp.float32), out,
+                            jnp.where(local < count, laid_at, n_rows),
+                            gates, 0, n_rows)
+    visits = jnp.where(bounds[count] > 0, items.count, 0)
+    return delta, visits * row_tile
+
+
+def _tile_loop(tokens, ex, layer, order, bounds, sorted_at, gates,
+               tile: int, n_experts: int):
+    """The sorted groups times their experts a tile of `tile` rows a loop
+    step. -> (delta [T, D] float32, rows the tiles multiplied)."""
+    (t, d), k = tokens.shape, sorted_at.shape[1]
+    count = bounds.shape[0] - 1
+    n_assign = t * k
     # an expert is given a token at most once, so a group has at most
     # ceil(t / tile) tiles; all groups together have at most one tile a
     # group more than the assignments fill, and no more than assignments
     n_tiles = min(count * -(-t // tile), -(-n_assign // tile) + count,
                   n_assign)
-    # the assignments sorted by expert (stable: by token within an expert;
-    # other chips' last), by sorts and searches: a scatter of this many
-    # single values is the slow way on the chip
-    order = jnp.argsort(local, stable=True)
-    bounds = jnp.searchsorted(local[order], jnp.arange(count + 1))
     group_first, sizes = bounds[:-1], bounds[1:] - bounds[:-1]
     tiles_of = -(-sizes // tile)
     tile_ends = jnp.cumsum(tiles_of)
@@ -412,15 +591,10 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     # all there is unless the routing is that skewed, and nothing of the
     # size assignments x hidden is built for a share
     round_tiles = min(n_tiles, -(-ROUND_SLACK * n_assign * count
-                                 // (cfg.n_experts * tile)) + count)
+                                 // (n_experts * tile)) + count)
     kept_rows = min(round_tiles * tile, n_assign + tile)
-    # each token's assignments: where they sorted to, and their gates
-    sorted_at = jnp.argsort(order).reshape(t, k)
-    gates = jnp.where(mine, gates.reshape(-1), 0.0).reshape(t, k)
     # a group's last tile may run past the last assignment
     order = jnp.concatenate([order, jnp.full((tile,), n_assign, order.dtype)])
-
-    ex = params["experts"]
 
     def matrix(name, e):
         w = ex[name]
@@ -449,28 +623,11 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
 
         out = jax.lax.fori_loop(first_tile, last_tile, one_tile,
                                 jnp.zeros((kept_rows, d), jnp.float32))
-        # back to the tokens: each of a token's k assignments in turn
-        for slot in range(k):
-            at = sorted_at[:, slot]
-            here = (at >= base) & (at < end)
-            rows = jnp.take(out, jnp.clip(at - base, 0, kept_rows - 1),
-                            axis=0)
-            delta = delta + jnp.where(here, gates[:, slot],
-                                      0.0)[:, None] * rows
-        return delta
+        return _back_to_tokens(delta, out, sorted_at, gates, base, end)
 
     delta = jax.lax.fori_loop(0, -(-used // round_tiles), one_round,
                               jnp.zeros((t, d), jnp.float32))
-    if "shared" in params:
-        shared = _swiglu(tokens, *(params["shared"][name]
-                                   for name in ("gate", "up", "down")))
-        if "shared_gate" in params:
-            shared = shared * jax.nn.sigmoid(exact_dot(
-                tokens, params["shared_gate"], w_contract=1))
-        delta = delta + shared
-    stats = jnp.stack([jnp.sum(mine), used * tile,
-                       jnp.sum(sizes > 0)]).astype(jnp.float32)
-    return delta.reshape(b, s, d).astype(normed.dtype), stats
+    return delta, used * tile
 
 
 def ep_topk_ffn_delta(params: Dict, normed: jax.Array,

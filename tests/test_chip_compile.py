@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from pipeedge_tpu.models import ShardConfig, registry
 from pipeedge_tpu.ops import (attention, decode_attention, fused_quant,
                               int8_matmul, quant)
+from pipeedge_tpu.parallel import expert
 
 EDGE = (8, 197, 1024)       # the ViT-L stage edge at microbatch 8
 GPT2_HEADS, GPT2_HEAD_DIM = 12, 64
@@ -114,6 +115,32 @@ def _decode_attention(variant, batch, width):
     return build
 
 
+def _grouped_experts(stack, f, d, tokens, per_tok, n_experts, held):
+    """A decode step's grouped expert products (`expert._grouped`: the
+    sorted rows' parts, the two kernels, the way back) over a cell's stack
+    `[stack, held, ...]` of bfloat16 experts of `f` x `d`, `tokens` float32
+    rows each sent to `per_tok` of `n_experts`, at the row tile the step's
+    call takes."""
+    def build(on_chip):
+        tile = expert.expert_tile(tokens, per_tok, n_experts)
+        assert tile <= expert.GROUPED_RIDGE
+
+        def fn(rows, gate, up, down, layer, order, bounds, sorted_at, gates):
+            return expert._grouped(
+                rows, {"gate": gate, "up": up, "down": down}, layer, order,
+                bounds, sorted_at, gates, sorted_at % (held + 1),
+                *expert.grouped_layout(tile), False)
+        wide = on_chip((stack, held, f, d), jnp.bfloat16)
+        return fn, [on_chip((tokens, d), jnp.float32), wide, wide,
+                    on_chip((stack, held, d, f), jnp.bfloat16),
+                    on_chip((), jnp.int32),
+                    on_chip((tokens * per_tok,), jnp.int32),
+                    on_chip((held + 1,), jnp.int32),
+                    on_chip((tokens, per_tok), jnp.int32),
+                    on_chip((tokens, per_tok), jnp.float32)]
+    return build
+
+
 KERNELS = {
     "fused_encode_8": _encode(8),
     "fused_encode_4": _encode(4),
@@ -125,6 +152,14 @@ KERNELS = {
     "attention_causal_32x4096x128": _attention(32, 4096, 128, causal=True),
     "int8_decode_attention_v1": _decode_attention(1, batch=16, width=1024),
     "int8_decode_attention_v2": _decode_attention(2, batch=16, width=256),
+    # the five sparse cells' decode steps: stack, expert, rows, router, held
+    "grouped_experts_lfm2": _grouped_experts(10, 1792, 2048, 128, 4, 32, 32),
+    "grouped_experts_laguna": _grouped_experts(4, 512, 2048, 32, 8, 256,
+                                               256),
+    "grouped_experts_qwen3_next": _grouped_experts(4, 512, 2048, 8, 10, 512,
+                                                   256),
+    "grouped_experts_keye": _grouped_experts(6, 768, 2048, 8, 8, 128, 128),
+    "grouped_experts_kimi": _grouped_experts(4, 2048, 7168, 32, 8, 384, 12),
 }
 
 
@@ -133,6 +168,18 @@ def test_kernel_compiles_for_v5e(name, on_chip):
     fn, shapes = KERNELS[name](on_chip)
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(autouse=True)
+def mosaic_for_the_described_chip(monkeypatch):
+    """The default backend here is the CPU's, which keeps every expert
+    layer on the tile loop; these programs are compiled for the chip, where
+    a step's call takes the grouped kernel."""
+    monkeypatch.setattr(expert, "_grouped_mode", lambda: "mosaic")
+
+
+def _grouped_kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
 
 
 VIT_LARGE = "google/vit-large-patch16-224"
@@ -188,6 +235,7 @@ def test_keye_stage_program_compiles_for_v5e(span, last_only, on_chip):
     compiled = step.lower(params, on_chip((8, span), jnp.int32), cache,
                           on_chip((), jnp.int32), read_len=16384,
                           last_only=last_only).compile()
+    assert (_grouped_kernels(compiled) > 0) == (span == 1)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes > 1.7e9       # the cache, in place
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
@@ -222,6 +270,7 @@ def test_kimi_stage_program_compiles_for_v5e(span, last_only, rows, on_chip):
     compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
                           on_chip((), jnp.int32), read_len=4096,
                           last_only=last_only).compile()
+    assert (_grouped_kernels(compiled) > 0) == (span == 1)
     memory = compiled.memory_analysis()
     print(f"kimi {rows} rows, span {span}: arguments "
           f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
@@ -263,6 +312,7 @@ def test_qwen3_next_stage_program_compiles_for_v5e(span, last_only, on_chip):
     compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
                           on_chip((), jnp.int32), read_len=max_len,
                           last_only=last_only).compile()
+    assert (_grouped_kernels(compiled) > 0) == (span == 1)
     memory = compiled.memory_analysis()
     print(f"qwen3-next {rows} rows, span {span}: arguments "
           f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
@@ -309,6 +359,7 @@ def test_lfm2_stage_program_compiles_for_v5e(span, last_only, on_chip):
     compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
                           on_chip((), jnp.int32), read_len=max_len,
                           last_only=last_only).compile()
+    assert (_grouped_kernels(compiled) > 0) == (span == 1)
     memory = compiled.memory_analysis()
     print(f"lfm2 {rows} rows, span {span}: arguments "
           f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
@@ -355,6 +406,7 @@ def test_laguna_stage_program_compiles_for_v5e(span, last_only, on_chip):
     compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
                           on_chip((), jnp.int32), read_len=max_len,
                           last_only=last_only).compile()
+    assert (_grouped_kernels(compiled) > 0) == (span == 1)
     memory = compiled.memory_analysis()
     print(f"laguna {rows} rows, span {span}: arguments "
           f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "

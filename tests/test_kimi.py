@@ -412,7 +412,7 @@ def test_groups_of_one_two_and_three_tiles_match_the_plain_layer(how):
             held = slice(first, first + count)
             assert counts.tolist() == [sizes[held].sum(),
                                        tiles[held].sum() * tile,
-                                       (sizes[held] > 0).sum()]
+                                       (sizes[held] > 0).sum(), 0]
             total = total + delta
         np.testing.assert_allclose(total, wanted, atol=1e-5)
         return
@@ -425,7 +425,8 @@ def test_groups_of_one_two_and_three_tiles_match_the_plain_layer(how):
     delta, counts = jax.jit(lambda p, y, at: expert.topk_ffn_delta(
         p, y, cfg, layer=at))(params, x, layer)
     np.testing.assert_allclose(delta, wanted, atol=1e-5)
-    assert counts.tolist() == [200, tiles.sum() * tile, (sizes > 0).sum()]
+    assert counts.tolist() == [200, tiles.sum() * tile, (sizes > 0).sum(),
+                               0]
 
 
 def _counters():

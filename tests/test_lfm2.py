@@ -251,15 +251,16 @@ def test_the_shared_convolution_is_causal_and_carries_its_tail(width, span):
 # what the four families' tiny step (span 1) and span (8) programs traced to
 # before `causal_conv`, `CacheLeaf.kind` as a tuple, `_attend(precision=)`
 # and `gate_sum_eps` (the parent commit): equations at the top level and in
-# all
+# all. Since PR 41 nine more a traced expert layer: its fourth count and the
+# way back's select (on the CPU these programs keep the tile loop)
 TRACED = {("pipeedge/test-tiny-gpt2", 1): (34, 230),
           ("pipeedge/test-tiny-gpt2", 8): (32, 228),
-          ("pipeedge/test-tiny-keye", 1): (38, 746),
-          ("pipeedge/test-tiny-keye", 8): (36, 744),
-          ("pipeedge/test-tiny-kimi", 1): (37, 746),
-          ("pipeedge/test-tiny-kimi", 8): (37, 748),
-          ("pipeedge/test-tiny-qwen3-next", 1): (44, 2174),
-          ("pipeedge/test-tiny-qwen3-next", 8): (44, 2384)}
+          ("pipeedge/test-tiny-keye", 1): (38, 755),
+          ("pipeedge/test-tiny-keye", 8): (36, 753),
+          ("pipeedge/test-tiny-kimi", 1): (37, 755),
+          ("pipeedge/test-tiny-kimi", 8): (37, 757),
+          ("pipeedge/test-tiny-qwen3-next", 1): (44, 2210),
+          ("pipeedge/test-tiny-qwen3-next", 8): (44, 2420)}
 
 
 def _equations(jaxpr, names):
@@ -464,7 +465,8 @@ def test_at_load_an_expert_takes_one_tile_that_follows_its_group(activations):
     assert delta.dtype == activations
     if activations == "float32":
         np.testing.assert_allclose(delta, wanted, atol=1e-5)
-        assert stats.tolist() == [130, cfg.n_experts * tile, cfg.n_experts]
+        assert stats.tolist() == [130, cfg.n_experts * tile, cfg.n_experts,
+                                  0]
     else:       # a bfloat16 router may choose otherwise; the tile is the same
         assert stats[0] == 130 and stats[1] % tile == 0
         assert stats[1] <= (cfg.n_experts + 1) * tile
@@ -489,11 +491,11 @@ def test_a_fresh_cache_holds_each_leaf_for_its_owners_layers(size):
                         ("conv_experts", 3), ("attn_experts", 1),
                         ("conv_experts", 1))
         shapes = {"k": (2, 2, 32, 16), "v": (2, 2, 32, 16),
-                  "conv_tail": (6, 2, 2, 32), "stats": (8, 7, 2)}
+                  "conv_tail": (6, 2, 2, 32), "stats": (8, 8, 2)}
     elif size == "tiny@5":
         runs, cache = _fresh_cache(TINY + "@5", 2, 32)
         shapes = {"k": (1, 2, 32, 16), "v": (1, 2, 32, 16),
-                  "conv_tail": (4, 2, 2, 32), "stats": (5, 7, 2)}
+                  "conv_tail": (4, 2, 2, 32), "stats": (5, 8, 2)}
     else:       # the cell: 12 blocks, seven runs, 128 rows, 1,024 positions
         runs, cache = _fresh_cache(CELL, 128, 1024)
         assert runs == (("conv_dense", 2), ("attn_experts", 1),
@@ -501,7 +503,7 @@ def test_a_fresh_cache_holds_each_leaf_for_its_owners_layers(size):
                         ("conv_experts", 3), ("attn_experts", 1),
                         ("conv_experts", 1))
         shapes = {"k": (3, 128, 1024, 512), "v": (3, 128, 1024, 512),
-                  "conv_tail": (9, 128, 2, 2048), "stats": (12, 7, 2)}
+                  "conv_tail": (9, 128, 2, 2048), "stats": (12, 8, 2)}
     assert {name: leaf.shape for name, leaf in cache.items()} == shapes
     if size == "published":
         held = sum(leaf.size * leaf.dtype.itemsize

@@ -530,13 +530,13 @@ def test_a_fresh_cache_holds_each_kinds_leaves_for_its_layers_only(size):
                         ("full", 1))
         shapes = {"k": (2, 2, 32, 32), "v": (2, 2, 32, 32),
                   "gdn_state": (6, 2, 4, 8, 8), "gdn_conv": (6, 2, 3, 64),
-                  "stats": (8, 7, 2)}
+                  "stats": (8, 8, 2)}
     else:       # the cell: one period, 8 rows, 32,768 positions
         runs, cache = _fresh_cache(CELL, 8, 32768)
         assert runs == (("linear", 3), ("full", 1))
         shapes = {"k": (1, 8, 32768, 512), "v": (1, 8, 32768, 512),
                   "gdn_state": (3, 8, 32, 128, 128),
-                  "gdn_conv": (3, 8, 3, 8192), "stats": (4, 7, 2)}
+                  "gdn_conv": (3, 8, 3, 8192), "stats": (4, 8, 2)}
     assert {name: leaf.shape for name, leaf in cache.items()} == shapes
     held = sum(leaf.size * leaf.dtype.itemsize
                for name, leaf in cache.items() if name != "stats")
