@@ -12,10 +12,18 @@ under `shard_map` over a `jax.sharding.Mesh`:
   stage-sharded; stages with fewer blocks are zero-padded and masked). A tick
   runs them unrolled over per-block arrays sliced out of the stage's stack
   once a call; only the padded slots sit under a `lax.cond`.
-- One `lax.scan` over T = n_microbatches + n_stages - 1 "ticks" runs the
-  fill/steady/drain schedule; the inter-stage edge is `lax.ppermute` over ICI
-  — the collective-permute equivalent of the reference's gloo send/recv
-  threads (p2p:155-258), with zero host involvement in steady state.
+- One `lax.scan` over T "ticks" runs the fill/steady/drain schedule; the
+  inter-stage edge is `lax.ppermute` over ICI — the collective-permute
+  equivalent of the reference's gloo send/recv threads (p2p:155-258), with
+  zero host involvement in steady state.
+- The edge leaves a tick ahead of its use where the round is long enough
+  (`edge_lead`): a tick sends what the stage finished in the last tick and
+  computes on what landed in the last tick, so no block of a tick waits for
+  that tick's transfer and the compiler runs the stage's blocks between
+  `collective-permute-start` and `-done`. Stage s then works on microbatch
+  t - 2s and T = n_microbatches + 2 (n_stages - 1). A short round, or one
+  stage, keeps the transfer at the head of the tick: stage s on microbatch
+  t - s, T = n_microbatches + n_stages - 1 (`SpmdPipeline.n_ticks`).
 - Quantized edges: the payload is encoded to packed uint32 before the
   ppermute and decoded after, so only 32/bit of the activation bytes cross
   the interconnect (QuantPipe on the wire, reference runtime.py:73-119).
@@ -58,7 +66,32 @@ _M_STAGE_BLOCKS = prom.REGISTRY.gauge(
     "unconditional (every stage holds the block) / masked (padding on the "
     "shallower stages, under lax.cond)")
 
+_M_EDGE_LEAD = prom.REGISTRY.gauge(
+    "pipeedge_spmd_edge_lead_ticks",
+    "ticks by which the newest SPMD program's edge leaves ahead of its use: "
+    "1 = the transfer rides behind the stage's blocks, 0 = the tick starts "
+    "with it (one stage, or a round too short to pay the added ticks)")
+_M_TICKS = prom.REGISTRY.gauge(
+    "pipeedge_spmd_ticks",
+    "ticks the newest SPMD program's scan runs a call: microbatches + "
+    "(1 + lead) x (stages - 1)")
+
+# The lead is taken where the ticks it adds are a smaller share of a round,
+# (n_stages - 1) / n_ticks, than what it takes off a tick. On ViT-L's six
+# blocks a stage and a bf16[8,197,1024] payload over four v5e chips a tick
+# fell 2.6% at 128 microbatches and 2.2% at 1,024, and a round was 0.3%
+# slower with the lead at 96 and 0.4% faster at 128 (PERF.md section 6,
+# PR 42: `tools/bench_spmd_lead.py`): four stages break even near 114
+EDGE_LEAD_SHARE = 0.025
+
 BlockRange = Tuple[int, int]
+
+
+def edge_lead(n_ubatch: int, n_stages: int) -> int:
+    """Ticks by which a stage's output leaves ahead of its use (0 or 1),
+    from the call's shapes alone."""
+    added = n_stages - 1    # ticks a round; one stage has no edge to lead
+    return int(0 < added < EDGE_LEAD_SHARE * (n_ubatch + 2 * added))
 
 
 def partition_to_blocks(partition: Sequence[Tuple[int, int]]) -> List[BlockRange]:
@@ -171,7 +204,7 @@ class SpmdPipeline:
         # (tensor.set_tp_quant_bits): keying the cache on it makes a
         # flag flip rebuild instead of silently reusing the stale trace
         key = (inputs.shape, str(inputs.dtype), self.stage_bits,
-               get_tp_quant_bits())
+               get_tp_quant_bits(), edge_lead(inputs.shape[0], self.n_stages))
         fn = self._compiled.get(key)
         if fn is None:
             # the program waits for its input's shape: built at the first
@@ -180,6 +213,12 @@ class SpmdPipeline:
                 fn = self._build(inputs)
             self._compiled[key] = fn
         return fn
+
+    def n_ticks(self, n_ubatch: int) -> int:
+        """Trips of the tick scan a call of `n_ubatch` microbatches makes:
+        stage s works on microbatch t - (1 + lead) s."""
+        lead = edge_lead(n_ubatch, self.n_stages)
+        return n_ubatch + (1 + lead) * (self.n_stages - 1)
 
     def run(self, inputs: jax.Array) -> jax.Array:
         fn = self.compiled_for(inputs)
@@ -195,7 +234,10 @@ class SpmdPipeline:
         min_b = self.min_blocks
         mesh = self.mesh
         n_ubatch = inputs.shape[0]
-        n_ticks = n_ubatch + n_stages - 1
+        lead = edge_lead(n_ubatch, n_stages)
+        n_ticks = self.n_ticks(n_ubatch)
+        _M_EDGE_LEAD.set(lead)
+        _M_TICKS.set(n_ticks)
         dp = mesh.shape.get("dp", 1)
 
         sp = mesh.shape.get("sp", 1)
@@ -447,8 +489,14 @@ class SpmdPipeline:
             outputs0 = jnp.zeros((n_ubatch,) + out_shape.shape, out_shape.dtype)
 
             def tick(carry, t):
-                prev_enc, outputs = carry
-                recv = decode(permute_payload(prev_enc), stage)
+                # `in_flight` is what this stage finished in the last tick.
+                # With the lead, what it computes on is what `landed` in
+                # the last tick, and nothing in this tick reads `arriving`:
+                # the transfer has the stage's blocks to hide behind.
+                # Without, `landed` is empty and the tick waits for it
+                in_flight, landed, outputs = carry
+                arriving = permute_payload(in_flight)
+                recv = decode(landed[0] if lead else arriving, stage)
                 in_idx = jnp.clip(t, 0, n_ubatch - 1)
                 x = jnp.where(is_first, embed_at(in_idx), recv)
                 # Every stage runs its blocks every tick, including fill
@@ -459,7 +507,7 @@ class SpmdPipeline:
                 # cannot shorten any tick — it would only spend the saved
                 # FLOPs on idle waiting at the same wall-clock.
                 h = run_blocks(blocks, n_valid, x)
-                out_idx = t - (n_stages - 1)
+                out_idx = t - (1 + lead) * (n_stages - 1)
 
                 def fin(hh):
                     if sp > 1:
@@ -488,10 +536,12 @@ class SpmdPipeline:
                     + (0,) * len(out_shape.shape))
                 valid = jnp.logical_and(out_idx >= 0, is_last)
                 outputs = jnp.where(valid, updated, outputs)
-                return (encode(h, stage), outputs), None
+                return (encode(h, stage), (arriving,) * lead, outputs), None
 
-            (_, outputs), _ = jax.lax.scan(
-                tick, (zero_carry(act_dtype), outputs0), jnp.arange(n_ticks))
+            (_, _, outputs), _ = jax.lax.scan(
+                tick, (zero_carry(act_dtype),
+                       tuple(zero_carry(act_dtype) for _ in range(lead)),
+                       outputs0), jnp.arange(n_ticks))
             # only the last stage wrote real outputs; fan them back out
             return jax.lax.psum(outputs, "stage")
 

@@ -776,7 +776,7 @@ def run_pipeline_spmd(args, stage_layers, stage_quant, stage_ranks,
         # all-stage aggregate — per-stage attribution comes from the dcn
         # --stage-tp path, where each worker folds its own tally
         blocks_per_stage = max((r - l + 1) // 4 for l, r in stage_layers)
-        ticks = len(ubatches) + n_stages - 1
+        ticks = pipe.n_ticks(len(ubatches))
         summary = qcollectives.record_collectives(
             executions=2 * ticks * max(1, blocks_per_stage))
         logger.info("quantized collectives (--tp-quant-bits %d): %s",

@@ -488,8 +488,11 @@ def test_gpt2_medium_decode_step_keeps_cache_rows_as_rows(on_chip):
 def test_spmd_vit_large_cell_compiles_for_v5e(n_ubatch, topo):
     """`vit-l.spmd-4stage` at its real widths: four stages of six ViT-L
     blocks on the four described chips, microbatches of 8 in bfloat16; a
-    round's 1,027 ticks, which have to fit a chip beside the other staged
-    round (2.5 GB), and a short program of 7. A chip holds its stage's
+    round's 1,030 ticks, which have to fit a chip beside the other staged
+    round (2.5 GB), and a short program of 7. The round takes the edge's
+    lead (`spmd.edge_lead`): in the scheduled module the blocks' fusions
+    run between `collective-permute-start` and its `-done`; the short
+    program keeps the tick that waits. A chip holds its stage's
     weights as the stack it is given and once more as the per-block arrays
     the tick scan reads, never a third time among the temporaries; only the
     short program can tell, a round's two buffers of embedded images (6.6
@@ -528,7 +531,27 @@ def test_spmd_vit_large_cell_compiles_for_v5e(n_ubatch, topo):
         shape, jnp.bfloat16, sharding=NamedSharding(mesh, P()))).compile()
     logits, = jax.tree_util.tree_leaves(compiled.out_info)
     assert logits.shape == (n_ubatch, ubatch, cfg.num_labels)
-    assert "collective-permute" in compiled.as_text()
+    # the edge: a tick's schedule, from its `-start` to its `-done`
+    lead = n_ubatch == 1024
+    assert spmd.edge_lead(n_ubatch, pipe.n_stages) == lead
+    assert pipe.n_ticks(n_ubatch) == (1030 if lead else 7)
+    text = compiled.as_text()
+    assert "is_scheduled=true" in text
+    assert f"constant({pipe.n_ticks(n_ubatch)})" in text    # the trips
+    lines = text.splitlines()
+    start, = [i for i, line in enumerate(lines)
+              if " collective-permute-start(" in line]
+    done, = [i for i, line in enumerate(lines)
+             if " collective-permute-done(" in line]
+    tick = next(i for i in range(start, 0, -1) if lines[i].endswith("{"))
+    end = next(i for i in range(done, len(lines)) if lines[i] == "}")
+    fusions = [i for i in range(tick, end) if " fusion(" in lines[i]]
+    behind = [i for i in fusions if start < i < done]
+    assert len(fusions) >= 4 * per_stage
+    if lead:    # the stage's blocks ride between the two
+        assert len(behind) > 0.9 * len(fusions), (len(behind), len(fusions))
+    else:       # they wait for `-done`
+        assert not behind
     memory = compiled.memory_analysis()
     stage_bytes = per_stage * sum(
         int(np.prod(leaf.shape)) * leaf.dtype.itemsize

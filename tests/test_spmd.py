@@ -256,6 +256,38 @@ def test_spmd_dp_stage_sp_mesh(tiny_vit4):
     np.testing.assert_allclose(got, expected, rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("axis", ["tp", "sp"])
+def test_spmd_edge_lead_on_the_inner_axes(monkeypatch, axis):
+    """`spmd.edge_lead` under a within-stage axis: Megatron blocks, and the
+    sp body, which embeds a microbatch a tick on stage 0 and gathers the
+    chunks on the last stage. With the edge a tick ahead of its use the
+    logits are the waiting schedule's to the bit, and the oracle's."""
+    from transformers import BertConfig, BertForSequenceClassification
+    hf_cfg = BertConfig(**TINY4, vocab_size=100, max_position_embeddings=64,
+                        num_labels=3)
+    torch.manual_seed(3)
+    model = BertForSequenceClassification(hf_cfg).eval()
+    cfg = TransformerConfig(model_type="bert", **TINY4, num_labels=3,
+                            vocab_size=100, max_position_embeddings=64)
+    weights = {k: v.numpy() for k, v in model.state_dict().items()}
+    partition = [(1, 8), (9, 16)]
+    mesh = spmd.make_pipeline_mesh(2, dp=2, **{axis: 2})
+    pipe = spmd.build_spmd_pipeline(
+        bert_mod.FAMILY, cfg, partition,
+        _stage_params(bert_mod, cfg, partition, weights), mesh)
+    ids = jnp.asarray(
+        np.random.default_rng(7).integers(0, 100, size=(5, 4, 12)),
+        dtype=jnp.int32)
+    got = {}
+    for share in (0.0, 1.0):
+        monkeypatch.setattr(spmd, "EDGE_LEAD_SHARE", share)
+        assert spmd.edge_lead(5, pipe.n_stages) == int(share)
+        got[share] = np.asarray(pipe.run(ids))
+    np.testing.assert_array_equal(got[1.0], got[0.0])
+    np.testing.assert_allclose(got[1.0], _expected(bert_mod, cfg, weights, ids),
+                               rtol=2e-4, atol=2e-5)
+
+
 def _tiny_gpt2():
     from transformers import GPT2Config, GPT2LMHeadModel
     hf_cfg = GPT2Config(n_embd=32, n_layer=4, n_head=4, n_inner=64,

@@ -140,6 +140,34 @@ def test_pipeline_grads_match_single_device(setup):
         rgrads, pipe_grads)
 
 
+@pytest.mark.parametrize("remat", [False, True], ids=["saved", "remat"])
+def test_step_is_the_same_on_both_sides_of_the_edges_lead(
+        setup, monkeypatch, remat):
+    """`spmd.edge_lead`: with the edge a tick ahead of its use a training
+    step's loss and updated parameters are the waiting schedule's (the
+    same blocks on the same microbatches in the same order; the added
+    bubble ticks carry zero cotangents), and the backward's transposed
+    permutes have the same room as the forward's."""
+    import optax
+    cfg, weights, pipe, x, y = setup
+    results = {}
+    for share in (0.0, 1.0):
+        monkeypatch.setattr(spmd, "EDGE_LEAD_SHARE", share)
+        fresh = spmd.build_spmd_pipeline(vit_mod.FAMILY, cfg, PARTITION,
+                                         _stage_params(cfg, weights),
+                                         pipe.mesh, remat=remat)
+        assert spmd.edge_lead(x.shape[0], fresh.n_stages) == int(share)
+        assert fresh.n_ticks(x.shape[0]) == x.shape[0] + 1 + int(share)
+        step, opt_state = train.make_train_step(fresh, optax.sgd(0.05), x)
+        params, _, loss = step(fresh.params, opt_state, x, y)
+        results[share] = (float(loss), jax.tree_util.tree_map(np.asarray,
+                                                              params))
+    assert results[0.0][0] == results[1.0][0]
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-8),
+        results[0.0][1], results[1.0][1])
+
+
 def test_train_step_learns_and_shards(setup):
     """A few SGD steps through the pipeline reduce the loss; quantized
     edges are refused."""
