@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.stage_cache import init_cache
 from ..telemetry import metrics as prom
 from ..utils.threads import make_rlock
 
@@ -97,7 +98,6 @@ class KvPagePool:
             raise ValueError(
                 "paged KV covers the host-driven pipeline; tp/ep/sp mesh "
                 "pipelines keep their sharded dense caches")
-        from ..parallel.decode import init_cache
         self.pipe = pipe
         self.n_pages = int(n_pages)
         self.page_size = int(page_size)

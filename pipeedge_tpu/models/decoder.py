@@ -1,0 +1,259 @@
+"""The kit the sparse decoder families share (keye, kimi, qwen3_next, lfm2,
+laguna): what a family is NOT is here, so that its module holds its mixers,
+its cache's leaves, its kinds of block and its key map, and imports no
+other family.
+
+- products with weights stored `[out, in]` (`lin`), in chunks of rows where
+  the result is wide (`in_row_chunks`); the dense SwiGLU (`dense_ffn`) and
+  the routed expert layer (`routed_experts`); either, as a cached block
+  step's FFN, with the counts every family's `STATS` start with (`ffn`);
+- queries in chunks whose scores stay under `SCORE_BYTES` (`query_chunk`,
+  `map_query_chunks` over `split_queries` / `join_queries`);
+- the hooks of a family that embeds tokens alone and runs through the
+  cached decode path only (`token_hooks`);
+- loading: host leaves stacked a run and placed a leaf at a time
+  (`assemble_shard`), and the two loaders that follow from a family's
+  `_assemble(cfg, shard_config, get, dtype)` (`loader`).
+
+It imports `layers`, `shard` and `parallel/expert.py` (which imports
+`layers` only); the cache a block step sees is `models/stage_cache.py`.
+"""
+from __future__ import annotations
+
+from typing import Callable, Dict, Mapping, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from . import ShardConfig
+from ..parallel.expert import topk_ffn_delta
+from .layers import TransformerConfig, exact_dot
+from .shard import build_shard_params
+
+# bytes of float32 attention scores one chunk of queries may hold, and of
+# the three-pass result of one chunk of rows of a wide product
+SCORE_BYTES = 1 << 29
+PRODUCT_BYTES = 1 << 29
+
+# what every family's block step counts first into the cache's `stats`
+# leaf, in this order (`ffn`); a family's own counts follow
+MOE_STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
+             "moe_grouped_calls", "moe_layer_calls")
+
+
+def lin(w: jax.Array, x: jax.Array) -> jax.Array:
+    """x over w stored [out, in] (`exact_dot`)."""
+    return exact_dot(x, w, w_contract=1).astype(x.dtype)
+
+
+def in_row_chunks(fn, x: jax.Array, widest: int) -> jax.Array:
+    """`fn` over the rows of x [B, S, D] -> [B, S, N], in chunks of rows
+    whose three-pass product of width `widest` stays under
+    `PRODUCT_BYTES`."""
+    b, s, d = x.shape
+    rows, n = b * s, 1
+    while rows % (2 * n) == 0 and rows // n * widest * 12 > PRODUCT_BYTES:
+        n *= 2
+    if n == 1:
+        return fn(x)
+    out = jax.lax.map(fn, x.reshape(n, 1, rows // n, d))
+    return out.reshape(b, s, -1)
+
+
+def dense_ffn(p: Dict, normed: jax.Array) -> jax.Array:
+    def swiglu(rows):
+        hidden = jax.nn.silu(lin(p["gate"], rows)) * lin(p["up"], rows)
+        return lin(p["down"], hidden)
+    return in_row_chunks(swiglu, normed, p["gate"].shape[0])
+
+
+def by_head(x: jax.Array, heads: int) -> tuple:
+    """[B, K, heads * Dh] -> one [B, K, Dh] a head."""
+    return tuple(jnp.split(x, heads, axis=-1))
+
+
+def routed_experts(p: Dict, normed, cfg: TransformerConfig):
+    """The routed FFN's delta (with the shared expert's, where the block
+    has one) and counts: `p["experts"]` is the block's own leaves, or
+    `(stack, layer)` where the decode scan keeps the stacked blocks'
+    experts whole (the decode driver's `_run_blocks`)."""
+    experts, layer = p["experts"], None
+    if isinstance(experts, tuple):
+        experts, layer = experts
+    return topk_ffn_delta(
+        dict({name: p[name] for name in ("router", "shared", "shared_gate")
+              if name in p}, experts=experts), normed, cfg, layer=layer)
+
+
+def ffn(p: Dict, normed: jax.Array, cfg: TransformerConfig):
+    """The FFN of a cached block step over `normed`, its input's second
+    norm: the routed experts where the block has a router, else its dense
+    SwiGLU. -> (delta, what the call counts under `MOE_STATS`, int32 [5])."""
+    if "router" in p:
+        delta, moe = routed_experts(p, normed, cfg)
+        return delta, jnp.concatenate([moe.astype(jnp.int32),
+                                       jnp.ones(1, jnp.int32)])
+    return dense_ffn(p["mlp"], normed), jnp.zeros(5, jnp.int32)
+
+
+def query_chunk(n_q: int, scores_per_query_bytes: int) -> int:
+    """The most of `n_q` queries, halved while even, whose float32 scores
+    (`scores_per_query_bytes` a query: rows x heads live at once x keys x 4)
+    stay under `SCORE_BYTES`."""
+    chunk = n_q
+    while chunk > 1 and chunk % 2 == 0 \
+            and chunk * scores_per_query_bytes > SCORE_BYTES:
+        chunk //= 2
+    return chunk
+
+
+def split_queries(x: jax.Array, chunk: int) -> jax.Array:
+    """[B, Q, ...] -> [Q / chunk, B, chunk, ...], what `lax.map` walks."""
+    b, n_q = x.shape[:2]
+    return jnp.moveaxis(
+        x.reshape((b, n_q // chunk, chunk) + x.shape[2:]), 1, 0)
+
+
+def join_queries(x: jax.Array) -> jax.Array:
+    """The way back: [n, B, chunk, ...] -> [B, n * chunk, ...]."""
+    n, b, chunk = x.shape[:3]
+    return jnp.moveaxis(x, 0, 1).reshape((b, n * chunk) + x.shape[3:])
+
+
+def map_query_chunks(fn, chunk: int, queries: tuple, rows: tuple = ()):
+    """`fn(queries, rows)` a chunk of `chunk` queries after another
+    (`query_chunk`; one call where that is all of them): `queries` arrays
+    [B, Q, ...], handed as [B, chunk, ...]; `rows` arrays [Q, ...] (a mask's
+    rows, the queries' positions), handed as [chunk, ...]. `fn` returns its
+    queries' rows [B, chunk, ...], or a tuple of them and counts, which are
+    summed over the chunks. -> [B, Q, ...] (and the counts)."""
+    n_q = queries[0].shape[1]
+    if chunk == n_q:
+        return fn(queries, rows)
+    out = jax.lax.map(lambda xs: fn(*xs), (
+        tuple(split_queries(x, chunk) for x in queries),
+        tuple(x.reshape((n_q // chunk, chunk) + x.shape[1:]) for x in rows)))
+    if isinstance(out, tuple):
+        return (join_queries(out[0]),) \
+            + tuple(jnp.sum(count) for count in out[1:])
+    return join_queries(out)
+
+
+def token_hooks(name: str, dtype, norm: Callable) -> Dict:
+    """`FamilySpec`'s hooks of a family whose embedding is its tokens' rows
+    in `dtype` (positions live in its mixers), that runs through the cached
+    decode path only, and whose head follows `norm(p, x, eps)`."""
+    def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
+        """Token embedding [B, K] -> [B, K, D]."""
+        return jnp.take(pe["wte"], tok, axis=0).astype(dtype)
+
+    def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig):
+        return span_embed(p, input_ids, 0)
+
+    def decode_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
+        return span_embed(pe, tok.reshape(-1, 1), pos)
+
+    def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
+                 attention_fn=None):
+        raise NotImplementedError(
+            f"the {name} family runs through the cached decode path only: "
+            "its blocks come in runs of more than one kind, which the "
+            "forward path (models/shard.py shard_apply) does not scan yet")
+
+    def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig):
+        """Final norm + LM head -> [B, S, vocab] logits."""
+        return lin(p["head"]["w"], norm(p["ln"], hidden, cfg.layer_norm_eps))
+
+    return dict(embed=embed, span_embed=span_embed, decode_embed=decode_embed,
+                sublayer=sublayer, finalize=finalize)
+
+
+# -- loading -------------------------------------------------------------------
+
+def stack(leaves):
+    """One `[n, ...]` array of like leaves, on the host where they are host
+    arrays (the device never holds a layer twice)."""
+    return (np if isinstance(leaves[0], np.ndarray) else jnp).stack(leaves)
+
+
+def on_device(params, dtype, float32: tuple = ()):
+    """Host leaves onto the device in `dtype`, one at a time and each
+    waited for: transfers are asynchronous, and unfenced every leaf's
+    float16 copy from the file would sit on the device beside the
+    converted model (16.8 GB of a 16.9 GB chip, my chip run, PR 27).
+    `float32`: the leaves the family keeps in float32 as published (a
+    router's correction bias), each the last keys of its path."""
+    flat, tree = jax.tree_util.tree_flatten_with_path(params)
+    out = []
+    for path, leaf in flat:
+        keys = tuple(getattr(step, "key", None) for step in path)
+        keep = any(keys[-len(last):] == last for last in float32)
+        out.append(jax.block_until_ready(jnp.asarray(leaf).astype(
+            jnp.float32 if keep else dtype)))
+    return jax.tree_util.tree_unflatten(tree, out)
+
+
+def assemble_shard(shard_config: ShardConfig, get_embed, get_block,
+                   get_final, dtype, kind=None, float32: tuple = ()) -> Dict:
+    """`build_shard_params` of getters whose leaves are host arrays: every
+    leaf stays one until its run of like blocks (`kind(block_id)`) is
+    stacked, then goes to the device (`on_device`). Traced values pass
+    through as well: `jax.eval_shape` over a family's `_assemble` with a
+    `get` of `jnp.zeros` gives a model's shapes without its values."""
+    return on_device(build_shard_params(
+        shard_config, get_embed, get_block, get_final,
+        stack=lambda blocks: jax.tree_util.tree_map(
+            lambda *leaves: stack(leaves), *blocks),
+        kind=kind), dtype, float32)
+
+
+def whole_blocks(name: str, subs: tuple) -> None:
+    """Refuse the sublayers `subs` of a block unless they are all four."""
+    if subs != (0, 1, 2, 3):
+        raise NotImplementedError(
+            f"the {name} family takes whole blocks: a partition that cuts "
+            "one is for the forward path, which it does not run")
+
+
+def norm_ones(key: str, shape: tuple):
+    """The rule of what `init_params` does not draw: a norm's weight is 1."""
+    if key.endswith("norm.weight"):
+        return np.ones(shape, np.float32)
+    return None
+
+
+def loader(assemble: Callable, undrawn: Callable = norm_ones) \
+        -> Tuple[Callable, Callable]:
+    """(load_params, init_params) of a family from its `assemble(cfg,
+    shard_config, get, dtype)`, where `get(key, shape)` is a tensor of the
+    published scheme: read from a state-dict npz and checked against the
+    model's shape (a sliced vocabulary is the table's first rows), or drawn
+    from `seed`, but for the keys `undrawn(key, shape)` gives a value for.
+    One assembly, so the two trees agree leaf by leaf."""
+    def load_params(cfg: TransformerConfig, shard_config: ShardConfig,
+                    weights: Mapping, dtype=jnp.float32) -> Dict:
+        """Shard params from a published-style state-dict npz."""
+        def get(key, shape):
+            value = np.asarray(weights[key])
+            if key in ("model.embed_tokens.weight", "lm_head.weight"):
+                value = value[:shape[0]]
+            if value.shape != shape:
+                raise ValueError(f"{key}: {value.shape} in the file, {shape} "
+                                 "in the model")
+            return value
+        return assemble(cfg, shard_config, get, dtype)
+
+    def init_params(cfg: TransformerConfig, shard_config: ShardConfig,
+                    seed: int = 0, dtype=jnp.float32) -> Dict:
+        """Random shard params with `load_params`' pytree structure."""
+        rng = np.random.default_rng(seed)
+
+        def get(key, shape):
+            value = undrawn(key, shape)
+            if value is None:
+                value = rng.normal(0, 0.02, size=shape).astype(np.float32)
+            return value
+        return assemble(cfg, shard_config, get, dtype)
+
+    return load_params, init_params

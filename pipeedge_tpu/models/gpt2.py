@@ -100,7 +100,7 @@ def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig) -> jax.Array:
 
 
 FAMILY = FamilySpec(name="gpt2", embed=embed, sublayer=sublayer,
-                    finalize=finalize)
+                    finalize=finalize, decoder_model=True)
 
 
 def _a(x, dtype):

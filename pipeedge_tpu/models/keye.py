@@ -22,9 +22,9 @@ k-th largest score by bisection over its bits (`_topk_mask`).
 **Cache.** Three leaves the family names (`cache_leaves`): `k` and `v`
 with the KV heads folded into one axis (`[L, B, T, kv_heads * head_dim]`:
 a 4 x 128 tail would be padded to a tile of 8 or 16 rows on the chip) and
-`ik` `[L, B, T, index_head_dim]`, written and read by the decode
-subsystem's `_write_rows` and `_read_window` like any other; and the
-`stats` leaf the block's counts are added to (parallel/decode.py).
+`ik` `[L, B, T, index_head_dim]`, written and read by the stage cache's
+`write_rows` and `read_window` like any other; and the `stats` leaf the
+block's counts are added to (models/stage_cache.py).
 
 **Precision.** Activations and the cache are float32 whatever the weights
 are stored in, and every product is made to about float32 accuracy
@@ -55,24 +55,21 @@ host.
 """
 from __future__ import annotations
 
-from typing import Dict, Mapping
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import ShardConfig
+from . import ShardConfig, decoder
+from .decoder import by_head, routed_experts
 from .layers import (TransformerConfig, exact_dot, layer_norm, rms_norm,
                      rope_frequencies, rope_rotate)
-from .shard import FamilySpec, build_shard_params
+from .shard import FamilySpec
+from .stage_cache import attend_width, read_window
 
 # what a block step counts into the cache's `stats` leaf, in this order
-STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
-         "moe_grouped_calls", "moe_layer_calls", "sparse_scored", "sparse_kept")
-
-# bytes of float32 attention scores one chunk of queries may hold: the
-# chunk is the largest power of two of query rows that stays under it
-_SCORE_BYTES = 1 << 29
+STATS = decoder.MOE_STATS + ("sparse_scored", "sparse_kept")
 
 
 def prefill_span(cfg: TransformerConfig) -> int:
@@ -220,29 +217,20 @@ def _attend_selected(q, k, v, keep) -> jax.Array:
     return jnp.stack(out, axis=2).astype(q.dtype).reshape(b, s, h * hd)
 
 
-def _by_head(x: jax.Array, heads: int) -> tuple:
-    """[B, K, heads * Dh] -> one [B, K, Dh] a head."""
-    return tuple(jnp.split(x, heads, axis=-1))
-
-
 def sparse_attention(q, iq, iw, q_pos, parts, cfg: TransformerConfig):
     """Attention of the queries at absolute positions `q_pos` [Q] over the
     key `parts`: each (k, v: a [B,K,Dh] a KV head, ik [B,K,Di], k_pos [K],
     live [K] or None). A key may be attended if it is live and not after
     the query; of those the indexer keeps `cfg.index_topk`. Queries run in
-    chunks so that no chunk's scores pass `_SCORE_BYTES`.
+    chunks so that no chunk's scores pass `decoder.SCORE_BYTES`.
 
     Returns (ctx [B, Q, H*Dh], scored, kept): the counts of positions the
     indexer scored and of positions attended, int32."""
     b, n_q, h, _ = q.shape
     n_keys = sum(part[2].shape[1] for part in parts)
-    chunk = n_q     # scores are live one KV group at a time
-    while chunk > 1 and chunk % 2 == 0 \
-            and b * (h // cfg.kv_heads) * chunk * n_keys * 4 > _SCORE_BYTES:
-        chunk //= 2
 
-    def one_chunk(args):
-        q_c, iq_c, iw_c, pos_c = args
+    def one_chunk(queries, positions):
+        (q_c, iq_c, iw_c), (pos_c,) = queries, positions
         valid = []
         for _, _, _, k_pos, live in parts:
             ok = k_pos[None, :] <= pos_c[:, None]
@@ -263,38 +251,11 @@ def sparse_attention(q, iq, iw, q_pos, parts, cfg: TransformerConfig):
         kept = sum(jnp.sum(m, dtype=jnp.int32) for m in keep)
         return ctx, scored, kept
 
-    if chunk == n_q:
-        return one_chunk((q, iq, iw, q_pos))
-    n = n_q // chunk
-
-    def chunks(x):      # [B, Q, ...] -> [n, B, chunk, ...]
-        return jnp.moveaxis(x.reshape((b, n, chunk) + x.shape[2:]), 1, 0)
-
-    ctx, scored, kept = jax.lax.map(
-        one_chunk, (chunks(q), chunks(iq), chunks(iw),
-                    q_pos.reshape(n, chunk)))
-    return (jnp.moveaxis(ctx, 0, 1).reshape(b, n_q, -1),
-            jnp.sum(scored), jnp.sum(kept))
-
-
-def _experts(p: Dict, normed, cfg: TransformerConfig):
-    """The routed FFN's delta (with the shared expert's, where the block
-    has one) and counts: `p["experts"]` is the block's own leaves, or
-    `(stack, layer)` where the decode scan keeps the stacked blocks'
-    experts whole (parallel/decode.py `_run_blocks`)."""
-    from ..parallel.expert import topk_ffn_delta
-    experts, layer = p["experts"], None
-    if isinstance(experts, tuple):
-        experts, layer = experts
-    return topk_ffn_delta(
-        dict({name: p[name] for name in ("router", "shared", "shared_gate")
-              if name in p}, experts=experts), normed, cfg, layer=layer)
-
-
-def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    """Token embedding only: positions live in the rotation. Float32 from
-    here on (module docstring, Precision)."""
-    return jnp.take(p["wte"], input_ids, axis=0).astype(jnp.float32)
+    # scores are live one KV group at a time
+    return decoder.map_query_chunks(
+        one_chunk, decoder.query_chunk(
+            n_q, b * (h // cfg.kv_heads) * n_keys * 4),
+        (q, iq, iw), (q_pos,))
 
 
 def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
@@ -310,15 +271,15 @@ def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
         pos = jnp.arange(normed.shape[1])
         q, k, v, iq, ik, iw = _project(p, normed, cfg, pos)
         ctx, _, _ = sparse_attention(
-            q, iq, iw, pos, [(_by_head(k, cfg.kv_heads),
-                              _by_head(v, cfg.kv_heads), ik, pos, None)], cfg)
+            q, iq, iw, pos, [(by_head(k, cfg.kv_heads),
+                              by_head(v, cfg.kv_heads), ik, pos, None)], cfg)
         return (ctx, data)
     if sub == 1:
         ctx, skip = data
         return _lin(p["attn_out"]["w"], ctx) + skip
     if sub == 2:
         normed = rms_norm(p["ln_after"], data, cfg.layer_norm_eps)
-        return (_experts(p, normed, cfg)[0], data)
+        return (routed_experts(p, normed, cfg)[0], data)
     if sub == 3:
         delta, skip = data
         return delta + skip
@@ -326,31 +287,35 @@ def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
 
 
 def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    """Final RMSNorm + LM head -> [B, S, vocab] logits."""
+    """Final RMSNorm + LM head (stored `[in, out]`) -> [B, S, vocab] logits."""
     return _lin(p["head"]["w"], rms_norm(p["ln"], hidden,
                                          cfg.layer_norm_eps))
 
 
+def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
+    """Token embedding alone [B, K] -> [B, K, D] (positions live in the
+    rotation), float32 from here on (module docstring, Precision)."""
+    return jnp.take(pe["wte"], tok, axis=0).astype(jnp.float32)
+
+
+def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:
+    return span_embed(p, input_ids, 0)
+
+
 def decode_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
-    """Single decode-step token embed [B, 1, D]."""
+    """Single decode-step token embed [B, 1, D]: the rows gathered `[B]`,
+    the axis added after (the kit's gathers `[B, 1]`: another program)."""
     return jnp.take(pe["wte"], tok.reshape(-1), axis=0)[:, None].astype(
         jnp.float32)
 
 
-def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
-    """K-token span embed [B, K] -> [B, K, D]."""
-    return jnp.take(pe["wte"], tok, axis=0).astype(jnp.float32)
-
-
 def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
                       prefill: bool, read_len=None):
-    """KV-cached block (parallel/decode.py's `_block_step` contract): the
+    """KV-cached block (the decode driver's `_block_step` contract): the
     rows of `x` sit at [pos, pos + S), attend the cached window [0, width)
-    below `pos` and themselves, and are recorded for `_write_rows` with
+    below `pos` and themselves, and are recorded for `write_rows` with
     their indexer keys and the step's counts. A prefill (`pos` 0, nothing
     cached) attends its own rows alone."""
-    from ..parallel.decode import _attend_width, _read_window
-
     b, s, _ = x.shape
     normed = rms_norm(p["ln_before"], x, cfg.layer_norm_eps)
     q_pos = jnp.asarray(pos) + jnp.arange(s)
@@ -359,151 +324,101 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
     # through the cache's dtype, as if read back from it
     k, v, ik = (new.astype(stack[name].dtype).astype(x.dtype)
                 for name, new in (("k", k), ("v", v), ("ik", ik)))
-    parts = [(_by_head(k, cfg.kv_heads), _by_head(v, cfg.kv_heads), ik,
+    parts = [(by_head(k, cfg.kv_heads), by_head(v, cfg.kv_heads), ik,
               q_pos, None)]
     if not prefill:
-        width = _attend_width(bcache, read_len)
+        width = attend_width(bcache, read_len)
         lanes = [slice(g * cfg.head_dim, (g + 1) * cfg.head_dim)
                  for g in range(cfg.kv_heads)]
         at = jnp.arange(width)
         parts.insert(0, tuple(
-            tuple(_read_window(stack[name], bcache.layer, width, head)
+            tuple(read_window(stack[name], bcache.layer, width, head)
                   for head in lanes) for name in ("k", "v"))
-            + (_read_window(stack["ik"], bcache.layer, width), at,
+            + (read_window(stack["ik"], bcache.layer, width), at,
                at < pos))
     ctx, scored, kept = sparse_attention(q, iq, iw, q_pos, parts, cfg)
     h = _lin(p["attn_out"]["w"], ctx) + x
-    delta, moe = _experts(p, rms_norm(p["ln_after"], h, cfg.layer_norm_eps),
-                          cfg)
+    delta, moe = routed_experts(
+        p, rms_norm(p["ln_after"], h, cfg.layer_norm_eps), cfg)
     stats = jnp.concatenate([moe.astype(jnp.int32),
                              jnp.stack([jnp.int32(1), scored, kept])])
     rows = {"k": k, "v": v, "ik": ik, "stats": stats}
     return h + delta, bcache._replace(rows=rows)
 
 
+# the hooks are keye's own, not `decoder.token_hooks`: of the five sparse
+# families it alone runs the forward path (`sublayer`), stores its matrices
+# `[in, out]` (`_lin` in `finalize`) and gathers a step's rows `[B]`
 FAMILY = FamilySpec(name="keye", embed=embed, sublayer=sublayer,
                     finalize=finalize, cached_block_step=cached_block_step,
                     decode_embed=decode_embed, span_embed=span_embed,
-                    position_dependent_attention=True,
+                    decoder_model=True, position_dependent_attention=True,
                     cache_leaves=cache_leaves, prefill_span=prefill_span,
                     whole_leaves=("experts",), stats_names=STATS)
 
 
-def _stack_on_host(blocks):
-    """Per-block host leaves -> one host array a leaf, `[L, ...]`."""
-    return jax.tree_util.tree_map(lambda *xs: np.stack(xs), *blocks)
+def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
+              dtype) -> Dict:
+    """Shard params from `get(key, shape)`, a tensor of Qwen3-MoE's HF
+    state dict and our indexer's (module docstring; `decoder.loader`,
+    `assemble_shard`). A linear's kernel `[out, in]` is turned `[in, out]`
+    on the host; the experts' stay as stored. A block cut by the partition
+    (the forward path's) gets the leaves of its sublayers `subs`."""
+    d, f = cfg.hidden_size, cfg.moe_intermediate_size
+    hd, hi = cfg.head_dim, cfg.index_head_dim
+    qd, kvd = cfg.num_attention_heads * hd, cfg.kv_heads * hd
 
+    def scale(key, n):
+        return {"scale": get(key, (n,))}
 
-def _on_device(params, dtype):
-    """Host leaves onto the device in `dtype`, one at a time and each
-    waited for: transfers are asynchronous, and unfenced every leaf's
-    float16 copy from the file would sit on the device beside the
-    converted model (16.8 GB of a 16.9 GB chip, my chip run, PR 27)."""
-    flat, tree = jax.tree_util.tree_flatten(params)
-    for i, leaf in enumerate(flat):
-        flat[i] = jax.block_until_ready(jnp.asarray(leaf).astype(dtype))
-    return jax.tree_util.tree_unflatten(tree, flat)
-
-
-def _t(x) -> np.ndarray:
-    """HF nn.Linear kernel [out, in] -> [in, out], still on the host."""
-    return np.asarray(x).T
-
-
-def load_params(cfg: TransformerConfig, shard_config: ShardConfig,
-                weights: Mapping, dtype=jnp.float32) -> Dict:
-    """Shard params from a Qwen3-MoE-style state-dict npz (module
-    docstring). Every leaf stays a host array until its blocks are
-    stacked, so the device never holds a layer twice."""
-    def get(key):
-        return np.asarray(weights[key])
-
-    def scale(key):
-        return {"scale": get(key)}
+    def turned(key, n_out, n_in):
+        return {"w": get(key, (n_out, n_in)).T}
 
     def get_embed() -> Dict:
-        return {"wte": get("model.embed_tokens.weight")}
+        return {"wte": get("model.embed_tokens.weight", (cfg.vocab_size, d))}
 
     def get_block(block_id: int, subs: tuple) -> Dict:
         root = f"model.layers.{block_id}."
         att, idx = root + "self_attn.", root + "self_attn.indexer."
         p: Dict = {}
         if 0 in subs:
-            p["ln_before"] = scale(root + "input_layernorm.weight")
-            for name in ("q", "k", "v"):
-                p[name] = {"w": _t(get(att + name + "_proj.weight"))}
+            p["ln_before"] = scale(root + "input_layernorm.weight", d)
+            for name, width in (("q", qd), ("k", kvd), ("v", kvd)):
+                p[name] = turned(att + name + "_proj.weight", width, d)
                 if cfg.qk_norm and name != "v":
-                    p[name + "_norm"] = scale(att + name + "_norm.weight")
-            p["index_q"] = {"w": _t(get(idx + "wq.weight"))}
-            p["index_k"] = {"w": _t(get(idx + "wk.weight"))}
-            p["index_w"] = {"w": _t(get(idx + "weights_proj.weight"))}
-            p["index_k_norm"] = {"scale": get(idx + "k_norm.weight"),
-                                 "bias": get(idx + "k_norm.bias")}
+                    p[name + "_norm"] = scale(att + name + "_norm.weight", hd)
+            p["index_q"] = turned(idx + "wq.weight", cfg.index_heads * hi, d)
+            p["index_k"] = turned(idx + "wk.weight", hi, d)
+            p["index_w"] = turned(idx + "weights_proj.weight",
+                                  cfg.index_heads, d)
+            p["index_k_norm"] = {"scale": get(idx + "k_norm.weight", (hi,)),
+                                 "bias": get(idx + "k_norm.bias", (hi,))}
         if 1 in subs:
-            p["attn_out"] = {"w": _t(get(att + "o_proj.weight"))}
+            p["attn_out"] = turned(att + "o_proj.weight", d, qd)
         if 2 in subs:
-            p["ln_after"] = scale(root + "post_attention_layernorm.weight")
-            p["router"] = {"w": _t(get(root + "mlp.gate.weight"))}
+            p["ln_after"] = scale(root + "post_attention_layernorm.weight", d)
+            p["router"] = turned(root + "mlp.gate.weight", cfg.n_experts, d)
             p["experts"] = {
-                name: np.stack([get(f"{root}mlp.experts.{e}.{name}_proj."
-                                    "weight") for e in range(cfg.n_experts)])
-                for name in ("gate", "up", "down")}
+                name: decoder.stack([
+                    get(f"{root}mlp.experts.{e}.{name}_proj.weight", shape)
+                    for e in range(cfg.n_experts)])
+                for name, shape in (("gate", (f, d)), ("up", (f, d)),
+                                    ("down", (d, f)))}
         return p
 
     def get_final() -> Dict:
-        return {"ln": scale("model.norm.weight"),
-                "head": {"w": _t(get("lm_head.weight"))}}
+        return {"ln": scale("model.norm.weight", d),
+                "head": turned("lm_head.weight", cfg.vocab_size, d)}
 
-    return _on_device(build_shard_params(shard_config, get_embed, get_block,
-                                         get_final, stack=_stack_on_host),
-                      dtype)
+    return decoder.assemble_shard(shard_config, get_embed, get_block,
+                                  get_final, dtype)
 
 
-def init_params(cfg: TransformerConfig, shard_config: ShardConfig,
-                seed: int = 0, dtype=jnp.float32) -> Dict:
-    """Random shard params with the same pytree structure as `load_params`."""
-    rng = np.random.default_rng(seed)
-    d, f, e = cfg.hidden_size, cfg.moe_intermediate_size, cfg.n_experts
-    qd, kvd = cfg.num_attention_heads * cfg.head_dim, \
-        cfg.kv_heads * cfg.head_dim
+def _undrawn(key: str, shape: tuple):
+    """What `init_params` does not draw: norms of scale 1 and bias 0."""
+    if key.endswith("norm.bias"):
+        return np.zeros(shape, np.float32)
+    return decoder.norm_ones(key, shape)
 
-    def mat(*shape):
-        return rng.normal(0, 0.02, size=shape).astype(np.float32)
 
-    def ones(n):
-        return {"scale": np.ones((n,), np.float32)}
-
-    def get_embed() -> Dict:
-        return {"wte": mat(cfg.vocab_size, d)}
-
-    def get_block(block_id: int, subs: tuple) -> Dict:
-        p: Dict = {}
-        if 0 in subs:
-            p["ln_before"] = ones(d)
-            p["q"], p["k"], p["v"] = ({"w": mat(d, n)}
-                                      for n in (qd, kvd, kvd))
-            if cfg.qk_norm:
-                p["q_norm"], p["k_norm"] = ones(cfg.head_dim), \
-                    ones(cfg.head_dim)
-            p["index_q"] = {"w": mat(d, cfg.index_heads
-                                     * cfg.index_head_dim)}
-            p["index_k"] = {"w": mat(d, cfg.index_head_dim)}
-            p["index_w"] = {"w": mat(d, cfg.index_heads)}
-            p["index_k_norm"] = {
-                "scale": np.ones((cfg.index_head_dim,), np.float32),
-                "bias": np.zeros((cfg.index_head_dim,), np.float32)}
-        if 1 in subs:
-            p["attn_out"] = {"w": mat(qd, d)}
-        if 2 in subs:
-            p["ln_after"] = ones(d)
-            p["router"] = {"w": mat(d, e)}
-            p["experts"] = {"gate": mat(e, f, d), "up": mat(e, f, d),
-                            "down": mat(e, d, f)}
-        return p
-
-    def get_final() -> Dict:
-        return {"ln": ones(d), "head": {"w": mat(d, cfg.vocab_size)}}
-
-    return _on_device(build_shard_params(shard_config, get_embed, get_block,
-                                         get_final, stack=_stack_on_host),
-                      dtype)
+load_params, init_params = decoder.loader(_assemble, _undrawn)

@@ -38,7 +38,7 @@ in the absorbed form: with `W_kvb` split by head into `W_uk_j`, `W_uv_j`,
 = W_uv_j (sum p c_kv)`: 64 heads over one 576-wide row, and no cached row is
 ever expanded again. One softmax runs over both. A decode step's own row is
 attended in the absorbed form as well. Queries run in chunks so that no
-chunk's scores pass `_SCORE_BYTES`.
+chunk's scores pass `decoder.SCORE_BYTES`.
 
 **Precision.** Weights as stored (bfloat16). Activations and the cache are
 float32: products with weights through `exact_dot` / `exact_einsum`,
@@ -64,30 +64,24 @@ layer's experts the held ones are read, by their published index.
 from __future__ import annotations
 
 import math
-from typing import Dict, Mapping
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import ShardConfig, layers
-from .keye import _experts
-from .layers import (TransformerConfig, exact_dot, exact_einsum, rms_norm,
-                     rope_frequencies)
-from .shard import FamilySpec, build_shard_params
+from . import ShardConfig, decoder, layers
+from .decoder import in_row_chunks, lin
+from .layers import TransformerConfig, exact_einsum, rms_norm, rope_frequencies
+from .shard import FamilySpec
+from .stage_cache import attend_width, read_window
 
 # what a block step counts into the cache's `stats` leaf, in this order
-STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
-         "moe_grouped_calls", "moe_layer_calls", "mla_rows_written", "mla_rows_expanded",
-         "mla_rows_read")
+STATS = decoder.MOE_STATS + ("mla_rows_written", "mla_rows_expanded",
+                             "mla_rows_read")
 
 # activations and cache (module docstring, Precision)
 ACTIVATIONS = jnp.float32
-
-# bytes of float32 attention scores one chunk of queries may hold, and of
-# the three-pass result of one chunk of rows of a wide product
-_SCORE_BYTES = 1 << 29
-_PRODUCT_BYTES = 1 << 29
 
 
 def prefill_span(cfg: TransformerConfig) -> int:
@@ -141,33 +135,14 @@ def rotate(x: jax.Array, pos: jax.Array, cfg: TransformerConfig):
         x.dtype)
 
 
-def _lin(w: jax.Array, x: jax.Array) -> jax.Array:
-    """x over w stored [out, in] (`exact_dot`)."""
-    return exact_dot(x, w, w_contract=1).astype(x.dtype)
-
-
-def _in_row_chunks(fn, x: jax.Array, widest: int) -> jax.Array:
-    """`fn` over the rows of x [B, S, D] -> [B, S, N], in chunks of rows
-    whose three-pass product of width `widest` stays under
-    `_PRODUCT_BYTES`."""
-    b, s, d = x.shape
-    rows, n = b * s, 1
-    while rows % (2 * n) == 0 and rows // n * widest * 12 > _PRODUCT_BYTES:
-        n *= 2
-    if n == 1:
-        return fn(x)
-    out = jax.lax.map(fn, x.reshape(n, 1, rows // n, d))
-    return out.reshape(b, s, -1)
-
-
 def _queries(p: Dict, normed, pos, cfg: TransformerConfig):
     """(q_nope [B,S,H,Dn], q_pe [B,S,H,Dr] rotated) of `normed`."""
     b, s, _ = normed.shape
     heads = cfg.num_attention_heads
     eps = cfg.layer_norm_eps
-    q = _in_row_chunks(
-        lambda rows: _lin(p["q_b"]["w"], rms_norm(
-            p["q_a_norm"], _lin(p["q_a"]["w"], rows), eps)),
+    q = in_row_chunks(
+        lambda rows: lin(p["q_b"]["w"], rms_norm(
+            p["q_a_norm"], lin(p["q_a"]["w"], rows), eps)),
         normed, heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
     q = q.reshape(b, s, heads, -1)
     q_nope, q_pe = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
@@ -177,7 +152,7 @@ def _queries(p: Dict, normed, pos, cfg: TransformerConfig):
 def _latent(p: Dict, normed, pos, cfg: TransformerConfig):
     """(c_kv [B,S,C] normed, k_pe [B,S,Dr] rotated) of `normed`: the cache's
     row."""
-    c_kv, k_pe = jnp.split(_lin(p["kv_a"]["w"], normed),
+    c_kv, k_pe = jnp.split(lin(p["kv_a"]["w"], normed),
                            [cfg.kv_lora_rank], axis=-1)
     return (rms_norm(p["kv_a_norm"], c_kv, cfg.layer_norm_eps),
             rotate(k_pe, pos, cfg))
@@ -241,75 +216,28 @@ def latent_attention(p: Dict, q_nope, q_pe, latent, own,
     b, n_q, heads, _ = q_nope.shape
     n_keys = sum(part[0].shape[1] for part in latent) \
         + (own[0].shape[1] if own is not None else 0)
-    chunk = n_q
-    while chunk > 1 and chunk % 2 == 0 \
-            and b * heads * chunk * n_keys * 4 > _SCORE_BYTES:
-        chunk //= 2
     scale = attention_scale(cfg)
-    if chunk == n_q:
-        return _attend_chunk(p, q_nope, q_pe, latent, own, scale).reshape(
-            b, n_q, -1)
-    n = n_q // chunk
 
-    def chunks(x):      # [B, Q, ...] -> [n, B, chunk, ...]
-        return jnp.moveaxis(x.reshape((b, n, chunk) + x.shape[2:]), 1, 0)
-
-    def one(args):
-        q_n, q_r, keeps = args
+    def one(queries, keeps):
         parts = [part[:2] + (keep,) for part, keep in zip(latent, keeps)]
         mine = None if own is None else own[:3] + (keeps[-1],)
-        return _attend_chunk(p, q_n, q_r, parts, mine, scale)
+        return _attend_chunk(p, *queries, parts, mine, scale)
 
-    keeps = tuple(part[-1].reshape(n, chunk, -1)
-                  for part in list(latent) + ([own] if own is not None
-                                              else []))
-    ctx = jax.lax.map(one, (chunks(q_nope), chunks(q_pe), keeps))
-    return jnp.moveaxis(ctx, 0, 1).reshape(b, n_q, -1)
-
-
-def _dense_ffn(p: Dict, normed: jax.Array) -> jax.Array:
-    def swiglu(rows):
-        hidden = jax.nn.silu(_lin(p["gate"], rows)) * _lin(p["up"], rows)
-        return _lin(p["down"], hidden)
-    return _in_row_chunks(swiglu, normed, p["gate"].shape[0])
-
-
-def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
-    """Token embedding [B, K] -> [B, K, D]: positions live in the rotation."""
-    return jnp.take(pe["wte"], tok, axis=0).astype(ACTIVATIONS)
-
-
-def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    return span_embed(p, input_ids, 0)
-
-
-def decode_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
-    return span_embed(pe, tok.reshape(-1, 1), pos)
-
-
-def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
-             attention_fn=None):
-    raise NotImplementedError(
-        "the kimi family runs through the cached decode path only: its "
-        "blocks come in runs of two kinds, which the forward path "
-        "(models/shard.py shard_apply) does not scan yet")
-
-
-def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    """Final RMSNorm + LM head -> [B, S, vocab] logits."""
-    return _lin(p["head"]["w"], rms_norm(p["ln"], hidden,
-                                         cfg.layer_norm_eps))
+    ctx = decoder.map_query_chunks(
+        one, decoder.query_chunk(n_q, b * heads * n_keys * 4),
+        (q_nope, q_pe),
+        tuple(part[-1] for part in list(latent) + ([own] if own is not None
+                                                   else [])))
+    return ctx.reshape(b, n_q, -1)
 
 
 def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
                       prefill: bool, read_len=None):
-    """Cached block (parallel/decode.py's `_block_step` contract): the rows
+    """Cached block (the decode driver's `_block_step` contract): the rows
     of `x` sit at [pos, pos + S), attend the cached window below `pos` in
     the absorbed form and themselves in the expanded form (a single row:
-    absorbed too), and are recorded for `_write_rows` as latent rows with
+    absorbed too), and are recorded for `write_rows` as latent rows with
     the step's counts. A prefill (`pos` 0) reads no cache."""
-    from ..parallel.decode import _attend_width, _read_window
-
     b, s, _ = x.shape
     normed = rms_norm(p["ln_before"], x, cfg.layer_norm_eps)
     q_pos = jnp.asarray(pos) + jnp.arange(s)
@@ -326,60 +254,35 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
     else:
         latent.append((c_kv, k_pe, causal))
     if not prefill:
-        width = _attend_width(bcache, read_len)
+        width = attend_width(bcache, read_len)
         live = jnp.broadcast_to(jnp.arange(width) < pos, (s, width))
-        latent.insert(0, (_read_window(stack["c_kv"], bcache.layer, width),
-                          _read_window(stack["k_pe"], bcache.layer, width),
+        latent.insert(0, (read_window(stack["c_kv"], bcache.layer, width),
+                          read_window(stack["k_pe"], bcache.layer, width),
                           live))
         read = (b * jnp.asarray(pos)).astype(jnp.int32)
     ctx = latent_attention(p, q_nope, q_pe, latent, own, cfg)
-    h = _lin(p["attn_out"]["w"], ctx) + x
-    normed = rms_norm(p["ln_after"], h, cfg.layer_norm_eps)
-    if "router" in p:
-        delta, moe = _experts(p, normed, cfg)
-        moe = jnp.concatenate([moe.astype(jnp.int32), jnp.ones(1, jnp.int32)])
-    else:
-        delta, moe = _dense_ffn(p["mlp"], normed), jnp.zeros(5, jnp.int32)
+    h = lin(p["attn_out"]["w"], ctx) + x
+    delta, moe = decoder.ffn(p, rms_norm(p["ln_after"], h, cfg.layer_norm_eps),
+                             cfg)
     stats = jnp.concatenate([moe, jnp.stack(
         [jnp.int32(b * s), jnp.int32(b * s if s > 1 else 0), read])])
     return h + delta, bcache._replace(
         rows={"c_kv": c_kv, "k_pe": k_pe, "stats": stats})
 
 
-FAMILY = FamilySpec(name="kimi", embed=embed, sublayer=sublayer,
-                    finalize=finalize, cached_block_step=cached_block_step,
-                    decode_embed=decode_embed, span_embed=span_embed,
-                    position_dependent_attention=True,
+FAMILY = FamilySpec(name="kimi", cached_block_step=cached_block_step,
+                    **decoder.token_hooks("kimi", ACTIVATIONS, rms_norm),
+                    decoder_model=True, position_dependent_attention=True,
                     cache_leaves=cache_leaves, prefill_span=prefill_span,
                     whole_leaves=("experts",), stats_names=STATS,
                     block_kind=block_kind)
 
 
-def _stack(leaves):
-    """One `[n, ...]` array of like leaves, on the host where they are host
-    arrays (the device never holds a layer twice)."""
-    return (np if isinstance(leaves[0], np.ndarray) else jnp).stack(leaves)
-
-
-def _on_device(params, dtype):
-    """Host leaves onto the device in `dtype` (the router's correction bias
-    stays float32, as published), one at a time and each waited for
-    (models/keye.py `_on_device`)."""
-    flat, tree = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for path, leaf in flat:
-        keep = getattr(path[-1], "key", None) == "bias"
-        out.append(jax.block_until_ready(jnp.asarray(leaf).astype(
-            jnp.float32 if keep else dtype)))
-    return jax.tree_util.tree_unflatten(tree, out)
-
-
 def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
               dtype) -> Dict:
-    """Shard params from `get(key, shape)`, a tensor of the published
-    scheme: every leaf stays a host array until its run is stacked. (Traced
-    values pass through as well: `jax.eval_shape` over this with a `get` of
-    `jnp.zeros` gives the model's shapes without its 3.5 G values.)"""
+    """Shard params from `get(key, shape)`, a tensor of DeepSeek-V3's HF
+    state dict (module docstring; `decoder.loader`, `assemble_shard`). The
+    router's correction bias stays float32, as published."""
     d, heads = cfg.hidden_size, cfg.num_attention_heads
     nope, rope, v_dim = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
         cfg.v_head_dim
@@ -399,10 +302,7 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
                            (cfg.vocab_size, d))}
 
     def get_block(block_id: int, subs: tuple) -> Dict:
-        if subs != (0, 1, 2, 3):
-            raise NotImplementedError(
-                "the kimi family takes whole blocks: a partition that cuts "
-                "one is for the forward path, which it does not run")
+        decoder.whole_blocks("kimi", subs)
         root = f"model.layers.{block_id}."
         att = root + "self_attn."
         kv_b = get(att + "kv_b_proj.weight", (heads * (nope + v_dim), rank))
@@ -430,7 +330,7 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
                         (cfg.n_experts,))}
         held = [mlp(f"{root}mlp.experts.{e}.", f)
                 for e in range(first, first + count)]
-        p["experts"] = {name: _stack([one[name] for one in held])
+        p["experts"] = {name: decoder.stack([one[name] for one in held])
                         for name in ("gate", "up", "down")}
         p["shared"] = mlp(root + "mlp.shared_experts.",
                           f * cfg.n_shared_experts)
@@ -440,35 +340,10 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
         return {"ln": scale("model.norm.weight", d),
                 "head": {"w": get("lm_head.weight", (cfg.vocab_size, d))}}
 
-    return _on_device(build_shard_params(
-        shard_config, get_embed, get_block, get_final,
-        stack=lambda blocks: jax.tree_util.tree_map(
-            lambda *leaves: _stack(leaves), *blocks),
-        kind=lambda block_id: block_kind(cfg, block_id)), dtype)
+    return decoder.assemble_shard(
+        shard_config, get_embed, get_block, get_final, dtype,
+        kind=lambda block_id: block_kind(cfg, block_id),
+        float32=(("router", "bias"),))
 
 
-def load_params(cfg: TransformerConfig, shard_config: ShardConfig,
-                weights: Mapping, dtype=jnp.float32) -> Dict:
-    """Shard params from a DeepSeek-V3-style state-dict npz (module
-    docstring). A sliced vocabulary is the table's first rows."""
-    def get(key, shape):
-        value = np.asarray(weights[key])
-        if key in ("model.embed_tokens.weight", "lm_head.weight"):
-            value = value[:shape[0]]
-        if value.shape != shape:
-            raise ValueError(f"{key}: {value.shape} in the file, {shape} "
-                             "in the model")
-        return value
-    return _assemble(cfg, shard_config, get, dtype)
-
-
-def init_params(cfg: TransformerConfig, shard_config: ShardConfig,
-                seed: int = 0, dtype=jnp.float32) -> Dict:
-    """Random shard params with the same pytree structure as `load_params`."""
-    rng = np.random.default_rng(seed)
-
-    def get(key, shape):
-        if key.endswith("norm.weight"):
-            return np.ones(shape, np.float32)
-        return rng.normal(0, 0.02, size=shape).astype(np.float32)
-    return _assemble(cfg, shard_config, get, dtype)
+load_params, init_params = decoder.loader(_assemble)

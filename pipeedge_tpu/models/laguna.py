@@ -30,7 +30,7 @@ times `sigmoid(g_proj u)`, one gate a head, before `o_proj`.
 `CacheLeaf`). The full blocks own `k`, `v` `[L_full, B, T, G*Dh]`, a row a
 position up to the stage's `max_len`, read as the ladder's window
 (`attend_bucket`). The sliding blocks own `k_ring`, `v_ring` `[L_sliding, B,
-W, G*Dh]`, a RING of the last `W` positions (parallel/decode.py, "A ring"):
+W, G*Dh]`, a RING of the last `W` positions (models/stage_cache.py, "A ring"):
 written at `pos mod W`, read whole at every position, masked by what each
 slot holds. At 32 rows x 8,192 positions in float32 the three window layers
 of the cell's cut keep 0.40 GB where rows a position would be 6.4 GB.
@@ -61,31 +61,26 @@ shared_expert_gate.weight` in an expert layer; `model.embed_tokens`,
 """
 from __future__ import annotations
 
-from typing import Dict, Mapping
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import ShardConfig
-from .keye import _experts
-from .kimi import _dense_ffn, _in_row_chunks, _lin, _on_device, _stack
+from . import ShardConfig, decoder
+from .decoder import in_row_chunks, lin
 from .layers import (TransformerConfig, rms_norm, rotate_halves,
                      rope_frequencies, yarn_frequencies)
-from .shard import CacheLeaf, FamilySpec, build_shard_params
+from .shard import CacheLeaf, FamilySpec
+from .stage_cache import Window, cache_update_and_read, read_window
 
 # what a block step counts into the cache's `stats` leaf, in this order
-STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
-         "moe_grouped_calls", "moe_layer_calls", "swa_positions_read", "swa_positions_live",
-         "swa_ring_wraps")
+STATS = decoder.MOE_STATS + ("swa_positions_read", "swa_positions_live",
+                             "swa_ring_wraps")
 
 # activations and cache (module docstring, Precision)
 ACTIVATIONS = jnp.float32
 _ATTENTION = jax.lax.Precision.HIGHEST
-
-# bytes of float32 attention scores one chunk of queries may hold (one KV
-# group's at a time)
-_SCORE_BYTES = 1 << 29
 
 _MIXERS = {"full_attention": "full", "sliding_attention": "sliding"}
 _FFNS = ("dense", "routed")
@@ -174,7 +169,7 @@ def _attend_group(q, ks, vs, keeps):
 
 def attend(q, ks, vs, keeps) -> jax.Array:
     """Grouped-query attention of q [B, Q, H, Dh] over key parts as
-    `decode._cache_update_and_read` hands them with `unread`: a cached
+    `stage_cache.cache_update_and_read` hands them with `unread`: a cached
     window as a `Window`, the call's rows `[B, S, G, Dh]`; `keeps` a [Q, K] a
     part. A KV group at a time: its lanes of the window are read when the
     group before is done (each read waits for that group's context, through
@@ -182,38 +177,26 @@ def attend(q, ks, vs, keeps) -> jax.Array:
     live at a time and not all of them, which a span's loop over chunks of
     queries would otherwise hold from its start (2.1 GB a full layer in the
     cell); within a group the queries in chunks whose scores stay under
-    `_SCORE_BYTES`. -> [B, Q, H, Dh]."""
-    from ..parallel.decode import Window, _read_window
-
+    `decoder.SCORE_BYTES`. -> [B, Q, H, Dh]."""
     b, n_q, h, hd = q.shape
     groups = ks[-1].shape[2]
     n_keys = sum(keep.shape[1] for keep in keeps)
-    chunk = n_q
-    while chunk > 1 and chunk % 2 == 0 and \
-            b * (h // groups) * chunk * n_keys * 4 > _SCORE_BYTES:
-        chunk //= 2
-    n = n_q // chunk
+    chunk = decoder.query_chunk(n_q, b * (h // groups) * n_keys * 4)
     q = q.reshape(b, n_q, groups, h // groups, hd)
     out, done = [], 0
     for grp in range(groups):
         def mine(part, grp=grp, done=done):
             if isinstance(part, Window):
-                return _read_window(part.buf, part.layer + done, part.width,
-                                    slice(grp * hd, (grp + 1) * hd)
-                                    ).astype(q.dtype)
+                return read_window(part.buf, part.layer + done, part.width,
+                                   slice(grp * hd, (grp + 1) * hd)
+                                   ).astype(q.dtype)
             return part[:, :, grp]
 
         k_g, v_g = [mine(k) for k in ks], [mine(v) for v in vs]
-        if n == 1:
-            ctx = _attend_group(q[:, :, grp], k_g, v_g, keeps)
-        else:
-            ctx = jax.lax.map(
-                lambda xs, k_g=k_g, v_g=v_g: _attend_group(xs[0], k_g, v_g,
-                                                           xs[1]),
-                (jnp.moveaxis(q[:, :, grp].reshape(b, n, chunk, -1, hd), 1,
-                              0),
-                 tuple(keep.reshape(n, chunk, -1) for keep in keeps)))
-            ctx = jnp.moveaxis(ctx, 0, 1).reshape(b, n_q, -1, hd)
+        ctx = decoder.map_query_chunks(
+            lambda queries, masks, k_g=k_g, v_g=v_g: _attend_group(
+                queries[0], k_g, v_g, masks),
+            chunk, (q[:, :, grp],), tuple(keeps))
         # `done` is zero, and known only when this group's context is
         ctx, done = jax.lax.optimization_barrier((ctx, jnp.int32(0)))
         out.append(ctx)
@@ -226,8 +209,6 @@ def attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
     layer's leaves hold below `pos` and its own rows. -> (out, the cache
     with the rows recorded, counts int32 [3]: the window layers'
     `swa_positions_read`, `swa_positions_live`, `swa_ring_wraps`)."""
-    from ..parallel.decode import _cache_update_and_read
-
     b, s, _ = normed.shape
     eps, hd, groups = cfg.layer_norm_eps, cfg.head_dim, cfg.kv_heads
     sliding = "k_ring" in bcache.stack
@@ -238,16 +219,16 @@ def attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
             f"heads of {hd} was built with q_proj {p['q']['w'].shape} and "
             f"g_proj {p['gate']['w'].shape}")
     q_pos = jnp.asarray(pos) + jnp.arange(s)
-    q = _in_row_chunks(lambda rows: _lin(p["q"]["w"], rows), normed,
-                       heads * hd).reshape(b, s, heads, hd)
-    k = _lin(p["k"]["w"], normed).reshape(b, s, groups, hd)
-    v = _lin(p["v"]["w"], normed).reshape(b, s, groups, hd)
-    gate = jax.nn.sigmoid(_lin(p["gate"]["w"], normed))        # [B, S, H]
+    q = in_row_chunks(lambda rows: lin(p["q"]["w"], rows), normed,
+                      heads * hd).reshape(b, s, heads, hd)
+    k = lin(p["k"]["w"], normed).reshape(b, s, groups, hd)
+    v = lin(p["v"]["w"], normed).reshape(b, s, groups, hd)
+    gate = jax.nn.sigmoid(lin(p["gate"]["w"], normed))        # [B, S, H]
     q = rotate(rms_norm(p["q_norm"], q, eps), q_pos, cfg, sliding)
     k = rotate(rms_norm(p["k_norm"], k, eps), q_pos, cfg, sliding)
     leaves = dict(window=cfg.sliding_window, names=("k_ring", "v_ring"),
                   ring=True) if sliding else dict(read_len=read_len)
-    ks, vs, keeps, bcache = _cache_update_and_read(
+    ks, vs, keeps, bcache = cache_update_and_read(
         bcache, k, v, pos, prefill, s, normed.dtype, unread=True, **leaves)
     counts = jnp.zeros(3, jnp.int32)
     if sliding:
@@ -257,64 +238,29 @@ def attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
             b * sum(jnp.sum(keep, dtype=jnp.int32) for keep in keeps),
             (jnp.asarray(pos) % ring + s > ring).astype(jnp.int32)])
     ctx = attend(q, ks, vs, keeps) * gate[..., None]
-    return _lin(p["attn_out"]["w"], ctx.reshape(b, s, heads * hd)), \
+    return lin(p["attn_out"]["w"], ctx.reshape(b, s, heads * hd)), \
         bcache, counts
-
-
-# -- the family's hooks --------------------------------------------------------
-
-def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
-    """Token embedding [B, K] -> [B, K, D]: positions live in the rotation."""
-    return jnp.take(pe["wte"], tok, axis=0).astype(ACTIVATIONS)
-
-
-def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    return span_embed(p, input_ids, 0)
-
-
-def decode_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
-    return span_embed(pe, tok.reshape(-1, 1), pos)
-
-
-def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
-             attention_fn=None):
-    raise NotImplementedError(
-        "the laguna family runs through the cached decode path only: its "
-        "blocks come in runs of up to four kinds, which the forward path "
-        "(models/shard.py shard_apply) does not scan yet")
-
-
-def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    """Final RMSNorm + the untied head -> logits."""
-    return _lin(p["head"]["w"], rms_norm(p["ln"], hidden,
-                                         cfg.layer_norm_eps))
 
 
 def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
                       prefill: bool, read_len=None):
-    """Cached block (parallel/decode.py's `_block_step` contract) of any of
+    """Cached block (the decode driver's `_block_step` contract) of any of
     the kinds. The rows of `x` sit at [pos, pos + S): a full block attends
     the cached window below `pos`, a sliding block its ring, both their own
-    rows, and their keys and values are recorded for `_write_rows`."""
+    rows, and their keys and values are recorded for `write_rows`."""
     eps = cfg.layer_norm_eps
     mixed, bcache, counts = attention(
         p, rms_norm(p["ln_before"], x, eps), bcache, pos, cfg, prefill,
         read_len)
     h = x + mixed
-    normed = rms_norm(p["ln_after"], h, eps)
-    if "router" in p:
-        delta, moe = _experts(p, normed, cfg)
-        moe = jnp.concatenate([moe.astype(jnp.int32), jnp.ones(1, jnp.int32)])
-    else:
-        delta, moe = _dense_ffn(p["mlp"], normed), jnp.zeros(5, jnp.int32)
+    delta, moe = decoder.ffn(p, rms_norm(p["ln_after"], h, eps), cfg)
     return h + delta, bcache._replace(
         rows=dict(bcache.rows, stats=jnp.concatenate([moe, counts])))
 
 
-FAMILY = FamilySpec(name="laguna", embed=embed, sublayer=sublayer,
-                    finalize=finalize, cached_block_step=cached_block_step,
-                    decode_embed=decode_embed, span_embed=span_embed,
-                    position_dependent_attention=True,
+FAMILY = FamilySpec(name="laguna", cached_block_step=cached_block_step,
+                    **decoder.token_hooks("laguna", ACTIVATIONS, rms_norm),
+                    decoder_model=True, position_dependent_attention=True,
                     cache_leaves=cache_leaves, prefill_span=prefill_span,
                     whole_leaves=("experts",), stats_names=STATS,
                     block_kind=block_kind)
@@ -325,8 +271,7 @@ FAMILY = FamilySpec(name="laguna", embed=embed, sublayer=sublayer,
 def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
               dtype) -> Dict:
     """Shard params from `get(key, shape)`, a tensor of the published
-    scheme (models/kimi.py `_assemble`: host leaves until a run is stacked;
-    traced values pass through, for `jax.eval_shape`)."""
+    scheme (module docstring; `decoder.loader`, `assemble_shard`)."""
     d, groups, hd = cfg.hidden_size, cfg.kv_heads, cfg.head_dim
     first, count = cfg.held_experts or (0, cfg.n_experts)
 
@@ -343,10 +288,7 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
                            (cfg.vocab_size, d))}
 
     def get_block(block_id: int, subs: tuple) -> Dict:
-        if subs != (0, 1, 2, 3):
-            raise NotImplementedError(
-                "the laguna family takes whole blocks: a partition that "
-                "cuts one is for the forward path, which it does not run")
+        decoder.whole_blocks("laguna", subs)
         root = f"model.layers.{block_id}."
         att = root + "self_attn."
         heads = cfg.layer_heads[block_id]
@@ -366,7 +308,7 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
                                 (cfg.n_experts, d)).T}
         held = [mlp(f"{root}mlp.experts.{e}.", cfg.moe_intermediate_size)
                 for e in range(first, first + count)]
-        p["experts"] = {name: _stack([one[name] for one in held])
+        p["experts"] = {name: decoder.stack([one[name] for one in held])
                         for name in ("gate", "up", "down")}
         p["shared"] = mlp(root + "mlp.shared_expert.",
                           cfg.moe_intermediate_size * cfg.n_shared_experts)
@@ -378,35 +320,9 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
         return {"ln": scale("model.norm.weight", d),
                 "head": {"w": get("lm_head.weight", (cfg.vocab_size, d))}}
 
-    return _on_device(build_shard_params(
-        shard_config, get_embed, get_block, get_final,
-        stack=lambda blocks: jax.tree_util.tree_map(
-            lambda *leaves: _stack(leaves), *blocks),
-        kind=lambda block_id: block_kind(cfg, block_id)), dtype)
+    return decoder.assemble_shard(
+        shard_config, get_embed, get_block, get_final, dtype,
+        kind=lambda block_id: block_kind(cfg, block_id))
 
 
-def load_params(cfg: TransformerConfig, shard_config: ShardConfig,
-                weights: Mapping, dtype=jnp.float32) -> Dict:
-    """Shard params from a published-style state-dict npz (module
-    docstring). A sliced vocabulary is the table's first rows."""
-    def get(key, shape):
-        value = np.asarray(weights[key])
-        if key in ("model.embed_tokens.weight", "lm_head.weight"):
-            value = value[:shape[0]]
-        if value.shape != shape:
-            raise ValueError(f"{key}: {value.shape} in the file, {shape} "
-                             "in the model")
-        return value
-    return _assemble(cfg, shard_config, get, dtype)
-
-
-def init_params(cfg: TransformerConfig, shard_config: ShardConfig,
-                seed: int = 0, dtype=jnp.float32) -> Dict:
-    """Random shard params with the same pytree structure as `load_params`."""
-    rng = np.random.default_rng(seed)
-
-    def get(key, shape):
-        if key.endswith(("norm.weight", "layernorm.weight")):
-            return np.ones(shape, np.float32)
-        return rng.normal(0, 0.02, size=shape).astype(np.float32)
-    return _assemble(cfg, shard_config, get, dtype)
+load_params, init_params = decoder.loader(_assemble)

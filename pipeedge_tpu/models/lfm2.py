@@ -19,7 +19,7 @@ out_proj(C * c)`. Its whole state is `m` at the last `K - 1` positions.
 
 **Attention.** GQA; q and k are RMS-normed a head before the rotation,
 which turns the whole head (halves layout); causal softmax at `Dh**-0.5`.
-The window is read in its stored form (parallel/decode.py `_attend`).
+The window is read in its stored form (models/stage_cache.py `attend`).
 
 **Cache: a leaf that blocks of two kinds own** (`cache_leaves`,
 models/shard.py `CacheLeaf`). The attention blocks own `k`, `v` `[L_attn,
@@ -62,22 +62,21 @@ SwiGLU is `w2(silu(w1 u) * w3 u)`.
 """
 from __future__ import annotations
 
-from typing import Dict, Mapping
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from . import ShardConfig
-from .keye import _experts
-from .kimi import _dense_ffn, _in_row_chunks, _lin, _on_device, _stack
+from . import ShardConfig, decoder
+from .decoder import in_row_chunks, lin
 from .layers import TransformerConfig, causal_conv, rms_norm, rope_rotate
-from .shard import CacheLeaf, FamilySpec, build_shard_params
+from .shard import CacheLeaf, FamilySpec
+from .stage_cache import attend, cache_update_and_read
 
 # what a block step counts into the cache's `stats` leaf, in this order
-STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
-         "moe_grouped_calls", "moe_layer_calls", "shortconv_positions_spanned",
-         "shortconv_positions_stepped", "shortconv_tail_carries")
+STATS = decoder.MOE_STATS + ("shortconv_positions_spanned",
+                             "shortconv_positions_stepped",
+                             "shortconv_tail_carries")
 
 # activations, cache and tail (module docstring, Precision)
 ACTIVATIONS = jnp.float32
@@ -119,11 +118,11 @@ def short_conv(p: Dict, normed, tail, cfg: TransformerConfig):
     """The gated short convolution of `normed` [B, S, D] after `tail`
     [B, K - 1, D], its inputs `m` at the positions before. -> (out [B, S,
     D], the tail after the span)."""
-    gates = _in_row_chunks(lambda rows: _lin(p["conv_in"], rows), normed,
-                           p["conv_in"].shape[0])
+    gates = in_row_chunks(lambda rows: lin(p["conv_in"], rows), normed,
+                          p["conv_in"].shape[0])
     before, after, u = jnp.split(gates, 3, axis=-1)         # B, C, x
     mixed, tail = causal_conv(p["conv"], before * u, tail)
-    return _lin(p["conv_out"], (after * mixed).astype(normed.dtype)), tail
+    return lin(p["conv_out"], (after * mixed).astype(normed.dtype)), tail
 
 
 def attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
@@ -131,60 +130,28 @@ def attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
     """GQA of `normed` [B, S, D] at [pos, pos + S) over the cached window
     below `pos` and its own rows. -> (out, the cache with the rows k, v
     recorded)."""
-    from ..parallel.decode import _attend, _cache_update_and_read
-
     b, s, _ = normed.shape
     eps, hd = cfg.layer_norm_eps, cfg.head_dim
     q_pos = jnp.asarray(pos) + jnp.arange(s)
-    q = _lin(p["q"]["w"], normed).reshape(b, s, cfg.num_attention_heads, hd)
-    k = _lin(p["k"]["w"], normed).reshape(b, s, cfg.kv_heads, hd)
-    v = _lin(p["v"]["w"], normed).reshape(b, s, cfg.kv_heads, hd)
+    q = lin(p["q"]["w"], normed).reshape(b, s, cfg.num_attention_heads, hd)
+    k = lin(p["k"]["w"], normed).reshape(b, s, cfg.kv_heads, hd)
+    v = lin(p["v"]["w"], normed).reshape(b, s, cfg.kv_heads, hd)
     q = rope_rotate(rms_norm(p["q_norm"], q, eps), q_pos, cfg.rope_theta)
     k = rope_rotate(rms_norm(p["k_norm"], k, eps), q_pos, cfg.rope_theta)
-    k, v, keep, bcache = _cache_update_and_read(
+    k, v, keep, bcache = cache_update_and_read(
         bcache, k, v, pos, prefill, s, normed.dtype, read_len=read_len)
-    ctx = _attend(q, k, v, keep, cfg, precision=_ATTENTION)
-    return _lin(p["attn_out"]["w"], ctx), bcache
-
-
-# -- the family's hooks --------------------------------------------------------
-
-def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
-    """Token embedding [B, K] -> [B, K, D]: positions live in the rotation
-    and in the convolution's tail."""
-    return jnp.take(pe["wte"], tok, axis=0).astype(ACTIVATIONS)
-
-
-def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    return span_embed(p, input_ids, 0)
-
-
-def decode_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
-    return span_embed(pe, tok.reshape(-1, 1), pos)
-
-
-def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
-             attention_fn=None):
-    raise NotImplementedError(
-        "the lfm2 family runs through the cached decode path only: its "
-        "blocks come in runs of up to four kinds, which the forward path "
-        "(models/shard.py shard_apply) does not scan yet")
-
-
-def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    """`embedding_norm` (the final RMSNorm) + the tied head -> logits."""
-    return _lin(p["head"]["w"], rms_norm(p["ln"], hidden,
-                                         cfg.layer_norm_eps))
+    ctx = attend(q, k, v, keep, cfg, precision=_ATTENTION)
+    return lin(p["attn_out"]["w"], ctx), bcache
 
 
 def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
                       prefill: bool, read_len=None):
-    """Cached block (parallel/decode.py's `_block_step` contract) of any of
+    """Cached block (the decode driver's `_block_step` contract) of any of
     the four kinds. The rows of `x` sit at [pos, pos + S). A convolution
     block takes its tail from the cache (a prefill, at `pos` 0: zeros) and
     records what it is after the span, which takes its place; an attention
     block attends the cached window below `pos` and its own rows and
-    records their keys and values for `_write_rows`."""
+    records their keys and values for `write_rows`."""
     b, s, _ = x.shape
     eps = cfg.layer_norm_eps
     normed = rms_norm(p["ln_before"], x, eps)
@@ -203,20 +170,16 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
         mixed, bcache = attention(p, normed, bcache, pos, cfg, prefill,
                                   read_len)
     h = x + mixed
-    normed = rms_norm(p["ln_after"], h, eps)
-    if "router" in p:
-        delta, moe = _experts(p, normed, cfg)
-        moe = jnp.concatenate([moe.astype(jnp.int32), jnp.ones(1, jnp.int32)])
-    else:
-        delta, moe = _dense_ffn(p["mlp"], normed), jnp.zeros(5, jnp.int32)
+    delta, moe = decoder.ffn(p, rms_norm(p["ln_after"], h, eps), cfg)
     return h + delta, bcache._replace(
         rows=dict(bcache.rows, stats=jnp.concatenate([moe, counts])))
 
 
-FAMILY = FamilySpec(name="lfm2", embed=embed, sublayer=sublayer,
-                    finalize=finalize, cached_block_step=cached_block_step,
-                    decode_embed=decode_embed, span_embed=span_embed,
-                    position_dependent_attention=True,
+# the head is the embedding, tied, after `embedding_norm`; positions live in
+# the rotation and in the convolution's tail
+FAMILY = FamilySpec(name="lfm2", cached_block_step=cached_block_step,
+                    **decoder.token_hooks("lfm2", ACTIVATIONS, rms_norm),
+                    decoder_model=True, position_dependent_attention=True,
                     cache_leaves=cache_leaves, prefill_span=prefill_span,
                     whole_leaves=("experts",), stats_names=STATS,
                     block_kind=block_kind)
@@ -227,8 +190,8 @@ FAMILY = FamilySpec(name="lfm2", embed=embed, sublayer=sublayer,
 def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
               dtype) -> Dict:
     """Shard params from `get(key, shape)`, a tensor of the published
-    scheme (models/kimi.py `_assemble`: host leaves until a run is stacked;
-    traced values pass through, for `jax.eval_shape`)."""
+    scheme (module docstring; `decoder.loader`, `assemble_shard`). The
+    router's bias stays float32, as published."""
     d, heads, groups, hd = cfg.hidden_size, cfg.num_attention_heads, \
         cfg.kv_heads, cfg.head_dim
     first, count = cfg.held_experts or (0, cfg.n_experts)
@@ -252,10 +215,7 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
         return {"wte": table()}
 
     def get_block(block_id: int, subs: tuple) -> Dict:
-        if subs != (0, 1, 2, 3):
-            raise NotImplementedError(
-                "the lfm2 family takes whole blocks: a partition that cuts "
-                "one is for the forward path, which it does not run")
+        decoder.whole_blocks("lfm2", subs)
         root = f"model.layers.{block_id}."
         mixer, ffn = block_kind(cfg, block_id).split("_")
         if mixer == "conv":
@@ -285,7 +245,7 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
         held = [mlp(f"{root}feed_forward.experts.{e}.",
                     cfg.moe_intermediate_size)
                 for e in range(first, first + count)]
-        p["experts"] = {name: _stack([one[name] for one in held])
+        p["experts"] = {name: decoder.stack([one[name] for one in held])
                         for name in ("gate", "up", "down")}
         return p
 
@@ -293,35 +253,10 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
         return {"ln": scale("model.embedding_norm.weight", d),
                 "head": {"w": table()}}
 
-    return _on_device(build_shard_params(
-        shard_config, get_embed, get_block, get_final,
-        stack=lambda blocks: jax.tree_util.tree_map(
-            lambda *leaves: _stack(leaves), *blocks),
-        kind=lambda block_id: block_kind(cfg, block_id)), dtype)
+    return decoder.assemble_shard(
+        shard_config, get_embed, get_block, get_final, dtype,
+        kind=lambda block_id: block_kind(cfg, block_id),
+        float32=(("router", "bias"),))
 
 
-def load_params(cfg: TransformerConfig, shard_config: ShardConfig,
-                weights: Mapping, dtype=jnp.float32) -> Dict:
-    """Shard params from a published-style state-dict npz (module
-    docstring). A sliced vocabulary is the table's first rows."""
-    def get(key, shape):
-        value = np.asarray(weights[key])
-        if key == "model.embed_tokens.weight":
-            value = value[:shape[0]]
-        if value.shape != shape:
-            raise ValueError(f"{key}: {value.shape} in the file, {shape} "
-                             "in the model")
-        return value
-    return _assemble(cfg, shard_config, get, dtype)
-
-
-def init_params(cfg: TransformerConfig, shard_config: ShardConfig,
-                seed: int = 0, dtype=jnp.float32) -> Dict:
-    """Random shard params with the same pytree structure as `load_params`."""
-    rng = np.random.default_rng(seed)
-
-    def get(key, shape):
-        if key.endswith(("norm.weight", "layernorm.weight")):
-            return np.ones(shape, np.float32)
-        return rng.normal(0, 0.02, size=shape).astype(np.float32)
-    return _assemble(cfg, shard_config, get, dtype)
+load_params, init_params = decoder.loader(_assemble)

@@ -41,6 +41,7 @@ import numpy as np
 from . import ShardConfig
 from .layers import TransformerConfig, dense, rms_norm, rope_rotate
 from .shard import FamilySpec, build_shard_params
+from .stage_cache import attend, cache_update_and_read
 
 SUBLAYER_PARAMS = {
     0: ("ln_before", "q", "k", "v"),
@@ -69,19 +70,17 @@ def _window_keep(keep: jax.Array, q_pos, cfg: TransformerConfig):
 def _gqa_attend(q, k, v, cfg: TransformerConfig, keep=None) -> jax.Array:
     """softmax(QK^T)V over grouped kv heads; `keep` optionally masks key
     positions (the decode path: k, v and keep are then the parts
-    `_cache_update_and_read` returns, sliding window included), else
-    causal (+ window). The decode subsystem's `_attend` is the masked
+    `cache_update_and_read` returns, sliding window included), else
+    causal (+ window). The stage cache's `attend` is the masked
     softmax and the grouping both (a query head reads its group's kv head;
     nothing is repeated up to the query heads) — ONE copy of the attention
     numerics for both consumers."""
-    from ..parallel.decode import _attend
-
     if keep is None:                 # full forward: causal over [S, S]
         s_q, s_k = q.shape[1], k.shape[1]
         q_pos = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 0)
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 1)
         keep = _window_keep(k_pos <= q_pos, q_pos, cfg)
-    return _attend(q, k, v, keep, cfg)
+    return attend(q, k, v, keep, cfg)
 
 
 def _qkv_rope(p: Dict, normed: jax.Array, cfg: TransformerConfig, pos):
@@ -154,20 +153,18 @@ def _block_tail(p: Dict, x, ctx, cfg: TransformerConfig):
 
 def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
                       prefill: bool, read_len=None):
-    """KV-cached llama block (decode subsystem contract, parallel/decode.py
-    `_block_step` shape): prefill writes the whole prompt's POST-RoPE K and
-    V at [0, S); a decode step rotates the single new token at `pos` and
+    """KV-cached llama block (the decode driver's `_block_step` contract):
+    prefill writes the whole prompt's POST-RoPE K and V at [0, S); a decode
+    step rotates the single new token at `pos` and
     attends over the masked cache window (truncated to the static
     `read_len` bucket when the pipeline passes one — cache positions are
     absolute from 0, so the window mask anchors unchanged)."""
-    from ..parallel.decode import _cache_update_and_read
-
     normed = rms_norm(p["ln_before"], x, cfg.layer_norm_eps)
     s = normed.shape[1]
     # pos + offset covers prefill (pos=0), decode (s=1), and span steps
     pos_ids = jnp.asarray(pos) + jnp.arange(s)
     q, k_new, v_new = _qkv_rope(p, normed, cfg, pos_ids)
-    k, v, keep, bcache = _cache_update_and_read(
+    k, v, keep, bcache = cache_update_and_read(
         bcache, k_new, v_new, pos, prefill, s, q.dtype, read_len=read_len,
         window=cfg.sliding_window)
     ctx = _gqa_attend(q, k, v, cfg, keep=keep)
@@ -182,12 +179,11 @@ def tp_cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
     core swapped for a cache-attend over the head-sharded GQA cache
     slice. Requires heads AND kv_heads divisible by the tp degree.
     `read_len`: static bucketed attend window (position axis unsharded)."""
-    from ..parallel.decode import _cache_update_and_read
     from ..parallel.tensor import _tp_llama_block_local
 
     def cache_attend(q, k_new, v_new):
         nonlocal bcache
-        k, v, keep, bcache = _cache_update_and_read(
+        k, v, keep, bcache = cache_update_and_read(
             bcache, k_new, v_new, pos, prefill, x.shape[1], q.dtype,
             read_len=read_len, window=cfg.sliding_window)
         return _gqa_attend(q, k, v, cfg, keep=keep)
@@ -233,7 +229,7 @@ def sp_prefill_block_step(p: Dict, x, bcache, cfg: TransformerConfig,
 FAMILY = FamilySpec(name="llama", embed=embed, sublayer=sublayer,
                     finalize=finalize, cached_block_step=cached_block_step,
                     decode_embed=decode_embed, span_embed=span_embed,
-                    position_dependent_attention=True,
+                    decoder_model=True, position_dependent_attention=True,
                     tp_cached_block_step=tp_cached_block_step,
                     tp_finalize=tp_finalize,
                     sp_prefill_block_step=sp_prefill_block_step)

@@ -73,23 +73,22 @@ program splits nothing.
 """
 from __future__ import annotations
 
-from typing import Dict, Mapping
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import ShardConfig
+from . import ShardConfig, decoder
 from ..telemetry import metrics as prom
-from .keye import _by_head, _experts
-from .kimi import _in_row_chunks, _lin, _on_device, _stack
+from .decoder import by_head, in_row_chunks, lin
 from .layers import TransformerConfig, causal_conv, rope_rotate
-from .shard import CacheLeaf, FamilySpec, build_shard_params
+from .shard import CacheLeaf, FamilySpec
+from .stage_cache import attend_width, read_window
 
 # what a block step counts into the cache's `stats` leaf, in this order
-STATS = ("moe_assignments", "moe_rows_computed", "moe_experts_touched",
-         "moe_grouped_calls", "moe_layer_calls", "gdn_positions_chunked", "gdn_positions_stepped",
-         "gdn_state_carries")
+STATS = decoder.MOE_STATS + ("gdn_positions_chunked", "gdn_positions_stepped",
+                             "gdn_state_carries")
 
 # activations, cache and state (module docstring, Precision)
 ACTIVATIONS = jnp.float32
@@ -102,10 +101,6 @@ ACTIVATIONS = jnp.float32
 # attention found the same, PR 27)
 _STATE = jax.lax.Precision.HIGHEST
 _ATTENTION = jax.lax.Precision.HIGHEST
-
-# bytes of float32 attention scores one chunk of queries may hold (one KV
-# group's at a time)
-_SCORE_BYTES = 1 << 29
 
 # the widest diagonal block of a chunk's triangular matrix that is inverted a
 # row at a time (`inverse_block`); wider ones are merged from two
@@ -340,10 +335,10 @@ def gated_delta_net(p: Dict, normed, state, tail, cfg: TransformerConfig):
     hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
     dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
     eps = cfg.layer_norm_eps
-    m = _in_row_chunks(lambda rows: _lin(p["in_m"], rows), normed,
-                       p["in_m"].shape[0])
-    z = _lin(p["in_z"], normed).reshape(b, s, hv, dv)
-    ba = _lin(p["in_ba"], normed).astype(jnp.float32)
+    m = in_row_chunks(lambda rows: lin(p["in_m"], rows), normed,
+                      p["in_m"].shape[0])
+    z = lin(p["in_z"], normed).reshape(b, s, hv, dv)
+    ba = lin(p["in_ba"], normed).astype(jnp.float32)
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(p["a_log"].astype(jnp.float32)) * jax.nn.softplus(
         ba[..., hv:] + p["dt_bias"].astype(jnp.float32))
@@ -364,7 +359,7 @@ def gated_delta_net(p: Dict, normed, state, tail, cfg: TransformerConfig):
         o, state = delta_chunked(q, k, v, beta, g, state, cfg.linear_chunk)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + eps) \
         * p["out_norm"].astype(jnp.float32) * jax.nn.silu(z)
-    return _lin(p["out"], o.reshape(b, s, -1).astype(normed.dtype)), \
+    return lin(p["out"], o.reshape(b, s, -1).astype(normed.dtype)), \
         state, tail
 
 
@@ -406,21 +401,19 @@ def _attend_chunk(q, parts, first, pos):
 
 def attend(q, parts, pos) -> jax.Array:
     """`_attend_chunk` over all queries [B, Q, H, Dh], in chunks of queries
-    whose scores (one KV group's) stay under `_SCORE_BYTES`."""
+    whose scores (one KV group's) stay under `decoder.SCORE_BYTES`."""
     b, n_q, h, _ = q.shape
     n_keys = sum(part[0][0].shape[1] for part in parts)
-    chunk = n_q
-    while chunk > 1 and chunk % 2 == 0 and \
-            b * (h // len(parts[0][0])) * chunk * n_keys * 4 > _SCORE_BYTES:
-        chunk //= 2
+    chunk = decoder.query_chunk(
+        n_q, b * (h // len(parts[0][0])) * n_keys * 4)
     if chunk == n_q:
         return _attend_chunk(q, parts, 0, pos)
-    n = n_q // chunk
-    ctx = jax.lax.map(
+    # not `decoder.map_query_chunks`: a chunk's causal mask asks how many
+    # rows into the span the chunk starts
+    return decoder.join_queries(jax.lax.map(
         lambda xs: _attend_chunk(xs[0], parts, xs[1], pos),
-        (jnp.moveaxis(q.reshape((b, n, chunk) + q.shape[2:]), 1, 0),
-         jnp.arange(n) * chunk))
-    return jnp.moveaxis(ctx, 0, 1).reshape(b, n_q, -1)
+        (decoder.split_queries(q, chunk),
+         jnp.arange(n_q // chunk) * chunk)))
 
 
 def gated_attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
@@ -428,70 +421,41 @@ def gated_attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
     """The full mixer of `normed` [B, S, D] at [pos, pos + S) over the
     cached window below `pos` and its own rows. -> (out, the rows k, v
     [B, S, G*Dh] for the cache)."""
-    from ..parallel.decode import _attend_width, _read_window
-
     b, s, _ = normed.shape
     eps, groups = cfg.layer_norm_eps, cfg.kv_heads
     q_pos = jnp.asarray(pos) + jnp.arange(s)
-    q = _in_row_chunks(lambda rows: _lin(p["q"]["w"], rows), normed,
-                       p["q"]["w"].shape[0])
+    q = in_row_chunks(lambda rows: lin(p["q"]["w"], rows), normed,
+                      p["q"]["w"].shape[0])
     q = q.reshape(b, s, cfg.num_attention_heads, -1)
-    gate = _lin(p["gate"]["w"], normed)
-    k = _lin(p["k"]["w"], normed).reshape(b, s, groups, -1)
-    v = _lin(p["v"]["w"], normed)
+    gate = lin(p["gate"]["w"], normed)
+    k = lin(p["k"]["w"], normed).reshape(b, s, groups, -1)
+    v = lin(p["v"]["w"], normed)
     q = partial_rotate(rms(p["q_norm"], q, eps), q_pos, cfg)
     k = partial_rotate(rms(p["k_norm"], k, eps), q_pos, cfg).reshape(b, s, -1)
     stack = bcache.stack
     # through the cache's dtype, as if read back from it
     k = k.astype(stack["k"].dtype).astype(normed.dtype)
     v = v.astype(stack["v"].dtype).astype(normed.dtype)
-    parts = [(_by_head(k, groups), _by_head(v, groups), True)]
+    parts = [(by_head(k, groups), by_head(v, groups), True)]
     if not prefill:
-        width = _attend_width(bcache, read_len)
+        width = attend_width(bcache, read_len)
         lanes = [slice(grp * cfg.head_dim, (grp + 1) * cfg.head_dim)
                  for grp in range(groups)]
         parts.insert(0, tuple(
-            tuple(_read_window(stack[name], bcache.layer, width, head)
+            tuple(read_window(stack[name], bcache.layer, width, head)
                   for head in lanes) for name in ("k", "v")) + (False,))
     ctx = attend(q, parts, pos)
-    return _lin(p["attn_out"]["w"], ctx * jax.nn.sigmoid(gate)), k, v
+    return lin(p["attn_out"]["w"], ctx * jax.nn.sigmoid(gate)), k, v
 
 
 # -- the family's hooks --------------------------------------------------------
 
-def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
-    """Token embedding [B, K] -> [B, K, D]: positions live in the rotation
-    and in the state."""
-    return jnp.take(pe["wte"], tok, axis=0).astype(ACTIVATIONS)
-
-
-def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    return span_embed(p, input_ids, 0)
-
-
-def decode_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
-    return span_embed(pe, tok.reshape(-1, 1), pos)
-
-
-def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
-             attention_fn=None):
-    raise NotImplementedError(
-        "the qwen3_next family runs through the cached decode path only: "
-        "its blocks come in runs of two kinds, which the forward path "
-        "(models/shard.py shard_apply) does not scan yet")
-
-
-def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig) -> jax.Array:
-    """Final (zero-centred) norm + LM head -> [B, S, vocab] logits."""
-    return _lin(p["head"]["w"], rms(p["ln"], hidden, cfg.layer_norm_eps))
-
-
 def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
                       prefill: bool, read_len=None):
-    """Cached block (parallel/decode.py's `_block_step` contract) of either
+    """Cached block (the decode driver's `_block_step` contract) of either
     kind. The rows of `x` sit at [pos, pos + S). A full block attends the
     cached window below `pos` and its own rows and records their keys and
-    values for `_write_rows`; a linear block takes its state and its
+    values for `write_rows`; a linear block takes its state and its
     convolution's tail from the cache (a prefill, at `pos` 0: zeros) and
     records what they are after the span, which takes their place."""
     b, s, _ = x.shape
@@ -515,16 +479,17 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
                                       read_len)
         rows = {"k": k, "v": v}
     h = x + mixed
-    delta, moe = _experts(p, rms(p["ln_after"], h, eps), cfg)
+    delta, moe = decoder.routed_experts(p, rms(p["ln_after"], h, eps), cfg)
     rows["stats"] = jnp.concatenate(
         [moe.astype(jnp.int32), jnp.ones(1, jnp.int32), counts])
     return h + delta, bcache._replace(rows=rows)
 
 
-FAMILY = FamilySpec(name="qwen3_next", embed=embed, sublayer=sublayer,
-                    finalize=finalize, cached_block_step=cached_block_step,
-                    decode_embed=decode_embed, span_embed=span_embed,
-                    position_dependent_attention=True,
+# the head follows the zero-centred norm; positions live in the rotation and
+# in the state
+FAMILY = FamilySpec(name="qwen3_next", cached_block_step=cached_block_step,
+                    **decoder.token_hooks("qwen3_next", ACTIVATIONS, rms),
+                    decoder_model=True, position_dependent_attention=True,
                     cache_leaves=cache_leaves, prefill_span=prefill_span,
                     whole_leaves=("experts",), stats_names=STATS,
                     block_kind=block_kind)
@@ -535,8 +500,7 @@ FAMILY = FamilySpec(name="qwen3_next", embed=embed, sublayer=sublayer,
 def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
               dtype) -> Dict:
     """Shard params from `get(key, shape)`, a tensor of the published
-    scheme (models/kimi.py `_assemble`: host leaves until a run is stacked;
-    traced values pass through, for `jax.eval_shape`)."""
+    scheme (module docstring; `decoder.loader`, `assemble_shard`)."""
     d, heads, groups, hd = cfg.hidden_size, cfg.num_attention_heads, \
         cfg.kv_heads, cfg.head_dim
     block = inverse_block(cfg.linear_chunk)
@@ -592,10 +556,7 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
                                       (d, heads * hd))}}
 
     def get_block(block_id: int, subs: tuple) -> Dict:
-        if subs != (0, 1, 2, 3):
-            raise NotImplementedError(
-                "the qwen3_next family takes whole blocks: a partition that "
-                "cuts one is for the forward path, which it does not run")
+        decoder.whole_blocks("qwen3_next", subs)
         root = f"model.layers.{block_id}."
         p = full_mixer(root + "self_attn.") \
             if block_kind(cfg, block_id) == "full" \
@@ -606,7 +567,7 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
                                 (cfg.n_experts, d)).T}
         held = [mlp(f"{root}mlp.experts.{e}.", f)
                 for e in range(first, first + count)]
-        p["experts"] = {name: _stack([one[name] for one in held])
+        p["experts"] = {name: decoder.stack([one[name] for one in held])
                         for name in ("gate", "up", "down")}
         p["shared"] = mlp(root + "mlp.shared_expert.",
                           f * cfg.n_shared_experts)
@@ -618,41 +579,22 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
         return {"ln": get("model.norm.weight", (d,)),
                 "head": {"w": get("lm_head.weight", (cfg.vocab_size, d))}}
 
-    return _on_device(build_shard_params(
-        shard_config, get_embed, get_block, get_final,
-        stack=lambda blocks: jax.tree_util.tree_map(
-            lambda *leaves: _stack(leaves), *blocks),
-        kind=lambda block_id: block_kind(cfg, block_id)), dtype)
+    return decoder.assemble_shard(
+        shard_config, get_embed, get_block, get_final, dtype,
+        kind=lambda block_id: block_kind(cfg, block_id))
 
 
-def load_params(cfg: TransformerConfig, shard_config: ShardConfig,
-                weights: Mapping, dtype=jnp.float32) -> Dict:
-    """Shard params from a published-style state-dict npz (module
-    docstring). A sliced vocabulary is the table's first rows."""
-    def get(key, shape):
-        value = np.asarray(weights[key])
-        if key in ("model.embed_tokens.weight", "lm_head.weight"):
-            value = value[:shape[0]]
-        if value.shape != shape:
-            raise ValueError(f"{key}: {value.shape} in the file, {shape} "
-                             "in the model")
-        return value
-    return _assemble(cfg, shard_config, get, dtype)
+def _undrawn(key: str, shape: tuple):
+    """What `init_params` does not draw: the gated norm's weights about 1
+    (the zero-centred norms' are drawn, about 0), and the decays spread
+    over the heads from a half to nearly one a position."""
+    if key.endswith("linear_attn.norm.weight"):
+        return np.ones(shape, np.float32)
+    if key.endswith("A_log"):   # exp(g) = 2**-exp(A_log) at a = 0
+        return np.linspace(-6.5, 0.0, shape[0], dtype=np.float32)
+    if key.endswith("dt_bias"):
+        return np.zeros(shape, np.float32)
+    return None
 
 
-def init_params(cfg: TransformerConfig, shard_config: ShardConfig,
-                seed: int = 0, dtype=jnp.float32) -> Dict:
-    """Random shard params with the same pytree structure as `load_params`:
-    zero-centred norm weights about 0, the gated norm's about 1, and the
-    decays spread over the heads from a half to nearly one a position."""
-    rng = np.random.default_rng(seed)
-
-    def get(key, shape):
-        if key.endswith("linear_attn.norm.weight"):
-            return np.ones(shape, np.float32)
-        if key.endswith("A_log"):   # exp(g) = 2**-exp(A_log) at a = 0
-            return np.linspace(-6.5, 0.0, shape[0], dtype=np.float32)
-        if key.endswith("dt_bias"):
-            return np.zeros(shape, np.float32)
-        return rng.normal(0, 0.02, size=shape).astype(np.float32)
-    return _assemble(cfg, shard_config, get, dtype)
+load_params, init_params = decoder.loader(_assemble, _undrawn)

@@ -344,8 +344,7 @@ def decoder_model(model_name: str) -> str:
     """argparse `type=` of the decoding CLIs: a registered causal decoder,
     whole or as `<name>@<cut>`."""
     try:
-        known = get_model_entry(model_name).config.model_type in (
-            "gpt2", "llama", "keye", "kimi", "qwen3_next", "lfm2", "laguna")
+        known = get_model_entry(model_name).family.FAMILY.decoder_model
     except (KeyError, ValueError):
         known = False
     if not known:
@@ -375,15 +374,20 @@ def make_shard_config(model_name: str, layer_start: int, layer_end: int) -> Shar
                        is_last=layer_end == get_model_layers(model_name))
 
 
+# The deepest stage whose full blocks the host driver's programs run unrolled:
+# every registered model's depth. On the v5e six unrolled ViT-Large blocks
+# take 2.083 ms where a scan over their stack takes 2.360 (PR 30's pair, the
+# driver's; shard.shard_apply has the reason), and no cell, test or job ever
+# asked for another limit.
+UNROLL_BLOCKS = 48
+
+
 def should_unroll_blocks(n_blocks: int) -> bool:
-    """Execution-layout policy: unroll full blocks when the depth is within
-    PIPEEDGE_UNROLL_BLOCKS (default 48, covering every registered model —
-    on the v5e six unrolled ViT-Large blocks take 2.083 ms where a scan over
-    their stack takes 2.360; see shard.shard_apply). 0 disables unrolling
-    (always scan) in the host driver's stage programs; the SPMD driver
-    always unrolls (parallel/spmd.py::run_blocks)."""
-    limit = int(os.getenv("PIPEEDGE_UNROLL_BLOCKS", "48"))
-    return 0 < n_blocks <= limit
+    """Execution-layout policy of the host driver's stage programs: unroll
+    full blocks when the depth is within `UNROLL_BLOCKS`. The SPMD driver
+    always unrolls (parallel/spmd.py::run_blocks) and passes
+    `module_shard_factory(unroll=False)` for the stacked layout it takes."""
+    return 0 < n_blocks <= UNROLL_BLOCKS
 
 
 class _TimedReads(Mapping):

@@ -36,7 +36,7 @@ class BlockRuns(NamedTuple):
     """A shard's full blocks where they are not all of one kind (a leading
     dense layer before the expert layers): one stacked pytree `[n, ...]` a
     run of like blocks, in the model's order. The decode scan takes a run
-    at a time (parallel/decode.py `_run_blocks`); a shard of one kind keeps
+    at a time (the decode driver's `_run_blocks`); a shard of one kind keeps
     the bare stacked pytree."""
     runs: tuple
 
@@ -55,7 +55,7 @@ class CacheLeaf(NamedTuple):
     stage's `max_len` (0): a RING of the last `length` positions, `[L, B,
     min(length, max_len)] + shape`, position `p` at slot `p mod length`
     (a layer that attends a window of that many positions and needs keep no
-    more; parallel/decode.py, "A ring")."""
+    more; models/stage_cache.py, "A ring")."""
     shape: tuple
     dtype: Any
     kind: Any = None
@@ -78,7 +78,7 @@ def kind_runs(family, cfg: TransformerConfig,
 class FamilySpec:
     """Pure-function hooks defining a model family (vit/bert/deit/gpt2/
     llama). The two optional hooks plug a decoder family into the
-    KV-cache decode subsystem (parallel/decode.py): `cached_block_step`
+    KV-cache decode subsystem (the decode drivers): `cached_block_step`
     replaces the default GPT-2-shaped block step, `decode_embed` the
     default wte+wpe single-token embedding."""
     name: str
@@ -89,10 +89,12 @@ class FamilySpec:
     decode_embed: Any = None         # (embed_params, tok, pos) -> [B, 1, D]
     span_embed: Any = None           # (embed_params, tok [B,K], pos) ->
     #                                  [B, K, D] (speculative verify span)
+    # a causal decoder: what the decoding CLIs take (`registry.decoder_model`)
+    decoder_model: bool = False
     # attention reads absolute positions (RoPE): chunk-local attention
     # overrides (sequence-parallel cores) would rotate at wrong offsets
     position_dependent_attention: bool = False
-    # tensor-parallel decode variants (per-device bodies under shard_map;
+    # tensor-parallel variants of the decode step (per-device bodies under shard_map;
     # families whose cached step differs from the GPT-2 shape supply them)
     tp_cached_block_step: Any = None  # (+ axis=...) kwarg
     tp_finalize: Any = None           # (pf, hidden, cfg, axis) vocab-sharded
@@ -163,7 +165,7 @@ def shard_apply(family: FamilySpec, cfg: TransformerConfig,
             raise NotImplementedError(
                 f"the {family.name} family's blocks come in runs of "
                 "different kinds, which the forward path does not scan yet; "
-                "it runs through the cached decode path (parallel/decode.py)")
+                "it runs through the cached decode path (`DecodePipeline`)")
         if isinstance(blocks, (tuple, list)):
             for block_params in blocks:
                 data = _apply_slice(family, block_params, data, full, cfg)

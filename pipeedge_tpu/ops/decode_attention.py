@@ -1,6 +1,6 @@
 """Fused int8-KV decode-step attention as a Pallas TPU kernel.
 
-The XLA int8 decode path (parallel/decode.py `_cache_update_and_read`)
+The XLA int8 decode path (models/stage_cache.py `cache_update_and_read`)
 dequantizes the attended cache window to a full-precision [B, T, H, Dh]
 copy before the attend matmuls — XLA does not fuse elementwise producers
 into dot operands, so the dequantized K AND V copies are materialized
@@ -75,7 +75,7 @@ def _kernel(pos_ref, q_ref, kq_ref, ks_ref, kz_ref, vq_ref, vs_ref, vz_ref,
         k = jnp.where(fresh, k_new[None], k)
         v = jnp.where(fresh, v_new[None], v)
         # round K/V (and below, the probs) through the pipeline dtype at
-        # the same points the XLA path does (_dequantize_rows -> dtype,
+        # the same points the XLA path does (dequantize_rows -> dtype,
         # probs.astype(dtype)); f32 pipelines make these no-ops. The
         # online softmax still differs from the full softmax at the
         # rounding level — flash-style accumulation is mathematically,
@@ -198,7 +198,7 @@ def int8_decode_attention(q, k_q, k_scale, k_shift, v_q, v_scale, v_shift,
 
     q/k_new/v_new: [B, 1, H, Dh]; k_q/v_q: [B, T, H, Dh] int8;
     scales/shifts: [B, T, H] float32; `pos` traced scalar. Returns
-    [B, 1, H*Dh] context, matching `_attend`'s output layout.
+    [B, 1, H*Dh] context, matching `attend`'s output layout.
 
     `variant` 1: per-batch-cell grid, fori_loop over KV blocks (live
     blocks only). `variant` 2: per-KV-block grid processing all batch

@@ -57,7 +57,7 @@ from ..utils import jax_compat
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models import ShardConfig
+from ..models import ShardConfig, stage_cache
 from ..models.layers import TransformerConfig
 from ..models.shard import FamilySpec
 from . import decode as dec
@@ -149,14 +149,14 @@ class SpmdDecodePipeline:
 
             def live(args):
                 # the block steps read a stacked cache by layer index and
-                # hand back the rows to write (decode.LayerCache): give
+                # hand back the rows to write (stage_cache.LayerCache): give
                 # them this layer as a stack of one
                 c, cache_j = args
                 one = jax.tree_util.tree_map(lambda a: a[None], cache_j)
-                y, bc = block_fn(bp, c, dec.LayerCache(one, 0), pos, cfg,
-                                 prefill)
+                y, bc = block_fn(bp, c, stage_cache.LayerCache(one, 0), pos,
+                                 cfg, prefill)
                 rows = jax.tree_util.tree_map(lambda a: a[None], bc.rows)
-                one = dec._write_rows(one, rows, 0 if prefill else pos)
+                one = stage_cache.write_rows(one, rows, 0 if prefill else pos)
                 return y, jax.tree_util.tree_map(lambda a: a[0], one)
 
             out, bc_new = jax.lax.cond(

@@ -31,7 +31,8 @@ def _free_ports(n):
 
 
 def _run_chaos_fleet(tmp_path, world, chaos=None, victim=1, extra=(),
-                     batch=24, timeout=240, env_extra=None):
+                     batch=24, timeout=240, env_extra=None,
+                     worker_exit_s=60):
     """Launch a `world`-rank failover-mode fleet, arming `chaos` in the
     victim's env (`env_extra` lands in EVERY rank's env). Returns
     (data rc, data output, [worker outputs])."""
@@ -48,23 +49,26 @@ def _run_chaos_fleet(tmp_path, world, chaos=None, victim=1, extra=(),
         d = tmp_path / f"rank{r}"
         d.mkdir(parents=True, exist_ok=True)
         dirs.append(d)
-    workers = []
+    # a worker's log goes to a file: it outlives a worker that has to be
+    # killed (a restarted incarnation the fleet finished without), and no
+    # pipe fills while rank 0 runs
+    workers, logs = [], []
     for r in range(1, world):
         wenv = dict(env, DCN_CHAOS=chaos) if (chaos and r == victim) \
             else env
+        logs.append(open(dirs[r] / "worker.log", "w+"))
         workers.append(subprocess.Popen(
             common + [str(r), str(world)] + opts, cwd=dirs[r], env=wenv,
-            text=True, stdout=subprocess.PIPE, stderr=subprocess.STDOUT))
+            text=True, stdout=logs[-1], stderr=subprocess.STDOUT))
     try:
         data = subprocess.run(common + ["0", str(world)] + opts,
                               cwd=dirs[0], env=env, capture_output=True,
                               text=True, timeout=timeout)
-        wouts = []
         for w in workers:
             try:
-                wouts.append(w.communicate(timeout=60)[0])
+                w.wait(timeout=worker_exit_s)
             except subprocess.TimeoutExpired:
-                wouts.append("<no output: killed>")
+                pass
     finally:
         for w in workers:
             try:
@@ -74,6 +78,11 @@ def _run_chaos_fleet(tmp_path, world, chaos=None, victim=1, extra=(),
             except OSError:
                 pass
             w.wait()
+        wouts = []
+        for log in logs:
+            log.seek(0)
+            wouts.append(log.read())
+            log.close()
     return data, wouts, dirs
 
 
@@ -118,7 +127,8 @@ def test_chaos_restart_rejoins_and_heals(tmp_path):
     partition runs on the ORIGINAL ranks, every round's results exactly
     once.
 
-    Was flaky (fails ~1 in 3 on the pristine tree): when detection of
+    Was flaky (fails ~1 in 3 on the pristine tree) at a time when three
+    rounds outlasted the restart: when detection of
     the death ran late enough that the restarted incarnation's JOIN was
     admitted FIRST, the victim moved dead_ranks -> benched_ranks before
     the round loop's 0.5s poll ever saw a dead scheduled rank, so
@@ -130,20 +140,26 @@ def test_chaos_restart_rejoins_and_heals(tmp_path):
     benched_ranks while a death episode is open — a freshly rejoined
     incarnation holds no stage state, so the round must fail over to a
     spare either way (the heal then restores it at the boundary)."""
+    # rounds enough to outlast the restart: the new incarnation is back 3 to
+    # 4 s after the death (1.5 s of delay, then an interpreter and its
+    # imports) and a round of this fleet takes 0.45 s, so three rounds were
+    # over before it had announced itself and the run then showed no rejoin
+    # (every run of the driver's); a heal needs a boundary after that
+    rounds = 16
     data, wouts, dirs = _run_chaos_fleet(
         tmp_path, world=4, chaos="restart@3:1500", batch=16,
-        extra=["--rounds", "3", "--on-peer-rejoin", "heal",
-               "--save-results", "results.npz"])
-    assert data.returncode == 0, data.stdout + data.stderr
+        extra=["--rounds", str(rounds), "--on-peer-rejoin", "heal",
+               "--save-results", "results.npz"], worker_exit_s=20)
     out = data.stdout + data.stderr
+    assert data.returncode == 0, out
     # the failover leg ran (spare took the stage over)
-    assert "moves rank 1 -> 2" in out
+    assert "moves rank 1 -> 2" in out, out
     # the restarted incarnation was admitted exactly once...
-    assert out.count("rejoin_rank=1") == 1
-    assert "epoch=1" in out
+    assert out.count("rejoin_rank=1") == 1, out
+    assert "epoch=1" in out, out
     # ...and the heal restored the pre-failure placement with a finite
     # time-to-full-capacity
-    assert "heal_round=" in out
+    assert "heal_round=" in out, out
     heal_line = [ln for ln in data.stdout.splitlines()
                  if ln.startswith("heal_round=")][0]
     assert "ranks=0,1" in heal_line
@@ -152,9 +168,9 @@ def test_chaos_restart_rejoins_and_heals(tmp_path):
     assert "chaos: killing this process" in wouts[0]
     assert "re-exec as epoch 1" in wouts[0]
     assert "JOIN announced" in wouts[0]
-    # 4 microbatches x 3 rounds, exactly once each
+    # 4 microbatches a round, exactly once each
     results = np.load(dirs[0] / "results.npz")
-    assert len(results.files) == 12
+    assert len(results.files) == 4 * rounds
 
 
 @pytest.mark.slow

@@ -1,0 +1,151 @@
+"""Ask the chip's compiler, without the chip: the stage programs of
+`qwen3-next.longdoc-batch`, `lfm2.extract-batch` and `laguna-xs2.repo-batch`
+at their real sizes.
+
+The second half of `test_chip_compile.py` (which says what such a compile
+shows and what it does not), in a file of its own so that `--dist loadfile`
+gives the two halves to two workers. The described chip and the switch to
+the grouped kernels are that file's fixtures.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from pipeedge_tpu.models import ShardConfig, registry, stage_cache
+from test_chip_compile import (  # noqa: F401 — fixtures, found by name
+    _grouped_kernels, mosaic_for_the_described_chip, on_chip, topo)
+
+QWEN3_NEXT_CELL = "Qwen/Qwen3-Next-80B-A3B-Instruct@4,e0+256,v75968"
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (1024, True)])
+def test_qwen3_next_stage_program_compiles_for_v5e(span, last_only, on_chip):
+    """`qwen3-next.longdoc-batch` at its real size: one period (three Gated
+    DeltaNet layers, one gated full-attention layer) at the published widths
+    with 256 of 512 experts held, 8 rows, the 32,768 bucket; a decode step
+    and one span of the prefill. The resident bytes (7.36 GB of weights,
+    1.07 GB of keys and values in ONE layer, 53 MB of state in three) and
+    the program's temporaries have to fit one chip's 16 GB."""
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry(QWEN3_NEXT_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    rows, max_len = 8, 32768
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg),
+        runs=kind_runs(entry.family.FAMILY, cfg, stage)))
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=max_len,
+                          last_only=last_only).compile()
+    assert (_grouped_kernels(compiled) > 0) == (span == 1)
+    memory = compiled.memory_analysis()
+    print(f"qwen3-next {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * (max_len * 4096 + 6586368)
+    assert memory.alias_size_in_bytes > cache_bytes     # updated in place
+    # keys and values in the one full layer only: four layers' would be 4.3 GB
+    assert memory.argument_size_in_bytes < 7.37e9 + 1.05 * cache_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+    # the span's temporaries as they were with the chunk's inverse a row at a
+    # time (2.34 GB): the blocked inverse buys no speed with memory, the
+    # cell peaks at 11.1 of 16 GB beside the benchmark's reference
+    assert memory.temp_size_in_bytes < (2.35e9 if span > 1 else 0.07e9)
+
+
+LFM2_CELL = "LiquidAI/LFM2-8B-A1B@12"
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (128, True)])
+def test_lfm2_stage_program_compiles_for_v5e(span, last_only, on_chip):
+    """`lfm2.extract-batch` at its real size: twelve blocks at the published
+    widths in seven runs (two dense convolution blocks, then attention and
+    routed convolution blocks), all 32 experts held, 128 rows, the 1,024
+    bucket; a decode step and one span of the prefill. The resident bytes
+    (8.13 GB of weights with the tied table held as embedding and as head,
+    1.61 GB of keys and values in THREE layers, 19 MB of tails in nine) and
+    the program's temporaries have to fit one chip's 16 GB."""
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry(LFM2_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    rows, max_len = 128, 1024
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg),
+        runs=kind_runs(entry.family.FAMILY, cfg, stage)))
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=max_len,
+                          last_only=last_only).compile()
+    assert (_grouped_kernels(compiled) > 0) == (span == 1)
+    memory = compiled.memory_analysis()
+    print(f"lfm2 {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * (max_len * 12288 + 147456)
+    assert memory.alias_size_in_bytes > cache_bytes     # updated in place
+    # keys and values in the three attention layers only, no leaf padded:
+    # twelve layers' would be 6.4 GB
+    assert memory.argument_size_in_bytes < 8.14e9 + 1.05 * cache_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+
+
+LAGUNA_CELL = "poolside/Laguna-XS.2@5"
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (128, True)])
+def test_laguna_stage_program_compiles_for_v5e(span, last_only, on_chip):
+    """`laguna-xs2.repo-batch` at its real size: five blocks at the
+    published widths in three runs (the dense full block of 48 query heads,
+    three window blocks of 64, a routed full block), all 256 experts held,
+    32 rows, the 8,192 bucket; a decode step and one span of the prefill.
+    The window blocks' leaves are rings of 512 positions: 7.74 GB of
+    weights, 4.29 GB of keys and values in the TWO full layers and 0.40 GB
+    of rings in three (all five kept whole would be 18.5 GB with the
+    weights), and the program's temporaries have to fit one chip's 16 GB."""
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry(LAGUNA_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    rows, max_len = 32, 8192
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg),
+        runs=kind_runs(entry.family.FAMILY, cfg, stage)))
+    assert cache["k_ring"].shape == (3, rows, 512, 1024)
+    assert cache["k"].shape == (2, rows, max_len, 1024)
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=max_len,
+                          last_only=last_only).compile()
+    assert (_grouped_kernels(compiled) > 0) == (span == 1)
+    memory = compiled.memory_analysis()
+    print(f"laguna {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * (max_len * 16384 + 3 * 512 * 8192)
+    assert memory.alias_size_in_bytes > cache_bytes     # updated in place
+    # no leaf padded, and the rings are rings
+    assert memory.argument_size_in_bytes < 7.75e9 + 1.02 * cache_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9

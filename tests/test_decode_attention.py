@@ -10,7 +10,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from pipeedge_tpu.models import registry
+from pipeedge_tpu.models import registry, stage_cache
 from pipeedge_tpu.ops import decode_attention
 from pipeedge_tpu.parallel import decode
 
@@ -31,21 +31,21 @@ def test_kernel_matches_xla_dequant_attend(variant):
     k_new = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
     v_new = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
 
-    kq, ks, kz = decode._quantize_rows(k_rows)
-    vq, vs, vz = decode._quantize_rows(v_rows)
+    kq, ks, kz = stage_cache.quantize_rows(k_rows)
+    vq, vs, vz = stage_cache.quantize_rows(v_rows)
 
     got = decode_attention.int8_decode_attention(
         q, kq, ks, kz, vq, vs, vz, k_new, v_new, pos, interpret=True,
         variant=variant)
 
     # reference: the XLA path's math
-    k = decode._dequantize_rows(kq, ks, kz, jnp.float32)
-    v = decode._dequantize_rows(vq, vs, vz, jnp.float32)
+    k = stage_cache.dequantize_rows(kq, ks, kz, jnp.float32)
+    v = stage_cache.dequantize_rows(vq, vs, vz, jnp.float32)
     k = k.at[:, pos:pos + 1].set(k_new)
     v = v.at[:, pos:pos + 1].set(v_new)
     keep = (jnp.arange(t) <= pos)[None, :]
     cfg = registry.get_model_config("pipeedge/test-tiny-gpt2")
-    want = decode._attend(q, k, v, keep, cfg)
+    want = stage_cache.attend(q, k, v, keep, cfg)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 

@@ -13,7 +13,8 @@ import pytest
 
 from benchmark import costs_keye, weights
 from benchmark.reference import keye_vl2 as reference
-from pipeedge_tpu.models import keye, llama, registry
+from pipeedge_tpu.models import (decoder, keye, llama, registry,
+                                 stage_cache)
 from pipeedge_tpu.models.layers import rope_rotate
 from pipeedge_tpu.parallel import decode, expert
 from pipeedge_tpu.telemetry import metrics as prom
@@ -65,10 +66,10 @@ def test_spans_then_decode_match_the_reference(prompt_len, tiny):
 
 
 def test_queries_in_chunks_match_the_reference(tiny, monkeypatch):
-    """A span whose scores would pass `_SCORE_BYTES` runs its queries in
+    """A span whose scores would pass `decoder.SCORE_BYTES` runs its queries in
     chunks (at real sizes, always): two queries a chunk here."""
     config, path, _, ids, wanted = tiny
-    monkeypatch.setattr(keye, "_SCORE_BYTES", 2 * 2 * 2 * 40 * 4)
+    monkeypatch.setattr(decoder, "SCORE_BYTES", 2 * 2 * 2 * 40 * 4)
     pipe = decode.build_decode_pipeline(TINY, None, max_len=32,
                                         dtype=jnp.float32, model_file=path)
     data, _ = pipe._prefill(jnp.asarray(ids[:, :24], jnp.int32))
@@ -263,11 +264,11 @@ def test_counters_of_one_batch_are_what_its_sizes_predict(tiny):
 
 
 def test_a_count_passes_two_to_the_31():
-    cache = {decode.STATS: jnp.zeros((2, 3, 2), jnp.int32)}
+    cache = {stage_cache.STATS: jnp.zeros((2, 3, 2), jnp.int32)}
     step = jnp.full((2, 3), 2 ** 30 + 5, jnp.int32)
     for _ in range(5):
-        cache = decode._write_rows(cache, {decode.STATS: step}, 0)
-    assert decode.read_stats(cache).tolist() == [10 * (2 ** 30 + 5)] * 3
+        cache = stage_cache.write_rows(cache, {stage_cache.STATS: step}, 0)
+    assert stage_cache.read_stats(cache).tolist() == [10 * (2 ** 30 + 5)] * 3
 
 
 def test_a_depth_cut_is_the_first_blocks_and_the_same_head(tiny):

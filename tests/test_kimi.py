@@ -15,7 +15,8 @@ import pytest
 
 from benchmark import costs_kimi, weights
 from benchmark.reference import kimi_k2 as reference
-from pipeedge_tpu.models import ShardConfig, kimi, registry
+from pipeedge_tpu.models import (ShardConfig, decoder, kimi, registry,
+                                 stage_cache)
 from pipeedge_tpu.models.shard import BlockRuns, shard_apply
 from pipeedge_tpu.parallel import decode, expert
 from pipeedge_tpu.telemetry import metrics as prom
@@ -95,14 +96,14 @@ def test_the_whole_model_matches_the_reference(tmp_path):
         <= TOLERANCE * (wanted.max() - wanted.min())
 
 
-@pytest.mark.parametrize("limit", ["_SCORE_BYTES", "_PRODUCT_BYTES"])
+@pytest.mark.parametrize("limit", ["SCORE_BYTES", "PRODUCT_BYTES"])
 def test_chunks_of_queries_and_of_rows_change_nothing(limit, tiny,
                                                       monkeypatch):
     """At real sizes a span's scores and its widest three-pass products run
     in chunks; forced here: two queries, and four rows, a chunk."""
     config, path, _, ids, wanted = tiny
-    monkeypatch.setattr(kimi, limit, {"_SCORE_BYTES": 2 * 4 * 2 * 40 * 4,
-                                      "_PRODUCT_BYTES": 4 * 64 * 12}[limit])
+    monkeypatch.setattr(decoder, limit, {"SCORE_BYTES": 2 * 4 * 2 * 40 * 4,
+                                         "PRODUCT_BYTES": 4 * 64 * 12}[limit])
     pipe = decode.build_decode_pipeline(
         config["program_model"], None, max_len=32, dtype=jnp.float32,
         model_file=path)
@@ -536,7 +537,7 @@ def test_what_the_family_cannot_do_is_refused_by_name(asked):
             shard_apply(entry.family.FAMILY, entry.config, stage, params,
                         jnp.zeros((1, 4), jnp.int32))
         with pytest.raises(NotImplementedError, match="kimi"):
-            kimi.sublayer({}, 0, None, entry.config)
+            kimi.FAMILY.sublayer({}, 0, None, entry.config)
         return
     if asked == "kv_pages":
         import sys
@@ -625,7 +626,7 @@ def test_a_run_of_like_blocks_is_one_scan(model, equations, scans):
     stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
     params = jax.eval_shape(lambda: entry.family.init_params(cfg, stage))
     leaves = family.cache_leaves(cfg) if family.cache_leaves else None
-    cache = jax.eval_shape(lambda: decode.init_cache(
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
         cfg, cfg.num_hidden_layers, 2, 32, leaves=leaves))
     run = decode._make_stage_run(family, cfg, stage)
     jaxpr = jax.make_jaxpr(

@@ -17,7 +17,7 @@ import pytest
 
 from benchmark import costs_laguna as costs, weights
 from benchmark.reference import laguna as reference
-from pipeedge_tpu.models import ShardConfig, laguna, registry
+from pipeedge_tpu.models import ShardConfig, laguna, registry, stage_cache
 from pipeedge_tpu.models.layers import rope_frequencies, yarn_frequencies
 from pipeedge_tpu.models.shard import (BlockRuns, CacheLeaf, kind_runs,
                                        shard_apply)
@@ -184,13 +184,13 @@ def _rows(rng, layers, batch, span, width):
     (5, [(0, 3), (3, 4), (7, 5), (12, 2)]),
 ])
 def test_rows_are_written_where_they_fall_in_a_ring(ring, calls):
-    """`_write_rows` against the rule itself: position p at slot p mod W,
+    """`write_rows` against the rule itself: position p at slot p mod W,
     a later row over an earlier one, every other slot as it was."""
     rng = np.random.default_rng(ring)
     cache = {"k_ring": jnp.asarray(rng.normal(size=(2, 3, ring, 4)),
                                    jnp.float32)}
     wanted = np.array(cache["k_ring"])
-    write = jax.jit(lambda cache, rows, pos: decode._write_rows(
+    write = jax.jit(lambda cache, rows, pos: stage_cache.write_rows(
         cache, rows, pos, rings=("k_ring",)))
     for pos, span in calls:
         rows = _rows(rng, 2, 3, span, 4)
@@ -209,7 +209,7 @@ def test_the_ring_gives_the_numbers_of_the_window_mask_over_a_full_leaf(
         calls):
     """The Llama family's `window=` mask over a leaf of `max_len` positions
     and a ring of `window` positions, fed the same rows call after call:
-    `_attend` over what each read hands back gives the same contexts (the
+    `attend` over what each read hands back gives the same contexts (the
     kept keys are the same set; a ring's sit in another order)."""
     window, max_len, groups, hd = 8, 32, 2, 4
     cfg = registry.get_model_config(TINY)
@@ -224,13 +224,13 @@ def test_the_ring_gives_the_numbers_of_the_window_mask_over_a_full_leaf(
                             jnp.float32) for _ in range(2))
         got, kept = {}, {}
         for ring, cache in caches.items():
-            ks, vs, keeps, bcache = decode._cache_update_and_read(
-                decode.LayerCache(cache, jnp.int32(0)), k, v, pos, False,
+            ks, vs, keeps, bcache = stage_cache.cache_update_and_read(
+                stage_cache.LayerCache(cache, jnp.int32(0)), k, v, pos, False,
                 span, jnp.float32, read_len=max_len, window=window,
                 ring=ring)
-            got[ring] = np.asarray(decode._attend(q, ks, vs, keeps, cfg))
+            got[ring] = np.asarray(stage_cache.attend(q, ks, vs, keeps, cfg))
             rows = {name: row[None] for name, row in bcache.rows.items()}
-            caches[ring] = decode._write_rows(
+            caches[ring] = stage_cache.write_rows(
                 cache, rows, pos, rings=("k", "v") if ring else ())
             kept[ring] = int(keeps[0].sum())
         np.testing.assert_allclose(got[True], got[False], atol=2e-6)
@@ -246,8 +246,8 @@ def test_a_slot_is_kept_by_the_position_it_holds():
              "v": jnp.zeros((1, 1, window, 4))}
     k = v = jnp.zeros((1, 3, 1, 4))
     for pos in (0, 1, 5, 8, 9, 21):
-        keep = np.asarray(decode._cache_update_and_read(
-            decode.LayerCache(cache, jnp.int32(0)), k, v, pos, False, 3,
+        keep = np.asarray(stage_cache.cache_update_and_read(
+            stage_cache.LayerCache(cache, jnp.int32(0)), k, v, pos, False, 3,
             jnp.float32, window=window, ring=True)[2][0])
         for i in range(3):
             for slot in range(window):
@@ -264,7 +264,7 @@ def test_a_fresh_cache_holds_each_leaf_at_its_own_length(size):
     entry = registry.get_model_entry(model)
     cfg = entry.config
     stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
-    cache = jax.eval_shape(lambda: decode.init_cache(
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
         cfg, cfg.num_hidden_layers, rows, max_len,
         leaves=laguna.cache_leaves(cfg),
         runs=kind_runs(laguna.FAMILY, cfg, stage)))
@@ -519,14 +519,14 @@ def test_the_expert_layer_is_the_scaled_softmax_top_k_and_a_gated_shared_one(
     """`s = softmax(router u)`, the 2 largest of 8, renormalised, times 2.5;
     plus `sigmoid(shared_expert_gate u)` times the shared expert, which the
     factor does not scale: against a plain loop over the block's leaves."""
-    from pipeedge_tpu.models.keye import _experts
+    from pipeedge_tpu.models.decoder import routed_experts
     _, _, pipe, _, _ = tiny
     cfg = pipe.cfg
     run = pipe.stages[0]["params"]["blocks"].runs[1]     # three routed blocks
     block = jax.tree_util.tree_map(lambda leaf: leaf[1], run)
     x = jnp.asarray(np.random.default_rng(5).normal(size=(2, 7, 32)),
                     jnp.float32)
-    got, stats = _experts(block, x, cfg)
+    got, stats = routed_experts(block, x, cfg)
     assert (cfg.routed_scaling_factor, cfg.norm_topk_prob, cfg.router,
             cfg.num_experts_per_tok) == (2.5, True, "softmax", 2)
 
@@ -683,7 +683,7 @@ def test_what_the_family_cannot_do_is_refused_by_name(asked):
             shard_apply(entry.family.FAMILY, entry.config, stage, params,
                         jnp.zeros((1, 4), jnp.int32))
         with pytest.raises(NotImplementedError, match="laguna"):
-            laguna.sublayer({}, 0, None, entry.config)
+            laguna.FAMILY.sublayer({}, 0, None, entry.config)
         return
     if asked in ("kv_pages", "speculative"):
         pipe = decode.DecodePipeline(entry.family.FAMILY, entry.config,

@@ -16,7 +16,7 @@ import pytest
 
 from benchmark import costs_lfm2 as costs, weights
 from benchmark.reference import lfm2_moe as reference
-from pipeedge_tpu.models import ShardConfig, lfm2, registry
+from pipeedge_tpu.models import ShardConfig, lfm2, registry, stage_cache
 from pipeedge_tpu.models.layers import causal_conv
 from pipeedge_tpu.models.shard import (BlockRuns, CacheLeaf, kind_runs,
                                        shard_apply)
@@ -249,7 +249,7 @@ def test_the_shared_convolution_is_causal_and_carries_its_tail(width, span):
 
 
 # what the four families' tiny step (span 1) and span (8) programs traced to
-# before `causal_conv`, `CacheLeaf.kind` as a tuple, `_attend(precision=)`
+# before `causal_conv`, `CacheLeaf.kind` as a tuple, `attend(precision=)`
 # and `gate_sum_eps` (the parent commit): equations at the top level and in
 # all. Since PR 41 nine more a traced expert layer: its fourth count and the
 # way back's select (on the CPU these programs keep the tile loop)
@@ -478,7 +478,7 @@ def _fresh_cache(model, rows, max_len):
     entry = registry.get_model_entry(model)
     stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
     runs = kind_runs(entry.family.FAMILY, entry.config, stage)
-    return runs, jax.eval_shape(lambda: decode.init_cache(
+    return runs, jax.eval_shape(lambda: stage_cache.init_cache(
         entry.config, entry.config.num_hidden_layers, rows, max_len,
         leaves=lfm2.cache_leaves(entry.config), runs=runs))
 
@@ -560,27 +560,27 @@ def test_a_leaf_says_one_kind_or_several_and_a_kinds_leaves_agree():
     leaves = {"k": CacheLeaf((4,), jnp.float32, ("a_x", "a_y")),
               "state": CacheLeaf((4,), jnp.float32, "b", whole=True),
               "stats": row}
-    owner = decode._owner(leaves)
+    owner = stage_cache.leaf_owners(leaves)
     assert owner == {"k": ("a_x", "a_y"), "state": ("b",)}
-    assert decode._shares_layers(owner, "a_y") == ("a_x", "a_y")
-    assert decode._shares_layers(owner, "b") == ("b",)
-    assert decode._shares_layers(owner, "c") == ()
+    assert stage_cache.shares_layers(owner, "a_y") == ("a_x", "a_y")
+    assert stage_cache.shares_layers(owner, "b") == ("b",)
+    assert stage_cache.shares_layers(owner, "c") == ()
     cfg = registry.get_model_config(TINY)
     runs = (("a_x", 2), ("b", 1), ("a_y", 3), ("b", 2))
-    cache = jax.eval_shape(lambda: decode.init_cache(
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
         cfg, 8, 1, 16, leaves=leaves, runs=runs))
     assert cache["k"].shape == (5, 1, 16, 4)
     assert cache["state"].shape == (3, 1, 4)
     # blocks of one kind cannot count two leaves' layers differently
     leaves["v"] = CacheLeaf((4,), jnp.float32, "a_x")
     with pytest.raises(ValueError, match="one layer index"):
-        decode._shares_layers(decode._owner(leaves), "a_x")
+        stage_cache.shares_layers(stage_cache.leaf_owners(leaves), "a_x")
 
 
 def test_leaves_of_kinds_need_the_stages_runs():
     cfg = registry.get_model_config(TINY)
     with pytest.raises(ValueError, match="runs of kinds"):
-        decode.init_cache(cfg, 8, 1, 16, leaves=lfm2.cache_leaves(cfg))
+        stage_cache.init_cache(cfg, 8, 1, 16, leaves=lfm2.cache_leaves(cfg))
 
 
 @pytest.mark.parametrize("model, widths", [(TINY, 2), (CELL, 2)])
@@ -692,7 +692,7 @@ def test_what_the_family_cannot_do_is_refused_by_name(asked):
             shard_apply(entry.family.FAMILY, entry.config, stage, params,
                         jnp.zeros((1, 4), jnp.int32))
         with pytest.raises(NotImplementedError, match="lfm2"):
-            lfm2.sublayer({}, 0, None, entry.config)
+            lfm2.FAMILY.sublayer({}, 0, None, entry.config)
         return
     if asked in ("kv_pages", "speculative"):
         pipe = decode.DecodePipeline(entry.family.FAMILY, entry.config,

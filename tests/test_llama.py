@@ -8,7 +8,7 @@ torch = pytest.importorskip("torch")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from pipeedge_tpu.models import ShardConfig  # noqa: E402
+from pipeedge_tpu.models import ShardConfig, stage_cache  # noqa: E402
 from pipeedge_tpu.models import llama as llama_mod  # noqa: E402
 from pipeedge_tpu.models.layers import TransformerConfig  # noqa: E402
 from pipeedge_tpu.models.registry import get_model_config  # noqa: E402
@@ -92,7 +92,7 @@ def test_greedy_decode_matches_hf_generate(llama_setup):
     pipe = decode.DecodePipeline(
         llama_mod.FAMILY, cfg, partition,
         _stage_params(cfg, partition, weights), max_len=32)
-    cache = decode.init_cache(cfg, 1, 2, 8)
+    cache = stage_cache.init_cache(cfg, 1, 2, 8)
     assert cache["k"].shape[3] == cfg.kv_heads * cfg.head_dim   # GQA-sized
     ids = np.random.default_rng(7).integers(0, cfg.vocab_size, size=(2, 6))
     got = np.asarray(pipe.generate(ids, new_tokens=8))

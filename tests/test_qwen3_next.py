@@ -17,7 +17,8 @@ import pytest
 
 from benchmark import costs_qwen3_next as costs, weights
 from benchmark.reference import qwen3_next as reference
-from pipeedge_tpu.models import ShardConfig, qwen3_next, registry
+from pipeedge_tpu.models import (ShardConfig, decoder, qwen3_next, registry,
+                                 stage_cache)
 from pipeedge_tpu.models.shard import BlockRuns, kind_runs, shard_apply
 from pipeedge_tpu.parallel import decode, expert
 from pipeedge_tpu.telemetry import metrics as prom
@@ -121,7 +122,7 @@ def test_queries_in_chunks_change_nothing(tiny, monkeypatch):
     """At real sizes a span's scores run in chunks of queries; forced here:
     two queries a chunk."""
     config, path, _, ids, wanted = tiny
-    monkeypatch.setattr(qwen3_next, "_SCORE_BYTES", 2 * 2 * 2 * 40 * 4)
+    monkeypatch.setattr(decoder, "SCORE_BYTES", 2 * 2 * 2 * 40 * 4)
     pipe = decode.build_decode_pipeline(
         config["program_model"], None, max_len=32, dtype=jnp.float32,
         model_file=path)
@@ -399,7 +400,7 @@ def test_the_gate_multiplies_the_heads_outputs():
     stack = {name: jnp.zeros((1, 1, 8, groups * hd)) for name in ("k", "v")}
     got, _, _ = qwen3_next.gated_attention(
         jax.tree_util.tree_map(jnp.asarray, p), jnp.asarray(x),
-        decode.LayerCache(stack, 0), 0, cfg, prefill=True)
+        stage_cache.LayerCache(stack, 0), 0, cfg, prefill=True)
 
     def norm(t, w):
         return t / np.sqrt((t * t).mean(-1, keepdims=True) + 1e-6) * (1 + w)
@@ -517,7 +518,7 @@ def _fresh_cache(model, rows, max_len):
     entry = registry.get_model_entry(model)
     stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
     runs = kind_runs(entry.family.FAMILY, entry.config, stage)
-    return runs, jax.eval_shape(lambda: decode.init_cache(
+    return runs, jax.eval_shape(lambda: stage_cache.init_cache(
         entry.config, entry.config.num_hidden_layers, rows, max_len,
         leaves=qwen3_next.cache_leaves(entry.config), runs=runs))
 
@@ -594,7 +595,7 @@ def test_a_stage_of_one_kind_has_no_layers_of_the_other():
 def test_leaves_of_kinds_need_the_stages_runs():
     cfg = registry.get_model_config(TINY)
     with pytest.raises(ValueError, match="runs of kinds"):
-        decode.init_cache(cfg, 8, 1, 16,
+        stage_cache.init_cache(cfg, 8, 1, 16,
                           leaves=qwen3_next.cache_leaves(cfg))
 
 
@@ -677,7 +678,7 @@ def test_what_the_family_cannot_do_is_refused_by_name(asked):
             shard_apply(entry.family.FAMILY, entry.config, stage, params,
                         jnp.zeros((1, 4), jnp.int32))
         with pytest.raises(NotImplementedError, match="qwen3_next"):
-            qwen3_next.sublayer({}, 0, None, entry.config)
+            qwen3_next.FAMILY.sublayer({}, 0, None, entry.config)
         return
     if asked in ("kv_pages", "speculative"):
         pipe = decode.DecodePipeline(entry.family.FAMILY, entry.config,

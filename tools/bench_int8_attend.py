@@ -47,7 +47,7 @@ def main():
 
     from pipeedge_tpu.ops.decode_attention import (
         int8_decode_attention, int8_decode_attention_supported)
-    from pipeedge_tpu.parallel import decode as dec
+    from pipeedge_tpu.models import stage_cache
 
     b, h, d = args.batch, args.heads, args.head_dim
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
@@ -99,9 +99,9 @@ def main():
 
         def xla_route(q, pos, kq, ks, kz, vq, vs, vz, k_new, v_new):
             # the production XLA path's math: dequantize window, fresh
-            # row substitution, masked attend (decode._attend)
-            k = dec._dequantize_rows(kq, ks, kz, dtype)
-            v = dec._dequantize_rows(vq, vs, vz, dtype)
+            # row substitution, masked attend (stage_cache.attend)
+            k = stage_cache.dequantize_rows(kq, ks, kz, dtype)
+            v = stage_cache.dequantize_rows(vq, vs, vz, dtype)
             k = jax.lax.dynamic_update_slice(k, k_new, (0, pos, 0, 0))
             v = jax.lax.dynamic_update_slice(v, v_new, (0, pos, 0, 0))
             keep = (jnp.arange(width) <= pos)[None, :]

@@ -53,7 +53,7 @@ def run_dcn(args, cfg, total, partition, max_len, dtype):
     import jax.numpy as jnp
 
     from pipeedge_tpu.comm import dcn, wire
-    from pipeedge_tpu.models import registry
+    from pipeedge_tpu.models import registry, stage_cache
     from pipeedge_tpu.parallel import decode
 
     world = len(partition)
@@ -105,8 +105,8 @@ def run_dcn(args, cfg, total, partition, max_len, dtype):
             """One full fleet-lockstep generation (prefill + steps). Every
             rank executes the same step count, so no control plane is
             needed; returns rank 0's tokens."""
-            cache = decode.init_cache(cfg, (r - l + 1) // 4,
-                                      args.batch_size, max_len, dtype)
+            cache = stage_cache.init_cache(cfg, (r - l + 1) // 4,
+                                           args.batch_size, max_len, dtype)
             rng = jax.random.PRNGKey(args.seed)
             tokens = []
 
