@@ -1,7 +1,7 @@
 """The kit the sparse decoder families share (keye, kimi, qwen3_next, lfm2,
-laguna): what a family is NOT is here, so that its module holds its mixers,
-its cache's leaves, its kinds of block and its key map, and imports no
-other family.
+laguna, minicpm_sala): what a family is NOT is here, so that its module
+holds its mixers, its cache's leaves, its kinds of block and its key map,
+and imports no other family.
 
 - products with weights stored `[out, in]` (`lin`), in chunks of rows where
   the result is wide (`in_row_chunks`); the dense SwiGLU (`dense_ffn`) and
@@ -143,10 +143,15 @@ def map_query_chunks(fn, chunk: int, queries: tuple, rows: tuple = ()):
 def token_hooks(name: str, dtype, norm: Callable) -> Dict:
     """`FamilySpec`'s hooks of a family whose embedding is its tokens' rows
     in `dtype` (positions live in its mixers), that runs through the cached
-    decode path only, and whose head follows `norm(p, x, eps)`."""
+    decode path only, and whose head follows `norm(p, x, eps)`. Where the
+    embedding's or the head's leaves hold a `factor` (a family's loader puts
+    it there from the configuration: MiniCPM's `scale_emb`, and its
+    `dim_model_base / hidden_size`), the rows, or the head's normed input,
+    are multiplied by it."""
     def span_embed(pe: Dict, tok: jax.Array, pos) -> jax.Array:
         """Token embedding [B, K] -> [B, K, D]."""
-        return jnp.take(pe["wte"], tok, axis=0).astype(dtype)
+        rows = jnp.take(pe["wte"], tok, axis=0).astype(dtype)
+        return rows * pe["factor"].astype(dtype) if "factor" in pe else rows
 
     def embed(p: Dict, input_ids: jax.Array, cfg: TransformerConfig):
         return span_embed(p, input_ids, 0)
@@ -163,7 +168,10 @@ def token_hooks(name: str, dtype, norm: Callable) -> Dict:
 
     def finalize(p: Dict, hidden: jax.Array, cfg: TransformerConfig):
         """Final norm + LM head -> [B, S, vocab] logits."""
-        return lin(p["head"]["w"], norm(p["ln"], hidden, cfg.layer_norm_eps))
+        normed = norm(p["ln"], hidden, cfg.layer_norm_eps)
+        if "factor" in p:
+            normed = normed * p["factor"].astype(normed.dtype)
+        return lin(p["head"]["w"], normed)
 
     return dict(embed=embed, span_embed=span_embed, decode_embed=decode_embed,
                 sublayer=sublayer, finalize=finalize)

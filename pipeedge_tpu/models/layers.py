@@ -22,7 +22,8 @@ class TransformerConfig:
     local constants so the framework runs with zero egress)."""
     model_type: str              # the family: 'vit' | 'bert' | 'deit' |
     #                              'gpt2' | 'llama' | 'keye' | 'kimi' |
-    #                              'qwen3_next' | 'lfm2'
+    #                              'qwen3_next' | 'lfm2' | 'laguna' |
+    #                              'minicpm_sala'
     hidden_size: int
     num_hidden_layers: int       # transformer blocks (sublayers = 4x this)
     num_attention_heads: int
@@ -130,6 +131,21 @@ class TransformerConfig:
     # `partial_rotary_factor` are the full layers')
     layer_heads: tuple = ()
     sliding_rope_theta: float = 0.0
+    # minicpm_sala family ("minicpm4" | "lightning-attn" in `layer_types`):
+    # MiniCPM's three scalings (the embedding times `scale_emb`, a block's
+    # two deltas times `scale_depth / sqrt(published_layers)`, the head's
+    # input over `hidden_size / dim_model_base`; 0 = none), the depth the
+    # model was published with, which a cut keeps (`<name>@<blocks>`
+    # replaces `num_hidden_layers` alone: the residual's factor and a
+    # lightning layer's decay are the whole model's), and the block-sparse
+    # attention's sizes: (kernel_size, kernel_stride, block_size, topk,
+    # init_blocks, window_size, dense_len), in positions but `topk` and
+    # `init_blocks`, in blocks
+    scale_emb: float = 0.0
+    scale_depth: float = 0.0
+    dim_model_base: int = 0
+    published_layers: int = 0
+    sparse_attention: tuple = ()
 
     @property
     def head_dim(self) -> int:

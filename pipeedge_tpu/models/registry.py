@@ -31,6 +31,7 @@ from . import kimi as kimi_mod
 from . import laguna as laguna_mod
 from . import lfm2 as lfm2_mod
 from . import llama as llama_mod
+from . import minicpm_sala as minicpm_sala_mod
 from . import qwen3_next as qwen3_next_mod
 from . import vit as vit_mod
 
@@ -174,12 +175,31 @@ def _laguna(name, weights, hidden, pattern, heads, kv_heads, head_dim,
         prefill_chunk=span))
 
 
+def _minicpm_sala(name, weights, hidden, pattern, heads, kv_heads, head_dim,
+                  dense_width, vocab, max_pos, sparse, chunk, span,
+                  scale_emb=12.0, scale_depth=1.4, base=256):
+    blocks = len(pattern)
+    return ModelEntry(name, 4 * blocks, weights, minicpm_sala_mod,
+                      TransformerConfig(
+        model_type="minicpm_sala", hidden_size=hidden,
+        num_hidden_layers=blocks, num_attention_heads=heads,
+        num_kv_heads=kv_heads, attn_head_dim=head_dim,
+        intermediate_size=dense_width, layer_norm_eps=1e-6, vocab_size=vocab,
+        max_position_embeddings=max_pos, rope_theta=10000.0, qk_norm=True,
+        layer_types=_layer_types(pattern), linear_chunk=chunk,
+        prefill_chunk=span, scale_emb=scale_emb, scale_depth=scale_depth,
+        dim_model_base=base, published_layers=blocks,
+        sparse_attention=tuple(sparse)))
+
+
 # a pattern of mixers, one letter a block. LFM2's: c a gated short
 # convolution, a grouped-query attention (no interval: the last attention
-# comes early). Laguna's: f attention over every position, s over a window
+# comes early). Laguna's: f attention over every position, s over a window.
+# MiniCPM-SALA's: m MiniCPM4's block-sparse attention, l lightning attention
 def _layer_types(pattern: str) -> tuple:
     return tuple({"c": "conv", "a": "full_attention", "f": "full_attention",
-                  "s": "sliding_attention"}[m] for m in pattern)
+                  "s": "sliding_attention", "m": "minicpm4",
+                  "l": "lightning-attn"}[m] for m in pattern)
 
 
 _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
@@ -250,6 +270,18 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
             max_pos=262144, experts=256, expert_width=512, per_tok=8,
             yarn=(64.0, 4096, 64.0, 1.0, 1.4158883083359672),
             sliding_theta=10000.0, span=128),
+    # MiniCPM-SALA: 8 layers of MiniCPM4's block-sparse attention (32 query
+    # and 2 KV heads, no rotation; pooled keys of 32 positions every 16,
+    # blocks of 64: the first, the 32 local and the 64 best) among 24 of
+    # lightning attention (a state of 128 x 128 a head, 32 heads), a dense
+    # SwiGLU in every layer, MiniCPM's scalings. One chip holds the first of
+    # eight pipeline stages, one period: `...@4`
+    _minicpm_sala("openbmb/MiniCPM-SALA", "MiniCPM-SALA.npz", 4096,
+                  "m" + "l" * 8 + "m" + "l" * 6 + "mm" + "l" * 4 + "m"
+                  + "l" * 6 + "mmm", 32, 2, 128, dense_width=16384,
+                  vocab=73448, max_pos=524288,
+                  sparse=(32, 16, 64, 64, 1, 2048, 8192), chunk=128,
+                  span=1024),
     # tiny synthetic models for fast tests / CI (not in the reference's list)
     _vit("pipeedge/test-tiny-vit", 8, "test-tiny-vit.npz", 32, 2, 4, 64, 5,
          patch=4, img=16),
@@ -287,6 +319,13 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
             max_pos=64, experts=8, expert_width=16, per_tok=2,
             yarn=(4.0, 16, 2.0, 0.25, 1.1386294361119891),
             sliding_theta=100.0, span=4, theta=10000.0),
+    # six blocks: either kind has two runs in one stage; kernels of 4
+    # every 2, blocks of 8 (the first, the 2 local and the 2 best), dense
+    # up to 32 positions
+    _minicpm_sala("pipeedge/test-tiny-minicpm-sala",
+                  "test-tiny-minicpm-sala.npz", 32, "mlllml", 4, 2, 8,
+                  dense_width=64, vocab=100, max_pos=128,
+                  sparse=(4, 2, 8, 2, 1, 16, 32), chunk=4, span=8),
     _gpt2("pipeedge/test-tiny-moe", 8, "test-tiny-moe.npz", 32, 2, 4, 64,
           vocab=100, max_pos=64, n_experts=4, capacity_factor=4.0),
 ]}

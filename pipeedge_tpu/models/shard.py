@@ -55,12 +55,18 @@ class CacheLeaf(NamedTuple):
     stage's `max_len` (0): a RING of the last `length` positions, `[L, B,
     min(length, max_len)] + shape`, position `p` at slot `p mod length`
     (a layer that attends a window of that many positions and needs keep no
-    more; models/stage_cache.py, "A ring")."""
+    more; models/stage_cache.py, "A ring"). `stride`: the leaf keeps a row
+    every `stride` positions, `[L, B, max_len // stride] + shape`, row `j` a
+    summary of the `reach` positions from `stride * j` (pooled keys), written
+    by the call that brings the last of them (models/stage_cache.py, "A
+    stride")."""
     shape: tuple
     dtype: Any
     kind: Any = None
     whole: bool = False
     length: int = 0
+    stride: int = 0
+    reach: int = 0
 
 
 def kind_runs(family, cfg: TransformerConfig,

@@ -66,6 +66,15 @@ Cache = Dict[str, jax.Array]   # {'k': [L, B, T, H*Dh], 'v': [L, B, T, H*Dh]}
 # `pos`, slot `s` holds the largest `p < pos` with `p mod W == s`, nothing
 # while that is negative (`cache_update_and_read`, `ring=True`). The ladder
 # (`attend_bucket`) does not reach it: it reads `W` at every position.
+#
+# A stride. A leaf with a `stride` keeps one row every `stride` positions,
+# `[L, B, max_len // stride, ...]`: row `j` summarises the `reach` positions
+# from `stride * j` (the mean of their keys) and exists once the last of them
+# is written, so a call at `[pos, pos + S)` completes the rows from
+# `first_strided_row(pos)` on, `strided_rows(S)` of them at most, some from
+# positions of the call before. The block step hands exactly that many, those
+# it does not complete as the cache held them, and `write_rows` puts them
+# there; a reader masks row `j` by `stride * j + reach - 1 <= ` its query.
 STATS = "stats"
 _STATS_UNIT = 20
 
@@ -88,6 +97,27 @@ def ring_names(leaves) -> tuple:
     """The leaves that keep a ring of positions, not `max_len` of them."""
     return tuple(name for name, leaf in (leaves or {}).items()
                  if getattr(leaf, "length", 0))
+
+
+def stride_names(leaves) -> Dict:
+    """{leaf: (stride, reach)} of the leaves that keep a row every `stride`
+    positions."""
+    return {name: (leaf.stride, leaf.reach)
+            for name, leaf in (leaves or {}).items()
+            if getattr(leaf, "stride", 0)}
+
+
+def first_strided_row(pos, stride: int, reach: int):
+    """The first row of a strided leaf that a call at `pos` can complete:
+    the least `j >= 0` whose last position `stride * j + reach - 1` is at or
+    past `pos`."""
+    return jnp.maximum(-((reach - 1 - jnp.asarray(pos)) // stride), 0)
+
+
+def strided_rows(span: int, stride: int, held: int) -> int:
+    """How many rows of a strided leaf of `held` rows a call of `span`
+    positions hands `write_rows`: every row it could complete."""
+    return min(-(-span // stride), held)
 
 
 def shares_layers(owner: Dict, kind) -> tuple:
@@ -144,7 +174,7 @@ def read_window(buf: jax.Array, layer, width: int,
 
 
 def write_rows(cache: Cache, rows: Cache, pos, whole: tuple = (),
-               rings: tuple = ()) -> Cache:
+               rings: tuple = (), strides=None) -> Cache:
     """Every layer's new `rows` (leaves `[L, B, S, ...]`) into the stacked
     cache at positions [pos, pos + S): one in-place update a leaf, for a
     decode step, a span and a prefill alike. A leaf named in `whole` has no
@@ -154,10 +184,16 @@ def write_rows(cache: Cache, rows: Cache, pos, whole: tuple = (),
     written back, the one that ends no later than the ring does and the
     one at the ring's start, which takes the rows that ran past its end
     (none, as a rule). Of a span longer than the ring (a whole prompt
-    through the prefill program) the last W rows are written."""
-    def write(buf, new):
+    through the prefill program) the last W rows are written. A leaf named
+    in `strides` ({name: (stride, reach)}, `stride_names`) takes its rows
+    from `first_strided_row(pos)` on ("A stride", above)."""
+    def write(buf, new, at=pos):
         return jax.lax.dynamic_update_slice(
-            buf, new.astype(buf.dtype), (0, 0, pos) + (0,) * (buf.ndim - 3))
+            buf, new.astype(buf.dtype), (0, 0, at) + (0,) * (buf.ndim - 3))
+
+    def strided(name):
+        return lambda buf, new: write(
+            buf, new, first_strided_row(pos, *strides[name]))
 
     def around(buf, new):
         ring, span, first = buf.shape[2], new.shape[2], pos
@@ -200,7 +236,9 @@ def write_rows(cache: Cache, rows: Cache, pos, whole: tuple = (),
     return {name: buf if not buf.shape[0] else
             (add if name == STATS else
              replace if name in whole else
-             around if name in rings else write)(buf, rows[name])
+             around if name in rings else
+             strided(name) if name in (strides or ()) else
+             write)(buf, rows[name])
             for name, buf in cache.items()}
 
 
@@ -223,7 +261,8 @@ def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
     that names the kind (or kinds) of block that owns it, `runs`
     (`kind_runs`: the stage's blocks as `(kind, count)`) gives its `L`, the
     count of those kinds among the `n_blocks`; a `whole` leaf has no `T`,
-    and one with a `length` keeps `min(length, max_len)` positions, a ring.
+    one with a `length` keeps `min(length, max_len)` positions, a ring, and
+    one with a `stride` a row every `stride` positions.
 
     `cache_bits=8` stores K/V as int8 with per-(position, head) affine
     scales (QuantPipe's activation-compression idea applied to the decode
@@ -259,7 +298,8 @@ def init_cache(cfg: TransformerConfig, n_blocks: int, batch: int,
                 jnp.zeros((layers(name), batch)
                           + (() if name in whole else
                              (min(getattr(tail, "length", 0) or max_len,
-                                  max_len),))
+                                  max_len)
+                              // (getattr(tail, "stride", 0) or 1),))
                           + tuple(tail.shape), tail.dtype)
                 for name, tail in leaves.items()}
     shape = (n_blocks, batch, max_len, cfg.kv_heads * cfg.head_dim)

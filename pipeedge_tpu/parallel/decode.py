@@ -40,7 +40,8 @@ from ..models.stage_cache import (STATS, Cache, LayerCache, LayerSlice,
                                   cache_update_and_read,
                                   cache_write_quantized, init_cache,
                                   leaf_owners, read_stats, ring_names,
-                                  shares_layers, whole_names, write_rows)
+                                  shares_layers, stride_names, whole_names,
+                                  write_rows)
 from ..models.shard import BlockRuns, kind_runs
 from ..models.layers import (TransformerConfig, dense, gelu_new, layer_norm)
 
@@ -426,7 +427,8 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
         name: jnp.concatenate(parts) for name in cache
         if (parts := [new[name] for new in rows if name in new])}
     return x, write_rows(cache, rows, 0 if prefill else pos,
-                         whole=whole_names(leaves), rings=ring_names(leaves))
+                         whole=whole_names(leaves), rings=ring_names(leaves),
+                         strides=stride_names(leaves))
 
 
 # every stage program takes (params, data, cache[, pos]) and donates the
@@ -1383,13 +1385,16 @@ class DecodePipeline:
         per-stage cache layout (block split, max_len, quantization,
         dtype, KV geometry, and the geometry of every leaf the family names:
         its shape and type, the kind of block that owns it and whether it is
-        a row a position, a ring of them or a row a request) matches — a
-        mismatched handle would otherwise die deep inside jit with an opaque shape error or
-        silently corrupt attend windows (round-4 advice)."""
+        a row a position, a ring of them, a row every few or a row a request)
+        matches — a mismatched handle would otherwise die deep inside jit
+        with an opaque shape error or silently corrupt attend windows
+        (round-4 advice)."""
         named = tuple(
             (name, tuple(leaf.shape), jnp.dtype(leaf.dtype).name,
              getattr(leaf, "kind", None), getattr(leaf, "whole", False))
             + ((leaf.length,) if getattr(leaf, "length", 0) else ())
+            + ((leaf.stride, leaf.reach) if getattr(leaf, "stride", 0)
+               else ())
             for name, leaf in sorted((self.cache_leaves or {}).items()))
         return ("decode-prefix-v2",
                 tuple(st.get("runs") or st["n_blocks"]

@@ -433,3 +433,53 @@ def test_spmd_vit_large_cell_compiles_for_v5e(n_ubatch, topo):
     assert memory.temp_size_in_bytes < 2 * embedded + 1.5 * stage_bytes
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 16e9 - 2.5e9
+
+
+MINICPM_SALA_CELL = "openbmb/MiniCPM-SALA@4"
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (1024, True)])
+def test_minicpm_sala_stage_program_compiles_for_v5e(span, last_only,
+                                                     on_chip):
+    """`minicpm-sala.longctx-batch` at its real size: one period (a
+    block-sparse layer, three lightning layers) at the published widths, 2
+    rows, the 65,536 bucket; a decode step (pooled keys scored over the
+    width, 97 blocks of 64 gathered a KV head) and one span of the prefill
+    (the window under the selection's mask). The resident bytes (3.42 GB of
+    weights, 0.28 GB of keys, values and pooled keys in ONE layer, 13 MB of
+    state in three) and the program's temporaries have to fit one chip's
+    16 GB beside the benchmark's float32 reference."""
+    from pipeedge_tpu.models import ShardConfig, registry, stage_cache
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry(MINICPM_SALA_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    rows, max_len = 2, 65536
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg),
+        runs=kind_runs(entry.family.FAMILY, cfg, stage)))
+    assert cache["k"].shape == (1, rows, max_len, 256)
+    assert cache["k_pool"].shape == (1, rows, max_len // 16, 256)
+    assert cache["la_state"].shape == (3, rows, 32, 128, 128)
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=max_len,
+                          last_only=last_only).compile()
+    memory = compiled.memory_analysis()
+    print(f"minicpm-sala {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e6:.0f} MB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * (max_len * 2112 + 3 * 2097152)
+    assert memory.alias_size_in_bytes >= cache_bytes     # updated in place
+    # keys and values in the one sparse layer only, no leaf padded
+    assert memory.argument_size_in_bytes < 3.43e9 + 1.02 * cache_bytes
+    # a span holds one KV head's lanes of the window and a chunk's scores;
+    # a step its gathered slots and the pooled keys, never a window
+    assert memory.temp_size_in_bytes < (1.2e9 if span > 1 else 0.1e9)
