@@ -8,15 +8,14 @@ and imports no other family.
   the routed expert layer (`routed_experts`); either, as a cached block
   step's FFN, with the counts every family's `STATS` start with (`ffn`);
 - queries in chunks whose scores stay under `SCORE_BYTES` (`query_chunk`,
-  `map_query_chunks` over `split_queries` / `join_queries`);
+  `map_query_chunks`), one softmax over masked key parts (`attend_masked`);
 - the hooks of a family that embeds tokens alone and runs through the
   cached decode path only (`token_hooks`);
-- loading: host leaves stacked a run and placed a leaf at a time
-  (`assemble_shard`), and the two loaders that follow from a family's
-  `_assemble(cfg, shard_config, get, dtype)` (`loader`).
+- loading: leaves stacked a run, placed one at a time (`assemble_shard`), and
+  the loaders (`loader`) of a family's `_assemble(cfg, shard_config, get, dt)`.
 
-It imports `layers`, `shard` and `parallel/expert.py` (which imports
-`layers` only); the cache a block step sees is `models/stage_cache.py`.
+It imports `layers`, `shard`, `ops/masked_attention.py`, `parallel/expert.py`
+(which imports `layers`); a block step's cache is `models/stage_cache.py`.
 """
 from __future__ import annotations
 
@@ -27,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ShardConfig
+from ..ops import masked_attention
 from ..parallel.expert import topk_ffn_delta
 from .layers import TransformerConfig, exact_dot
 from .shard import build_shard_params
@@ -138,6 +138,80 @@ def map_query_chunks(fn, chunk: int, queries: tuple, rows: tuple = ()):
         return (join_queries(out[0]),) \
             + tuple(jnp.sum(count) for count in out[1:])
     return join_queries(out)
+
+
+# -- one softmax over selection-masked key parts -------------------------------
+
+# rows of a tile (queries x a KV group's query heads) from which a span's
+# masked attention takes the streaming kernel: one full tile of the matrix
+# unit's 128 rows. Under it a call is a decode step's (one query a row of the
+# batch: 8 or 16 rows a group), bound by the bytes of its keys, which the
+# einsums read once as well. The cells' spans have 512 to 4,096 rows
+FUSED_ROWS = 128
+
+# what a family's block step counts after its own counts where it attends
+# through `attend_masked`: calls whose attention took the kernel
+ATTEND_STATS = ("attend_fused_calls",)
+
+
+def _fused_mode():
+    """How this backend runs the masked-attention kernel: "mosaic" on a TPU,
+    None where Mosaic cannot run (the einsums serve every call); the tests
+    put "interpret" here."""
+    return "mosaic" if jax.default_backend() == "tpu" else None
+
+
+def softmax_over(q, ks, vs, keeps, spec: str):
+    """One softmax over key parts: scores `spec`(q, k) a part at `HIGHEST`,
+    over the root of the head's width, masked by `keeps` (-1e30: a masked
+    key's weight is exactly 0); the weights are divided by their sum after
+    they have met the values. `spec` names q's and a part's axes, e.g.
+    "bqrd,bkd->brqk". -> (the weighted values, q's axes; the weights' sum,
+    the scores' axes but the keys')."""
+    hd = q.shape[-1]
+
+    def dots(spec, x, y):
+        return jnp.einsum(spec, x, y, precision=jax.lax.Precision.HIGHEST,
+                          preferred_element_type=jnp.float32)
+
+    scores = [jnp.where(keep, dots(spec, q, k) * hd ** -0.5, -1e30)
+              for k, keep in zip(ks, keeps)]
+    top = jnp.max(jnp.concatenate(
+        [jnp.max(sc, axis=-1, keepdims=True) for sc in scores], -1),
+        axis=-1, keepdims=True)
+    probs = [jnp.exp(sc - top) for sc in scores]
+    total = sum(jnp.sum(pr, axis=-1) for pr in probs)
+    ins, out = spec.split("->")
+    back = f"{out},{ins.split(',')[1]}->{ins.split(',')[0]}"
+    mixed = sum(dots(back, pr, v) for pr, v in zip(probs, vs))
+    return mixed, total
+
+
+def attend_masked(q, ks, vs, keeps):
+    """A KV group's query heads q [B, Q, r, Dh] over key parts ks, vs (a
+    [B, K, Dh] a part, as read) under the masks `keeps` (a bool [B or 1, Q,
+    K] a part; every query keeps a key of some part): one softmax over all
+    parts. -> (context [B, Q, r, Dh] float32, 1 where the streaming kernel
+    ran, else 0).
+
+    Which way is read off the call: float32 operands, heads of whole lanes,
+    parts of whole key blocks, `FUSED_ROWS` rows or more, on a backend that
+    runs Mosaic."""
+    b, n_q, heads, hd = q.shape
+    mode = _fused_mode()
+    fits = n_q * heads >= FUSED_ROWS and hd % 128 == 0 \
+        and q.dtype == jnp.float32 \
+        and all(masked_attention.key_block(k.shape[1]) for k in ks)
+    if not (mode and fits):
+        mixed, total = softmax_over(
+            q, ks, vs, [keep[:, None] for keep in keeps], "bqrd,bkd->brqk")
+        return mixed / jnp.moveaxis(total, 1, 2)[..., None], 0
+    ctx = masked_attention.attend(
+        jnp.moveaxis(q, 2, 1), [k.astype(q.dtype) for k in ks],
+        [v.astype(q.dtype) for v in vs],
+        [jnp.broadcast_to(keep, (b, n_q, k.shape[1]))
+         for k, keep in zip(ks, keeps)], interpret=mode == "interpret")
+    return jnp.moveaxis(ctx, 1, 2), 1
 
 
 def token_hooks(name: str, dtype, norm: Callable) -> Dict:

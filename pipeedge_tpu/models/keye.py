@@ -41,7 +41,9 @@ they read is already apart.
 
 **Prefill** runs in spans of `prefill_span(cfg)` positions through the
 decode-shaped stage program, each span attending the cache written so far
-and its own rows: no program holds a whole long prompt's scores.
+and its own rows: no program holds a whole long prompt's scores, and on a
+TPU none holds a chunk's either (`decoder.attend_masked`: the streaming
+kernel of `ops/masked_attention.py` for a span, the einsums for a step).
 
 The vision tower is not here: the M-RoPE takes three position rows so that
 image tokens could be placed, and text gives it three equal ones.
@@ -69,7 +71,8 @@ from .shard import FamilySpec
 from .stage_cache import attend_width, read_window
 
 # what a block step counts into the cache's `stats` leaf, in this order
-STATS = decoder.MOE_STATS + ("sparse_scored", "sparse_kept")
+STATS = decoder.MOE_STATS + ("sparse_scored", "sparse_kept") \
+    + decoder.ATTEND_STATS
 
 
 def prefill_span(cfg: TransformerConfig) -> int:
@@ -188,33 +191,21 @@ def _topk_mask(score: jax.Array, valid: jax.Array, k: int) -> jax.Array:
     return jax.lax.cond(tied, by_position, lambda: above | level)
 
 
-def _attend_selected(q, k, v, keep) -> jax.Array:
+def _attend_selected(q, k, v, keep):
     """Grouped-query attention of q [B,Q,H,Dh] over key parts under
-    per-row masks `keep` (a [B,Q,K] a part): one softmax over all parts.
-    A part's k and v are tuples of one [B,K,Dh] a KV head, each used as it
-    was read: not repeated for its query heads, not put beside the others.
-    -> [B, Q, H*Dh]."""
+    per-row masks `keep` (a [B,Q,K] a part): one softmax over all parts, a
+    KV group at a time (`decoder.attend_masked`). A part's k and v are
+    tuples of one [B,K,Dh] a KV head, each used as it was read: not repeated
+    for its query heads, not put beside the others.
+    -> ([B, Q, H*Dh], 1 where the streaming kernel ran)."""
     b, s, h, hd = q.shape
     groups = len(k[0])
     q4 = q.reshape(b, s, groups, h // groups, hd)
-    out = []
-    for g in range(groups):
-        scores = [jnp.where(m[:, None], jnp.einsum(
-            "bqrd,bkd->brqk", q4[:, :, g], kp[g].astype(q.dtype),
-            preferred_element_type=jnp.float32, precision=_ACTIVATIONS)
-            / jnp.sqrt(jnp.float32(hd)),
-            -1e30) for kp, m in zip(k, keep)]
-        top = jnp.max(jnp.concatenate(
-            [jnp.max(sc, axis=-1, keepdims=True) for sc in scores], -1),
-            axis=-1, keepdims=True)
-        probs = [jnp.exp(sc - top) for sc in scores]
-        total = sum(jnp.sum(pr, axis=-1, keepdims=True) for pr in probs)
-        out.append(sum(jnp.einsum(
-            "brqk,bkd->bqrd", (pr / total).astype(q.dtype),
-            vp[g].astype(q.dtype), preferred_element_type=jnp.float32,
-            precision=_ACTIVATIONS)
-            for pr, vp in zip(probs, v)))
-    return jnp.stack(out, axis=2).astype(q.dtype).reshape(b, s, h * hd)
+    out, fused = zip(*(decoder.attend_masked(
+        q4[:, :, g], [kp[g] for kp in k], [vp[g] for vp in v], keep)
+        for g in range(groups)))
+    return jnp.stack(out, axis=2).astype(q.dtype).reshape(b, s, h * hd), \
+        fused[0]
 
 
 def sparse_attention(q, iq, iw, q_pos, parts, cfg: TransformerConfig):
@@ -224,8 +215,9 @@ def sparse_attention(q, iq, iw, q_pos, parts, cfg: TransformerConfig):
     the query; of those the indexer keeps `cfg.index_topk`. Queries run in
     chunks so that no chunk's scores pass `decoder.SCORE_BYTES`.
 
-    Returns (ctx [B, Q, H*Dh], scored, kept): the counts of positions the
-    indexer scored and of positions attended, int32."""
+    Returns (ctx [B, Q, H*Dh], scored, kept, fused): the counts of positions
+    the indexer scored and of positions attended, and 1 where the attention
+    took the streaming kernel, int32."""
     b, n_q, h, _ = q.shape
     n_keys = sum(part[2].shape[1] for part in parts)
 
@@ -245,17 +237,18 @@ def sparse_attention(q, iq, iw, q_pos, parts, cfg: TransformerConfig):
                               cfg.index_topk)
             keep = jnp.split(mask, np.cumsum(
                 [part[2].shape[1] for part in parts])[:-1], axis=-1)
-        ctx = _attend_selected(q_c, [part[0] for part in parts],
-                               [part[1] for part in parts], keep)
+        ctx, fused = _attend_selected(q_c, [part[0] for part in parts],
+                                      [part[1] for part in parts], keep)
         scored = b * sum(jnp.sum(ok, dtype=jnp.int32) for ok in valid)
         kept = sum(jnp.sum(m, dtype=jnp.int32) for m in keep)
-        return ctx, scored, kept
+        return ctx, scored, kept, jnp.int32(fused)
 
     # scores are live one KV group at a time
-    return decoder.map_query_chunks(
+    ctx, scored, kept, fused = decoder.map_query_chunks(
         one_chunk, decoder.query_chunk(
             n_q, b * (h // cfg.kv_heads) * n_keys * 4),
         (q, iq, iw), (q_pos,))
+    return ctx, scored, kept, jnp.minimum(fused, 1)
 
 
 def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
@@ -270,7 +263,7 @@ def sublayer(p: Dict, sub: int, data, cfg: TransformerConfig,
         normed = rms_norm(p["ln_before"], data, cfg.layer_norm_eps)
         pos = jnp.arange(normed.shape[1])
         q, k, v, iq, ik, iw = _project(p, normed, cfg, pos)
-        ctx, _, _ = sparse_attention(
+        ctx, *_ = sparse_attention(
             q, iq, iw, pos, [(by_head(k, cfg.kv_heads),
                               by_head(v, cfg.kv_heads), ik, pos, None)], cfg)
         return (ctx, data)
@@ -336,12 +329,13 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
                   for head in lanes) for name in ("k", "v"))
             + (read_window(stack["ik"], bcache.layer, width), at,
                at < pos))
-    ctx, scored, kept = sparse_attention(q, iq, iw, q_pos, parts, cfg)
+    ctx, scored, kept, fused = sparse_attention(q, iq, iw, q_pos, parts,
+                                                cfg)
     h = _lin(p["attn_out"]["w"], ctx) + x
     delta, moe = routed_experts(
         p, rms_norm(p["ln_after"], h, cfg.layer_norm_eps), cfg)
     stats = jnp.concatenate([moe.astype(jnp.int32),
-                             jnp.stack([jnp.int32(1), scored, kept])])
+                             jnp.stack([jnp.int32(1), scored, kept, fused])])
     rows = {"k": k, "v": v, "ik": ik, "stats": stats}
     return h + delta, bcache._replace(rows=rows)
 

@@ -68,7 +68,11 @@ window of `k` and `v` whole, a KV head's lanes at a time, and applies the
 selection as a mask, as keye's indexer does: with uniform random prompts a
 chunk of queries keeps nearly every block between them, so a union gathers
 the window, and a gather a query reads each block once a query and feeds the
-matrix unit 16 rows (PERF.md, PR 44). `sparse_blocks_kept` and
+matrix unit 16 rows (PERF.md, PR 44). The masked softmax itself is
+`decoder.attend_masked`: on a TPU a streaming kernel that keeps a chunk's
+scores in VMEM and skips the key blocks no query of a tile keeps (the dead
+end of the ladder's window, the span's own later rows), elsewhere and for a
+step the einsums (PERF.md, PR 45). `sparse_blocks_kept` and
 `sparse_blocks_read` count both, a query a KV head: a step reads the slots
 it gathers, a span every block at or before the query.
 
@@ -111,10 +115,11 @@ from .stage_cache import (attend_width, first_strided_row, read_window,
                           strided_rows)
 
 # what a block step counts into the cache's `stats` leaf, in this order
-STATS = ("sparse_blocks_kept", "sparse_blocks_read", "sparse_kernels_scored",
-         "sparse_dense_calls", "pooled_rows_written",
-         "lightning_positions_chunked", "lightning_positions_stepped",
-         "lightning_state_carries")
+STATS = ("sparse_blocks_kept", "sparse_blocks_read", "sparse_kernels_scored") \
+    + decoder.ATTEND_STATS + (
+        "sparse_dense_calls", "pooled_rows_written",
+        "lightning_positions_chunked", "lightning_positions_stepped",
+        "lightning_state_carries")
 
 # activations, cache and state (module docstring, Precision)
 ACTIVATIONS = jnp.float32
@@ -400,24 +405,6 @@ def gather_blocks(buf, layer, blocks, size: int, hd: int):
     return out.reshape(b, groups, n * size, hd)
 
 
-def _softmax_over(q, ks, vs, keeps, spec: str):
-    """One softmax over key parts: scores `spec`(q, k) a part, masked by
-    `keeps`; the weights are divided by their sum after they have met the
-    values. `spec` names q's and a part's axes, e.g. "bqrd,bkd->brqk"."""
-    hd = q.shape[-1]
-    scores = [jnp.where(keep, _dots(spec, q, k) * hd ** -0.5, -1e30)
-              for k, keep in zip(ks, keeps)]
-    top = jnp.max(jnp.concatenate(
-        [jnp.max(sc, axis=-1, keepdims=True) for sc in scores], -1),
-        axis=-1, keepdims=True)
-    probs = [jnp.exp(sc - top) for sc in scores]
-    total = sum(jnp.sum(pr, axis=-1) for pr in probs)
-    ins, out = spec.split("->")
-    back = f"{out},{ins.split(',')[1]}->{ins.split(',')[0]}"
-    mixed = sum(_dots(back, pr, v) for pr, v in zip(probs, vs))
-    return mixed, total
-
-
 def attend_span(q, k_new, v_new, pool, bcache, pos, width: int, sp: Sparse,
                 dense: bool):
     """The sparse layer's attention of a span's queries q [B, S, H, Dh] at
@@ -427,8 +414,10 @@ def attend_span(q, k_new, v_new, pool, bcache, pos, width: int, sp: Sparse,
     G*Dh] the pooled keys with the call's own rows in place; `dense`: no
     query is past `dense_len`, nothing is scored. A KV head at a time, its
     lanes of the window read when the head before is done, the queries in
-    chunks whose scores stay under `decoder.SCORE_BYTES`. -> (context [B, S,
-    H, Dh], blocks kept, blocks read, kernels scored)."""
+    chunks whose scores stay under `decoder.SCORE_BYTES`, each chunk one
+    softmax over both parts (`decoder.attend_masked`). -> (context [B, S, H,
+    Dh], [blocks kept, blocks read, kernels scored, 1 where the attention
+    took the streaming kernel])."""
     b, s, h, hd = q.shape
     groups = k_new.shape[2]
     n_blocks = -(-max(width, s) // sp.block)
@@ -469,12 +458,10 @@ def attend_span(q, k_new, v_new, pool, bcache, pos, width: int, sp: Sparse,
                     past[..., 0], jnp.sum(kept, axis=-1), live_blocks[None]))
                 scored = b * jnp.sum(jnp.where(
                     past[0, :, 0], kernels_done(t, sp, pool_g.shape[1]), 0))
-            mixed, total = _softmax_over(
-                q_c, ks, vs, [keep[:, None] for keep in keeps],
-                "bqrd,bkd->brqk")
-            ctx = mixed / jnp.moveaxis(total, 1, 2)[..., None]
+            ctx, fused = decoder.attend_masked(q_c, ks, vs, keeps)
             return ctx.astype(q_c.dtype), kept_n.astype(jnp.int32), \
-                read_n.astype(jnp.int32), scored.astype(jnp.int32)
+                read_n.astype(jnp.int32), scored.astype(jnp.int32), \
+                jnp.int32(fused)
 
         ctx, *counted = decoder.map_query_chunks(
             one_chunk, chunk, (q[:, :, grp],), (t_all, jnp.arange(s)))
@@ -483,14 +470,16 @@ def attend_span(q, k_new, v_new, pool, bcache, pos, width: int, sp: Sparse,
         ctx, done = jax.lax.optimization_barrier((ctx, jnp.int32(0)))
         out.append(ctx)
         counts.append(jnp.stack([jnp.sum(c) for c in counted]))
-    return jnp.stack(out, axis=2).reshape(b, s, h, hd), sum(counts)
+    counts = sum(counts)
+    return jnp.stack(out, axis=2).reshape(b, s, h, hd), \
+        counts.at[3].min(1)
 
 
 def attend_step(q, k_new, v_new, pool, bcache, pos, width: int, sp: Sparse):
     """The sparse layer's attention of ONE query a row, q [B, 1, H, Dh] at
     `pos`, over its kept blocks alone (module docstring, What a call reads):
     every KV head's blocks in one gather a leaf. -> (context [B, 1, H, Dh],
-    blocks kept, blocks read, kernels scored)."""
+    [blocks kept, blocks read, kernels scored, 0: the einsums])."""
     b, _, h, hd = q.shape
     groups = k_new.shape[2]
     t = jnp.asarray(pos).reshape(1)
@@ -508,12 +497,13 @@ def attend_step(q, k_new, v_new, pool, bcache, pos, width: int, sp: Sparse):
     cached_k, cached_v = (gather_blocks(
         bcache.stack[name], bcache.layer, blocks, sp.block, hd
         ).astype(q.dtype) for name in ("k", "v"))
-    mixed, total = _softmax_over(
+    mixed, total = decoder.softmax_over(
         q, [cached_k, own_k], [cached_v, own_v],
         [keep[:, :, None], jnp.ones((1, 1, 1, 1), bool)], "bgrd,bgkd->bgrk")
     counts = jnp.stack([
         jnp.sum(kept), jnp.int32(kept.size),
-        b * groups * kernels_done(t[0], sp, pool.shape[1])]).astype(jnp.int32)
+        b * groups * kernels_done(t[0], sp, pool.shape[1]), 0]).astype(
+            jnp.int32)
     return (mixed / total[..., None]).astype(q.dtype).reshape(b, 1, h, hd), \
         counts
 
@@ -522,7 +512,7 @@ def sparse_attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
                      prefill: bool, read_len=None):
     """The sparse mixer of `normed` [B, S, D] at [pos, pos + S). -> (out [B,
     S, D], the rows k, v [B, S, G*Dh] and k_pool for the cache, counts int32
-    [5]: `STATS`' first five)."""
+    [6]: `STATS`' first six)."""
     b, s, _ = normed.shape
     heads, groups, hd = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
     eps, sp = cfg.layer_norm_eps, sparse_of(cfg)
@@ -589,7 +579,7 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
         mixed, state = lightning(p, normed, state.astype(jnp.float32), pos,
                                  cfg)
         rows = {"la_state": state}
-        counts = jnp.array([0] * 5 + [b * s if s > 1 else 0,
+        counts = jnp.array([0] * 6 + [b * s if s > 1 else 0,
                                       b if s == 1 else 0,
                                       0 if prefill else 1], jnp.int32)
     else:

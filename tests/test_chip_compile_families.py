@@ -149,3 +149,50 @@ def test_laguna_stage_program_compiles_for_v5e(span, last_only, on_chip):
     # no leaf padded, and the rings are rings
     assert memory.argument_size_in_bytes < 7.75e9 + 1.02 * cache_bytes
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+
+
+KEYE_CELL = "Kwai-Keye/Keye-VL-2.0-30B-A3B@6"
+MINICPM_SALA_CELL = "openbmb/MiniCPM-SALA@4"
+
+
+@pytest.mark.parametrize("cell, rows, span, max_len, resident", [
+    (KEYE_CELL, 8, 512, 16384, 12.3e9), (MINICPM_SALA_CELL, 2, 1024, 65536,
+                                         4.0e9)],
+    ids=["keye", "minicpm-sala"])
+def test_widest_span_program_with_the_masked_attention_kernel_compiles_for_v5e(
+        cell, rows, span, max_len, resident, on_chip, monkeypatch):
+    """The widest span program of `keye-vl2.long-batch` (8 rows, spans of
+    512, the 16,384 bucket) and of `minicpm-sala.longctx-batch` (2 rows,
+    spans of 1,024, the 65,536 bucket) with the streaming kernel in them
+    (`decoder.attend_masked` takes it on a backend that runs Mosaic, which
+    the default backend here is not): a VMEM or lowering refusal shows here,
+    before chip time. Argument and temporary bytes printed."""
+    from pipeedge_tpu.models import decoder
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode
+    monkeypatch.setattr(decoder, "_fused_mode", lambda: "mosaic")
+    entry = registry.get_model_entry(cell)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg),
+        runs=kind_runs(entry.family.FAMILY, cfg, stage)))
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=max_len,
+                          last_only=True).compile()
+    text = compiled.as_text()
+    assert "masked_attention" in text and "tpu_custom_call" in text
+    memory = compiled.memory_analysis()
+    print(f"{cell} {rows} rows, span {span} at {max_len} with the masked-"
+          f"attention kernel: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    assert memory.argument_size_in_bytes < resident
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9

@@ -137,7 +137,7 @@ def test_attention_that_keeps_every_key_is_llamas():
             for _ in range(2))
     iq = jnp.zeros((2, 12, 2, 8))
     at = jnp.arange(12)
-    got, scored, kept = keye.sparse_attention(
+    got, scored, kept, fused = keye.sparse_attention(
         q, iq, jnp.zeros((2, 12, 2)), at,
         [(tuple(k[:, :, g] for g in range(2)),
           tuple(v[:, :, g] for g in range(2)), jnp.zeros((2, 12, 8)), at,
@@ -145,6 +145,7 @@ def test_attention_that_keeps_every_key_is_llamas():
     np.testing.assert_allclose(got, llama._gqa_attend(q, k, v, cfg),
                                atol=1e-6)
     assert int(scored) == int(kept) == 2 * 12 * 13 // 2
+    assert int(fused) == 0          # the CPU runs no Mosaic: the einsums
 
 
 @pytest.mark.parametrize("rows", ["equal", "unequal"])
@@ -251,6 +252,9 @@ def test_counters_of_one_batch_are_what_its_sizes_predict(tiny):
     assert gained["sparse_scored", "decode"] == rows * layers \
         * sum(range(25, 32))
     assert gained["sparse_kept", "decode"] == rows * layers * steps * topk
+    # the CPU keeps the einsums (`decoder.attend_masked`)
+    assert gained["attend_fused_calls", "prefill"] == 0
+    assert gained["attend_fused_calls", "decode"] == 0
     experts = _config()["num_experts"]
     for phase, tokens in (("prefill", rows * 8), ("decode", rows)):
         # a prefill in spans of 8: every touched expert's group is one tile

@@ -252,11 +252,13 @@ def test_the_shared_convolution_is_causal_and_carries_its_tail(width, span):
 # before `causal_conv`, `CacheLeaf.kind` as a tuple, `attend(precision=)`
 # and `gate_sum_eps` (the parent commit): equations at the top level and in
 # all. Since PR 41 nine more a traced expert layer: its fourth count and the
-# way back's select (on the CPU these programs keep the tile loop)
+# way back's select (on the CPU these programs keep the tile loop). Since
+# PR 45 keye's masked softmax is `decoder.softmax_over` (the weights divided
+# by their sum once, after the values, not a part at a time: eight fewer)
 TRACED = {("pipeedge/test-tiny-gpt2", 1): (34, 230),
           ("pipeedge/test-tiny-gpt2", 8): (32, 228),
-          ("pipeedge/test-tiny-keye", 1): (38, 755),
-          ("pipeedge/test-tiny-keye", 8): (36, 753),
+          ("pipeedge/test-tiny-keye", 1): (38, 747),
+          ("pipeedge/test-tiny-keye", 8): (36, 745),
           ("pipeedge/test-tiny-kimi", 1): (37, 755),
           ("pipeedge/test-tiny-kimi", 8): (37, 757),
           ("pipeedge/test-tiny-qwen3-next", 1): (44, 2210),

@@ -263,13 +263,14 @@ def test_a_step_reads_the_blocks_it_keeps_and_no_window(tiny):
     _, caches = pipe._prefill(jnp.asarray(ids[:, :PROMPT], jnp.int32))
     before = stage_cache.read_stats(caches[0])
     _, caches = pipe.extend(ids[:, PROMPT:PROMPT + 1], caches, PROMPT)
-    kept, fetched, scored, dense, _, _, stepped, carried = \
+    kept, fetched, scored, fused, dense, _, _, stepped, carried = \
         stage_cache.read_stats(caches[0]) - before
     sparse_layers = sum(kind == "minicpm4" for kind in cfg.layer_types)
     assert kept == fetched == sparse_layers * 2 * cfg.kv_heads * sp.slots
     assert scored == sparse_layers * 2 * cfg.kv_heads * (
         (PROMPT + 1 - sp.kernel) // sp.stride + 1)
     assert (dense, stepped, carried) == (0, 2 * 4, 4)
+    assert fused == 0       # a one-query call keeps the einsums
 
     entry = registry.get_model_entry(TINY)
     stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
@@ -329,6 +330,9 @@ def test_the_counters_reach_the_registry_by_phase(tiny):
     # a span's masked window reads every block at or before a query
     assert 0 < after["sparse_blocks_kept", "prefill"] \
         < after["sparse_blocks_read", "prefill"]
+    # the CPU keeps the einsums (`decoder.attend_masked`)
+    assert after["attend_fused_calls", "prefill"] == 0
+    assert after["attend_fused_calls", "decode"] == 0
     gauge = prom.REGISTRY.gauge("pipeedge_cache_leaf_bytes", "")
     assert gauge.value(leaf="k_pool") == 2 * 2 * (MAX_LEN // 2) * 16 * 4
     assert gauge.value(leaf="la_state") == 4 * 2 * 4 * 8 * 8 * 4
