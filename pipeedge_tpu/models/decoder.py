@@ -194,15 +194,15 @@ def attend_masked(q, ks, vs, keeps):
     parts. -> (context [B, Q, r, Dh] float32, 1 where the streaming kernel
     ran, else 0).
 
-    Which way is read off the call: float32 operands, heads of whole lanes,
-    parts of whole key blocks, `FUSED_ROWS` rows or more, on a backend that
-    runs Mosaic."""
+    Which way is read off the call (`kernel_mode`, which a caller that
+    sizes its query chunks for the einsums' scores asks as well): float32
+    operands, heads of whole lanes, parts of whole key blocks, `FUSED_ROWS`
+    rows or more, on a backend that runs Mosaic. The kernel holds no score
+    outside VMEM: a call that takes it needs no chunks of queries (keye and
+    minicpm_sala chunk for their selections; qwen3_next hands a span whole)."""
     b, n_q, heads, hd = q.shape
-    mode = _fused_mode()
-    fits = n_q * heads >= FUSED_ROWS and hd % 128 == 0 \
-        and q.dtype == jnp.float32 \
-        and all(masked_attention.key_block(k.shape[1]) for k in ks)
-    if not (mode and fits):
+    mode = kernel_mode(n_q * heads, hd, q.dtype, [k.shape[1] for k in ks])
+    if not mode:
         mixed, total = softmax_over(
             q, ks, vs, [keep[:, None] for keep in keeps], "bqrd,bkd->brqk")
         return mixed / jnp.moveaxis(total, 1, 2)[..., None], 0
@@ -212,6 +212,17 @@ def attend_masked(q, ks, vs, keeps):
         [jnp.broadcast_to(keep, (b, n_q, k.shape[1]))
          for k, keep in zip(ks, keeps)], interpret=mode == "interpret")
     return jnp.moveaxis(ctx, 1, 2), 1
+
+
+def kernel_mode(rows: int, head_dim: int, dtype, part_keys):
+    """How `attend_masked` runs a call of `rows` rows (queries x the KV
+    group's query heads) with heads of `head_dim` lanes in `dtype` over
+    parts of `part_keys` keys each: `_fused_mode()` where the streaming
+    kernel takes it, None where the einsums do."""
+    fits = rows >= FUSED_ROWS and head_dim % 128 == 0 \
+        and dtype == jnp.float32 \
+        and all(masked_attention.key_block(n) for n in part_keys)
+    return _fused_mode() if fits else None
 
 
 def token_hooks(name: str, dtype, norm: Callable) -> Dict:

@@ -49,7 +49,7 @@ four layers of keys and values would be 4.3 GB.
 **Precision.** Weights as stored (bfloat16); activations, cache and state
 float32: products with weights through `exact_dot`, the delta rule's
 products of two activations at `HIGHEST`, the delta rule's (the state is a
-sum over every position before) and the attention's (`_ATTENTION` says what
+sum over every position before) and the attention's (`_STATE` says what
 `HIGH` did); a step's and the chunk's inverse's are float32 multiplications
 and sums on the vector unit, which is what `HIGHEST` stands in for. The
 router's top-10 of 512 is a discrete choice that a bfloat16 computation
@@ -88,19 +88,19 @@ from .stage_cache import attend_width, read_window
 
 # what a block step counts into the cache's `stats` leaf, in this order
 STATS = decoder.MOE_STATS + ("gdn_positions_chunked", "gdn_positions_stepped",
-                             "gdn_state_carries")
+                             "gdn_state_carries") + decoder.ATTEND_STATS
 
 # activations, cache and state (module docstring, Precision)
 ACTIVATIONS = jnp.float32
 # products of two activations: float32 in full, the delta rule's (the state
-# is a sum over every position before it) and the attention's. At `HIGH`
+# is a sum over every position before it) and the attention's, which are
+# `decoder.attend_masked`'s (`HIGHEST` in the einsums and the kernel). At `HIGH`
 # (three bfloat16 passes, about 16 bits) the first chip run's greedy tokens
 # lay up to 0.86% of the logits' range from the reference's (PERF.md, PR 33):
 # q and k are normed, so scores are of order 1 and 1e-4 off, and the router's
 # top-10 of 512 after them is a discrete choice that amplifies it (keye's
 # attention found the same, PR 27)
 _STATE = jax.lax.Precision.HIGHEST
-_ATTENTION = jax.lax.Precision.HIGHEST
 
 # the widest diagonal block of a chunk's triangular matrix that is inverted a
 # row at a time (`inverse_block`); wider ones are merged from two
@@ -369,58 +369,53 @@ def _attend_chunk(q, parts, first, pos):
     """Context [B, Q, H*Dh] of the queries q [B, Q, H, Dh] that sit `first`
     rows into the span at `pos`. `parts`: (k, v: one [B, K, Dh] a KV head;
     own: the part is the span's rows, causal, else cached rows, live below
-    `pos`). One softmax over all parts, a KV group at a time."""
+    `pos`). One softmax over all parts, a KV group at a time
+    (`decoder.attend_masked`). -> (context, 1 where the streaming kernel
+    ran)."""
     b, n_q, h, hd = q.shape
     groups = len(parts[0][0])
     q = q.reshape(b, n_q, groups, h // groups, hd)
-    keeps = []
+    keeps = []      # one mask for every row of the batch: [1, Q, K] a part
     for k, _, own in parts:
         at = jnp.arange(k[0].shape[1])
-        keeps.append(at[None, :] <= first + jnp.arange(n_q)[:, None] if own
-                     else jnp.broadcast_to(at < pos, (n_q, at.shape[0])))
-    out = []
-    for grp in range(groups):
-        scores = [jnp.where(keep[None, None], jnp.einsum(
-            "bqrd,bkd->brqk", q[:, :, grp], k[grp].astype(q.dtype),
-            preferred_element_type=jnp.float32, precision=_ATTENTION)
-            * hd ** -0.5, -1e30) for (k, _, _), keep in zip(parts, keeps)]
-        top = jnp.max(jnp.concatenate(
-            [jnp.max(sc, axis=-1, keepdims=True) for sc in scores], -1),
-            axis=-1, keepdims=True)
-        # the weights are divided by their sum after they have met the
-        # values: one pass over [.., Q, Dh] and not one over [.., Q, K]
-        probs = [jnp.exp(sc - top) for sc in scores]
-        total = sum(jnp.sum(pr, axis=-1) for pr in probs)       # [B, r, Q]
-        mixed = sum(jnp.einsum(
-            "brqk,bkd->bqrd", pr.astype(q.dtype), v[grp].astype(q.dtype),
-            preferred_element_type=jnp.float32, precision=_ATTENTION)
-            for pr, (_, v, _) in zip(probs, parts))
-        out.append(mixed / jnp.moveaxis(total, 1, 2)[..., None])
-    return jnp.stack(out, axis=2).astype(q.dtype).reshape(b, n_q, h * hd)
+        keep = at[None, :] <= first + jnp.arange(n_q)[:, None] if own \
+            else jnp.broadcast_to(at < pos, (n_q, at.shape[0]))
+        keeps.append(keep[None])
+    out, fused = zip(*(decoder.attend_masked(
+        q[:, :, grp], [k[grp] for k, _, _ in parts],
+        [v[grp] for _, v, _ in parts], keeps) for grp in range(groups)))
+    return jnp.stack(out, axis=2).astype(q.dtype).reshape(b, n_q, h * hd), \
+        fused[0]
 
 
-def attend(q, parts, pos) -> jax.Array:
-    """`_attend_chunk` over all queries [B, Q, H, Dh], in chunks of queries
-    whose scores (one KV group's) stay under `decoder.SCORE_BYTES`."""
-    b, n_q, h, _ = q.shape
-    n_keys = sum(part[0][0].shape[1] for part in parts)
-    chunk = decoder.query_chunk(
-        n_q, b * (h // len(parts[0][0])) * n_keys * 4)
+def attend(q, parts, pos):
+    """`_attend_chunk` over all queries [B, Q, H, Dh]: in one call where a
+    KV group's call takes the streaming kernel (`decoder.kernel_mode`: no
+    score leaves VMEM), else in chunks of queries whose scores (one KV
+    group's) stay under `decoder.SCORE_BYTES`."""
+    b, n_q, h, hd = q.shape
+    per_group = h // len(parts[0][0])       # query heads a KV group
+    part_keys = [part[0][0].shape[1] for part in parts]
+    chunk = n_q if decoder.kernel_mode(n_q * per_group, hd, q.dtype,
+                                       part_keys) \
+        else decoder.query_chunk(n_q, b * per_group * sum(part_keys) * 4)
     if chunk == n_q:
         return _attend_chunk(q, parts, 0, pos)
     # not `decoder.map_query_chunks`: a chunk's causal mask asks how many
-    # rows into the span the chunk starts
+    # rows into the span the chunk starts. A chunk has fewer rows than the
+    # span, so no chunk takes the kernel where the span did not
     return decoder.join_queries(jax.lax.map(
-        lambda xs: _attend_chunk(xs[0], parts, xs[1], pos),
+        lambda xs: _attend_chunk(xs[0], parts, xs[1], pos)[0],
         (decoder.split_queries(q, chunk),
-         jnp.arange(n_q // chunk) * chunk)))
+         jnp.arange(n_q // chunk) * chunk))), 0
 
 
 def gated_attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
                     prefill: bool, read_len=None):
     """The full mixer of `normed` [B, S, D] at [pos, pos + S) over the
     cached window below `pos` and its own rows. -> (out, the rows k, v
-    [B, S, G*Dh] for the cache)."""
+    [B, S, G*Dh] for the cache, 1 where the attention took the streaming
+    kernel)."""
     b, s, _ = normed.shape
     eps, groups = cfg.layer_norm_eps, cfg.kv_heads
     q_pos = jnp.asarray(pos) + jnp.arange(s)
@@ -444,8 +439,8 @@ def gated_attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
         parts.insert(0, tuple(
             tuple(read_window(stack[name], bcache.layer, width, head)
                   for head in lanes) for name in ("k", "v")) + (False,))
-    ctx = attend(q, parts, pos)
-    return lin(p["attn_out"]["w"], ctx * jax.nn.sigmoid(gate)), k, v
+    ctx, fused = attend(q, parts, pos)
+    return lin(p["attn_out"]["w"], ctx * jax.nn.sigmoid(gate)), k, v, fused
 
 
 # -- the family's hooks --------------------------------------------------------
@@ -461,7 +456,6 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
     b, s, _ = x.shape
     eps = cfg.layer_norm_eps
     normed = rms(p["ln_before"], x, eps)
-    counts = jnp.zeros(3, jnp.int32)
     if "in_m" in p:
         stack = bcache.stack
         state, tail = (jax.lax.dynamic_index_in_dim(
@@ -473,11 +467,12 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
             p, normed, state.astype(jnp.float32), tail, cfg)
         rows = {"gdn_state": state, "gdn_conv": tail}
         counts = jnp.array([b * s if s > 1 else 0, b if s == 1 else 0,
-                            0 if prefill else 1], jnp.int32)
+                            0 if prefill else 1, 0], jnp.int32)
     else:
-        mixed, k, v = gated_attention(p, normed, bcache, pos, cfg, prefill,
-                                      read_len)
+        mixed, k, v, fused = gated_attention(p, normed, bcache, pos, cfg,
+                                             prefill, read_len)
         rows = {"k": k, "v": v}
+        counts = jnp.array([0, 0, 0, fused], jnp.int32)
     h = x + mixed
     delta, moe = decoder.routed_experts(p, rms(p["ln_after"], h, eps), cfg)
     rows["stats"] = jnp.concatenate(

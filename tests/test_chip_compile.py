@@ -14,8 +14,11 @@ such a file loads the TPU's library. Keep every such test in this file or in
 three of the five sparse families' stage programs: under `--dist loadfile` a
 file is one worker's, and the two are the run's longest.
 """
+import base64
 import dataclasses
+import hashlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -190,6 +193,55 @@ def _grouped_kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def program_digest(lowered) -> str:
+    """sha256 of a lowered program's text with no source location in it:
+    `as_text()` prints none of the program's own, and each Mosaic kernel's
+    serialized body (which carries its call stack's files and lines, so a
+    moved call site is another text and another key in the compile cache:
+    PERF.md section 7, row 35) is decoded and printed without them."""
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        context = jax_mlir.make_ir_context()
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    text = re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body,
+                  lowered.as_text())
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+# what the programs that PR 46 must not move lowered to on its parent
+# (902c6f1) for the described v5e: the five other sparse families' step
+# programs at their cells' shapes and keye's and MiniCPM-SALA's widest span
+# programs with the masked-attention kernel in them. A PR that means to
+# change one of them replaces its line; one that does not has moved it
+HELD = {
+    "keye-step":
+        "b89d35925b38a386b1e753689b1801900fc518253b24aa5bb8823183a278903b",
+    "kimi-step":
+        "cb8b5ae52365ef44bb9372bb6afb52ba9edfdb066e98993a0f1fa5aba71b07c4",
+    "lfm2-step":
+        "2e7edcaae8c5fa5cba6b8111b9e7d328812b828bcbb3971dc36c2c337423df12",
+    "laguna-step":
+        "2efceb8d2ac9c77c1e195dbc0e0ba577f9f40c2463b6465e2e135bd0ed2ed155",
+    "minicpm-sala-step":
+        "d598b02c91be9c5def6972e328f1dd3e9b5d62fc5bbeb7d218bf2f221d574f7e",
+    "keye-span-kernel":
+        "8aa2a53a9b5635cc54a8bf8c4bc419a067b8dfcb95612cf1c934197ed94695f5",
+    "minicpm-sala-span-kernel":
+        "80915726e31fa0558f122b77a5c7351dde7765fe848b941f1084ea210050cbc9",
+}
+
+
+def held(name: str, lowered) -> None:
+    digest = program_digest(lowered)
+    assert digest == HELD[name], f"{name} lowers to {digest}"
+
+
 VIT_LARGE = "google/vit-large-patch16-224"
 
 
@@ -241,9 +293,12 @@ def test_keye_stage_program_compiles_for_v5e(span, last_only, on_chip):
     params, cache = jax.tree_util.tree_map(
         lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
     _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
-    compiled = step.lower(params, on_chip((8, span), jnp.int32), cache,
-                          on_chip((), jnp.int32), read_len=16384,
-                          last_only=last_only).compile()
+    lowered = step.lower(params, on_chip((8, span), jnp.int32), cache,
+                         on_chip((), jnp.int32), read_len=16384,
+                         last_only=last_only)
+    if span == 1:
+        held("keye-step", lowered)
+    compiled = lowered.compile()
     assert (_grouped_kernels(compiled) > 0) == (span == 1)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes > 1.7e9       # the cache, in place
@@ -276,9 +331,12 @@ def test_kimi_stage_program_compiles_for_v5e(span, last_only, rows, on_chip):
     params, cache = jax.tree_util.tree_map(
         lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
     _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
-    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
-                          on_chip((), jnp.int32), read_len=4096,
-                          last_only=last_only).compile()
+    lowered = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                         on_chip((), jnp.int32), read_len=4096,
+                         last_only=last_only)
+    if (rows, span) == (32, 1):
+        held("kimi-step", lowered)
+    compiled = lowered.compile()
     assert (_grouped_kernels(compiled) > 0) == (span == 1)
     memory = compiled.memory_analysis()
     print(f"kimi {rows} rows, span {span}: arguments "
@@ -468,9 +526,12 @@ def test_minicpm_sala_stage_program_compiles_for_v5e(span, last_only,
     params, cache = jax.tree_util.tree_map(
         lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
     _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
-    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
-                          on_chip((), jnp.int32), read_len=max_len,
-                          last_only=last_only).compile()
+    lowered = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                         on_chip((), jnp.int32), read_len=max_len,
+                         last_only=last_only)
+    if span == 1:
+        held("minicpm-sala-step", lowered)
+    compiled = lowered.compile()
     memory = compiled.memory_analysis()
     print(f"minicpm-sala {rows} rows, span {span}: arguments "
           f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "

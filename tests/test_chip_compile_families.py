@@ -13,7 +13,7 @@ import pytest
 
 from pipeedge_tpu.models import ShardConfig, registry, stage_cache
 from test_chip_compile import (  # noqa: F401 — fixtures, found by name
-    _grouped_kernels, mosaic_for_the_described_chip, on_chip, topo)
+    _grouped_kernels, held, mosaic_for_the_described_chip, on_chip, topo)
 
 QWEN3_NEXT_CELL = "Qwen/Qwen3-Next-80B-A3B-Instruct@4,e0+256,v75968"
 
@@ -88,9 +88,12 @@ def test_lfm2_stage_program_compiles_for_v5e(span, last_only, on_chip):
     params, cache = jax.tree_util.tree_map(
         lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
     _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
-    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
-                          on_chip((), jnp.int32), read_len=max_len,
-                          last_only=last_only).compile()
+    lowered = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                         on_chip((), jnp.int32), read_len=max_len,
+                         last_only=last_only)
+    if span == 1:
+        held("lfm2-step", lowered)
+    compiled = lowered.compile()
     assert (_grouped_kernels(compiled) > 0) == (span == 1)
     memory = compiled.memory_analysis()
     print(f"lfm2 {rows} rows, span {span}: arguments "
@@ -135,9 +138,12 @@ def test_laguna_stage_program_compiles_for_v5e(span, last_only, on_chip):
     params, cache = jax.tree_util.tree_map(
         lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
     _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
-    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
-                          on_chip((), jnp.int32), read_len=max_len,
-                          last_only=last_only).compile()
+    lowered = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                         on_chip((), jnp.int32), read_len=max_len,
+                         last_only=last_only)
+    if span == 1:
+        held("laguna-step", lowered)
+    compiled = lowered.compile()
     assert (_grouped_kernels(compiled) > 0) == (span == 1)
     memory = compiled.memory_analysis()
     print(f"laguna {rows} rows, span {span}: arguments "
@@ -155,18 +161,22 @@ KEYE_CELL = "Kwai-Keye/Keye-VL-2.0-30B-A3B@6"
 MINICPM_SALA_CELL = "openbmb/MiniCPM-SALA@4"
 
 
-@pytest.mark.parametrize("cell, rows, span, max_len, resident", [
-    (KEYE_CELL, 8, 512, 16384, 12.3e9), (MINICPM_SALA_CELL, 2, 1024, 65536,
-                                         4.0e9)],
-    ids=["keye", "minicpm-sala"])
+@pytest.mark.parametrize("cell, rows, span, max_len, resident, hold", [
+    (KEYE_CELL, 8, 512, 16384, 12.3e9, "keye-span-kernel"),
+    (MINICPM_SALA_CELL, 2, 1024, 65536, 4.0e9, "minicpm-sala-span-kernel"),
+    (QWEN3_NEXT_CELL, 8, 1024, 32768, 8.6e9, None)],
+    ids=["keye", "minicpm-sala", "qwen3-next"])
 def test_widest_span_program_with_the_masked_attention_kernel_compiles_for_v5e(
-        cell, rows, span, max_len, resident, on_chip, monkeypatch):
+        cell, rows, span, max_len, resident, hold, on_chip, monkeypatch):
     """The widest span program of `keye-vl2.long-batch` (8 rows, spans of
-    512, the 16,384 bucket) and of `minicpm-sala.longctx-batch` (2 rows,
-    spans of 1,024, the 65,536 bucket) with the streaming kernel in them
+    512, the 16,384 bucket), of `minicpm-sala.longctx-batch` (2 rows,
+    spans of 1,024, the 65,536 bucket) and of `qwen3-next.longdoc-batch` (8
+    rows, spans of 1,024, the 32,768 bucket: heads of 256, the span's
+    queries whole, a tile of 1,024 rows) with the streaming kernel in them
     (`decoder.attend_masked` takes it on a backend that runs Mosaic, which
     the default backend here is not): a VMEM or lowering refusal shows here,
-    before chip time. Argument and temporary bytes printed."""
+    before chip time. Argument and temporary bytes printed. `hold`: the
+    line of `test_chip_compile.HELD` the lowered text is held to."""
     from pipeedge_tpu.models import decoder
     from pipeedge_tpu.models.shard import kind_runs
     from pipeedge_tpu.parallel import decode
@@ -183,9 +193,12 @@ def test_widest_span_program_with_the_masked_attention_kernel_compiles_for_v5e(
     params, cache = jax.tree_util.tree_map(
         lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
     _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
-    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
-                          on_chip((), jnp.int32), read_len=max_len,
-                          last_only=True).compile()
+    lowered = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                         on_chip((), jnp.int32), read_len=max_len,
+                         last_only=True)
+    if hold:        # the two callers PR 46 must not move
+        held(hold, lowered)
+    compiled = lowered.compile()
     text = compiled.as_text()
     assert "masked_attention" in text and "tpu_custom_call" in text
     memory = compiled.memory_analysis()

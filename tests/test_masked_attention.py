@@ -19,23 +19,24 @@ from pipeedge_tpu.ops import masked_attention
 
 
 def _case(rows, n_q, heads, keys, *, block=0, dead=(), blind=None, seed=0,
-          scale=1.0):
-    """Queries [B, Q, r, 128], parts of `keys` keys and their masks: a key
-    is kept with probability 0.3 (`block`: a block of that many at a time),
+          scale=1.0, lanes=128, share=0.3):
+    """Queries [B, Q, r, `lanes`], parts of `keys` keys and their masks: a
+    key is kept with probability `share` (`block`: a block of that many at a
+    time; 1.0: qwen3_next's mask, causal and nothing else),
     never inside `dead` (part, from, to), never in part `blind[0]` for the
     queries of row 0 from `blind[1]` on; the LAST part is causal from its
     start (a span's own rows), so every query keeps a key."""
     rng = np.random.default_rng(seed)
-    q = rng.normal(size=(rows, n_q, heads, 128)) * scale
-    ks = [rng.normal(size=(rows, n, 128)) for n in keys]
-    vs = [rng.normal(size=(rows, n, 128)) for n in keys]
+    q = rng.normal(size=(rows, n_q, heads, lanes)) * scale
+    ks = [rng.normal(size=(rows, n, lanes)) for n in keys]
+    vs = [rng.normal(size=(rows, n, lanes)) for n in keys]
     keeps = []
     for n in keys:
         if block:
-            keep = np.repeat(rng.random((rows, n_q, n // block)) < 0.3,
+            keep = np.repeat(rng.random((rows, n_q, n // block)) < share,
                              block, axis=-1)
         else:
-            keep = rng.random((rows, n_q, n)) < 0.3
+            keep = rng.random((rows, n_q, n)) < share
         keeps.append(keep)
     for part, lo, hi in dead:
         keeps[part][:, :, lo:hi] = False
@@ -81,6 +82,18 @@ CASES = {
     # within a part and from one part to the next
     "maxima_far_apart": dict(rows=1, n_q=32, heads=8, keys=[1024, 128],
                              scale=6.0, seed=3),
+    # qwen3_next's gated layer: a KV group of 8 heads of 256 lanes, a tile of
+    # 128 queries (1,024 rows, the cell's), the mask causal and nothing
+    # else: a window live to a position that is no whole key block, a first
+    # span's own rows alone, a window whose end is two dead blocks
+    "heads_of_256_window_live_to_no_whole_block": dict(
+        rows=1, n_q=128, heads=8, keys=[1024, 128], lanes=256, share=1.0,
+        dead=[(0, 700, 1024)]),
+    "heads_of_256_own_rows_only": dict(rows=2, n_q=128, heads=8, keys=[128],
+                                       lanes=256, share=1.0),
+    "heads_of_256_dead_end_of_whole_blocks": dict(
+        rows=1, n_q=64, heads=8, keys=[2048, 128], lanes=256, share=1.0,
+        dead=[(0, 1024, 2048)]),
 }
 
 

@@ -398,7 +398,7 @@ def test_the_gate_multiplies_the_heads_outputs():
          "attn_out": {"w": mat(d, heads * hd)}}
     x = mat(1, 6, d)
     stack = {name: jnp.zeros((1, 1, 8, groups * hd)) for name in ("k", "v")}
-    got, _, _ = qwen3_next.gated_attention(
+    got, _, _, fused = qwen3_next.gated_attention(
         jax.tree_util.tree_map(jnp.asarray, p), jnp.asarray(x),
         stage_cache.LayerCache(stack, 0), 0, cfg, prefill=True)
 
@@ -422,6 +422,147 @@ def test_the_gate_multiplies_the_heads_outputs():
     gate = 1 / (1 + np.exp(-(x[0] @ p["gate"]["w"].T)))
     wanted = (out.reshape(6, -1) * gate) @ p["attn_out"]["w"].T
     np.testing.assert_allclose(got[0], wanted, atol=2e-5)
+    assert fused == 0       # the CPU keeps the einsums
+
+
+# a span of the gated layer at the cell's head (256 lanes, 2 KV groups; 2
+# query heads a group here): (rows, span, window, pos)
+SPANS = {
+    # the live length is no multiple of the kernel's key block of 512
+    "window_live_to_no_whole_block": (2, 128, 1024, 700),
+    # a prompt's first span: its own rows alone, causal
+    "own_rows_only": (2, 128, 0, 0),
+    # the ladder's width past the live length: two dead blocks at the end
+    "window_with_a_dead_end_of_whole_blocks": (1, 256, 2048, 1024),
+}
+
+
+def _span(rows, span, width, pos, seed=4, heads=4, groups=2, hd=256):
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    q = draw(rows, span, heads, hd)
+    parts = [(tuple(draw(rows, span, hd) for _ in range(groups)),
+              tuple(draw(rows, span, hd) for _ in range(groups)), True)]
+    if width:
+        parts.insert(0, (tuple(draw(rows, width, hd) for _ in range(groups)),
+                         tuple(draw(rows, width, hd) for _ in range(groups)),
+                         False))
+    return q, parts, pos
+
+
+@pytest.mark.parametrize("name", list(SPANS))
+def test_a_span_of_the_gated_layer_takes_the_kernel_whole(name, monkeypatch):
+    """`attend` over heads of 256 under the causal mask: the einsums in
+    chunks of queries (`decoder.SCORE_BYTES`), the streaming kernel (in
+    interpret mode here) in one call a KV group over the whole span, the
+    same context to 1e-6 of its range."""
+    q, parts, pos = _span(*SPANS[name])
+    rows, span = q.shape[:2]
+    keys = sum(part[0][0].shape[1] for part in parts)
+    # the einsums walk the span in four chunks, each from its own offset
+    monkeypatch.setattr(decoder, "SCORE_BYTES",
+                        span // 4 * rows * 2 * keys * 4)
+    want, took = qwen3_next.attend(q, parts, pos)
+    assert took == 0
+    calls = []
+    kernel = decoder.masked_attention.attend
+    monkeypatch.setattr(decoder.masked_attention, "attend",
+                        lambda q, *a, **kw: calls.append(q.shape)
+                        or kernel(q, *a, **kw))
+    monkeypatch.setattr(decoder, "_fused_mode", lambda: "interpret")
+    got, took = qwen3_next.attend(q, parts, pos)
+    assert took == 1 and got.shape == want.shape
+    assert calls == [(rows, 2, span, 256)] * 2      # one call a KV group
+    assert np.isfinite(np.asarray(got)).all()
+    gap = float(jnp.max(jnp.abs(got - want)))
+    assert gap <= 1e-6 * float(jnp.max(want) - jnp.min(want))
+
+
+def test_a_span_that_fills_no_tile_keeps_its_chunks(monkeypatch):
+    """Where the seam says einsums (own rows that are no whole key block
+    here), `attend` chunks by `decoder.SCORE_BYTES` as it did, on a backend
+    that runs Mosaic too."""
+    q, parts, pos = _span(1, 96, 0, 0)
+    want, _ = qwen3_next.attend(q, parts, pos)
+    monkeypatch.setattr(decoder, "_fused_mode", lambda: "interpret")
+    monkeypatch.setattr(decoder, "SCORE_BYTES", 32 * 2 * 96 * 4)
+    text = str(jax.make_jaxpr(lambda q: qwen3_next.attend(q, parts, pos)[0])(
+        q))
+    assert "pallas_call" not in text and "scan" in text
+    got, took = qwen3_next.attend(q, parts, pos)
+    assert took == 0
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+WIDE = "pipeedge/test-wide-qwen3-next"
+
+
+@pytest.fixture
+def wide(monkeypatch):
+    """A period of four blocks whose full layer has the kernel's shapes:
+    heads of 128 lanes, spans of 128 positions (256 rows a KV group)."""
+    monkeypatch.setitem(registry._MODELS, WIDE, registry._qwen3_next(
+        WIDE, "test-wide-qwen3-next.npz", 32, 4, 4, 2, 128, (2, 4, 8, 8, 4),
+        vocab=100, max_pos=512, experts=8, expert_width=16, per_tok=2,
+        span=128))
+    return WIDE
+
+
+def test_fused_calls_count_spans_by_full_layers_through_the_pipeline(
+        wide, monkeypatch):
+    """`pipeedge_attend_fused_calls_total{phase}` through `generate`: a
+    prompt of three whole spans over one full layer takes the kernel three
+    times (the first span too: its own rows alone), a fourth span of 40
+    rows and every step keep the einsums; the tokens are the einsums'."""
+    ids = np.random.default_rng(9).integers(0, 100, size=(2, 3 * 128 + 40))
+    pipe = decode.build_decode_pipeline(wide, None, max_len=512)
+    want = np.asarray(pipe.generate(ids, 4))
+    monkeypatch.setattr(decoder, "_fused_mode", lambda: "interpret")
+    pipe = decode.build_decode_pipeline(wide, None, max_len=512)
+    before = _counters()
+    got = np.asarray(pipe.generate(ids, 4))
+    gained = {key: value - before[key] for key, value in _counters().items()}
+    assert gained["attend_fused_calls", "prefill"] == 3 * 1
+    assert gained["attend_fused_calls", "decode"] == 0
+    assert gained["moe_layer_calls", "prefill"] == 4 * 4
+    np.testing.assert_array_equal(got, want)
+
+
+def test_a_step_traces_to_the_parents_equations_but_for_the_stats_leaf():
+    """The decode step of the tiny model on the seam (`decoder.softmax_over`
+    for the layer's own copy of the einsums) is the program it was before
+    PR 46: every primitive as often as then (2,210 equations), but that a KV
+    group's queries are sliced once and not once a key part (two full
+    layers x two groups: 4 `slice` and 4 `squeeze` fewer), over a `stats`
+    leaf one count wider; no kernel in it."""
+    from test_lfm2 import _equations
+    pipe = decode.build_decode_pipeline(TINY, None, max_len=32)
+    entry = registry.get_model_entry(TINY)
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    run = decode._make_stage_run(entry.family.FAMILY, entry.config, stage)
+    cache = pipe._fresh_caches(2)[0]
+    assert cache["stats"].shape == (8, len(qwen3_next.STATS), 2)
+    assert qwen3_next.STATS[-1:] == decoder.ATTEND_STATS
+    jaxpr = jax.make_jaxpr(
+        lambda p, d, c, pos: run(p, d, c, pos, prefill=False, read_len=32))(
+            pipe.stages[0]["params"],
+            jax.ShapeDtypeStruct((2, 1), jnp.int32), cache,
+            jax.ShapeDtypeStruct((), jnp.int32))
+    names = _equations(jaxpr.jaxpr, collections.Counter())
+    assert "pallas_call" not in names
+    assert (names["slice"], names["squeeze"]) == (102 - 4, 104 - 4)
+    assert sum(names.values()) - names["slice"] - names["squeeze"] == 2004
+    assert {name: names[name] for name in (
+        "dot_general", "exp", "reduce_max", "reduce_sum", "select_n",
+        "concatenate", "div", "max", "dynamic_slice",
+        "dynamic_update_slice", "scan", "while")} == {
+            "dot_general": 67, "exp": 16, "reduce_max": 16, "reduce_sum": 47,
+            "select_n": 159, "concatenate": 42, "div": 47, "max": 16,
+            "dynamic_slice": 52, "dynamic_update_slice": 6, "scan": 12,
+            "while": 8}
 
 
 # -- the expert layer ----------------------------------------------------------
@@ -531,13 +672,13 @@ def test_a_fresh_cache_holds_each_kinds_leaves_for_its_layers_only(size):
                         ("full", 1))
         shapes = {"k": (2, 2, 32, 32), "v": (2, 2, 32, 32),
                   "gdn_state": (6, 2, 4, 8, 8), "gdn_conv": (6, 2, 3, 64),
-                  "stats": (8, 8, 2)}
+                  "stats": (8, 9, 2)}
     else:       # the cell: one period, 8 rows, 32,768 positions
         runs, cache = _fresh_cache(CELL, 8, 32768)
         assert runs == (("linear", 3), ("full", 1))
         shapes = {"k": (1, 8, 32768, 512), "v": (1, 8, 32768, 512),
                   "gdn_state": (3, 8, 32, 128, 128),
-                  "gdn_conv": (3, 8, 3, 8192), "stats": (4, 8, 2)}
+                  "gdn_conv": (3, 8, 3, 8192), "stats": (4, 9, 2)}
     assert {name: leaf.shape for name, leaf in cache.items()} == shapes
     held = sum(leaf.size * leaf.dtype.itemsize
                for name, leaf in cache.items() if name != "stats")
@@ -619,6 +760,9 @@ def test_counters_of_one_batch_are_what_its_sizes_predict(tiny):
     assert gained["gdn_state_carries", "decode"] == 7 * 6
     assert gained["moe_layer_calls", "prefill"] == 3 * 8
     assert gained["moe_layer_calls", "decode"] == 7 * 8
+    # the CPU keeps the einsums (`decoder.attend_masked`)
+    assert gained["attend_fused_calls", "prefill"] == 0
+    assert gained["attend_fused_calls", "decode"] == 0
     # 2 of 8 a token, 4 of 8 held: about one held assignment a token a layer
     assert 0 < gained["moe_assignments", "prefill"] <= 2 * 21 * 8 * 2
 
