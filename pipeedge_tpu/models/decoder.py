@@ -1,5 +1,5 @@
 """The kit the sparse decoder families share (keye, kimi, qwen3_next, lfm2,
-laguna, minicpm_sala): what a family is NOT is here, so that its module
+laguna, minicpm_sala, nemotron_h): what a family is NOT is here, so that its module
 holds its mixers, its cache's leaves, its kinds of block and its key map,
 and imports no other family.
 
@@ -7,6 +7,7 @@ and imports no other family.
   the result is wide (`in_row_chunks`); the dense SwiGLU (`dense_ffn`) and
   the routed expert layer (`routed_experts`); either, as a cached block
   step's FFN, with the counts every family's `STATS` start with (`ffn`);
+- a decay's `exp` to an ulp, for a state that compounds it (`exp_ulp`);
 - queries in chunks whose scores stay under `SCORE_BYTES` (`query_chunk`,
   `map_query_chunks`), one softmax over masked key parts (`attend_masked`);
 - the hooks of a family that embeds tokens alone and runs through the
@@ -27,7 +28,7 @@ import numpy as np
 
 from . import ShardConfig
 from ..ops import masked_attention
-from ..parallel.expert import topk_ffn_delta
+from ..parallel.expert import ACTS, topk_ffn_delta
 from .layers import TransformerConfig, exact_dot
 from .shard import build_shard_params
 
@@ -61,11 +62,39 @@ def in_row_chunks(fn, x: jax.Array, widest: int) -> jax.Array:
     return out.reshape(b, s, -1)
 
 
-def dense_ffn(p: Dict, normed: jax.Array) -> jax.Array:
-    def swiglu(rows):
-        hidden = jax.nn.silu(lin(p["gate"], rows)) * lin(p["up"], rows)
+def dense_ffn(p: Dict, normed: jax.Array, act: str = "silu") -> jax.Array:
+    """A dense FFN of `normed` [B, S, D] in chunks of rows: a SwiGLU where
+    `p` has a gate matrix, else `down(act(up x))` (`expert.ACTS`)."""
+    def mlp(rows):
+        if "gate" in p:
+            hidden = jax.nn.silu(lin(p["gate"], rows)) * lin(p["up"], rows)
+        else:
+            hidden = ACTS[act](lin(p["up"], rows))
         return lin(p["down"], hidden)
-    return in_row_chunks(swiglu, normed, p["gate"].shape[0])
+    return in_row_chunks(mlp, normed, p["up"].shape[0])
+
+
+def exp_ulp(x: jax.Array) -> jax.Array:
+    """exp(x) for float32 x <= 0 to an ulp: x = n ln 2 + r (ln 2 in two
+    parts), a polynomial in r (Cephes `expf`'s), 2**n from its bits.
+
+    A decay is applied a position after another (a chunk after another), so
+    its error compounds over a head's memory, thousands of positions for the
+    slowest. The chip's own float32 `exp` is a few 1e-7 off WITH A BIAS: the
+    one-token form lay 1.7e-4 from a float64 recurrence after 512 positions
+    where the chunked form, which takes the exp of sums, lay 3e-6, and the
+    plain reference's scan over 32 k positions 1e-3 of the logits' range
+    from the program (my chip runs, PR 33). `1 + expm1(x)` repaired the
+    slowest heads only (the chip's `expm1` is `exp - 1` but for tiny x)."""
+    n = jnp.round(x * 1.44269504088896341)
+    r = (x - n * 0.693359375) - n * -2.12194440e-4
+    poly = 1.9875691500e-4
+    for c in (1.3981999507e-3, 8.3334519073e-3, 4.1665795894e-2,
+              1.6666665459e-1, 5.0000001201e-1):
+        poly = poly * r + c
+    two_n = jax.lax.bitcast_convert_type(
+        (jnp.maximum(n, -126.0).astype(jnp.int32) + 127) << 23, jnp.float32)
+    return jnp.where(x < -87.0, 0.0, (poly * r * r + r + 1.0) * two_n)
 
 
 def by_head(x: jax.Array, heads: int) -> tuple:
@@ -75,14 +104,16 @@ def by_head(x: jax.Array, heads: int) -> tuple:
 
 def routed_experts(p: Dict, normed, cfg: TransformerConfig):
     """The routed FFN's delta (with the shared expert's, where the block
-    has one) and counts: `p["experts"]` is the block's own leaves, or
+    hands one over; through the block's `latent`, where it has one) and
+    counts: `p["experts"]` is the block's own leaves, or
     `(stack, layer)` where the decode scan keeps the stacked blocks'
     experts whole (the decode driver's `_run_blocks`)."""
     experts, layer = p["experts"], None
     if isinstance(experts, tuple):
         experts, layer = experts
     return topk_ffn_delta(
-        dict({name: p[name] for name in ("router", "shared", "shared_gate")
+        dict({name: p[name] for name in ("router", "shared", "shared_gate",
+                                         "latent")
               if name in p}, experts=experts), normed, cfg, layer=layer)
 
 
@@ -316,20 +347,23 @@ def norm_ones(key: str, shape: tuple):
     return None
 
 
-def loader(assemble: Callable, undrawn: Callable = norm_ones) \
+def loader(assemble: Callable, undrawn: Callable = norm_ones,
+           vocabulary: tuple = ("model.embed_tokens.weight",
+                                "lm_head.weight")) \
         -> Tuple[Callable, Callable]:
     """(load_params, init_params) of a family from its `assemble(cfg,
     shard_config, get, dtype)`, where `get(key, shape)` is a tensor of the
     published scheme: read from a state-dict npz and checked against the
-    model's shape (a sliced vocabulary is the table's first rows), or drawn
-    from `seed`, but for the keys `undrawn(key, shape)` gives a value for.
-    One assembly, so the two trees agree leaf by leaf."""
+    model's shape (a sliced vocabulary is the first rows of the tables the
+    scheme names `vocabulary`), or drawn from `seed`, but for the keys
+    `undrawn(key, shape)` gives a value for. One assembly, so the two trees
+    agree leaf by leaf."""
     def load_params(cfg: TransformerConfig, shard_config: ShardConfig,
                     weights: Mapping, dtype=jnp.float32) -> Dict:
         """Shard params from a published-style state-dict npz."""
         def get(key, shape):
             value = np.asarray(weights[key])
-            if key in ("model.embed_tokens.weight", "lm_head.weight"):
+            if key in vocabulary:
                 value = value[:shape[0]]
             if value.shape != shape:
                 raise ValueError(f"{key}: {value.shape} in the file, {shape} "

@@ -23,7 +23,7 @@ class TransformerConfig:
     model_type: str              # the family: 'vit' | 'bert' | 'deit' |
     #                              'gpt2' | 'llama' | 'keye' | 'kimi' |
     #                              'qwen3_next' | 'lfm2' | 'laguna' |
-    #                              'minicpm_sala'
+    #                              'minicpm_sala' | 'nemotron_h'
     hidden_size: int
     num_hidden_layers: int       # transformer blocks (sublayers = 4x this)
     num_attention_heads: int
@@ -146,6 +146,23 @@ class TransformerConfig:
     dim_model_base: int = 0
     published_layers: int = 0
     sparse_attention: tuple = ()
+    # nemotron_h family ("mamba" | "attention" | "experts" in `layer_types`:
+    # a block is ONE sublayer, `h += mixer(norm(h))`). Mamba-2: `ssm_heads`
+    # heads of `ssm_head_dim`, a state of `ssm_state` a lane of a head,
+    # `ssm_groups` groups of heads that share B and C (`conv_kernel` and
+    # `linear_chunk` as above). The expert layer: the routed experts read
+    # and write a latent of `moe_latent_size` (0 = the model's width) and
+    # the shared expert is `shared_expert_width` wide (0 =
+    # `n_shared_experts` times the routed width); an expert without a gate
+    # matrix is `down(act(up x))` with `expert_act` ("relu2": the square of
+    # a ReLU), one with a gate matrix is a SwiGLU whatever this says
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 0
+    moe_latent_size: int = 0
+    shared_expert_width: int = 0
+    expert_act: str = "silu"
 
     @property
     def head_dim(self) -> int:

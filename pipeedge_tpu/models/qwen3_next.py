@@ -166,27 +166,8 @@ def partial_rotate(x: jax.Array, pos: jax.Array,
 
 # -- the gated delta rule ----------------------------------------------------
 
-def _exp(x: jax.Array) -> jax.Array:
-    """exp(x) for float32 x <= 0 to an ulp: x = n ln 2 + r (ln 2 in two
-    parts), a polynomial in r (Cephes `expf`'s), 2**n from its bits.
-
-    A decay is applied a position after another (a chunk after another), so
-    its error compounds over a head's memory, thousands of positions for the
-    slowest. The chip's own float32 `exp` is a few 1e-7 off WITH A BIAS: the
-    one-token form lay 1.7e-4 from a float64 recurrence after 512 positions
-    where the chunked form, which takes the exp of sums, lay 3e-6, and the
-    plain reference's scan over 32 k positions 1e-3 of the logits' range
-    from the program (my chip runs, PR 33). `1 + expm1(x)` repaired the
-    slowest heads only (the chip's `expm1` is `exp - 1` but for tiny x)."""
-    n = jnp.round(x * 1.44269504088896341)
-    r = (x - n * 0.693359375) - n * -2.12194440e-4
-    poly = 1.9875691500e-4
-    for c in (1.3981999507e-3, 8.3334519073e-3, 4.1665795894e-2,
-              1.6666665459e-1, 5.0000001201e-1):
-        poly = poly * r + c
-    two_n = jax.lax.bitcast_convert_type(
-        (jnp.maximum(n, -126.0).astype(jnp.int32) + 127) << 23, jnp.float32)
-    return jnp.where(x < -87.0, 0.0, (poly * r * r + r + 1.0) * two_n)
+# a decay's exp where it is applied a position (a chunk) after another
+_exp = decoder.exp_ulp
 
 
 def _decay(x: jax.Array) -> jax.Array:

@@ -32,6 +32,7 @@ from . import laguna as laguna_mod
 from . import lfm2 as lfm2_mod
 from . import llama as llama_mod
 from . import minicpm_sala as minicpm_sala_mod
+from . import nemotron_h as nemotron_h_mod
 from . import qwen3_next as qwen3_next_mod
 from . import vit as vit_mod
 
@@ -41,7 +42,8 @@ logger = logging.getLogger(__name__)
 @dataclasses.dataclass(frozen=True)
 class ModelEntry:
     name: str
-    layers: int                  # sublayer count = 4 * blocks
+    layers: int                  # sublayer count = 4 * blocks (a block of
+    #                              one sublayer, nemotron_h's, counts four too)
     weights_file: str            # default npz filename (reference format)
     family: object               # module: vit_mod | bert_mod | deit_mod
     config: TransformerConfig
@@ -192,14 +194,38 @@ def _minicpm_sala(name, weights, hidden, pattern, heads, kv_heads, head_dim,
         sparse_attention=tuple(sparse)))
 
 
+def _nemotron_h(name, weights, hidden, pattern, heads, kv_heads, head_dim,
+                ssm, vocab, max_pos, experts, expert_width, latent,
+                shared_width, per_tok, span):
+    ssm_heads, ssm_head_dim, state, groups, conv, chunk = ssm
+    blocks = len(pattern)
+    return ModelEntry(name, 4 * blocks, weights, nemotron_h_mod,
+                      TransformerConfig(
+        model_type="nemotron_h", hidden_size=hidden,
+        num_hidden_layers=blocks, num_attention_heads=heads,
+        num_kv_heads=kv_heads, attn_head_dim=head_dim, intermediate_size=0,
+        layer_norm_eps=1e-5, vocab_size=vocab,
+        max_position_embeddings=max_pos, layer_types=_layer_types(pattern),
+        ssm_heads=ssm_heads, ssm_head_dim=ssm_head_dim, ssm_state=state,
+        ssm_groups=groups, conv_kernel=conv, linear_chunk=chunk,
+        n_experts=experts, moe_intermediate_size=expert_width,
+        moe_latent_size=latent, shared_expert_width=shared_width,
+        num_experts_per_tok=per_tok, norm_topk_prob=True, router="sigmoid",
+        routed_scaling_factor=5.0, gate_sum_eps=1e-20, n_shared_experts=1,
+        expert_act="relu2", prefill_chunk=span))
+
+
 # a pattern of mixers, one letter a block. LFM2's: c a gated short
 # convolution, a grouped-query attention (no interval: the last attention
 # comes early). Laguna's: f attention over every position, s over a window.
-# MiniCPM-SALA's: m MiniCPM4's block-sparse attention, l lightning attention
+# MiniCPM-SALA's: m MiniCPM4's block-sparse attention, l lightning attention.
+# Nemotron-H's, the published `hybrid_override_pattern` as it is, a letter a
+# SUBLAYER: M a Mamba-2 mixer, * an attention, E an expert layer
 def _layer_types(pattern: str) -> tuple:
     return tuple({"c": "conv", "a": "full_attention", "f": "full_attention",
                   "s": "sliding_attention", "m": "minicpm4",
-                  "l": "lightning-attn"}[m] for m in pattern)
+                  "l": "lightning-attn", "M": "mamba", "*": "attention",
+                  "E": "experts"}[m] for m in pattern)
 
 
 _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
@@ -282,6 +308,20 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
                   vocab=73448, max_pos=524288,
                   sparse=(32, 16, 64, 64, 1, 2048, 8192), chunk=128,
                   span=1024),
+    # Nemotron-3-Super: 88 layers of ONE sublayer each, 40 Mamba-2 mixers
+    # (128 heads of 64, a state of 128 a lane, 8 groups of B and C), 8 plain
+    # attentions (32 query and 2 KV heads of 128, no rotation) and 40 expert
+    # layers (512 experts of 2,688 in a 1,024-wide latent, 22 a token by a
+    # sigmoid, beside a shared one of 5,376; relu squared, no gate matrix).
+    # One chip holds a quarter of each expert layer in the first of eight
+    # pipeline stages, one period: `...@11,e0+128,v32768`
+    _nemotron_h("nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16",
+                "NVIDIA-Nemotron-3-Super-120B-A12B-BF16.npz", 4096,
+                "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+                "EMEMEMEMEM*EMEMEMEM*EMEMEMEME", 32, 2, 128,
+                ssm=(128, 64, 128, 8, 4, 128), vocab=131072, max_pos=262144,
+                experts=512, expert_width=2688, latent=1024,
+                shared_width=5376, per_tok=22, span=64),
     # tiny synthetic models for fast tests / CI (not in the reference's list)
     _vit("pipeedge/test-tiny-vit", 8, "test-tiny-vit.npz", 32, 2, 4, 64, 5,
          patch=4, img=16),
@@ -326,6 +366,12 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
                   "test-tiny-minicpm-sala.npz", 32, "mlllml", 4, 2, 8,
                   dense_width=64, vocab=100, max_pos=128,
                   sparse=(4, 2, 8, 2, 1, 16, 32), chunk=4, span=8),
+    # eight blocks of one sublayer: a run of two Mamba-2 blocks among runs
+    # of one, three expert runs, the attention between them
+    _nemotron_h("pipeedge/test-tiny-nemotron-h", "test-tiny-nemotron-h.npz",
+                32, "MEMM*EME", 4, 2, 8, ssm=(4, 8, 8, 2, 4, 4), vocab=100,
+                max_pos=64, experts=8, expert_width=16, latent=16,
+                shared_width=24, per_tok=3, span=8),
     _gpt2("pipeedge/test-tiny-moe", 8, "test-tiny-moe.npz", 32, 2, 4, 64,
           vocab=100, max_pos=64, n_experts=4, capacity_factor=4.0),
 ]}
@@ -344,7 +390,11 @@ def get_model_entry(model_name: str) -> ModelEntry:
     stage-sized deployment runs), `e<first>+<count>` (of each expert
     layer's experts the `count` from `first`: the share of one of the chips
     a deployment divides a layer over; the router keeps its width) and
-    `v<rows>` (the first rows of the vocabulary, in embedding and head)."""
+    `v<rows>` (the first rows of the vocabulary, in embedding and head). A
+    cut's `layers` counts four sublayers a block, as `-pt` numbers them, also
+    where a block is one sublayer (the nemotron_h family, whose blocks are
+    the letters of its pattern): a partition names such a block by its four
+    numbers and the family takes it whole."""
     name, at, cut = model_name.partition("@")
     entry = _MODELS[name]
     if not at:

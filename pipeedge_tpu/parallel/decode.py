@@ -24,6 +24,7 @@ token-for-token (tests/test_decode.py).
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -368,6 +369,53 @@ def attend_bucket(pos_next: int, max_len: int, floor: int = 64,
     return min(width, 2 * base, max_len)
 
 
+# bytes of the rows the LONGEST run of blocks leaves for a `whole` leaf from
+# which every run that writes the leaf writes its rows into the stack at once,
+# at its own layers, and the later runs read the stack so written (they read
+# other layers: nothing changes for them). The choice is the leaf's, made once
+# before the runs (`_written_by_run`): a leaf is written a run at a time by
+# all of its runs or gathered from all of them, whatever their lengths.
+# Gathered until the end and put in the leaf's place, as smaller states are,
+# the runs' rows are a second copy of the leaf alive beside the first and a
+# concatenation's bytes a call: 2.76 GB of Mamba-2 state at 128 rows of
+# nemotron_h where the states of qwen3_next (17 MB a layer at 8 rows),
+# minicpm_sala and lfm2 are noise. A chain of updates of one donated buffer,
+# each after the last reader of the layers it writes, is what XLA updates in
+# place. Each update is fenced to its run's output (`optimization_barrier`):
+# left free, the chip's compiler put a Mamba-2 layer's update after the NEXT
+# run's grouped kernels, and the cell's eleven-run step program then computed
+# other hidden states from its third Mamba-2 layer on (0.26 of the logits'
+# range from the float32 reference; the same program with the tile loop for
+# the kernels, or with the state gathered, agreed to 6e-7; eight runs agreed
+# either way). Fenced it agrees (PERF.md, PR 47, and section 7). The fence is
+# a workaround, not a cure: `tests/test_chip_compile_families.py` holds the
+# scheduled step program to the order it gives. Two ways to update a leaf is
+# one too many: ROADMAP D1 asks for the one (every `whole` leaf a run at a
+# time), which changes the siblings' programs and so needs their cells
+# measured
+WHOLE_IN_PLACE_BYTES = 1 << 26
+
+
+def _n_blocks(run) -> int:
+    return jax.tree_util.tree_leaves(run)[0].shape[0]
+
+
+def _written_by_run(runs, kinds, cache: Cache, leaves) -> tuple:
+    """The `whole` leaves that `_run_blocks` writes a run at a time: those
+    whose longest run leaves `WHOLE_IN_PLACE_BYTES` of rows or more (a
+    layer's rows of a `whole` leaf are the layer)."""
+    owner = leaf_owners(leaves)
+
+    def longest(name):      # in blocks, of the runs that write the leaf
+        return max((_n_blocks(run) for run, kind in zip(runs, kinds)
+                    if kind in owner.get(name, (kind,))), default=0)
+
+    return tuple(
+        name for name in whole_names(leaves) if name in cache
+        and longest(name) * math.prod(cache[name].shape[1:])
+        * cache[name].dtype.itemsize >= WHOLE_IN_PLACE_BYTES)
+
+
 def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
                 prefill: bool, block_fn=_block_step,
                 whole: tuple = (), kinds: tuple = (),
@@ -390,11 +438,16 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
     the TPU compiler lays a carried buffer out to suit the rows written
     into it and copies the whole cache into and out of that layout around
     the loop (PERF.md, PR 25). Block leaves named in `whole` are not
-    scanned over either: the block step gets each as a `LayerSlice`."""
+    scanned over either: the block step gets each as a `LayerSlice`. A
+    `whole` leaf whose rows of its longest run are `WHOLE_IN_PLACE_BYTES` or
+    more is written a run at a time, by every run, and not gathered
+    (`_written_by_run`)."""
     runs = blocks.runs if isinstance(blocks, BlockRuns) else (blocks,)
+    kinds = kinds or (None,) * len(runs)
     owner = leaf_owners(leaves)
+    placed = _written_by_run(runs, kinds, cache, leaves)
     rows, done, of_kind = [], 0, {}
-    for run, kind in zip(runs, kinds or (None,) * len(runs)):
+    for run, kind in zip(runs, kinds):
         view, first = cache, done
         if owner:       # this kind's leaves, at their owners' layers
             view = {name: buf for name, buf in cache.items()
@@ -416,8 +469,18 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
             y, bc = block_fn(bp, y, LayerCache(view, at), pos, cfg, prefill)
             return y, bc.rows
 
-        n_blocks = jax.tree_util.tree_leaves(run)[0].shape[0]
+        n_blocks = _n_blocks(run)
         x, new = jax.lax.scan(body, x, (run, jnp.arange(n_blocks)))
+        for name in placed:
+            if name not in new:
+                continue
+            new = dict(new)
+            # written at the run's layers, and done before the next run
+            x, written = jax.lax.optimization_barrier((
+                x, jax.lax.dynamic_update_slice(
+                    cache[name], new.pop(name).astype(cache[name].dtype),
+                    (first,) + (0,) * (cache[name].ndim - 1))))
+            cache = dict(cache, **{name: written})
         rows.append(new)
         done += n_blocks
         of_kind[kind] = of_kind.get(kind, 0) + n_blocks
@@ -426,9 +489,12 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
     rows = rows[0] if len(rows) == 1 else {
         name: jnp.concatenate(parts) for name in cache
         if (parts := [new[name] for new in rows if name in new])}
-    return x, write_rows(cache, rows, 0 if prefill else pos,
-                         whole=whole_names(leaves), rings=ring_names(leaves),
-                         strides=stride_names(leaves))
+    written = write_rows(
+        {name: buf for name, buf in cache.items() if name not in placed}
+        if placed else cache, rows, 0 if prefill else pos,
+        whole=whole_names(leaves), rings=ring_names(leaves),
+        strides=stride_names(leaves))
+    return x, dict(written, **{name: cache[name] for name in placed})
 
 
 # every stage program takes (params, data, cache[, pos]) and donates the

@@ -255,7 +255,7 @@ def shard_moe_params(params: Dict, mesh: Mesh, axis: str = "ep") -> Dict:
 
 # rows of one tile of the grouped product, at most: the assignments are
 # sorted by expert, each expert's group is covered by whole tiles, and one
-# loop step multiplies one tile by its expert's three matrices. How many
+# loop step multiplies one tile by its expert's matrices. How many
 # rows a call's tiles have is `expert_tile`'s to say; this is its cap (256
 # rows of a 2,048 x 768 expert are as many FLOPs in one bfloat16 pass as its
 # weights are bytes on a v5e). What `expert_tile` returns also says which
@@ -391,17 +391,45 @@ def _swiglu(x: jax.Array, gate_w, up_w, down_w) -> jax.Array:
     return product(hidden, down_w)
 
 
+# an expert that has no gate matrix is `down(act(up x))`, `act` the
+# configuration's `expert_act`
+ACTS = {"silu": jax.nn.silu, "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+
+
+def expert_names(ex: Dict) -> tuple:
+    """An expert's matrices in the order it multiplies them: a SwiGLU's
+    three, or the two of an expert without a gate matrix."""
+    return ("gate", "up", "down") if "gate" in ex else ("up", "down")
+
+
+def _expert_ffn(x: jax.Array, w: Dict, act: str) -> jax.Array:
+    """One expert's FFN of x [rows, D] over its `nn.Linear` matrices `w`
+    [out, in] as stored -> float32 [rows, D']: `_swiglu` where it has a
+    gate matrix, else `down(act(up x))`."""
+    if "gate" in w:
+        return _swiglu(x, w["gate"], w["up"], w["down"])
+    hidden = ACTS[act](exact_dot(x, w["up"], w_contract=1)).astype(x.dtype)
+    return exact_dot(hidden, w["down"], w_contract=1)
+
+
 def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
                    held=None, layer=None):
-    """Routed SwiGLU FFN delta of `normed` [B, S, D], and its counts.
+    """Routed FFN delta of `normed` [B, S, D], and its counts.
 
     `params`: `router` {w [D, E][, bias]}, `experts` {gate, up [.., F, D],
-    down [.., D, F]} (`nn.Linear` layout, no bias), the expert axis holding
-    the `held` experts only, and where the model has one `shared` {gate, up
-    [Fs, D], down [D, Fs]}, the expert every token goes through, which is
+    down [.., D, F]} (`nn.Linear` layout, no bias; a SwiGLU), or {up, down}
+    alone (`down(act(up x))`, `act` = `cfg.expert_act`: what form an expert
+    has is read from its leaves), the expert axis holding
+    the `held` experts only, and where the model has one `shared` {[gate,]
+    up [Fs, D], down [D, Fs]}, the expert every token goes through, which is
     added here (every chip of a deployment computes it alike: when shares
     are added up it counts once), times `sigmoid(shared_gate . token)`
-    where the model has a `shared_gate` [1, D] beside it. `held` = (first, count): the experts this
+    where the model has a `shared_gate` [1, D] beside it. Where the model
+    has a `latent` {down [Dl, D], up [D, Dl]} the router still reads the
+    token and the routed experts read and write its latent, `latent.down`
+    of it, Dl wide (their matrices are [F, Dl] and [Dl, F]); their weighted
+    sum is taken back up by `latent.up`, which is linear, so shares of the
+    experts still add up. `held` = (first, count): the experts this
     caller computes, `first` possibly traced (an 'ep' device's slab); None
     = `cfg.held_experts`, or all. Assignments to other experts cost a sort
     key and nothing more, and add nothing here. `layer`, when given,
@@ -431,6 +459,10 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     t, k = tokens.shape[0], cfg.num_experts_per_tok
     first, count = held or cfg.held_experts or (0, cfg.n_experts)
     experts, gates = topk_route(params["router"], tokens, cfg)
+    rows_in, latent = tokens, params.get("latent")
+    if latent:      # what the experts read: the token's latent
+        rows_in = exact_dot(tokens, latent["down"],
+                            w_contract=1).astype(tokens.dtype)
     local = experts.reshape(-1) - first                     # [A]
     mine = (local >= 0) & (local < count)
     local = jnp.where(mine, local, count)                   # others sort last
@@ -449,16 +481,19 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     # each token's assignments: where they sorted to, and their gates
     sorted_at = jnp.argsort(order).reshape(t, k)
     gates = jnp.where(mine, gates.reshape(-1), 0.0).reshape(t, k)
-    ways = (tokens, params["experts"], layer, order, bounds, sorted_at,
+    ways = (rows_in, params["experts"], layer, order, bounds, sorted_at,
             gates)
     if mode:
         delta, rows = _grouped(*ways, local.reshape(t, k),
-                               *grouped_layout(tile), mode == "interpret")
+                               *grouped_layout(tile), mode == "interpret",
+                               cfg.expert_act)
     else:
-        delta, rows = _tile_loop(*ways, tile, cfg.n_experts)
+        delta, rows = _tile_loop(*ways, tile, cfg.n_experts, cfg.expert_act)
+    if latent:
+        delta = exact_dot(delta.astype(tokens.dtype), latent["up"],
+                          w_contract=1)
     if "shared" in params:
-        shared = _swiglu(tokens, *(params["shared"][name]
-                                   for name in ("gate", "up", "down")))
+        shared = _expert_ffn(tokens, params["shared"], cfg.expert_act)
         if "shared_gate" in params:
             shared = shared * jax.nn.sigmoid(exact_dot(
                 tokens, params["shared_gate"], w_contract=1))
@@ -485,11 +520,14 @@ def _back_to_tokens(delta, out, sorted_at, gates, base, end):
 
 
 def _grouped(tokens, ex, layer, order, bounds, sorted_at, gates, local,
-             row_tile: int, aligned: bool, interpret: bool):
+             row_tile: int, aligned: bool, interpret: bool,
+             act: str = "silu"):
     """The sorted groups times their experts in two grouped kernels
-    (`ops/grouped_matmul.py`): `silu(gate x) * up x`, then `down`; the
-    arithmetic is `_swiglu`'s over `exact_dot`. -> (delta [T, D] float32,
-    rows the kernels multiplied).
+    (`ops/grouped_matmul.py`): `silu(gate x) * up x` (`act(up x)` of
+    experts without a gate matrix: one stack through the first kernel, the
+    activation between the two), then `down`; the arithmetic is
+    `_expert_ffn`'s over `exact_dot`. -> (delta [T, D] float32, rows the
+    kernels multiplied).
 
     The stack is handed over as it lies, its leading axes flattened (a free
     reshape), and group g is its matrix `layer * experts + g`: a layer's
@@ -500,7 +538,7 @@ def _grouped(tokens, ex, layer, order, bounds, sorted_at, gates, local,
     (t, d), k = tokens.shape, sorted_at.shape[1]
     count = bounds.shape[0] - 1
     stacks = {name: w.reshape((-1,) + w.shape[-2:]) for name, w in ex.items()}
-    first_group = 0 if layer is None else layer * ex["gate"].shape[-3]
+    first_group = 0 if layer is None else layer * ex["up"].shape[-3]
     n_assign = t * k
     sizes = bounds[1:] - bounds[:-1]
     sorted_tokens = order // k
@@ -531,7 +569,7 @@ def _grouped(tokens, ex, layer, order, bounds, sorted_at, gates, local,
     rows = jnp.take(tokens, token_of_row, axis=0)
 
     # `exact_dot`'s three cases, the parts of a row tile next to each other
-    w_dtype = ex["gate"].dtype
+    w_dtype = ex["up"].dtype
     precision = None
     if tokens.dtype == w_dtype == jnp.float32:
         precision = jax.lax.Precision.HIGHEST
@@ -550,7 +588,10 @@ def _grouped(tokens, ex, layer, order, bounds, sorted_at, gates, local,
     call = partial(gm.grouped_matmul, items=items, row_tile=row_tile,
                    precision=precision, interpret=interpret)
     parts, x = by_tiles(rows)
-    hidden = call(x, (stacks["gate"], stacks["up"]), parts=parts)
+    if "gate" in ex:
+        hidden = call(x, (stacks["gate"], stacks["up"]), parts=parts)
+    else:
+        hidden = ACTS[act](call(x, (stacks["up"],), parts=parts))
     parts, x = by_tiles(hidden.astype(tokens.dtype))
     out = call(x, (stacks["down"],), parts=parts)
     # other chips' assignments lie nowhere in the layout
@@ -562,7 +603,7 @@ def _grouped(tokens, ex, layer, order, bounds, sorted_at, gates, local,
 
 
 def _tile_loop(tokens, ex, layer, order, bounds, sorted_at, gates,
-               tile: int, n_experts: int):
+               tile: int, n_experts: int, act: str = "silu"):
     """The sorted groups times their experts a tile of `tile` rows a loop
     step. -> (delta [T, D] float32, rows the tiles multiplied)."""
     (t, d), k = tokens.shape, sorted_at.shape[1]
@@ -616,8 +657,8 @@ def _tile_loop(tokens, ex, layer, order, bounds, sorted_at, gates,
             at = jax.lax.dynamic_slice_in_dim(order, tile_start[i], tile)
             x = jnp.take(tokens, jnp.minimum(at // k, t - 1), axis=0)
             e = tile_expert[i]
-            y = _swiglu(x, *(matrix(name, e)
-                             for name in ("gate", "up", "down")))
+            y = _expert_ffn(x, {name: matrix(name, e)
+                                for name in expert_names(ex)}, act)
             return jax.lax.dynamic_update_slice_in_dim(
                 out, y, tile_start[i] - base, 0)
 

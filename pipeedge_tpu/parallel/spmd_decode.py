@@ -83,15 +83,15 @@ class SpmdDecodePipeline:
         if mesh.shape["stage"] != n_stages:
             raise ValueError(f"mesh 'stage' axis {mesh.shape['stage']} != "
                              f"{n_stages} pipeline stages")
-        if cfg.n_experts:
-            raise NotImplementedError(
-                "SPMD wave decode covers dense families; MoE decodes via "
-                "DecodePipeline(ep_mesh/tp_ep_mesh)")
         if getattr(family, "cache_leaves", None) is not None:
             raise NotImplementedError(
                 f"the {family.name} family names its own cache leaves (a "
                 "state a request, rows every few positions): the SPMD wave "
                 "decoder stacks the plain k, v pair over its stages")
+        if cfg.n_experts:
+            raise NotImplementedError(
+                "SPMD wave decode covers dense families; MoE decodes via "
+                "DecodePipeline(ep_mesh/tp_ep_mesh)")
         if edge_bits not in (0, 2, 4, 6, 8, 16):
             raise ValueError(f"edge_bits must be one of 0/2/4/6/8/16, got "
                              f"{edge_bits}")

@@ -209,3 +209,94 @@ def test_widest_span_program_with_the_masked_attention_kernel_compiles_for_v5e(
           f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
     assert memory.argument_size_in_bytes < resident
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+
+
+NEMOTRON_CELL = "nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16@11,e0+128,v32768"
+
+
+def _updates_and_kernels(text: str, leaf: str) -> str:
+    """The scheduled entry computation of a compiled program as a string of
+    `U` (an instruction that gives the buffer `leaf`, `f32[5,...]`, and is
+    or calls a `dynamic-update-slice`) and `K` (a Mosaic kernel's call), in
+    the order the chip runs them."""
+    import re
+    bodies = {match.group(1): match.group(2) for match in re.finditer(
+        r"^%?([\w.\-]+) [^\n]*\{\n(.*?)^\}", text, re.S | re.M)}
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M)
+    order = ""
+    for line in entry.group(1).splitlines():
+        _, _, made = line.partition(" = ")
+        if "tpu_custom_call" in made:
+            order += "K"
+        elif made.startswith(leaf) and "parameter(" not in made:
+            called = re.search(r"calls=%?([\w.\-]+)", made)
+            if "dynamic-update-slice" in made + bodies.get(
+                    called.group(1) if called else "", ""):
+                order += "U"
+    return order
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (64, True)])
+def test_nemotron_h_stage_program_compiles_for_v5e(span, last_only, on_chip):
+    """`nemotron3-super.reason-batch` at its real size: the period
+    `MEMEMEM*EME` at the published widths in eleven runs of one block, 128
+    of 512 experts held, 128 rows, the 1,024 bucket; a decode step (the
+    grouped kernels over non-gated experts in a 1,024-wide latent) and one
+    span of the prefill, 64 positions (the registry's: at 128 the compiler
+    wants 5.5 GB of temporaries beside 12.3 GB; PERF.md, PR 47). The resident
+    bytes (9.30 GB of weights, 2.76 GB of Mamba-2 state and tails in FIVE
+    layers, 0.27 GB of keys and values in ONE) and the program's
+    temporaries have to fit one chip's 16 GB, with no second copy of the
+    state among the step's (`decode.WHOLE_IN_PLACE_BYTES`). And the step's
+    schedule has each Mamba-2 layer's state written BEFORE the kernels of the
+    expert layer that follows it: with `_run_blocks`' fence taken out the
+    same compile gives `KKUUKKUKKUKKUKK`, the first layer's update after the
+    next run's two kernels with its operands held in VMEM across them, and
+    that program computed other hidden states on the chip (PERF.md section
+    7, row 38; the fence is a workaround, and this is what holds a later
+    compiler, kernel or family to the order it buys)."""
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode
+    entry = registry.get_model_entry(NEMOTRON_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    rows, max_len = 128, 1024
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    count = sum(leaf.size for leaf in jax.tree_util.tree_leaves(params))
+    assert count == 4648163712      # 4.648 G: 9.30 GB at 2 B
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg),
+        runs=kind_runs(entry.family.FAMILY, cfg, stage)))
+    assert cache["ssm_state"].shape == (5, rows, 128, 64, 128)
+    assert cache["k"].shape == (1, rows, max_len, 256)
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    compiled = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                          on_chip((), jnp.int32), read_len=max_len,
+                          last_only=last_only).compile()
+    assert cfg.prefill_chunk == 64
+    assert (_grouped_kernels(compiled) > 0) == (span == 1)
+    memory = compiled.memory_analysis()
+    print(f"nemotron-h {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * (max_len * 2048 + 5 * (4194304 + 122880))
+    assert memory.alias_size_in_bytes > cache_bytes     # updated in place
+    assert memory.argument_size_in_bytes < 9.31e9 + 1.02 * cache_bytes
+    # one chip's 15.75 GiB less what the runtime keeps: the span's program
+    # takes 3.04 GB, the step's 0.16 GB, which no copy of a layer's 537 MB
+    # of state fits into
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.6e9
+    if span == 1:
+        assert memory.temp_size_in_bytes < 0.4e9
+        order = _updates_and_kernels(compiled.as_text(),
+                                     f"f32[5,{rows},128,64,128]")
+        # `MEMEMEM*EME`: five updates, five pairs of grouped kernels, and
+        # before an expert layer's pair every earlier Mamba-2 layer's update
+        assert order.count("U") == 5 and order.count("K") == 10, order
+        assert all(order[:at].count("U") > order[:at].count("K") // 2
+                   for at, event in enumerate(order) if event == "K"), order

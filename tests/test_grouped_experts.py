@@ -33,7 +33,12 @@ STEPS = {
     "qwen3-next": (2, 32, 10, (0, 16), "softmax", 16, 32, True),
     "keye": (2, 16, 8, None, "softmax", 24, 32, False),
     "kimi": (8, 48, 8, (0, 6), "sigmoid", 32, 64, True),
+    # experts without a gate matrix in a latent (`LATENT`), 5.5 tokens each
+    "nemotron-h": (16, 64, 22, (0, 16), "sigmoid", 24, 64, True),
 }
+
+# family -> the width of the latent its routed experts read and write
+LATENT = {"nemotron-h": 32}
 
 # cell -> (model, rows of a step, tokens of a span)
 CELLS = {
@@ -44,6 +49,9 @@ CELLS = {
     "keye-vl2.long-batch": ("Kwai-Keye/Keye-VL-2.0-30B-A3B@6", 8, 8 * 512),
     "kimi-k2.agent-batch": ("moonshotai/Kimi-K2-Instruct@5,e0+12,v20480", 32,
                             32 * 128),
+    "nemotron3-super.reason-batch": (
+        "nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16@11,e0+128,v32768",
+        128, 128 * 64),
 }
 
 
@@ -53,13 +61,16 @@ def grouped(monkeypatch):
 
 
 def _layer(experts, per_tok, held, router, f, d, shared, dtype, stack=None,
-           seed=0):
+           seed=0, latent=0):
+    """`latent`: the routed experts are `down(relu(up c)^2)` over a latent
+    of that width (no gate matrix); 0: SwiGLUs on the model's width."""
     rng = np.random.default_rng(seed)
     cfg = TransformerConfig(
         model_type="tiny", hidden_size=d, num_hidden_layers=1,
         num_attention_heads=1, intermediate_size=f, n_experts=experts,
         num_experts_per_tok=per_tok, moe_intermediate_size=f, router=router,
-        norm_topk_prob=True, held_experts=held or ())
+        norm_topk_prob=True, held_experts=held or (),
+        moe_latent_size=latent, expert_act="relu2" if latent else "silu")
     lead = (held[1] if held else experts,)
     if stack:
         lead = (stack,) + lead
@@ -76,6 +87,11 @@ def _layer(experts, per_tok, held, router, f, d, shared, dtype, stack=None,
             rng.normal(size=(experts,)) / 16, jnp.float32)
     if shared:
         params["shared"] = {"gate": w(f, d), "up": w(f, d), "down": w(d, f)}
+    if latent:
+        params["latent"] = {"down": w(latent, d), "up": w(d, latent)}
+        params["experts"] = {"up": w(*lead, f, latent),
+                             "down": w(*lead, latent, f)}
+        params.get("shared", {}).pop("gate", None)
     return cfg, params
 
 
@@ -105,7 +121,8 @@ def _close(got, wanted):
 @pytest.mark.parametrize("family", sorted(STEPS))
 def test_the_grouped_kernels_are_the_loop(family, weights, monkeypatch):
     rows, *shape = STEPS[family]
-    cfg, params = _layer(*shape, jnp.dtype(weights))
+    cfg, params = _layer(*shape, jnp.dtype(weights),
+                         latent=LATENT.get(family, 0))
     (wanted, loop), (got, kernel) = _both_ways(cfg, params,
                                                _rows(rows, shape[5]),
                                                monkeypatch)
@@ -379,6 +396,11 @@ def test_a_step_walks_its_groups_in_kernels_and_a_span_in_the_loop(
               "experts": {"gate": leaf(4, held, f, d),
                           "up": leaf(4, held, f, d),
                           "down": leaf(4, held, d, f)}}
+    if cfg.moe_latent_size:     # two matrices an expert, in the latent
+        wide = cfg.moe_latent_size
+        params.update(latent={"down": leaf(wide, d), "up": leaf(d, wide)},
+                      experts={"up": leaf(4, held, f, wide),
+                               "down": leaf(4, held, wide, f)})
     if cfg.router == "sigmoid":
         params["router"]["bias"] = jax.ShapeDtypeStruct((cfg.n_experts,),
                                                         jnp.float32)
