@@ -55,17 +55,27 @@ attention kind owns `k`, `v` `[L_attn, B, T, G*Dh]`. The Mamba-2 blocks own
 `ssm_state` `[L_mamba, B, H, P, N]` (4.19 MB a request a layer at the
 published sizes) and `ssm_conv` `[L_mamba, B, K - 1, H P + 2 G N]`, a row a
 REQUEST, read and replaced whole by every call. The expert blocks own none.
-At 128 rows the state is 537 MB a layer: the decode driver writes every
-run's state into the stack as the run leaves it, fenced to the run, and
-gathers no second copy (`parallel/decode.py::WHOLE_IN_PLACE_BYTES`, a
-choice a leaf).
+At 128 rows the state is 537 MB a layer: the decode driver gathers no
+second copy of such a leaf (`parallel/decode.py::WHOLE_IN_PLACE_BYTES`, a
+choice a leaf). A span's state goes into the stack as its run leaves it,
+fenced to the run. A step's is moved on where it lies: on a backend that
+runs Mosaic `ops/ssm_step.py` reads a tile of the layer's state, updates it,
+reduces it against C and writes it back to the block it came from, the stack
+aliased in and out (`state_kernel_mode`, read off the call: a step, a
+float32 leaf the driver places, a head's state of whole tiles), where the
+jnp step behind the driver's update moves a layer three times (XLA computes
+`a S + dt x B^T` once for `y` and once more for the update). Elsewhere the
+jnp step serves (`ssm_step`; the tests put "interpret" into `_kernel_mode`).
 
 **Precision.** Weights as stored (bfloat16; `A_log`, `D`, `dt_bias` and the
 router's bias float32); activations, state, tail, keys and values float32:
 products with weights through `exact_dot`, `C B^T`, the state's products and
 q.k at `HIGHEST`, a step's state update float32 multiplications and sums on
-the vector unit; the router's scores in float32 (its top-22 of 512 is a
-discrete choice a narrower computation makes differently).
+the vector unit, in the kernel as in the jnp step (three products and a sum
+an element, the decay `exp_ulp`'s, the sum over N in the lanes' order;
+nothing of it through the matrix unit or a bfloat16 pass); the router's
+scores in float32 (its top-22 of 512 is a discrete choice a narrower
+computation makes differently).
 
 **Prefill** runs in spans of `cfg.prefill_chunk` positions (a multiple of the
 chunk) through the decode-shaped stage program; a span's Mamba-2 mixer runs
@@ -93,6 +103,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import ssm_step as ssm_kernel
 from . import ShardConfig, decoder
 from .decoder import by_head, exp_ulp, lin
 from .layers import TransformerConfig, causal_conv, rms_norm
@@ -103,9 +114,11 @@ from .stage_cache import (attend, attend_width, cache_update_and_read,
 # what a block step counts into the cache's `stats` leaf, in this order: the
 # expert layers' five, the Mamba-2 layers' (`ssm_state_carries`: calls that
 # took a convolution tail, and so a state, that is not zeros from the cache),
-# the attention layers' spans that took the streaming kernel
+# the attention layers' spans that took the streaming kernel, and the stepped
+# positions whose state the in-place kernel updated (`state_kernel_mode`)
 STATS = decoder.MOE_STATS + ("ssm_positions_chunked", "ssm_positions_stepped",
-                             "ssm_state_carries") + decoder.ATTEND_STATS
+                             "ssm_state_carries") + decoder.ATTEND_STATS \
+    + ("ssm_steps_fused",)
 
 # activations, state, tail, keys and values (module docstring, Precision)
 ACTIVATIONS = jnp.float32
@@ -166,6 +179,29 @@ def ssm_step(x, bm, cm, dt, la, state):
     return jnp.sum(state * cm[:, :, None, None, :], axis=-1), state
 
 
+def _kernel_mode():
+    """How this backend runs the state kernel (`ops/ssm_step.py`): "mosaic"
+    on a TPU, None where Mosaic cannot run (`ssm_step` serves every call);
+    the tests put "interpret" here."""
+    return "mosaic" if jax.default_backend() == "tpu" else None
+
+
+def state_kernel_mode(bcache, span: int, prefill: bool):
+    """How a Mamba-2 block's call moves its state on, read off the call:
+    `_kernel_mode()` where the kernel updates the layer where it lies in the
+    cache's stack (a step, over what the cache holds, of a float32 leaf that
+    the decode driver has its blocks write in place, `LayerCache.placed`;
+    compiled, a head's state of whole tiles: interpret mode knows none);
+    None where `ssm_step` or `ssm_chunked` hand back the rows' state for the
+    driver to write."""
+    stack = bcache.stack["ssm_state"]
+    mode = _kernel_mode()
+    fits = span == 1 and not prefill and "ssm_state" in bcache.placed \
+        and stack.dtype == jnp.float32 \
+        and (mode == "interpret" or ssm_kernel.whole_tiles(*stack.shape[3:]))
+    return mode if fits else None
+
+
 def ssm_chunked(x, bm, cm, dt, la, state, chunk: int):
     """The recurrence over a span in chunks (module docstring): x [B, S, G,
     R, P], bm, cm [B, S, G, N], dt, la [B, S, G, R], state [B, G, R, P, N],
@@ -203,9 +239,11 @@ def ssm_chunked(x, bm, cm, dt, la, state, chunk: int):
     return y[:, :s], state
 
 
-def _mamba_rows(p: Dict, normed, state, tail, cfg: TransformerConfig):
+def _mamba_rows(p: Dict, normed, state, tail, cfg: TransformerConfig,
+                in_place=None):
     """`mamba` of some rows of the batch, from their `state` [rows, H, P, N]
-    and `tail` [rows, K - 1, channels]. -> (out, state, tail)."""
+    and `tail` [rows, K - 1, channels]. -> (out, state, tail). A step with
+    `in_place` (`mamba`) reads no `state` and hands back what that does."""
     b, s, _ = normed.shape
     h, hd, n, g = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, \
         cfg.ssm_groups
@@ -222,31 +260,40 @@ def _mamba_rows(p: Dict, normed, state, tail, cfg: TransformerConfig):
     la = (-jnp.exp(p["a_log"].astype(jnp.float32)) * dt).reshape(
         b, s, g, h // g)
     dt = dt.reshape(b, s, g, h // g)
-    state = state.astype(jnp.float32).reshape(
-        (b, g, h // g) + state.shape[2:])
-    if s == 1:
-        y, state = ssm_step(x[:, 0], bm[:, 0], cm[:, 0], dt[:, 0], la[:, 0],
-                            state)
-        y = y[:, None]
+    if in_place is not None:
+        state, y = in_place(exp_ulp(la[:, 0]).reshape(b, h),
+                            (dt[:, 0, ..., None] * x[:, 0]).reshape(b, h, hd),
+                            bm[:, 0], cm[:, 0])
+        y = y.reshape(b, 1, g, h // g, hd)
     else:
-        y, state = ssm_chunked(x, bm, cm, dt, la, state,
-                               min(cfg.linear_chunk, s))
+        state = state.astype(jnp.float32).reshape(
+            (b, g, h // g) + state.shape[2:])
+        if s == 1:
+            y, state = ssm_step(x[:, 0], bm[:, 0], cm[:, 0], dt[:, 0],
+                                la[:, 0], state)
+            y = y[:, None]
+        else:
+            y, state = ssm_chunked(x, bm, cm, dt, la, state,
+                                   min(cfg.linear_chunk, s))
+        state = state.reshape((b, h) + state.shape[3:])
     y = y + p["d_skip"].astype(jnp.float32).reshape(g, h // g, 1) * x
     # the gate goes in before the norm; a norm a group of heads
     y = y.reshape(b, s, g, -1) * jax.nn.silu(z).reshape(b, s, g, -1)
     y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
                           + cfg.layer_norm_eps)
     y = y.reshape(b, s, inner) * p["out_norm"].astype(jnp.float32)
-    return lin(p["out"], y.astype(normed.dtype)), \
-        state.reshape((b, h) + state.shape[3:]), tail
+    return lin(p["out"], y.astype(normed.dtype)), state, tail
 
 
-def mamba(p: Dict, normed, read, cfg: TransformerConfig):
+def mamba(p: Dict, normed, read, cfg: TransformerConfig, in_place=None):
     """The Mamba-2 mixer of `normed` [B, S, D]. `read(name, first, rows)`
     hands the cache's `ssm_state` [rows, H, P, N] and `ssm_conv` [rows, K -
     1, channels] (the convolution's inputs at the positions before) of the
     requests `[first, first + rows)`. -> (out [B, S, D], state, tail) after
-    the span.
+    the span. A step whose state the kernel updates where it lies
+    (`state_kernel_mode`) brings `in_place(decay [B, H], dt x [B, H, P], B_t,
+    C_t [B, G, N]) -> (the cache's stack, y [B, H, P])`: no state is read
+    here, all rows go in one call, and `state` is that stack.
 
     The rows of the batch in groups whose input projection's three-pass
     product stays under `decoder.PRODUCT_BYTES` (`in_row_chunks`' rule, by
@@ -255,6 +302,9 @@ def mamba(p: Dict, normed, read, cfg: TransformerConfig):
     positions would hold 3.6 GB of projections, a gigabyte of decays a
     chunk and half a gigabyte of state copied out."""
     b, s, _ = normed.shape
+    if in_place is not None:
+        return _mamba_rows(p, normed, None, read("ssm_conv", 0, b), cfg,
+                           in_place)
     groups = 1
     while b % (2 * groups) == 0 and b // groups * s * p["in_proj"].shape[0] \
             * 12 > decoder.PRODUCT_BYTES:
@@ -321,29 +371,43 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
     the three kinds: one sublayer after one norm. The rows of `x` sit at
     [pos, pos + S). A Mamba-2 block takes its state and its convolution's
     tail from the cache (a prefill, at `pos` 0: zeros) and records what they
-    are after the span, which takes their place; an attention block attends
+    are after the span, which takes their place (a step whose state the
+    kernel has updated in the stack, `state_kernel_mode`, records no state:
+    it hands back the cache with that stack); an attention block attends
     the cached window below `pos` and its own rows and records their keys
     and values for `write_rows`; an expert block touches no leaf."""
     b, s, _ = x.shape
     normed = rms_norm(p["ln"], x, cfg.layer_norm_eps)
-    moe, counts = jnp.zeros(5, jnp.int32), [0, 0, 0, 0]
+    moe, counts = jnp.zeros(5, jnp.int32), [0, 0, 0, 0, 0]
     rows = {}
     if "in_proj" in p:
+        stack, layer = bcache.stack, bcache.layer
+        mode = state_kernel_mode(bcache, s, prefill)
+
         def read(name, first, count):
-            buf = bcache.stack[name]
+            buf = stack[name]
             got = jax.lax.dynamic_slice(
-                buf, (bcache.layer, first) + (0,) * (buf.ndim - 2),
+                buf, (layer, first) + (0,) * (buf.ndim - 2),
                 (1, count) + buf.shape[2:])[0]
             return jnp.zeros_like(got) if prefill else got
 
         counts = [b * s if s > 1 else 0, b if s == 1 else 0,
-                  jnp.any(read("ssm_conv", 0, b) != 0).astype(jnp.int32), 0]
-        mixed, state, tail = mamba(p, normed, read, cfg)
-        rows = {"ssm_state": state, "ssm_conv": tail}
+                  jnp.any(read("ssm_conv", 0, b) != 0).astype(jnp.int32), 0,
+                  b if mode else 0]
+        if mode:
+            mixed, state, tail = mamba(
+                p, normed, read, cfg, in_place=lambda *step: ssm_kernel.step(
+                    stack["ssm_state"], layer, *step,
+                    interpret=mode == "interpret"))
+            rows = {"ssm_conv": tail}
+            bcache = bcache._replace(stack=dict(stack, ssm_state=state))
+        else:
+            mixed, state, tail = mamba(p, normed, read, cfg)
+            rows = {"ssm_state": state, "ssm_conv": tail}
     elif "q" in p:
         mixed, bcache, fused = attention(p, normed, bcache, pos, cfg,
                                          prefill, read_len)
-        rows, counts = dict(bcache.rows), [0, 0, 0, fused]
+        rows, counts = dict(bcache.rows), [0, 0, 0, fused, 0]
     else:
         mixed, moe = decoder.routed_experts(
             {name: leaf for name, leaf in p.items() if name != "shared"},

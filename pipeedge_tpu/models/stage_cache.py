@@ -146,10 +146,16 @@ class LayerCache(NamedTuple):
     """What a block step holds of its stage's cache: the stacked buffers
     (leaves `[L, B, T, ...]`, as they were before this step), the index of
     the one layer it may read, and, once a cache function has run, the
-    `rows` (`[B, S, ...]` a leaf) this step writes at `[pos, pos + S)`."""
+    `rows` (`[B, S, ...]` a leaf) this step writes at `[pos, pos + S)`.
+    `placed` names the leaves whose layer this step may instead write where
+    it lies (a decode step's `whole` leaves that the driver has written a
+    run at a time, `parallel/decode.py::WHOLE_IN_PLACE_BYTES`): a block that
+    does hands back `stack` with that leaf's stack replaced by the updated
+    one, and no rows for it."""
     stack: Cache
     layer: jax.Array
     rows: Optional[Cache] = None
+    placed: tuple = ()
 
 
 class Window(NamedTuple):

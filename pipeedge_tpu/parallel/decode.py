@@ -370,29 +370,41 @@ def attend_bucket(pos_next: int, max_len: int, floor: int = 64,
 
 
 # bytes of the rows the LONGEST run of blocks leaves for a `whole` leaf from
-# which every run that writes the leaf writes its rows into the stack at once,
-# at its own layers, and the later runs read the stack so written (they read
-# other layers: nothing changes for them). The choice is the leaf's, made once
-# before the runs (`_written_by_run`): a leaf is written a run at a time by
-# all of its runs or gathered from all of them, whatever their lengths.
-# Gathered until the end and put in the leaf's place, as smaller states are,
-# the runs' rows are a second copy of the leaf alive beside the first and a
-# concatenation's bytes a call: 2.76 GB of Mamba-2 state at 128 rows of
-# nemotron_h where the states of qwen3_next (17 MB a layer at 8 rows),
-# minicpm_sala and lfm2 are noise. A chain of updates of one donated buffer,
-# each after the last reader of the layers it writes, is what XLA updates in
-# place. Each update is fenced to its run's output (`optimization_barrier`):
-# left free, the chip's compiler put a Mamba-2 layer's update after the NEXT
-# run's grouped kernels, and the cell's eleven-run step program then computed
-# other hidden states from its third Mamba-2 layer on (0.26 of the logits'
-# range from the float32 reference; the same program with the tile loop for
-# the kernels, or with the state gathered, agreed to 6e-7; eight runs agreed
-# either way). Fenced it agrees (PERF.md, PR 47, and section 7). The fence is
-# a workaround, not a cure: `tests/test_chip_compile_families.py` holds the
-# scheduled step program to the order it gives. Two ways to update a leaf is
-# one too many: ROADMAP D1 asks for the one (every `whole` leaf a run at a
-# time), which changes the siblings' programs and so needs their cells
-# measured
+# which the leaf is written where it lies, a run at a time, and the later
+# runs read the stack so written (they read other layers: nothing changes for
+# them). The choice is the leaf's, made once before the runs
+# (`_written_by_run`): a leaf is placed for all of its runs or gathered from
+# all of them, whatever their lengths. Gathered until the end and put in the
+# leaf's place, as smaller states are, the runs' rows are a second copy of
+# the leaf alive beside the first and a concatenation's bytes a call: 2.76 GB
+# of Mamba-2 state at 128 rows of nemotron_h where the states of qwen3_next
+# (17 MB a layer at 8 rows), minicpm_sala and lfm2 are noise. A placed leaf
+# is written one way in a span and one in a step.
+#
+# A span (and a step on a backend without Mosaic): every run that writes the
+# leaf puts its rows into the donated stack at its own layers as it leaves
+# them, a chain of updates of one buffer, each after the last reader of the
+# layers it writes, which XLA does in place. Each update is fenced to its
+# run's output (`optimization_barrier`): left free, the chip's compiler put a
+# Mamba-2 layer's update after the NEXT run's grouped kernels, and the cell's
+# eleven-run step program then computed other hidden states from its third
+# Mamba-2 layer on (0.26 of the logits' range from the float32 reference;
+# the same program with the tile loop for the kernels, or with the state
+# gathered, agreed to 6e-7; eight runs agreed either way; PERF.md, PR 47, and
+# section 7). The fence is a workaround, not a cure.
+#
+# A step (PR 48): the run's blocks are called one after another, not
+# scanned, each told which leaves are placed (`LayerCache.placed`), and a
+# block may update its layer of such a leaf where it lies and hand back the
+# stack, which the next block takes: nemotron_h's state kernel
+# (`ops/ssm_step.py`, the stack aliased in and out, one read and one write
+# of a layer where the update above and the `y` beside it were three). The
+# kernel's `y` feeds the residual, so data puts it before the next run's
+# kernels and it needs no fence; rows a block hands back instead go the
+# span's way. `tests/test_chip_compile_families.py` holds the scheduled step
+# program to that order either way. ROADMAP D1 asks for one way to update
+# every `whole` leaf, which changes the siblings' programs and so needs
+# their cells measured
 WHOLE_IN_PLACE_BYTES = 1 << 26
 
 
@@ -441,11 +453,14 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
     scanned over either: the block step gets each as a `LayerSlice`. A
     `whole` leaf whose rows of its longest run are `WHOLE_IN_PLACE_BYTES` or
     more is written a run at a time, by every run, and not gathered
-    (`_written_by_run`)."""
+    (`_written_by_run`); in a step the runs that own one are not scanned but
+    unrolled, and their blocks may write it themselves (the comment over
+    `WHOLE_IN_PLACE_BYTES`)."""
     runs = blocks.runs if isinstance(blocks, BlockRuns) else (blocks,)
     kinds = kinds or (None,) * len(runs)
     owner = leaf_owners(leaves)
     placed = _written_by_run(runs, kinds, cache, leaves)
+    step = not prefill and x.shape[1] == 1
     rows, done, of_kind = [], 0, {}
     for run, kind in zip(runs, kinds):
         view, first = cache, done
@@ -459,18 +474,38 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
         if held:
             run = {name: leaf for name, leaf in run.items()
                    if name not in held}
+        mine = tuple(name for name in placed if name in view) if step else ()
 
-        def body(y, xs, held=held, first=first, view=view):
-            bp, layer = xs
+        def block(y, bp, layer, view, held=held, first=first, mine=mine):
             if held:
                 bp = dict(bp, **{name: LayerSlice(leaf, layer)
                                  for name, leaf in held.items()})
             at = first + layer if first else layer      # the cache's layer
-            y, bc = block_fn(bp, y, LayerCache(view, at), pos, cfg, prefill)
+            return block_fn(bp, y, LayerCache(view, at, placed=mine), pos,
+                            cfg, prefill)
+
+        def body(y, xs, view=view):
+            y, bc = block(y, *xs, view)
             return y, bc.rows
 
         n_blocks = _n_blocks(run)
-        x, new = jax.lax.scan(body, x, (run, jnp.arange(n_blocks)))
+        if mine:
+            # a step of a run that owns placed leaves: its blocks one after
+            # another, not scanned, so that a block may update its layer of
+            # such a leaf where it lies and hand the stack to the next (a
+            # scan would carry the stack: PERF.md, PR 25); the rows of the
+            # blocks that did not are stacked as the scan stacks them
+            new = []
+            for layer in range(n_blocks):
+                x, bc = block(x, jax.tree_util.tree_map(
+                    lambda leaf, layer=layer: leaf[layer], run), layer, view)
+                view = bc.stack
+                new.append(bc.rows)
+            cache = dict(cache, **{name: view[name] for name in mine})
+            new = {name: jnp.stack([rows[name] for rows in new])
+                   for name in new[0]}
+        else:
+            x, new = jax.lax.scan(body, x, (run, jnp.arange(n_blocks)))
         for name in placed:
             if name not in new:
                 continue

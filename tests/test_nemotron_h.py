@@ -489,6 +489,52 @@ def test_a_state_written_a_run_at_a_time_is_the_state_gathered(model,
             np.testing.assert_array_equal(leaf, cache[name])
 
 
+@pytest.mark.parametrize("placed", [False, True], ids=["gathered", "placed"])
+@pytest.mark.parametrize("model, mamba_layers", [(TINY + "@3", 2), (TINY, 4)],
+                         ids=["runs-of-one", "an-MM-run"])
+def test_a_steps_state_through_the_kernel_is_the_jnp_steps(
+        model, mamba_layers, placed, monkeypatch):
+    """`nemotron_h.state_kernel_mode`: a prefill and eight steps with the
+    state kernel (`ops/ssm_step.py`, interpret mode) against the same with
+    the jnp step, in a stage whose Mamba-2 runs are of one block (`MEM`) and
+    in one with a run of two (`MEMM*EME`), with `WHOLE_IN_PLACE_BYTES` on
+    either side of the twin's leaf. Where the driver does not place the leaf
+    no step takes the kernel and `ssm_steps_fused` stays 0; where it does,
+    every stepped position of every Mamba-2 layer does, the run of two
+    unrolled so that the second block takes the stack the first hands back;
+    `ssm_positions_stepped` counts the same either way."""
+    if placed:
+        monkeypatch.setattr(decode, "WHOLE_IN_PLACE_BYTES", 0)
+    rows, prompt, steps = 2, 11, 8
+    ids = np.random.default_rng(11).integers(0, 50, size=(rows, prompt + steps))
+    fused, stepped = (nemotron_h.STATS.index(name) for name in (
+        "ssm_steps_fused", "ssm_positions_stepped"))
+    out = []
+    for mode in (None, "interpret"):
+        monkeypatch.setattr(nemotron_h, "_kernel_mode", lambda mode=mode: mode)
+        pipe = decode.build_decode_pipeline(model, None, max_len=32)
+        data, caches = pipe._prefill(jnp.asarray(ids[:, :prompt], jnp.int32))
+        logits = [np.asarray(data[:, -1])]
+        after_prefill = stage_cache.read_stats(caches[0])
+        for pos in range(prompt, prompt + steps):
+            data, caches = pipe.extend(ids[:, pos:pos + 1], caches, pos)
+            logits.append(np.asarray(data[:, 0]))
+        counts = stage_cache.read_stats(caches[0]) - after_prefill
+        assert counts[stepped] == rows * steps * mamba_layers
+        assert counts[fused] == (
+            rows * steps * mamba_layers if placed and mode else 0)
+        assert after_prefill[fused] == 0
+        out.append((np.stack(logits, 1), {
+            name: np.asarray(caches[0][name])
+            for name in ("ssm_state", "ssm_conv", "k", "v")}))
+    (wanted, cache), (got, fused_cache) = out
+    assert _gap(got, wanted) < 1e-6
+    for name, leaf in cache.items():
+        assert leaf.shape == fused_cache[name].shape
+        if leaf.size:
+            assert _gap(fused_cache[name], leaf) < 1e-6, name
+
+
 def _counters():
     return {(name, phase): prom.REGISTRY.counter(
         f"pipeedge_{name}_total", "").value(phase=phase)
