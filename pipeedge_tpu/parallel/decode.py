@@ -25,6 +25,7 @@ token-for-token (tests/test_decode.py).
 from __future__ import annotations
 
 import math
+from collections import deque
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -32,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry
+from ..telemetry import generate_account
 from ..telemetry import metrics as prom
 from ..utils import jax_compat
 
@@ -860,6 +862,15 @@ def _pick_last(out, rng, temperature: float, top_k: int):
     return pick_next(out, rng, temperature=temperature, top_k=top_k)
 
 
+@jax.jit
+def join_tokens(ids, *tokens):
+    """A batch's result: the prompts `[B, S]` with the tokens picked, each
+    `[B]`, beside them, in one program a (batch, prompt, token count): an
+    eager `stack` is a dispatch a token after the last step, with the
+    device idle under them (PERF.md section 6, PR 49)."""
+    return jnp.concatenate([ids, jnp.stack(tokens, axis=1)], axis=1)
+
+
 def make_next_picker(temperature: float = 0.0, top_k: int = 0):
     """`pick(out [B, S, V], rng) -> (tokens [B], ids [B, 1], rng)`: one
     program between two stage programs. From the last stage's output
@@ -1312,6 +1323,11 @@ class DecodePipeline:
         self.attend_floor = attend_floor
         # the ladder `generate` asks `_read_len` for
         self.job_per_octave = job_per_octave(self.cache_leaves, self.stages)
+        # the accounts of the last batches `generate` ran (plain dicts:
+        # telemetry/generate_account.py), and the build counters a batch
+        # reads to know whether a program was built inside it
+        self.batch_accounts = deque(maxlen=generate_account.ACCOUNTS_KEPT)
+        prom.count_jax_compiles()
 
     def _read_len(self, pos: int, span: int = 1, per_octave: int = 1):
         """Static attend window for a decode/span step whose last query
@@ -1377,10 +1393,13 @@ class DecodePipeline:
         return st["decode"](st["params"], data, cache, pos, read_len=rl)
 
     def _prefill(self, ids, prefill_ubatch: Optional[int] = None,
-                 per_octave: int = 1):
+                 per_octave: int = 1, account=generate_account.NO_ACCOUNT):
         """Run the prompt through all stages; returns (last-stage output,
         per-stage caches). Where the family prefills in spans, each span
         attends a window off the ladder of `per_octave` (`_read_len`).
+        `account` is `generate`'s of its batch: the `generate/alloc` and
+        `generate/prefill` spans are its phases then, and each span's
+        output a candidate mark.
 
         `prefill_ubatch` splits the batch into chunks so prefill PIPELINES
         across stages: JAX dispatch is asynchronous, so stage i's program
@@ -1389,18 +1408,21 @@ class DecodePipeline:
         axis afterwards. (For capacity-bounded MoE models chunking changes
         the routed token set, like any batch-size change.)"""
         batch = ids.shape[0]
+        span = account.span
 
         def run_stages(data):
-            caches = self._fresh_caches(data.shape[0])
+            with span("alloc"):
+                caches = self._fresh_caches(data.shape[0])
             if self.prefill_span:       # span by span over the cache
                 for start in range(0, data.shape[1], self.prefill_span):
-                    with telemetry.span("generate", "prefill"):
+                    with span("prefill"):
                         out, caches = self.extend(
                             data[:, start:start + self.prefill_span],
                             caches, start, last_only=True,
                             per_octave=per_octave)
+                    account.span_out(out)
                 return out, caches
-            with telemetry.span("generate", "prefill"):
+            with span("prefill"):
                 for i, st in enumerate(self.stages):
                     if st["device"] is not None:
                         data = jax.device_put(data, st["device"])
@@ -1408,6 +1430,8 @@ class DecodePipeline:
                                                     caches[i])
             return data, caches
 
+        if self.prefill_span:
+            account.expect_spans(-(-ids.shape[1] // self.prefill_span))
         if prefill_ubatch is None or prefill_ubatch >= batch:
             return run_stages(ids)
         if prefill_ubatch <= 0:
@@ -1421,11 +1445,12 @@ class DecodePipeline:
             data, caches = run_stages(ids[c0:c0 + prefill_ubatch])
             outs.append(data)
             chunk_caches.append(caches)
-        merged = [jax.tree_util.tree_map(
-            lambda *xs: jnp.concatenate(xs, axis=1), *[cc[i] for cc in
-                                                       chunk_caches])
-            for i in range(len(self.stages))]
-        return jnp.concatenate(outs, axis=0), merged
+        with span("prefill"):
+            merged = [jax.tree_util.tree_map(
+                lambda *xs: jnp.concatenate(xs, axis=1), *[cc[i] for cc in
+                                                           chunk_caches])
+                for i in range(len(self.stages))]
+            return jnp.concatenate(outs, axis=0), merged
 
     def extend(self, tokens, caches, pos: int, last_only: bool = False,
                per_octave: int = 1):
@@ -1543,11 +1568,25 @@ class DecodePipeline:
         `attend_bucket`) and attend a window close to the live length. The
         tokens are those of any other ladder: the positions a wider window
         adds are masked to exact zeros."""
+        if new_tokens <= 0:
+            return jnp.asarray(ids, jnp.int32)
+        rows, suffix_len = jnp.shape(ids)
+        with generate_account.BatchAccount(self.batch_accounts, rows,
+                                           suffix_len, new_tokens) as account:
+            return self._generate(account, ids, new_tokens, temperature,
+                                  top_k, seed, step_callback, prefill_ubatch,
+                                  prefix)
+
+    def _generate(self, account, ids, new_tokens, temperature, top_k, seed,
+                  step_callback, prefill_ubatch, prefix):
+        """`generate`'s batch, inside its `generate/batch` span: every call
+        into the runtime is a phase of `account`
+        (`telemetry/generate_account.py`), which watches the device's
+        progress on the tokens picked and fences nothing before the last
+        dispatch."""
         ids = jnp.asarray(ids, jnp.int32)
         batch, suffix_len = ids.shape
         prompt_len = suffix_len + (prefix["len"] if prefix else 0)
-        if new_tokens <= 0:
-            return ids
         validate_capacity(self.cfg, self.max_len, prompt_len, new_tokens)
         if prefix is None and prompt_len % self.sp_degree:
             raise ValueError(f"prompt length {prompt_len} not divisible by "
@@ -1568,33 +1607,44 @@ class DecodePipeline:
             # broadcast the prefix's B=1 cache rows to this batch (the
             # beam-search batch-tiling rule), then run the whole suffix
             # as one span at the prefix offset
-            caches = [_repeat_batch(c, batch) for c in prefix["caches"]]
-            data, caches = self.extend(ids, caches, prefix["len"],
-                                       per_octave=self.job_per_octave)
+            with account.span("alloc"):
+                caches = [_repeat_batch(c, batch) for c in prefix["caches"]]
+            with account.span("prefill"):
+                data, caches = self.extend(ids, caches, prefix["len"],
+                                           per_octave=self.job_per_octave)
         else:
             data, caches = self._prefill(ids, prefill_ubatch,
-                                         per_octave=self.job_per_octave)
+                                         per_octave=self.job_per_octave,
+                                         account=account)
         # the counts as the prompt left them: copies, since the caches are
         # donated to the steps; read back once, after the last step
-        after_prompt = [c[STATS] + 0 for c in caches if STATS in c]
+        with account.span("finish"):
+            after_prompt = [c[STATS] + 0 for c in caches if STATS in c]
         tokens = []
         for step in range(new_tokens):
             if step:
-                with telemetry.span("generate", "step"):
+                with account.span("step"):
                     for i, st in enumerate(self.stages):
                         if st["device"] is not None:
                             data = jax.device_put(data, st["device"])
                         data, caches[i] = self._decode_step(
                             st, data, caches[i], prompt_len + step - 1,
                             per_octave=self.job_per_octave)
-            with telemetry.span("generate", "pick"):
+            with account.span("pick"):
                 token, data, rng = pick(data, rng)
             tokens.append(token)
+            account.token(token)
             if step_callback is not None:
                 step_callback(step, token)
+        # one program, dispatched before the wait, so the device never
+        # waits for the host
+        with account.span("finish"):
+            result = join_tokens(ids, *tokens)
+        account.wait()
         if after_prompt:
-            self._count(after_prompt, caches)
-        return jnp.concatenate([ids, jnp.stack(tokens, axis=1)], axis=1)
+            with account.span("finish"):
+                self._count(after_prompt, caches)
+        return result
 
     def _count(self, after_prompt, caches) -> None:
         """Add a batch's device counts to the registry's counters, by

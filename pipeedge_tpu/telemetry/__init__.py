@@ -39,7 +39,10 @@ lifecycle; the streaming handler's `readback` and `write`), `exec` (the
 decode executor's worker phases: `wait0`, `admit`, `pick`, `emit`,
 `eos`, `retire`, `publish`), `startup` (`startup()`: where a process's
 set-up goes, phase by phase; also the always-on
-`pipeedge_startup_seconds_total{phase}`).
+`pipeedge_startup_seconds_total{phase}`), `generate` (a call of
+`DecodePipeline.generate`: `batch` around its phases `alloc`, `prefill`,
+`step`, `pick`, `finish`, `wait`; `generate_account.py` is their sink and
+the batch's always-on account).
 """
 from __future__ import annotations
 
@@ -164,14 +167,14 @@ class _Span:
     """Live span: stamps monotonic_ns on enter/exit and records into the
     ring on exit (when `rec` is set); when `ann` is set — a profiler
     session is live — the span is also that `TraceAnnotation`'s life.
-    `seconds`, a counter labelled by phase, gets the same two stamps'
-    difference under the span's name (`startup()`)."""
+    `sink`, a callable `(name, t0, t1)`, gets the same two stamps
+    (`sunk_span`)."""
 
     __slots__ = ("_rec", "_ann", "_cat", "_name", "_stage", "_mb", "_rid",
-                 "_seconds", "_t0")
+                 "_sink", "_t0")
 
     def __init__(self, rec, ann, cat, name, stage, mb, rid=None,
-                 seconds=None):
+                 sink=None):
         self._rec = rec
         self._ann = ann
         self._cat = cat
@@ -179,7 +182,7 @@ class _Span:
         self._stage = stage
         self._mb = mb
         self._rid = rid
-        self._seconds = seconds
+        self._sink = sink
 
     def __enter__(self):
         if self._ann is not None:
@@ -194,8 +197,8 @@ class _Span:
         if self._rec is not None:
             self._rec.record(self._cat, self._name, self._t0, t1,
                              self._stage, self._mb, rid=self._rid)
-        if self._seconds is not None:
-            self._seconds.inc((t1 - self._t0) / 1e9, phase=self._name)
+        if self._sink is not None:
+            self._sink(self._name, self._t0, t1)
         return False
 
 
@@ -276,6 +279,19 @@ def span(cat: str, name: str, stage: Optional[int] = None,
     return _Span(rec, ann(f"{cat}/{name}"), cat, name, stage, mb, rid)
 
 
+def sunk_span(cat: str, name: str, sink) -> _Span:
+    """`span(cat, name)` that is never the no-op: with a ring it is a span,
+    under a live profiler session a `TraceAnnotation`, and the same two
+    clock readings always go to `sink(name, t0, t1)` (nanoseconds of
+    `time.monotonic_ns`), so that what the always-on account adds up and
+    what a trace shows cannot disagree (`startup()`,
+    `generate_account.BatchAccount`)."""
+    ann = _live_annotation()
+    if ann is not None:
+        ann = ann(f"{cat}/{name}")
+    return _Span(_recorder, ann, cat, name, None, None, sink=sink)
+
+
 # -- start-up phases -----------------------------------------------------
 
 # where a process's set-up goes, in the order a serving process meets
@@ -302,6 +318,10 @@ _STARTUP_BYTES.declare(phase="weights_read")
 _startup_open = threading.local()
 
 
+def _add_startup_seconds(phase, t0, t1):
+    _STARTUP_SECONDS.inc((t1 - t0) / 1e9, phase=phase)
+
+
 class _Startup:
     """One start-up phase on this thread, as a run of `startup/<phase>`
     spans: one, unless a phase opened inside it suspends it meanwhile."""
@@ -315,11 +335,7 @@ class _Startup:
         self._phase = phase
 
     def _resume(self):
-        ann = _live_annotation()
-        if ann is not None:
-            ann = ann(f"startup/{self._phase}")
-        self._span = _Span(_recorder, ann, "startup", self._phase, None,
-                           None, seconds=_STARTUP_SECONDS)
+        self._span = sunk_span("startup", self._phase, _add_startup_seconds)
         self._span.__enter__()
 
     def _suspend(self, *exc):

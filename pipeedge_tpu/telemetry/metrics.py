@@ -452,7 +452,8 @@ BUILD_STEPS = ("trace", "lower", "compile", "cache_read")
 # the jitted functions the pipeline builders and the decode path make, by
 # the name the device trace prints after `jit_`; every other build (eager
 # operations, a caller's own programs) is `other`
-PROGRAMS = ("prefill", "decode_step", "pick_next", "host_stage_step",
+PROGRAMS = ("prefill", "decode_step", "pick_next", "join_tokens",
+            "host_stage_step",
             "shard_apply", "spmd_body", "tp_prefill", "tp_decode_step",
             "ep_prefill", "ep_decode_step", "tp_ep_prefill",
             "tp_ep_decode_step", "sp_prefill")

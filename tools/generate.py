@@ -18,6 +18,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 from pipeedge_tpu import telemetry  # noqa: E402
+from pipeedge_tpu.telemetry import generate_account  # noqa: E402
 
 
 def prompt_ids(args, cfg):
@@ -26,8 +27,12 @@ def prompt_ids(args, cfg):
         0, cfg.vocab_size, size=(args.batch_size, args.prompt_len))
 
 
-def print_summary(args, dt, result, label):
+def print_summary(args, dt, result, label, accounts=()):
+    """`accounts`: the pipeline's `batch_accounts` where the timed batch
+    was a call of `DecodePipeline.generate`; its account is the last."""
     print(telemetry.startup_line())
+    if accounts:
+        print(generate_account.account_line(accounts[-1]))
     print(f"generated {args.batch_size}x{args.new_tokens} tokens in "
           f"{dt:.3f}s = {args.batch_size * args.new_tokens / dt:.1f} tok/s "
           f"({label})")
@@ -567,7 +572,8 @@ def main():
     if args.monitor:
         import monitoring
         monitoring.finish()
-    print_summary(args, dt, out, label)
+    print_summary(args, dt, out, label,
+                  () if args.beams else pipe.batch_accounts)
 
 
 if __name__ == "__main__":
