@@ -41,8 +41,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..utils import jax_compat
-from ..models.layers import (TransformerConfig, _three_parts, exact_dot,
-                             gelu)
+from ..models.layers import TransformerConfig, exact_dot, gelu
 from ..ops import grouped_matmul as gm
 
 
@@ -259,7 +258,7 @@ def shard_moe_params(params: Dict, mesh: Mesh, axis: str = "ep") -> Dict:
 # rows a call's tiles have is `expert_tile`'s to say; this is its cap (256
 # rows of a 2,048 x 768 expert are as many FLOPs in one bfloat16 pass as its
 # weights are bytes on a v5e). What `expert_tile` returns also says which
-# calls leave the loop for the grouped kernels (`GROUPED_RIDGE`, below).
+# calls leave the loop for the grouped kernel (`GROUPED_RIDGE`, below).
 EXPERT_TILE = 256
 
 # standard deviations of a group's size that a tile leaves room for above
@@ -303,7 +302,7 @@ def expert_tile(tokens: int, per_tok: int, n_experts: int) -> int:
 ROUND_SLACK = 4
 
 # a call whose tile is at most this many rows walks its groups inside one
-# grouped kernel a product (`ops/grouped_matmul.py`); above it the tile loop
+# grouped kernel (`ops/grouped_matmul.py`); above it the tile loop
 # stays. Under the ridge a tile is bound by its expert's bytes and the loop
 # pays a trip, three slices, a gather and a write for every touched expert,
 # which the kernel does not (PERF.md, PR 41's table: one layer call alone,
@@ -447,8 +446,8 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
       (`_tile_loop`), bound by its three-pass products;
     - a tile at or under it (a step of 128 rows, top-4 of 32: groups of 16;
       a step of 32 rows, top-8 of 256: single assignments), on a backend
-      that runs Mosaic: the gate and up products and then the down product
-      walk the groups inside one kernel each (`_grouped`), bound by the
+      that runs Mosaic: one kernel walks the groups, up, activation and
+      down a visit, the hidden rows in VMEM (`_grouped`), bound by the
       touched experts' bytes.
 
     A row's result does not depend on the way, but for the order of the
@@ -522,12 +521,12 @@ def _back_to_tokens(delta, out, sorted_at, gates, base, end):
 def _grouped(tokens, ex, layer, order, bounds, sorted_at, gates, local,
              row_tile: int, aligned: bool, interpret: bool,
              act: str = "silu"):
-    """The sorted groups times their experts in two grouped kernels
-    (`ops/grouped_matmul.py`): `silu(gate x) * up x` (`act(up x)` of
-    experts without a gate matrix: one stack through the first kernel, the
-    activation between the two), then `down`; the arithmetic is
+    """The sorted groups through their experts in one grouped kernel
+    (`ops/grouped_matmul.py::grouped_ffn`): `down(silu(gate x) * up x)`, or
+    `down(act(up x))` of experts without a gate matrix; the rows go in as
+    they were gathered and the hidden stays in the kernel; the arithmetic is
     `_expert_ffn`'s over `exact_dot`. -> (delta [T, D] float32, rows the
-    kernels multiplied).
+    kernel multiplied).
 
     The stack is handed over as it lies, its leading axes flattened (a free
     reshape), and group g is its matrix `layer * experts + g`: a layer's
@@ -566,34 +565,10 @@ def _grouped(tokens, ex, layer, order, bounds, sorted_at, gates, local,
     n_rows = n_tiles * row_tile
     items = gm.group_items(starts, starts + sizes, first_group, row_tile,
                            gm.max_items(n_rows, count, row_tile))
-    rows = jnp.take(tokens, token_of_row, axis=0)
-
-    # `exact_dot`'s three cases, the parts of a row tile next to each other
-    w_dtype = ex["up"].dtype
-    precision = None
-    if tokens.dtype == w_dtype == jnp.float32:
-        precision = jax.lax.Precision.HIGHEST
-
-    def by_tiles(x):
-        if x.dtype != jnp.float32:
-            parts = x.astype(w_dtype)[None]
-        elif w_dtype == jnp.float32:
-            parts = x[None]
-        else:
-            parts = _three_parts(x, w_dtype)
-        n = parts.shape[0]
-        return n, parts.reshape(n, n_tiles, row_tile, -1).swapaxes(
-            0, 1).reshape(n * n_rows, -1)
-
-    call = partial(gm.grouped_matmul, items=items, row_tile=row_tile,
-                   precision=precision, interpret=interpret)
-    parts, x = by_tiles(rows)
-    if "gate" in ex:
-        hidden = call(x, (stacks["gate"], stacks["up"]), parts=parts)
-    else:
-        hidden = ACTS[act](call(x, (stacks["up"],), parts=parts))
-    parts, x = by_tiles(hidden.astype(tokens.dtype))
-    out = call(x, (stacks["down"],), parts=parts)
+    out = gm.grouped_ffn(
+        jnp.take(tokens, token_of_row, axis=0),
+        [stacks[name] for name in expert_names(ex)], items,
+        row_tile=row_tile, act=ACTS[act], interpret=interpret)
     # other chips' assignments lie nowhere in the layout
     delta = _back_to_tokens(jnp.zeros((t, d), jnp.float32), out,
                             jnp.where(local < count, laid_at, n_rows),

@@ -17,6 +17,7 @@ file is one worker's, and the two are the run's longest.
 import base64
 import dataclasses
 import hashlib
+import math
 import os
 import re
 
@@ -126,23 +127,25 @@ def _decode_attention(variant, batch, width):
     return build
 
 
-def _grouped_experts(stack, f, d, tokens, per_tok, n_experts, held):
-    """A decode step's grouped expert products (`expert._grouped`: the
-    sorted rows' parts, the two kernels, the way back) over a cell's stack
-    `[stack, held, ...]` of bfloat16 experts of `f` x `d`, `tokens` float32
-    rows each sent to `per_tok` of `n_experts`, at the row tile the step's
-    call takes."""
+def _grouped_experts(stack, f, d, tokens, per_tok, n_experts, held,
+                     act="silu"):
+    """A decode step's grouped expert layer (`expert._grouped`: the sorted
+    rows' gather, the kernel, the way back) over a cell's stack `[stack,
+    held, ...]` of bfloat16 experts of `f` x `d`, `tokens` float32 rows each
+    sent to `per_tok` of `n_experts`, at the row tile the step's call takes.
+    `act` "silu": SwiGLUs; another: experts of two matrices under it."""
     def build(on_chip):
         tile = expert.expert_tile(tokens, per_tok, n_experts)
         assert tile <= expert.GROUPED_RIDGE
 
-        def fn(rows, gate, up, down, layer, order, bounds, sorted_at, gates):
+        def fn(rows, ups, down, layer, order, bounds, sorted_at, gates):
             return expert._grouped(
-                rows, {"gate": gate, "up": up, "down": down}, layer, order,
+                rows, dict(ups, down=down), layer, order,
                 bounds, sorted_at, gates, sorted_at % (held + 1),
-                *expert.grouped_layout(tile), False)
+                *expert.grouped_layout(tile), False, act)
         wide = on_chip((stack, held, f, d), jnp.bfloat16)
-        return fn, [on_chip((tokens, d), jnp.float32), wide, wide,
+        ups = {"gate": wide, "up": wide} if act == "silu" else {"up": wide}
+        return fn, [on_chip((tokens, d), jnp.float32), ups,
                     on_chip((stack, held, d, f), jnp.bfloat16),
                     on_chip((), jnp.int32),
                     on_chip((tokens * per_tok,), jnp.int32),
@@ -163,7 +166,7 @@ KERNELS = {
     "attention_causal_32x4096x128": _attention(32, 4096, 128, causal=True),
     "int8_decode_attention_v1": _decode_attention(1, batch=16, width=1024),
     "int8_decode_attention_v2": _decode_attention(2, batch=16, width=256),
-    # the five sparse cells' decode steps: stack, expert, rows, router, held
+    # the six sparse cells' decode steps: stack, expert, rows, router, held
     "grouped_experts_lfm2": _grouped_experts(10, 1792, 2048, 128, 4, 32, 32),
     "grouped_experts_laguna": _grouped_experts(4, 512, 2048, 32, 8, 256,
                                                256),
@@ -171,6 +174,9 @@ KERNELS = {
                                                    256),
     "grouped_experts_keye": _grouped_experts(6, 768, 2048, 8, 8, 128, 128),
     "grouped_experts_kimi": _grouped_experts(4, 2048, 7168, 32, 8, 384, 12),
+    # experts of two matrices in the token's latent of 1,024
+    "grouped_experts_nemotron": _grouped_experts(5, 2688, 1024, 128, 22, 512,
+                                                 128, act="relu2"),
 }
 
 
@@ -179,6 +185,28 @@ def test_kernel_compiles_for_v5e(name, on_chip):
     fn, shapes = KERNELS[name](on_chip)
     compiled = jax.jit(fn).lower(*shapes).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", sorted(
+    name for name in KERNELS if name.startswith("grouped_experts")))
+def test_a_steps_experts_are_one_kernel_and_nothing_laid_out(name, on_chip):
+    """The expert layer of a step is ONE Mosaic call (until PR 50 two, the
+    hidden in HBM between them), and around it nothing is as large as the
+    laid rows but those rows (float32 `[rows, K]` in, `[rows, D]` out): no
+    hidden `[rows, F]` and no buffer of a row's three bfloat16 parts, which
+    the kernel makes in VMEM (they were XLA passes over every laid row,
+    visited or not: 2.1 ms of nemotron's 28.1 ms step; PERF.md, PR 50)."""
+    fn, shapes = KERNELS[name](on_chip)
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    (tokens, k), f = shapes[0].shape, shapes[1]["up"].shape[2]
+    made = {(kind, tuple(int(n) for n in dims.split(",")))
+            for kind, dims in re.findall(r"\b(f32|bf16)\[([\d,]+)\]", text)}
+    laid = max(dims[0] for kind, dims in made
+               if kind == "f32" and dims[1:] == (k,))
+    assert laid > tokens and ("f32", (laid, f)) not in made
+    assert not [dims for kind, dims in made
+                if kind == "bf16" and 3 in dims and math.prod(dims) > laid]
 
 
 @pytest.fixture(autouse=True)
@@ -218,16 +246,19 @@ def program_digest(lowered) -> str:
 # (902c6f1) for the described v5e: the five other sparse families' step
 # programs at their cells' shapes and keye's and MiniCPM-SALA's widest span
 # programs with the masked-attention kernel in them. A PR that means to
-# change one of them replaces its line; one that does not has moved it
+# change one of them replaces its line; one that does not has moved it.
+# PR 50 replaced the four expert families' steps (one grouped kernel an
+# expert layer where there were two and the passes between them); the
+# step without experts and the two spans are 902c6f1's still
 HELD = {
     "keye-step":
-        "b89d35925b38a386b1e753689b1801900fc518253b24aa5bb8823183a278903b",
+        "45ca775948fd5eae5c9d3d63581f70342a75d1d2a992911cad38db009bb6bc26",
     "kimi-step":
-        "cb8b5ae52365ef44bb9372bb6afb52ba9edfdb066e98993a0f1fa5aba71b07c4",
+        "396ad2b254209424d7e9099982f3777c38afd58844ba2a44cb80a03270bb0ed5",
     "lfm2-step":
-        "2e7edcaae8c5fa5cba6b8111b9e7d328812b828bcbb3971dc36c2c337423df12",
+        "0283eb1b0f8c7cc85221583736a18a88bbfb918104911fb9557ee2f5d3dc143c",
     "laguna-step":
-        "2efceb8d2ac9c77c1e195dbc0e0ba577f9f40c2463b6465e2e135bd0ed2ed155",
+        "a1f55e75df3b59293def2571a07dee74953226d08692db1abec6edeaa70f9e64",
     "minicpm-sala-step":
         "d598b02c91be9c5def6972e328f1dd3e9b5d62fc5bbeb7d218bf2f221d574f7e",
     "keye-span-kernel":

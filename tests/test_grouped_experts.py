@@ -1,6 +1,8 @@
-"""The expert layer's grouped kernels (`ops/grouped_matmul.py`) against its
-tile loop (`parallel/expert.py`): the same call both ways, the kernel in
-interpret mode, and the rule that says which calls take which.
+"""The expert layer's grouped kernel (`ops/grouped_matmul.py`: up, activation
+and down in one walk of the touched experts) against its tile loop
+(`parallel/expert.py`): the same call both ways, the kernel in interpret
+mode, the kernel alone against `_expert_ffn` a group, and the rule that
+says which calls take which.
 
 The CPU backend keeps every call on the loop (`expert._grouped_mode` is None
 here); the tests put "interpret" there. What Mosaic makes of the cells'
@@ -16,7 +18,7 @@ import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
 from pipeedge_tpu.models import registry
-from pipeedge_tpu.models.layers import TransformerConfig
+from pipeedge_tpu.models.layers import TransformerConfig, _three_parts
 from pipeedge_tpu.ops import grouped_matmul
 from pipeedge_tpu.parallel import expert
 from pipeedge_tpu.utils import jax_compat
@@ -133,14 +135,14 @@ def test_the_grouped_kernels_are_the_loop(family, weights, monkeypatch):
 
 
 def test_a_single_bfloat16_pass_would_fail_the_tolerance(monkeypatch):
-    """What the tolerance is for: rows rounded to bfloat16 before the
-    kernel (one part where `exact_dot` has three) are a hundred times off."""
+    """What the tolerance is for: rows rounded to bfloat16 in the kernel
+    (one part where `exact_dot` has three) are a hundred times off."""
     rows, *shape = STEPS["lfm2"]
     cfg, params = _layer(*shape, jnp.bfloat16)
     x = _rows(rows, shape[5])
     (wanted, _), _ = _both_ways(cfg, params, x, monkeypatch)
-    monkeypatch.setattr(expert, "_three_parts",
-                        lambda rows, dtype: rows.astype(dtype)[None])
+    monkeypatch.setattr(grouped_matmul, "row_parts",
+                        lambda rows, dtype: (1, rows.astype(dtype)))
     got, _ = jax.jit(lambda p, y: expert.topk_ffn_delta(p, y, cfg))(params, x)
     span = float(np.max(wanted) - np.min(wanted))
     assert np.max(np.abs(got - wanted)) > 20 * TOLERANCE * span
@@ -278,10 +280,127 @@ def test_the_kernel_leaves_unowned_rows_alone_and_the_layer_is_finite(
     assert counts[0] < rows * shape[1] and np.all(np.isfinite(got))
     bounds = jnp.asarray([0, 3, 3, 7])
     items = grouped_matmul.group_items(bounds[:-1], bounds[1:], 0, 16, 3)
-    out = grouped_matmul.grouped_matmul(
-        jnp.ones((16, 8)), (jnp.ones((3, 8, 8)),), items, row_tile=16,
-        interpret=True)
-    assert np.all(np.asarray(out[:7]) == 8) and np.all(np.isnan(out[7:]))
+    out = grouped_matmul.grouped_ffn(
+        jnp.ones((16, 8)), (jnp.ones((3, 8, 8)), jnp.ones((3, 8, 8))), items,
+        row_tile=16, act=expert.ACTS["relu2"], interpret=True)
+    # eight hidden values of 8 squared, added up
+    assert np.all(np.asarray(out[:7]) == 512) and np.all(np.isnan(out[7:]))
+
+
+def _kernel_alone(sizes, row_tile, f, k, d, form, weights, rows_dtype,
+                  seed=3):
+    """The kernel over packed groups of `sizes` rows -> (its rows, what
+    `_expert_ffn` gives each group's rows, which rows a group owns)."""
+    rng = np.random.default_rng(seed)
+    act, gated = form
+    bounds = np.concatenate([[0], np.cumsum(sizes)])
+    n_rows = -(-int(bounds[-1]) // row_tile) * row_tile
+
+    def w(*shape):
+        return jnp.asarray(rng.normal(size=shape) / math.sqrt(shape[-1]),
+                           jnp.float32).astype(weights)
+    ex = {"up": w(len(sizes), f, k), "down": w(len(sizes), d, f)}
+    if gated:
+        ex["gate"] = w(len(sizes), f, k)
+    x = jnp.asarray(rng.normal(size=(n_rows, k)), rows_dtype)
+    items = grouped_matmul.group_items(
+        jnp.asarray(bounds[:-1]), jnp.asarray(bounds[1:]), 0, row_tile,
+        grouped_matmul.max_items(n_rows, len(sizes), row_tile))
+    got = grouped_matmul.grouped_ffn(
+        x, [ex[name] for name in expert.expert_names(ex)], items,
+        row_tile=row_tile, act=expert.ACTS[act], interpret=True)
+    wanted = np.full((n_rows, d), np.nan, np.float32)
+    for g in range(len(sizes)):
+        wanted[bounds[g]:bounds[g + 1]] = expert._expert_ffn(
+            x[bounds[g]:bounds[g + 1]],
+            {name: leaf[g] for name, leaf in ex.items()}, act)
+    return np.asarray(got), wanted, ~np.isnan(wanted[:, 0])
+
+
+@pytest.mark.parametrize("weights, rows_dtype", [
+    ("bfloat16", "float32"), ("float32", "float32"),
+    ("bfloat16", "bfloat16")])
+@pytest.mark.parametrize("form", [("relu2", False), ("silu", False),
+                                  ("silu", True)])
+def test_the_kernel_is_an_experts_ffn_in_each_of_its_forms(form, weights,
+                                                           rows_dtype):
+    """Experts of two matrices under either activation and SwiGLUs, in
+    `exact_dot`'s three cases: three parts, `HIGHEST`, one narrow pass (the
+    hidden rounded to the rows' bfloat16 between the products, as
+    `_expert_ffn` rounds it)."""
+    got, wanted, owned = _kernel_alone(
+        [5, 0, 16, 3], 16, 48, 32, 40, form, jnp.dtype(weights),
+        jnp.dtype(rows_dtype))
+    assert np.sum(owned) == 24
+    span = float(np.max(wanted[owned]) - np.min(wanted[owned]))
+    np.testing.assert_allclose(
+        got[owned], wanted[owned], rtol=0,
+        atol=(2e-2 if rows_dtype == "bfloat16" else TOLERANCE) * span)
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 4])
+def test_three_groups_in_one_packed_tile_each_keep_their_rows(blocks,
+                                                              monkeypatch):
+    """Groups of 5, 4 and 6 rows in one row tile of 16 (three visits, then
+    a fourth group in the next tile), the hidden of 512 whole and cut in
+    two and four blocks: a visit's blocks add up in the output block where
+    it lies, and the rows that earlier visits of the tile wrote stay."""
+    f, k, d = 512, 128, 256
+    monkeypatch.setattr(grouped_matmul, "BLOCK_BYTES",
+                        (f // blocks) * (k + d) * 2)
+    assert grouped_matmul.column_block(f, k + d, 2) == f // blocks
+    got, wanted, owned = _kernel_alone(
+        [5, 4, 6, 9], 16, f, k, d, ("relu2", False), jnp.bfloat16,
+        jnp.float32)
+    assert list(np.flatnonzero(~owned)) == list(range(24, 32))
+    _close(got[owned], wanted[owned])
+    assert np.all(np.isnan(got[~owned]))
+
+
+def test_a_hidden_cut_in_blocks_is_the_whole_one_reordered(monkeypatch):
+    """The one difference the blocks may make: `down`'s float32 sums a
+    block at a time. Against the hidden whole that is a few units in the
+    last place of the largest value, and nothing like a lost part."""
+    f, k, d = 512, 128, 128
+    shape = ([7, 16, 2], 16, f, k, d, ("silu", True), jnp.bfloat16,
+             jnp.float32)
+    whole, _, owned = _kernel_alone(*shape)
+    monkeypatch.setattr(grouped_matmul, "BLOCK_BYTES", 128 * (2 * k + d) * 2)
+    assert grouped_matmul.column_block(f, 2 * k + d, 2) == 128
+    cut, _, _ = _kernel_alone(*shape)
+    span = float(np.max(whole[owned]) - np.min(whole[owned]))
+    gap = float(np.max(np.abs(cut[owned] - whole[owned])))
+    assert 0 < gap < 1e-6 * span
+
+
+@pytest.mark.parametrize("scale", [1.0, 1e-30, 3e38, 2.0 ** -130])
+def test_the_parts_made_in_the_kernel_are_three_parts_bit_for_bit(scale):
+    """`row_parts` rounds on the bits what `_three_parts` rounds with
+    `reduce_precision`: the same three bfloat16 values a number, inside a
+    kernel and outside, at the ends of the range too (a part that rounds up
+    to infinity, subnormal remainders), and ties go to even."""
+    from jax.experimental import pallas as pl
+    x = np.random.default_rng(7).normal(size=(16, 256)).astype(np.float32)
+    x[0, :4] = [1.00390625, 1.01171875, -1.00390625, 0.0]   # ties
+    with np.errstate(over="ignore"):      # 3e38: some of them infinite
+        x = jnp.asarray(x * np.float32(scale))
+    wanted = np.asarray(_three_parts(x, jnp.bfloat16).astype(jnp.float32))
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = grouped_matmul.row_parts(x_ref[...], jnp.bfloat16)[1]
+    in_kernel = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((48, 256), jnp.bfloat16),
+        interpret=True)(x)
+    parts, outside = jax.jit(
+        lambda y: grouped_matmul.row_parts(y, jnp.bfloat16))(x)
+    assert parts == 3
+    for got in (in_kernel, outside):
+        got = np.asarray(got.astype(jnp.float32)).reshape(3, 16, 256)
+        np.testing.assert_array_equal(got.view(np.uint32),
+                                      wanted.view(np.uint32))
+    if scale == 1.0:    # three parts are three: the second is not zero
+        assert np.count_nonzero(wanted[1]) > 4000
+        assert np.all(wanted[0, 0, :3] == [1.0, 1.015625, -1.0])
 
 
 @pytest.mark.parametrize("sizes, row_tile", [
@@ -326,9 +445,11 @@ def test_short_tables_are_read_by_comparison(shape):
 
 
 @pytest.mark.parametrize("n, k, wanted", [
-    (1792, 2048, 896), (2048, 1792, 1024), (512, 2048, 512),
-    (2048, 512, 2048), (768, 2048, 768), (2048, 7168, 256),
-    (7168, 2048, 1024), (48, 32, 48)])
+    # the six cells' hidden, a column of it `gate`'s, `up`'s and `down`'s:
+    # lfm2, laguna and qwen3-next, keye, kimi (the one that is cut), nemotron
+    (1792, 3 * 2048, 1792), (512, 3 * 2048, 512), (768, 3 * 2048, 768),
+    (2048, 3 * 7168, 512), (2688, 2 * 1024, 2688),
+    (7168, 3 * 2048, 1792), (2048, 6 * 7168, 256), (48, 32, 48)])
 def test_a_matrix_block_is_whole_lanes_under_the_block_bytes(n, k, wanted):
     block = grouped_matmul.column_block(n, k, 2)
     assert block == wanted and n % block == 0
@@ -381,7 +502,7 @@ def _primitives(jaxpr, names):
 def test_a_step_walks_its_groups_in_kernels_and_a_span_in_the_loop(
         cell, monkeypatch):
     """At the cell's real widths (shapes only): the step's program holds
-    two kernels and no loop, no slice of the stack, no row update; the
+    one kernel and no loop, no slice of the stack, no row update; the
     span's holds the tile loop and no kernel. On a backend without Mosaic
     both hold the loop."""
     model, step, span = CELLS[cell]
@@ -416,7 +537,7 @@ def test_a_step_walks_its_groups_in_kernels_and_a_span_in_the_loop(
     assert on_cpu["while"] == 2 and on_cpu["pallas_call"] == 0
     monkeypatch.setattr(expert, "_grouped_mode", lambda: "mosaic")
     names = traced(step)
-    assert names["pallas_call"] == 2
+    assert names["pallas_call"] == 1
     assert not (names["while"] or names["dynamic_slice"]
                 or names["dynamic_update_slice"])
     # the loop's scans are two binary searches over index vectors (the
