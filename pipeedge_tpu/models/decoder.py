@@ -2,24 +2,23 @@
 laguna, minicpm_sala, nemotron_h): what a family is NOT is here, so that its module
 holds its mixers, its cache's leaves, its kinds of block and its key map,
 and imports no other family.
-
 - products with weights stored `[out, in]` (`lin`), in chunks of rows where
   the result is wide (`in_row_chunks`); the dense SwiGLU (`dense_ffn`) and
   the routed expert layer (`routed_experts`); either, as a cached block
   step's FFN, with the counts every family's `STATS` start with (`ffn`);
-- a decay's `exp` to an ulp, for a state that compounds it (`exp_ulp`);
-- queries in chunks whose scores stay under `SCORE_BYTES` (`query_chunk`,
-  `map_query_chunks`), one softmax over masked key parts (`attend_masked`);
-- the hooks of a family that embeds tokens alone and runs through the
-  cached decode path only (`token_hooks`);
-- loading: leaves stacked a run, placed one at a time (`assemble_shard`), and
-  the loaders (`loader`) of a family's `_assemble(cfg, shard_config, get, dt)`.
-
+- a decay's `exp` to an ulp (`exp_ulp`); queries in chunks whose scores stay
+  under `SCORE_BYTES` (`query_chunk`, `map_query_chunks`), one softmax over
+  masked key parts (`attend_masked`); the hooks of a family on the cached
+  decode path alone (`token_hooks`);
+- loading: leaves stacked a run as records of their parts, made and placed
+  one at a time (`stack`, `on_device`, `assemble_shard`), and the loaders
+  (`loader`) of a family's `_assemble(cfg, shard_config, get, dtype)`.
 It imports `layers`, `shard`, `ops/masked_attention.py`, `parallel/expert.py`
 (which imports `layers`); a block step's cache is `models/stage_cache.py`.
 """
 from __future__ import annotations
 
+import mmap
 from typing import Callable, Dict, Mapping, Tuple
 
 import jax
@@ -27,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ShardConfig
+from .. import telemetry
 from ..ops import masked_attention
 from ..parallel.expert import ACTS, topk_ffn_delta
 from .layers import TransformerConfig, exact_dot
@@ -295,36 +295,181 @@ def token_hooks(name: str, dtype, norm: Callable) -> Dict:
 
 # -- loading -------------------------------------------------------------------
 
+class Stacked:
+    """A `[n, ...]` host leaf that is not made yet: its parts, each a host
+    array or a `Stacked` itself (a layer's experts, then the run's blocks),
+    and the shape and dtype `np.stack` would give them. `on_device` makes
+    it, once, where it is placed; a family that computes on one on the host
+    gets an array from `np.asarray` (`__array__`)."""
+
+    __slots__ = ("parts", "shape", "dtype", "nbytes")
+
+    def __init__(self, parts):
+        self.parts = tuple(parts)
+        self.shape = (len(self.parts),) + tuple(self.parts[0].shape)
+        if any(tuple(part.shape) != self.shape[1:] for part in self.parts):
+            raise ValueError("all parts of a stack must have the same shape")
+        self.dtype = np.result_type(*(part.dtype for part in self.parts))
+        self.nbytes = self.dtype.itemsize * int(
+            np.prod(self.shape, dtype=np.int64))
+
+    def __array__(self, dtype=None, copy=None):
+        out = np.empty(self.shape, dtype or self.dtype)
+        _fill(out, self)
+        return out
+
+
+def _mapped(leaf: np.ndarray) -> bool:
+    """Whether a host array is a view of a mapped file (`registry.
+    _TimedReads`): its memory is an `mmap`'s, and copying it is the read."""
+    while isinstance(leaf, np.ndarray):
+        leaf = leaf.base
+    return isinstance(leaf, mmap.mmap)
+
+
+def _fill(out: np.ndarray, leaf) -> None:
+    """Copy a host leaf into `out`, part by part into its slot."""
+    if isinstance(leaf, Stacked):
+        for slot, part in enumerate(leaf.parts):
+            _fill(out[slot, ...], part)
+    else:
+        np.copyto(out, leaf)
+
+
+def _memory_order(leaf: np.ndarray) -> tuple:
+    """The axes of a host array from the one its memory steps slowest along
+    to the fastest: `range(ndim)` for a C-ordered array, (1, 0) for the
+    `.T` of one."""
+    return tuple(sorted(range(leaf.ndim),
+                        key=lambda axis: -abs(leaf.strides[axis])))
+
+
+class _Rooms:
+    """Two host buffers in which `on_device` makes its leaves by turns:
+    leaf n + 1 is made in the one while leaf n's transfer reads the other,
+    and leaf n + 2 takes leaf n's, which by then has been waited for.
+    Memory that was touched once stays mapped; a leaf made in memory fresh
+    from `np.empty` pays a page fault every 4 KiB on top of its copy. A
+    backend that kept a leaf's room as the leaf's own buffer (XLA's CPU
+    client does that to a host array on a 64-byte boundary, where the cast
+    changes nothing) keeps it: the next leaf of that turn gets a new one."""
+
+    def __init__(self):
+        self._rooms, self._leaves, self._turn = [None, None], [None, None], 0
+
+    def take(self, nbytes: int) -> np.ndarray:
+        """`nbytes` of the other room than the last leaf's."""
+        turn = self._turn = 1 - self._turn
+        room, leaf = self._rooms[turn], self._leaves[turn]
+        if room is None or room.nbytes < nbytes or self._kept(room, leaf):
+            other = self._rooms[1 - turn]
+            room = self._rooms[turn] = np.empty(
+                max(nbytes, 0 if other is None else other.nbytes), np.uint8)
+        return room[:nbytes]
+
+    def placed(self, leaf) -> None:
+        """`leaf` is what the device made of the room taken last."""
+        self._leaves[self._turn] = leaf
+
+    @staticmethod
+    def _kept(room: np.ndarray, leaf) -> bool:
+        try:
+            at = leaf.unsafe_buffer_pointer() - room.ctypes.data
+        except (AttributeError, TypeError, ValueError):    # a traced leaf
+            return True
+        return 0 <= at < room.nbytes
+
+
+def _host(leaf, rooms: _Rooms):
+    """A leaf as `_put` should be handed it: a `Stacked` or a view of the
+    weights file as ONE new array in a room of `rooms`, each byte copied
+    once, from where it lies and in the order it lies there (a kernel its
+    family turned `.T` fills an array that is the `.T` of a C-ordered one:
+    a copy between arrays whose strides differ runs at a fifth of one
+    between like ones); anything else (an array a family computed, a drawn
+    one, a traced value) as it is. Where the parts are views of the file
+    (the first says) the copy is the file's read, a `weights_read` span a
+    leaf."""
+    first = leaf
+    while isinstance(first, Stacked):
+        first = first.parts[0]
+    from_file = isinstance(first, np.ndarray) and _mapped(first)
+    if not isinstance(leaf, Stacked) and not from_file:
+        return leaf
+    lead = len(leaf.shape) - first.ndim
+    order = tuple(range(lead)) + tuple(
+        lead + axis for axis in _memory_order(first))
+    out = rooms.take(leaf.nbytes).view(leaf.dtype).reshape(
+        [leaf.shape[axis] for axis in order]).transpose(np.argsort(order))
+    if from_file:
+        with telemetry.startup("weights_read"):
+            _fill(out, leaf)
+    else:
+        _fill(out, leaf)
+    return out
+
+
+def _put(host) -> jax.Array:
+    """`jnp.asarray(host)`, but a host array whose memory runs in another
+    order than its axes goes over as it lies and is turned on the device,
+    where that is a pass at the HBM's speed: `jnp.asarray` would make it
+    C-ordered on the host first (keye's head, 622 MB, 5.2 s of a 20 s load:
+    `tools/bench_loader.py`, my chip run, PR 53)."""
+    if isinstance(host, np.ndarray) and not host.flags.c_contiguous:
+        order = _memory_order(host)
+        as_it_lies = host.transpose(order)
+        if as_it_lies.flags.c_contiguous:
+            return jnp.asarray(as_it_lies).transpose(np.argsort(order))
+    return jnp.asarray(host)
+
+
 def stack(leaves):
-    """One `[n, ...]` array of like leaves, on the host where they are host
-    arrays (the device never holds a layer twice)."""
-    return (np if isinstance(leaves[0], np.ndarray) else jnp).stack(leaves)
+    """One `[n, ...]` leaf of like leaves: of host arrays (and of stacks of
+    them) the record of its parts, `Stacked`, which `on_device` makes once
+    (the host never holds a layer twice, nor the device); of anything else
+    `jnp.stack`'s array."""
+    if isinstance(leaves[0], (np.ndarray, Stacked)):
+        return Stacked(leaves)
+    return jnp.stack(leaves)
 
 
 def on_device(params, dtype, float32: tuple = ()):
-    """Host leaves onto the device in `dtype`, one at a time and each
-    waited for: transfers are asynchronous, and unfenced every leaf's
-    float16 copy from the file would sit on the device beside the
-    converted model (16.8 GB of a 16.9 GB chip, my chip run, PR 27).
+    """Host leaves onto the device in `dtype`, one at a time, each made on
+    the host (`_host`) just before. The fence trails by one leaf: leaf n's
+    transfer and cast are started, leaf n + 1 is made on the host
+    meanwhile, and only then is leaf n waited for, so the link and the
+    chip's cast work while the host copies. A fence there must be:
+    transfers are asynchronous, and unfenced every leaf's float16 copy from
+    the file would sit on the device beside the converted model (16.8 GB of
+    a 16.9 GB chip, my chip run, PR 27); trailing by one, one leaf's
+    float16 copy is there at a time (twice while a turned leaf is turned,
+    `_put`), and the last leaf is waited for before this returns.
     `float32`: the leaves the family keeps in float32 as published (a
     router's correction bias), each the last keys of its path."""
     flat, tree = jax.tree_util.tree_flatten_with_path(params)
-    out = []
+    out, rooms = [], _Rooms()
     for path, leaf in flat:
         keys = tuple(getattr(step, "key", None) for step in path)
         keep = any(keys[-len(last):] == last for last in float32)
-        out.append(jax.block_until_ready(jnp.asarray(leaf).astype(
-            jnp.float32 if keep else dtype)))
+        host = _host(leaf, rooms)
+        jax.block_until_ready(out[-1:])
+        out.append(_put(host).astype(jnp.float32 if keep else dtype))
+        if host is not leaf:
+            rooms.placed(out[-1])
+    jax.block_until_ready(out[-1:])
     return jax.tree_util.tree_unflatten(tree, out)
 
 
 def assemble_shard(shard_config: ShardConfig, get_embed, get_block,
                    get_final, dtype, kind=None, float32: tuple = ()) -> Dict:
-    """`build_shard_params` of getters whose leaves are host arrays: every
-    leaf stays one until its run of like blocks (`kind(block_id)`) is
-    stacked, then goes to the device (`on_device`). Traced values pass
-    through as well: `jax.eval_shape` over a family's `_assemble` with a
-    `get` of `jnp.zeros` gives a model's shapes without its values."""
+    """`build_shard_params` of getters whose leaves are host arrays, views
+    of the mapped weights file among them: every leaf stays what the getter
+    gave until its run of like blocks (`kind(block_id)`) is stacked, which
+    copies nothing (`stack`: a `Stacked` of the blocks' leaves, themselves
+    `Stacked` where a layer's experts are), then is made and goes to the
+    device (`on_device`). Traced values pass through as well:
+    `jax.eval_shape` over a family's `_assemble` with a `get` of
+    `jnp.zeros` gives a model's shapes without its values."""
     return on_device(build_shard_params(
         shard_config, get_embed, get_block, get_final,
         stack=lambda blocks: jax.tree_util.tree_map(

@@ -10,16 +10,21 @@ ViT-Huge num_labels=21843 override is baked in (model_cfg.py:62-66).
 from __future__ import annotations
 
 import dataclasses
+import io
 import logging
 import os
+import struct
+import zipfile
 from collections.abc import Mapping
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from numpy.lib import format as npy_format
 
 from .. import telemetry
+from ..telemetry import metrics as prom
 from . import ShardConfig
 from .layers import TransformerConfig
 from .shard import make_shard_fn, unstack_blocks
@@ -479,35 +484,119 @@ def should_unroll_blocks(n_blocks: int) -> bool:
     return 0 < n_blocks <= UNROLL_BLOCKS
 
 
+_LOCAL_HEADER = struct.Struct("<4s22xHH")    # signature, name and extra lengths
+_MEMBERS = prom.REGISTRY.counter(
+    "pipeedge_weights_members_total",
+    "members of a weights file handed to a loader: mapped (a view of the "
+    "file's pages) or read (np.load's copy: a compressed, Fortran-order or "
+    "object member)")
+for _path in ("mapped", "read"):
+    _MEMBERS.declare(path=_path)
+
+
 class _TimedReads(Mapping):
-    """A weights file (`.npz`) whose every array is read under
-    `telemetry.startup("weights_read")` and counted into that phase's
-    bytes: what a family's `load_params` is handed as `weights`."""
+    """A weights file (`.npz`) as a family's `load_params` is handed it
+    (`weights`): a member is a read-only view of ONE `np.memmap` of the
+    file, found through the zip's directory, its local header and the
+    `.npy` header behind it. Nothing is read until a view is copied, so a
+    byte of an expert goes from the file's pages into the leaf it belongs
+    to (`decoder.on_device`) and nowhere else on the host; `np.load` takes
+    a stored member in pieces with a CRC32 over every byte and hands back a
+    copy (0.45-0.7 GB/s on the builders' host, PERF.md section 6, PR 35).
+
+    What decides is what the file shows, a member at a time: one that is
+    `ZIP_STORED` (`np.savez`'s), unencrypted, C-ordered and holds no
+    objects is mapped; any other (`np.savez_compressed`'s, a Fortran-order
+    or object array, a `.npy` version without a public header reader) is
+    read with `np.load` as before, CRC and all.
+    `pipeedge_weights_members_total{path}` says which. Either way the
+    member's bytes add to `pipeedge_startup_bytes_total{phase=
+    "weights_read"}` as it is handed out, each once; the phase's seconds
+    are the directory, the headers, a read member's read, and the copies
+    out of the map that `decoder.on_device` makes (a family that hands a
+    view straight to `jnp.asarray` has its pages read inside that transfer,
+    in `weights_place`).
+
+    The map is the block's: `__exit__` lets go of it, and it is unmapped
+    with the last view of it. A view is never a parameter, though: where
+    the CPU backend would keep one as its own buffer (it does that to a
+    host array on a 64-byte boundary), the member is copied out here, or a
+    file rewritten in place would change a live model (a slice of a view
+    that a family hands over unconverted can still fall on such a boundary:
+    the file of a live CPU model is not to be rewritten in place)."""
 
     def __init__(self, path: str):
         with telemetry.startup("weights_read"):
-            self._file = np.load(path)
+            self._path = path
+            self._read = None       # np.load's file, for what is not mapped
+            self._file = open(path, "rb")
+            try:
+                with zipfile.ZipFile(self._file) as directory:
+                    self._members = {
+                        info.filename.removesuffix(".npy"): info
+                        for info in directory.infolist()}
+                self._map = np.asarray(np.memmap(self._file, np.uint8, "r"))
+            except (OSError, ValueError, zipfile.BadZipFile):
+                self._file.close()
+                raise
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
         self._file.close()
+        if self._read is not None:
+            self._read.close()
+        self._map = None
+
+    def _view(self, info: zipfile.ZipInfo):
+        """The member `info` as a view of the map, or None where the file
+        does not hold it as an array's own bytes in C order."""
+        if info.compress_type != zipfile.ZIP_STORED or info.flag_bits & 1 \
+                or not info.filename.endswith(".npy"):
+            return None
+        # the directory has no offset of the data: the local header's name
+        # and extra field (NumPy writes a zip64 one) come first
+        self._file.seek(info.header_offset)
+        magic, name, extra = _LOCAL_HEADER.unpack(
+            self._file.read(_LOCAL_HEADER.size))
+        if magic != zipfile.stringFileHeader:
+            return None
+        start = self._file.seek(name + extra, os.SEEK_CUR)
+        header_of = {(1, 0): npy_format.read_array_header_1_0,
+                     (2, 0): npy_format.read_array_header_2_0}.get(
+            npy_format.read_magic(self._file))
+        if header_of is None:
+            return None
+        shape, fortran_order, dtype = header_of(self._file)
+        at = self._file.tell()
+        nbytes = dtype.itemsize * int(np.prod(shape, dtype=np.int64))
+        if fortran_order or dtype.hasobject \
+                or at + nbytes != start + info.file_size:
+            return None
+        return self._map[at:at + nbytes].view(dtype).reshape(shape)
 
     def __getitem__(self, key):
         with telemetry.startup("weights_read") as phase:
-            value = self._file[key]
+            value = self._view(self._members[key])
+            _MEMBERS.inc(path="read" if value is None else "mapped")
+            if value is None:
+                if self._read is None:
+                    self._read = np.load(self._path)
+                value = self._read[key]
+            elif value.ctypes.data % 64 == 0:
+                value = value.copy()
             phase.moved(value.nbytes)
         return value
 
     def __contains__(self, key):
-        return key in self._file
+        return key in self._members
 
     def __iter__(self):
-        return iter(self._file)
+        return iter(self._members)
 
     def __len__(self):
-        return len(self._file)
+        return len(self._members)
 
 
 def module_shard_factory(model_name: str, model_file: Optional[str],

@@ -296,9 +296,9 @@ def sunk_span(cat: str, name: str, sink) -> _Span:
 
 # where a process's set-up goes, in the order a serving process meets
 # them: `backend` (the first `jax.devices()`: the accelerator runtime's
-# start), `weights_read` (file to host arrays), `weights_place` (host arrays
-# to a stage's parameters as its builder keeps them: cast, stacking,
-# device_put), `programs` (constructing the stage programs; nothing
+# start), `weights_read` (the file's bytes out of its mapped pages, or read),
+# `weights_place` (host arrays to a stage's parameters as its builder keeps
+# them: cast, device_put, the wait), `programs` (constructing them; nothing
 # compiles here) and `service` (executor, admission, governor, the HTTP
 # socket). The first and the last are tools/serve.py's.
 STARTUP_PHASES = ("backend", "weights_read", "weights_place", "programs",
@@ -310,8 +310,8 @@ _STARTUP_SECONDS = metrics.REGISTRY.counter(
     "phases exclude each other, one opened inside another suspends it")
 _STARTUP_BYTES = metrics.REGISTRY.counter(
     "pipeedge_startup_bytes_total",
-    "bytes a set-up phase moved: weights_read, the host arrays read from "
-    "the weights file")
+    "bytes a set-up phase moved: weights_read, the weights file's arrays "
+    "handed to the loader, each once")
 for _phase in STARTUP_PHASES:
     _STARTUP_SECONDS.declare(phase=_phase)
 _STARTUP_BYTES.declare(phase="weights_read")
