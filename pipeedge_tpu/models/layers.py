@@ -23,7 +23,8 @@ class TransformerConfig:
     model_type: str              # the family: 'vit' | 'bert' | 'deit' |
     #                              'gpt2' | 'llama' | 'keye' | 'kimi' |
     #                              'qwen3_next' | 'lfm2' | 'laguna' |
-    #                              'minicpm_sala' | 'nemotron_h'
+    #                              'minicpm_sala' | 'nemotron_h' |
+    #                              'granite_hybrid'
     hidden_size: int
     num_hidden_layers: int       # transformer blocks (sublayers = 4x this)
     num_attention_heads: int
@@ -163,6 +164,16 @@ class TransformerConfig:
     moe_latent_size: int = 0
     shared_expert_width: int = 0
     expert_act: str = "silu"
+    # granite_hybrid family ("mamba" | "attention" in `layer_types`, each
+    # mixer followed by a SwiGLU of `intermediate_size`; the `ssm_*` fields
+    # as above): the Granite line's four published constants. The embedding
+    # times `scale_emb` (above), each residual branch times
+    # `residual_multiplier`, the attention's scores times
+    # `attention_multiplier` (in place of `head_dim**-0.5`), the logits over
+    # `logits_scaling`; 0 = none
+    residual_multiplier: float = 0.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 0.0
 
     @property
     def head_dim(self) -> int:

@@ -31,6 +31,7 @@ from .shard import make_shard_fn, unstack_blocks
 from . import bert as bert_mod
 from . import deit as deit_mod
 from . import gpt2 as gpt2_mod
+from . import granite_hybrid as granite_hybrid_mod
 from . import keye as keye_mod
 from . import kimi as kimi_mod
 from . import laguna as laguna_mod
@@ -220,12 +221,33 @@ def _nemotron_h(name, weights, hidden, pattern, heads, kv_heads, head_dim,
         expert_act="relu2", prefill_chunk=span))
 
 
+def _granite_hybrid(name, weights, hidden, pattern, heads, kv_heads, head_dim,
+                    ssm, dense_width, vocab, max_pos, multipliers, span):
+    ssm_heads, ssm_head_dim, state, groups, conv, chunk = ssm
+    embedding, residual, attention, logits = multipliers
+    blocks = len(pattern)
+    return ModelEntry(name, 4 * blocks, weights, granite_hybrid_mod,
+                      TransformerConfig(
+        model_type="granite_hybrid", hidden_size=hidden,
+        num_hidden_layers=blocks, num_attention_heads=heads,
+        num_kv_heads=kv_heads, attn_head_dim=head_dim,
+        intermediate_size=dense_width, layer_norm_eps=1e-5, vocab_size=vocab,
+        max_position_embeddings=max_pos, layer_types=_layer_types(pattern),
+        ssm_heads=ssm_heads, ssm_head_dim=ssm_head_dim, ssm_state=state,
+        ssm_groups=groups, conv_kernel=conv, linear_chunk=chunk,
+        scale_emb=embedding, residual_multiplier=residual,
+        attention_multiplier=attention, logits_scaling=logits,
+        prefill_chunk=span))
+
+
 # a pattern of mixers, one letter a block. LFM2's: c a gated short
 # convolution, a grouped-query attention (no interval: the last attention
 # comes early). Laguna's: f attention over every position, s over a window.
 # MiniCPM-SALA's: m MiniCPM4's block-sparse attention, l lightning attention.
 # Nemotron-H's, the published `hybrid_override_pattern` as it is, a letter a
-# SUBLAYER: M a Mamba-2 mixer, * an attention, E an expert layer
+# SUBLAYER: M a Mamba-2 mixer, * an attention, E an expert layer. Granite
+# 4.0-H's, its published `layer_types` in Nemotron-H's letters: M a Mamba-2
+# block, * an attention block, each with its SwiGLU
 def _layer_types(pattern: str) -> tuple:
     return tuple({"c": "conv", "a": "full_attention", "f": "full_attention",
                   "s": "sliding_attention", "m": "minicpm4",
@@ -327,6 +349,18 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
                 ssm=(128, 64, 128, 8, 4, 128), vocab=131072, max_pos=262144,
                 experts=512, expert_width=2688, latent=1024,
                 shared_width=5376, per_tok=22, span=64),
+    # granite-4.0-h-micro: 40 blocks of a mixer and a SwiGLU of 8,192, 36
+    # Mamba-2 mixers (64 heads of 64, a state of 128 a lane, ONE group of B
+    # and C) and 4 plain attentions (32 query and 8 KV heads of 64, no
+    # rotation) at blocks 5, 15, 25 and 35; the embedding times 12, the
+    # residual branches times 0.22, the scores times 1/64, the logits over
+    # 8; the head is the embedding. One chip holds it whole (6.38 GB)
+    _granite_hybrid("ibm-granite/granite-4.0-h-micro",
+                    "granite-4.0-h-micro.npz", 2048,
+                    "MMMMM*" + "MMMMMMMMM*" * 3 + "MMMM", 32, 8, 64,
+                    ssm=(64, 64, 128, 1, 4, 256), dense_width=8192,
+                    vocab=100352, max_pos=131072,
+                    multipliers=(12.0, 0.22, 0.015625, 8.0), span=64),
     # tiny synthetic models for fast tests / CI (not in the reference's list)
     _vit("pipeedge/test-tiny-vit", 8, "test-tiny-vit.npz", 32, 2, 4, 64, 5,
          patch=4, img=16),
@@ -377,6 +411,13 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
                 32, "MEMM*EME", 4, 2, 8, ssm=(4, 8, 8, 2, 4, 4), vocab=100,
                 max_pos=64, experts=8, expert_width=16, latent=16,
                 shared_width=24, per_tok=3, span=8),
+    # eight blocks of a mixer and a SwiGLU: Mamba-2 runs of two, three and
+    # one around two attention blocks (the first not first in the stage),
+    # ONE group of four heads, the scores at 1 / head_dim
+    _granite_hybrid("pipeedge/test-tiny-granite-hybrid",
+                    "test-tiny-granite-hybrid.npz", 32, "MM*MMM*M", 4, 2, 8,
+                    ssm=(4, 8, 8, 1, 4, 4), dense_width=64, vocab=100,
+                    max_pos=64, multipliers=(12.0, 0.22, 0.125, 8.0), span=8),
     _gpt2("pipeedge/test-tiny-moe", 8, "test-tiny-moe.npz", 32, 2, 4, 64,
           vocab=100, max_pos=64, n_experts=4, capacity_factor=4.0),
 ]}

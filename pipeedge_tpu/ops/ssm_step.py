@@ -1,7 +1,7 @@
 """One position of a Mamba-2 layer's recurrence as a Pallas kernel that
 updates the layer's state where it lies in the cache's stack.
 
-A decode step of `models/nemotron_h.py` is, a head of a row,
+A decode step of `models/mamba2.py` is, a head of a row,
   S' = a S + (dt x) B^T        [P, N]
   y  = S' C                    [P]
 and the state is the largest thing a step touches (537 MB a layer at 128
@@ -32,7 +32,7 @@ the sum over `N` (one lane reduction a vreg).
 
 **Precision**: float32 multiplications and sums on the vector unit, nothing
 through the matrix unit and no bfloat16 pass; the same three products and
-one sum an element as `nemotron_h.ssm_step`, the sum over `N` in the
+one sum an element as `mamba2.ssm_step`, the sum over `N` in the
 hardware's order instead of XLA's.
 """
 from __future__ import annotations

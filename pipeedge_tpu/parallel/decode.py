@@ -386,9 +386,11 @@ def attend_bucket(pos_next: int, max_len: int, floor: int = 64,
 # A span (and a step on a backend without Mosaic): every run that writes the
 # leaf puts its rows into the donated stack at its own layers as it leaves
 # them, a chain of updates of one buffer, each after the last reader of the
-# layers it writes, which XLA does in place. Each update is fenced to its
-# run's output (`optimization_barrier`): left free, the chip's compiler put a
-# Mamba-2 layer's update after the NEXT run's grouped kernels, and the cell's
+# layers it writes, which XLA does in place (since PR 54 a span's run does so
+# a block at a time, the stack its scan's carry: `body_placing`). Each run's
+# updates are fenced to its output (`optimization_barrier`): left free, the
+# chip's compiler put a Mamba-2 layer's update after the NEXT run's grouped
+# kernels, and nemotron_h's
 # eleven-run step program then computed other hidden states from its third
 # Mamba-2 layer on (0.26 of the logits' range from the float32 reference;
 # the same program with the tile loop for the kernels, or with the state
@@ -398,7 +400,7 @@ def attend_bucket(pos_next: int, max_len: int, floor: int = 64,
 # A step (PR 48): the run's blocks are called one after another, not
 # scanned, each told which leaves are placed (`LayerCache.placed`), and a
 # block may update its layer of such a leaf where it lies and hand back the
-# stack, which the next block takes: nemotron_h's state kernel
+# stack, which the next block takes: the Mamba-2 state kernel
 # (`ops/ssm_step.py`, the stack aliased in and out, one read and one write
 # of a layer where the update above and the `y` beside it were three). The
 # kernel's `y` feeds the residual, so data puts it before the next run's
@@ -448,16 +450,18 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
     of FFN: two runs, one leaf) the second kind's run follows the first's
     in it (`shares_layers`). The scan only READS the stacked cache (each block its layer's window) and stacks the blocks'
     new rows; one update a leaf then writes them where the donated buffer's
-    layout is the program's own. The stack must not be the scan's carry:
-    the TPU compiler lays a carried buffer out to suit the rows written
-    into it and copies the whole cache into and out of that layout around
-    the loop (PERF.md, PR 25). Block leaves named in `whole` are not
+    layout is the program's own. A stack of rows a position must not be
+    the scan's carry: the TPU compiler lays a carried buffer out to suit
+    the rows written into it and copies the whole cache into and out of
+    that layout around the loop (PERF.md, PR 25). Block leaves named in
+    `whole` are not
     scanned over either: the block step gets each as a `LayerSlice`. A
     `whole` leaf whose rows of its longest run are `WHOLE_IN_PLACE_BYTES` or
     more is written a run at a time, by every run, and not gathered
-    (`_written_by_run`); in a step the runs that own one are not scanned but
-    unrolled, and their blocks may write it themselves (the comment over
-    `WHOLE_IN_PLACE_BYTES`)."""
+    (`_written_by_run`): a span's run carries it through its scan and
+    writes a layer as its block leaves it; in a step the runs that own one
+    are not scanned but unrolled, and their blocks may write it themselves
+    (the comment over `WHOLE_IN_PLACE_BYTES`)."""
     runs = blocks.runs if isinstance(blocks, BlockRuns) else (blocks,)
     kinds = kinds or (None,) * len(runs)
     owner = leaf_owners(leaves)
@@ -476,7 +480,8 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
         if held:
             run = {name: leaf for name, leaf in run.items()
                    if name not in held}
-        mine = tuple(name for name in placed if name in view) if step else ()
+        owned = tuple(name for name in placed if name in view)
+        mine = owned if step else ()
 
         def block(y, bp, layer, view, held=held, first=first, mine=mine):
             if held:
@@ -490,8 +495,32 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
             y, bc = block(y, *xs, view)
             return y, bc.rows
 
+        def body_placing(carry, xs, view=view, first=first):
+            # a span of a run that owns placed leaves: the stacks are the
+            # scan's carry and each block's rows go into them at its layer
+            # as it leaves them (a layer whole, in the stack's own layout:
+            # nothing for the compiler to lay out anew, PR 25's copy was of
+            # rows a position), so no run's rows are gathered beside the
+            # stack: nine layers of granite_hybrid's state are 1.2 GB
+            y, stacks = carry
+            y, bc = block(y, *xs, dict(view, **stacks))
+            rows = dict(bc.rows)
+            at = (first + xs[1],)
+            stacks = {name: jax.lax.dynamic_update_slice(
+                buf, rows.pop(name)[None].astype(buf.dtype),
+                at + (0,) * (buf.ndim - 1)) for name, buf in stacks.items()}
+            return (y, stacks), rows
+
         n_blocks = _n_blocks(run)
-        if mine:
+        if owned and not step:
+            (x, stacks), new = jax.lax.scan(
+                body_placing, (x, {name: cache[name] for name in owned}),
+                (run, jnp.arange(n_blocks)))
+            # done before the next run (the comment over
+            # `WHOLE_IN_PLACE_BYTES`)
+            x, stacks = jax.lax.optimization_barrier((x, stacks))
+            cache = dict(cache, **stacks)
+        elif mine:
             # a step of a run that owns placed leaves: its blocks one after
             # another, not scanned, so that a block may update its layer of
             # such a leaf where it lies and hand the stack to the next (a

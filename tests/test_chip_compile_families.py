@@ -264,10 +264,10 @@ def test_nemotron_h_stage_program_compiles_for_v5e(span, last_only, on_chip,
     data orders the kernel itself; this holds a later compiler, kernel or
     family to it. A span keeps the update and its fence (its program has no
     Mosaic kernel to slip behind)."""
-    from pipeedge_tpu.models import nemotron_h
+    from pipeedge_tpu.models import mamba2
     from pipeedge_tpu.models.shard import kind_runs
     from pipeedge_tpu.parallel import decode
-    monkeypatch.setattr(nemotron_h, "_kernel_mode", lambda: "mosaic")
+    monkeypatch.setattr(mamba2, "_kernel_mode", lambda: "mosaic")
     entry = registry.get_model_entry(NEMOTRON_CELL)
     cfg = entry.config
     stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
@@ -315,3 +315,86 @@ def test_nemotron_h_stage_program_compiles_for_v5e(span, last_only, on_chip,
         assert not re.search(re.escape(leaf) + r"\S* copy\(", text)
     else:       # a span: the chunked form, five fenced updates, no kernel
         assert order == "UUUUU", order
+
+
+GRANITE_CELL = "ibm-granite/granite-4.0-h-micro"
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (64, True)])
+def test_granite_hybrid_stage_program_compiles_for_v5e(span, last_only,
+                                                       on_chip, monkeypatch):
+    """`granite4-h-micro.summary-batch` at its real size: the whole model,
+    40 blocks in nine runs (5, 9, 9, 9 and 4 Mamba-2 blocks around four
+    attention blocks), 64 rows, the 1,024 bucket; a decode step and one
+    span of the prefill, 64 positions. The resident bytes (6.38 GB of
+    weights with the tied table ONCE, 4.83 GB of Mamba-2 state and 0.12 GB
+    of tails in 36 layers, 1.07 GB of keys and values in FOUR) and the
+    program's temporaries have to fit one chip's 16 GB, and a second copy
+    of the state does not (`decode.WHOLE_IN_PLACE_BYTES`): a step's state
+    goes through the in-place kernel (`ops/ssm_step.py`), 36 calls in block
+    order each given the stack the one before handed back, no
+    `dynamic-update-slice` and no copy of the stack left; a span keeps one
+    fenced chain of updates a Mamba-2 run, the stack the scan's carry."""
+    import time
+
+    from pipeedge_tpu.models import mamba2
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode
+    monkeypatch.setattr(mamba2, "_kernel_mode", lambda: "mosaic")
+    entry = registry.get_model_entry(GRANITE_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    rows, max_len = 64, 1024
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    runs = kind_runs(entry.family.FAMILY, cfg, stage)
+    assert [count for _, count in runs] == [5, 1, 9, 1, 9, 1, 9, 1, 4]
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg), runs=runs))
+    assert cache["ssm_state"].shape == (36, rows, 64, 64, 128)
+    assert cache["k"].shape == (4, rows, max_len, 512)
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    # the head is the embedding's array: one argument of the program
+    params["final"]["head"]["w"] = params["embeddings"]["wte"]
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    started = time.monotonic()
+    lowered = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                         on_chip((), jnp.int32), read_len=max_len,
+                         last_only=last_only)
+    traced = time.monotonic()
+    compiled = lowered.compile()
+    print(f"granite-hybrid span {span}: traced and lowered in "
+          f"{traced - started:.1f} s, compiled in "
+          f"{time.monotonic() - traced:.1f} s")
+    assert cfg.prefill_chunk == 64
+    text = compiled.as_text()
+    memory = compiled.memory_analysis()
+    print(f"granite-hybrid {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * (max_len * 16384 + 36 * (2097152 + 52224))
+    assert memory.alias_size_in_bytes > cache_bytes     # updated in place
+    # 6.38 GB of weights and the table a second time: the head's argument is
+    # the embedding's array in a run, which a described shape cannot say
+    assert memory.argument_size_in_bytes < 6.39e9 + 0.42e9 \
+        + 1.02 * cache_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.6e9
+    leaf = f"f32[36,{rows},64,64,128]"
+    order = _updates_and_kernels(text, leaf)
+    # no second `ssm_state`, nor a run's nine layers of it (1.2 GB)
+    assert not re.search(re.escape(leaf) + r"\S* copy\(", text)
+    if span == 1:
+        # 0.12 GB: the unrolled step's 36 kernels, each the stack in and out
+        assert memory.temp_size_in_bytes < 0.4e9
+        assert order == "S" * 36, order
+    else:
+        # 1.57 GB where the runs' rows gathered beside the stack took 4.21:
+        # a span's run carries the stack through its scan and writes a layer
+        # as its block leaves it (`decode._run_blocks`), so the entry
+        # computation itself updates nothing and calls no kernel
+        assert memory.temp_size_in_bytes < 2.0e9
+        assert order == "" and "tpu_custom_call" not in text
+        assert "dynamic-update-slice" in text

@@ -16,8 +16,8 @@ import pytest
 
 from benchmark import costs_nemotron_h as costs, weights
 from benchmark.reference import nemotron_h as reference
-from pipeedge_tpu.models import (ShardConfig, decoder, nemotron_h, registry,
-                                 stage_cache)
+from pipeedge_tpu.models import (ShardConfig, decoder, mamba2, nemotron_h,
+                                 registry, stage_cache)
 from pipeedge_tpu.models.shard import BlockRuns, kind_runs, shard_apply
 from pipeedge_tpu.parallel import decode, expert
 from pipeedge_tpu.telemetry import metrics as prom
@@ -511,7 +511,7 @@ def test_a_steps_state_through_the_kernel_is_the_jnp_steps(
         "ssm_steps_fused", "ssm_positions_stepped"))
     out = []
     for mode in (None, "interpret"):
-        monkeypatch.setattr(nemotron_h, "_kernel_mode", lambda mode=mode: mode)
+        monkeypatch.setattr(mamba2, "_kernel_mode", lambda mode=mode: mode)
         pipe = decode.build_decode_pipeline(model, None, max_len=32)
         data, caches = pipe._prefill(jnp.asarray(ids[:, :prompt], jnp.int32))
         logits = [np.asarray(data[:, -1])]

@@ -1,20 +1,24 @@
-"""One Mamba-2 layer's state step alone at `nemotron3-super.reason-batch`'s
-shape: the jnp step behind a `dynamic_update_slice` of the donated stack
-(`models/nemotron_h.py::ssm_step`, what every backend without Mosaic runs)
+"""One Mamba-2 layer's state step alone at the shapes of the cells that
+run it: the jnp step behind a `dynamic_update_slice` of the donated stack
+(`models/mamba2.py::ssm_step`, what every backend without Mosaic runs)
 against the in-place kernel (`ops/ssm_step.py`).
 
-The evidence behind `ops/ssm_step.py::BLOCK_BYTES`. A stack of five layers'
-state `[5, rows, 128, 64, 128]` float32 (2.68 GB at 128 rows) is donated to
+The evidence behind `ops/ssm_step.py::BLOCK_BYTES`. A stack of layers' state
+`[L, rows, H, P, N]` float32 whose heads share B and C in `G` groups
+(`--stacks`, `L,rows,H,P,N/G` each: `nemotron3-super.reason-batch`'s five
+layers of 128 heads in 8 groups at 128 rows, 2.68 GB, a grid cell 4 rows of
+a group; `granite4-h-micro.summary-batch`'s 36 layers of 64 heads in ONE
+group at 64 rows, 4.83 GB, a grid cell one row's whole state) is donated to
 one program that moves every layer `--inner` positions on, each layer's `y`
 folded into the next call's `x` so that the calls stay in order; median of
 `--reps` programs, ms a layer call, and the state's bytes read once and
 written once over that time as a share of the chip's 819 GB/s. Then one
 position of both ways from the same stack: the largest gap of `y` and of
 the state as a share of their range (the ways differ by the order of the
-sum over N). Prints one JSON line a way.
+sum over N). Prints one JSON line a stack and way.
 
-Usage: python tools/bench_ssm_step.py [--rows 128] [--block-mib 0.5,1,2,4]
-    [--tiny]
+Usage: python tools/bench_ssm_step.py [--stacks 5,128,128,64,128/8;36,64,64,64,128/1]
+    [--block-mib 0.5,1,2,4] [--tiny]
 `--tiny` runs a small stack with the kernel in interpret mode (a rehearsal
 on the CPU: no time of it means anything).
 """
@@ -35,7 +39,7 @@ def _program(way, layers, inner, interpret):
     positions on, through `way` ("jnp" or "kernel")."""
     import jax
     import jax.numpy as jnp
-    from pipeedge_tpu.models import nemotron_h
+    from pipeedge_tpu.models import mamba2
     from pipeedge_tpu.models.decoder import exp_ulp
     from pipeedge_tpu.ops import ssm_step
 
@@ -47,7 +51,7 @@ def _program(way, layers, inner, interpret):
                 (dt[..., None] * x).reshape(b, g * per, hd), bm, cm,
                 interpret=interpret)
             return stack, y.reshape(x.shape)
-        y, new = nemotron_h.ssm_step(
+        y, new = mamba2.ssm_step(
             x, bm, cm, dt, la, stack[layer].reshape(x.shape + bm.shape[-1:]))
         return jax.lax.dynamic_update_slice(
             stack, new.reshape((1,) + stack.shape[1:]),
@@ -65,7 +69,9 @@ def _program(way, layers, inner, interpret):
 
 def main():
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    p.add_argument("--rows", type=int, default=128)
+    p.add_argument("--stacks", default="5,128,128,64,128/8;36,64,64,64,128/1",
+                   help="the stacks to time, `L,rows,H,P,N/G` each, `;` "
+                        "between them (default: the two cells')")
     p.add_argument("--block-mib", default="",
                    help="`ssm_step.BLOCK_BYTES` to try, in MiB (default: "
                         "the module's)")
@@ -75,14 +81,26 @@ def main():
     p.add_argument("--tiny", action="store_true")
     args = p.parse_args()
     import jax
-    import jax.numpy as jnp
-    import numpy as np
     from pipeedge_tpu.ops import ssm_step
     print("devices:", json.dumps({
         "platform": jax.devices()[0].platform,
         "kind": jax.devices()[0].device_kind, "count": jax.device_count()}))
-    layers, rows, groups, per, hd, n = (2, 2, 2, 2, 8, 8) if args.tiny \
-        else (5, args.rows, 8, 16, 64, 128)
+    blocks = [float(b) for b in args.block_mib.split(",") if b] or [
+        ssm_step.BLOCK_BYTES / 2 ** 20]
+    for spec in ("2,2,4,8,8/2;2,2,4,8,8/1" if args.tiny
+                 else args.stacks).split(";"):
+        shape, _, groups = spec.partition("/")
+        layers, rows, heads, hd, n = (int(size) for size in shape.split(","))
+        _time_stack(args, blocks, layers, rows, int(groups or 1), heads, hd,
+                    n)
+
+
+def _time_stack(args, blocks, layers, rows, groups, heads, hd, n):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from pipeedge_tpu.ops import ssm_step
+    per = heads // groups
     keys = jax.random.split(jax.random.PRNGKey(args.seed), 6)
     x = jax.random.normal(keys[0], (rows, groups, per, hd), jnp.float32)
     bm = jax.random.normal(keys[1], (rows, groups, n), jnp.float32)
@@ -96,8 +114,6 @@ def main():
     def fresh():
         return jax.random.normal(keys[4], shape, jnp.float32)
 
-    blocks = [float(b) for b in args.block_mib.split(",") if b] or [
-        ssm_step.BLOCK_BYTES / 2 ** 20]
     ways = [("jnp", None)] + [("kernel", mib) for mib in blocks]
     first = {}
     for way, mib in ways:
@@ -125,13 +141,14 @@ def main():
                 for name, got, wanted in zip(
                     ("state", "y"), first[way], first["jnp"])}
         print(json.dumps({
+            "stack": list(shape), "groups": groups,
             "way": way, "block_mib": mib, "rows": rows,
             "rows_a_cell": None if mib is None else ssm_step.row_tile(
                 rows, per * hd * n * 4),
             "ms_a_layer_call": round(1e3 * call_s, 4),
             "state_bytes_moved": 2 * layer_bytes,
             "hbm_share": round(2 * layer_bytes / call_s / HBM_BYTES_PER_S, 4),
-            "gap_to_jnp": gaps}))
+            "gap_to_jnp": gaps}), flush=True)
 
 
 if __name__ == "__main__":
