@@ -41,7 +41,7 @@ import numpy as np
 from . import ShardConfig
 from .layers import TransformerConfig, dense, rms_norm, rope_rotate
 from .shard import FamilySpec, build_shard_params
-from .stage_cache import attend, cache_update_and_read
+from .stage_cache import attend, attend_rows, cache_update_and_read
 
 SUBLAYER_PARAMS = {
     0: ("ln_before", "q", "k", "v"),
@@ -226,13 +226,31 @@ def sp_prefill_block_step(p: Dict, x, bcache, cfg: TransformerConfig,
             cache_gather(bcache, k_new, v_new))
 
 
+def rows_block_step(p: Dict, x, bcache, at, cfg: TransformerConfig,
+                    block: int):
+    """`cached_block_step` for one token a row, row r at `at.pos[r]` (the
+    served executor's step, parallel/decode_rows.py): each row's q and k
+    are rotated at its own position (the rows taken as one sequence of R
+    positions, which is what `rope_rotate` turns) and attend their own
+    slot of the cache."""
+    normed = rms_norm(p["ln_before"], x, cfg.layer_norm_eps)
+    rows = x.shape[0]
+    q, k_new, v_new = (
+        y.reshape((rows, 1) + y.shape[2:])
+        for y in _qkv_rope(p, normed.reshape(1, rows, -1), cfg, at.pos))
+    ctx, bcache = attend_rows(bcache, q, k_new, v_new, at, block, cfg,
+                              window=cfg.sliding_window)
+    return _block_tail(p, x, ctx, cfg), bcache
+
+
 FAMILY = FamilySpec(name="llama", embed=embed, sublayer=sublayer,
                     finalize=finalize, cached_block_step=cached_block_step,
                     decode_embed=decode_embed, span_embed=span_embed,
                     decoder_model=True, position_dependent_attention=True,
                     tp_cached_block_step=tp_cached_block_step,
                     tp_finalize=tp_finalize,
-                    sp_prefill_block_step=sp_prefill_block_step)
+                    sp_prefill_block_step=sp_prefill_block_step,
+                    rows_block_step=rows_block_step)
 
 
 def _a(x, dtype):

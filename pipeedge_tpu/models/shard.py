@@ -127,6 +127,11 @@ class FamilySpec:
     # `QuantizedTensor` as the payload's first tensor (the int8
     # stage-seam tunnel, parallel/pipeline.py + ops/int8_matmul.py)
     wire_subs: tuple = ()
+    # `cached_block_step` for one token a row, each row at its own position:
+    # (p, x, bcache, at: stage_cache.RowsAt, cfg, block) -> (x, bcache), around
+    # `stage_cache.attend_rows` (parallel/decode_rows.py: the served
+    # executor steps all running rows in one program where a family has it)
+    rows_block_step: Any = None
 
 
 def _apply_slice(family: FamilySpec, block_params: Dict, data: ShardData,

@@ -542,3 +542,112 @@ def cache_update_and_read(bcache: LayerCache, k_new: jax.Array,
         q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, (s, width), 0)
         keep &= k_pos > q_pos - window
     return (k, k_new), (v, v_new), (keep, keep_new), bcache
+
+
+# -- a step whose rows stand each at its own position ----------------------
+# The served executor steps every running request's row in one program
+# (`parallel/decode_rows.py`): row r is slot `base + r` of a stage-wide cache
+# and stands at `pos[r]`, -1 where the slot is dead (free, or not at this
+# stage in this tick). Such a step reads slots [base, base + R) of the plain
+# `k`, `v` pair and no window ladder: it walks the positions in blocks up to
+# the furthest live row (`reach`) under one online softmax, so one program a
+# count of rows serves every length and every place in the cache.
+
+class RowsAt(NamedTuple):
+    """Where the R rows of such a step stand, all traced: the first of
+    their slots, each row's position (0 where the row is dead, for reading
+    and embedding), and the furthest live position."""
+    base: jax.Array
+    pos: jax.Array
+    reach: jax.Array
+
+
+def attend_rows(bcache: LayerCache, q: jax.Array, k_new: jax.Array,
+                v_new: jax.Array, at: RowsAt, block: int,
+                cfg: TransformerConfig, window: int = 0,
+                names: tuple = ("k", "v"), precision=None):
+    """One decode step's attention for R rows at their own positions:
+    q [R,1,H,Dh] and the step's own k_new, v_new [R,1,G,Dh] against slots
+    [at.base, at.base + R) of this layer's cache, row r reading the
+    positions below `at.pos[r]` (inside its sliding `window`, where one is
+    set) and its own new row. -> (context [R,1,H*Dh], the cache with the
+    new rows recorded for `write_rows_at`).
+
+    The positions are walked `block` at a time up to `at.reach`, the
+    furthest live row's position, each block read as it is stored (`_scores`,
+    `_context`) and folded into a running maximum, sum and context: what a
+    shorter row does not hold is masked to exact zeros, and nothing past
+    it is read. A dead row (position 0) attends its own row alone."""
+    stack = bcache.stack
+    rows, _, h, hd = q.shape
+    k_buf, v_buf = (stack[name] for name in names)
+    held = k_buf.shape[2]
+    block = min(block, held)
+    # through the cache's dtype, as if read back from it
+    k_new = k_new.astype(k_buf.dtype).astype(q.dtype)
+    v_new = v_new.astype(v_buf.dtype).astype(q.dtype)
+    bcache = bcache._replace(rows={names[0]: _fold(k_new),
+                                   names[1]: _fold(v_new)})
+    below = at.pos[:, None, None, None]
+    scale = jnp.sqrt(jnp.float32(hd))
+    top = _scores(q, k_new, precision) / scale              # [R,H,1,1]
+    total = jnp.ones_like(top)
+    ctx = _context(total.astype(q.dtype), v_new, hd, precision)
+
+    def fold(j, carry):
+        top, total, ctx = carry
+        # the last block of a cache that is no whole number of blocks
+        # starts early, and leaves what the block before it held to it
+        first = j * block
+        start = jnp.minimum(first, held - block)
+        k, v = (jax.lax.dynamic_slice(
+            buf, (bcache.layer, at.base, start, 0),
+            (1, rows, block, buf.shape[3]))[0].astype(q.dtype)
+            for buf in (k_buf, v_buf))
+        k_pos = start + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, 1, block), 3)
+        keep = (k_pos >= first) & (k_pos < below)
+        if window:
+            keep &= k_pos > below - window
+        part = jnp.where(keep, _scores(q, k, precision) / scale, -1e30)
+        new_top = jnp.maximum(top, jnp.max(part, axis=-1, keepdims=True))
+        probs = jnp.exp(part - new_top)
+        shrink = jnp.exp(top - new_top)
+        total = total * shrink + jnp.sum(probs, axis=-1, keepdims=True)
+        ctx = ctx * jnp.transpose(shrink, (0, 2, 1, 3)) \
+            + _context(probs.astype(q.dtype), v, hd, precision)
+        return new_top, total, ctx
+
+    n_blocks = (jnp.minimum(at.reach, held) + block - 1) // block
+    top, total, ctx = jax.lax.fori_loop(0, n_blocks, fold,
+                                        (top, total, ctx))
+    ctx = ctx / jnp.transpose(total, (0, 2, 1, 3))
+    return ctx.astype(q.dtype).reshape(rows, 1, h * hd), bcache
+
+
+def write_rows_at(cache: Cache, rows: Cache, base, pos: jax.Array) -> Cache:
+    """Every layer's new `rows` (leaves `[L, R, 1, ...]`) into the stacked
+    cache, row r into slot `base + r` at position `pos[r]`: one in-place
+    update a leaf a row, `[L, 1, 1, ...]` at `(0, base + r, pos[r])`, as
+    `write_rows` makes one for all rows at one position, in a loop over the rows (unrolled, 48
+    rows were 290 operations and 0.4 s more of every set-up to lower; a
+    step's time is the same: my chip runs, PR 55). A dead row (`pos`
+    negative) puts back what its slot held at position 0. Not one scatter a
+    leaf: for a scatter the chip's compiler picks a layout of its own for
+    the whole stack and copies 2.4 GB of gpt2-medium's 48 slots into it and
+    back, twice a leaf a step (`tests/test_chip_compile.py` holds the step
+    to no such copy, and the loop's carry to the stack's own layout)."""
+    def write(buf, new):
+        tail = (0,) * (buf.ndim - 3)
+        new = new.astype(buf.dtype)
+
+        def one(r, buf):
+            at = (0, base + r, jnp.maximum(pos[r], 0)) + tail
+            row = jax.lax.dynamic_slice_in_dim(new, r, 1, axis=1)
+            held = jax.lax.dynamic_slice(buf, at, row.shape)
+            return jax.lax.dynamic_update_slice(
+                buf, jnp.where(pos[r] >= 0, row, held), at)
+
+        return jax.lax.fori_loop(0, new.shape[1], one, buf)
+
+    return {name: write(buf, rows[name]) for name, buf in cache.items()}

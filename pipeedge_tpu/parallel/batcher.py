@@ -10,14 +10,38 @@ compiled stage programs.
 
 TPU-first constraints drive the design:
 
-- **Static shapes preserved**: each request keeps its OWN per-stage cache
-  slots (created at admission, freed at completion), so the compiled
-  prefill/decode programs are exactly DecodePipeline's — one program per
-  (batch, prompt-shape) signature, shared by every request with that
-  signature, and token-for-token identical to a solo `generate()` run.
-  There is no cross-request padding or masking to invalidate shapes.
+- **Static shapes preserved**: a request's prompt pass runs alone, at its
+  own length, on a cache of its own, through exactly DecodePipeline's
+  prefill and span programs — one program per (batch, prompt-shape)
+  signature, shared by every request with that signature.
+- **The rows that step together**: each stage holds ONE cache of
+  `max_active` row slots, made once (`decode_rows.StageRows`). A request
+  takes a slot a row at admission (lowest free first); the cache of its
+  own that its prompt pass fills is made as that pass goes out at stage 0,
+  and its rows are copied into the slots as the pass leaves each stage, so
+  a burst admitted together holds one such cache a stage in flight, not
+  one a request. From then on every row that stands at a decode step of a
+  stage goes out as ONE program with a position a row, dead slots computed
+  and discarded, over the least rung of rows that spans the live slots
+  wherever they lie (`parallel/decode_rows.py`). The program picks
+  (greedy) and keeps the next tokens on the device, so step n + 1 is
+  dispatched before step n's tokens are read; the thread that ticks reads
+  each step's tokens back once and hands host integers to every request's
+  `on_token`. Rows join and leave between steps: a cap, every row's eos, a
+  cancel or an expiry frees the slots as the tokens are read, a step after
+  they were picked, and a step sent meanwhile is discarded. Token for token what a solo
+  `generate()` gives, the rows being independent. Rows batch where the
+  code can see that they may: the stages' programs take row positions
+  (`decode_rows.rows_block_fn`: the plain dense block and llama's; a
+  family that names its cache leaves has one `pos` a program), the cache
+  is not paged (`kv is None`), the request is greedy and fits the slots.
+  Every other request keeps a cache of its own per stage and one dispatch
+  a stage-step, as all did before: a sampled request (its picks split its
+  own key over its own rows), the paged backend's, a family's whose
+  program takes one position.
 - **Wave scheduling, host-driven**: the scheduler advances one "tick" at a
-  time; per tick each stage dispatches at most one request's stage-step.
+  time; per tick each stage dispatches at most one program: one request's
+  prompt pass or stage-step, or the step of every row that holds a slot.
   Stages are processed back-to-front so a request advances exactly one
   stage per tick (and a token finishing at the last stage re-enters stage
   0 within the same tick — no idle gap). JAX dispatch is asynchronous, so
@@ -50,7 +74,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,8 +83,9 @@ import numpy as np
 from .. import telemetry
 from ..telemetry import metrics as prom
 from ..utils.threads import make_condition
-from .decode import (DecodePipeline, _repeat_batch, make_next_picker,
-                     validate_capacity)
+from .decode import (M_ATTEND, DecodePipeline, _repeat_batch,
+                     make_next_picker, validate_capacity)
+from .decode_rows import StageRows, rows_block_fn
 
 # iteration-level scheduling counters (docs/OBSERVABILITY.md): one family
 # per event. The `executor` label has one value; it stays because scrapes
@@ -73,8 +98,25 @@ M_CHUNKS = prom.REGISTRY.counter(
     "pipeedge_prefill_chunks_total",
     "prompt chunks dispatched by the chunked-prefill scheduler, "
     "by executor")
+M_ROWS = prom.REGISTRY.counter(
+    "pipeedge_decode_step_rows_total",
+    "rows of the decode steps that went out as one program for every "
+    "running row: kind=live the rows that stood at the step, kind=slots "
+    "the rows its program computed (its rung)")
 M_STEPS.declare(executor="wave")
 M_CHUNKS.declare(executor="wave")
+for _kind in ("live", "slots"):
+    M_ROWS.declare(kind=_kind)
+
+
+class _Rows(NamedTuple):
+    """One dispatch of the rows that step together, from stage 0 to the
+    last: the requests whose rows stand at it, the first slot of the rung
+    its program computes, and the position of each slot of the rung (-1:
+    dead; both go to the device with each stage's call)."""
+    reqs: list
+    base: int
+    pos: np.ndarray
 
 
 def _sched_mark(name: str, rid) -> None:
@@ -110,7 +152,7 @@ class _Request:
     deadline: Optional[float] = None
     expired: bool = False            # the deadline check tripped
     rows_done: Optional[np.ndarray] = None   # [B] eos seen per row
-    caches: Optional[List] = None    # per-stage cache slots (admission)
+    caches: Optional[List] = None    # per-stage caches (`_seed_caches`)
     # paged-KV plane (pipeedge_tpu/kv): page tables + sharing state when
     # a PagedKvBackend drives this request instead of dense cache slots
     kvstate: Optional[Dict] = None
@@ -134,13 +176,23 @@ class _Request:
     # the last picked token as [B, 1] ids, made by the pick's own program:
     # the next decode step's input
     step_ids: Optional[jax.Array] = None
+    # the rows that step together (greedy, on a stage that takes row
+    # positions): the slots of the stage-wide cache this request's rows
+    # hold from admission to completion, and the tokens whose pick has been
+    # DISPATCHED. `tokens` then holds host integers, appended as the worker
+    # reads each step back, a step behind `sent`
+    greedy: bool = True
+    slots: Optional[List[int]] = None
+    sent: int = 0
+    done: bool = False
 
     @property
     def pos(self) -> int:
         """Cache position for the NEXT decode wave: the wave that produces
-        token len(tokens)+1 attends through position prompt_len +
-        len(tokens) - 1 (mirrors DecodePipeline.generate's pos)."""
-        return self.prompt_len + len(self.tokens) - 1
+        token n+1 attends through position prompt_len + n - 1 (mirrors
+        DecodePipeline.generate's pos), n the tokens picked so far."""
+        picked = len(self.tokens) if self.slots is None else self.sent
+        return self.prompt_len + picked - 1
 
 
 def _build_request(pipe: DecodePipeline, rid, ids, new_tokens: int,
@@ -181,19 +233,22 @@ def _build_request(pipe: DecodePipeline, rid, ids, new_tokens: int,
         pad_token=eos_token if pad_token is None else pad_token,
         on_token=on_token, cancel=cancel,
         deadline=None if deadline is None else float(deadline),
-        shipped=shipped)
+        shipped=shipped, greedy=temperature <= 0.0)
 
 
-def _seed_caches(pipe: DecodePipeline, req: _Request) -> str:
-    """Create the request's per-stage cache slots and return its prompt
-    pass kind: a prefix-seeded request's suffix runs as one SPAN at the
-    prefix offset (prompt caching); otherwise a fresh prefill."""
-    if req.prefix is not None:
-        req.caches = [_repeat_batch(c, req.ids.shape[0])
-                      for c in req.prefix["caches"]]
-        return "span"
-    req.caches = pipe._fresh_caches(req.ids.shape[0])
-    return "prefill"
+def _seed_caches(pipe: DecodePipeline, req: _Request) -> None:
+    """`exec/seed`: create the request's per-stage caches, as its prompt
+    pass goes out at stage 0 and not at admission: a request that holds
+    slots gives each up again as its prompt leaves that stage
+    (`StageRows.install`), so of a burst admitted together only the few
+    whose prompt is on its way hold one (100 MB a row at gpt2-medium's
+    1,024 positions), not all of them."""
+    with telemetry.span("exec", "seed", rid=str(req.rid)):
+        if req.prefix is not None:
+            req.caches = [_repeat_batch(c, req.ids.shape[0])
+                          for c in req.prefix["caches"]]
+        else:
+            req.caches = pipe._fresh_caches(req.ids.shape[0])
 
 
 def _next_chunk(req: _Request, chunk_tokens: int) -> jnp.ndarray:
@@ -371,8 +426,9 @@ class ContinuousBatcher:
     >>> out = batcher.wait("a")                  # [B, S+8]
     >>> batcher.stop()
 
-    `max_active` bounds the requests that hold cache slots; the rest wait
-    in `pending`. A worker that raises marks the executor dead, and
+    `max_active` bounds the requests that run, and is the count of row
+    slots of each stage's cache (a request of B rows takes B); the rest
+    wait in `pending`. A worker that raises marks the executor dead, and
     `stop()` with requests in flight does the same: every current and
     later `submit` and `wait` raises instead of hanging (the /healthz
     contract of tools/serve.py).
@@ -433,6 +489,16 @@ class ContinuousBatcher:
         self.results: Dict = {}
         self.stats = {"ticks": 0, "stage_steps": 0, "tokens": 0,
                       "prefill_chunks": 0}
+        # the rows that step together (module docstring): a stage-wide
+        # cache of `max_active` row slots, where the stages' programs take
+        # a position a row and no paged backend holds the cache
+        block_fn = rows_block_fn(pipe) if kv is None else None
+        self.rows: Optional[StageRows] = None if block_fn is None \
+            else StageRows(pipe, self.max_active, block_fn)
+        # the requests whose newest token this tick put into `rows.ids`,
+        # and the ticks not read back yet as (that tick's ids, its requests)
+        self._sent: List[_Request] = []
+        self._unread: deque = deque()
         # the served life cycle (start/wait/stop): ONE condition guards
         # the queues and `results` between the worker and the caller
         # threads. The worker holds its lock for the whole of a tick.
@@ -485,10 +551,13 @@ class ContinuousBatcher:
         continuous-batching payoff: short answers release capacity
         immediately instead of padding to the cap.
 
-        `on_token(step, tokens)` fires as each step's pick lands (tokens
-        is the [B] device array — the callback decides when to block on
-        readback), the streaming hook `tools/serve.py` chains to chunked
-        HTTP responses.
+        `on_token(step, tokens)` fires once a token of every row, in
+        order, the streaming hook `tools/serve.py` chains to chunked HTTP
+        responses. `tokens` is `[B]`: host integers where the request's
+        rows step with the others (the executor has read them back), the
+        device array as its pick lands where the request steps alone (the
+        callback decides when to block on the read-back). `np.asarray`
+        takes either.
 
         `cancel` (an is_set()-style flag, e.g. threading.Event) requests
         cooperative cancellation: once set, the request completes at its
@@ -555,8 +624,17 @@ class ContinuousBatcher:
                     self._live_rids.discard(req.rid)
                     continue
             else:
+                n_rows = int(req.ids.shape[0])
+                if (self.rows is not None and req.greedy
+                        and n_rows <= self.rows.slots):
+                    if self.rows.n_free < n_rows:
+                        break       # head-of-line: wait for rows to leave
+                    req.slots = self.rows.take(n_rows)
                 self.pending.popleft()
-                kind, data = _seed_caches(self.pipe, req), req.ids
+                # a prefix-seeded request's suffix runs as one SPAN at the
+                # prefix offset (prompt caching); otherwise a fresh prefill
+                kind = "prefill" if req.prefix is None else "span"
+                data = req.ids
             kind, data = _maybe_chunk(req, kind, data, self.chunk_tokens)
             if kind == "chunk":
                 self.stats["prefill_chunks"] += 1
@@ -590,6 +668,9 @@ class ContinuousBatcher:
             M_CHUNKS.inc(executor="wave")
             reentries.append((req, data, "chunk"))
             return
+        if req.slots is not None:
+            self._join_rows(req, out, reentries)
+            return
         token = _pick_token(req, out)
         self.stats["tokens"] += int(token.shape[0])
         _emit_token(req, token, self.on_step)
@@ -611,6 +692,9 @@ class ContinuousBatcher:
             self.results[req.rid] = _finalize_tokens(req)
             req.caches = None            # free this request's cache slots
             req.chunk_rest = None
+            req.done = True
+            if req.slots is not None:
+                self.rows.free(req.slots)    # ... its rows of the stages'
             if self.kv is not None:
                 self.kv.release(req)     # ... or its page references
             self.active -= 1
@@ -663,21 +747,162 @@ class ContinuousBatcher:
             self._budget -= item[1].shape[1]
         return item
 
+    # -- the rows that step together ---------------------------------------
+
+    def _join_rows(self, req: _Request, out, reentries: list) -> None:
+        """A request that holds slots leaves its prompt pass: its first
+        token is picked as any request's is and put into its slots of the
+        stage-wide ids, where its first step finds it and the worker's next
+        read-back brings it to the host."""
+        with telemetry.span("exec", "pick", rid=str(req.rid)):
+            _, step_ids, req.rng = req.pick(out, req.rng)
+            self.rows.join(step_ids, req.slots)
+            M_STEPS.inc(executor="wave")
+        self._sent_token(req, reentries)
+
+    def _sent_token(self, req: _Request, reentries: list) -> None:
+        """The pick of `req`'s next token has gone out: it re-enters stage 0
+        unless that was its last. An eos, a cancel or an expiry shows a
+        step later, when the token is read (`_deliver`), and the step sent
+        meanwhile is computed and discarded."""
+        req.sent += 1
+        self.stats["tokens"] += len(req.slots)
+        self._sent.append(req)
+        if req.sent < req.new_tokens:
+            reentries.append((req, None, "step"))
+
+    def _gather_rows(self, first: _Request) -> "_Rows":
+        """Every request queued for a step at stage 0 whose rows hold slots,
+        `first` (just popped) among them, as ONE dispatch: the rung that
+        spans their slots, lowest to highest, and each row's position, dead
+        (-1) where a slot of the rung is not theirs."""
+        def rows_step(item):
+            return item[2] == "step" and item[0].slots is not None
+
+        q = self._stage_q[0]
+        reqs = [first] + [item[0] for item in q if rows_step(item)]
+        if len(reqs) > 1:
+            self._stage_q[0] = deque(item for item in q
+                                     if not rows_step(item))
+        held = [slot for req in reqs for slot in req.slots]
+        rung, base = self.rows.span(min(held), max(held))
+        pos = np.full(rung, -1, np.int32)
+        for req in reqs:
+            pos[np.asarray(req.slots) - base] = req.pos
+        return _Rows(reqs, base, pos)
+
+    def _step_rows(self, i: int, group, hidden, reentries: list):
+        """One stage-step of every row that stands at it: one program, ONE
+        `stage`/`exec{i}` span. `group` is the `_Rows` the stage before
+        handed on, or at stage 0 the request just popped, whose step takes
+        every other queued one with it. At the last stage the program has
+        picked: the counters, the `on_step` hook and the rows' re-entry
+        follow. -> (the group, the stage's output)."""
+        t0 = time.monotonic_ns()
+        last = i + 1 == self.n_stages
+        with telemetry.span("stage", f"exec{i}", stage=i):
+            if i == 0:
+                group = self._gather_rows(group)
+            out = self.rows.step(i, hidden, group.base, group.pos)
+            live, rung = group.pos[group.pos >= 0], len(group.pos)
+            M_ATTEND.inc(rung * self.rows.walked(
+                rung, int(group.pos.max())), phase="decode", kind="read")
+            M_ATTEND.inc(int(live.sum()), phase="decode", kind="live")
+            if last:
+                M_STEPS.inc(executor="wave")
+                M_ROWS.inc(live.size, kind="live")
+                M_ROWS.inc(rung, kind="slots")
+        if telemetry.enabled():
+            # the dispatch is every row's: `trace_report --request` finds
+            # a request's share of a stage under `compute/rows{i}`
+            t1 = time.monotonic_ns()
+            for req in group.reqs:
+                telemetry.record("compute", f"rows{i}", t0, t1, stage=i,
+                                 rid=str(req.rid))
+        if last:
+            if self.on_step is not None:
+                self.on_step()
+            for req in group.reqs:
+                if not req.done:
+                    self._sent_token(req, reentries)
+        return group, out
+
+    def _read(self, due: list) -> list:
+        """`exec/read`: the one read-back a step. Blocks on the device for
+        the tokens of the ticks in `due`; touches nothing the caller threads
+        share, so the worker is outside its lock here."""
+        if not due:
+            return due
+        with telemetry.span("exec", "read"):
+            return [(np.asarray(ids)[:, 0], reqs) for ids, reqs in due]
+
+    def _deliver(self, read: list) -> None:
+        """Hand each request the tokens a read-back brought, as host
+        integers, and decide its end from them: the cap, every row's eos, a
+        cancel or an expiry. Rows leave here, between steps, and pending
+        requests take their slots for the next tick."""
+        for host, reqs in read:
+            for req in reqs:
+                if req.done:
+                    continue        # ended a step ago: computed, discarded
+                token = host[req.slots]
+                req.tokens.append(token)
+                _emit_token(req, token, None)
+                done = len(req.tokens) >= req.new_tokens
+                if not done and (_expired(req) or (
+                        req.cancel is not None and req.cancel.is_set())):
+                    done = True     # expired/caller gone: free the slots
+                elif not done and req.eos_token is not None:
+                    done = _all_rows_eos(req, token)
+                if done:
+                    self._complete(req)
+        if read:
+            self._admit()
+
+    def _due(self, fresh: bool) -> list:
+        """The ticks to read back now: every one but this tick's own, which
+        waits until the next tick's programs are out (step n + 1 is
+        dispatched before step n's tokens are read)."""
+        keep = 1 if fresh else 0
+        return [self._unread.popleft()
+                for _ in range(len(self._unread) - keep)]
+
+    def warm(self) -> None:
+        """Build the programs that no request of a warm-up sent alone would
+        meet (the wider rungs of the rows' step) before traffic comes: a
+        compile inside a live window is a stalled user."""
+        if self.rows is not None:
+            with self.cond:
+                self.rows.warm()
+
     def tick(self) -> bool:
         """Advance every stage by at most one stage-step; returns whether
         any work remains.
 
         Strict wave semantics: stages are drained back-to-front and a
         token finishing at the last stage re-enters stage 0 only AFTER the
-        tick, so every request advances exactly one stage per tick and all
-        of a tick's dispatches belong to DISTINCT requests. That makes a
+        tick, so every request advances exactly one stage per tick and a
+        tick dispatches at most one program a stage. That makes a
         tick one parallel stage-time: no intra-tick data dependencies, so
         with stages on distinct devices the asynchronously dispatched
         steps genuinely overlap. (A solo request therefore costs exactly
         n_stages ticks per token — the pipeline-bubble baseline the
         batcher exists to fill.) With `step_join`, completions refill
         stage 0 mid-tick; with `chunk_tokens`, stage 0's pop obeys the
-        per-tick prefill token budget."""
+        per-tick prefill token budget. A step popped at stage 0 takes
+        every other queued step of rows that hold slots with it: they are
+        one dispatch, and stay one through the later stages.
+
+        The tokens of the rows that step together are read back a tick
+        late, after the next tick's programs are out."""
+        worked, fresh = self._dispatch()
+        self._deliver(self._read(self._due(fresh)))
+        return (worked or self.active > 0 or bool(self.pending)
+                or bool(self._unread))
+
+    def _dispatch(self):
+        """A tick's programs. -> (whether any went out, whether rows that
+        step together were sent a token, which `_unread` now holds)."""
         cap = max(self.prefill_budget, self.chunk_tokens)
         self._budget = min(self._budget + self.prefill_budget, cap)
         self._admit()
@@ -685,15 +910,36 @@ class ContinuousBatcher:
         reentries: list = []
         eos_pending: list = []
         for i in reversed(range(self.n_stages)):
-            if not self._stage_q[i]:
+            q = self._stage_q[i]
+            if i == 0 and any(item[0].done for item in q):
+                # ended (eos, cancel, expiry) while their next step waited
+                q = self._stage_q[0] = deque(
+                    item for item in q if not item[0].done)
+            if not q:
                 continue
             req, data, kind = (self._pop_stage0() if i == 0
-                               else self._stage_q[i].popleft())
-            out = (self.kv.run_stage(i, req, data, kind)
-                   if self.kv is not None
-                   else _run_stage(self.pipe, i, req, data, kind))
+                               else q.popleft())
             self.stats["stage_steps"] += 1
             worked = True
+            if kind == "rows" or (kind == "step"
+                                  and req.slots is not None):
+                group, out = self._step_rows(i, req, data, reentries)
+                if i + 1 < self.n_stages:
+                    self._stage_q[i + 1].append((group, out, "rows"))
+                continue
+            if self.kv is not None:
+                out = self.kv.run_stage(i, req, data, kind)
+            else:
+                if req.caches is None:
+                    _seed_caches(self.pipe, req)    # its prompt sets out
+                out = _run_stage(self.pipe, i, req, data, kind)
+            if req.slots is not None and (kind != "chunk"
+                                          or req.chunk_final):
+                # the prompt is through this stage: its rows go into
+                # their slots of the stage's cache
+                with telemetry.span("exec", "install", rid=str(req.rid)):
+                    self.rows.install(i, req.caches[i], req.slots)
+                    req.caches[i] = None
             if i + 1 < self.n_stages:
                 self._stage_q[i + 1].append((req, out, kind))
             else:
@@ -703,7 +949,11 @@ class ContinuousBatcher:
             self._decide_eos(req)
         self.stats["ticks"] += worked
         self._admit()                # a completion may free a slot mid-tick
-        return worked or self.active > 0 or bool(self.pending)
+        fresh = bool(self._sent)
+        if fresh:
+            self._unread.append((self.rows.ids, self._sent))
+            self._sent = []
+        return worked, fresh
 
     def run(self) -> Dict:
         """Drive ticks until every submitted request completes; returns
@@ -726,27 +976,36 @@ class ContinuousBatcher:
 
     def _loop(self) -> None:
         while True:
-            # `exec/wait0`, the worker's only blocking wait: first for the
-            # condition's lock, which every submitting and every waiting
-            # caller thread shares with it, then for work
+            # `exec/wait0`, the worker's only wait for the caller threads:
+            # first for the condition's lock, which every submitting and
+            # every waiting caller thread shares with it, then for work
             with telemetry.span("exec", "wait0", stage=0):
                 self.cond.acquire()
-                while not self._stop and not (self.pending or self.active):
+                while not self._stop and not (self.pending or self.active
+                                              or self._unread):
                     self.cond.wait()
             try:
-                if self._stop:
-                    return
+                # a tick in three parts: its programs go out under the
+                # lock; the read-back of the tick before, which blocks on
+                # the device, runs outside it (callers `submit` and `wait`
+                # meanwhile); the tokens are handed out under it again
                 try:
-                    self.tick()
-                except BaseException as exc:   # noqa: BLE001 — a wedged
-                    # worker would hang every waiter forever; record the
-                    # failure so they raise instead
-                    self._die(exc)
-                    raise
-                if self.results:
-                    self.cond.notify_all()
-            finally:
-                self.cond.release()
+                    if self._stop:
+                        return
+                    _, fresh = self._dispatch()
+                    due = self._due(fresh)
+                finally:
+                    self.cond.release()
+                read = self._read(due)
+                with self.cond:
+                    self._deliver(read)
+                    if self.results:
+                        self.cond.notify_all()
+            except BaseException as exc:   # noqa: BLE001 — a wedged
+                # worker would hang every waiter forever; record the
+                # failure so they raise instead
+                self._die(exc)
+                raise
 
     @property
     def dead(self) -> Optional[BaseException]:

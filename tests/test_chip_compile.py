@@ -381,19 +381,11 @@ def test_kimi_stage_program_compiles_for_v5e(span, last_only, rows, on_chip):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
 
 
-def test_gpt2_medium_decode_step_keeps_cache_rows_as_rows(on_chip):
-    """`gpt2-m.offline-batch`'s decode step at its real size (32 rows, 1,024
-    positions, the 512 bucket, bfloat16; shapes from the loader): the chip
-    keeps the cache as it is declared, a position a row of whole lanes, so
-    the step writes its rows as rows and reads the window as stored. The
-    three ways that was lost before (PERF.md, PR 25 and PR 32): positions
-    minor-most in tiles of 128; a tile of 128 positions rewritten to store
-    one; the window copied or converted whole on its way to the attention."""
-    import re
-    from pipeedge_tpu.parallel import decode
+def _gpt2_medium_shapes(on_chip):
+    """(registry entry, config, one stage's parameters as shapes on the
+    described chip) of gpt2-medium whole, bfloat16, from the loader."""
     entry = registry.get_model_entry("gpt2-medium")
     cfg = entry.config
-    rows, max_len, read_len = 32, 1024, 512
     d, n_layers = cfg.hidden_size, cfg.num_hidden_layers
 
     def zeros(*shape):      # a checkpoint's worth of shapes, no bytes
@@ -415,6 +407,22 @@ def test_gpt2_medium_decode_step_keeps_cache_rows_as_rows(on_chip):
     params["blocks"] = jax.tree_util.tree_map(
         lambda leaf: on_chip((n_layers,) + leaf.shape[1:], leaf.dtype),
         one_block["blocks"])
+    return entry, cfg, params
+
+
+def test_gpt2_medium_decode_step_keeps_cache_rows_as_rows(on_chip):
+    """`gpt2-m.offline-batch`'s decode step at its real size (32 rows, 1,024
+    positions, the 512 bucket, bfloat16; shapes from the loader): the chip
+    keeps the cache as it is declared, a position a row of whole lanes, so
+    the step writes its rows as rows and reads the window as stored. The
+    three ways that was lost before (PERF.md, PR 25 and PR 32): positions
+    minor-most in tiles of 128; a tile of 128 positions rewritten to store
+    one; the window copied or converted whole on its way to the attention."""
+    import re
+    from pipeedge_tpu.parallel import decode
+    rows, max_len, read_len = 32, 1024, 512
+    entry, cfg, params = _gpt2_medium_shapes(on_chip)
+    n_layers = cfg.num_hidden_layers
     cache = jax.tree_util.tree_map(
         lambda leaf: on_chip(leaf.shape, leaf.dtype),
         jax.eval_shape(lambda: stage_cache.init_cache(
@@ -444,6 +452,55 @@ def test_gpt2_medium_decode_step_keeps_cache_rows_as_rows(on_chip):
     assert largest < rows * read_len * width, largest
     cache_bytes = 2 * n_layers * rows * max_len * width * 2
     assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+
+
+@pytest.mark.parametrize("rung", [1, 8, 32, 48])
+def test_gpt2_medium_rows_step_updates_the_stage_cache_in_place(rung, on_chip):
+    """The served cells' step at its real size (`--max-active 48 --max-len
+    1024`, bfloat16): every rung of `decode_rows.row_rungs` compiles for the
+    chip, the stage-wide cache keeps its declared layout (a position a row
+    of whole lanes), the donated cache is the returned one (4.83 GB once,
+    not twice), and nothing of a rung's window is copied or converted on
+    its way to the walk, though the rung's first slot is traced."""
+    import re
+    from pipeedge_tpu.parallel import decode_rows
+    slots, max_len = 48, 1024
+    entry, cfg, params = _gpt2_medium_shapes(on_chip)
+    n_layers, width = cfg.num_hidden_layers, cfg.kv_heads * cfg.head_dim
+    assert decode_rows.row_rungs(slots) == (1, 8, 32, 48)
+    cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: stage_cache.init_cache(
+            cfg, n_layers, slots, max_len, jnp.bfloat16)))
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    block = decode_rows.walk_block(max_len, rung)
+    step = decode_rows.make_rows_step(
+        entry.family.FAMILY, cfg, stage, decode_rows.block_step_rows, rung,
+        block)
+    compiled = step.lower(params, on_chip((slots, 1), jnp.int32), None,
+                          cache, on_chip((1 + rung,), jnp.int32)).compile()
+    text = compiled.as_text()
+    leaf = rf"bf16\[{n_layers},{slots},{max_len},{width}\]"
+    layouts = re.findall(leaf + r"\{([\d,]+)[:}][^=]* parameter\(\d+\)",
+                         text)
+    assert len(layouts) >= 2, layouts
+    for minor_to_major in layouts:
+        assert minor_to_major.split(",")[0] != "2", minor_to_major
+    cache_bytes = 2 * n_layers * slots * max_len * width * 2
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    # (the head's product with one row is a fused multiply and sum over the
+    # table, converted inside the fusion: not a copy of it)
+    moved = [dims for dims in re.findall(
+        r"= \w+\[([\d,]*)\]\S* (?:copy|convert)\(", text)
+        if str(cfg.vocab_size) not in dims.split(",")]
+    largest = max([int(np.prod([int(n) for n in dims.split(",") if n]))
+                   for dims in moved], default=0)
+    # a block of the walk, or (one row: products of a row and a matrix) a
+    # layer's widest weight
+    assert largest <= max(rung * block * width,
+                          cfg.hidden_size * cfg.intermediate_size), (
+        largest, rung, block)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
 
 
 @pytest.mark.parametrize("n_ubatch", [1024, 4])

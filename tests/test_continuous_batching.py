@@ -175,15 +175,21 @@ def test_on_step_fires_once_per_decode_pick(pipe):
     assert len(steps) == 2 * 4
 
 
-def test_step_join_admits_in_the_completion_tick(pipe):
-    """With max_active=1, a queued request must enter stage 0 in the
-    SAME tick its predecessor completes (the reversed stage drain
-    visits stage 0 after the completion) — strictly fewer ticks than
-    the wave-boundary default."""
+@pytest.mark.parametrize("sampled", [True, False],
+                         ids=["steps-alone", "rows-together"])
+def test_step_join_admits_in_the_completion_tick(pipe, sampled):
+    """With max_active=1, a queued request that steps alone (a sampled one)
+    must enter stage 0 in the SAME tick its predecessor completes (the
+    reversed stage drain visits stage 0 after the completion) — strictly
+    fewer ticks than the wave-boundary default. The rows that step
+    together leave when their tokens are read, between two steps, and a
+    freed slot is taken at the next step boundary whatever `step_join`
+    says: the same ticks both ways."""
     def ticks_to_drain(step_join):
         b = ContinuousBatcher(pipe, max_active=1, step_join=step_join)
         for i, ids in enumerate(_prompts(3, lens=(5,), seed0=23)):
-            b.submit(i, ids, new_tokens=3)
+            b.submit(i, ids, new_tokens=3,
+                     temperature=0.7 if sampled else 0.0, seed=i)
         n = 0
         while b.tick():
             n += 1
@@ -191,7 +197,10 @@ def test_step_join_admits_in_the_completion_tick(pipe):
         return n
 
     joined, waved = ticks_to_drain(True), ticks_to_drain(False)
-    assert joined < waved, (joined, waved)
+    if sampled:
+        assert joined < waved, (joined, waved)
+    else:
+        assert joined == waved, (joined, waved)
 
 
 def test_stage0_budget_policy_defers_and_never_starves(pipe):

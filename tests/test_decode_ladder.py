@@ -240,8 +240,17 @@ def test_the_batcher_and_the_speculative_decoder_bind_todays_widths():
     batcher = ContinuousBatcher(pipe, chunk_tokens=8)
     batcher.submit("r", ids, new_tokens=30)
     np.testing.assert_array_equal(batcher.run()["r"], want)
-    assert {span for span, _, _ in seen} >= {1, 8}      # chunks and steps
+    # the chunks bind today's widths; the steps of the rows that stand
+    # together bind none (they walk the cache up to the furthest row)
+    assert {span for span, _, _ in seen} == {8, 5}      # 37 = 4 x 8 + 5
     assert _as_before(seen, 128), seen
+    # a request that steps alone (sampled) binds them for its steps too
+    sampled = np.asarray(pipe.generate(ids, 30, temperature=0.7, seed=1))
+    del seen[:]
+    batcher.submit("s", ids, new_tokens=30, temperature=0.7, seed=1)
+    np.testing.assert_array_equal(batcher.run()["s"], sampled)
+    steps_alone = [entry for entry in seen if entry[0] == 1]
+    assert len(steps_alone) == 29 and _as_before(steps_alone, 128), seen
 
     draft = _long_pipe(128, seed=6, attend_floor=4)
     drafted = _spy_widths(draft)

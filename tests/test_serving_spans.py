@@ -62,10 +62,16 @@ def _run_offline(pipe, tag):
 @pytest.mark.parametrize("run", [_run_thread, _run_offline],
                          ids=["thread", "run"])
 def test_executor_worker_is_always_inside_a_named_span(pipe, run):
-    """Per token one `stage/exec0`, `exec/pick` and `exec/emit`, one
-    `exec/retire` per request, all request-tagged; the spans of the one
-    worker never overlap, and what lies between two of them is book-keeping
-    only (that no dispatch hides there is the next test's to say)."""
+    """One `stage/exec0` a dispatch: a prompt pass a request (tagged with
+    it, as its `exec/seed`, `exec/install` and `exec/pick` are), then one
+    for every
+    step of the rows that stand at it together (no request's tag; at most
+    a token's worth, seven where all three step from the first step on, as
+    they do when the caller submits before it ticks). One `exec/emit` a
+    token a request and one `exec/retire` a request, tagged; one
+    `exec/read` a tick that brought tokens. The spans of the one worker
+    never overlap, and what lies between two of them is book-keeping only
+    (that no dispatch hides there is the next test's to say)."""
     run(pipe, "warm")                   # compile outside the measurement
     rec = telemetry.configure()
     try:
@@ -78,10 +84,18 @@ def test_executor_worker_is_always_inside_a_named_span(pipe, run):
     tokens = REQUESTS * NEW_TOKENS
     counts = Counter((s["cat"], s["name"]) for s in spans
                      if s["name"] not in ("wait0", "admit"))
-    assert counts == {("stage", "exec0"): tokens, ("exec", "pick"): tokens,
+    steps = counts.pop(("stage", "exec0")) - REQUESTS
+    reads = counts.pop(("exec", "read"))
+    assert NEW_TOKENS - 1 <= steps <= tokens - REQUESTS
+    assert run is _run_thread or steps == NEW_TOKENS - 1
+    assert 1 <= reads <= steps + REQUESTS + 1
+    assert counts == {("exec", "seed"): REQUESTS,
+                      ("exec", "install"): REQUESTS,
+                      ("exec", "pick"): REQUESTS,
                       ("exec", "emit"): tokens, ("exec", "retire"): REQUESTS}
-    for name in ("exec0", "pick", "emit", "retire"):
+    for name in ("exec0", "seed", "install", "pick", "emit", "retire"):
         per_request = Counter(s["rid"] for s in spans if s["name"] == name)
+        per_request.pop(None, None)     # the steps of all rows
         assert set(per_request) == {f"r{i}" for i in range(REQUESTS)}
     assert all(a["t1"] <= b["t0"] for a, b in zip(spans, spans[1:]))
 
@@ -114,12 +128,16 @@ def _run_generate(pipe, tag):
 def test_a_token_is_a_stage_program_and_a_pick_and_nothing_eager(
         pipe, tmp_path, run, step, pick):
     """What the profiler sees the decoding thread dispatch, from its first
-    stage program to its last pick: `prefill`, `decode_step` and
+    stage program to its last pick. `generate`: `prefill`, `decode_step` and
     `pick_next`, one pick a stage program, and the one `slice` that cuts a
-    prompt pass's last position out, once a request. An eager slice,
-    split, cast or reshape of a step's token would be a `PjitFunction` of
-    its own here, as it is a dispatch of its own on the chip (PERF.md
-    section 6, PR 28)."""
+    prompt pass's last position out, once a batch. The executor: a prompt
+    pass is the zeros of the cache it fills (`exec/seed`: a
+    `broadcast_in_dim`, and a `convert_element_type` where the dtype asks),
+    `prefill`, its `slice` and `pick_next`, then `install_rows` and
+    `join_ids` once a request; a step of the rows that stand together is
+    `rows_step` alone, the pick inside it. An eager slice, split, cast or
+    reshape of a step's token would be a `PjitFunction` of its own here, as
+    it is a dispatch of its own on the chip (PERF.md section 6, PR 28)."""
     run(pipe, "warm")                   # compile outside the trace
     with tracing.trace(str(tmp_path)):
         run(pipe, "r")
@@ -131,16 +149,34 @@ def test_a_token_is_a_stage_program_and_a_pick_and_nothing_eager(
                for line in plane.lines
                if any(e.name == step for e in line.events)]
     t0 = next(e.start_ns for e in line if e.name == step)
-    t1 = max(e.start_ns + e.duration_ns for e in line if e.name == pick)
+    t1 = max(e.start_ns + e.duration_ns for e in line
+             if e.name in (step, pick))
     programs = Counter(e.name for e in line if t0 <= e.start_ns < t1
                        and e.name.startswith("PjitFunction("))
-    assert set(programs) == {"PjitFunction(prefill)", "PjitFunction(slice)",
-                             "PjitFunction(decode_step)",
-                             "PjitFunction(pick_next)"}
+    if run is _run_generate:
+        assert set(programs) == {
+            "PjitFunction(prefill)", "PjitFunction(slice)",
+            "PjitFunction(decode_step)", "PjitFunction(pick_next)"}
+        assert programs["PjitFunction(pick_next)"] == (
+            programs["PjitFunction(prefill)"]
+            + programs["PjitFunction(decode_step)"])
+    else:
+        seed = {"PjitFunction(broadcast_in_dim)",
+                "PjitFunction(convert_element_type)"}
+        assert set(programs) - seed == {
+            "PjitFunction(prefill)", "PjitFunction(slice)",
+            "PjitFunction(pick_next)", "PjitFunction(install_rows)",
+            "PjitFunction(join_ids)", "PjitFunction(rows_step)"}
+        # the profiler may show a call more than once: count in requests
+        each = programs["PjitFunction(prefill)"] // REQUESTS
+        for once in ("pick_next", "install_rows", "join_ids"):
+            assert programs[f"PjitFunction({once})"] == each * REQUESTS
+        # a cache's two leaves a request, never a step's token
+        assert sum(programs[name] for name in seed) <= 4 * each * REQUESTS, \
+            programs
+        assert each * (NEW_TOKENS - 1) <= programs[
+            "PjitFunction(rows_step)"] <= each * REQUESTS * (NEW_TOKENS - 1)
     assert programs["PjitFunction(slice)"] == programs["PjitFunction(prefill)"]
-    assert programs["PjitFunction(pick_next)"] == (
-        programs["PjitFunction(prefill)"]
-        + programs["PjitFunction(decode_step)"])
 
 
 def test_worker_waits_in_a_span_of_its_own(pipe):

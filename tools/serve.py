@@ -371,7 +371,11 @@ class _Service:
             pipe, max_active=max_active, kv=self.kv_backend,
             chunk_tokens=self.chunked_prefill,
             prefill_budget=prefill_budget, step_join=self.step_join,
-            on_step=self._on_step).start()
+            on_step=self._on_step)
+        # the step programs of the rows that step together, every rung,
+        # before the first request: one request alone meets one rung
+        self.executor.warm()
+        self.executor.start()
         # ONE lock: the prefix registry, the degraded window and the rid
         # counter share the condition the executor's worker ticks under
         self.cond = self.executor.cond
@@ -1667,6 +1671,9 @@ def _inject_stall(pipe, spec, parser):
     for key, fn in list(st.items()):
         if callable(fn):
             st[key] = slow(fn)
+    # the programs the executor makes for this stage later (the step of the
+    # rows that stand together, decode_rows.StageRows) are wrapped as made
+    st["wrap"] = slow
     print(f"chaos: injecting {ms_s}ms stall into every step of stage "
           f"{idx}", flush=True)
 
