@@ -70,7 +70,7 @@ class Sizes:
     max_len: int = 1024
     prompt_len: int = 128
     new_tokens: int = 32
-    train_batch: int = 8        # tools/bench_train.py's one-chip sizing
+    train_batch: int = 8        # one chip's sizing (docs/TRAIN.md)
     train_ubatches: int = 4
     train_steps: int = 4
     edge_shape: tuple = (8, 197, 1024)      # the ViT-L stage edge

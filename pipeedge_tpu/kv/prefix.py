@@ -248,7 +248,7 @@ class PrefixTrie:
         return {"pages_cached": nodes,
                 "lookups": int(total),
                 # hits/misses exposed raw so consumers can difference
-                # two snapshots into a WINDOW rate (benchkit serve_kv)
+                # two snapshots into a WINDOW rate (a phase's hits)
                 # instead of the lifetime-cumulative hit_rate below
                 "hits": int(hits), "misses": int(misses),
                 "hit_rate": (None if total == 0

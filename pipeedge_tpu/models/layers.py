@@ -261,19 +261,19 @@ def set_fast_numerics(enabled) -> None:
     dtype instead of float32, and exact-erf GeLU becomes the tanh
     approximation. Trades exact HF/reference numerics parity for fewer
     f32 intermediates (less VPU/HBM traffic between the MXU matmuls) —
-    the measured cost of the parity default is the 'f32 numerics'
-    bucket in docs/PERF.md's MFU attribution.
+    the cost of the parity default is those f32 intermediates, and
+    no cell of the benchmark turns this on (it waits for the q8 cells).
 
     `enabled` is True/False, or None to RESET: discard any programmatic
     choice and defer to PIPEEDGE_FAST_NUMERICS again (without None the
     env opt-in would be permanently dead for the rest of the process
-    after any caller touched the toggle — ADVICE.md r5).
+    after any caller touched the toggle).
 
     TRACE-TIME flag: programs compiled while the mode is on keep it
     (jit caches by shape/dtype, not by this flag) — enable it BEFORE
-    building/first-calling a model, as bench.py's fast-numerics pass and
-    tools/bench_mfu_buckets.py do. Accuracy delta vs the exact mode is
-    measured and recorded (tests/test_models.py, docs/PERF.md)."""
+    building/first-calling a model, and build a fresh jit wrapper for
+    each mode of an A/B. Accuracy delta vs the exact mode is held by
+    tests/test_models.py (top-1 agreement on the tiny fixtures)."""
     global _FAST_NUMERICS
     _FAST_NUMERICS = None if enabled is None else bool(enabled)
 

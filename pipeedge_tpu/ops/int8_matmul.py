@@ -19,7 +19,7 @@ than per-row: s_x[m, kb] * s_w[n] * (x_q[m, kb*bk:...] @ w_q[...]).
 Mode selection (`PIPEEDGE_INT8_MATMUL`, mirroring ops/fused_quant.py):
 - `auto` (default): native Pallas kernel on TPU (a lowering error is an
   error); the block-scaled XLA reference path elsewhere (same math, so
-  CPU CI and the recipe run the identical quantization).
+  CPU CI and a chip run do the identical quantization).
 - `interpret`: Pallas kernel in interpret mode — the CPU CI path that
   keeps the kernel's math honest without TPU hardware.
 - `1`/`0`: force the kernel / force the XLA reference.

@@ -1177,7 +1177,7 @@ def build_decode_pipeline(model_name: str,
                           stage_params: Optional[Sequence] = None,
                           **pipe_kw) -> "DecodePipeline":
     """Registry-driven `DecodePipeline` construction — THE shared build
-    path for the CLIs (tools/generate.py, tools/serve.py, bench_decode),
+    path for the CLIs (tools/generate.py, tools/serve.py, the benchmark),
     so model lookup, per-stage weight loading, and the position-capacity
     clamp cannot drift between tools. `stage_params` supplies already-
     loaded per-stage pytrees (callers that also need them for other

@@ -359,10 +359,10 @@ _EXEMPLAR_RE = None
 def parse_exemplars(text: str, family: str) -> List[dict]:
     """The client side of the `# EXEMPLAR` exposition contract: parse a
     rendered /metrics document back into `{le, trace_id, value}` rows for
-    one histogram family — how the benchkit serve recipe lifts the
-    p99-bucket -> trace-id links off a live server into its trajectory
-    record (value is the observation in the instrument's native unit,
-    seconds for latency histograms)."""
+    one histogram family — how a client or the fleet collector lifts
+    the p99-bucket -> trace-id links off a live server (value is the
+    observation in the instrument's native unit, seconds for latency
+    histograms)."""
     global _EXEMPLAR_RE  # pylint: disable=global-statement
     import re
     if _EXEMPLAR_RE is None:

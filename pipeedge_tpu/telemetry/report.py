@@ -86,7 +86,7 @@ def _percentile(sorted_vals: List[float], q: float) -> float:
 def percentile(vals: Sequence[float], q: float) -> float:
     """Public nearest-rank percentile over (not necessarily sorted)
     samples — the one percentile definition every report surface shares
-    (trace reports, benchkit records, loadgen summaries)."""
+    (trace reports, loadgen summaries)."""
     return _percentile(sorted(vals), q)
 
 
@@ -101,8 +101,8 @@ def segment_medians(spans: Sequence[dict],
     """Per-(category, name) duration percentiles over a span list:
     `{"cat/name": {"n", "p50_ms", "p95_ms"}}`. The per-segment view of
     where a microbatch's end-to-end time goes — dispatch vs transfer vs
-    emit — consumed by `tools/trace_report.py` and bench.py's latency
-    breakdown. Feed/results names embed microbatch ids; they are folded
+    emit — consumed by `tools/trace_report.py`'s segment table.
+    Feed/results names embed microbatch ids; they are folded
     to their prefix so the table stays bounded."""
     cats = SEGMENT_CATEGORIES if cats is None else cats
     series: Dict[str, List[float]] = {}

@@ -1057,7 +1057,7 @@ def _consider_rebalance(ctx, args, policy, sched, prev_digests: dict,
                    list(stage_layers), proposal.partition,
                    proposal.bottleneck_before_s,
                    proposal.bottleneck_after_s, 100 * proposal.gain)
-    # machine-parseable line (bench_rebalance.py / CI grep this)
+    # machine-parseable line (tests/test_rebalance.py greps this)
     print(f"rebalance_round={rnd} "
           f"partition={','.join(f'{l},{r}' for l, r in proposal.partition)} "
           f"predicted_gain={proposal.gain:.4f}")

@@ -605,6 +605,11 @@ def overload_server():
                 raise RuntimeError(f"server died: {proc.stdout.read()}")
         else:
             raise RuntimeError("server never came up")
+        # keep reading: a pipe nobody reads holds 64 KB, and a server that
+        # has logged that much (XLA says two long lines for every program
+        # it loads from the compile cache) blocks in its next write
+        threading.Thread(target=lambda: [None for _ in proc.stdout],
+                         daemon=True).start()
         yield port
     finally:
         proc.terminate()

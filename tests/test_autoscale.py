@@ -278,6 +278,75 @@ def test_advise_logs_without_acting():
 
 
 # ---------------------------------------------------------------------------
+# a whole ramp, a whole steady run: the counts a fleet run is held to
+# ---------------------------------------------------------------------------
+
+def _ramp_signals(n_windows, per_replica=4.0):
+    """A window's signals under loadgen's `ramp:2:10` load: the queue an
+    offered rate leaves at ONE replica's capacity (the controller divides
+    by its size), so the rise wants capacity and the fall gives it back."""
+    from tools.loadgen import parse_ramp_spec, ramp_rate
+    ramp = parse_ramp_spec("ramp:2:10")
+    for i in range(n_windows):
+        rate = ramp_rate(float(i), float(n_windows), ramp)
+        yield {"queue_depth": max(0.0, (rate - per_replica) * 4.0),
+               "brownout_level": 0, "burn_rate": 0.0}
+
+
+@pytest.mark.parametrize("mode", ["auto", "advise"])
+def test_ramp_scales_up_under_load_and_drains_to_the_floor(mode):
+    """One seeded ramp through either arm of the A/B: the closed loop
+    leaves the floor WHILE the ramp still offers load and is back on it
+    at the end, with two to four applied moves (a ceiling of 3), none
+    held and at most the one damped reversal at the ramp's turn: the
+    count does not explode. The advisory arm logs its decisions and moves
+    nothing."""
+    n = 60
+    ctl, state = _ctl(size=1, mode=mode, confirm=2, cooldown_s=3.0,
+                      queue_high=8.0, queue_low=1.0)
+    sizes, first_up = [], None
+    for i, sig in enumerate(_ramp_signals(n)):
+        d = ctl.tick(sig, now=float(i))
+        if d is not None and d.direction == "up" and first_up is None:
+            first_up = i
+        sizes.append(state["size"])
+    # calm windows after the load has gone: the drain's last step
+    for t in range(n, n + 30):
+        if state["size"] == 1:
+            break
+        ctl.tick(COLD, now=float(t))
+    snap = ctl.snapshot()
+    assert first_up is not None and first_up < n * 2 // 3
+    assert snap["ticks"] >= n
+    assert snap["decisions"]["held"] == 0
+    assert snap["decisions"]["flap_damped"] <= 1
+    if mode == "auto":
+        assert max(sizes) > 1 and state["size"] == 1    # up, then drained
+        assert 2 <= snap["decisions"]["applied"] <= 4
+        assert snap["decisions"]["advised"] == 0
+    else:
+        assert set(sizes) == {1} and state["applied"] == []
+        assert snap["decisions"]["advised"] >= 1
+        assert snap["decisions"]["applied"] == 0
+
+
+@pytest.mark.parametrize("size,signals", [(1, NEUTRAL), (2, NEUTRAL),
+                                          (1, COLD), (3, HOT)],
+                         ids=["floor-comfortable", "above-floor-comfortable",
+                              "floor-idle", "ceiling-hot"])
+def test_steady_run_ticks_and_decides_nothing(size, signals):
+    """The control run: under flat load the governor ticks every window
+    and renders no decision of ANY outcome: zero flaps on a clean fleet."""
+    ctl, state = _ctl(size=size, confirm=1, cooldown_s=0.0)
+    runner = AutoscaleRunner(ctl, signals_fn=lambda: signals,
+                             interval_s=0.01, emit=lambda line: None)
+    assert [runner.tick_once() for _ in range(40)] == [None] * 40
+    snap = ctl.snapshot()
+    assert snap["ticks"] == 40 and state["size"] == size
+    assert sum(snap["decisions"].values()) == 0 and state["applied"] == []
+
+
+# ---------------------------------------------------------------------------
 # snapshot + fleet mining + runner
 # ---------------------------------------------------------------------------
 

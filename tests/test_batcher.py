@@ -1,6 +1,6 @@
 """Continuous batching: multi-request wave scheduling over DecodePipeline.
 
-VERDICT r2 item 4: interleaving S concurrent requests across K pipeline
+Interleaving S concurrent requests across K pipeline
 stages must (a) stay token-identical per request to a solo generate() run
 and (b) approach min(S, K)x a single stream's throughput (a solo stream
 busies 1 of K stages per tick; a full wave busies all K).

@@ -133,6 +133,62 @@ def test_chunked_thread_driven_token_identical(pipe):
         + kv.trie.stats()["pages_cached"] == kv.pool.n_pages
 
 
+@pytest.mark.parametrize("chunked", [False, True],
+                         ids=["run-to-completion", "chunked-step-join"])
+def test_long_prompt_spike_mid_run_serves_every_request(pipe, chunked):
+    """Steady short requests with a spike of long prompts landing while
+    they decode, through either arm of the scheduler: no request errors
+    or is lost, every answer is the solo answer (so the two arms agree
+    token for token), chunk waves ran in the chunked arm and only there,
+    and the pool ends with every page free or cached."""
+    kv = _backend(pipe, n_pages=48)
+    kw = dict(chunk_tokens=8, prefill_budget=16, step_join=True) \
+        if chunked else {}
+    ex = ContinuousBatcher(pipe, kv=kv, **kw).start()
+    short = _prompts(4, lens=(6,), seed0=61)
+    long_ = _prompts(3, lens=(33, 29, 37), seed0=67)
+    outs, errors = {}, []
+
+    def client(rid, ids, new_tokens):
+        try:
+            ex.submit(rid, ids, new_tokens)
+            outs[rid] = ex.wait(rid, timeout=300)
+        except Exception as exc:    # noqa: BLE001 — counted, must be none
+            errors.append((rid, repr(exc)))
+
+    try:
+        threads = [threading.Thread(target=client, daemon=True,
+                                    args=(f"s{i}", ids, 8))
+                   for i, ids in enumerate(short)]
+        for t in threads[:2]:
+            t.start()
+        # the spike: back to back, while the first shorts are in flight
+        spike = [threading.Thread(target=client, daemon=True,
+                                  args=(f"l{i}", ids, 4))
+                 for i, ids in enumerate(long_)]
+        for t in spike + threads[2:]:
+            t.start()
+        for t in threads + spike:
+            t.join(timeout=300)
+            assert not t.is_alive()
+        chunks = ex.snapshot()["prefill_chunks"]
+    finally:
+        ex.stop()
+    assert errors == [] and len(outs) == len(short) + len(long_)
+    for i, ids in enumerate(short):
+        np.testing.assert_array_equal(
+            outs[f"s{i}"], np.asarray(pipe.generate(ids, 8)))
+    for i, ids in enumerate(long_):
+        np.testing.assert_array_equal(
+            outs[f"l{i}"], np.asarray(pipe.generate(ids, 4)))
+    if chunked:
+        assert chunks >= 5 + 4 + 5      # 33, 29, 37 tokens in chunks of 8
+    else:
+        assert chunks == 0
+    assert kv.pool.free_pages \
+        + kv.trie.stats()["pages_cached"] == kv.pool.n_pages
+
+
 def test_chunk_interleave_deterministic(pipe):
     """Two runs of the same mixed workload produce identical tokens AND
     identical chunk counts — the interleave policy is pure queue

@@ -76,19 +76,29 @@ def _run_fleet(tmp_path, opts, world, env_extra=None, per_rank_dirs=False,
             rank_dirs.append(d)
     else:
         rank_dirs = [tmp_path] * world
+    # a worker's log goes to a file: a pipe nobody reads while rank 0 runs
+    # holds 64 KB, and a worker that has logged that much blocks
+    logs = [open(tmp_path / f"worker{r}.log", "w+")
+            for r in range(1, world)]
     workers = [subprocess.Popen(common + rank_argv(r, world) + argv,
-                                cwd=rank_dirs[r], env=env,
-                                stdout=subprocess.PIPE,
+                                cwd=rank_dirs[r], env=env, stdout=log,
                                 stderr=subprocess.STDOUT, text=True)
-               for r in range(1, world)]
+               for r, log in enumerate(logs, start=1)]
     try:
         data = subprocess.run(common + rank_argv(0, world) + argv,
                               cwd=rank_dirs[0], env=env, capture_output=True,
                               text=True, timeout=data_timeout)
-        wouts = [w.communicate(timeout=60)[0] for w in workers]
+        for w in workers:
+            w.wait(timeout=60)
     finally:
         for w in workers:
             w.kill()
+            w.wait()
+        wouts = []
+        for log in logs:
+            log.seek(0)
+            wouts.append(log.read())
+            log.close()
     for r, (w, wout) in enumerate(zip(workers, wouts), start=1):
         assert w.returncode == 0, f"rank {r}:\n{wout}"
     return data, wouts, rank_dirs

@@ -336,7 +336,7 @@ def test_generate_dcn_matches_local(tmp_path):
 
 @pytest.mark.fleet
 def test_generate_dcn_adaptive_edge_quant(tmp_path):
-    """VERDICT r2 item 6: the adaptive bitwidth policies steer decode DCN
+    """The adaptive bitwidth policies steer decode DCN
     edges. ADAPTIVE_QUANT=HEURISTIC2 with an aggressive SEND_CONSTRAINT
     forces rank 0's output edge from raw (bit 0) down to the 2-bit floor
     after the first telemetry window; the consumer keeps decoding because

@@ -3,7 +3,8 @@
 The kernel (ops/decode_attention.py) must reproduce the XLA int8 decode
 step's semantics: dequantized cache reads, EXACT fresh-row substitution,
 [0, pos] masking. Interpret mode on CPU; the same code lowers natively
-on TPU (bench_decode's int8 rows exercise it there)."""
+on TPU (tests/test_chip_compile.py compiles it for the described
+chip)."""
 import numpy as np
 import pytest
 

@@ -146,7 +146,7 @@ def test_quantize_compute_setter_env_and_skip(monkeypatch):
     layers.set_quantize_compute(None)                  # defer to env
     qc = layers.quantize_compute()
     assert qc.enabled and qc.skip_tags == {"attn.out", "mlp.down"}
-    # the programmatic setter beats the env (the A/B pin the recipes use)
+    # the programmatic setter beats the env (the pin an A/B needs)
     layers.set_quantize_compute(False)
     assert not layers.quantize_compute().enabled
     cfg = layers.QuantizeCompute(enabled=True, block_k=64,
@@ -263,17 +263,35 @@ def test_tunnel_gating_requires_wire_sub_boundary():
         layers.set_quantize_compute(None)
 
 
-def test_int8_compute_top1_agreement_on_fixture():
-    """The recipe's quality gate, in-process: pure int8 compute (no wire
-    edge) agrees >= 0.99 top-1 with exact on the tiny fixture."""
+@pytest.mark.parametrize("clamp", ["off", "inline", "sidecar"])
+def test_int8_compute_top1_agreement_on_fixture(clamp, tmp_path):
+    """Pure int8 compute (no wire edge) agrees >= 0.99 top-1 with exact
+    on the tiny fixture, in process: with dynamic block scales alone,
+    with Banner clamp alphas calibrated from the first batch, and with
+    the same alphas read back from a sidecar file (what
+    `tools/calibrate.py` writes and a server loads)."""
     from pipeedge_tpu.models import registry
+    from pipeedge_tpu.utils import calibrate
 
     x = _tiny_images(batch=16)
     fn, params, _ = registry.module_shard_factory(
         MODEL, None, 1, registry.get_model_layers(MODEL))
     raw = fn.__wrapped__
     exact = np.asarray(jax.jit(raw)(params, x))
-    layers.set_quantize_compute(layers.QuantizeCompute(enabled=True))
+    qc = layers.QuantizeCompute(enabled=True)
+    if clamp != "off":
+        alphas, _, _ = calibrate.calibrate_shard(
+            MODEL, None, 1, registry.get_model_layers(MODEL),
+            [np.asarray(x[:8])])
+        assert alphas and all(a > 0 for a in alphas.values())
+        if clamp == "sidecar":
+            path = str(tmp_path / "tiny.int8scales.npz")
+            calibrate.write_sidecar(path, alphas, {}, meta={"bit": 8})
+            qc = calibrate.quantize_compute_from_sidecar(path)
+            assert qc.clamp_alphas == pytest.approx(alphas)
+        else:
+            qc = layers.QuantizeCompute(enabled=True, clamp_alphas=alphas)
+    layers.set_quantize_compute(qc)
     try:
         q = np.asarray(jax.jit(raw)(params, x))
     finally:

@@ -312,6 +312,48 @@ def test_worker_death_mid_lease_redispatches_to_survivor(
         plane.close()
 
 
+def test_readmitted_rank_takes_leases_again(pipe, prefill_pipe):
+    """The other half of a worker's death: when its respawn joins (the
+    transport's rejoin callback, epoch + 1) the fleet readmits the rank,
+    `dead` empties, and the round-robin lands leases on it again, with
+    the same tokens as before the fault."""
+    plane = _ShipPlane(prefill_pipe, n_workers=2)
+    try:
+        fleet = plane.fleet
+        rng = np.random.default_rng(59)
+        ids = rng.integers(0, 100, size=(1, 6))
+        want = np.asarray(pipe.generate(ids, 4))
+        fleet._on_peer_death(1)
+        assert fleet.snapshot()["dead"] == [1]
+        assert fleet.live_ranks() == frozenset({2})
+        served = []
+        dispatch = fleet._dispatch_once
+
+        def recording(ls, ids_t):
+            served.append(ls.rank)
+            return dispatch(ls, ids_t)
+
+        fleet._dispatch_once = recording
+        handles = [fleet.prefill(ids, rid=f"down{i}") for i in range(2)]
+        assert served == [2, 2]             # nothing sent to the dead rank
+        fleet._on_peer_rejoin(1, epoch=1)
+        snap = fleet.snapshot()
+        assert snap["dead"] == [] and snap["live"] == [1, 2]
+        handles += [fleet.prefill(ids, rid=f"back{i}") for i in range(2)]
+        assert sorted(served[2:]) == [1, 2]     # the readmitted rank serves
+        kv = _backend(pipe)
+        for i, handle in enumerate(handles):
+            batcher = ContinuousBatcher(pipe, kv=kv)
+            batcher.submit(i, ids, new_tokens=4, shipped=handle)
+            np.testing.assert_array_equal(batcher.run()[i], want)
+        snap = fleet.snapshot()
+        assert snap["leases"]["shipped"] == 4 and snap["in_flight"] == 0
+        assert kv.sweep_orphans(set()) == 0
+        assert kv.pool.stats()["leaked"] == 0
+    finally:
+        plane.close()
+
+
 def test_zombie_ack_stale_attempt_is_fenced():
     """A ship ack for a re-dispatched lease (stale attempt number) or an
     unknown lease must DROP, never resolve — the lease fence above the
@@ -450,6 +492,9 @@ def test_chaos_prefill_process_killed_midburst_all_requests_survive(
                         for _, p in addrs)
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
                DCN_CONNECT_TIMEOUT="30")
+    # a worker's log goes to a file: nobody reads a pipe while the burst
+    # runs, it holds 64 KB, and a worker that has logged that much blocks
+    logs = [open(tmp_path / f"worker{r}.log", "w") for r in (1, 2)]
     workers = [
         subprocess.Popen(
             [sys.executable, os.path.join(REPO, "tools",
@@ -457,9 +502,8 @@ def test_chaos_prefill_process_killed_midburst_all_requests_survive(
              str(r), str(world), "--dcn-addrs", addr_arg, "-m", MODEL,
              "-pt", "1,4,5,8", "--max-len", str(MAX_LEN),
              "-t", "float32", "--heartbeat-interval", "0.5"],
-            env=env, text=True, stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT)
-        for r in (1, 2)]
+            env=env, text=True, stdout=log, stderr=subprocess.STDOUT)
+        for r, log in zip((1, 2), logs)]
     ctx = dcn.DistDcnContext(world, 0, addrs)
     ctx.init()
     # heartbeats make the SIGKILL detectable in ~2s (a killed worker
@@ -523,4 +567,111 @@ def test_chaos_prefill_process_killed_midburst_all_requests_survive(
             if w.poll() is None:
                 os.kill(w.pid, signal.SIGKILL)
             w.wait()
-            w.stdout.close()
+        for log in logs:
+            log.close()
+
+
+# ---------------------------------------------------------------------------
+# the worker supervisor: respawn, epochs, retirement (tools/serve.py)
+# ---------------------------------------------------------------------------
+
+_STANDIN_WORKER = """
+import os, sys, time
+rank, log = sys.argv[1], sys.argv[2]
+with open(log, "a") as fh:
+    fh.write(f"{rank} {os.environ['DCN_EPOCH']} "
+             f"{os.environ.get('DCN_CHAOS', '-')}\\n")
+print(f"prefill worker rank {rank} ready", flush=True)
+time.sleep(600)
+"""
+
+
+def _wait_for(pred, what, budget=60.0):
+    end = time.monotonic() + budget
+    while time.monotonic() < end:
+        got = pred()
+        if got:
+            return got
+        time.sleep(0.05)
+    raise AssertionError(f"timed out waiting for {what}")
+
+
+@pytest.fixture
+def standin_fleet(tmp_path, monkeypatch):
+    """A `PrefillWorkerSupervisor` over two stand-in workers that say the
+    ready line and sleep: the supervisor's own lifecycle, without a model
+    build a process (a respawn is cross-process by nature)."""
+    from tools import serve as serve_mod
+    script = tmp_path / "worker.py"
+    script.write_text(_STANDIN_WORKER)
+    log = tmp_path / "incarnations.log"
+    monkeypatch.setenv("PIPEEDGE_PREFILL_CHAOS", "kill@2")
+    sups = []
+
+    def make(**kw):
+        sup = serve_mod.PrefillWorkerSupervisor(
+            [str(script), str(log)], ranks=(1, 2), **kw)
+        sups.append(sup)
+        sup.wait_ready(timeout=60)
+        return sup
+
+    make.incarnations = lambda: [tuple(line.split()) for line in
+                                 log.read_text().splitlines()]
+    yield make
+    for sup in sups:
+        sup.stop()
+
+
+@pytest.mark.fleet
+def test_supervisor_respawns_a_killed_worker_at_the_next_epoch(
+        standin_fleet):
+    """A SIGKILLed worker comes back as a new process carrying
+    DCN_EPOCH + 1 (what lets its JOIN through the decode side's death
+    fence) and says ready again; the chaos spec armed its first
+    incarnation only; the other rank is not touched."""
+    sup = standin_fleet()
+    before = sup.snapshot()
+    assert {r: v["epoch"] for r, v in before.items()} == {"1": 0, "2": 0}
+    os.kill(before["1"]["pid"], signal.SIGKILL)
+    def respawned():
+        snap = sup.snapshot()
+        one = snap.get("1", {})
+        return snap if one.get("epoch") == 1 and one["alive"] else None
+
+    after = _wait_for(respawned, "the respawn of rank 1")
+    assert after["1"]["pid"] != before["1"]["pid"]
+    assert after["2"] == before["2"]
+    sup.wait_ready(timeout=60)
+    _wait_for(lambda: len(standin_fleet.incarnations()) == 3,
+              "the respawn's line")
+    assert sorted(standin_fleet.incarnations()) == [
+        ("1", "0", "kill@2"), ("1", "1", "-"), ("2", "0", "-")]
+
+
+@pytest.mark.fleet
+def test_supervisor_without_respawn_lets_a_dead_worker_go(standin_fleet):
+    sup = standin_fleet(respawn=False)
+    os.kill(sup.snapshot()["2"]["pid"], signal.SIGKILL)
+    _wait_for(lambda: "2" not in sup.snapshot(), "rank 2's record to go")
+    time.sleep(3 * sup.RESPAWN_DELAY_S)
+    assert set(sup.snapshot()) == {"1"}
+    assert len(standin_fleet.incarnations()) == 2
+
+
+@pytest.mark.fleet
+def test_supervisor_retired_rank_stays_down_and_rejoins_at_epoch_plus_one(
+        standin_fleet):
+    """Scale-in then scale-out: a retired rank is not resurrected by the
+    watch loop, and bringing it back continues its epoch sequence, so the
+    rejoin is fenced against the retired incarnation like a respawn."""
+    sup = standin_fleet()
+    assert sup.retire_rank(2) and not sup.retire_rank(2)
+    time.sleep(3 * sup.RESPAWN_DELAY_S)
+    assert set(sup.snapshot()) == {"1"} and sup.ranks == (1,)
+    assert sup.add_rank() == 2              # the lowest retired id first
+    sup.wait_ready(timeout=60)
+    snap = sup.snapshot()
+    assert snap["2"]["epoch"] == 1 and snap["2"]["alive"]
+    assert snap["1"]["epoch"] == 0
+    with pytest.raises(ValueError, match="already active"):
+        sup.add_rank(2)

@@ -228,11 +228,23 @@ def test_token_budget_head_keeps_queue_position():
                               registry=prom.Registry(), token_budget=100)
     h1 = ctl.admit("interactive", tokens=50)
     h2 = ctl.admit("interactive", tokens=50)
+    # the order is the controller's own: the tickets its grant loop
+    # woke, read where it runs, under the controller's lock (the two
+    # waiters both wake from ONE release, and which thread runs first
+    # after that says nothing about who was granted first)
     order = []
+    names = {80: "big", 10: "small"}
+    grant_locked = ctl._grant_locked
+
+    def recording_grants(now, to_wake, expired):
+        before = len(to_wake)
+        grant_locked(now, to_wake, expired)
+        order.extend(names[t.tokens] for t in to_wake[before:])
+
+    ctl._grant_locked = recording_grants
 
     def waiter(name, tokens):
         ctl.admit("interactive", tokens=tokens)
-        order.append(name)
 
     def wait_depth(n, budget=120.0):
         end = time.monotonic() + budget
@@ -428,6 +440,49 @@ def test_paged_thread_driven_token_identical_and_prefix_shared(pipe):
 # ---------------------------------------------------------------------------
 # KV shipping: int8 bit-path + disaggregated loopback acceptance
 # ---------------------------------------------------------------------------
+
+def test_shared_prefix_phase_hits_in_the_window_and_leaks_nothing(pipe):
+    """A phase of shared-prefix traffic (loadgen's `shared:PFX:TOTAL:POOL`
+    prompts, every prefix one of POOL seed-derived ones) read as the
+    DIFFERENCE of two trie snapshots: warm-up misses before the window do
+    not dilute it, every request after a prefix's first is a hit, every
+    answer is the solo answer, and the pool closes the phase with every
+    page free or cached."""
+    import random
+
+    from tools.loadgen import prompt_ids
+    kv = _backend(pipe, n_pages=32, page_size=4)
+    warm = ContinuousBatcher(pipe, kv=kv)
+    for i, ids in enumerate(_prompts(2, lens=(9,), seed0=5)):
+        warm.submit(f"w{i}", ids, new_tokens=2)     # two sure misses
+    warm.run()
+    before = kv.trie.stats()
+    assert before["lookups"] == 2 and before["hits"] == 0
+
+    rng = random.Random(3)
+    prompts = [np.asarray([prompt_ids("shared:8:12:2", rng, base_seed=3)],
+                          np.int64) for _ in range(8)]
+    prefixes = {tuple(ids[0, :8]) for ids in prompts}
+    assert len(prefixes) == 2                       # both pool slots drawn
+    results = {}
+    for i, ids in enumerate(prompts):
+        # one at a time: a request's pages are published when it is
+        # admitted, so the next one with its prefix finds them
+        batcher = ContinuousBatcher(pipe, kv=kv)
+        batcher.submit(i, ids, new_tokens=4)
+        results.update(batcher.run())
+    after = kv.trie.stats()
+    lookups = after["lookups"] - before["lookups"]
+    hits = after["hits"] - before["hits"]
+    assert lookups == len(prompts)
+    assert hits == len(prompts) - len(prefixes)
+    assert after["pages_reused_total"] - before["pages_reused_total"] \
+        >= 2 * hits                                 # 8 tokens = 2 pages
+    for i, ids in enumerate(prompts):
+        np.testing.assert_array_equal(
+            results[i], np.asarray(pipe.generate(ids, 4)))
+    assert kv.pool.free_pages + after["pages_cached"] == kv.pool.n_pages
+
 
 def test_int8_kv_ship_bit_path(pipe):
     """The int8 ship path is deterministic bit-for-bit (socket bytes =

@@ -40,7 +40,7 @@ def _cpu_multiprocess_collectives_available() -> bool:
            "device_put-to-global-mesh); needs jax >= 0.5's Gloo CPU "
            "collectives or a real TPU fleet",
     strict=True, run=True)
-def test_two_process_spmd_pipeline():
+def test_two_process_spmd_pipeline(tmp_path):
     with socket.create_server(("127.0.0.1", 0)) as s:
         coord = f"127.0.0.1:{s.getsockname()[1]}"
     script = os.path.join(REPO, "tests", "multihost_spmd_main.py")
@@ -48,18 +48,24 @@ def test_two_process_spmd_pipeline():
     # config, but each child must bring up its own 4-device CPU backend
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["PYTHONPATH"] = REPO
+    # each rank's log goes to a file: the ranks are waited for in turn, and
+    # a pipe nobody reads yet holds 64 KB before its writer blocks
+    logs = [open(tmp_path / f"rank{r}.log", "w+") for r in range(2)]
     procs = [subprocess.Popen([sys.executable, script, str(r), "2", coord],
-                              env=env, stdout=subprocess.PIPE,
+                              env=env, stdout=log,
                               stderr=subprocess.STDOUT, text=True)
-             for r in range(2)]
+             for r, log in enumerate(logs)]
     outs = []
     try:
         for p in procs:
-            out, _ = p.communicate(timeout=240)
-            outs.append(out)
+            p.wait(timeout=240)
     finally:
-        for p in procs:
+        for p, log in zip(procs, logs):
             p.kill()
+            p.wait()
+            log.seek(0)
+            outs.append(log.read())
+            log.close()
     for r, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"rank {r}:\n{out}"
         assert f"MULTIHOST-OK rank={r} local=4 global=8" in out, out

@@ -284,6 +284,129 @@ def test_mb_latency_segments_by_round():
         (e["id"], e["name"]) for e in flows})
 
 
+# -- the report CLI on a synthetic trace ---------------------------------
+
+def _report_cli(monkeypatch, capsys, *argv):
+    """`tools/trace_report.py` in process: (exit code, printed record)."""
+    from tools import trace_report
+    monkeypatch.setattr(sys, "argv", ["trace_report.py", *argv])
+    code = trace_report.main()
+    out = capsys.readouterr().out.strip()
+    return code, (json.loads(out.splitlines()[-1]) if out else None)
+
+
+def _synthetic_input(tmp_path, shape, spans=None):
+    """The two-stage timeline, its first microbatch tagged as request q1,
+    written as either input shape the CLI takes."""
+    spans = _two_stage_spans() if spans is None else spans
+    spans = [dict(s, rid="q1") if s.get("mb") == 0 else s for s in spans]
+    path = tmp_path / f"{shape}.json"
+    if shape == "trace":
+        chrome_trace.dump_trace(spans, str(path))
+    else:
+        path.write_text(json.dumps({
+            "bundle": "pipeedge-postmortem", "trigger": "deadline",
+            "rid": "q1", "spans": spans}))
+    return str(path)
+
+
+@pytest.mark.parametrize("shape", ["trace", "bundle"])
+def test_trace_report_cli_reads_both_input_shapes(
+        tmp_path, monkeypatch, capsys, shape):
+    """A merged Chrome trace and a postmortem bundle give the same
+    report: the bubble, the wire share and the microbatch percentiles of
+    the timeline that was written, and `--require-spans` is satisfied."""
+    path = _synthetic_input(tmp_path, shape)
+    code, rec = _report_cli(monkeypatch, capsys, path, "--require-spans")
+    assert code == 0 and rec["trace"] == path
+    assert rec["spans"] == 6 and rec["bubble_pct"] == 50.0
+    assert rec["edges"]["r0:send->r1"]["share_pct"] == 2.5
+    assert rec["mb_latency"]["n"] == 2 and rec["mb_latency"]["p50_ms"] == 20.0
+    assert rec["requests"]["n"] == 1
+    assert rec["requests"]["worst"][0]["rid"] == "q1"
+
+
+@pytest.mark.parametrize("shape,rid,code", [("trace", "q1", 0),
+                                            ("bundle", "q1", 0),
+                                            ("trace", "q9", 3)])
+def test_trace_report_cli_request_timeline(tmp_path, monkeypatch, capsys,
+                                           shape, rid, code):
+    """`--request`: a known id prints its timeline (from a bundle, with
+    what triggered the bundle) and exits 0; an unknown one exits 3."""
+    path = _synthetic_input(tmp_path, shape)
+    got, rec = _report_cli(monkeypatch, capsys, path, "--request", rid)
+    assert got == code and rec["rid"] == rid and rec["found"] == (code == 0)
+    if code == 0:
+        assert rec["stages"] == [0, 1] and rec["total_ms"] == 20.0
+        assert rec["dominant_stall"]["busy_ms"] == 10.0
+        assert rec.get("bundle_trigger") == (
+            "deadline" if shape == "bundle" else None)
+
+
+@pytest.mark.parametrize("flag", ["--require-spans",
+                                  "--require-local-edges"])
+def test_trace_report_cli_gates_fail_on_a_trace_without_the_thing(
+        tmp_path, monkeypatch, capsys, flag):
+    """The machine-checkable gates: a trace with no microbatch spans
+    fails `--require-spans`, one whose edges all rode the wire fails
+    `--require-local-edges`, and the record is printed either way."""
+    spans = ([s for s in _two_stage_spans() if s["cat"] != "stage"]
+             if flag == "--require-spans" else None)
+    path = _synthetic_input(tmp_path, "trace", spans)
+    code, rec = _report_cli(monkeypatch, capsys, path, flag)
+    assert code == 1 and rec["trace"] == path
+    assert _report_cli(monkeypatch, capsys, path)[0] == 0
+
+
+def test_trace_report_cli_wants_exactly_one_source(tmp_path, monkeypatch,
+                                                   capsys):
+    path = _synthetic_input(tmp_path, "trace")
+    for argv in ((), (path, "--fleet", "http://127.0.0.1:1")):
+        with pytest.raises(SystemExit) as err:
+            _report_cli(monkeypatch, capsys, *argv)
+        assert err.value.code == 2
+        assert "exactly one" in capsys.readouterr().err
+
+
+# -- percentile helpers --------------------------------------------------
+
+@pytest.mark.parametrize("vals,q,want", [
+    ([], 50, 0.0),                      # an empty series reports 0, no raise
+    ([7.0], 99, 7.0),
+    ([3.0, 1.0, 2.0], 50, 2.0),         # unsorted input
+    ([1.0, 2.0, 3.0, 4.0], 0, 1.0),
+    ([1.0, 2.0, 3.0, 4.0], 100, 4.0),
+    ([1.0, 2.0, 3.0, 4.0], 50, 3.0),    # nearest rank: round(1.5) = 2
+    (list(range(1, 101)), 99, 99),
+    (list(range(1, 101)), 95, 95),
+], ids=["empty", "single", "unsorted", "q0", "q100", "even-n-median",
+        "p99-of-100", "p95-of-100"])
+def test_percentile_is_nearest_rank(vals, q, want):
+    given = list(vals)
+    assert report.percentile(vals, q) == want
+    assert vals == given                    # sorted a copy, not the input
+    if vals:
+        # the ONE definition: the load generator's summary agrees
+        from tools import loadgen
+        assert loadgen._percentile(vals, q) == round(want, 3)
+
+
+def test_segment_medians_percentiles_by_category():
+    """`{cat/name: n, p50, p95}` over the categories asked for, open spans
+    and other categories left out."""
+    ms = 1_000_000
+    spans = [{"cat": "stage", "name": "dispatch", "t0": 0, "t1": k * ms}
+             for k in (1, 2, 3, 4, 100)]
+    spans += [{"cat": "stage", "name": "dispatch", "t0": 0, "t1": None},
+              {"cat": "wire", "name": "send->r1", "t0": 0, "t1": 5 * ms},
+              {"cat": "serve", "name": "generate", "t0": 0, "t1": 9 * ms}]
+    seg = report.segment_medians(spans)
+    assert seg == {"stage/dispatch": {"n": 5, "p50_ms": 3.0, "p95_ms": 100.0},
+                   "wire/send->": {"n": 1, "p50_ms": 5.0, "p95_ms": 5.0}}
+    assert list(report.segment_medians(
+        spans, cats=frozenset(("serve",)))) == ["serve/generate"]
+
+
 # -- prometheus metrics -------------------------------------------------
 
 _PROM_LINE = re.compile(
@@ -327,6 +450,21 @@ def test_metrics_registry_renders_prometheus_text():
         r.gauge("edge_wire_bytes_total", "wrong type")
     with pytest.raises(ValueError):
         c.inc(-1)
+
+
+def test_parse_exemplars_roundtrip():
+    from pipeedge_tpu.telemetry import metrics as prom
+    reg = prom.Registry()
+    h = reg.histogram("bench_test_latency_seconds", "x",
+                      buckets=(0.1, 1.0))
+    h.observe(0.05, exemplar="q1")
+    h.observe(0.5, exemplar="q2")
+    h.observe(5.0, exemplar="q3")
+    rows = prom.parse_exemplars(reg.render(),
+                                "bench_test_latency_seconds")
+    assert {(r["le"], r["trace_id"]) for r in rows} == {
+        ("0.1", "q1"), ("1", "q2"), ("+Inf", "q3")}
+    assert prom.parse_exemplars(reg.render(), "other_family") == []
 
 
 def test_metrics_monitoring_snapshot_bridge(tmp_path, monkeypatch):
@@ -398,9 +536,12 @@ def test_traced_dcn_round_and_report(tmp_path):
             "--sched-timeout", "120", "--trace-spans", str(trace)]
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
                DCN_CONNECT_TIMEOUT="30")
-    worker = subprocess.Popen(common + ["1", "2"] + opts, cwd=tmp_path,
-                              env=env, stdout=subprocess.PIPE,
-                              stderr=subprocess.STDOUT, text=True)
+    # the worker's log goes to a file: a pipe nobody reads while rank 0
+    # runs holds 64 KB, and a worker that has logged that much blocks
+    with open(tmp_path / "worker.log", "w") as log:
+        worker = subprocess.Popen(common + ["1", "2"] + opts, cwd=tmp_path,
+                                  env=env, stdout=log,
+                                  stderr=subprocess.STDOUT, text=True)
     try:
         data = subprocess.run(common + ["0", "2"] + opts, cwd=tmp_path,
                               env=env, capture_output=True, text=True,

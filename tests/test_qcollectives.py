@@ -274,3 +274,59 @@ def test_error_bound_monotonic():
     b44 = qcollectives.qpsum_error_bound(1.0, 4, 4)
     assert b84 < b88
     assert b84 < b44
+
+
+@pytest.mark.parametrize("n_tp,bit", [(2, 8), (4, 8), (2, 4)])
+def test_tp_model_quantized_top1_agreement(n_tp, bit):
+    """The whole tiny ViT with every block's Megatron psums quantized
+    (exact math, quantized comms): its logits pick the exact model's
+    top-1 on >= 0.99 of a seeded batch, and the trace tally counts two
+    psum sites a block, each moving fewer bytes than the raw psum."""
+    from pipeedge_tpu.models import registry
+    from pipeedge_tpu.parallel import tensor
+
+    name = "pipeedge/test-tiny-vit"
+    entry = registry.get_model_entry(name)
+    cfg, family = entry.config, entry.family
+    assert cfg.num_attention_heads % n_tp == 0
+    params = registry.module_shard_factory(
+        name, None, 1, registry.get_model_layers(name),
+        dtype=jnp.float32, unroll=True)[1]
+    mesh = _mesh(n_tp)
+    blocks = tuple(tensor.shard_block_params(cfg, bp, mesh)
+                   for bp in params["blocks"])
+    specs, local = tensor.family_tp_plan(cfg)
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(
+        32, cfg.num_channels, cfg.image_size, cfg.image_size)), jnp.float32)
+
+    def logits(mode_bits):
+        # the bitwidth binds at trace time: a fresh body and wrapper
+        tensor.set_tp_quant_bits(mode_bits)
+        try:
+            body = jax_compat.shard_map(
+                partial(local, cfg=cfg, axis="tp"), mesh=mesh,
+                in_specs=(specs, P()), out_specs=P())
+
+            @jax.jit
+            def run(ep, fp, bps, x):
+                h = family.embed(ep, x, cfg)
+                for bp in bps:
+                    h = body(bp, h)
+                return family.finalize(fp, h, cfg)
+
+            return np.asarray(run(params["embeddings"], params["final"],
+                                  blocks, x))
+        finally:
+            tensor.set_tp_quant_bits(0)
+
+    exact = logits(0)
+    qcollectives.reset_trace_tally()
+    quant = logits(bit)
+    tally = qcollectives.trace_tally()
+    qcollectives.reset_trace_tally()
+    assert not np.array_equal(exact, quant)
+    assert np.mean(exact.argmax(-1) == quant.argmax(-1)) >= 0.99
+    assert len(tally) == 2 * len(blocks)
+    assert all(t["kind"] == "psum" and 0 < t["wire_bytes"] < t["raw_bytes"]
+               for t in tally)

@@ -339,9 +339,12 @@ def _run_rebalance_fleet(tmp_path, chaos, rebalance_mode="auto",
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
                DCN_CONNECT_TIMEOUT="30")
     wenv = dict(env, DCN_CHAOS=chaos) if chaos else env
-    worker = subprocess.Popen(common + ["1", "2"] + opts, cwd=tmp_path,
-                              env=wenv, stdout=subprocess.PIPE,
-                              stderr=subprocess.STDOUT, text=True)
+    # the worker's log goes to a file: a pipe nobody reads while rank 0
+    # runs holds 64 KB, and a worker that has logged that much blocks
+    with open(tmp_path / "worker.log", "w") as log:
+        worker = subprocess.Popen(common + ["1", "2"] + opts, cwd=tmp_path,
+                                  env=wenv, stdout=log,
+                                  stderr=subprocess.STDOUT, text=True)
     try:
         data = subprocess.run(common + ["0", "2"] + opts, cwd=tmp_path,
                               env=env, capture_output=True, text=True,

@@ -362,6 +362,33 @@ def test_analyze_spans_requests_section():
     assert rec2["requests"] == {}
 
 
+def test_worst_exemplar_of_a_scrape_resolves_to_its_timeline():
+    """The p99 -> trace link, end to end without a server: a latency
+    histogram's exposition is parsed back into exemplar rows, the worst
+    row names the slow request, and that id resolves against the span
+    list to a timeline with a dominant stall."""
+    reg = prom.Registry()
+    h = reg.histogram("pipeedge_serve_request_latency_seconds", "t",
+                      buckets=(0.01, 0.1))
+    spans = []
+    for rid, ms in (("q1", 4), ("q2", 60), ("q3", 7)):
+        h.observe(ms / 1e3, exemplar=rid)
+        spans += [
+            {"cat": "serve", "name": "admit:interactive", "rank": 0,
+             "t0": 0, "t1": _ms(1), "rid": rid},
+            {"cat": "compute", "name": "stage1", "rank": 0, "stage": 1,
+             "mb": 0, "t0": _ms(1), "t1": _ms(ms), "rid": rid}]
+    rows = prom.parse_exemplars(reg.render(),
+                                "pipeedge_serve_request_latency_seconds")
+    assert {r["trace_id"] for r in rows} == {"q2", "q3"}   # a row a bucket
+    worst = max(rows, key=lambda r: r["value"])
+    assert worst["trace_id"] == "q2" and worst["le"] == "0.1"
+    tl = report.request_timeline(spans, worst["trace_id"])
+    assert tl["found"] and tl["total_ms"] == 60.0
+    assert tl["dominant_stall"]["segment"] == "stage1/compute"
+    assert tl["dominant_stall"]["busy_ms"] == 59.0
+
+
 # -- loadgen worst-N -----------------------------------------------------
 
 def test_loadgen_stats_worst_n_and_deadline_rids():
@@ -493,6 +520,11 @@ def test_traced_serve_stall_attribution(tmp_path):
                 raise RuntimeError(f"server died: {proc.stdout.read()}")
         else:
             raise RuntimeError("server never came up")
+        # keep reading: a pipe nobody reads holds 64 KB, and a server that
+        # has logged that much (XLA says two long lines for every program
+        # it loads from the compile cache) blocks in its next write
+        threading.Thread(target=lambda: [None for _ in proc.stdout],
+                         daemon=True).start()
 
         # 0) warmup: the first request pays the XLA compiles, and its
         #    timeline would (correctly!) name the compile as its
@@ -543,6 +575,12 @@ def test_traced_serve_stall_attribution(tmp_path):
             in metrics
         assert "pipeedge_postmortems_written_total" in metrics
         assert 'trace_id="' in metrics
+        # the rows parse back, and every one names a request this
+        # server answered (resolved against the trace below)
+        exemplars = prom.parse_exemplars(
+            metrics, "pipeedge_serve_request_latency_seconds")
+        assert exemplars and all(e["trace_id"] for e in exemplars)
+        worst_rid = max(exemplars, key=lambda e: e["value"])["trace_id"]
     finally:
         proc.send_signal(signal.SIGTERM)   # trace written on unwind
         try:
@@ -580,3 +618,9 @@ def test_traced_serve_stall_attribution(tmp_path):
     assert rec["requests"]["n"] >= 2
     assert any(w["rid"] == resp504["rid"] or w["rid"] == rid
                for w in rec["requests"]["worst"])
+    # the scrape's worst exemplar resolves to a timeline of its own (the
+    # p99 -> trace link), in process: the trace is already on disk
+    with open(trace_path, encoding="utf8") as fh:
+        spans = chrome_trace.trace_to_spans(json.load(fh))
+    linked = report.request_timeline(spans, worst_rid)
+    assert linked["found"] and linked["dominant_stall"] is not None

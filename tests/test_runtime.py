@@ -120,7 +120,7 @@ def test_scheduler_driven_partition(tmp_path):
 
 def test_per_edge_send_telemetry_csvs(tmp_path):
     """Each inter-stage edge gets its own send telemetry key/CSV with real
-    wire bytes per microbatch (VERDICT r1 #1): the 8-bit quantized edge 0
+    wire bytes per microbatch: the 8-bit quantized edge 0
     reports far fewer Mbits than the raw mid-block edge 1."""
     import csv as csvmod
     proc = _run(tmp_path, "0", "3", "-m", MODEL, "-pt", "1,4,5,6,7,8",

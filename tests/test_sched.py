@@ -340,31 +340,6 @@ def test_profiles_upsert_semantics(tmp_path):
         profiles.ProfilerResults.load(str(results_yml))
 
 
-def test_scaling_projection_from_committed_profiles():
-    """The round-5 scaling projection (tools/project_scaling.py) is
-    deterministic given the committed chip profiles: the native
-    scheduler balances ViT-L b=8 across 8 v5e stages and the projected
-    speedup beats the >=4x north star in every comm scenario."""
-    import importlib.util
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "project_scaling", os.path.join(repo, "tools",
-                                        "project_scaling.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    r = mod.project("google/vit-large-patch16-224", 8, 8)
-    assert len(r["partition"]) == 8
-    # contiguous cover of the 96 sublayers
-    assert r["partition"][0][0] == 1 and r["partition"][-1][1] == 96
-    for proj in r["projected"].values():
-        assert proj["speedup_vs_single"] >= 4.0, r["projected"]
-    fa = r["fused_anchor_projection"]
-    assert fa is not None
-    for k in ("overlapped_comm", "serialized_ici_1600gbps",
-              "serialized_dcn_100gbps"):
-        assert fa[k]["speedup_vs_single"] >= 4.0, fa
-
-
 def test_build_tree_is_trusted_only_for_its_sources(monkeypatch, tmp_path):
     """native/build is git-ignored and outlives checkouts and copies: its
     binaries count as built only while the digest stamped beside them is

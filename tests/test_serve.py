@@ -6,6 +6,7 @@ import socket
 import struct
 import subprocess
 import sys
+import threading
 import time
 import urllib.request
 
@@ -52,6 +53,11 @@ def _spawn_server(extra_args=()):
                 raise RuntimeError(f"server died: {proc.stdout.read()}")
         else:
             raise RuntimeError("server never came up")
+        # keep reading: a pipe nobody reads holds 64 KB, and a server that
+        # has logged that much (XLA says two long lines for every program
+        # it loads from the compile cache) blocks in its next write
+        threading.Thread(target=lambda: [None for _ in proc.stdout],
+                         daemon=True).start()
         yield port
     finally:
         proc.terminate()
@@ -437,7 +443,7 @@ def _await_live(ex, rid):
 def test_executor_stop_wakes_pending_submitter():
     """stop() fails the waiter of a request that never got a slot, not
     only those in flight: "b" sits in `pending` behind "a" (max_active=1)
-    and its wait raises instead of hanging forever (ADVICE.md r5).
+    and its wait raises instead of hanging forever.
 
     Deterministic by construction (this flaked under full-suite load
     when it was sleep-paced): both clients submit, in order, before the
@@ -614,7 +620,7 @@ def test_streaming_disconnect_cancels_generation(tight_server):
     """A streaming client that disconnects mid-response must not keep
     decoding to the cap on a dead socket: the handler's write failure
     sets the request's cancel flag, the executor completes it early, and
-    the admission slot frees (ADVICE.md r5). Verified via the server's
+    the admission slot frees. Verified via the server's
     cumulative token counter: the aborted 40-token request generates only
     a handful of tokens."""
     port = tight_server

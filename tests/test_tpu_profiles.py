@@ -1,6 +1,6 @@
 """Committed real-chip (TPU v5e) profiles drive the native scheduler:
-profile -> models.yml/device_types.yml -> sched-pipeline DP partition
-(BASELINE.md config 3), no TPU needed at test time.
+profile -> models.yml/device_types.yml -> sched-pipeline DP partition,
+no TPU needed at test time.
 
 Skipped until profiles/tpu/*.yml are generated on the chip
 (profiles/README.md recipe), and skipped wherever the native

@@ -4,10 +4,10 @@ Separate from test_weights.py because that module is fleet-marked
 (subprocess CLIs); these tests are in-process, only slow (full-size
 compiles + two forwards per family).
 
-One anchor per model family (VERDICT r3 item 7): the torch side is FROZEN
+One anchor per model family: the torch side is FROZEN
 at fixture-generation time (tools/make_parity_fixture.py), so HF init-
 recipe drift and this framework's conversion/forward drift are both
-caught for every family — not just ViT, as in rounds 2-3.
+caught for every family, not just ViT.
 """
 import numpy as np
 import pytest

@@ -222,7 +222,7 @@ class _Stats:
         self.during_window = during_window
         self.during = []
         # per-class worst-N (latency_ms, rid) of served requests: the
-        # cross-reference from a bench run into trace_report --request
+        # cross-reference from a load run into trace_report --request
         # and the flight recorder's postmortem bundles
         self.worst = {c: [] for c in classes}
         # request ids the server answered 504 (each one triggered a
@@ -331,8 +331,8 @@ def arrival_offsets(n, qps, arrival="uniform", rng=None, duration_s=None):
     (see `parse_ramp_spec`) ignores `n`/`qps` and shapes the rate over
     `duration_s` instead — the arrival count falls out of the rate
     integral. Pure: same (n, qps, arrival, rng seed) -> same offsets,
-    so a serve-recipe run is reproducible end-to-end (the bench-record
-    contract)."""
+    so a load run is reproducible end-to-end from its seed (which rides
+    the report)."""
     ramp = parse_ramp_spec(arrival)
     if ramp is not None:
         if duration_s is None or duration_s <= 0:
@@ -388,8 +388,8 @@ def run_load(url, duration_s, qps, mix=None, slo_ms=None,
              arrival="uniform", burst=None):
     """Offer `qps` requests/s for `duration_s` with the per-class `mix`;
     return the report dict (see module doc for the outcome taxonomy).
-    Importable — the overload acceptance test, the CI smoke, and the
-    benchkit serve recipe all call this in-process instead of shelling
+    Importable — the overload acceptance test and the chaos targets
+    (tools/chaos_dcn.py) call this in-process instead of shelling
     out. `seed` drives EVERYTHING random end-to-end (arrival process,
     class draw, prompt token sampling) and rides the report. `burst`
     (see `parse_burst_spec`) injects a seeded mid-run long-prompt spike
@@ -498,7 +498,7 @@ def run_load(url, duration_s, qps, mix=None, slo_ms=None,
               "classes": {}, "totals": dict.fromkeys(OUTCOMES, 0)}
     if ramp is not None:
         # parsed spec echoed alongside the raw `arrival` string: the
-        # autoscale CI job and bench recipe read the shape from here
+        # autoscale CI job reads the shape from here
         report["ramp"] = dict(ramp)
     all_lat = []
     for c in classes:
@@ -524,8 +524,8 @@ def run_load(url, duration_s, qps, mix=None, slo_ms=None,
         }
         for k in OUTCOMES:
             report["totals"][k] += counts[k]
-    # aggregate served-latency percentiles: the serve recipe's
-    # latency_ms block (per-class views stay under classes.*)
+    # aggregate served-latency percentiles (per-class views stay under
+    # classes.*)
     report["latency_ms"] = {"p50": _percentile(all_lat, 50),
                             "p95": _percentile(all_lat, 95),
                             "p99": _percentile(all_lat, 99),
@@ -536,7 +536,7 @@ def run_load(url, duration_s, qps, mix=None, slo_ms=None,
         "max": max(ra) if ra else None,
         "distinct": len({round(v, 3) for v in ra})}
     # 504'd request ids: each one triggered a deadline postmortem bundle
-    # server-side — the bench-to-bundle cross-reference
+    # server-side — the load-run-to-bundle cross-reference
     report["deadline_rids"] = stats.deadline_rids
     report["first_error"] = stats.first_error
     if burst is not None:
@@ -563,8 +563,8 @@ def run_load(url, duration_s, qps, mix=None, slo_ms=None,
 
 
 def merge_class_map(pairs, what, default):
-    """CLI `CLASS=VALUE` pairs merged over `default` — shared with the
-    benchkit serve recipe (raises ValueError on malformed pairs)."""
+    """CLI `CLASS=VALUE` pairs merged over `default` (raises ValueError
+    on malformed pairs)."""
     return {**default, **parse_class_map(pairs, what)}
 
 

@@ -1,6 +1,6 @@
 """Generate the committed HF-torch parity fixtures (one per model family).
 
-VERDICT r2 item 5 / r3 item 7 (real-weights accuracy): pretrained
+Real-weights accuracy: pretrained
 checkpoints are not downloadable in this zero-egress environment
 (docs/REAL_WEIGHTS.md logs the attempt), so these fixtures anchor the
 parity claim per family instead: HF torch's own float32 logits on a fixed

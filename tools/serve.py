@@ -834,8 +834,8 @@ class _Service:
         if self.chunked_prefill or self.step_join:
             # iteration-level scheduling state: the configured chunk
             # size, the EFFECTIVE one (brownout may have clamped it),
-            # and how many chunk waves have run — the serve_kv bench's
-            # chunked-arm evidence (docs/SERVING.md)
+            # and how many chunk waves have run: that chunked prefill
+            # engaged (docs/SERVING.md)
             s["scheduler"] = {
                 "chunked_prefill": self.chunked_prefill,
                 "chunk_tokens": self.executor.chunk_tokens,

@@ -102,31 +102,13 @@ def _emit_profiles(args, spans) -> None:
 
 
 def _load_spans(path: str):
-    """Span dicts from any input shape: a merged Chrome-trace JSON
-    (`--trace-spans` output), a flight-recorder postmortem bundle
-    (telemetry/flight.py — its `spans` slice is already span dicts), or
-    a benchkit trajectory record/artifact whose serve block names the
-    trace it produced (`serve.trace`) — so `trace_report BENCH_r06.json
-    --request q17` resolves a record's p99 exemplar without the caller
-    digging the trace path out by hand."""
+    """Span dicts from either input shape: a merged Chrome-trace JSON
+    (`--trace-spans` output) or a flight-recorder postmortem bundle
+    (telemetry/flight.py — its `spans` slice is already span dicts)."""
     with open(path, encoding="utf8") as f:
         doc = json.load(f)
     if isinstance(doc, dict) and doc.get("bundle") == "pipeedge-postmortem":
         return list(doc.get("spans", ())), doc
-    if isinstance(doc, dict) and str(doc.get("schema",
-                                             "")).startswith("pipeedge-bench"):
-        records = ([doc] if "scenario" in doc
-                   else list(doc.get("records", ())))
-        for rec in records:
-            trace = (rec.get("serve") or {}).get("trace")
-            if trace:
-                if not os.path.isabs(trace):
-                    trace = os.path.join(os.path.dirname(
-                        os.path.abspath(path)), trace)
-                with open(trace, encoding="utf8") as fh:
-                    return chrome_trace.trace_to_spans(json.load(fh)), None
-        raise SystemExit(f"{path} is a bench record but no scenario in "
-                         "it carries a serve.trace path")
     return chrome_trace.trace_to_spans(doc), None
 
 
