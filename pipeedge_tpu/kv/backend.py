@@ -242,9 +242,7 @@ class PagedKvBackend:
         # disaggregated tokens are identical to colocated ones
         token, req.step_ids, req.rng = req.pick(
             jnp.asarray(handle["logits"])[:, None], req.rng)
-        req.tokens.append(token)
-        if req.on_token is not None:
-            req.on_token(0, token)
+        req.tokens.append(token)    # the executor hands it on (`_emit`)
         done = len(req.tokens) >= req.new_tokens
         if not done and req.eos_token is not None:
             hit = np.asarray(token) == req.eos_token

@@ -103,10 +103,17 @@ M_ROWS = prom.REGISTRY.counter(
     "rows of the decode steps that went out as one program for every "
     "running row: kind=live the rows that stood at the step, kind=slots "
     "the rows its program computed (its rung)")
+M_WAKEUPS = prom.REGISTRY.counter(
+    "pipeedge_wait_wakeups_total",
+    "times a caller blocked in the executor's wait() woke: kind=own its "
+    "request had ended (or the executor had), kind=other it had not and "
+    "the caller slept again (0: a request's end wakes its own waiter)")
 M_STEPS.declare(executor="wave")
 M_CHUNKS.declare(executor="wave")
 for _kind in ("live", "slots"):
     M_ROWS.declare(kind=_kind)
+for _kind in ("own", "other"):
+    M_WAKEUPS.declare(kind=_kind)
 
 
 class _Rows(NamedTuple):
@@ -138,8 +145,11 @@ class _Request:
     prefix: Optional[Dict] = None    # precompute_prefix handle
     eos_token: Optional[int] = None  # stop early once every row emitted it
     pad_token: Optional[int] = None  # fills rows past their own eos
-    # streaming hook: fires (step, [B] device tokens) as each pick lands
+    # streaming hook: fires (step, [B] tokens) as each pick lands
     on_token: Optional[object] = None
+    # the caller's handle of a stream the executor's `on_tokens` sink
+    # writes (tools/serve.py): handed back with every token, never read
+    stream: Optional[object] = None
     # cooperative cancellation: an is_set()-style flag (threading.Event)
     # checked after each pick — a cancelled request completes with the
     # tokens decoded so far, freeing its cache slots/admission slot early
@@ -201,7 +211,8 @@ def _build_request(pipe: DecodePipeline, rid, ids, new_tokens: int,
                    prefix: Optional[Dict],
                    on_token=None, cancel=None,
                    deadline: Optional[float] = None,
-                   shipped: Optional[Dict] = None) -> _Request:
+                   shipped: Optional[Dict] = None,
+                   stream=None) -> _Request:
     """Validate one request's arguments against `pipe` and build its
     `_Request` — the admission contract `submit` and tools/serve.py's
     `prevalidate` share (identical errors before and after the response
@@ -231,7 +242,7 @@ def _build_request(pipe: DecodePipeline, rid, ids, new_tokens: int,
         rng=jax.random.PRNGKey(seed), prompt_len=prompt_len,
         prefix=prefix, eos_token=eos_token,
         pad_token=eos_token if pad_token is None else pad_token,
-        on_token=on_token, cancel=cancel,
+        on_token=on_token, stream=stream, cancel=cancel,
         deadline=None if deadline is None else float(deadline),
         shipped=shipped, greedy=temperature <= 0.0)
 
@@ -381,17 +392,6 @@ def _pick_token(req: _Request, out):
     return token
 
 
-def _emit_token(req: _Request, token, on_step) -> None:
-    """`exec/emit`: the executor's `on_step` and the request's `on_token`
-    call-backs (tools/serve.py: admission re-grants, the hand-over of the
-    device token to the streaming HTTP thread)."""
-    with telemetry.span("exec", "emit", rid=str(req.rid)):
-        if on_step is not None:
-            on_step()
-        if req.on_token is not None:
-            req.on_token(len(req.tokens) - 1, token)
-
-
 def _all_rows_eos(req: _Request, token) -> bool:
     """`exec/eos`: read the just-picked token back (blocks on the device)
     and report whether every row of the request has now emitted eos."""
@@ -419,7 +419,8 @@ class ContinuousBatcher:
 
     Served, the executor drives itself: `start()` runs the ticks on its
     own worker thread, and each caller thread (one HTTP handler a request
-    in tools/serve.py) hands a request over and blocks for its result:
+    in tools/serve.py) hands a request over and blocks for its result, on
+    an event that this request's end alone sets:
 
     >>> batcher = ContinuousBatcher(pipe, max_active=48).start()
     >>> batcher.submit("a", ids, new_tokens=8)   # returns immediately
@@ -437,7 +438,7 @@ class ContinuousBatcher:
     def __init__(self, pipe: DecodePipeline, max_active: Optional[int] = None,
                  kv=None, chunk_tokens: int = 0,
                  prefill_budget: Optional[int] = None,
-                 step_join: bool = False, on_step=None):
+                 step_join: bool = False, on_step=None, on_tokens=None):
         if pipe.sp_degree != 1:
             raise ValueError("continuous batching drives per-request decode "
                              "waves; sp prefill is a whole-pipeline pass "
@@ -477,6 +478,12 @@ class ContinuousBatcher:
         # on_step(): fired after each decode-step boundary (a pick
         # landed) — tools/serve.py chains admission re-grants to it
         self.on_step = on_step
+        # on_tokens(rows): the hand-over of a tick's tokens, ONE call for
+        # every request submitted with a `stream`: rows is a list of
+        # (stream, step, [B] tokens), in the order the rows stand in the
+        # step. Called under the executor's lock: it must not block
+        # (tools/serve.py appends the list to its writer's queue)
+        self.on_tokens = on_tokens
         self.pending: deque = deque()
         self.active = 0
         self._live_rids = set()      # pending + admitted (not yet completed)
@@ -501,12 +508,15 @@ class ContinuousBatcher:
         self._unread: deque = deque()
         # the served life cycle (start/wait/stop): ONE condition guards
         # the queues and `results` between the worker and the caller
-        # threads. The worker holds its lock for the whole of a tick.
+        # threads, and the worker alone waits on it (for work). A caller
+        # that finds its request unfinished leaves an event of its own in
+        # `_waiters`, which that request's end sets (`_hand_back`).
         # tools/serve.py takes the same lock for its prefix registry and
         # its admission checks: there is no second lock to order against.
         # Re-entrant, so a caller may hold it across a look-up of its own
         # and `submit`.
         self.cond = make_condition("batcher.results")
+        self._waiters: Dict = {}
         self._worker: Optional[threading.Thread] = None
         self._stop = False
         self._dead: Optional[BaseException] = None
@@ -524,7 +534,7 @@ class ContinuousBatcher:
                prefix: Optional[Dict] = None,
                on_token=None, cancel=None,
                deadline: Optional[float] = None,
-               shipped: Optional[Dict] = None) -> None:
+               shipped: Optional[Dict] = None, stream=None) -> None:
         """Queue a request. `ids` [B, S] is a prompt batch decoded in
         lockstep (B=1 for a single sequence); each distinct (B, S) shape
         compiles its own prefill program, shared across requests.
@@ -552,12 +562,15 @@ class ContinuousBatcher:
         immediately instead of padding to the cap.
 
         `on_token(step, tokens)` fires once a token of every row, in
-        order, the streaming hook `tools/serve.py` chains to chunked HTTP
-        responses. `tokens` is `[B]`: host integers where the request's
-        rows step with the others (the executor has read them back), the
-        device array as its pick lands where the request steps alone (the
-        callback decides when to block on the read-back). `np.asarray`
-        takes either.
+        order: a library caller's streaming hook. `tokens` is `[B]`: host
+        integers where the request's rows step with the others (the
+        executor has read them back), the device array as its pick lands
+        where the request steps alone (the callback decides when to block
+        on the read-back). `np.asarray` takes either. `stream` is the
+        server's way: any handle, given back as `(stream, step, tokens)`
+        in the ONE call a tick of the executor's `on_tokens` that carries
+        every streaming request's token (`tools/serve.py` chains it to
+        chunked HTTP responses).
 
         `cancel` (an is_set()-style flag, e.g. threading.Event) requests
         cooperative cancellation: once set, the request completes at its
@@ -582,7 +595,7 @@ class ContinuousBatcher:
                                  temperature, top_k, seed, eos_token,
                                  pad_token, prefix, on_token=on_token,
                                  cancel=cancel, deadline=deadline,
-                                 shipped=shipped)
+                                 shipped=shipped, stream=stream)
             if self.kv is not None:
                 # a reservation bigger than the whole pool would wedge the
                 # pending queue forever (can_admit never true): reject it
@@ -590,7 +603,7 @@ class ContinuousBatcher:
                 self.kv.check_admittable(req)
             self._live_rids.add(rid)
             self.pending.append(req)
-            self.cond.notify_all()       # the worker may be waiting for work
+            self.cond.notify()      # the worker alone waits here, for work
 
     def _admit(self) -> None:
         """Join pending requests while slots (and pages) last: `exec/admit`,
@@ -607,8 +620,7 @@ class ContinuousBatcher:
                 # dead before its first wave: never seed caches or touch
                 # the pipeline — the whole point of deadline propagation
                 self.pending.popleft()
-                self.results[req.rid] = _finalize_tokens(req)
-                self._live_rids.discard(req.rid)
+                self._hand_back(req)
                 continue
             if self.kv is not None:
                 if not self.kv.can_admit(req):
@@ -618,10 +630,10 @@ class ContinuousBatcher:
                 if req.tokens:
                     # shipped install picked the first token in admit
                     self.stats["tokens"] += int(req.ids.shape[0])
+                    self._emit([(req, req.tokens[-1])])
                 if kind == "done":
                     self.kv.release(req)
-                    self.results[req.rid] = _finalize_tokens(req)
-                    self._live_rids.discard(req.rid)
+                    self._hand_back(req)
                     continue
             else:
                 n_rows = int(req.ids.shape[0])
@@ -673,7 +685,7 @@ class ContinuousBatcher:
             return
         token = _pick_token(req, out)
         self.stats["tokens"] += int(token.shape[0])
-        _emit_token(req, token, self.on_step)
+        self._emit([(req, token)], self.on_step)
         done = len(req.tokens) >= req.new_tokens
         if not done and (_expired(req) or (req.cancel is not None
                                            and req.cancel.is_set())):
@@ -687,9 +699,38 @@ class ContinuousBatcher:
         else:
             reentries.append((req, req.step_ids, "step"))
 
+    def _emit(self, landed: list, on_step=None) -> None:
+        """`exec/emit`: the hand-over of the tokens that landed, `(request,
+        [B] tokens)` each and each already the last of its `req.tokens`:
+        the executor's `on_step` (tools/serve.py: admission re-grants), a
+        request's own `on_token`, and ONE call of `on_tokens` for every
+        row of a stream, whatever their number: one span, one call and
+        one wake-up of the writer behind it a tick."""
+        with telemetry.span("exec", "emit"):
+            if on_step is not None:
+                on_step()
+            rows = []
+            for req, token in landed:
+                step = len(req.tokens) - 1
+                if req.on_token is not None:
+                    req.on_token(step, token)
+                if req.stream is not None:
+                    rows.append((req.stream, step, token))
+            if rows and self.on_tokens is not None:
+                self.on_tokens(rows)
+
+    def _hand_back(self, req: _Request) -> None:
+        """A request's end: its result where `wait` finds it, and its own
+        waiter woken, nobody else's."""
+        self.results[req.rid] = _finalize_tokens(req)
+        self._live_rids.discard(req.rid)
+        waiter = self._waiters.pop(req.rid, None)
+        if waiter is not None:
+            waiter.set()
+
     def _complete(self, req: _Request) -> None:
         with telemetry.span("exec", "retire", rid=str(req.rid)):
-            self.results[req.rid] = _finalize_tokens(req)
+            self._hand_back(req)
             req.caches = None            # free this request's cache slots
             req.chunk_rest = None
             req.done = True
@@ -698,7 +739,6 @@ class ContinuousBatcher:
             if self.kv is not None:
                 self.kv.release(req)     # ... or its page references
             self.active -= 1
-            self._live_rids.discard(req.rid)
             _sched_mark("retire", req.rid)
         if self.step_join:
             # the slot freed at THIS step boundary joins a pending
@@ -837,17 +877,22 @@ class ContinuousBatcher:
             return [(np.asarray(ids)[:, 0], reqs) for ids, reqs in due]
 
     def _deliver(self, read: list) -> None:
-        """Hand each request the tokens a read-back brought, as host
-        integers, and decide its end from them: the cap, every row's eos, a
-        cancel or an expiry. Rows leave here, between steps, and pending
-        requests take their slots for the next tick."""
+        """Hand the tokens a read-back brought over, as host integers and
+        a tick's in one `_emit`, and decide each request's end from them:
+        the cap, every row's eos, a cancel or an expiry. Rows leave here,
+        between steps, and pending requests take their slots for the next
+        tick."""
         for host, reqs in read:
+            landed = []
             for req in reqs:
                 if req.done:
                     continue        # ended a step ago: computed, discarded
                 token = host[req.slots]
                 req.tokens.append(token)
-                _emit_token(req, token, None)
+                landed.append((req, token))
+            if landed:
+                self._emit(landed)  # a cancel set in there shows below
+            for req, token in landed:
                 done = len(req.tokens) >= req.new_tokens
                 if not done and (_expired(req) or (
                         req.cancel is not None and req.cancel.is_set())):
@@ -977,8 +1022,8 @@ class ContinuousBatcher:
     def _loop(self) -> None:
         while True:
             # `exec/wait0`, the worker's only wait for the caller threads:
-            # first for the condition's lock, which every submitting and
-            # every waiting caller thread shares with it, then for work
+            # first for the condition's lock, which every submitting
+            # caller thread shares with it, then for work
             with telemetry.span("exec", "wait0", stage=0):
                 self.cond.acquire()
                 while not self._stop and not (self.pending or self.active
@@ -999,8 +1044,6 @@ class ContinuousBatcher:
                 read = self._read(due)
                 with self.cond:
                     self._deliver(read)
-                    if self.results:
-                        self.cond.notify_all()
             except BaseException as exc:   # noqa: BLE001 — a wedged
                 # worker would hang every waiter forever; record the
                 # failure so they raise instead
@@ -1014,10 +1057,14 @@ class ContinuousBatcher:
         return self._dead
 
     def _die(self, exc: BaseException) -> None:
-        """Record the first cause of death and wake every waiter."""
+        """Record the first cause of death and wake every waiter, and the
+        worker where it waits for work."""
         with self.cond:
             if self._dead is None:
                 self._dead = exc
+            for waiter in self._waiters.values():
+                waiter.set()
+            self._waiters.clear()
             self.cond.notify_all()
 
     def _check_dead(self) -> None:
@@ -1030,15 +1077,21 @@ class ContinuousBatcher:
         worker has died or `stop()` cut the request short, TimeoutError
         after `timeout` seconds."""
         end = None if timeout is None else time.monotonic() + timeout
-        with self.cond:
-            while rid not in self.results:
+        while True:
+            with self.cond:
+                if rid in self.results:
+                    return self.results.pop(rid)
                 self._check_dead()
-                left = None if end is None else end - time.monotonic()
-                if left is not None and left <= 0:
-                    raise TimeoutError(f"request {rid!r} not done after "
-                                       f"{timeout}s")
-                self.cond.wait(left)
-            return self.results.pop(rid)
+                # this request's own event: its end sets it (`_hand_back`),
+                # and the executor's (`_die`); no other request's does
+                waiter = self._waiters.setdefault(rid, threading.Event())
+            left = None if end is None else end - time.monotonic()
+            if (left is not None and left <= 0) or not waiter.wait(left):
+                self._waiters.pop(rid, None)
+                raise TimeoutError(f"request {rid!r} not done after "
+                                   f"{timeout}s")
+            own = rid in self.results or self._dead is not None
+            M_WAKEUPS.inc(kind="own" if own else "other")
 
     def live_rids(self) -> Optional[set]:
         """The ids of every request pending or admitted and not yet

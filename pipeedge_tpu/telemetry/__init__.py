@@ -35,9 +35,9 @@ pipeline per-stage dispatch/retire), `compute` (the jitted shard step),
 lifecycle), `runtime` (schedule rounds), `failover` (detection→recovery),
 `rejoin` (JOIN admission → heal-to-full-capacity), `health` (gray-failure
 lifecycle transitions, pipeedge_tpu/health/), `serve` (HTTP request
-lifecycle; the streaming handler's `readback` and `write`), `exec` (the
-decode executor's worker phases: `wait0`, `admit`, `pick`, `emit`,
-`eos`, `retire`, `publish`), `startup` (`startup()`: where a process's
+lifecycle; the stream writer's `flush` a hand-over, `readback` and
+`write` a line), `exec` (the decode executor's worker phases: `wait0`,
+`admit`, `pick`, `emit` (a tick's hand-over), `eos`, `retire`, `publish`), `startup` (`startup()`: where a process's
 set-up goes, phase by phase; also the always-on
 `pipeedge_startup_seconds_total{phase}`), `generate` (a call of
 `DecodePipeline.generate`: `batch` around its phases `alloc`, `prefill`,

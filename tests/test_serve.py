@@ -618,7 +618,7 @@ def tight_server():
 
 def test_streaming_disconnect_cancels_generation(tight_server):
     """A streaming client that disconnects mid-response must not keep
-    decoding to the cap on a dead socket: the handler's write failure
+    decoding to the cap on a dead socket: the writer thread's failed send
     sets the request's cancel flag, the executor completes it early, and
     the admission slot frees. Verified via the server's
     cumulative token counter: the aborted 40-token request generates only

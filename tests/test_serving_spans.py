@@ -1,7 +1,7 @@
 """The serving loop under the one probe (docs/OBSERVABILITY.md): the decode
-executor's phases as `exec` spans, the HTTP thread's two waits, the span
-digest and the compile counters on /metrics, stable names for the jitted
-stage programs, and the profiler hook."""
+executor's phases as `exec` spans, the writer thread's flush, read-back and
+write, the span digest and the compile counters on /metrics, stable names for
+the jitted stage programs, and the profiler hook."""
 import json
 import statistics
 import threading
@@ -67,9 +67,11 @@ def test_executor_worker_is_always_inside_a_named_span(pipe, run):
     for every
     step of the rows that stand at it together (no request's tag; at most
     a token's worth, seven where all three step from the first step on, as
-    they do when the caller submits before it ticks). One `exec/emit` a
-    token a request and one `exec/retire` a request, tagged; one
-    `exec/read` a tick that brought tokens. The spans of the one worker
+    they do when the caller submits before it ticks). One `exec/retire` a
+    request, tagged; one `exec/read` a tick that brought tokens and, since
+    a tick's tokens leave in one hand-over, one `exec/emit` behind it for
+    all the rows it brought (no request's tag: a token's worth at least,
+    never one a token a request). The spans of the one worker
     never overlap, and what lies between two of them is book-keeping only
     (that no dispatch hides there is the next test's to say)."""
     run(pipe, "warm")                   # compile outside the measurement
@@ -88,12 +90,15 @@ def test_executor_worker_is_always_inside_a_named_span(pipe, run):
     reads = counts.pop(("exec", "read"))
     assert NEW_TOKENS - 1 <= steps <= tokens - REQUESTS
     assert run is _run_thread or steps == NEW_TOKENS - 1
+    emits = counts.pop(("exec", "emit"))
     assert 1 <= reads <= steps + REQUESTS + 1
+    assert NEW_TOKENS <= emits <= reads
     assert counts == {("exec", "seed"): REQUESTS,
                       ("exec", "install"): REQUESTS,
                       ("exec", "pick"): REQUESTS,
-                      ("exec", "emit"): tokens, ("exec", "retire"): REQUESTS}
-    for name in ("exec0", "seed", "install", "pick", "emit", "retire"):
+                      ("exec", "retire"): REQUESTS}
+    assert not any(s.get("rid") for s in spans if s["name"] == "emit")
+    for name in ("exec0", "seed", "install", "pick", "retire"):
         per_request = Counter(s["rid"] for s in spans if s["name"] == name)
         per_request.pop(None, None)     # the steps of all rows
         assert set(per_request) == {f"r{i}" for i in range(REQUESTS)}
@@ -310,11 +315,14 @@ def test_metrics_digest_is_monotone_and_equals_the_rings_sums(server):
     seconds = _family(end, "pipeedge_span_seconds_total")
     count0 = _family(start, "pipeedge_span_count_total")
     seconds0 = _family(start, "pipeedge_span_seconds_total")
-    # streamed tokens cross the HTTP thread's two waits once each
+    # streamed tokens cross the writer thread's two spans once each,
+    # inside the one `serve/flush` of the hand-over that brought them
     assert count[("serve", "readback", "")] \
         - count0.get(("serve", "readback", ""), 0) == 10
     assert count[("serve", "write", "")] \
         - count0.get(("serve", "write", ""), 0) == 10
+    assert 1 <= count[("serve", "flush", "")] \
+        - count0.get(("serve", "flush", ""), 0) <= 10
     assert count[("stage", "exec0", "0")] \
         - count0.get(("stage", "exec0", "0"), 0) == 13
     assert ("exec", "wait0", "0") in count
@@ -329,7 +337,8 @@ def test_metrics_digest_is_monotone_and_equals_the_rings_sums(server):
             sums[key] = (n + 1, ns + span["t1"] - span["t0"])
     assert {("stage", "exec0", "0"), ("exec", "pick", ""),
             ("exec", "emit", ""), ("exec", "retire", ""),
-            ("serve", "readback", ""), ("serve", "write", "")} <= set(sums)
+            ("serve", "readback", ""), ("serve", "write", ""),
+            ("serve", "flush", "")} <= set(sums)
     for key, (n, ns) in sums.items():
         if key == ("exec", "wait0", "0"):
             continue
@@ -342,7 +351,7 @@ def test_metrics_digest_is_monotone_and_equals_the_rings_sums(server):
 def test_debug_profile_writes_a_trace_that_names_the_serving_loop(server):
     """POST /debug/profile: one session at a time (409 for a second), the
     seconds capped, and the trace it leaves names the executor's and the
-    HTTP thread's phases on the profiler's clock."""
+    HTTP and writer threads' phases on the profiler's clock."""
     port, profile_dir = server
     _post(port, "/generate", {"ids": [[1, 2, 3, 4]], "new_tokens": 4,
                               "stream": True})    # warm: compile nothing
@@ -365,7 +374,7 @@ def test_debug_profile_writes_a_trace_that_names_the_serving_loop(server):
 
     names = _host_events(profile_dir)
     assert {"stage/exec0", "exec/pick", "exec/emit", "exec/retire",
-            "exec/wait0", "serve/readback", "serve/write",
+            "exec/wait0", "serve/readback", "serve/write", "serve/flush",
             "serve/generate"} <= names
     with pytest.raises(urllib.error.HTTPError) as bad:
         _post(port, "/debug/profile?seconds=0")

@@ -5,9 +5,13 @@ batch inference; SURVEY.md §2.4): a stdlib-only JSON/HTTP server over one
 `ContinuousBatcher` (parallel/batcher.py), whose own worker thread ticks
 the waves — strict wave semantics, JAX async dispatch keeps every stage
 busy from a single host thread. Handler threads submit to it and wait on
-it: requests admit as they arrive, share the pipeline, and prompt prefixes
-registered once via /prefix are reused by any number of /generate
-requests (prompt caching).
+it, each for its own request's end: requests admit as they arrive, share
+the pipeline, and prompt prefixes registered once via /prefix are reused
+by any number of /generate requests (prompt caching). A streamed answer's
+lines are written by ONE writer thread (`pipeedge_tpu/serving/streams.py`),
+to which the executor hands a tick's tokens in one call: a request is one
+thread, its handler, whatever the number of its tokens (docs/SERVING.md,
+"The threads of a server").
 
 Overload is handled as a fault, not a steady state (docs/SERVING.md):
 every /generate rides the SLO-aware admission plane
@@ -105,7 +109,7 @@ lands (raw picked tokens — post-eos rows are NOT yet masked), then a
 final line `{"ids": ..., "first_token_ms": t, "steps": n}` carrying the
 authoritative (eos-masked) result, identical to the non-streaming
 response. First-token latency is measured server-side from request
-receipt to the first step's readback.
+receipt to the first line's write.
 
 Paged KV plane (`--kv-pages N`, docs/SERVING.md): the executor swaps
 its dense per-request cache slots for page tables over one shared
@@ -135,7 +139,6 @@ Usage: python tools/serve.py -m gpt2 [--port 8321] ...
 import argparse
 import json
 import os
-import queue as queue_mod
 import sys
 import threading
 import time
@@ -147,6 +150,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from pipeedge_tpu import health as peer_health  # noqa: E402
 from pipeedge_tpu import telemetry  # noqa: E402
+from pipeedge_tpu.serving.streams import Stream, StreamWriter  # noqa: E402
 from pipeedge_tpu.serving import (AdmissionController,  # noqa: E402
                                   AdmissionShed, BrownoutLadder,
                                   DeadlineExceeded, REQUEST_CLASSES,
@@ -200,8 +204,8 @@ class ServiceDegraded(RuntimeError):
 
 
 class _Service:
-    """Owns the pipeline + executor; HTTP handler threads submit requests
-    and wait for (or stream) their results."""
+    """Owns the pipeline, the executor and the writer of the streams; HTTP
+    handler threads submit requests and wait for their results."""
 
     def __init__(self, pipe, max_active=None, max_prefixes=8, spec=None,
                  edge_itemsize=2,
@@ -367,11 +371,14 @@ class _Service:
         # AFTER the executor (it needs its concurrency bound).
         self.chunked_prefill = int(chunked_prefill)
         self.step_join = bool(step_join)
+        # the one writer of every streamed answer: the executor hands it a
+        # tick's tokens in one call (pipeedge_tpu/serving/streams.py)
+        self.streams = StreamWriter().start()
         self.executor = ContinuousBatcher(
             pipe, max_active=max_active, kv=self.kv_backend,
             chunk_tokens=self.chunked_prefill,
             prefill_budget=prefill_budget, step_join=self.step_join,
-            on_step=self._on_step)
+            on_step=self._on_step, on_tokens=self.streams.hand_over)
         # the step programs of the rows that step together, every rung,
         # before the first request: one request alone meets one rung
         self.executor.warm()
@@ -627,7 +634,6 @@ class _Service:
                                   "since": time.monotonic(),
                                   "retry_after": float(retry_after),
                                   "phase": "degraded"}
-            self.cond.notify_all()
         self.m_degraded.inc()
         if dead_rank is not None:
             self.m_last_dead.set(int(dead_rank))
@@ -647,7 +653,6 @@ class _Service:
         with self.cond:
             if self.degraded_info is not None:
                 self.degraded_info["phase"] = "healing"
-                self.cond.notify_all()
 
     def exit_degraded(self, healed: bool = False, rank=None):
         """Close the window. `healed=True` records the close as a
@@ -662,7 +667,6 @@ class _Service:
                 self._heal_s.append(
                     time.monotonic() - self.degraded_info["since"])
             self.degraded_info = None
-            self.cond.notify_all()
         self._recovered.set()     # wake replay waiters immediately
         self.flight.note("degraded_closed", healed=healed, rank=rank)
         if healed and was_open:
@@ -1138,7 +1142,8 @@ class _Service:
             # request was in flight when the stage died. Replay it once
             # after recovery instead of surfacing the transient — except
             # streamed requests, whose partial output cannot be unsent.
-            if on_token is not None or not self._await_recovery():
+            if on_token is not None or kw.get("stream") is not None \
+                    or not self._await_recovery():
                 raise
             self.m_replays.inc()
             self.flight.note("replay", rid=rid)
@@ -1235,6 +1240,9 @@ class _Service:
         if self.admission is not None:
             self.admission.close()   # shed every queued waiter (shutdown)
         self.executor.stop()
+        # after the executor: a handler whose request that stop failed
+        # finds no writer and returns without a final line
+        self.streams.stop()
         # tear the ship plane down LAST: in-flight prefills were already
         # failed fast by the executor stop above
         close = getattr(self.prefill_fleet, "close", None)
@@ -1287,27 +1295,26 @@ def make_handler(service, model_name, profile_dir=None):
             self.end_headers()
             self.wfile.write(body)
 
-        def _chunk(self, obj):
-            data = json.dumps(obj).encode() + b"\n"
-            self.wfile.write(f"{len(data):x}\r\n".encode() + data + b"\r\n")
-            self.wfile.flush()
-
         def _stream_generate(self, ids, new_tokens, kw,
                              request_class="interactive", deadline_s=None,
                              rid=None):
             """Chunked x-ndjson response: one line per decode step as the
-            token lands, then the authoritative final line. The worker
-            pushes DEVICE token arrays into a queue; the readback (the
-            blocking part) happens here in the handler thread, so
-            streaming never stalls the executor.
+            token lands, then the authoritative final line. This thread
+            sends the headers, hands the request to the executor with a
+            stream of the service's ONE writer thread, and sleeps until
+            the request ends; the executor hands every running stream's
+            token to the writer in one call a tick, and the writer formats
+            and writes the lines (pipeedge_tpu/serving/streams.py), so
+            streaming never stalls the executor and a step of 48 rows
+            wakes one thread.
 
-            A client that disconnects mid-stream (write fails) sets the
-            request's `cancel` flag: the executor completes the request
-            at its next pick instead of decoding to the cap, so dead
-            requests free their admission slot / cache memory early
-            (repeated disconnects could otherwise occupy every
-            max_active slot with vanished clients)."""
-            import numpy as np
+            A client that disconnects mid-stream (write fails), or that
+            takes nothing for too long, sets the request's `cancel` flag:
+            the executor completes the request at its next pick instead
+            of decoding to the cap, so dead requests free their admission
+            slot / cache memory early (repeated disconnects could
+            otherwise occupy every max_active slot with vanished
+            clients)."""
             t0 = time.monotonic()
             # validate BEFORE headers commit: bad requests still 400
             # (raises into do_POST's error mapping) and don't spend
@@ -1332,75 +1339,33 @@ def make_handler(service, model_name, profile_dir=None):
                                                "outcome": "shed"})
                 raise
             try:
-                cancel = threading.Event()
-                kw.update(cancel=cancel, request_class=request_class,
-                          ticket=ticket, deadline=deadline, rid=rid)
-                q = queue_mod.Queue()
-                worker = threading.Thread(
-                    target=self._run_generate,
-                    args=(ids, new_tokens, kw, q), daemon=True)
-                # once started, generate() owns the ticket's release
-                worker.start()
+                self.send_response(200)
+                self.send_header("Content-Type", "application/x-ndjson")
+                self.send_header("Transfer-Encoding", "chunked")
+                self.send_header(RID_HEADER, rid)
+                self.end_headers()
             except BaseException:
                 if ticket is not None:
                     service.admission.release(ticket, completed=False)
                 raise
-            self.send_response(200)
-            self.send_header("Content-Type", "application/x-ndjson")
-            self.send_header("Transfer-Encoding", "chunked")
-            self.send_header(RID_HEADER, rid)
-            self.end_headers()
-            steps = 0
-            first_ms = None
-            while True:
-                kind, payload = q.get()
-                if kind in ("error", "result"):
-                    final = ({"error": str(payload), "rid": rid}
-                             if kind == "error"
-                             else {"ids": payload.tolist(),
-                                   "first_token_ms": first_ms,
-                                   "steps": steps, "rid": rid})
-                    if not cancel.is_set():
-                        try:
-                            self._chunk(final)
-                        except OSError:
-                            cancel.set()
-                    break
-                step, token = payload
-                # the blocking device readback happens HERE, in the
-                # handler thread — the executor worker only enqueued the
-                # device array and moved on
-                with telemetry.span("serve", "readback", rid=rid):
-                    tok = np.asarray(token).tolist()
-                if first_ms is None:
-                    first_ms = round((time.monotonic() - t0) * 1e3, 3)
-                if not cancel.is_set():
-                    try:
-                        with telemetry.span("serve", "write", rid=rid):
-                            self._chunk({"step": step, "tokens": tok})
-                    except OSError:
-                        # client went away: cancel the generation but keep
-                        # draining the queue until the worker's terminal
-                        # result/error (it completes early at its next
-                        # pick, releasing the executor slot)
-                        cancel.set()
-                steps += 1
-            if not cancel.is_set():
-                try:
-                    self.wfile.write(b"0\r\n\r\n")
-                    self.wfile.flush()
-                except OSError:
-                    pass    # disconnect after the final line: nothing owed
-
-        def _run_generate(self, ids, new_tokens, kw, q):
+            # from here to `closed` the socket is the writer's
+            cancel = threading.Event()
+            stream = Stream(self.connection, rid, cancel, t0)
+            kw.update(cancel=cancel, request_class=request_class,
+                      ticket=ticket, deadline=deadline, rid=rid,
+                      stream=stream)
             try:
-                out = service.generate(
-                    ids, new_tokens,
-                    on_token=lambda step, tok: q.put(("token", (step, tok))),
-                    **kw)
-                q.put(("result", out))
+                # generate() owns the ticket's release
+                out = service.generate(ids, new_tokens, **kw)
             except BaseException as exc:   # noqa: BLE001 — surfaced as a
-                q.put(("error", exc))      # terminal stream line
+                service.streams.finish(stream, error=str(exc))  # stream line
+            else:
+                service.streams.finish(stream, ids=out.tolist())
+            stream.closed.wait()
+            if cancel.is_set():
+                # a line may have stopped half-way: not a connection to
+                # read another request from
+                self.close_connection = True
 
         def do_GET(self):
             if self.path == "/metrics":
