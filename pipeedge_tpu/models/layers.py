@@ -24,7 +24,7 @@ class TransformerConfig:
     #                              'gpt2' | 'llama' | 'keye' | 'kimi' |
     #                              'qwen3_next' | 'lfm2' | 'laguna' |
     #                              'minicpm_sala' | 'nemotron_h' |
-    #                              'granite_hybrid'
+    #                              'granite_hybrid' | 'brumby'
     hidden_size: int
     num_hidden_layers: int       # transformer blocks (sublayers = 4x this)
     num_attention_heads: int

@@ -29,6 +29,7 @@ from . import ShardConfig
 from .layers import TransformerConfig
 from .shard import make_shard_fn, unstack_blocks
 from . import bert as bert_mod
+from . import brumby as brumby_mod
 from . import deit as deit_mod
 from . import gpt2 as gpt2_mod
 from . import granite_hybrid as granite_hybrid_mod
@@ -240,6 +241,18 @@ def _granite_hybrid(name, weights, hidden, pattern, heads, kv_heads, head_dim,
         prefill_chunk=span))
 
 
+def _brumby(name, weights, hidden, blocks, heads, kv_heads, head_dim,
+            dense_width, vocab, max_pos, chunk, span, theta=1e6):
+    return ModelEntry(name, 4 * blocks, weights, brumby_mod,
+                      TransformerConfig(
+        model_type="brumby", hidden_size=hidden, num_hidden_layers=blocks,
+        num_attention_heads=heads, num_kv_heads=kv_heads,
+        attn_head_dim=head_dim, intermediate_size=dense_width,
+        layer_norm_eps=1e-6, vocab_size=vocab,
+        max_position_embeddings=max_pos, rope_theta=theta, qk_norm=True,
+        linear_chunk=chunk, prefill_chunk=span))
+
+
 # a pattern of mixers, one letter a block. LFM2's: c a gated short
 # convolution, a grouped-query attention (no interval: the last attention
 # comes early). Laguna's: f attention over every position, s over a window.
@@ -361,6 +374,15 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
                     ssm=(64, 64, 128, 1, 4, 256), dense_width=8192,
                     vocab=100352, max_pos=131072,
                     multipliers=(12.0, 0.22, 0.015625, 8.0), span=64),
+    # Brumby-14B-Base: Qwen3-14B's trunk (40 blocks, a SwiGLU of 17,408, two
+    # tables of 151,936) with every attention a power-retention layer: 40
+    # query and 8 KV heads of 128, q/k norms, rotation, a gate a KV head, a
+    # state of 128 x 8,320 a KV head (34 MB a request a layer) and no keys
+    # or values at all. One chip holds the first of four pipeline stages
+    # with both tables: `...@10`
+    _brumby("manifestai/Brumby-14B-Base", "Brumby-14B-Base.npz", 5120, 40, 40,
+            8, 128, dense_width=17408, vocab=151936, max_pos=32768, chunk=128,
+            span=256),
     # tiny synthetic models for fast tests / CI (not in the reference's list)
     _vit("pipeedge/test-tiny-vit", 8, "test-tiny-vit.npz", 32, 2, 4, 64, 5,
          patch=4, img=16),
@@ -418,6 +440,11 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
                     "test-tiny-granite-hybrid.npz", 32, "MM*MMM*M", 4, 2, 8,
                     ssm=(4, 8, 8, 1, 4, 4), dense_width=64, vocab=100,
                     max_pos=64, multipliers=(12.0, 0.22, 0.125, 8.0), span=8),
+    # four blocks, all alike: two KV heads of two query heads of 8 (a state
+    # of 8 x 40 a KV head), chunks of 4 in spans of 6: a span's last chunk
+    # is short
+    _brumby("pipeedge/test-tiny-brumby", "test-tiny-brumby.npz", 32, 4, 4, 2,
+            8, dense_width=64, vocab=100, max_pos=64, chunk=4, span=6),
     _gpt2("pipeedge/test-tiny-moe", 8, "test-tiny-moe.npz", 32, 2, 4, 64,
           vocab=100, max_pos=64, n_experts=4, capacity_factor=4.0),
 ]}

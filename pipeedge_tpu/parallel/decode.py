@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import math
 from collections import deque
-from functools import partial
+from functools import cached_property, partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -1361,13 +1361,13 @@ class DecodePipeline:
     def _read_len(self, pos: int, span: int = 1, per_octave: int = 1):
         """Static attend window for a decode/span step whose last query
         row sits at host-known pos + span - 1 (None when this pipeline's
-        stage programs aren't bucketed), from `attend_bucket`'s ladder of
-        `per_octave` widths an octave: 1, the powers of two, for every
-        caller that must have met its programs before it serves (the
-        batcher, the speculative decoder, beam search); `job_per_octave`
-        for `generate`, the batch job."""
-        if not self._bucketed:
-            return None
+        stage programs aren't bucketed; ONE width, `max_len`, where no leaf
+        keeps a row a position: `keeps_positions`), from `attend_bucket`'s
+        ladder of `per_octave` widths an octave: 1 for every caller that
+        must have met its programs before it serves, `job_per_octave` for
+        `generate`, the batch job."""
+        if not (self._bucketed and self.keeps_positions):
+            return self.max_len if self._bucketed else None
         # a span's window is at least eight spans wide (a prompt prefilled
         # in spans of 512 starts at 4096, not at 512) and its widths are
         # whole spans apart; a step's are whole floors apart
@@ -1409,7 +1409,7 @@ class DecodePipeline:
         counted as it goes out (`M_ATTEND`): the window compiled for and
         the positions of it that are live, a row a query."""
         rl = self._read_len(pos, span, per_octave)
-        queries = data.shape[0] * span
+        queries = data.shape[0] * span * self.keeps_positions
         phase = "prefill" if span > 1 or last_only else "decode"
         M_ATTEND.inc(queries * (self.max_len if rl is None else rl),
                      phase=phase, kind="read")
@@ -1747,3 +1747,22 @@ class DecodePipeline:
         best_hist = jnp.take_along_axis(
             history, best[:, None, None], axis=1)[:, 0]   # [B, new_tokens]
         return jnp.concatenate([ids, best_hist], axis=1)
+
+    @cached_property
+    def keeps_positions(self) -> bool:
+        """Whether any leaf of the stages' caches is a row a position (the
+        plain `k`, `v` pair; a family's leaf that is not `whole`; a ring,
+        which is read whatever the window). Where none is (a family whose
+        every leaf is a recurrent state a request: models/brumby.py) there
+        is no window to bucket: `_read_len` binds the one width `max_len`
+        into every span and step program, so a generation builds ONE of
+        each whatever its positions (an octave's widths would be programs
+        that differ in a number nothing reads), `max_len` bounds the
+        positions a rotation may see and nothing in memory, and
+        `_decode_step` counts no attended position (`M_ATTEND`). Below the
+        last line that stands in a Mosaic kernel's call stack, so that the
+        siblings' step programs keep their keys in the compile cache
+        (ROADMAP S9)."""
+        leaves = self.cache_leaves
+        return leaves is None \
+            or bool(set(leaves) - {STATS} - set(whole_names(leaves)))

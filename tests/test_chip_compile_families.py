@@ -398,3 +398,73 @@ def test_granite_hybrid_stage_program_compiles_for_v5e(span, last_only,
         assert memory.temp_size_in_bytes < 2.0e9
         assert order == "" and "tpu_custom_call" not in text
         assert "dynamic-update-slice" in text
+
+
+BRUMBY_CELL = "manifestai/Brumby-14B-Base@10"
+
+
+@pytest.mark.parametrize("span, last_only", [(1, False), (256, True)])
+def test_brumby_stage_program_compiles_for_v5e(span, last_only, on_chip,
+                                               monkeypatch):
+    """`brumby-14b.longgen-batch` at its real size: ten of forty layers at
+    the published widths in one run, both tables, 8 rows, the ONE width the
+    stage binds (`max_len` 2,048: no leaf is a row a position); a decode
+    step and one span of the prefill, 256 positions in two chunks of 128.
+    The resident bytes (9.72 GB of weights, 2.73 GB of state and 0.02 GB of
+    sums in ten layers) and the program's temporaries have to fit one chip's
+    16 GB, and a second copy of the state does not help: a step's state goes
+    through the in-place kernel (`ops/retention_step.py`), ten calls in
+    block order each given the stack the one before handed back, no
+    `dynamic-update-slice` of the stack and no copy of it; a span carries
+    the stack through its scan."""
+    import time
+
+    from pipeedge_tpu.models import brumby
+    from pipeedge_tpu.parallel import decode
+    monkeypatch.setattr(brumby, "_kernel_mode", lambda: "mosaic")
+    entry = registry.get_model_entry(BRUMBY_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    rows, max_len = 8, 2048
+    params = jax.eval_shape(lambda: entry.family._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
+        cfg, cfg.num_hidden_layers, rows, max_len,
+        leaves=entry.family.cache_leaves(cfg)))
+    assert cache["pr_state"].shape == (10, rows, 8, 128, 8320)
+    assert cache["pr_sum"].shape == (10, rows, 8, 8320)
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    _, step = decode.make_stage_fns(entry.family.FAMILY, cfg, stage)
+    started = time.monotonic()
+    lowered = step.lower(params, on_chip((rows, span), jnp.int32), cache,
+                         on_chip((), jnp.int32), read_len=max_len,
+                         last_only=last_only)
+    traced = time.monotonic()
+    compiled = lowered.compile()
+    print(f"brumby span {span}: traced and lowered in "
+          f"{traced - started:.1f} s, compiled in "
+          f"{time.monotonic() - traced:.1f} s")
+    assert cfg.prefill_chunk == 256 and cfg.linear_chunk == 128
+    text = compiled.as_text()
+    memory = compiled.memory_analysis()
+    print(f"brumby {rows} rows, span {span}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = rows * 10 * (8 * 128 * 8320 + 8 * 8320) * 4
+    assert memory.alias_size_in_bytes >= cache_bytes    # updated in place
+    assert memory.argument_size_in_bytes < 9.73e9 + 1.01 * cache_bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.6e9
+    leaf = f"f32[10,{rows},8,128,8320]"
+    order = _updates_and_kernels(text, leaf)
+    assert not re.search(re.escape(leaf) + r"\S* copy\(", text)
+    if span == 1:
+        assert memory.temp_size_in_bytes < 0.4e9
+        assert order == "S" * 10, order
+    else:
+        # 1.63 GB, the SwiGLU's three-part products; 4.46 GB with a KV
+        # head's cell all 8 rows (`brumby.CELL_BYTES`), which did not fit
+        assert memory.temp_size_in_bytes < 2.0e9
+        assert order == "" and "tpu_custom_call" not in text
+        assert "dynamic-update-slice" in text

@@ -84,7 +84,7 @@ def test_the_job_ladder_gives_the_cells_their_widths(cell, max_len, prompt,
     import collections
     import types
     pipe = types.SimpleNamespace(_bucketed=True, max_len=max_len,
-                                 attend_floor=64)
+                                 attend_floor=64, keeps_positions=True)
 
     def read_len(pos, n=1, per_octave=octave):
         return decode.DecodePipeline._read_len(pipe, pos, n, per_octave)
