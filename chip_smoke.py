@@ -13,7 +13,8 @@ children are the CLIs as users start them (`save_model_weights.py`,
 ViT-Large and gpt2-medium, on weights made from a seed. The exception is the
 `probe` phase, which is this file run again with `--child probe`: the device,
 the fixed cost of a dispatch, whether `block_until_ready` fences, and the
-repaired Pallas kernels against their XLA references.
+repaired Pallas kernels and the short attention core against their XLA
+references.
 
 Each phase prints one JSON line. A phase that fails raises and the script
 exits non-zero; nothing is caught and reported as a skip, and nothing carries
@@ -55,6 +56,15 @@ BUDGET_S = 1150.0
 # for a difference in fusion between the one-program and the staged forward.
 BF16_LOGIT_TOLERANCE = 2.0 ** -5
 
+# The short attention kernel's context agrees with the einsums' to this share
+# of the context's range: a bfloat16 result is rounded to 2**-9 of its value,
+# so two of them differ by 2**-9 of the range at its ends, and the float32
+# ones by the two products' passes (my chip runs, PR 60, calls 189 and 203:
+# at most 9.6e-4 and 2.5e-4). A scratch buffer whose store overtook a read
+# shows as half the range or more (`ops/short_attention.py`, module
+# docstring).
+ATTENTION_TOLERANCE = 2.0 ** -8
+
 
 @dataclasses.dataclass(frozen=True)
 class Sizes:
@@ -77,6 +87,16 @@ class Sizes:
     matmul_mkn: tuple = (1576, 1024, 4096)  # 8 x 197 rows into the MLP
     fence_dim: int = 8192       # the fence check's matmul chain
     fence_chain: int = 16
+    # the short attention core's calls (batch, positions, heads, head width,
+    # type), each one `ops/short_attention.py::takes`: ViT-L's and DeiT-B's,
+    # the longest row of ViT-L's width, one tile of keys with and without a
+    # remainder, a single row, a head of 128, float32
+    attention_calls: tuple = (
+        (8, 197, 16, 64, "bfloat16"), (8, 198, 12, 64, "bfloat16"),
+        (8, 256, 16, 64, "bfloat16"), (8, 129, 16, 64, "bfloat16"),
+        (8, 128, 16, 64, "bfloat16"), (8, 50, 16, 64, "bfloat16"),
+        (2, 1, 2, 64, "bfloat16"), (4, 197, 8, 128, "bfloat16"),
+        (8, 197, 12, 64, "float32"))
 
 
 class PhaseFailed(RuntimeError):
@@ -537,7 +557,9 @@ def child_probe(sizes):
     import jax.numpy as jnp
     import numpy as np
 
-    from pipeedge_tpu.ops import fused_quant, int8_matmul, quant
+    from pipeedge_tpu.models import layers
+    from pipeedge_tpu.ops import (fused_quant, int8_matmul, quant,
+                                  short_attention)
 
     facts = {}
     # the fixed cost of one small dispatch, fenced each way
@@ -627,6 +649,28 @@ def child_probe(sizes):
     rel = float(jnp.max(jnp.abs(got - ref)) / jnp.max(jnp.abs(ref)))
     kernel_checks["int8_matmul"] = {"max_rel_diff": rel}
     _check(rel <= 1e-4, f"int8 matmul is {rel} from matmul_xla")
+    # the short attention core against the einsum core, at every kind of
+    # call `takes` admits: Mosaic's ordering of scratch accesses is what
+    # interpret mode cannot show, so on the CPU this only rehearses
+    interpret = jax.default_backend() != "tpu"
+    gaps = {}
+    for b, s, h, hd, dtype in sizes.attention_calls:
+        _check(short_attention.takes(s, h * hd, hd, jnp.dtype(dtype).itemsize),
+               f"the short core does not take {(b, s, h, hd, dtype)}")
+        q, k, v = (jnp.asarray(rng.normal(size=(b, s, h * hd)), dtype)
+                   for _ in range(3))
+        got = jax.jit(lambda q, k, v, h=h: short_attention.short_attention(
+            q, k, v, h, layers.einsum_core, interpret))(q, k, v)
+        want = jax.jit(lambda q, k, v, split=(b, s, h, hd): layers.einsum_core(
+            q.reshape(split), k.reshape(split), v.reshape(split)
+        ).reshape(q.shape))(q, k, v)
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        gap = float(np.abs(got - want).max() / (want.max() - want.min()))
+        gaps[f"{b}x{s}x{h}x{hd}_{dtype}"] = gap
+        _check(np.isfinite(got).all() and gap <= ATTENTION_TOLERANCE,
+               f"the short attention core is {gap} of the range from the "
+               f"einsums at {(b, s, h, hd, dtype)}")
+    kernel_checks["short_attention"] = {"gap_of_range": gaps}
     facts.update(kernel_in_program=in_program, kernel_checks=kernel_checks)
     print(f"probe: {json.dumps(facts)}", flush=True)
 
@@ -634,6 +678,11 @@ def child_probe(sizes):
 def child_devices():
     from pipeedge_tpu.utils import report_devices
     report_devices()
+
+
+def _as_tuple(value):
+    """A field of `Sizes` as JSON brought it: lists are its tuples."""
+    return tuple(map(_as_tuple, value)) if isinstance(value, list) else value
 
 
 def main(argv=None):
@@ -647,8 +696,8 @@ def main(argv=None):
     args = parser.parse_args(argv)
     if args.child == "probe":
         fields = json.loads(args.sizes)
-        child_probe(Sizes(**{key: tuple(value) if isinstance(value, list)
-                             else value for key, value in fields.items()}))
+        child_probe(Sizes(**{key: _as_tuple(value)
+                             for key, value in fields.items()}))
         return 0
     if args.child == "devices":
         child_devices()

@@ -14,6 +14,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import metrics as prom
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -388,15 +390,60 @@ def exact_einsum(spec: str, x: jax.Array, w: jax.Array) -> jax.Array:
 
 
 def _use_fused_attention(seq_len: int) -> bool:
-    """Pallas fused attention: on TPU for long sequences, where streaming the
-    [S, S] scores through VMEM beats XLA (measured ~5x at S=8192); for short
-    sequences (ViT's 197, BERT's 512) XLA's fused einsum path wins. Override
-    with env PIPEEDGE_FUSED_ATTENTION=0/1."""
+    """The streaming Pallas kernel (`ops/attention.py::fused_attention`): on
+    a TPU from 1,024 positions, where streaming the [S, S] scores through
+    VMEM beats XLA (measured ~5x at S=8192). Shorter rows are the short
+    core's where `_short_core_mode` says so (ViT's 197, DeiT's 198) and the
+    einsums' otherwise (BERT's and GPT-2's 512). Override with env
+    PIPEEDGE_FUSED_ATTENTION=0/1, which decides between this kernel and the
+    einsums and never reaches the short core."""
     import os
     env = os.getenv("PIPEEDGE_FUSED_ATTENTION")
     if env is not None:
         return env not in ("0", "false", "no")
     return jax.default_backend() == "tpu" and seq_len >= 1024
+
+
+# /metrics plane: which form an uncached block's attention core took, counted
+# where `self_attention` chooses, so once a block a program TRACED (a program
+# that scans its blocks counts one, an unrolled stage program one a block)
+_M_CORE_BLOCKS = prom.REGISTRY.counter(
+    "pipeedge_attn_core_blocks_total",
+    "attention cores `layers.self_attention` traced, by the form it chose "
+    "from the call: fused = a kernel of ops/ (the short core, or the "
+    "streaming one from 1,024 positions), einsum = XLA's two einsums; a "
+    "`core_fn` override is not counted")
+for _path in ("fused", "einsum"):
+    _M_CORE_BLOCKS.declare(path=_path)
+
+
+def _kernel_mode():
+    """How this backend runs the short attention core
+    (`ops/short_attention.py`): "mosaic" on a TPU, None where Mosaic cannot
+    run (the einsums serve every call); the tests put "interpret" here."""
+    return "mosaic" if jax.default_backend() == "tpu" else None
+
+
+def _short_core_mode(seq_len: int, width: int, num_heads: int, dtype):
+    """How an unmasked, non-causal core over q, k, v `[B, seq_len, width]`
+    of `num_heads` heads runs, read off the call: `_kernel_mode()` where the
+    short kernel takes it (heads of 64 or 128 in whole slabs of lanes, its
+    blocks and scratch within its VMEM budget: `ops/short_attention.py::
+    takes`, a function of S, H * Dh and the type; exact numerics, since the
+    kernel's softmax is the float32 one), None where the einsums keep it:
+    the CPU, ViT-H's heads of 80, rows past 256 at ViT-L's width. At ViT-L's
+    call (8 x 197 x 16 heads of 64, bfloat16) the kernel is 27.0 us a block
+    in the four-chip cell's traced window where the einsums with their three
+    transposed copies are 35.2 (PERF.md section 6, PR 60); no cell measures
+    the rule at another length. The kernel's module is imported only on a
+    backend that could run it, for a call without mask or `causal`."""
+    mode = _kernel_mode()
+    if mode is None or fast_numerics_enabled():
+        return None
+    from ..ops import short_attention
+    fits = short_attention.takes(seq_len, width, width // num_heads,
+                                 jnp.dtype(dtype).itemsize)
+    return mode if fits else None
 
 
 def rms_norm(p, x: jax.Array, eps: float) -> jax.Array:
@@ -486,6 +533,34 @@ def apply_causal_mask(scores: jax.Array) -> jax.Array:
     return jnp.where(k_pos <= q_pos, scores, -1e30)
 
 
+def einsum_core(q: jax.Array, k: jax.Array, v: jax.Array,
+                causal: bool = False,
+                mask: Optional[jax.Array] = None) -> jax.Array:
+    """softmax(q k^T / sqrt(Dh)) v as XLA's two einsums over q, k, v
+    `[B, S, H, Dh]` -> the context `[B, S, H, Dh]`: `self_attention`'s core
+    wherever no kernel takes it, and, unmasked, what the short kernel's
+    backward differentiates (`ops/short_attention.py`), so the two cannot
+    drift apart. Scores and softmax in float32, the probabilities cast to
+    the operands' type before the second product."""
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / jnp.sqrt(jnp.float32(q.shape[-1]))
+    if causal:
+        scores = apply_causal_mask(scores)
+    if mask is not None:
+        # mask: [B, S] with 1 = attend, 0 = ignore
+        bias = jnp.where(mask[:, None, None, :] > 0, 0.0, -1e9).astype(jnp.float32)
+        scores = scores + bias
+    if fast_numerics_enabled():
+        # model-dtype softmax: the MXU accumulation above stays f32
+        # (free); only the VPU softmax intermediates narrow
+        probs = jax.nn.softmax(scores.astype(q.dtype), axis=-1)
+    else:
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
 def self_attention(p, x: jax.Array, num_heads: int,
                    mask: Optional[jax.Array] = None,
                    core_fn=None, causal: bool = False,
@@ -494,11 +569,21 @@ def self_attention(p, x: jax.Array, num_heads: int,
 
     Matches HF `{ViT,Bert}SelfAttention` semantics: returns the concatenated
     per-head context; the output projection lives in the next sublayer
-    (reference vit.py:58-63). Softmax in float32. On TPU the
-    softmax(QK^T)V core runs as a fused Pallas kernel (ops/attention.py).
+    (reference vit.py:58-63). Softmax in float32.
+
+    The softmax(QK^T)V core takes one of three forms, read off the call (no
+    option chooses; `pipeedge_attn_core_blocks_total{path}` counts each
+    traced core): unmasked and non-causal on a TPU, with heads of 64 or 128
+    and a row short enough for VMEM, the short kernel
+    (`ops/short_attention.py`, q, k, v read as `dense` wrote them:
+    `_short_core_mode`); unmasked from 1,024 positions on a TPU the
+    streaming kernel (`ops/attention.py`: `_use_fused_attention`); XLA's
+    einsums everywhere else (the CPU, a padding mask, a short causal row).
+    The short kernel and the einsums can be differentiated (the kernel's
+    backward is the einsums'); the streaming kernel cannot.
 
     `causal` applies a lower-triangular mask (decoder families, e.g. GPT-2);
-    the fused kernel handles it natively (and skips past-frontier K/V
+    the streaming kernel handles it natively (and skips past-frontier K/V
     blocks), so the long-sequence perf path covers decoders too.
 
     `tag_prefix` tags the q/k/v projections (`<prefix>.q` etc.) for the
@@ -526,25 +611,19 @@ def self_attention(p, x: jax.Array, num_heads: int,
         return core_fn(q, k, v).reshape(b, s, d)
     if mask is None and _use_fused_attention(s):
         from ..ops.attention import fused_attention
+        _M_CORE_BLOCKS.inc(path="fused")
         return fused_attention(q, k, v, causal=causal).reshape(b, s, d)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(hd))
-    if causal:
-        scores = apply_causal_mask(scores)
-    if mask is not None:
-        # mask: [B, S] with 1 = attend, 0 = ignore
-        bias = jnp.where(mask[:, None, None, :] > 0, 0.0, -1e9).astype(jnp.float32)
-        scores = scores + bias
-    if fast_numerics_enabled():
-        # model-dtype softmax: the MXU accumulation above stays f32
-        # (free); only the VPU softmax intermediates narrow
-        probs = jax.nn.softmax(scores.astype(x.dtype), axis=-1)
-    else:
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
-                     preferred_element_type=jnp.float32).astype(x.dtype)
-    return ctx.reshape(b, s, d)
+    mode = None if causal or mask is not None \
+        else _short_core_mode(s, d, num_heads, q.dtype)
+    if mode is not None:
+        # the projections' own lay-out: the reshapes above are views
+        from ..ops.short_attention import short_attention
+        _M_CORE_BLOCKS.inc(path="fused")
+        return short_attention(q.reshape(b, s, d), k.reshape(b, s, d),
+                               v.reshape(b, s, d), num_heads, einsum_core,
+                               mode == "interpret")
+    _M_CORE_BLOCKS.inc(path="einsum")
+    return einsum_core(q, k, v, causal, mask).reshape(b, s, d)
 
 
 def gelu(x: jax.Array) -> jax.Array:

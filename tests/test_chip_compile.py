@@ -27,9 +27,9 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from pipeedge_tpu.models import ShardConfig, registry, stage_cache
+from pipeedge_tpu.models import ShardConfig, layers, registry, stage_cache
 from pipeedge_tpu.ops import (attention, decode_attention, fused_quant,
-                              int8_matmul, quant)
+                              int8_matmul, quant, short_attention)
 from pipeedge_tpu.parallel import expert
 
 EDGE = (8, 197, 1024)       # the ViT-L stage edge at microbatch 8
@@ -112,6 +112,16 @@ def _attention(bh, seq, dim, causal):
     return build
 
 
+def _short_attention(batch, seq, heads, head_dim, dtype=jnp.bfloat16):
+    def build(on_chip):
+        assert short_attention.takes(seq, heads * head_dim, head_dim,
+                                     jnp.dtype(dtype).itemsize)
+        qkv = on_chip((batch, seq, heads * head_dim), dtype)
+        return (lambda q, k, v: short_attention.short_attention(
+            q, k, v, heads, layers.einsum_core), [qkv, qkv, qkv])
+    return build
+
+
 def _decode_attention(variant, batch, width):
     def build(on_chip):
         h, d = GPT2_HEADS, GPT2_HEAD_DIM
@@ -164,6 +174,18 @@ KERNELS = {
     "int8_matmul_1576x4096x1024": _matmul(1576, 4096, 1024),
     "attention_16x1024x64": _attention(16, 1024, 64, causal=False),
     "attention_causal_32x4096x128": _attention(32, 4096, 128, causal=True),
+    # the short core (`layers._short_core_mode` gives it these): ViT-L's
+    # call, DeiT-B's, the longest row its budget holds at ViT-L's width, a
+    # head of 128, float32, and rows of one tile of keys down to one row
+    "short_attention_vit_l_8x197x16x64": _short_attention(8, 197, 16, 64),
+    "short_attention_deit_b_8x198x12x64": _short_attention(8, 198, 12, 64),
+    "short_attention_8x256x16x64": _short_attention(8, 256, 16, 64),
+    "short_attention_4x197x8x128": _short_attention(4, 197, 8, 128),
+    "short_attention_f32_8x197x12x64": _short_attention(
+        8, 197, 12, 64, dtype=jnp.float32),
+    "short_attention_8x50x16x64": _short_attention(8, 50, 16, 64),
+    "short_attention_8x128x16x64": _short_attention(8, 128, 16, 64),
+    "short_attention_2x1x2x64": _short_attention(2, 1, 2, 64),
     "int8_decode_attention_v1": _decode_attention(1, batch=16, width=1024),
     "int8_decode_attention_v2": _decode_attention(2, batch=16, width=256),
     # the six sparse cells' decode steps: stack, expert, rows, router, held
@@ -212,9 +234,11 @@ def test_a_steps_experts_are_one_kernel_and_nothing_laid_out(name, on_chip):
 @pytest.fixture(autouse=True)
 def mosaic_for_the_described_chip(monkeypatch):
     """The default backend here is the CPU's, which keeps every expert
-    layer on the tile loop; these programs are compiled for the chip, where
-    a step's call takes the grouped kernel."""
+    layer on the tile loop and every uncached block's attention core on the
+    einsums; these programs are compiled for the chip, where a step's call
+    takes the grouped kernel and ViT-L's core the short kernel."""
     monkeypatch.setattr(expert, "_grouped_mode", lambda: "mosaic")
+    monkeypatch.setattr(layers, "_kernel_mode", lambda: "mosaic")
 
 
 def _grouped_kernels(compiled) -> int:
@@ -303,6 +327,70 @@ def test_vit_large_forward_compiles_for_v5e(on_chip):
     compiled = jax.jit(forward).lower(params, images).compile()
     logits, = jax.tree_util.tree_leaves(compiled.out_info)
     assert logits.shape == (8, cfg.num_labels)
+    # the scanned block's core is the short kernel, and nothing as large as
+    # q, k or v is copied on its way in: the einsums' three transposed
+    # copies (`bf16[8,197,1024]{1,2,0}`) are gone
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not re.findall(r"= bf16\[8,197,1024\]\{1,2,0[^}]*\} copy\(", text)
+
+
+def test_vit_large_train_step_compiles_for_v5e(topo):
+    """`chip_smoke.py`'s `train` phase (`tools/train.py -m vit-large -t
+    bfloat16 --remat -b 8 -u 4` on one chip): the loss's gradient through
+    one stage of 24 rematerialised blocks. The forward's core is the short
+    kernel, which has no transpose of its own: its backward is the einsums',
+    so the step holds the kernel (forward and recomputed) and compiles."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from pipeedge_tpu.parallel import spmd, train
+    entry, cfg, one_block = _vit_large_one_block()
+    blocks, n_ubatch, ubatch = cfg.num_hidden_layers, 4, 8
+    mesh = spmd.make_pipeline_mesh(1, devices=topo.devices[:1])
+
+    def placed(tree, spec, lead=()):
+        return jax.tree_util.tree_map(
+            lambda leaf: jax.ShapeDtypeStruct(
+                lead + leaf.shape, leaf.dtype,
+                sharding=NamedSharding(mesh, spec)), tree)
+
+    a_block = jax.tree_util.tree_map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape[1:], leaf.dtype),
+        one_block["blocks"])
+    trainable = {
+        "embed": placed(one_block["embeddings"], P()),
+        "final": placed(one_block["final"], P()),
+        "blocks": placed(a_block, P("stage"), (1, blocks)),
+    }
+    n_blocks = jax.ShapeDtypeStruct(
+        (1,), jnp.int32, sharding=NamedSharding(mesh, P("stage")))
+    pipe = spmd.SpmdPipeline(
+        family=entry.family.FAMILY, cfg=cfg, mesh=mesh, n_stages=1,
+        max_blocks=blocks, min_blocks=blocks,
+        params={**trainable, "n_blocks": n_blocks}, stage_bits=(0,),
+        remat=True)
+    shape = (n_ubatch, ubatch, cfg.num_channels, cfg.image_size,
+             cfg.image_size)
+    forward = pipe._build(np.broadcast_to(np.zeros((), jnp.bfloat16), shape))
+
+    def loss(trainable, n_blocks, images, labels):
+        return train.softmax_xent(
+            forward({**trainable, "n_blocks": n_blocks}, images), labels)
+
+    fused = layers._M_CORE_BLOCKS.value(path="fused")
+    einsum = layers._M_CORE_BLOCKS.value(path="einsum")
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        trainable, n_blocks,
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P())),
+        jax.ShapeDtypeStruct((n_ubatch, ubatch), jnp.int32,
+                             sharding=NamedSharding(mesh, P()))).compile()
+    assert layers._M_CORE_BLOCKS.value(path="fused") > fused
+    assert layers._M_CORE_BLOCKS.value(path="einsum") == einsum
+    value, grads = compiled.out_info
+    assert value.shape == ()
+    assert jax.tree_util.tree_map(lambda leaf: leaf.shape, grads) \
+        == jax.tree_util.tree_map(lambda leaf: leaf.shape, trainable)
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
 
 
 @pytest.mark.parametrize("span, last_only", [(1, False), (512, True)])

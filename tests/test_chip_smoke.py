@@ -35,7 +35,8 @@ def tiny(chip_smoke):
         decoder="pipeedge/test-tiny-gpt2", vocab=100, max_len=48,
         prompt_len=8, new_tokens=6, train_batch=2, train_ubatches=2,
         train_steps=3, edge_shape=(4, 5, 32), matmul_mkn=(16, 256, 128),
-        fence_dim=128, fence_chain=2)
+        fence_dim=128, fence_chain=2,
+        attention_calls=((2, 9, 2, 64, "bfloat16"), (1, 5, 1, 128, "float32")))
 
 
 def _host_devices(monkeypatch, n):
@@ -104,6 +105,8 @@ def test_one_chip_phases_at_tiny_size(chip_smoke, tiny, monkeypatch, capsys):
     assert by_phase["vit_two_stages"]["top1_agreement"] == 1.0
     assert by_phase["serve"]["stream_matches_plain"] is True
     assert by_phase["probe"]["dispatch_ms"] > 0
+    assert len(by_phase["probe"]["kernel_checks"]["short_attention"][
+        "gap_of_range"]) == 2
     # on the CPU `auto` is the XLA ops, by the backend's name: no kernel
     assert not any(by_phase["probe"]["kernel_in_program"].values())
 
