@@ -549,6 +549,100 @@ def _median_ms(fn, reps):
     return statistics.median(samples) * 1e3
 
 
+# where `kernel_checks.gelu` counts misses: |x| up to GELU_COUNTED_TO and a
+# value a few binades over the least normal number (under it a backend may
+# flush a part of the sum)
+GELU_COUNTED_TO = 64.0
+GELU_NO_FLUSH = 2.0 ** -120
+
+
+def gelu_float64(x):
+    """`0.5 x erfc(-x / sqrt 2)` of float64(x); -inf gives 0."""
+    import numpy as np
+    from scipy.special import erfc
+    x = np.asarray(x).astype(np.float64)
+    with np.errstate(invalid="ignore"):
+        y = 0.5 * x * erfc(-x / math.sqrt(2.0))
+    return np.where(np.isneginf(x), 0.0, y)
+
+
+def spacing(y, dtype):
+    """`dtype`'s ulp at |y|, a subnormal's being the least normal's."""
+    import ml_dtypes
+    import numpy as np
+    info = ml_dtypes.finfo(dtype)
+    exponent = np.floor(np.log2(np.maximum(np.abs(y), float(info.tiny))))
+    return 2.0 ** (exponent - info.nmant)
+
+
+def gelu_bfloat16_ulps(fn):
+    """(x, the float64 GeLU of x, |fn(x) - it| in bfloat16 ulp, the ulp) at
+    the 65,280 finite bfloat16 inputs."""
+    import jax
+    import ml_dtypes
+    import numpy as np
+    bf16 = ml_dtypes.bfloat16
+    with np.errstate(invalid="ignore"):
+        x = np.arange(65536, dtype=np.uint32).astype(np.uint16).view(
+            bf16).astype(np.float64)
+    x = x[np.isfinite(x)]
+    want, got = gelu_float64(x), np.asarray(jax.jit(fn)(x.astype(bf16)))
+    ulp = spacing(want, bf16)
+    return x, want, np.abs(got.astype(np.float64) - want) / ulp, ulp
+
+
+def gelu_float32_inputs():
+    """ISSUE 61's grid of 4,096 points of [-12, 12], and 400,000 points of
+    [-6.3, 6.3], where a float32 GeLU is above 2^-30."""
+    import numpy as np
+    return {"grid": np.linspace(-12, 12, 4096).astype(np.float32),
+            "dense": np.random.default_rng(61).uniform(
+                -6.3, 6.3, 400000).astype(np.float32)}
+
+
+def gelu_float32_ulps(fn, x):
+    """|fn(x) - GeLU(x)| in float32 ulp, an error under 2^-30 being none."""
+    import jax
+    import numpy as np
+    want = gelu_float64(x)
+    err = np.abs(np.asarray(jax.jit(fn)(x)).astype(np.float64) - want)
+    return np.where(err <= 2.0 ** -30, 0.0, err / spacing(want, np.float32))
+
+
+def exp_worst_ulp():
+    """How far this backend's float32 `exp` is from float64's, in float32
+    ulp, at the arguments the GeLU gives it (-a^2 / 2 up to a = 6.3): no
+    float32 form that takes an `exp` is closer. 1 on the CPU, 63 on a v5e."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    a = gelu_float32_inputs()["dense"]
+    v = a * a * np.float32(-0.5)
+    want = np.exp(v.astype(np.float64))
+    got = np.asarray(jax.jit(jnp.exp)(v)).astype(np.float64)
+    return float((np.abs(got - want) / spacing(want, np.float32)).max())
+
+
+def gelu_miss_counts(fn):
+    """What `kernel_checks.gelu` reports of one form on this backend."""
+    import numpy as np
+    float32 = {name: gelu_float32_ulps(fn, x)
+               for name, x in gelu_float32_inputs().items()}
+    x, want, ulps, ulp = gelu_bfloat16_ulps(fn)
+    whole = np.abs(want) >= GELU_NO_FLUSH
+    counted = whole & (np.abs(x) <= GELU_COUNTED_TO)
+    return {"counted": int(counted.sum()),
+            "past_half_an_ulp": int((ulps[counted] > 0.5).sum()),
+            "past_an_ulp": int((ulps[counted] > 1).sum()),
+            "worst_ulp": float(ulps[whole].max()),
+            "past_an_ulp_or_2^-24": int(
+                (ulps > np.maximum(1.0, 2.0 ** -24 / ulp)).sum()),
+            "float32_worst_ulp": {name: float(e.max())
+                                  for name, e in float32.items()},
+            "float32_past_4_ulp": {name: int((e > 4).sum())
+                                   for name, e in float32.items()}}
+
+
 def child_probe(sizes):
     from pipeedge_tpu.utils import enable_compile_cache, report_devices
     enable_compile_cache()
@@ -671,6 +765,25 @@ def child_probe(sizes):
                f"the short attention core is {gap} of the range from the "
                f"einsums at {(b, s, h, hd, dtype)}")
     kernel_checks["short_attention"] = {"gap_of_range": gaps}
+    # the erf GeLU at every bfloat16 input and on two float32 samples against
+    # float64, beside the form it replaced: what this backend's `exp`, divide
+    # and fusion make of each
+    kernel_checks["gelu"] = {
+        name: gelu_miss_counts(fn) for name, fn in (
+            ("shipped", layers.gelu),
+            ("jax_nn_gelu", lambda v: jax.nn.gelu(v, approximate=False)))}
+    kernel_checks["gelu"]["exp_worst_ulp"] = exp_worst_ulp()
+    shipped, before = (kernel_checks["gelu"][name]
+                       for name in ("shipped", "jax_nn_gelu"))
+    # float32: the form's own roundings are 5 ulp; the rest is the
+    # backend's `exp` (on a v5e `jax.nn.gelu`, whose `erfc` takes no `exp`
+    # for |x| < 1, is closer there: PERF.md section 7, row 47)
+    _check(shipped["past_an_ulp_or_2^-24"] == 0
+           and all(shipped[key] <= before[key] for key in
+                   ("past_half_an_ulp", "past_an_ulp", "worst_ulp"))
+           and max(shipped["float32_worst_ulp"].values())
+           <= kernel_checks["gelu"]["exp_worst_ulp"] + 6,
+           f"the erf GeLU misses the float64 one: {kernel_checks['gelu']}")
     facts.update(kernel_in_program=in_program, kernel_checks=kernel_checks)
     print(f"probe: {json.dumps(facts)}", flush=True)
 

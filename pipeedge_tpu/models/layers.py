@@ -628,8 +628,105 @@ def self_attention(p, x: jax.Array, num_heads: int,
 
 def gelu(x: jax.Array) -> jax.Array:
     """Exact (erf) GeLU, matching torch `nn.GELU()` default used by HF
-    (tanh approximation under fast-numerics)."""
-    return jax.nn.gelu(x, approximate=fast_numerics_enabled())
+    (tanh approximation under fast-numerics): `_erf_gelu`, in float32 from
+    the input whatever its floating type."""
+    if fast_numerics_enabled():
+        return jax.nn.gelu(x, approximate=True)
+    return _erf_gelu(x)
+
+
+# `tools/fit_gelu.py` prints these: Phi(-a) exp(a^2 / 2) = t * P(t) for
+# t = 1 / (a + GELU_C), to 4.3e-8 relative up to a = 6.6 (past it a float32
+# GeLU is under 2^-30) and 5.3e-5 up to GELU_CLAMP, where the `exp` is 0
+GELU_C = 3.5
+GELU_K = (0.39774173498153687, 1.457862138748169, 3.134087562561035,
+          29.51571273803711, -90.99691009521484, 605.0781860351562,
+          -1265.1190185546875, 862.3145141601562)
+GELU_CLAMP = 14.0
+INV_SQRT_2PI = 0.3989422804014327
+
+
+def _leading_bits(v: jax.Array, bits: int) -> jax.Array:
+    """float32 `v` with all but its first `bits` significant bits cleared:
+    the part of a compensated sum or square that is exact by construction.
+    By a mask, because XLA folds the arithmetic spellings away: it rewrites
+    `a - ((a + c) - c)` to 0 and `exp(p) * exp(q)` to `exp(p + q)`."""
+    kept = jax.lax.bitcast_convert_type(v, jnp.uint32) \
+        & jnp.uint32(0xFFFFFFFF << (24 - bits) & 0xFFFFFFFF)
+    return jax.lax.bitcast_convert_type(kept, jnp.float32)
+
+
+def _gelu_parts(x: jax.Array):
+    """(x, a, t P(t), exp(-a^2 / 2)) in float32, `a = min(|x|, GELU_CLAMP)`:
+    what the value and the slope of `_erf_gelu` are both made of.
+
+    A bfloat16 input's eight bits leave `a * a` exact in float32, and the
+    rounding of `a + c` is sixteen bits under the output's. An input of more
+    bits (float32: BERT, the tensor-parallel block and the expert layer run
+    it) rounds the square by half a float32 ulp, which the `exp` multiplies
+    by a^2 / 2 (4.5 at a = 3), and the sum by another half, which `t P(t)`
+    multiplies by up to 2.8: 7.7 ulp with the dozen other roundings. So
+    there both are taken of leading bits, which ARE exact, and what the bits
+    dropped is put back to first order: `exp(-a^2 / 2) = exp(-h^2 / 2)
+    (1 + r + r^2 / 2)`, `r = -(a - h)(a + h) / 2`, and `G(a) = G(a') +
+    (a - a') (a G(a') - 1 / sqrt(2 pi))` for `G = t P(t)`: 4.9 ulp, and 3.4
+    on ISSUE 61's grid (`tests/test_gelu_exact.py`). Seventeen more
+    operations, none of them in a bfloat16 program."""
+    xf = x.astype(jnp.float32)
+    a = jnp.minimum(jnp.abs(xf), GELU_CLAMP)
+    wide = x.dtype != jnp.bfloat16
+    shifted = _leading_bits(a + GELU_C, 16) if wide else a + GELU_C
+    t = 1.0 / shifted
+    poly = jnp.float32(GELU_K[-1])
+    for k in GELU_K[-2::-1]:
+        poly = poly * t + k
+    tail = poly * t
+    root = _leading_bits(a, 12) if wide else a
+    decay = jnp.exp(root * root * -0.5)
+    if wide:
+        tail = tail + (a - (shifted - GELU_C)) * (a * tail - INV_SQRT_2PI)
+        r = (a - root) * (a + root) * -0.5
+        decay = decay + decay * (r + r * r * 0.5)
+    return xf, a, tail, decay
+
+
+def _gelu_value(dtype, xf, a, tail, decay) -> jax.Array:
+    # a * t * P first: near 0.4 in the tail, so the product with the decay
+    # is subnormal only where the GeLU is
+    return (jnp.maximum(xf, 0.0) - (a * tail) * decay).astype(dtype)
+
+
+@jax.custom_jvp
+def _erf_gelu(x: jax.Array) -> jax.Array:
+    """`x Phi(x)` as `max(x, 0) - a Phi(-a)`, `a = |x|`, in float32: one
+    branch for every x, one divide and one `exp`, and the tail keeps its
+    RELATIVE accuracy because `Phi(-a) = t P(t) exp(-a^2 / 2)` leaves the
+    decay to the `exp` (docstring of `tools/fit_gelu.py`).
+
+    `jax.nn.gelu`'s `0.5 x erfc(-x / sqrt 2)` is, on a TPU, XLA's float32
+    `erfc`: both of its ranges' polynomials, an `exp` and two divides,
+    computed and selected, some 90 vector operations a value for the 40
+    here, which trail the ViT block's up product (PERF.md section 6, PR 61);
+    and on a bfloat16 input it rounds `sqrt 1/2` and the products to
+    bfloat16, where this form is the correctly rounded GeLU at every
+    bfloat16 input whose value is above 2^-24 (`tests/test_gelu_exact.py`).
+
+    `a` is clamped so that an infinite x meets 0 and not `inf * 0`: -inf
+    gives 0 (`jax.nn.gelu` gives NaN there). The slope is written out,
+    `Phi(x) + x phi(x)` from the parts the value is made of: differentiating
+    through the polynomial, the divide and the `exp` would give the same
+    number for twice the backward's work."""
+    return _gelu_value(x.dtype, *_gelu_parts(x))
+
+
+@_erf_gelu.defjvp
+def _erf_gelu_jvp(primals, tangents):
+    (x,), (dx,) = primals, tangents
+    xf, a, tail, decay = _gelu_parts(x)
+    below = tail * decay                                    # Phi(-a)
+    slope = jnp.where(xf < 0, below, 1.0 - below) + xf * decay * INV_SQRT_2PI
+    return (_gelu_value(x.dtype, xf, a, tail, decay),
+            (dx.astype(jnp.float32) * slope).astype(x.dtype))
 
 
 def gelu_new(x: jax.Array) -> jax.Array:

@@ -15,6 +15,7 @@ three of the five sparse families' stage programs: under `--dist loadfile` a
 file is one worker's, and the two are the run's longest.
 """
 import base64
+import collections
 import dataclasses
 import hashlib
 import math
@@ -333,6 +334,17 @@ def test_vit_large_forward_compiles_for_v5e(on_chip):
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert not re.findall(r"= bf16\[8,197,1024\]\{1,2,0[^}]*\} copy\(", text)
+    # the GeLU behind the up product is `layers._erf_gelu`'s one divide and
+    # one `exp`, fused with the product: no `erfc` expansion (two divides,
+    # four selects), and nothing as wide as the MLP's hidden outside a
+    # fusion that holds a product
+    hidden = [line for line in text.splitlines()
+              if re.search(r"= f32\[8,197,4096\]\S* [a-z]+\(", line)]
+    ops = collections.Counter(
+        re.search(r"\} ([a-z\-]+)\(", line).group(1) for line in hidden)
+    assert "erfc" not in text
+    assert ops["divide"] == 1 and ops["exponential"] == 1, ops
+    assert ops["select"] == 0 and ops["convolution"] == 1, ops
 
 
 def test_vit_large_train_step_compiles_for_v5e(topo):

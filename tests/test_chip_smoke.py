@@ -107,6 +107,17 @@ def test_one_chip_phases_at_tiny_size(chip_smoke, tiny, monkeypatch, capsys):
     assert by_phase["probe"]["dispatch_ms"] > 0
     assert len(by_phase["probe"]["kernel_checks"]["short_attention"][
         "gap_of_range"]) == 2
+    # every bfloat16 input through both forms of the GeLU, counted
+    gelu = by_phase["probe"]["kernel_checks"]["gelu"]
+    assert gelu["shipped"]["counted"] == gelu["jax_nn_gelu"]["counted"] > 3e4
+    assert gelu["shipped"]["past_an_ulp"] == 0
+    # and two float32 samples: ISSUE 61's grid within its 4 ulp, with an
+    # `exp` that is within one (a v5e's is 63 off: what the probe is for)
+    assert gelu["exp_worst_ulp"] <= 1
+    assert gelu["shipped"]["float32_worst_ulp"]["grid"] <= 4 \
+        < gelu["jax_nn_gelu"]["float32_worst_ulp"]["grid"]
+    assert gelu["shipped"]["float32_past_4_ulp"]["dense"] \
+        < gelu["jax_nn_gelu"]["float32_past_4_ulp"]["dense"]
     # on the CPU `auto` is the XLA ops, by the backend's name: no kernel
     assert not any(by_phase["probe"]["kernel_in_program"].values())
 

@@ -26,11 +26,10 @@ TINY = dict(hidden_size=32, num_hidden_layers=3, num_attention_heads=4,
             intermediate_size=64)
 
 
-@pytest.fixture(scope="module")
-def vit_setup():
+def tiny_vit(seed):
     from transformers import ViTConfig, ViTForImageClassification
     hf_cfg = ViTConfig(**TINY, image_size=16, patch_size=4, num_labels=5)
-    torch.manual_seed(0)
+    torch.manual_seed(seed)
     model = ViTForImageClassification(hf_cfg).eval()
     cfg = TransformerConfig(model_type="vit", **TINY, num_labels=5,
                             image_size=16, patch_size=4)
@@ -39,6 +38,11 @@ def vit_setup():
     with torch.no_grad():
         expected = model(x).logits.numpy()
     return cfg, weights, np.asarray(x), expected
+
+
+@pytest.fixture(scope="module")
+def vit_setup():
+    return tiny_vit(0)
 
 
 @pytest.fixture(scope="module")
@@ -160,20 +164,44 @@ def test_gpt2_causal_masking(gpt2_setup):
     assert not np.allclose(base[:, 5:], got[:, 5:])
 
 
-def test_unrolled_blocks_match_scanned(vit_setup):
-    """The unrolled execution layout (shard.unstack_blocks — the faster TPU
-    path) computes bit-identical results to the scanned stacked layout."""
+def scanned_and_unrolled(cfg, weights, x):
+    """(the stacked parameters, the unstacked, the logits of each)."""
     from pipeedge_tpu.models.shard import unstack_blocks
-
-    cfg, weights, x, expected = vit_setup
     total = 4 * cfg.num_hidden_layers
     sc = ShardConfig(1, total, is_first=True, is_last=True)
     params = vit_mod.load_params(cfg, sc, weights)
     fn = make_shard_fn(vit_mod.FAMILY, cfg, sc)
-    scanned = np.asarray(fn(params, jnp.asarray(x)))
     unrolled_params = unstack_blocks(params)
+    return (params, unrolled_params, np.asarray(fn(params, jnp.asarray(x))),
+            np.asarray(fn(unrolled_params, jnp.asarray(x))))
+
+
+def test_unrolled_blocks_match_scanned(vit_setup):
+    """The unrolled execution layout (shard.unstack_blocks — the faster TPU
+    path) computes bit-identical results to the scanned stacked layout, and
+    holds its parameters to the bit. (Bit-identical RESULTS are this seed's:
+    `test_scanned_and_unrolled_differ_in_a_last_bit_on_other_seeds` shows
+    what holds on any.)"""
+    import jax
+    from pipeedge_tpu.models.shard import unstack_blocks
+
+    cfg, weights, x, expected = vit_setup
+    params, unrolled_params, scanned, unrolled = scanned_and_unrolled(
+        cfg, weights, x)
     assert isinstance(unrolled_params["blocks"], tuple)
-    unrolled = np.asarray(fn(unrolled_params, jnp.asarray(x)))
+    assert len(unrolled_params["blocks"]) == cfg.num_hidden_layers
+    # what is structural holds to the bit: block i's leaves are row i of the
+    # stacked leaves, and nothing outside the blocks is touched
+    for i, block in enumerate(unrolled_params["blocks"]):
+        row = jax.tree_util.tree_map(lambda leaf: leaf[i], params["blocks"])
+        assert jax.tree_util.tree_structure(block) \
+            == jax.tree_util.tree_structure(row)
+        for got, want in zip(jax.tree_util.tree_leaves(block),
+                             jax.tree_util.tree_leaves(row)):
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for key in params:
+        assert key == "blocks" or unrolled_params[key] is params[key]
     np.testing.assert_array_equal(scanned, unrolled)
     np.testing.assert_allclose(unrolled, expected, rtol=2e-4, atol=2e-5)
     # idempotent / no-op cases
@@ -181,6 +209,33 @@ def test_unrolled_blocks_match_scanned(vit_setup):
     head_only = ShardConfig(1, 2, is_first=True, is_last=False)
     hp = vit_mod.load_params(cfg, head_only, weights)
     assert unstack_blocks(hp) is hp  # no full blocks: returned unchanged
+
+
+@pytest.mark.parametrize("form", ["jax.nn.gelu", "layers.gelu"])
+def test_scanned_and_unrolled_differ_in_a_last_bit_on_other_seeds(
+        form, monkeypatch):
+    """What a failure of the `assert_array_equal` above may and may not
+    mean. Over the fixture's seed and seven more, with the activation every
+    program had before PR 61 (`jax.nn.gelu(approximate=False)`) as with the
+    shipped one, the two programs agree to 2e-6 on every seed and to the BIT
+    on some only (0, 3 and 4 of 0-7 under `jax.nn.gelu`; 0, 1, 3, 4, 6 and 7
+    under `layers.gelu`): they are two programs with two sets of fusions.
+    A change of arithmetic that moves seed 0 out of that set has not broken
+    `unstack_blocks`; one that breaks the 2e-6 on any seed has."""
+    import jax
+    if form == "jax.nn.gelu":
+        monkeypatch.setattr(
+            vit_mod, "gelu", lambda v: jax.nn.gelu(v, approximate=False))
+    to_the_bit = []
+    for seed in range(8):
+        cfg, weights, x, expected = tiny_vit(seed)
+        _, _, scanned, unrolled = scanned_and_unrolled(cfg, weights, x)
+        np.testing.assert_allclose(scanned, unrolled, rtol=2e-6, atol=2e-7)
+        np.testing.assert_allclose(unrolled, expected, rtol=2e-4, atol=2e-5)
+        to_the_bit.append(bool((scanned == unrolled).all()))
+    print(f"{form}: scanned == unrolled to the bit on seeds "
+          f"{[s for s, same in enumerate(to_the_bit) if same]} of 0-7")
+    assert to_the_bit[0] and not all(to_the_bit)
 
 
 def test_bert_model_no_head_returns_pooler(bert_setup):
