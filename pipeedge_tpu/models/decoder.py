@@ -102,27 +102,31 @@ def by_head(x: jax.Array, heads: int) -> tuple:
     return tuple(jnp.split(x, heads, axis=-1))
 
 
-def routed_experts(p: Dict, normed, cfg: TransformerConfig):
+def routed_experts(p: Dict, normed, cfg: TransformerConfig, live=None):
     """The routed FFN's delta (with the shared expert's, where the block
     hands one over; through the block's `latent`, where it has one) and
     counts: `p["experts"]` is the block's own leaves, or
     `(stack, layer)` where the decode scan keeps the stacked blocks'
-    experts whole (the decode driver's `_run_blocks`)."""
+    experts whole (the decode driver's `_run_blocks`). `live`: which of the
+    tokens count (`topk_ffn_delta`); None = all."""
     experts, layer = p["experts"], None
     if isinstance(experts, tuple):
         experts, layer = experts
     return topk_ffn_delta(
         dict({name: p[name] for name in ("router", "shared", "shared_gate",
                                          "latent")
-              if name in p}, experts=experts), normed, cfg, layer=layer)
+              if name in p}, experts=experts), normed, cfg, layer=layer,
+        live=live)
 
 
-def ffn(p: Dict, normed: jax.Array, cfg: TransformerConfig):
+def ffn(p: Dict, normed: jax.Array, cfg: TransformerConfig, live=None):
     """The FFN of a cached block step over `normed`, its input's second
     norm: the routed experts where the block has a router, else its dense
-    SwiGLU. -> (delta, what the call counts under `MOE_STATS`, int32 [5])."""
+    SwiGLU. `live` (bool, a token of `normed` each; None = all): the rows of
+    a step that stand for a request; the others go to no expert and are not
+    counted. -> (delta, what the call counts under `MOE_STATS`, int32 [5])."""
     if "router" in p:
-        delta, moe = routed_experts(p, normed, cfg)
+        delta, moe = routed_experts(p, normed, cfg, live)
         return delta, jnp.concatenate([moe.astype(jnp.int32),
                                        jnp.ones(1, jnp.int32)])
     return dense_ffn(p["mlp"], normed), jnp.zeros(5, jnp.int32)

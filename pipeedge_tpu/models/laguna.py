@@ -4,6 +4,15 @@ a few that attend every position and most that attend the last
 rotations; a leading dense SwiGLU FFN, then many small experts routed by a
 softmax beside a gated shared one.
 
+The window-and-full block of two families. What differs between them is
+read off the configuration and off a block's leaves, never off the family's
+name: the gate a head (`cfg.head_gate`: a block has a `gate` leaf or none),
+the shared expert (`cfg.n_shared_experts`: `shared`, `shared_gate`), the
+leading dense layers (`cfg.first_k_dense`), the routed scaling factor, the
+heads and the rotation of each kind of layer. `models/mellum.py` is the
+second family (no gate, no shared expert, no dense layer, one head count,
+the whole head turned in both kinds) and holds no code of the block.
+
 The block, `x` [B, S, D], plain RMSNorm, no bias anywhere:
   h = x + Attn(rms(x; input_layernorm));
   x' = h + FFN(rms(h; post_attention_layernorm))
@@ -46,6 +55,12 @@ computation makes differently from the float32 reference: PERF.md, PRs 27,
 decode-shaped stage program: a span's window layers attend their ring and
 the span's own rows, not the ladder's width. A step is the span of one.
 
+**Served**, a request's rows step with every other running row
+(`rows_block_step`, parallel/decode_rows.py): a slot of the stage-wide cache
+is rows to `max_len` in the full blocks' leaves and a ring in the sliding
+blocks', each ring at its own row's position; the prompt pass runs in the
+spans above on the request's own cache.
+
 Refused by name: the forward path (`sublayer`), tp, sp and ep meshes, the
 int8 cache, `--kv-pages` (a page holds positions of one length) and
 speculative verify (a rejected draft's rows have overwritten slots of the
@@ -72,7 +87,8 @@ from .decoder import in_row_chunks, lin
 from .layers import (TransformerConfig, rms_norm, rotate_halves,
                      rope_frequencies, yarn_frequencies)
 from .shard import CacheLeaf, FamilySpec
-from .stage_cache import Window, cache_update_and_read, read_window
+from .stage_cache import (RowsAt, Window, attend_rows, cache_update_and_read,
+                          read_window, rows_walked)
 
 # what a block step counts into the cache's `stats` leaf, in this order
 STATS = decoder.MOE_STATS + ("swa_positions_read", "swa_positions_live",
@@ -203,29 +219,42 @@ def attend(q, ks, vs, keeps) -> jax.Array:
     return jnp.stack(out, axis=2).reshape(b, n_q, h, hd)
 
 
-def attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
-              prefill: bool, read_len=None):
-    """Gated GQA of `normed` [B, S, D] at [pos, pos + S) over what its
-    layer's leaves hold below `pos` and its own rows. -> (out, the cache
-    with the rows recorded, counts int32 [3]: the window layers'
-    `swa_positions_read`, `swa_positions_live`, `swa_ring_wraps`)."""
+def _project(p: Dict, normed, q_pos, cfg: TransformerConfig, sliding: bool):
+    """q [B, S, H, Dh], k, v [B, S, G, Dh] of `normed` [B, S, D] at positions
+    `q_pos` [S], normed a head and rotated by the layer's scheme, and the
+    gate a head [B, S, H] where the block has one (None where it has
+    none)."""
     b, s, _ = normed.shape
     eps, hd, groups = cfg.layer_norm_eps, cfg.head_dim, cfg.kv_heads
-    sliding = "k_ring" in bcache.stack
     heads = heads_of(cfg, "sliding" if sliding else "full")
-    if p["q"]["w"].shape[0] != heads * hd or p["gate"]["w"].shape[0] != heads:
+    if p["q"]["w"].shape[0] != heads * hd \
+            or ("gate" in p and p["gate"]["w"].shape[0] != heads):
         raise ValueError(
             f"a {'sliding' if sliding else 'full'} block of {heads} query "
-            f"heads of {hd} was built with q_proj {p['q']['w'].shape} and "
-            f"g_proj {p['gate']['w'].shape}")
-    q_pos = jnp.asarray(pos) + jnp.arange(s)
+            f"heads of {hd} was built with q_proj {p['q']['w'].shape}"
+            + (f" and g_proj {p['gate']['w'].shape}" if "gate" in p else ""))
     q = in_row_chunks(lambda rows: lin(p["q"]["w"], rows), normed,
                       heads * hd).reshape(b, s, heads, hd)
     k = lin(p["k"]["w"], normed).reshape(b, s, groups, hd)
     v = lin(p["v"]["w"], normed).reshape(b, s, groups, hd)
-    gate = jax.nn.sigmoid(lin(p["gate"]["w"], normed))        # [B, S, H]
+    gate = jax.nn.sigmoid(lin(p["gate"]["w"], normed)) \
+        if "gate" in p else None                              # [B, S, H]
     q = rotate(rms_norm(p["q_norm"], q, eps), q_pos, cfg, sliding)
     k = rotate(rms_norm(p["k_norm"], k, eps), q_pos, cfg, sliding)
+    return q, k, v, gate
+
+
+def attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
+              prefill: bool, read_len=None):
+    """GQA of `normed` [B, S, D] at [pos, pos + S) over what its layer's
+    leaves hold below `pos` and its own rows, gated a head where the block
+    has a gate. -> (out, the cache with the rows recorded, counts int32 [3]:
+    the window layers' `swa_positions_read`, `swa_positions_live`,
+    `swa_ring_wraps`)."""
+    b, s, _ = normed.shape
+    sliding = "k_ring" in bcache.stack
+    q, k, v, gate = _project(p, normed, jnp.asarray(pos) + jnp.arange(s),
+                             cfg, sliding)
     leaves = dict(window=cfg.sliding_window, names=("k_ring", "v_ring"),
                   ring=True) if sliding else dict(read_len=read_len)
     ks, vs, keeps, bcache = cache_update_and_read(
@@ -237,9 +266,10 @@ def attention(p: Dict, normed, bcache, pos, cfg: TransformerConfig,
             jnp.int32(b * sum(keep.size for keep in keeps)),
             b * sum(jnp.sum(keep, dtype=jnp.int32) for keep in keeps),
             (jnp.asarray(pos) % ring + s > ring).astype(jnp.int32)])
-    ctx = attend(q, ks, vs, keeps) * gate[..., None]
-    return lin(p["attn_out"]["w"], ctx.reshape(b, s, heads * hd)), \
-        bcache, counts
+    ctx = attend(q, ks, vs, keeps)
+    if gate is not None:
+        ctx = ctx * gate[..., None]
+    return lin(p["attn_out"]["w"], ctx.reshape(b, s, -1)), bcache, counts
 
 
 def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
@@ -258,7 +288,48 @@ def cached_block_step(p: Dict, x, bcache, pos, cfg: TransformerConfig,
         rows=dict(bcache.rows, stats=jnp.concatenate([moe, counts])))
 
 
+def rows_block_step(p: Dict, x, bcache, at: RowsAt, cfg: TransformerConfig,
+                    block: int):
+    """`cached_block_step` for one token a row, row r at `at.pos[r]` (the
+    served executor's step, parallel/decode_rows.py), of any of the kinds:
+    each row's q and k are rotated at its own position (the rows taken as
+    one sequence of R positions, which is what `rotate` turns); a full block
+    walks its slots' rows to the furthest live row, a sliding block its
+    slots' rings, each slot masked by the position it holds FOR ITS ROW
+    (`stage_cache.attend_rows`). A dead row is routed to no expert and adds
+    to no count."""
+    eps, rows = cfg.layer_norm_eps, x.shape[0]
+    sliding = "k_ring" in bcache.stack
+    normed = rms_norm(p["ln_before"], x, eps)
+    q, k, v, gate = (
+        None if y is None else y.reshape((rows, 1) + y.shape[2:])
+        for y in _project(p, normed.reshape(1, rows, -1), at.pos, cfg,
+                          sliding))
+    window = cfg.sliding_window if sliding else 0
+    ctx, bcache = attend_rows(
+        bcache, q, k, v, at, block, cfg, window=window,
+        names=("k_ring", "v_ring") if sliding else ("k", "v"),
+        precision=_ATTENTION, ring=sliding)
+    counts = jnp.zeros(3, jnp.int32)
+    if sliding:     # a live row's ring as walked and its own row
+        ring = bcache.stack["k_ring"].shape[2]
+        counts = jnp.stack([
+            jnp.sum(at.live, dtype=jnp.int32)
+            * (rows_walked(at.reach, ring, block) + 1),
+            jnp.sum(jnp.where(at.live, jnp.minimum(at.pos + 1, window), 0),
+                    dtype=jnp.int32),
+            jnp.int32(0)])
+    if gate is not None:
+        ctx = (ctx.reshape(q.shape) * gate[..., None]).reshape(ctx.shape)
+    h = x + lin(p["attn_out"]["w"], ctx)
+    delta, moe = decoder.ffn(p, rms_norm(p["ln_after"], h, eps), cfg,
+                             live=at.live)
+    return h + delta, bcache._replace(
+        rows=dict(bcache.rows, stats=jnp.concatenate([moe, counts])))
+
+
 FAMILY = FamilySpec(name="laguna", cached_block_step=cached_block_step,
+                    rows_block_step=rows_block_step,
                     **decoder.token_hooks("laguna", ACTIVATIONS, rms_norm),
                     decoder_model=True, position_dependent_attention=True,
                     cache_leaves=cache_leaves, prefill_span=prefill_span,
@@ -288,7 +359,7 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
                            (cfg.vocab_size, d))}
 
     def get_block(block_id: int, subs: tuple) -> Dict:
-        decoder.whole_blocks("laguna", subs)
+        decoder.whole_blocks(cfg.model_type, subs)
         root = f"model.layers.{block_id}."
         att = root + "self_attn."
         heads = cfg.layer_heads[block_id]
@@ -296,11 +367,12 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
              "q": {"w": get(att + "q_proj.weight", (heads * hd, d))},
              "k": {"w": get(att + "k_proj.weight", (groups * hd, d))},
              "v": {"w": get(att + "v_proj.weight", (groups * hd, d))},
-             "gate": {"w": get(att + "g_proj.weight", (heads, d))},
              "q_norm": scale(att + "q_norm.weight", hd),
              "k_norm": scale(att + "k_norm.weight", hd),
              "attn_out": {"w": get(att + "o_proj.weight", (d, heads * hd))},
              "ln_after": scale(root + "post_attention_layernorm.weight", d)}
+        if cfg.head_gate:
+            p["gate"] = {"w": get(att + "g_proj.weight", (heads, d))}
         if block_id < cfg.first_k_dense:
             p["mlp"] = mlp(root + "mlp.", cfg.intermediate_size)
             return p
@@ -310,10 +382,12 @@ def _assemble(cfg: TransformerConfig, shard_config: ShardConfig, get,
                 for e in range(first, first + count)]
         p["experts"] = {name: decoder.stack([one[name] for one in held])
                         for name in ("gate", "up", "down")}
-        p["shared"] = mlp(root + "mlp.shared_expert.",
-                          cfg.moe_intermediate_size * cfg.n_shared_experts)
-        p["shared_gate"] = get(root + "mlp.shared_expert_gate.weight",
-                               (1, d))
+        if cfg.n_shared_experts:
+            p["shared"] = mlp(
+                root + "mlp.shared_expert.",
+                cfg.moe_intermediate_size * cfg.n_shared_experts)
+            p["shared_gate"] = get(root + "mlp.shared_expert_gate.weight",
+                                   (1, d))
         return p
 
     def get_final() -> Dict:

@@ -25,6 +25,7 @@ class TransformerConfig:
     model_type: str              # the family: 'vit' | 'bert' | 'deit' |
     #                              'gpt2' | 'llama' | 'keye' | 'kimi' |
     #                              'qwen3_next' | 'lfm2' | 'laguna' |
+    #                              'mellum' (laguna's block) |
     #                              'minicpm_sala' | 'nemotron_h' |
     #                              'granite_hybrid' | 'brumby'
     hidden_size: int
@@ -134,6 +135,9 @@ class TransformerConfig:
     # `partial_rotary_factor` are the full layers')
     layer_heads: tuple = ()
     sliding_rope_theta: float = 0.0
+    # ... and whether each head's attention output is multiplied by a gate
+    # of its own, `sigmoid(g_proj u)` (laguna has one, mellum none)
+    head_gate: bool = False
     # minicpm_sala family ("minicpm4" | "lightning-attn" in `layer_types`):
     # MiniCPM's three scalings (the embedding times `scale_emb`, a block's
     # two deltas times `scale_depth / sqrt(published_layers)`, the head's

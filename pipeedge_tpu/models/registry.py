@@ -37,6 +37,7 @@ from . import keye as keye_mod
 from . import kimi as kimi_mod
 from . import laguna as laguna_mod
 from . import lfm2 as lfm2_mod
+from . import mellum as mellum_mod
 from . import llama as llama_mod
 from . import minicpm_sala as minicpm_sala_mod
 from . import nemotron_h as nemotron_h_mod
@@ -181,7 +182,28 @@ def _laguna(name, weights, hidden, pattern, heads, kv_heads, head_dim,
         layer_types=_layer_types(pattern),
         layer_heads=tuple({"f": full_heads, "s": sliding_heads}[m]
                           for m in pattern),
-        prefill_chunk=span))
+        head_gate=True, prefill_chunk=span))
+
+
+def _mellum(name, weights, hidden, pattern, heads, kv_heads, head_dim,
+            window, vocab, max_pos, experts, expert_width, per_tok, yarn,
+            span, theta=500000.0):
+    """laguna's block (`models/mellum.py`): one head count and one base for
+    both kinds of layer, the whole head turned, no gate a head, no shared
+    expert, no dense layer, no scaling factor."""
+    blocks = len(pattern)
+    return ModelEntry(name, 4 * blocks, weights, mellum_mod,
+                      TransformerConfig(
+        model_type="mellum", hidden_size=hidden, num_hidden_layers=blocks,
+        num_attention_heads=heads, num_kv_heads=kv_heads,
+        attn_head_dim=head_dim, intermediate_size=0, layer_norm_eps=1e-6,
+        vocab_size=vocab, max_position_embeddings=max_pos, rope_theta=theta,
+        rope_yarn=tuple(yarn), partial_rotary_factor=1.0,
+        sliding_rope_theta=theta, sliding_window=window, qk_norm=True,
+        n_experts=experts, moe_intermediate_size=expert_width,
+        num_experts_per_tok=per_tok, norm_topk_prob=True,
+        layer_types=_layer_types(pattern),
+        layer_heads=(heads,) * blocks, prefill_chunk=span))
 
 
 def _minicpm_sala(name, weights, hidden, pattern, heads, kv_heads, head_dim,
@@ -336,6 +358,17 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
             max_pos=262144, experts=256, expert_width=512, per_tok=8,
             yarn=(64.0, 4096, 64.0, 1.0, 1.4158883083359672),
             sliding_theta=10000.0, span=128),
+    # Mellum2-12B-A2.5B: periods of three layers that attend the last 1,024
+    # positions and one full-attention layer under YaRN, 32 query and 4 KV
+    # heads of 128 in both, the whole head turned; every FFN 64 experts of
+    # 896 routed 8 a token by a renormalised softmax, none shared, no dense
+    # layer. One chip holds the first of four pipeline stages, two periods:
+    # `...@8`
+    _mellum("JetBrains/Mellum2-12B-A2.5B-Instruct",
+            "Mellum2-12B-A2.5B-Instruct.npz", 2304, "sssf" * 7, 32, 4, 128,
+            window=1024, vocab=98304, max_pos=131072, experts=64,
+            expert_width=896, per_tok=8,
+            yarn=(16.0, 8192, 32.0, 1.0, 1.2772588722239782), span=512),
     # MiniCPM-SALA: 8 layers of MiniCPM4's block-sparse attention (32 query
     # and 2 KV heads, no rotation; pooled keys of 32 positions every 16,
     # blocks of 64: the first, the 32 local and the 64 best) among 24 of
@@ -420,6 +453,14 @@ _MODELS: Dict[str, ModelEntry] = {e.name: e for e in [
             max_pos=64, experts=8, expert_width=16, per_tok=2,
             yarn=(4.0, 16, 2.0, 0.25, 1.1386294361119891),
             sliding_theta=100.0, span=4, theta=10000.0),
+    # eight blocks, two periods: every ring wraps past 8 positions, the
+    # spans of 4 are half a ring, the ramp of its YaRN has frequencies
+    # inside it; 3 of 8 experts a token
+    _mellum("pipeedge/test-tiny-mellum", "test-tiny-mellum.npz", 32,
+            "sssf" * 2, 4, 2, 16, window=8, vocab=100, max_pos=64,
+            experts=8, expert_width=16, per_tok=3,
+            yarn=(4.0, 16, 2.0, 0.25, 1.1386294361119891), span=4,
+            theta=10000.0),
     # six blocks: either kind has two runs in one stage; kernels of 4
     # every 2, blocks of 8 (the first, the 2 local and the 2 best), dense
     # up to 32 positions

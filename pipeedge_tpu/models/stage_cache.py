@@ -234,18 +234,25 @@ def write_rows(cache: Cache, rows: Cache, pos, whole: tuple = (),
         assert new.shape == buf.shape, (new.shape, buf.shape)
         return new.astype(buf.dtype)
 
-    def add(buf, new):      # `stats`: [L, n, 2] += [L, n]
-        low = buf[..., 1] + new
-        return jnp.stack([buf[..., 0] + (low >> _STATS_UNIT),
-                          low & ((1 << _STATS_UNIT) - 1)], axis=-1)
-
     return {name: buf if not buf.shape[0] else
-            (add if name == STATS else
+            (add_stats if name == STATS else
              replace if name in whole else
              around if name in rings else
              strided(name) if name in (strides or ()) else
              write)(buf, rows[name])
             for name, buf in cache.items()}
+
+
+def add_stats(buf: jax.Array, new: jax.Array) -> jax.Array:
+    """The `stats` leaf `[L, n, 2]` with a call's counts `[L, n]` added."""
+    low = buf[..., 1] + new
+    return jnp.stack([buf[..., 0] + (low >> _STATS_UNIT),
+                      low & ((1 << _STATS_UNIT) - 1)], axis=-1)
+
+
+def merge_stats(total: jax.Array, counted: jax.Array) -> jax.Array:
+    """Two `stats` leaves `[L, n, 2]` added, pair by pair."""
+    return add_stats(total, counted[..., 1]).at[..., 0].add(counted[..., 0])
 
 
 def read_stats(cache: Cache):
@@ -548,24 +555,45 @@ def cache_update_and_read(bcache: LayerCache, k_new: jax.Array,
 # The served executor steps every running request's row in one program
 # (`parallel/decode_rows.py`): row r is slot `base + r` of a stage-wide cache
 # and stands at `pos[r]`, -1 where the slot is dead (free, or not at this
-# stage in this tick). Such a step reads slots [base, base + R) of the plain
-# `k`, `v` pair and no window ladder: it walks the positions in blocks up to
-# the furthest live row (`reach`) under one online softmax, so one program a
-# count of rows serves every length and every place in the cache.
+# stage in this tick). Such a step reads slots [base, base + R) of leaves
+# that keep a row a position, and no window ladder: it walks the positions
+# in blocks up to the furthest live row (`reach`) under one online softmax,
+# so one program a count of rows serves every length and every place in the
+# cache. A ring (a leaf with a `length`) is walked the same way, slot by
+# slot: each slot's ring stands at its own row's position, so slot `s` of
+# row r holds the largest `p < pos[r]` with `p mod W == s`, and is masked by
+# that; the walk ends at the ring's end, or at `reach` while no row has
+# filled its ring.
 
 class RowsAt(NamedTuple):
     """Where the R rows of such a step stand, all traced: the first of
     their slots, each row's position (0 where the row is dead, for reading
-    and embedding), and the furthest live position."""
+    and embedding), the furthest live position, and which rows are live
+    (a dead row writes nothing, takes no token and is not counted)."""
     base: jax.Array
     pos: jax.Array
     reach: jax.Array
+    live: Optional[jax.Array] = None
+
+
+def walk_blocks(reach, held: int, block: int):
+    """Blocks of `block` positions (no more than the leaf's `held`) that a
+    step's walk of a leaf takes with its furthest live row at `reach`."""
+    return (jnp.minimum(reach, held) + block - 1) // block
+
+
+def rows_walked(reach, held: int, block: int):
+    """Positions of a leaf of `held` positions that such a walk reads:
+    whole blocks, the leaf at most."""
+    block = min(block, held)
+    return jnp.minimum(walk_blocks(reach, held, block) * block, held)
 
 
 def attend_rows(bcache: LayerCache, q: jax.Array, k_new: jax.Array,
                 v_new: jax.Array, at: RowsAt, block: int,
                 cfg: TransformerConfig, window: int = 0,
-                names: tuple = ("k", "v"), precision=None):
+                names: tuple = ("k", "v"), precision=None,
+                ring: bool = False):
     """One decode step's attention for R rows at their own positions:
     q [R,1,H,Dh] and the step's own k_new, v_new [R,1,G,Dh] against slots
     [at.base, at.base + R) of this layer's cache, row r reading the
@@ -577,7 +605,13 @@ def attend_rows(bcache: LayerCache, q: jax.Array, k_new: jax.Array,
     furthest live row's position, each block read as it is stored (`_scores`,
     `_context`) and folded into a running maximum, sum and context: what a
     shorter row does not hold is masked to exact zeros, and nothing past
-    it is read. A dead row (position 0) attends its own row alone."""
+    it is read. A dead row (position 0) attends its own row alone.
+
+    `ring` (STATIC): the two leaves are rings (`CacheLeaf.length`): a slot is
+    kept by the position it holds for ITS row, the largest below
+    `at.pos[r]` that falls on it, where that is inside the row's window;
+    the walk covers the ring, or the slots below `at.reach` while no row has
+    come round."""
     stack = bcache.stack
     rows, _, h, hd = q.shape
     k_buf, v_buf = (stack[name] for name in names)
@@ -604,9 +638,21 @@ def attend_rows(bcache: LayerCache, q: jax.Array, k_new: jax.Array,
             buf, (bcache.layer, at.base, start, 0),
             (1, rows, block, buf.shape[3]))[0].astype(q.dtype)
             for buf in (k_buf, v_buf))
-        k_pos = start + jax.lax.broadcasted_iota(
+        if ring and rows == 1:
+            # one row's ring, its values' heads apart: read as stored, the
+            # chip's compiler lays the WHOLE stack of rings out anew for
+            # this product, a copy of it every step (402 MB at 32 slots of
+            # mellum's six rings: compiled for the described v5e, PR 62;
+            # `tests/test_chip_compile_families.py` holds the rung to none)
+            v = v.reshape(v.shape[:2] + (-1, hd))
+        slot = start + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, 1, block), 3)
-        keep = (k_pos >= first) & (k_pos < below)
+        if ring:    # the position each slot holds; negative: nothing yet
+            k_pos = below - 1 - jnp.mod(below - 1 - slot, held)
+            keep = (slot >= first) & (k_pos >= 0)
+        else:
+            k_pos = slot
+            keep = (slot >= first) & (k_pos < below)
         if window:
             keep &= k_pos > below - window
         part = jnp.where(keep, _scores(q, k, precision) / scale, -1e30)
@@ -618,17 +664,19 @@ def attend_rows(bcache: LayerCache, q: jax.Array, k_new: jax.Array,
             + _context(probs.astype(q.dtype), v, hd, precision)
         return new_top, total, ctx
 
-    n_blocks = (jnp.minimum(at.reach, held) + block - 1) // block
-    top, total, ctx = jax.lax.fori_loop(0, n_blocks, fold,
-                                        (top, total, ctx))
+    top, total, ctx = jax.lax.fori_loop(
+        0, walk_blocks(at.reach, held, block), fold, (top, total, ctx))
     ctx = ctx / jnp.transpose(total, (0, 2, 1, 3))
     return ctx.astype(q.dtype).reshape(rows, 1, h * hd), bcache
 
 
-def write_rows_at(cache: Cache, rows: Cache, base, pos: jax.Array) -> Cache:
+def write_rows_at(cache: Cache, rows: Cache, base, pos: jax.Array,
+                  rings: tuple = ()) -> Cache:
     """Every layer's new `rows` (leaves `[L, R, 1, ...]`) into the stacked
-    cache, row r into slot `base + r` at position `pos[r]`: one in-place
-    update a leaf a row, `[L, 1, 1, ...]` at `(0, base + r, pos[r])`, as
+    cache, row r into slot `base + r` at position `pos[r]` (of a leaf named
+    in `rings` at `pos[r] mod W`; the `stats` leaf's counts are added): one
+    in-place update a leaf a row, `[L, 1, 1, ...]` at `(0, base + r,
+    pos[r])`, as
     `write_rows` makes one for all rows at one position, in a loop over the rows (unrolled, 48
     rows were 290 operations and 0.4 s more of every set-up to lower; a
     step's time is the same: my chip runs, PR 55). A dead row (`pos`
@@ -637,12 +685,15 @@ def write_rows_at(cache: Cache, rows: Cache, base, pos: jax.Array) -> Cache:
     the whole stack and copies 2.4 GB of gpt2-medium's 48 slots into it and
     back, twice a leaf a step (`tests/test_chip_compile.py` holds the step
     to no such copy, and the loop's carry to the stack's own layout)."""
-    def write(buf, new):
+    def write(buf, new, ring=False):
         tail = (0,) * (buf.ndim - 3)
         new = new.astype(buf.dtype)
 
         def one(r, buf):
-            at = (0, base + r, jnp.maximum(pos[r], 0)) + tail
+            where = jnp.maximum(pos[r], 0)
+            if ring:
+                where = where % buf.shape[2]
+            at = (0, base + r, where) + tail
             row = jax.lax.dynamic_slice_in_dim(new, r, 1, axis=1)
             held = jax.lax.dynamic_slice(buf, at, row.shape)
             return jax.lax.dynamic_update_slice(
@@ -650,4 +701,7 @@ def write_rows_at(cache: Cache, rows: Cache, base, pos: jax.Array) -> Cache:
 
         return jax.lax.fori_loop(0, new.shape[1], one, buf)
 
-    return {name: write(buf, rows[name]) for name, buf in cache.items()}
+    return {name: buf if not buf.shape[0] else
+            add_stats(buf, rows[name]) if name == STATS else
+            write(buf, rows[name], name in rings)
+            for name, buf in cache.items()}

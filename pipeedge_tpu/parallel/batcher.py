@@ -32,13 +32,18 @@ TPU-first constraints drive the design:
   they were picked, and a step sent meanwhile is discarded. Token for token what a solo
   `generate()` gives, the rows being independent. Rows batch where the
   code can see that they may: the stages' programs take row positions
-  (`decode_rows.rows_block_fn`: the plain dense block and llama's; a
-  family that names its cache leaves has one `pos` a program), the cache
-  is not paged (`kv is None`), the request is greedy and fits the slots.
-  Every other request keeps a cache of its own per stage and one dispatch
-  a stage-step, as all did before: a sampled request (its picks split its
-  own key over its own rows), the paged backend's, a family's whose
-  program takes one position.
+  (`decode_rows.rows_block_fn`: the plain dense block, llama's, and the
+  window-and-full block of laguna and mellum with a ring a slot; a family
+  with a leaf that is a state a request or a row every few positions has
+  one `pos` a program), the cache is not paged (`kv is None`), the request
+  is greedy and fits the slots. Every other request keeps a cache of its
+  own per stage and one dispatch a stage-step, as all did before: a
+  sampled request (its picks split its own key over its own rows), the
+  paged backend's, a family's whose program takes one position.
+- **A prompt pass in the family's spans**: a family that prefills in spans
+  (`FamilySpec.prefill_span`) has its prompt run span by span through the
+  "chunk" waves below, at its own span whatever `chunk_tokens` says, on
+  the request's own cache, other requests' steps between the spans.
 - **Wave scheduling, host-driven**: the scheduler advances one "tick" at a
   time; per tick each stage dispatches at most one program: one request's
   prompt pass or stage-step, or the step of every row that holds a slot.
@@ -83,7 +88,8 @@ import numpy as np
 from .. import telemetry
 from ..telemetry import metrics as prom
 from ..utils.threads import make_condition
-from .decode import (M_ATTEND, DecodePipeline, _repeat_batch,
+from ..models.stage_cache import STATS, read_stats
+from .decode import (M_ATTEND, DecodePipeline, _repeat_batch, count_stats,
                      make_next_picker, validate_capacity)
 from .decode_rows import StageRows, rows_block_fn
 
@@ -108,6 +114,15 @@ M_WAKEUPS = prom.REGISTRY.counter(
     "times a caller blocked in the executor's wait() woke: kind=own its "
     "request had ended (or the executor had), kind=other it had not and "
     "the caller slept again (0: a request's end wakes its own waiter)")
+M_PROMPT_SPANS = prom.REGISTRY.counter(
+    "pipeedge_prompt_spans_total",
+    "programs a prompt pass went out as at stage 0: one for a prompt run "
+    "whole, one a span where it runs in spans (a family's prefill_span, a "
+    "chunked prefill); with pipeedge_prompt_positions_total, the spans a "
+    "prompt and the positions a span")
+M_PROMPT_POSITIONS = prom.REGISTRY.counter(
+    "pipeedge_prompt_positions_total",
+    "prompt positions those programs ran, a row a position")
 M_STEPS.declare(executor="wave")
 M_CHUNKS.declare(executor="wave")
 for _kind in ("live", "slots"):
@@ -281,7 +296,7 @@ def _next_chunk(req: _Request, chunk_tokens: int) -> jnp.ndarray:
 
 
 def _maybe_chunk(req: _Request, kind: str, data,
-                 chunk_tokens: int):
+                 chunk_tokens: int, always: bool = False):
     """Convert a long prompt pass into its first CHUNK. A prompt pass
     ("prefill" for a fresh prompt, "span" for a prefix/trie-seeded
     suffix) longer than `chunk_tokens` becomes a sequence of "chunk"
@@ -291,9 +306,11 @@ def _maybe_chunk(req: _Request, kind: str, data,
     exact softmax zeros), and the scheduler interleaves other requests'
     decode steps between chunks. The base offset is uniform across
     seeding paths: prompt_len - data_len (0 fresh, shared_len trie,
-    prefix_len dense prefix)."""
+    prefix_len dense prefix). `always`: a prompt that fits one chunk is one
+    chunk all the same (a family that prefills in spans has no program for
+    a prompt run whole through its rings)."""
     if chunk_tokens < 1 or kind not in ("prefill", "span") \
-            or data.shape[1] <= chunk_tokens:
+            or (data.shape[1] <= chunk_tokens and not always):
         return kind, data
     req.chunk_next = req.prompt_len - data.shape[1]
     req.chunk_rest = data
@@ -329,10 +346,14 @@ def _run_stage(pipe: DecodePipeline, i: int, req: _Request, data,
         elif kind == "chunk":
             # chunked prefill: this slice of the prompt runs as a span
             # at its absolute offset; earlier chunks' KV rows are
-            # already in the caches, so attention is exact
+            # already in the caches, so attention is exact. A family's
+            # own span gives its last row alone to the head, as
+            # `DecodePipeline._prefill`'s does
             out, req.caches[i] = pipe._decode_step(
                 st, data, req.caches[i], req.chunk_off,
-                span=data.shape[1])
+                span=data.shape[1],
+                last_only=bool(pipe.prefill_span)
+                and i + 1 == len(pipe.stages))
         else:
             out, req.caches[i] = pipe._decode_step(st, data, req.caches[i],
                                                    req.pos)
@@ -464,6 +485,12 @@ class ContinuousBatcher:
         if chunk_tokens < 0:
             raise ValueError(f"chunk_tokens must be >= 0, got {chunk_tokens}")
         self.chunk_tokens = int(chunk_tokens)
+        # a family that prefills in spans (`FamilySpec.prefill_span`: its
+        # rings hold a window, its mixers run in chunks) has its prompt
+        # pass run span by span through the same "chunk" waves, on the
+        # request's own cache, other requests' steps between the spans:
+        # no option, the family says, and its span is the chunk whatever
+        # `chunk_tokens` is
         self.prefill_budget = (self.chunk_tokens if prefill_budget is None
                                else int(prefill_budget))
         if self.chunk_tokens and self.prefill_budget < 1:
@@ -506,6 +533,8 @@ class ContinuousBatcher:
         # and the ticks not read back yet as (that tick's ids, its requests)
         self._sent: List[_Request] = []
         self._unread: deque = deque()
+        # the device's counts already in the registry (`count_stats`)
+        self._counted = 0
         # the served life cycle (start/wait/stop): ONE condition guards
         # the queues and `results` between the worker and the caller
         # threads, and the worker alone waits on it (for work). A caller
@@ -520,6 +549,11 @@ class ContinuousBatcher:
         self._worker: Optional[threading.Thread] = None
         self._stop = False
         self._dead: Optional[BaseException] = None
+
+    @property
+    def _chunk(self) -> int:
+        """Positions a prompt pass runs at a time; 0 = whole."""
+        return self.pipe.prefill_span or self.chunk_tokens
 
     def set_chunk_tokens(self, n: int) -> None:
         """Retarget the chunk size (GIL-atomic int write) — the brownout
@@ -647,7 +681,8 @@ class ContinuousBatcher:
                 # prefix offset (prompt caching); otherwise a fresh prefill
                 kind = "prefill" if req.prefix is None else "span"
                 data = req.ids
-            kind, data = _maybe_chunk(req, kind, data, self.chunk_tokens)
+            kind, data = _maybe_chunk(req, kind, data, self._chunk,
+                                      always=bool(self.pipe.prefill_span))
             if kind == "chunk":
                 self.stats["prefill_chunks"] += 1
                 M_CHUNKS.inc(executor="wave")
@@ -675,7 +710,7 @@ class ContinuousBatcher:
                                  and req.cancel.is_set()):
                 self._complete(req)   # mid-prompt shed: free pages now
                 return
-            data = _next_chunk(req, self.chunk_tokens)
+            data = _next_chunk(req, self._chunk)
             self.stats["prefill_chunks"] += 1
             M_CHUNKS.inc(executor="wave")
             reentries.append((req, data, "chunk"))
@@ -912,6 +947,27 @@ class ContinuousBatcher:
         return [self._unread.popleft()
                 for _ in range(len(self._unread) - keep)]
 
+    def count_stats(self) -> None:
+        """Add what the family's block steps have counted on the device
+        since the last call (`FamilySpec.stats_names`; nothing where it has
+        none) to the registry's `pipeedge_<name>_total{phase}`, as
+        `DecodePipeline.generate` does once a batch: phase=prefill what the
+        installed requests' prompt passes counted, phase=decode what the
+        steps of the rows that step together did. The read waits for the
+        step in flight (under the executor's lock, so no step donates the
+        leaf meanwhile): a scrape of `/metrics` calls it, no tick does. A
+        request that steps alone (sampled) is not counted."""
+        names = getattr(self.pipe.family, "stats_names", ())
+        if self.rows is None or not names or not self.rows.prompt_stats:
+            return
+        with self.cond:
+            prompt = sum(read_stats({STATS: stats})
+                         for stats in self.rows.prompt_stats)
+            steps = sum(read_stats(cache) for cache in self.rows.caches)
+            now = np.stack([prompt, steps])
+            gained, self._counted = now - self._counted, now
+        count_stats(names, *gained)
+
     def warm(self) -> None:
         """Build the programs that no request of a warm-up sent alone would
         meet (the wider rungs of the rows' step) before traffic comes: a
@@ -972,6 +1028,9 @@ class ContinuousBatcher:
                 if i + 1 < self.n_stages:
                     self._stage_q[i + 1].append((group, out, "rows"))
                 continue
+            if i == 0:
+                M_PROMPT_SPANS.inc()
+                M_PROMPT_POSITIONS.inc(data.shape[0] * data.shape[1])
             if self.kv is not None:
                 out = self.kv.run_stage(i, req, data, kind)
             else:

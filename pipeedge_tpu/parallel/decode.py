@@ -337,6 +337,19 @@ M_LEAF_BYTES = prom.REGISTRY.gauge(
     "max_len")
 
 
+def count_stats(names, prompt, steps) -> None:
+    """Counts read back from `stats` leaves (`FamilySpec.stats_names`'
+    order) onto the registry's `pipeedge_<name>_total`, by phase: what
+    prompt passes counted, what decode steps did."""
+    for phase, counts in (("prefill", prompt), ("decode", steps)):
+        for name, count in zip(names, counts):
+            prom.REGISTRY.counter(
+                f"pipeedge_{name}_total",
+                "counted on the device by the stage programs, read back "
+                "once a batch (a server: once a scrape)").inc(
+                    float(count), phase=phase)
+
+
 def attend_bucket(pos_next: int, max_len: int, floor: int = 64,
                   per_octave: int = 1, grain: Optional[int] = None) -> int:
     """Static attend-window size for a decode step with `pos_next` valid
@@ -435,7 +448,7 @@ def _written_by_run(runs, kinds, cache: Cache, leaves) -> tuple:
 def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
                 prefill: bool, block_fn=_block_step,
                 whole: tuple = (), kinds: tuple = (),
-                leaves=None) -> Tuple[jax.Array, Cache]:
+                leaves=None, write=None) -> Tuple[jax.Array, Cache]:
     """Scan the stage's blocks over x: one scan a run of like blocks (a
     bare stacked pytree is one run; `BlockRuns`, a dense layer before
     expert layers, several), all over the one cache stack, a run's blocks
@@ -461,7 +474,12 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
     (`_written_by_run`): a span's run carries it through its scan and
     writes a layer as its block leaves it; in a step the runs that own one
     are not scanned but unrolled, and their blocks may write it themselves
-    (the comment over `WHOLE_IN_PLACE_BYTES`)."""
+    (the comment over `WHOLE_IN_PLACE_BYTES`).
+
+    `write(cache, rows)`, where given, writes the blocks' rows in place of
+    `write_rows` at `pos`: the step whose rows stand each at its own
+    position (parallel/decode_rows.py), whose `pos` is a `RowsAt` that the
+    block step reads and this function only hands on."""
     runs = blocks.runs if isinstance(blocks, BlockRuns) else (blocks,)
     kinds = kinds or (None,) * len(runs)
     owner = leaf_owners(leaves)
@@ -555,11 +573,11 @@ def _run_blocks(blocks, x, cache: Cache, pos, cfg: TransformerConfig,
     rows = rows[0] if len(rows) == 1 else {
         name: jnp.concatenate(parts) for name in cache
         if (parts := [new[name] for new in rows if name in new])}
-    written = write_rows(
-        {name: buf for name, buf in cache.items() if name not in placed}
-        if placed else cache, rows, 0 if prefill else pos,
-        whole=whole_names(leaves), rings=ring_names(leaves),
-        strides=stride_names(leaves))
+    rest = {name: buf for name, buf in cache.items()
+            if name not in placed} if placed else cache
+    written = write(rest, rows) if write is not None else write_rows(
+        rest, rows, 0 if prefill else pos, whole=whole_names(leaves),
+        rings=ring_names(leaves), strides=stride_names(leaves))
     return x, dict(written, **{name: cache[name] for name in placed})
 
 
@@ -608,6 +626,18 @@ def make_stage_fns(family, cfg: TransformerConfig, shard_config: ShardConfig,
     return prefill_fn, decode_fn
 
 
+def run_geometry(family, cfg: TransformerConfig,
+                 shard_config: ShardConfig) -> Dict:
+    """What `_run_blocks` is told of one stage's blocks, as its keywords:
+    the block leaves handed whole, the kind of each run, the family's cache
+    leaves."""
+    leaves = getattr(family, "cache_leaves", None)
+    return dict(
+        whole=tuple(getattr(family, "whole_leaves", ())),
+        kinds=tuple(kind for kind, _ in kind_runs(family, cfg, shard_config)),
+        leaves=leaves(cfg) if leaves is not None else None)
+
+
 def _make_stage_run(family, cfg: TransformerConfig,
                     shard_config: ShardConfig, block_fn=None,
                     finalize_fn=None, embed_fn=None, int8_optin=None):
@@ -627,10 +657,7 @@ def _make_stage_run(family, cfg: TransformerConfig,
             block_fn = partial(_block_step,
                                int8_optin=_resolve_int8_optin(int8_optin))
 
-    whole = tuple(getattr(family, "whole_leaves", ()))
-    kinds = tuple(kind for kind, _ in kind_runs(family, cfg, shard_config))
-    leaves = getattr(family, "cache_leaves", None)
-    leaves = leaves(cfg) if leaves is not None else None
+    geometry = run_geometry(family, cfg, shard_config)
 
     def run(params, data, cache, pos, prefill, read_len=None,
             last_only=False):
@@ -653,8 +680,7 @@ def _make_stage_run(family, cfg: TransformerConfig,
         bf = block_fn if read_len is None \
             else partial(block_fn, read_len=read_len)
         data, cache = _run_blocks(stage_blocks(params), data, cache, pos,
-                                  cfg, prefill, block_fn=bf, whole=whole,
-                                  kinds=kinds, leaves=leaves)
+                                  cfg, prefill, block_fn=bf, **geometry)
         if shard_config.is_last:
             if last_only:
                 data = data[:, -1:]
@@ -1678,16 +1704,9 @@ class DecodePipeline:
     def _count(self, after_prompt, caches) -> None:
         """Add a batch's device counts to the registry's counters, by
         phase: what the prompt counted, and what the steps added."""
-        names = self.family.stats_names
         prompt = sum(read_stats({STATS: s}) for s in after_prompt)
         total = sum(read_stats(c) for c in caches if STATS in c)
-        for phase, counts in (("prefill", prompt),
-                              ("decode", total - prompt)):
-            for name, count in zip(names, counts):
-                prom.REGISTRY.counter(
-                    f"pipeedge_{name}_total",
-                    "counted on the device by the stage programs, read "
-                    "back once a batch").inc(float(count), phase=phase)
+        count_stats(self.family.stats_names, prompt, total - prompt)
 
     def generate_beam(self, ids, new_tokens: int, beams: int):
         """Beam-search decode: keep the `beams` highest log-probability

@@ -24,11 +24,35 @@ which `DecodePipeline.generate` and every other driver keep as they are.
 
 Which families: those whose block has a row step. The plain dense block
 (GPT-2's, `decode._block_step`) has `block_step_rows` below; a family with
-its own `cached_block_step` says so with `FamilySpec.rows_block_step`
-(llama's). A family that names its cache leaves, an int8 cache, experts
-(a capacity-bound router makes rows compete) and the sharded makers have
-none: `rows_block_fn` answers None and the executor keeps one dispatch a
-request for them.
+its own `cached_block_step` says so with `FamilySpec.rows_block_step`:
+llama's, and the window-and-full block of laguna and mellum
+(`models/laguna.py`), the first whose stage is runs of more than one kind of
+block, whose cache has rings beside rows a position (a ring a slot, each at
+its own row's position: `stage_cache.attend_rows`, `write_rows_at`), whose
+FFN is routed experts (the router drops nothing, so rows do not compete; a
+dead slot's row goes to no expert) and whose block steps count into a
+`stats` leaf. The step runs the stage's blocks as every stage program does
+(`decode._run_blocks`: a scan a run, each run at its own leaves' layers).
+
+What still steps one dispatch a request, and why (`rows_block_fn` answers
+None):
+- a leaf that is a row a request (`whole`: a recurrent state or a
+  convolution's tail, replaced by every call): a dead slot's row would have
+  to leave its state as it was, and a joining request's state is no row to
+  write at a position; nothing here installs or masks one (qwen3_next,
+  lfm2, minicpm_sala's lightning layers, nemotron_h, granite_hybrid,
+  brumby);
+- a strided leaf (minicpm_sala's pooled keys, a row every few positions
+  written by the call that completes it): rows at different positions
+  complete different rows;
+- a family whose block has no row step (keye's selection, kimi's latent
+  attention);
+- GPT-2's capacity-bound experts (`cfg.n_experts` without a top-k: rows
+  compete for an expert's capacity, so a row's result would depend on its
+  neighbours);
+- an int8 cache (the walk reads a block as stored, and a quantized block
+  has scales beside it) and the sharded makers (tp, ep, tp x ep, sp: their
+  programs run under `shard_map` with one `pos`).
 """
 from __future__ import annotations
 
@@ -42,9 +66,11 @@ import numpy as np
 
 from ..models import ShardConfig
 from ..models.layers import TransformerConfig, layer_norm
-from ..models.stage_cache import (LayerCache, RowsAt, attend_rows,
-                                  write_rows_at)
-from .decode import _block_tail, _qkv, stage_blocks
+from ..models.stage_cache import (STATS, LayerCache, RowsAt, attend_rows,
+                                  merge_stats, ring_names, stride_names,
+                                  whole_names, write_rows_at)
+from .decode import (_block_tail, _qkv, _run_blocks, run_geometry,
+                     stage_blocks)
 
 # positions a block of the walk holds. A turn of the walk costs about 19 us
 # whatever it reads (gpt2-medium on the v5e, my chip run, PR 55: 48 rows at
@@ -73,15 +99,20 @@ def embed_rows(pe: Dict, tok: jax.Array, pos) -> jax.Array:
 
 def rows_block_fn(pipe):
     """The block step that takes row positions for `pipe`'s stages, or None
-    where its programs take one `pos`: see the module's docstring."""
-    if (pipe.cache_leaves is not None or pipe.cache_bits
-            or pipe.mesh is not None or pipe.ep_mesh is not None
-            or pipe.tp_ep_mesh is not None or pipe.sp_degree != 1
-            or pipe.cfg.n_experts
-            or getattr(pipe.family, "block_kind", None) is not None):
+    where its programs take one `pos`. What a call's family and leaves say
+    decides (the module's docstring has the reasons): None for an int8
+    cache and the sharded makers; the plain dense block's `block_step_rows`
+    where the family has no `cached_block_step` (None where its experts are
+    capacity-bound); else the family's `rows_block_step`, if it has one and
+    every leaf it names is a row a position or a ring (no `whole` leaf, no
+    strided one)."""
+    if (pipe.cache_bits or pipe.mesh is not None or pipe.ep_mesh is not None
+            or pipe.tp_ep_mesh is not None or pipe.sp_degree != 1):
         return None
     if getattr(pipe.family, "cached_block_step", None) is None:
-        return block_step_rows
+        return None if pipe.cfg.n_experts else block_step_rows
+    if whole_names(pipe.cache_leaves) or stride_names(pipe.cache_leaves):
+        return None
     return getattr(pipe.family, "rows_block_step", None)
 
 
@@ -124,24 +155,26 @@ def make_rows_step(family, cfg: TransformerConfig,
     stage `ids` with each live row's greedy pick in its slot. The cache is
     DONATED, as in every stage program."""
     embed = getattr(family, "decode_embed", None) or embed_rows
+    geometry = run_geometry(family, cfg, shard_config)
+    rings = ring_names(geometry["leaves"])
+
+    def step_block(bp, y, bcache, at, cfg_, prefill):
+        return block_fn(bp, y, bcache, at, cfg_, block)
 
     def rows_step(params, ids, hidden, cache, where):
         base, pos = where[0], where[1:]
         at = jnp.maximum(pos, 0)
-        at = RowsAt(base, at, jnp.max(at))
+        at = RowsAt(base, at, jnp.max(at), pos >= 0)
         mine = jax.lax.dynamic_slice(ids, (base, 0), (rows, 1))
         x = embed(params["embeddings"], mine, at.pos) \
             if shard_config.is_first else hidden
-
-        def body(y, xs):
-            bp, layer = xs
-            y, bc = block_fn(bp, y, LayerCache(cache, layer), at, cfg, block)
-            return y, bc.rows
-
-        blocks = stage_blocks(params)
-        n_blocks = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-        x, new = jax.lax.scan(body, x, (blocks, jnp.arange(n_blocks)))
-        cache = write_rows_at(cache, new, base, pos)
+        # the stage's runs of like blocks as every stage program scans
+        # them, each block handed `at` where those are handed one `pos`
+        x, cache = _run_blocks(
+            stage_blocks(params), x, cache, at, cfg, False,
+            block_fn=step_block, **geometry,
+            write=lambda held, new: write_rows_at(held, new, base, pos,
+                                                  rings))
         if not shard_config.is_last:
             return x, cache
         logits = family.finalize(params["final"], x, cfg)
@@ -156,10 +189,14 @@ def make_rows_step(family, cfg: TransformerConfig,
 @partial(jax.jit, donate_argnums=(0,))
 def install_rows(stage_cache, cache, slots):
     """A request's rows, as its prompt pass left them (`cache`, leaves
-    `[L, B, T, ...]`), into slots `slots [B]` of the stage-wide cache
-    (DONATED): one in-place update a leaf a row, whole rows, so nothing a
-    slot's last owner wrote outlives it."""
+    `[L, B, T, ...]`, a ring's `[L, B, W, ...]`), into slots `slots [B]` of
+    the stage-wide cache (DONATED): one in-place update a leaf a row, whole
+    rows and whole rings, so nothing a slot's last owner wrote outlives it.
+    The `stats` leaf is no row: what the prompt pass counted is added up
+    apart (`StageRows.install`)."""
     for name, rows in cache.items():
+        if name == STATS:
+            continue
         buf = stage_cache[name]
         for b in range(rows.shape[1]):
             buf = jax.lax.dynamic_update_slice(
@@ -167,6 +204,9 @@ def install_rows(stage_cache, cache, slots):
                 (0, slots[b]) + (0,) * (buf.ndim - 2))
         stage_cache = dict(stage_cache, **{name: buf})
     return stage_cache
+
+
+_merge_stats = jax.jit(merge_stats)
 
 
 @jax.jit
@@ -188,6 +228,11 @@ class StageRows:
         self.pipe, self.slots = pipe, int(slots)
         self.rungs = row_rungs(self.slots)
         self.caches = pipe._fresh_caches(self.slots)
+        # what the installed requests' prompt passes counted, a stage
+        # (`stats` leaves, where the family has them): the steps' counts
+        # are in the stage-wide caches' own
+        self.prompt_stats = [jnp.zeros_like(cache[STATS])
+                             for cache in self.caches if STATS in cache]
         self.ids = jnp.zeros((self.slots, 1), jnp.int32)
         self._free = list(range(self.slots))        # a heap
         total = 4 * pipe.cfg.num_hidden_layers
@@ -235,9 +280,12 @@ class StageRows:
 
     def install(self, i: int, cache, slots) -> None:
         """A request's rows of stage `i`, after its prompt pass, into its
-        slots."""
+        slots, and what that pass counted onto the stage's prompt counts."""
         self.caches[i] = install_rows(self.caches[i], cache,
                                       np.asarray(slots, np.int32))
+        if STATS in cache:
+            self.prompt_stats[i] = _merge_stats(self.prompt_stats[i],
+                                                cache[STATS])
 
     def join(self, step_ids, slots) -> None:
         """A request's first tokens into its slots of `ids`."""

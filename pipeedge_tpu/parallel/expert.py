@@ -412,7 +412,7 @@ def _expert_ffn(x: jax.Array, w: Dict, act: str) -> jax.Array:
 
 
 def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
-                   held=None, layer=None):
+                   held=None, layer=None, live=None):
     """Routed FFN delta of `normed` [B, S, D], and its counts.
 
     `params`: `router` {w [D, E][, bias]}, `experts` {gate, up [.., F, D],
@@ -434,7 +434,13 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
     key and nothing more, and add nothing here. `layer`, when given,
     indexes a leading layer axis of the expert leaves: the stacked blocks
     are then read one expert's matrices at a time and a whole layer's
-    experts are never copied out of the stack.
+    experts are never copied out of the stack. `live` (bool [B * S]; None =
+    every token): the tokens that stand for something. The router drops
+    nothing, so tokens do not compete and a row of padding changes no other
+    row's result; but its assignments would read experts' weights and be
+    counted, so they are handed on like another chip's: sorted last,
+    multiplied by no expert, their delta zero (the served executor's dead
+    slots, parallel/decode_rows.py).
 
     The assignments are sorted by expert, and the sorted groups are
     multiplied by their experts in one of two ways, by what the call's
@@ -464,6 +470,8 @@ def topk_ffn_delta(params: Dict, normed: jax.Array, cfg: TransformerConfig,
                             w_contract=1).astype(tokens.dtype)
     local = experts.reshape(-1) - first                     # [A]
     mine = (local >= 0) & (local < count)
+    if live is not None:
+        mine &= jnp.repeat(live.reshape(-1), k)
     local = jnp.where(mine, local, count)                   # others sort last
     # the assignments sorted by expert (stable: by token within an expert;
     # other chips' last), by sorts and searches: a scatter of this many

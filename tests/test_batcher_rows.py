@@ -293,11 +293,14 @@ def test_a_sampled_request_steps_alone_beside_greedy_rows(pipes):
     assert _rows("live") - live == 2 * 7
 
 
-@pytest.mark.parametrize("model", ["keye", "laguna", "moe"])
+@pytest.mark.parametrize("model", ["keye", "lfm2", "minicpm-sala", "moe"])
 def test_a_stage_that_takes_one_pos_goes_one_request_a_dispatch(model):
-    """A family that names its cache leaves (its `write_rows` takes one
-    `pos`) and the dense block with experts have no row step: the executor
-    makes no stage-wide cache and every stage-step is one request's."""
+    """A family whose block has no row step (keye's selection), one with a
+    leaf that is a row a request (lfm2's tails) or a row every few positions
+    (minicpm_sala's pooled keys) and the dense block with capacity-bound
+    experts: the executor makes no stage-wide cache and every stage-step is
+    one request's, its prompt pass in the family's spans where it has
+    them."""
     pipe = decode.build_decode_pipeline(f"pipeedge/test-tiny-{model}",
                                         max_len=32, dtype=jnp.float32)
     assert decode_rows.rows_block_fn(pipe) is None
@@ -310,7 +313,9 @@ def test_a_stage_that_takes_one_pos_goes_one_request_a_dispatch(model):
     results = batcher.run()
     for i, ids in enumerate(prompts):
         np.testing.assert_array_equal(results[i], _solo(pipe, ids, 4))
-    assert batcher.stats["stage_steps"] == 2 * 4
+    spans = sum(-(-ids.shape[1] // (pipe.prefill_span or ids.shape[1]))
+                for ids in prompts)
+    assert batcher.stats["stage_steps"] == spans + 2 * 3
     assert _rows("live") == live
 
 

@@ -7,6 +7,7 @@ shows and what it does not), in a file of its own so that `--dist loadfile`
 gives the two halves to two workers. The described chip and the switch to
 the grouped kernels are that file's fixtures.
 """
+import math
 import re
 
 import jax
@@ -157,6 +158,66 @@ def test_laguna_stage_program_compiles_for_v5e(span, last_only, on_chip):
     # no leaf padded, and the rings are rings
     assert memory.argument_size_in_bytes < 7.75e9 + 1.02 * cache_bytes
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+
+
+MELLUM_CELL = "JetBrains/Mellum2-12B-A2.5B-Instruct@8"
+
+
+@pytest.mark.parametrize("rung", [1, 32])
+def test_mellum_rows_step_updates_the_stage_cache_in_place(rung, on_chip):
+    """`mellum2.ide-mixed`'s step at its real size (`--max-active 32
+    --max-len 8704`): eight blocks at the published widths in four runs of
+    two kinds, every expert held, the rung of one row and the rung of all 32
+    (`decode_rows.row_rungs`). 7.59 GB of weights and 32 slots of 71.3 MB of
+    rows in the TWO full layers and 25.2 MB of rings in SIX: 3.09 GB, which
+    the program returns as it took them (the donated cache is the aliased
+    one), with the rung's first slot and every row's position traced; each
+    run's experts go through the grouped kernel; nothing of a rung's rows or
+    rings is copied or converted on its way to the walk."""
+    from pipeedge_tpu.models import laguna
+    from pipeedge_tpu.models.shard import kind_runs
+    from pipeedge_tpu.parallel import decode_rows
+    entry = registry.get_model_entry(MELLUM_CELL)
+    cfg = entry.config
+    stage = ShardConfig(1, entry.layers, is_first=True, is_last=True)
+    slots, max_len = 32, 8704
+    assert rung in decode_rows.row_rungs(slots)
+    params = jax.eval_shape(lambda: laguna._assemble(
+        cfg, stage, lambda key, shape: jnp.zeros(shape), jnp.bfloat16))
+    cache = jax.eval_shape(lambda: stage_cache.init_cache(
+        cfg, cfg.num_hidden_layers, slots, max_len,
+        leaves=entry.family.FAMILY.cache_leaves(cfg),
+        runs=kind_runs(entry.family.FAMILY, cfg, stage)))
+    assert cache["k_ring"].shape == (6, slots, 1024, 512)
+    assert cache["k"].shape == (2, slots, max_len, 512)
+    params, cache = jax.tree_util.tree_map(
+        lambda leaf: on_chip(leaf.shape, leaf.dtype), (params, cache))
+    block = decode_rows.walk_block(max_len, rung)
+    step = decode_rows.make_rows_step(
+        entry.family.FAMILY, cfg, stage, laguna.rows_block_step, rung, block)
+    compiled = step.lower(params, on_chip((slots, 1), jnp.int32), None,
+                          cache, on_chip((1 + rung,), jnp.int32)).compile()
+    assert _grouped_kernels(compiled) == 4      # one a run of blocks
+    memory = compiled.memory_analysis()
+    print(f"mellum rung {rung} of {slots}: arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.2f} GB, aliased "
+          f"{memory.alias_size_in_bytes / 1e9:.2f} GB")
+    cache_bytes = slots * (2 * max_len + 6 * 1024) * 4096
+    assert memory.alias_size_in_bytes > cache_bytes     # updated in place
+    assert memory.argument_size_in_bytes < 7.60e9 + 1.01 * cache_bytes
+    assert memory.temp_size_in_bytes < 1 << 29
+    # (one row's head is a fused multiply and sum over the table, converted
+    # inside the fusion: not a copy of it)
+    moved = [dims for dims in re.findall(
+        r"= \w+\[([\d,]*)\]\S* (?:copy|convert)\(", compiled.as_text())
+        if str(cfg.vocab_size) not in dims.split(",")]
+    largest = max([math.prod(int(n) for n in dims.split(",") if n)
+                   for dims in moved], default=0)
+    # a block of the walk of a rung's rows, or (one row) a layer's widest
+    # weight outside the experts
+    assert largest <= max(rung * block * 512, 4096 * cfg.hidden_size), (
+        largest, rung, block)
 
 
 KEYE_CELL = "Kwai-Keye/Keye-VL-2.0-30B-A3B@6"

@@ -769,3 +769,27 @@ def test_costs_against_hand_counts():
     assert prompt - full == 32 * 3 * 4 * 64 * hd \
         * costs.window_pairs(config, 0, 7680)
     assert 46e12 < full < 47e12 and 11.9e12 < prompt - full < 12e12
+
+
+def test_rows_step_together_through_the_block_it_shares_with_mellum(tiny):
+    """The served executor's step of rows that stand each at its own
+    position (parallel/decode_rows.py) over laguna's own block (a gate a
+    head, a shared expert, a leading dense layer, two head counts, three
+    kinds of run in one stage): the code `tests/test_mellum.py` holds to the
+    reference under the other family's name gives each request, token for
+    token, what it gets alone."""
+    from pipeedge_tpu.parallel import decode_rows
+    from pipeedge_tpu.parallel.batcher import ContinuousBatcher
+    config, _, pipe, _, _ = tiny
+    assert decode_rows.rows_block_fn(pipe) is laguna.rows_block_step
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, config["vocab_size"], size=(1, n))
+               for n in (4, 11, 26)]
+    batcher = ContinuousBatcher(pipe, max_active=4)
+    assert batcher.rows is not None
+    for i, ids in enumerate(prompts):
+        batcher.submit(i, ids, new_tokens=10)
+    results = batcher.run()
+    for i, ids in enumerate(prompts):
+        np.testing.assert_array_equal(
+            results[i], np.asarray(pipe.generate(ids, 10)))

@@ -10,7 +10,11 @@ against `benchmark/reference/<family>.forward` over the same ids. The gap of
 a position is the largest difference of a logit over the reference row's
 range. Prints one JSON line: the median and the worst gap of the spans' last
 rows and of the steps, the worst's position, and whether the largest logit
-agrees everywhere.
+agrees everywhere. A served cell's mix names no batch: one row then, of the
+mix's longest prompt and longest answer, under the server's `--max-len`
+(the executor's prompt pass runs the same span programs; its row step picks
+inside the program and has no logits to compare: `benchmark/correct.py`
+holds its tokens).
 
 Usage (from the root of a checkout, on the chip):
     python tools/compare_positions.py --workload keye-vl2.long-batch --seed 7
@@ -49,12 +53,18 @@ def main():
     config, traffic = ctx.config, ctx.traffic
     model = config["program_model"]
     dtype = jnp.bfloat16 if config["dtype"] == "bfloat16" else jnp.float32
-    rows, prompt_len = traffic["batch"], traffic["prompt_len"]
-    steps = args.steps or traffic["new_tokens"]
+    rows, prompt_len = traffic.get("batch", 1), traffic["prompt_len"]
+    steps, max_len = args.steps or traffic["new_tokens"], \
+        traffic.get("max_len")
+    if "server_args" in traffic:    # a served mix: its longest request
+        prompt_len = max(prompt_len["choices"])
+        steps = args.steps or max(traffic["new_tokens"]["log_uniform"])
+        served = traffic["server_args"]
+        max_len = int(served[served.index("--max-len") + 1])
     path = weights.write(config, ctx.seed, os.path.join(
         ctx.work, "weights", registry.get_model_default_weights_file(model)))
     pipe = decode.build_decode_pipeline(
-        model, None, max_len=traffic["max_len"], dtype=dtype, model_file=path)
+        model, None, max_len=max_len, dtype=dtype, model_file=path)
     span = pipe.prefill_span
     if not span:
         sys.exit(f"{model} prefills its prompt whole: nothing to compare "

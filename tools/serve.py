@@ -1370,6 +1370,9 @@ def make_handler(service, model_name, profile_dir=None):
         def do_GET(self):
             if self.path == "/metrics":
                 import monitoring
+                # what the family's block steps counted on the device
+                # since the last scrape (the expert and window counters)
+                service.executor.count_stats()
                 extra = prom.render_monitoring_snapshot(
                     monitoring.snapshot())
                 rec = telemetry.recorder()
